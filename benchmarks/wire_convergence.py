@@ -1,6 +1,6 @@
 """Wire-format convergence evidence: fp32 vs fp8 vs int4 outer syncs.
 
-ROADMAP item 5b / round-5 VERDICT #6: the lossy wire codecs
+The lossy wire codecs
 (ops/quantization.py) ship with speed numbers but no end-to-end quality
 evidence. This bench closes that gap in pure Python: a same-seed,
 same-batch-stream DiLoCo-style run per wire format, where every outer

@@ -56,39 +56,17 @@ def _bench_params():
             "w0": jnp.ones((n // 2,), jnp.bfloat16),
             "w1": jnp.ones((n - n // 2,), jnp.bfloat16),
         }, f"synthetic-{n}"
-    try:
-        from torchft_tpu.models.llama import Llama, LlamaConfig
+    from torchft_tpu.models.llama import Llama, LlamaConfig
 
-        seq = 512
-        config = LlamaConfig(
-            vocab_size=8192, dim=512, n_layers=6, n_heads=8, n_kv_heads=4,
-            ffn_hidden=1536, max_seq_len=seq, dtype=jnp.bfloat16,
-        )
-        model = Llama(config)
-        tokens = jnp.zeros((2, seq), dtype=jnp.int32)
-        params = model.init(jax.random.PRNGKey(0), tokens)
-        return params, "llama-27M (bench.py cpu-full config)"
-    except Exception as e:  # noqa: BLE001 — e.g. jax too old for the model
-        # Same leaf geometry as the 27M config, built without the model
-        # (this container's jax 0.4.37 lacks APIs the model needs). The
-        # flat-plane byte math is shape-exact either way.
-        vocab, dim, layers, ffn, kv_dim = 8192, 512, 6, 1536, 256
-        tree = {"embed": jnp.zeros((vocab, dim), jnp.bfloat16),
-                "output": jnp.zeros((dim, vocab), jnp.bfloat16),
-                "final_norm": jnp.zeros((dim,), jnp.bfloat16)}
-        for i in range(layers):
-            tree[f"layer_{i}"] = {
-                "wq": jnp.zeros((dim, dim), jnp.bfloat16),
-                "wk": jnp.zeros((dim, kv_dim), jnp.bfloat16),
-                "wv": jnp.zeros((dim, kv_dim), jnp.bfloat16),
-                "wo": jnp.zeros((dim, dim), jnp.bfloat16),
-                "w1": jnp.zeros((dim, ffn), jnp.bfloat16),
-                "w2": jnp.zeros((ffn, dim), jnp.bfloat16),
-                "w3": jnp.zeros((dim, ffn), jnp.bfloat16),
-                "attn_norm": jnp.zeros((dim,), jnp.bfloat16),
-                "ffn_norm": jnp.zeros((dim,), jnp.bfloat16),
-            }
-        return tree, f"llama-27M shapes (model init unavailable: {e})"
+    seq = 512
+    config = LlamaConfig(
+        vocab_size=8192, dim=512, n_layers=6, n_heads=8, n_kv_heads=4,
+        ffn_hidden=1536, max_seq_len=seq, dtype=jnp.bfloat16,
+    )
+    model = Llama(config)
+    tokens = jnp.zeros((2, seq), dtype=jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), tokens)
+    return params, "llama-27M (bench.py default config)"
 
 
 def _tree_bytes(tree) -> int:

@@ -36,8 +36,6 @@ from torchft_tpu.punisher import FAULT_MODES, kill_one
 _TRAINER = r"""
 import hashlib, json, os, pathlib, sys, time
 sys.path.insert(0, os.environ["TPUFT_REPO"])
-from torchft_tpu.utils.platform import honor_jax_platforms_env
-honor_jax_platforms_env()
 import jax
 import jax.numpy as jnp
 import numpy as np

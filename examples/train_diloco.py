@@ -25,10 +25,6 @@ sys.path.insert(0, REPO_ROOT)
 
 def train(args: argparse.Namespace) -> None:
     import jax
-
-    from torchft_tpu.utils.platform import honor_jax_platforms_env
-
-    honor_jax_platforms_env()
     import jax.numpy as jnp
     import numpy as np
     import optax
@@ -107,18 +103,18 @@ def train(args: argparse.Namespace) -> None:
 
 def demo(args: argparse.Namespace) -> None:
     from torchft_tpu.coordination import LighthouseServer
+    from torchft_tpu.launch import chip_envs
 
+    # One process per chip: each group gets its own (refused when the host
+    # has fewer chips than groups and the caller did not ask for the CPU).
+    chips = chip_envs(args.num_replica_groups)
     lighthouse = LighthouseServer(
         min_replicas=1, join_timeout_ms=5000, heartbeat_timeout_ms=2000
     )
-    env_base = {
-        **os.environ,
-        "TPUFT_LIGHTHOUSE": lighthouse.address(),
-        "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu"),
-    }
+    env_base = {**os.environ, "TPUFT_LIGHTHOUSE": lighthouse.address()}
 
     def spawn(group: int) -> subprocess.Popen:
-        env = {**env_base, "REPLICA_GROUP_ID": str(group)}
+        env = {**env_base, **chips[group], "REPLICA_GROUP_ID": str(group)}
         argv = [
             sys.executable, os.path.abspath(__file__),
             "--syncs", str(args.syncs),

@@ -6,10 +6,14 @@ ft_init_device_mesh, SURVEY.md §2.7).
 Each replica-group process builds a real jax Mesh over its devices and
 shards a Llama-family model with the megatron layout; gradients reduce
 across groups shard-by-shard via ft_allreduce_sharded, preserving the
-intra-slice sharding end to end. On this one-chip box the demo runs on
-virtual CPU devices (4 per group by default).
+intra-slice sharding end to end. The mesh spans every device the process
+sees: on a TPU host ``--demo`` gives each group process its own share of
+the chips (torchft_tpu.launch.chip_envs); the CPU is something the caller
+asks for by name.
 
     python examples/train_hsdp.py --demo --num-replica-groups 2 --steps 10
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4 \
+        python examples/train_hsdp.py --demo --num-replica-groups 2 --steps 10
 """
 
 from __future__ import annotations
@@ -28,14 +32,6 @@ sys.path.insert(0, REPO_ROOT)
 def train(args: argparse.Namespace) -> None:
     import jax
 
-    # Virtual intra-slice devices for the demo (must precede backend init).
-    # With a group jax cluster (TPUFT_JAX_COORDINATOR), this is the LOCAL
-    # device count per process and the mesh below spans the whole group.
-    try:
-        jax.config.update("jax_platforms", "cpu")
-        jax.config.update("jax_num_cpu_devices", args.devices_per_group)
-    except RuntimeError:
-        pass
     from torchft_tpu.bootstrap import init_group_jax_cluster, init_manager
 
     clustered = init_group_jax_cluster()
@@ -150,18 +146,19 @@ def train(args: argparse.Namespace) -> None:
 
 def demo(args: argparse.Namespace) -> None:
     from torchft_tpu.coordination import LighthouseServer
+    from torchft_tpu.launch import chip_envs
 
+    chips = chip_envs(args.num_replica_groups)
     lighthouse = LighthouseServer(
         min_replicas=1, join_timeout_ms=5000, heartbeat_timeout_ms=2000
     )
     env_base = {**os.environ, "TPUFT_LIGHTHOUSE": lighthouse.address()}
 
     def spawn(group: int) -> subprocess.Popen:
-        env = {**env_base, "REPLICA_GROUP_ID": str(group)}
+        env = {**env_base, **chips[group], "REPLICA_GROUP_ID": str(group)}
         argv = [
             sys.executable, os.path.abspath(__file__),
             "--steps", str(args.steps),
-            "--devices-per-group", str(args.devices_per_group),
             "--batch-size", str(args.batch_size),
             "--seq-len", str(args.seq_len),
         ]
@@ -201,7 +198,6 @@ def main() -> None:
     parser.add_argument("--steps", type=int, default=10)
     parser.add_argument("--batch-size", type=int, default=4)
     parser.add_argument("--seq-len", type=int, default=64)
-    parser.add_argument("--devices-per-group", type=int, default=4)
     parser.add_argument(
         "--scan-layers", action="store_true",
         help="lax.scan'd layer stack (O(1) HLO in depth)",

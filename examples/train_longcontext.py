@@ -11,6 +11,10 @@ context-parallel path at all (SURVEY.md §2.7).
 
     python examples/train_longcontext.py --demo --num-replica-groups 2 \
         --seq-len 512 --sp 4
+
+Each group needs ``--sp`` devices: on a TPU host ``--demo`` splits the chips
+between the group processes; the CPU is asked for by name
+(``JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4``).
 """
 
 from __future__ import annotations
@@ -28,13 +32,6 @@ sys.path.insert(0, REPO_ROOT)
 
 def train(args: argparse.Namespace) -> None:
     import jax
-
-    # Virtual devices for the demo box (precedes backend init).
-    try:
-        jax.config.update("jax_platforms", "cpu")
-        jax.config.update("jax_num_cpu_devices", args.sp)
-    except RuntimeError:
-        pass
     import jax.numpy as jnp
     import numpy as np
     import optax
@@ -63,6 +60,13 @@ def train(args: argparse.Namespace) -> None:
         ring_use_flash=args.ring_flash,
     )
     model = Llama(config)
+    if len(jax.devices()) < args.sp:
+        raise SystemExit(
+            f"--sp {args.sp} needs {args.sp} devices, this process sees "
+            f"{len(jax.devices())} ({jax.devices()[0].platform}); on the CPU "
+            "ask for them by name: JAX_PLATFORMS=cpu "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count={args.sp}"
+        )
     mesh = Mesh(np.array(jax.devices()[: args.sp]), ("sp",))
 
     tokens0 = jnp.zeros((args.batch_size, args.seq_len), dtype=jnp.int32)
@@ -153,14 +157,16 @@ def train(args: argparse.Namespace) -> None:
 
 def demo(args: argparse.Namespace) -> None:
     from torchft_tpu.coordination import LighthouseServer
+    from torchft_tpu.launch import chip_envs
 
+    chips = chip_envs(args.num_replica_groups)
     lighthouse = LighthouseServer(
         min_replicas=1, join_timeout_ms=5000, heartbeat_timeout_ms=2000
     )
     env_base = {**os.environ, "TPUFT_LIGHTHOUSE": lighthouse.address()}
 
     def spawn(group: int) -> subprocess.Popen:
-        env = {**env_base, "REPLICA_GROUP_ID": str(group)}
+        env = {**env_base, **chips[group], "REPLICA_GROUP_ID": str(group)}
         return subprocess.Popen(
             [
                 sys.executable, os.path.abspath(__file__),

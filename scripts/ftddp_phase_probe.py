@@ -1,11 +1,9 @@
 #!/usr/bin/env python
 """On-chip phase instrumentation for the FT-DDP lone-replica step.
 
-BENCH_TPU_* captured ft_ddp_vs_baseline 0.13 (27M) / 0.25 (444M): far more
-per-step overhead than one device-sync RTT explains at the large config.
-This probe times each phase of make_step_fn's lone path — quorum wait,
-fused dispatch, device sync, commit barrier — on the real chip to locate
-the cost before optimizing further.
+Times each phase of make_step_fn's lone path — quorum wait, fused
+dispatch, device sync, commit barrier — on the real chip, to locate the
+per-step FT overhead before optimizing it.
 
 Usage: python scripts/ftddp_phase_probe.py [dim n_layers]
 """
@@ -19,11 +17,9 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-from torchft_tpu.utils.platform import probe_accelerator
+from torchft_tpu.utils.platform import require_tpu
 
-if not probe_accelerator(timeout=180.0):
-    sys.stderr.write("phase probe: accelerator probe failed; aborting\n")
-    sys.exit(1)
+require_tpu()  # a chip script: exits non-zero when jax answers on anything else
 
 import jax
 import jax.numpy as jnp

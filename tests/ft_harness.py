@@ -12,7 +12,6 @@ from __future__ import annotations
 import logging
 import threading
 import time
-from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
@@ -225,18 +224,13 @@ class Runner:
         for attempt in range(self.attempts):
             store = StoreServer()
             try:
-                with ThreadPoolExecutor(
-                    max_workers=self.world_size,
-                    thread_name_prefix=f"replica{self.replica_group}",
-                ) as pool:
-                    futures = [
-                        pool.submit(self._run_rank, store, rank)
+                return _run_on_daemon_threads(
+                    [
+                        (lambda rank=rank: self._run_rank(store, rank))
                         for rank in range(self.world_size)
-                    ]
-                    results = []
-                    for fut in futures:
-                        results.append(fut.result())
-                    return results
+                    ],
+                    f"replica{self.replica_group}",
+                )
             except (InjectedFailure, DegradedReplicaError) as e:
                 # Both are "supervisor restarts the group" in production:
                 # an injected process death, or the health plane's
@@ -266,13 +260,47 @@ class Runner:
         )
 
 
+def _run_on_daemon_threads(
+    fns: List[Callable[[], Any]], name: str, timeout: Optional[float] = None
+) -> List[Any]:
+    """Runs ``fns`` concurrently and returns their results in order, raising
+    the first (in order) exception. A thread still running ``timeout``
+    seconds in raises TimeoutError instead — and, being a daemon, holds up
+    neither the caller nor interpreter exit. (A ThreadPoolExecutor joins its
+    workers without bound, on leaving its with-block and again at exit: one
+    stuck replica thread used to hang the whole tier-1 run.)"""
+    results: List[Any] = [None] * len(fns)
+    errors: List[Optional[BaseException]] = [None] * len(fns)
+
+    def run(i: int) -> None:
+        try:
+            results[i] = fns[i]()
+        except BaseException as e:  # re-raised on the caller's thread
+            errors[i] = e
+
+    threads = [
+        threading.Thread(target=run, args=(i,), name=f"{name}_{i}", daemon=True)
+        for i in range(len(fns))
+    ]
+    deadline = None if timeout is None else time.monotonic() + timeout
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(None if deadline is None else max(0.0, deadline - time.monotonic()))
+    for i, t in enumerate(threads):
+        if errors[i] is not None:
+            raise errors[i]
+        if t.is_alive():
+            raise TimeoutError(f"{name}_{i} still running after {timeout}s")
+    return results
+
+
 def run_replica_groups(runners: List[Runner], timeout: float = 120.0) -> List[List[Any]]:
-    """Runs all replica groups concurrently; returns per-group results."""
-    with ThreadPoolExecutor(
-        max_workers=len(runners), thread_name_prefix="group"
-    ) as pool:
-        futures = [pool.submit(r.run_replica) for r in runners]
-        return [f.result(timeout=timeout) for f in futures]
+    """Runs all replica groups concurrently; returns per-group results. A
+    group that has not finished after ``timeout`` seconds fails the test."""
+    return _run_on_daemon_threads(
+        [r.run_replica for r in runners], "group", timeout=timeout
+    )
 
 
 # ---------------------------------------------------------------------------

@@ -35,6 +35,11 @@ def lighthouse():
     server.shutdown()
 
 
+def _virtual_devices(n: int) -> dict:
+    """The caller asks for a CPU mesh by name; no example forces one."""
+    return {"XLA_FLAGS": f"--xla_force_host_platform_device_count={n}"}
+
+
 def _run(script: str, args: list, lighthouse, timeout: int = 180, env=None):
     full_env = {
         **os.environ,
@@ -96,9 +101,10 @@ def test_train_hsdp(lighthouse):
         "train_hsdp.py",
         [
             "--num-replica-groups", 1, "--steps", 2, "--batch-size", 4,
-            "--seq-len", 32, "--devices-per-group", 2,
+            "--seq-len", 32,
         ],
         lighthouse,
+        env=_virtual_devices(2),
     )
     assert "param_digest=" in out
 
@@ -109,10 +115,11 @@ def test_train_hsdp_fit_levers(lighthouse):
         "train_hsdp.py",
         [
             "--num-replica-groups", 1, "--steps", 2, "--batch-size", 4,
-            "--seq-len", 32, "--devices-per-group", 2,
+            "--seq-len", 32,
             "--scan-layers", "--remat", "--fused-ce",
         ],
         lighthouse,
+        env=_virtual_devices(2),
     )
     assert "param_digest=" in out
 
@@ -125,6 +132,7 @@ def test_train_longcontext(lighthouse):
             "--seq-len", 128, "--sp", 2,
         ],
         lighthouse,
+        env=_virtual_devices(2),
     )
     assert "param_digest=" in out
 

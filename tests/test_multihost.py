@@ -128,16 +128,6 @@ def test_init_group_jax_cluster_noop_without_coordinator(monkeypatch) -> None:
     assert init_group_jax_cluster() is False
 
 
-def test_honor_jax_platforms_env_noop_cases(monkeypatch) -> None:
-    from torchft_tpu.utils.platform import honor_jax_platforms_env
-
-    # Unset: no-op. Set after backend init: swallows the RuntimeError.
-    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-    honor_jax_platforms_env()
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    honor_jax_platforms_env()  # backend already initialized by conftest
-
-
 def test_launcher_rejects_coordinator_without_multirank() -> None:
     from torchft_tpu.launch import supervise
 

@@ -1,0 +1,228 @@
+"""Chipless COMPILES for the TPU: ask the chip's compiler before the chip.
+
+tests/test_mosaic_lowering.py only *lowers* the Pallas kernels (block-mapping
+validation). This file goes one step further and compiles — for a v5e that is
+described, not attached (``jax.experimental.topologies``) — the kernels of
+chip_smoke.py's main path at its real widths, and its whole plain step. That
+catches what lowering cannot: a kernel that wants more VMEM than it may use,
+a slice not aligned to the tiling, a program the TPU compiler refuses. Every
+case asserts the Mosaic call (``tpu_custom_call``) is in the compiled text.
+
+Nothing runs, so this says nothing about results or speed: a compile that
+passes is a rehearsal, not a chip run. Skipped where the topology cannot be
+described (no TPU compiler beside this jax). The persistent compile cache is
+off around the module: such compiles write entries a later chipless run
+cannot read back, and warns about.
+"""
+
+from __future__ import annotations
+
+import os
+from dataclasses import replace
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+
+# chip_smoke.py's shapes: CONFIGS["1b"] head geometry at batch 4 x seq 2048,
+# the sp=4 ring hop of its 8192-token ring phase, and its largest DiLoCo
+# fragment (one 128256 x 2048 vocabulary matrix) in 256-element blocks.
+B, S, H, KV, D = 4, 2048, 32, 8, 64
+RING_B, RING_HOP = 2, 2048
+FRAGMENT_BLOCKS = 128256 * 2048 // 256
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _compile_cache_off():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four devices of a described (not attached) v5e 2x2."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here: skip, with why
+        pytest.skip(f"v5e topology cannot be described: {type(e).__name__}: {e}")
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def chip(v5e):
+    """One of them, as a single-device sharding."""
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e[0])
+
+
+def _sds(shape, dtype, chip):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _qkv(chip, b=B, sq=S, sk=S):
+    return (
+        _sds((b, sq, H, D), jnp.bfloat16, chip),
+        _sds((b, sk, KV, D), jnp.bfloat16, chip),
+        _sds((b, sk, KV, D), jnp.bfloat16, chip),
+    )
+
+
+def test_flash_forward_compiles_at_smoke_widths(chip) -> None:
+    from torchft_tpu.ops.flash_attention import flash_attention
+
+    _compile(lambda q, k, v: flash_attention(q, k, v, interpret=False), *_qkv(chip))
+
+
+def test_flash_fused_backward_compiles_at_smoke_widths(chip) -> None:
+    from torchft_tpu.ops.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, interpret=False, use_pallas_bwd=True)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), *_qkv(chip))
+
+
+def test_flash_partial_pair_compiles_at_ring_hop(chip) -> None:
+    """The ring building blocks: one hop's partial forward and its backward
+    from the merged logsumexp, with explicit position arrays."""
+    from torchft_tpu.ops.flash_attention import (
+        flash_attention_partial,
+        flash_attention_partial_bwd,
+    )
+
+    q, k, v = _qkv(chip, b=RING_B, sq=RING_HOP, sk=RING_HOP)
+    pos = _sds((RING_B, RING_HOP), jnp.int32, chip)
+    _compile(
+        lambda q, k, v, qp, kp: flash_attention_partial(
+            q, k, v, qp, kp, interpret=False
+        ),
+        q, k, v, pos, pos,
+    )
+    lse = _sds((RING_B, RING_HOP, H), jnp.float32, chip)
+    _compile(
+        lambda q, k, v, d_out, out, lse, qp, kp: flash_attention_partial_bwd(
+            q, k, v, d_out, out, lse, qp, kp, D**-0.5, 512, 1024, False
+        ),
+        q, k, v, q, q, lse, pos, pos,
+    )
+
+
+@pytest.mark.parametrize("wire", ["fp8", "int8"])
+def test_codec_pair_compiles_at_fragment_size(chip, wire) -> None:
+    from torchft_tpu.ops import quantization as q
+
+    blocks = _sds((FRAGMENT_BLOCKS, q.BLOCK), jnp.float32, chip)
+    _compile(lambda x: q.quantize_blocks_pallas(x, wire=wire), blocks)
+    payload, scales = jax.eval_shape(
+        lambda x: q.quantize_blocks_pallas(x, wire=wire), blocks
+    )
+    _compile(
+        q.dequantize_blocks_pallas,
+        _sds(payload.shape, payload.dtype, chip),
+        _sds(scales.shape, scales.dtype, chip),
+    )
+
+
+@pytest.mark.slow  # 10-15 s: the tier-1 gate (-m 'not slow') is near its limit
+def test_plain_step_compiles_at_smoke_config(chip, monkeypatch) -> None:
+    """The whole plain SGD-momentum step chip_smoke.py runs, with its state
+    inside one chip's HBM. The model asks ``on_tpu()`` and would see the CPU
+    here: the test steers it, the program grows no option for it."""
+    import optax
+
+    import chip_smoke
+    import torchft_tpu.models.llama as llama
+    import torchft_tpu.ops.flash_attention as flash
+
+    for module in (llama, flash):
+        monkeypatch.setattr(module, "on_tpu", lambda: True)
+    config, batch, seq = chip_smoke.smoke_config(rehearse=False)
+    assert replace(config, n_layers=16, max_seq_len=8192) == replace(
+        llama.CONFIGS["1b"], attention_impl="flash", scan_layers=True,
+        remat="dots", loss_vocab_chunk=4096,
+    ), "chip_smoke cut a width of CONFIGS['1b']"
+    model = llama.Llama(config)
+    tx = optax.sgd(0.01, momentum=0.9)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((batch, seq), jnp.int32))
+    )
+    on_chip = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: _sds(a.shape, a.dtype, chip), tree
+    )
+    compiled = (
+        chip_smoke.make_plain_step(tx, chip_smoke.make_loss_fn(model))
+        .lower(
+            on_chip(params),
+            on_chip(jax.eval_shape(tx.init, params)),
+            _sds((batch, seq + 1), jnp.int32, chip),
+        )
+        .compile()
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    total = (
+        mem.argument_size_in_bytes + mem.output_size_in_bytes
+        + mem.temp_size_in_bytes - mem.alias_size_in_bytes
+    )
+    # The described-topology compile does not refuse an oversized program
+    # by itself: hold the bytes against the v5e's 15.75 GiB here.
+    assert total < 15.75 * 2**30, f"plain step needs {total / 2**30:.2f} GiB"
+
+
+def test_sharded_step_with_size_one_mesh_axis_compiles(v5e, monkeypatch) -> None:
+    """The layout of chip_smoke.py's two-chip replica groups: an fsdp=2 x
+    tp=1 mesh. Mosaic refuses to lower while ANY mesh axis is left
+    automatic, a size-1 one included — the flash dispatcher must take them
+    all. (This failed on the chip before it did in any CPU test: interpret
+    mode has no such rule. A tiny model shows it as well as a large one.)"""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import torchft_tpu.models.llama as llama
+    import torchft_tpu.ops.flash_attention as flash
+
+    for module in (llama, flash):
+        monkeypatch.setattr(module, "on_tpu", lambda: True)
+    mesh = Mesh(np.array(v5e[:2]).reshape(2, 1), ("fsdp", "tp"))
+    config = replace(
+        llama.CONFIGS["tiny"], attention_impl="flash", dtype=jnp.bfloat16,
+        n_layers=1, max_seq_len=128,
+    )
+    model = llama.Llama(config)
+    batch, seq = 4, 128
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((batch, seq), jnp.int32))
+    )
+    shardings = llama.plan_shardings(params, mesh, llama.sharding_plan("fsdp", "tp"))
+    params = jax.tree_util.tree_map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        params, shardings,
+    )
+    tokens = jax.ShapeDtypeStruct(
+        (batch, seq), jnp.int32, sharding=NamedSharding(mesh, P("fsdp", None))
+    )
+
+    def loss_fn(p, t):
+        return jnp.sum(model.apply(p, t) ** 2)
+
+    with jax.set_mesh(mesh):
+        compiled = jax.jit(jax.value_and_grad(loss_fn)).lower(params, tokens).compile()
+    assert "tpu_custom_call" in compiled.as_text()

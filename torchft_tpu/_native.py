@@ -9,6 +9,8 @@ framed RPC protocol directly from Python (see torchft_tpu/coordination.py).
 from __future__ import annotations
 
 import ctypes
+import fcntl
+import hashlib
 import os
 import shutil
 import subprocess
@@ -19,6 +21,7 @@ from typing import Tuple
 _REPO_ROOT = Path(__file__).resolve().parent.parent
 _NATIVE_DIR = _REPO_ROOT / "native"
 _BUILD_DIR = _NATIVE_DIR / "build"
+_STAMP = _BUILD_DIR / "libtpuft.so.digest"
 
 _lib = None
 _lib_lock = threading.Lock()
@@ -36,9 +39,9 @@ class NativeToolchainMissing(RuntimeError):
 def toolchain_state() -> Tuple[bool, str]:
     """(available, detail): whether the native plane can be loaded or built.
 
-    Available means a prebuilt libtpuft.so exists at any candidate path, or
-    both cmake and ninja are on PATH to build one."""
-    for path in _candidate_paths():
+    Available means a packaged libtpuft.so exists, or both cmake and ninja
+    are on PATH to build one from ``native/``."""
+    for path in _packaged_paths():
         if path.exists():
             return True, f"prebuilt libtpuft.so at {path}"
     missing = [tool for tool in ("cmake", "ninja") if shutil.which(tool) is None]
@@ -47,7 +50,7 @@ def toolchain_state() -> Tuple[bool, str]:
             f"no prebuilt libtpuft.so and {'/'.join(missing)} not on PATH "
             "(native plane unbuildable)"
         )
-    return True, "no prebuilt libtpuft.so; cmake+ninja available to build"
+    return True, "cmake+ninja available to build libtpuft.so from native/"
 
 
 def has_sim_hooks() -> bool:
@@ -57,43 +60,89 @@ def has_sim_hooks() -> bool:
     return _has_sim_hooks
 
 
-def _candidate_paths() -> list[Path]:
+def _packaged_paths() -> list[Path]:
+    """Libraries someone else built and vouches for: the operator's
+    ``$TPUFT_NATIVE_LIB`` and one shipped inside the package. The dev
+    build under ``native/build`` is not in this list — it is only loaded
+    when its stamp matches today's sources (:func:`ensure_built`)."""
     paths = []
     env = os.environ.get("TPUFT_NATIVE_LIB")
     if env:
         paths.append(Path(env))
     paths.append(Path(__file__).resolve().parent / "libtpuft.so")
-    paths.append(_BUILD_DIR / "libtpuft.so")
     return paths
+
+
+def source_digest() -> str:
+    """sha256 over every file the library is built from — the files git
+    would commit: ``native/CMakeLists.txt``, ``native/proto/*``,
+    ``native/src/*`` (relative names and bytes, in sorted order)."""
+    h = hashlib.sha256()
+    files = [_NATIVE_DIR / "CMakeLists.txt"]
+    for sub in ("proto", "src"):
+        files += sorted(f for f in (_NATIVE_DIR / sub).iterdir() if f.is_file())
+    for f in files:
+        h.update(str(f.relative_to(_NATIVE_DIR)).encode() + b"\0")
+        h.update(f.read_bytes())
+    return h.hexdigest()
+
+
+def _stamp_matches(digest: str) -> bool:
+    try:
+        return (_BUILD_DIR / "libtpuft.so").exists() and _STAMP.read_text() == digest
+    except OSError:
+        return False
 
 
 def ensure_built() -> Path:
     """Returns the path to libtpuft.so, building it if necessary.
 
+    The dev build carries a stamp of :func:`source_digest`; a library whose
+    stamp is missing or differs was built from other sources (an earlier
+    session, an edited ``native/src``) and is rebuilt from scratch rather
+    than loaded — ``native/build`` is git-ignored, so whatever sits there
+    is otherwise what would run.
+
     Raises :class:`NativeToolchainMissing` (not FileNotFoundError from a
     doomed subprocess) when there is nothing to load and no toolchain to
     build with — callers and the test suite key on that type."""
-    for path in _candidate_paths():
+    for path in _packaged_paths():
         if path.exists():
             return path
+    lib_path = _BUILD_DIR / "libtpuft.so"
+    digest = source_digest()
+    if _stamp_matches(digest):
+        return lib_path
     available, detail = toolchain_state()
     if not available:
         raise NativeToolchainMissing(detail)
-    # Build from source (dev / CI path).
-    _BUILD_DIR.mkdir(parents=True, exist_ok=True)
-    if not (_BUILD_DIR / "build.ninja").exists():
-        subprocess.run(
+    # Concurrent first loads (multi-process tests, launcher children) must
+    # not build over each other: serialize on the source directory itself.
+    lock_fd = os.open(_NATIVE_DIR, os.O_RDONLY)
+    try:
+        fcntl.flock(lock_fd, fcntl.LOCK_EX)
+        if _stamp_matches(digest):  # another process built it meanwhile
+            return lib_path
+        # From scratch: ninja decides by mtime, and a copied or checked-out
+        # tree can hold an edited source that is older than its object.
+        shutil.rmtree(_BUILD_DIR, ignore_errors=True)
+        _BUILD_DIR.mkdir(parents=True)
+        for argv in (
             ["cmake", "-B", str(_BUILD_DIR), "-G", "Ninja", str(_NATIVE_DIR)],
-            check=True,
-            capture_output=True,
-        )
-    subprocess.run(
-        ["ninja", "-C", str(_BUILD_DIR), "tpuft"], check=True, capture_output=True
-    )
-    lib_path = _BUILD_DIR / "libtpuft.so"
-    if not lib_path.exists():
-        raise RuntimeError(f"native build succeeded but {lib_path} is missing")
-    return lib_path
+            ["ninja", "-C", str(_BUILD_DIR), "tpuft"],
+        ):
+            proc = subprocess.run(argv, capture_output=True, text=True)
+            if proc.returncode != 0:
+                raise RuntimeError(
+                    f"native build failed ({' '.join(argv)}):\n"
+                    f"{proc.stdout[-2000:]}\n{proc.stderr[-2000:]}"
+                )
+        if not lib_path.exists():
+            raise RuntimeError(f"native build succeeded but {lib_path} is missing")
+        _STAMP.write_text(digest)
+        return lib_path
+    finally:
+        os.close(lock_fd)
 
 
 def load() -> ctypes.CDLL:

@@ -10,7 +10,10 @@ that relaunches dead replica groups).
 
 Each replica group becomes a supervised subprocess with:
   REPLICA_GROUP_ID, NUM_REPLICA_GROUPS, TPUFT_LIGHTHOUSE
-plus any TPUFT_* timeouts passed through. Dead groups are relaunched with
+plus any TPUFT_* timeouts passed through, and — on a host with TPU chips —
+its own disjoint set of them (:func:`chip_envs`): a chip belongs to one
+process at a time, so the launcher itself never initializes a JAX backend.
+Dead groups are relaunched with
 **exponential backoff**: the delay doubles per recent rapid death (deaths
 within ``_backoff_window`` seconds of each other — a genuinely
 crash-looping group, not chaos kills minutes apart), capped at
@@ -25,6 +28,7 @@ unrelated faults spread across days).
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import signal
 import subprocess
@@ -33,8 +37,90 @@ import time
 from typing import Dict, List, Optional
 
 from torchft_tpu.coordination import LighthouseServer
+from torchft_tpu.utils.platform import cpu_by_name
 
-__all__ = ["supervise", "main", "relaunch_delay", "prune_restart_window"]
+__all__ = [
+    "supervise",
+    "main",
+    "relaunch_delay",
+    "prune_restart_window",
+    "local_chip_count",
+    "chip_envs",
+]
+
+# libtpu's process-bounds shape for an isolated process that owns N of a
+# host's chips. Only what ran is listed (a v5e 2x2 host under libtpu 0.0.34:
+# four one-chip and two two-chip processes side by side); any other share is
+# refused, not guessed. A whole host needs no restriction at all.
+_CHIP_BOUNDS = {1: "1,1,1", 2: "1,2,1"}
+_GOOGLE_PCI_VENDOR = "0x1ae0"
+
+
+def local_chip_count() -> int:
+    """TPU chips this host lets us open, counted without a backend init.
+
+    On v5e and newer a chip is a VFIO group file ``/dev/vfio/<n>``; any
+    other device bound to vfio-pci looks the same there, so those files
+    count only as far as sysfs lists Google PCI devices (sysfs alone
+    over-counts: it lists the host's devices even where a sandbox passes
+    only some of them through). Before v5e a chip is ``/dev/accel<n>``."""
+    tpu_pci = 0
+    for vendor in glob.glob("/sys/bus/pci/devices/*/vendor"):
+        try:
+            with open(vendor) as f:
+                tpu_pci += f.read().strip() == _GOOGLE_PCI_VENDOR
+        except OSError:
+            pass
+    vfio = len(glob.glob("/dev/vfio/[0-9]*"))
+    return min(vfio, tpu_pci) + len(glob.glob("/dev/accel[0-9]*"))
+
+
+def chip_envs(
+    num_processes: int, env: Optional[Dict[str, str]] = None
+) -> List[Dict[str, str]]:
+    """One environment overlay per process, giving each its own equal,
+    disjoint share of this host's chips (``TPU_VISIBLE_CHIPS`` plus the
+    process-bounds pair libtpu wants with it). Every process is an
+    ISOLATED slice (``TPU_PROCESS_BOUNDS=1,1,1``) that numbers its devices
+    from 0: right for replica groups, which meet over host networking.
+
+    Empty overlays — nothing to assign — where the caller asked for the
+    CPU by name (:func:`~torchft_tpu.utils.platform.cpu_by_name` on
+    ``env``, default this process's environment), where the host has no
+    chips, or where one process owns the whole host. Raises when there are
+    fewer chips than processes (two processes cannot share a chip, and the
+    second would fail or hang inside TPU init rather than say so), and
+    when the share is one nobody has run."""
+    chips = local_chip_count()
+    if cpu_by_name(env) or chips == 0 or num_processes == 1:
+        return [{} for _ in range(num_processes)]
+    per = chips // num_processes
+    if per == 0:
+        raise RuntimeError(
+            f"{num_processes} processes cannot each own chips on a host "
+            f"with {chips}: a chip belongs to one process at a time. Start "
+            "fewer replica groups, or run on the CPU by name "
+            "(JAX_PLATFORMS=cpu, with "
+            "XLA_FLAGS=--xla_force_host_platform_device_count=N for a mesh)."
+        )
+    if per not in _CHIP_BOUNDS:
+        raise RuntimeError(
+            f"chip share {per} not verified for this host shape ({chips} "
+            f"chips over {num_processes} processes): only shares of "
+            f"{sorted(_CHIP_BOUNDS)} chips have run (v5e 2x2 host). Start "
+            f"{chips // max(_CHIP_BOUNDS)} or more processes, or add the "
+            "share to launch._CHIP_BOUNDS once it has run on such a host."
+        )
+    return [
+        {
+            "TPU_VISIBLE_CHIPS": ",".join(
+                str(c) for c in range(i * per, (i + 1) * per)
+            ),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": _CHIP_BOUNDS[per],
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+        }
+        for i in range(num_processes)
+    ]
 
 
 def relaunch_delay(
@@ -93,6 +179,22 @@ def supervise(
             "--jax-coordinator-port-base requires --group-world-size > 1 "
             "(a one-process group has nothing to cluster)"
         )
+    chips = chip_envs(
+        num_replica_groups * group_world_size,
+        {**os.environ, **(extra_env or {})},
+    )
+    if jax_coordinator_port_base and any(chips):
+        # chip_envs makes every process an isolated slice; the ranks of a
+        # clustered group must instead form ONE slice over their chips
+        # (real process bounds, addresses and task ids), which nobody has
+        # run on a chip yet.
+        raise RuntimeError(
+            "--jax-coordinator-port-base on a host with TPU chips: forming "
+            "one JAX cluster from several local processes over their own "
+            "chips is not verified. Give the group one process that owns "
+            "its chips (--group-world-size 1), or run on the CPU by name "
+            "(JAX_PLATFORMS=cpu)."
+        )
     own_lighthouse: Optional[LighthouseServer] = None
     if lighthouse_addr is None:
         own_lighthouse = LighthouseServer(
@@ -111,6 +213,7 @@ def supervise(
         for rank in range(group_world_size):
             env = {
                 **os.environ,
+                **chips[group * group_world_size + rank],
                 **(extra_env or {}),
                 "REPLICA_GROUP_ID": str(group),
                 "NUM_REPLICA_GROUPS": str(num_replica_groups),

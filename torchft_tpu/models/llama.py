@@ -71,9 +71,9 @@ class LlamaConfig:
     sp_axis: str = "sp"
     attention_block_size: int = 512
     # KV-block length for the flash path only (the kernel's sequential
-    # accumulation axis). The on-chip sweep (scripts/flash_block_sweep.py,
-    # TPU v5 lite) puts the knee at 512x1024: vs 512x512 the s=8192
-    # fwd+bwd drops 47.2 -> 37.9 ms. None = attention_block_size.
+    # accumulation axis). An earlier on-chip sweep
+    # (scripts/flash_block_sweep.py) put the knee at 512x1024; not
+    # re-measured on today's machine. None = attention_block_size.
     attention_block_k: Optional[int] = 1024
     # Mosaic kernels cannot be auto-partitioned by XLA SPMD: under a
     # jit-with-mesh (fsdp/tp/dp sharded train step) the flash path must
@@ -158,16 +158,13 @@ def large_bench_config(**overrides) -> LlamaConfig:
     used to be copied verbatim into all four files, and a retune in one
     silently drifted the other three.
 
-    The choices are on-chip measurements (TPU v5 lite, 2026-07-31):
+    The choices (their speed on today's v5e: not measured):
 
     - head geometry 8x128, not 16x64: identical params and FLOPs at
-      dim 1024, but the 64-wide heads starve the 128-lane MXU —
-      measured 306 -> 214 ms/step (1.43x) at batch 4 x seq 2048, i.e.
-      43.5% -> 63.1% MFU under the bench's 6N + 12*L*d*s accounting
-      (BENCH_TPU_LARGE.json).
-    - remat="dots" + batch 4: the 15.75 GB HBM budget, sized by
-      chipless AOT compiles (scripts/hbm_probe.py) — batch 8 without
-      remat needs ~29 GB.
+      dim 1024, but 64-wide heads half-fill the 128-lane MXU.
+    - remat="dots" + batch 4: the 15.75 GiB HBM budget, sized by
+      chipless compiles for a described v5e (scripts/hbm_probe.py) —
+      batch 8 without remat needs ~29 GB.
     - flash attention + scanned layers + fused CE: the long-sequence
       kernel path, O(1) HLO in depth, and no materialized logits.
 
@@ -292,17 +289,20 @@ def _flash_under_ambient_mesh(cfg: LlamaConfig, q, k, v, scale: float):
     parallel over (batch, head) in the non-SP case, so the wrapper maps
     batch over ``cfg.flash_batch_axes`` and heads over
     ``cfg.flash_tp_axis`` — the same layout ``sharding_plan`` gives the
-    QKV projections, so no resharding is introduced — and leaves any
-    other mesh axes automatic (``axis_names``: partial-manual). Axes
-    that are absent, size-1, or already manual (the model is inside a
-    caller's shard_map — shapes are already local and the kernel just
-    works) are excluded from the map; with none left the plain call is
-    used. A usable axis whose batch/head count doesn't divide STAYS
-    manual but drops out of the specs — the kernel then computes
-    replicated over it, because a bare pallas_call under jit-with-mesh
-    is the exact lowering error this wrapper exists to avoid, dividing
-    or not. GQA inside each shard is preserved: h and kv_heads are
-    divided by the same tp factor, so the group ratio is unchanged.
+    QKV projections, so no resharding is introduced. The map takes EVERY
+    mesh axis that is not manual already: Mosaic refuses to lower while
+    any axis of the mesh is left automatic, a size-1 one included (an
+    fsdp=2 x tp=1 group failed on the chip exactly so). Axes already
+    manual (the model is inside a caller's shard_map — shapes are
+    already local and the kernel just works) are excluded; with none
+    left the plain call is used. An axis that is not one of the
+    configured ones, has size 1, or whose batch/head count doesn't
+    divide is manual but drops out of the specs — the kernel then
+    computes replicated over it, because a bare pallas_call under
+    jit-with-mesh is the exact lowering error this wrapper exists to
+    avoid, dividing or not. GQA inside each shard is preserved: h and
+    kv_heads are divided by the same tp factor, so the group ratio is
+    unchanged.
 
     The ambient mesh is read via ``jax.sharding.get_abstract_mesh`` —
     bind it with ``jax.set_mesh(mesh)`` (what the in-repo drills and
@@ -324,29 +324,20 @@ def _flash_under_ambient_mesh(cfg: LlamaConfig, q, k, v, scale: float):
         zip(getattr(mesh, "axis_names", ()), getattr(mesh, "axis_types", ()))
     )
 
+    # Already-manual axes (the model is inside a caller's shard_map) must
+    # not be wrapped again — shapes are already local there and a nested
+    # map over local shapes mis-divides them. Every other axis becomes
+    # manual, whatever its size.
+    manual = {a for a, t in axis_types.items() if t != AxisType.Manual}
+    if not manual:
+        return call(q, k, v)
+
     def usable(axis: Optional[str]) -> bool:
-        if axis is None or axis not in axis_types:
-            return False
-        if mesh.shape[axis] <= 1:
-            return False
-        # Already-manual axes (the model is inside a caller's shard_map)
-        # must not be wrapped again — shapes are already local there and
-        # a nested map over local shapes mis-divides them.
-        return axis_types[axis] != AxisType.Manual
+        return axis in manual and mesh.shape[axis] > 1
 
     b, _, h, _ = q.shape
     kv_heads = k.shape[2]
-    # Every usable axis becomes manual: even when a dim doesn't divide
-    # (so its spec entry drops to None and the compute replicates over
-    # that axis), the kernel must still run inside the manual context —
-    # a bare pallas_call under jit-with-mesh is the exact lowering error
-    # this wrapper exists to avoid, dividing or not.
-    manual = {a for a in cfg.flash_batch_axes if usable(a)}
-    if usable(cfg.flash_tp_axis):
-        manual.add(cfg.flash_tp_axis)
-    if not manual:
-        return call(q, k, v)
-    usable_batch = tuple(a for a in cfg.flash_batch_axes if a in manual)
+    usable_batch = tuple(a for a in cfg.flash_batch_axes if usable(a))
     # Non-dividing fallback is PER-AXIS, not all-or-nothing: keep the
     # largest dividing subset (by total shard count) of the usable batch
     # axes instead of replicating over every one of them the moment the
@@ -357,11 +348,11 @@ def _flash_under_ambient_mesh(cfg: LlamaConfig, q, k, v, scale: float):
     batch_axes = _largest_dividing_subset(
         usable_batch, {a: mesh.shape[a] for a in usable_batch}, b
     )
-    tp = cfg.flash_tp_axis if cfg.flash_tp_axis in manual else None
+    tp = cfg.flash_tp_axis if usable(cfg.flash_tp_axis) else None
     if tp is not None and (h % mesh.shape[tp] or kv_heads % mesh.shape[tp]):
         tp = None
     dropped = tuple(a for a in usable_batch if a not in batch_axes)
-    if cfg.flash_tp_axis in manual and tp is None:
+    if usable(cfg.flash_tp_axis) and tp is None:
         dropped += (cfg.flash_tp_axis,)
     if dropped:
         _warn_flash_replicated(dropped, batch_axes, tp, (b, h, kv_heads), mesh)

@@ -252,9 +252,9 @@ def unpack_arrays(
 # are VMEM (1024 x 256 f32 = 1 MB/tile, double-buffered — well inside the
 # ~16 MB budget) and Mosaic tiling (1024 is a multiple of the 8-bit
 # payload's 32-row tile; a smaller n_blocks rides whole-dim via min()).
-# The original 8-row tiles made a 256 MB codec run a 32k-step grid whose
-# per-step overhead capped it at ~12 GB/s on a v5e (KERNEL_BENCH_TPU first
-# capture); 1024-row tiles measure ~19 GB/s, above the fused XLA path.
+# The original 8-row tiles made a 256 MB codec run a 32k-step grid of
+# per-step overhead; 1024-row tiles cut the grid 128x. Their speed on
+# today's v5e: not measured (scripts/codec_block_sweep.py is the sweep).
 _ROWS_PER_TILE = 1024
 
 

@@ -191,7 +191,10 @@ def _blockwise_core_bwd(scale: float, block_size: int, residuals, d_out):
         dk_blk = jnp.einsum("bskgt,bskgd->btkd", ds, qg)
         return dq_acc, (dk_blk, dv_blk)
 
-    dq_init = jnp.zeros((b, s, kv_heads, group, d), dtype=jnp.float32)
+    # zeros_like, not a fresh constant: under a caller's shard_map (the
+    # flash dispatcher maps itself over fsdp/tp) the carry must be varying
+    # over the same manual axes as q, or the scan's carry types mismatch.
+    dq_init = jnp.zeros_like(qg)
     dq, (dk_blocks, dv_blocks) = jax.lax.scan(
         scan_step, dq_init, (k_blocks, v_blocks, kp_blocks)
     )
@@ -253,11 +256,10 @@ def ring_attention(
     # The constant-initialized carries must be marked varying over the ring
     # axis or the fori_loop carry types mismatch under shard_map's
     # varying-manual-axes checking.
-    if hasattr(jax.lax, "pcast"):
-        acc, row_max, row_sum = (
-            jax.lax.pcast(x, (axis_name,), to="varying")
-            for x in (acc, row_max, row_sum)
-        )
+    acc, row_max, row_sum = (
+        jax.lax.pcast(x, (axis_name,), to="varying")
+        for x in (acc, row_max, row_sum)
+    )
 
     if s_local % kv_sub_blocks != 0:
         raise ValueError(
@@ -355,10 +357,9 @@ def _ring_flash_fwd_impl(
     lse = jnp.full((b, s_local, h), _NEG_INF, jnp.float32)
     # Constant-initialized carries must be varying over the ring axis (see
     # ring_attention above).
-    if hasattr(jax.lax, "pcast"):
-        out, lse = (
-            jax.lax.pcast(x, (axis_name,), to="varying") for x in (out, lse)
-        )
+    out, lse = (
+        jax.lax.pcast(x, (axis_name,), to="varying") for x in (out, lse)
+    )
 
     def ring_step(_, carry):
         out, lse, k_blk, v_blk, kp = carry
@@ -421,10 +422,9 @@ def _ring_bwd_loop(axis_name, dq0, k, v, k_pos, per_hop):
     axis_size = jax.lax.psum(1, axis_name)
     dk0 = jnp.zeros(k.shape, jnp.float32)
     dv0 = jnp.zeros_like(dk0)
-    if hasattr(jax.lax, "pcast"):
-        dq0, dk0, dv0 = (
-            jax.lax.pcast(x, (axis_name,), to="varying") for x in (dq0, dk0, dv0)
-        )
+    dq0, dk0, dv0 = (
+        jax.lax.pcast(x, (axis_name,), to="varying") for x in (dq0, dk0, dv0)
+    )
 
     def ring_step(_, carry):
         dq, k_blk, v_blk, kp, dk_blk, dv_blk = carry
