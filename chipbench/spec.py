@@ -1,0 +1,256 @@
+"""BENCHMARK.json and the files it names: loading, discovery by name, checks.
+
+Everything that belongs to one configuration, one traffic mix, one job or one
+per-layer metric is a file of its own, found by the name BENCHMARK.json gives
+it. A later PR adds a cell or a metric by adding files and entries, and edits
+no file that is here:
+
+    configuration  <dir>/configs/<config>.json
+    traffic mix    <dir>/traffic/<traffic>.json   (names its job)
+    job            <dir>/jobs/<job>.py
+    layer metric   <dir>/layer_metrics/<name>.py  (a module with ``read(obs)``)
+    end-to-end     <dir>/end_to_end/<name>.py     (the same)
+
+``<dir>`` is any directory of BENCHMARK.json's ``paths``, searched in order,
+so a later PR may bring a directory of its own.
+"""
+
+from __future__ import annotations
+
+import importlib.util
+import json
+import re
+from pathlib import Path
+from types import ModuleType
+from typing import Any, Dict, List, Optional
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+TOP_KEYS = {
+    "command", "paths", "run_seconds", "configs", "workloads", "end_to_end",
+    "per_layer",
+}
+
+
+class SpecError(Exception):
+    """BENCHMARK.json, or a file it names, is missing or malformed."""
+
+
+class Benchmark:
+    """BENCHMARK.json of one checkout, and the files found by name under it."""
+
+    def __init__(self, root: Path) -> None:
+        self.root = Path(root)
+        path = self.root / "BENCHMARK.json"
+        if not path.is_file():
+            raise SpecError(f"no BENCHMARK.json in {self.root}")
+        self.data: Dict[str, Any] = json.loads(path.read_text())
+        self.dirs: List[Path] = [self.root / p for p in self.data.get("paths", [])]
+
+    # -- discovery -----------------------------------------------------------
+
+    def find(self, kind: str, filename: str) -> Path:
+        for d in self.dirs:
+            candidate = d / kind / filename
+            if candidate.is_file():
+                return candidate
+        raise SpecError(
+            f"no {kind}/{filename} under any of {[str(d) for d in self.dirs]}"
+        )
+
+    def cell(self, workload: str) -> Dict[str, Any]:
+        for cell in self.data["workloads"]:
+            if cell["name"] == workload:
+                return cell
+        raise SpecError(f"workload {workload!r} is not in BENCHMARK.json")
+
+    def config(self, name: str) -> Dict[str, Any]:
+        for entry in self.data["configs"]:
+            if entry["name"] == name:
+                return json.loads((self.root / entry["file"]).read_text())
+        raise SpecError(f"configuration {name!r} is not in BENCHMARK.json")
+
+    def traffic(self, name: str) -> Dict[str, Any]:
+        return json.loads(self.find("traffic", f"{name}.json").read_text())
+
+    def job(self, name: str) -> ModuleType:
+        return load_module(self.find("jobs", f"{name}.py"))
+
+    def reader_path(self, group: str, metric: str) -> Path:
+        kind = "end_to_end" if group == "end_to_end" else "layer_metrics"
+        return self.find(kind, f"{metric}.py")
+
+    def reader(self, group: str, metric: str) -> ModuleType:
+        """The module whose ``read(obs)`` gives an ``end_to_end`` or
+        ``per_layer`` metric."""
+        return load_module(self.reader_path(group, metric))
+
+    def metrics_of(self, workload: str, group: str) -> List[Dict[str, Any]]:
+        """The ``end_to_end`` or ``per_layer`` metrics this cell reports: all
+        that list it under ``workloads``, and all that have no such key."""
+        return [
+            m for m in self.data[group]
+            if "workloads" not in m or workload in m["workloads"]
+        ]
+
+
+def load_module(path: Path) -> ModuleType:
+    """Imports a file by path: metric names hold dots and dashes, which an
+    ``import`` statement could not spell."""
+    safe = re.sub(r"[^A-Za-z0-9_]", "_", f"chipbench_file_{path.parent.name}_{path.stem}")
+    spec = importlib.util.spec_from_file_location(safe, path)
+    if spec is None or spec.loader is None:
+        raise SpecError(f"cannot import {path}")
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+# -- checks ------------------------------------------------------------------
+
+
+def problems(bench: Benchmark) -> List[str]:
+    """Every way this BENCHMARK.json breaks its contract that can be seen
+    without running anything; empty when it is sound."""
+    data, out = bench.data, []
+
+    def bad(msg: str) -> None:
+        out.append(msg)
+
+    if set(data) != TOP_KEYS:
+        bad(f"top-level keys {sorted(data)} != {sorted(TOP_KEYS)}")
+        return out
+    if not (isinstance(data["run_seconds"], int) and 1 <= data["run_seconds"] <= 51):
+        bad("run_seconds must be a whole number from 1 to 51")
+    for p in data["paths"]:
+        if p.startswith("/") or ".." in Path(p).parts or not (bench.root / p).is_dir():
+            bad(f"path {p!r} is not a directory inside the checkout")
+    for word in data["command"]:
+        if word.startswith("/") or ".." in Path(word).parts:
+            bad(f"command word {word!r} leaves the checkout")
+        if "/" in word and not any(
+            Path(word).parts[: len(Path(p).parts)] == Path(p).parts for p in data["paths"]
+        ):
+            bad(f"command word {word!r} names a file outside paths")
+
+    def check_names(entries: List[Dict[str, Any]], what: str) -> None:
+        seen = set()
+        for e in entries:
+            if not NAME.match(e.get("name", "")):
+                bad(f"{what} name {e.get('name')!r} has a character outside the allowed set")
+            if e.get("name") in seen:
+                bad(f"{what} name {e.get('name')!r} appears twice")
+            seen.add(e.get("name"))
+
+    check_names(data["configs"], "configuration")
+    check_names(data["workloads"], "workload")
+    check_names(data["end_to_end"] + data["per_layer"], "metric")
+
+    config_names = {c["name"] for c in data["configs"]}
+    files = set()
+    for c in data["configs"]:
+        if set(c) != {"name", "source", "file", "reduced", "why"}:
+            bad(f"configuration {c['name']}: keys {sorted(c)}")
+        path = bench.root / c["file"]
+        if not path.is_file() or not any(d in path.parents for d in bench.dirs):
+            bad(f"configuration {c['name']}: file {c['file']} is not under paths")
+        if c["file"] in files:
+            bad(f"configuration file {c['file']} used twice")
+        files.add(c["file"])
+        for key in c["reduced"]:
+            if not NAME.match(key) or key.endswith(("_dim", "_rank")) or "size" in key:
+                bad(f"configuration {c['name']}: reduced key {key!r} is a width or misnamed")
+        if not any(w["config"] == c["name"] for w in data["workloads"]):
+            bad(f"configuration {c['name']} is used by no cell")
+
+    pairs = set()
+    for w in data["workloads"]:
+        if set(w) != {"name", "config", "traffic", "chips", "why"}:
+            bad(f"workload {w['name']}: keys {sorted(w)}")
+        if w["config"] not in config_names:
+            bad(f"workload {w['name']}: configuration {w['config']!r} is not defined")
+        if w["chips"] not in (1, 4):
+            bad(f"workload {w['name']}: chips {w['chips']}")
+        if not (1 <= len(w["why"]) <= 200) or "\n" in w["why"] or "\t" in w["why"]:
+            bad(f"workload {w['name']}: why must be one line of 1 to 200 characters")
+        if (w["config"], w["traffic"]) in pairs:
+            bad(f"pair ({w['config']}, {w['traffic']}) appears twice")
+        pairs.add((w["config"], w["traffic"]))
+        if not NAME.match(w["traffic"]):
+            bad(f"workload {w['name']}: traffic name {w['traffic']!r}")
+        try:
+            traffic = bench.traffic(w["traffic"])
+            bench.find("jobs", f"{traffic['job']}.py")
+        except (SpecError, KeyError) as e:
+            bad(f"workload {w['name']}: {e}")
+    four = sum(1 for w in data["workloads"] if w["chips"] == 4)
+    if four > max(1, len(data["workloads"]) // 4):
+        bad(f"{four} four-chip cells of {len(data['workloads'])}")
+
+    workload_names = [w["name"] for w in data["workloads"]]
+    e2e = {m["name"]: m for m in data["end_to_end"]}
+    if "setup_s" not in e2e:
+        bad("end_to_end has no setup_s")
+
+    def cells_of(m: Dict[str, Any]) -> List[str]:
+        return m.get("workloads", workload_names)
+
+    for m in data["end_to_end"]:
+        if set(m) - {"workloads"} != {"name", "unit", "better", "bound", "source"}:
+            bad(f"metric {m['name']}: keys {sorted(m)}")
+        if m.get("source") not in ("host_clock", "device_trace"):
+            bad(f"end-to-end metric {m['name']}: source {m.get('source')!r}")
+        if not (isinstance(m.get("bound"), (int, float)) and 0 < m["bound"] <= 0.1):
+            bad(f"metric {m['name']}: bound {m.get('bound')!r}")
+    for m in data["per_layer"]:
+        if set(m) - {"workloads"} != {"name", "unit", "better", "source", "layer", "moves"}:
+            bad(f"metric {m['name']}: keys {sorted(m)}")
+        if m.get("source") not in SOURCES:
+            bad(f"metric {m['name']}: source {m.get('source')!r}")
+        moved = e2e.get(m.get("moves"))
+        if moved is None:
+            bad(f"metric {m['name']}: moves {m.get('moves')!r}, which is no end-to-end metric")
+        else:
+            missing = [c for c in cells_of(m) if c not in cells_of(moved)]
+            if missing:
+                bad(f"metric {m['name']} moves {moved['name']}, which {missing} do not report")
+    for group in ("end_to_end", "per_layer"):
+        for m in data[group]:
+            try:
+                bench.reader_path(group, m["name"])
+            except SpecError as e:
+                bad(str(e))
+    for m in data["end_to_end"] + data["per_layer"]:
+        if not UNIT.match(m.get("unit", "")):
+            bad(f"metric {m['name']}: unit {m.get('unit')!r}")
+        if m.get("better") not in ("lower", "higher"):
+            bad(f"metric {m['name']}: better {m.get('better')!r}")
+        for c in m.get("workloads", []):
+            if c not in workload_names:
+                bad(f"metric {m['name']} lists unknown workload {c!r}")
+    for w in workload_names:
+        mine = [m["name"] for m in bench.metrics_of(w, "end_to_end")]
+        if "setup_s" not in mine or len(mine) < 2:
+            bad(f"workload {w} reports end-to-end metrics {mine}")
+        if not bench.metrics_of(w, "per_layer"):
+            bad(f"workload {w} reports no per-layer metric")
+    return out
+
+
+def result_line(
+    correct: bool, attempted: int, failed: int,
+    metrics: Dict[str, Dict[str, Any]], device: Dict[str, Any],
+    breakdown: Optional[Dict[str, Any]] = None, rehearsal: bool = False,
+) -> str:
+    """The one JSON object a run prints last: exactly the contract's keys
+    (a rehearsal's line says so, and is never a chip result)."""
+    line: Dict[str, Any] = {
+        "correct": bool(correct), "attempted": int(attempted), "failed": int(failed),
+        "metrics": metrics, "device": device,
+    }
+    if breakdown is not None:
+        line["breakdown"] = breakdown
+    if rehearsal:
+        line["rehearsal"] = True
+    return json.dumps(line)
