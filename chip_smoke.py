@@ -1022,6 +1022,10 @@ def replica_axis(args, summary: dict) -> None:
             f"  group {r['group']}: chips {r['chips']!r} ({r['n_devices']} devices), step {r['step']}, "
             f"attempt {r['attempt']}, healed {r['healed']}, digest {r['digest'][:16]}"
         )
+        say("    wire stages, seconds (syncs): " + ", ".join(
+            f"{stage} {stats['sum']:.2f} ({stats['count']})"
+            for stage, stats in r["wire_stage_seconds"].items()
+        ))
     equal = reports[0]["digest"] == reports[1]["digest"]
     same_step = reports[0]["step"] == reports[1]["step"]
     # On the CPU nothing is assigned (chips is None); on the chip the two
@@ -1039,6 +1043,7 @@ def replica_axis(args, summary: dict) -> None:
     summary["replica-axis"] = {
         "seconds": round(seconds, 2), "digests_equal": equal,
         "final_step": reports[0]["step"],
+        "wire_stage_seconds": [r["wire_stage_seconds"] for r in reports],
     }
 
 
@@ -1050,6 +1055,7 @@ def hsdp_worker(args) -> None:
     import numpy as np
     import optax
 
+    from torchft_tpu import metrics
     from torchft_tpu.bootstrap import init_manager
     from torchft_tpu.models.llama import Llama, apply_sharding_plan, sharding_plan
     from torchft_tpu.optim import Optimizer
@@ -1151,6 +1157,15 @@ def hsdp_worker(args) -> None:
                     # step 1 got there by healing, not by training.
                     "healed": bool(attempt and (first_commit_lands_at or 0) > 1),
                     "digest": digest.hexdigest(),
+                    # Host seconds of this process's replica-axis syncs by
+                    # stage (tpuft_wire_stage_seconds): the split of the
+                    # tens of seconds a cross-group sync takes.
+                    "wire_stage_seconds": {
+                        stage: metrics.histogram_stats(
+                            "tpuft_wire_stage_seconds", stage=stage
+                        )
+                        for stage in ("stage", "bucket", "ring", "average", "scatter")
+                    },
                 }
             )
         )

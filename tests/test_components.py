@@ -119,6 +119,28 @@ def test_context_timeout_triggers_callback() -> None:
     assert not fired2.is_set()
 
 
+def test_timeout_heap_gauge_shows_entries_that_outlive_their_future(monkeypatch) -> None:
+    """A finished future's timed wrapper only marks its handle cancelled:
+    the heap entry (and through its callback the result) stays until the
+    deadline. The gauge is the heap's length, set on schedule and on pop."""
+    from torchft_tpu import metrics
+
+    manager = ft_futures._TimeoutManager()
+    monkeypatch.setattr(ft_futures, "_TIMEOUT_MANAGER", manager)
+    gauge = lambda: metrics.gauge_value("tpuft_timeout_heap_entries")  # noqa: E731
+    done: Future = Future()
+    timed = ft_futures.future_timeout(done, 0.3)
+    slow = ft_futures.future_timeout(Future(), 30.0)
+    assert gauge() == 2
+    done.set_result("payload")
+    assert timed.result(timeout=1) == "payload"
+    assert gauge() == 2, "a finished future's entry is still in the heap"
+    deadline = time.monotonic() + 5
+    while gauge() != 1 and time.monotonic() < deadline:
+        time.sleep(0.02)
+    assert gauge() == 1 and not slow.done()  # popped at its deadline, not before
+
+
 def test_commit_pipeline_depth_bookkeeping() -> None:
     """CommitPipeline: depth-bounded admission, oldest-first ordering, and
     a drain that empties it — the bookkeeping the pipelined-commit

@@ -795,3 +795,46 @@ def test_pipelined_wire_path_two_participants():
         rtol=1e-5,
     )
     assert manager.current_step() == 3
+
+
+# -- the step's own spans (tracing.phase) ------------------------------------
+
+
+def _inside(child, parent) -> bool:
+    return (
+        parent["t_mono"] <= child["t_mono"]
+        and child["t_mono"] + child["dur"] <= parent["t_mono"] + parent["dur"] + 1e-9
+    )
+
+
+@pytest.mark.parametrize("depth", [0, 1])
+def test_lone_replica_step_leaves_its_span_tree_in_the_journal(depth):
+    """One FT-DDP step of a lone replica, strict and pipelined: a root
+    ``step`` span with the synchronous part of start_quorum, the update's
+    dispatch, the device sync, the wait for the verdict and the adoption
+    inside it on the train thread, all under the root's step."""
+    from torchft_tpu import tracing
+
+    journal = tracing.TraceJournal(maxlen=512)
+    with tracing.use_journal(journal):
+        manager = scripted_manager(commit_pipeline_depth=depth)
+        opt = Optimizer(manager, optax.sgd(0.1), {"w": jnp.ones(3, jnp.float32)})
+        step_fn = opt.make_step_fn(lambda p, b: jnp.sum((p["w"] - b) ** 2))
+        for i in range(3):
+            step_fn(jnp.full((3,), float(i), jnp.float32))
+        opt.flush_pipeline()
+    spans = [e for e in journal.snapshot() if e["ph"] == "X"]
+    roots = [e for e in spans if e["name"] == "step"]
+    assert [r["step"] for r in roots] == [0, 1, 2]
+    want = {"start_quorum", "update_dispatch", "device_sync", "commit_wait", "adopt"}
+    for root in roots[-2:]:  # the pipelined window resolves a step late
+        children = [
+            e for e in spans
+            if e["name"] in want and e["thread"] == root["thread"] and _inside(e, root)
+        ]
+        assert {c["name"] for c in children} == want, [c["name"] for c in children]
+        if depth == 0:
+            assert {c["step"] for c in children} == {root["step"]}
+    # The names the goodput ledger and the health scorer read are still there.
+    names = {e["name"] for e in journal.snapshot()}
+    assert {"quorum", "commit_barrier", "commit", "vote_send"} <= names

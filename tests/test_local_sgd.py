@@ -421,3 +421,91 @@ def test_local_sgd_make_step_fn_fused_matches_plain() -> None:
     np.testing.assert_array_equal(
         np.asarray(algo.params["w"]), np.asarray(expected["w"])
     )
+
+
+# -- the fragment sync's own spans (tracing.phase) ---------------------------
+
+
+def _inside(child, parent) -> bool:
+    return (
+        parent["t_mono"] <= child["t_mono"]
+        and child["t_mono"] + child["dur"] <= parent["t_mono"] + parent["dur"] + 1e-9
+    )
+
+
+@pytest.mark.parametrize("quantize", [False, True])
+def test_fragment_sync_leaves_its_stages_in_the_journal(quantize) -> None:
+    """A streaming DiLoCo round: every inner step is a root ``step`` span
+    numbered by the inner step; prepare_sync holds quantize and launch,
+    perform_sync holds wait, restore, commit and apply_outer, each stamped
+    with the fragment and the manager's step, and each a sample of
+    tpuft_outer_sync_seconds under its stage."""
+    from torchft_tpu import metrics, tracing
+
+    stages = ("quantize", "launch", "wait", "restore", "commit", "apply_outer")
+    before = {
+        s: metrics.histogram_stats("tpuft_outer_sync_seconds", stage=s)["count"]
+        for s in stages
+    }
+    journal = tracing.TraceJournal(maxlen=1024)
+    with tracing.use_journal(journal):
+        manager = scripted_manager(use_async_quorum=False)
+        algo = DiLoCo(
+            manager, optax.sgd(0.1), optax.sgd(0.7), make_params(), sync_every=4,
+            n_fragments=2, fragment_sync_delay=1, should_quantize=quantize,
+        )
+        committed = [algo.step(fixed_grads(i)) for i in range(4)]
+    assert committed == [False, True, False, True]
+    spans = [e for e in journal.snapshot() if e["ph"] == "X"]
+    roots = [e for e in spans if e["name"] == "step"]
+    assert [r["args"]["inner_step"] for r in roots] == [0, 1, 2, 3]
+    by_name = lambda n: [e for e in spans if e["name"] == n]  # noqa: E731
+    for fragment, (launch_root, sync_root) in enumerate([(0, 1), (2, 3)]):
+        (prepare,) = [e for e in by_name("prepare_sync") if e["args"]["fragment"] == fragment]
+        (perform,) = [e for e in by_name("perform_sync") if e["args"]["fragment"] == fragment]
+        assert _inside(prepare, roots[launch_root]) and _inside(perform, roots[sync_root])
+        assert prepare["step"] == perform["step"] == fragment  # the step it commits
+        for parent, children in (
+            (prepare, ("sync_quantize", "sync_launch")),
+            (perform, ("sync_wait", "sync_restore", "sync_commit", "sync_apply_outer")),
+        ):
+            starts = []
+            for name in children:
+                (child,) = [e for e in by_name(name) if e["args"]["fragment"] == fragment]
+                assert _inside(child, parent) and child["step"] == parent["step"]
+                starts.append(child["t_mono"])
+            assert starts == sorted(starts), "stages out of order"
+        # The manager's own span sits inside the stage that calls it.
+        (commit,) = [e for e in by_name("sync_commit") if e["args"]["fragment"] == fragment]
+        assert any(_inside(b, commit) for b in by_name("commit_barrier"))
+    for s in stages:
+        after = metrics.histogram_stats("tpuft_outer_sync_seconds", stage=s)["count"]
+        assert after - before[s] == 2, s
+
+
+def test_failed_sync_still_closes_its_spans() -> None:
+    from torchft_tpu import tracing
+
+    journal = tracing.TraceJournal(maxlen=256)
+    with tracing.use_journal(journal):
+        manager = scripted_manager(use_async_quorum=False)
+        manager._client.should_commit.side_effect = None
+        manager._client.should_commit.return_value = False
+        algo = DiLoCo(manager, optax.sgd(0.1), optax.sgd(0.7), make_params(), sync_every=1)
+        assert not algo.step(fixed_grads(0))
+    names = [e["name"] for e in journal.snapshot() if e["ph"] == "X"]
+    assert "sync_commit" in names and "perform_sync" in names and "step" in names
+    assert "sync_apply_outer" not in names  # refused: no outer step
+
+
+def test_local_sgd_sync_is_one_perform_sync_span() -> None:
+    from torchft_tpu import tracing
+
+    journal = tracing.TraceJournal(maxlen=256)
+    with tracing.use_journal(journal):
+        manager = scripted_manager()
+        algo = LocalSGD(manager, optax.sgd(0.1), make_params(), sync_every=2)
+        assert [algo.step(fixed_grads(i)) for i in range(2)] == [False, True]
+    spans = [e for e in journal.snapshot() if e["ph"] == "X"]
+    (sync,) = [e for e in spans if e["name"] == "perform_sync"]
+    assert any(_inside(e, sync) for e in spans if e["name"] == "commit_barrier")

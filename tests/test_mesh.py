@@ -77,6 +77,56 @@ def test_ft_allreduce_sharded_preserves_sharding() -> None:
     ]
 
 
+WIRE_STAGES = {
+    "stage": "wire_stage", "bucket": "wire_concat", "ring": "wire_ring",
+    "average": "wire_average", "scatter": "wire_scatter",
+}
+
+
+def wire_stage_counts() -> dict:
+    from torchft_tpu import metrics
+
+    return {
+        stage: metrics.histogram_stats("tpuft_wire_stage_seconds", stage=stage)["count"]
+        for stage in WIRE_STAGES
+    }
+
+
+def test_ft_allreduce_sharded_records_the_five_wire_stages() -> None:
+    """One sharded sync: device to host, concatenation, the ring (recorded
+    where its future resolves, with the launch's start stamp), the average
+    and the scatter, each a journal span of the sync's step and a sample of
+    tpuft_wire_stage_seconds under its stage."""
+    from torchft_tpu import tracing
+
+    journal = tracing.TraceJournal(maxlen=256)
+    before = wire_stage_counts()
+    with tracing.use_journal(journal):
+        manager = scripted_manager(world=2)
+        ft_mesh = ft_init_device_mesh(
+            manager, mesh_shape=(4,), axis_names=("fsdp",), devices=jax.devices()[:4]
+        )
+        x = jax.device_put(
+            jnp.arange(16, dtype=jnp.float32).reshape(8, 2), ft_mesh.sharding("fsdp")
+        )
+        out = ft_allreduce_sharded(manager, {"w": x, "b": jnp.ones(3, jnp.float32)})
+    np.testing.assert_allclose(np.asarray(out["w"]), np.asarray(x) / 2.0)
+    spans = {e["name"]: e for e in journal.snapshot() if e["ph"] == "X"}
+    for stage, name in WIRE_STAGES.items():
+        assert name in spans, (name, sorted(spans))
+        assert spans[name]["step"] == manager.current_step()
+    # mesh.py stages the shards; allreduce_pytree then finds host arrays,
+    # buckets them, launches the ring, averages in the future's callback.
+    order = [spans[n]["t_mono"] for n in ("wire_concat", "wire_ring", "wire_average", "wire_scatter")]
+    assert order == sorted(order)
+    ring, average = spans["wire_ring"], spans["wire_average"]
+    assert ring["t_mono"] + ring["dur"] <= average["t_mono"] + 1e-9
+    after = wire_stage_counts()
+    assert {s: after[s] - before[s] for s in WIRE_STAGES} == {
+        "stage": 2, "bucket": 1, "ring": 1, "average": 1, "scatter": 1,
+    }
+
+
 def test_hsdp_two_groups_converge_bitwise() -> None:
     """2 replica groups (threads), each FSDP-sharding params over its own
     4-device sub-mesh; cross-group sync via ft_allreduce_sharded."""
@@ -141,6 +191,7 @@ def test_hsdp_two_groups_converge_bitwise() -> None:
             pg.shutdown()
             store.shutdown()
 
+    before = wire_stage_counts()
     try:
         with ThreadPoolExecutor(max_workers=2) as pool:
             results = list(pool.map(group_loop, range(2)))
@@ -148,3 +199,8 @@ def test_hsdp_two_groups_converge_bitwise() -> None:
             assert results[0][key].tobytes() == results[1][key].tobytes()
     finally:
         lighthouse.shutdown()
+    # Every step that crossed the groups went through the five stages, on a
+    # real ring (a step a group ran alone skips the wire).
+    grown = {s: n - before[s] for s, n in wire_stage_counts().items()}
+    assert all(grown[s] >= 2 for s in WIRE_STAGES), grown
+    assert grown["ring"] == grown["average"] == grown["scatter"] == grown["bucket"]

@@ -447,3 +447,284 @@ def test_rollback_stamps_shared_incident(tmp_path, monkeypatch) -> None:
         incidents[0]["args"]["incident"] in p.name
         for p in tmp_path.glob("tpuft_trace_*.jsonl")
     )
+
+
+# ---------------------------------------------------------------------------
+# the phase primitive: one recording site, three sinks
+# ---------------------------------------------------------------------------
+
+
+class _FakeAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: remembers what opened and
+    closed, and with which keyword arguments."""
+
+    log: list = []
+
+    def __init__(self, name, **kwargs):
+        self.name, self.kwargs = name, kwargs
+
+    def __enter__(self):
+        _FakeAnnotation.log.append(("enter", self.name, self.kwargs))
+        return self
+
+    def __exit__(self, *exc):
+        _FakeAnnotation.log.append(("exit", self.name, self.kwargs))
+
+
+@pytest.fixture
+def fake_annotations(monkeypatch):
+    _FakeAnnotation.log = []
+    monkeypatch.setattr(
+        tracing, "_annotation_types", (_FakeAnnotation, _FakeAnnotation)
+    )
+    return _FakeAnnotation.log
+
+
+def _stepping_clock(step: float = 0.25):
+    now = [100.0]
+
+    def mono() -> float:
+        now[0] += step
+        return now[0]
+
+    return mono
+
+
+@pytest.mark.parametrize("name", sorted(tracing.PHASES))
+def test_phase_feeds_the_sinks_its_table_row_names(name, fake_annotations) -> None:
+    """Every row of PHASES: the histogram, the journal event and the
+    annotation it names get ONE duration from two clock reads, and a sink
+    the row leaves out gets nothing."""
+    spec = tracing.PHASES[name]
+    journal = tracing.TraceJournal(maxlen=64, mono=_stepping_clock())
+    labels = {"replica_id": f"phase_test_{name}"}
+    before = (
+        metrics.histogram_stats(spec.histogram, **labels)
+        if spec.histogram else None
+    )
+    with tracing.phase(name, journal, labels, step=7, quorum_id=3, fragment=1, note="x"):
+        pass
+    events = journal.snapshot()
+    if spec.journal is None:
+        assert events == []
+    else:
+        (event,) = events
+        assert event["name"] == spec.journal and event["ph"] == "X"
+        assert (event["step"], event["quorum_id"]) == (7, 3)
+        assert event["args"] == {"fragment": 1, "note": "x"}
+        assert event["dur"] == pytest.approx(0.25)  # two reads of the clock
+    if spec.histogram is not None:
+        after = metrics.histogram_stats(spec.histogram, **labels)
+        assert after["count"] - before["count"] == 1
+        if spec.journal is not None:
+            assert after["sum"] - before["sum"] == pytest.approx(event["dur"])
+        if spec.stage is not None:
+            staged = metrics.histogram_stats(
+                spec.histogram, stage=spec.stage, **labels
+            )
+            assert staged["count"] >= 1
+    assert spec.annotation is not None
+    enter, leave = fake_annotations
+    assert enter[:2] == ("enter", spec.annotation) and leave[0] == "exit"
+    # The ids ride on the annotation; the free-form argument does not.
+    want = {"quorum_id": 3, "fragment": 1}
+    want["step_num" if spec.root else "step"] = 7
+    assert enter[2] == want
+
+
+def test_phase_with_the_journal_off_feeds_the_other_two(fake_annotations) -> None:
+    journal = tracing.TraceJournal(maxlen=16)
+    journal.set_enabled(False)
+    labels = {"replica_id": "phase_off"}
+    with tracing.phase("quorum", journal, labels, step=1):
+        pass
+    assert journal.snapshot() == []
+    assert metrics.histogram_stats("tpuft_quorum_seconds", **labels)["count"] == 1
+    assert [e[0] for e in fake_annotations] == ["enter", "exit"]
+    journal.set_enabled(True)
+    with tracing.phase("quorum", journal, labels, step=2):
+        pass
+    assert [e["step"] for e in journal.snapshot()] == [2]
+
+
+def test_phase_closes_every_sink_when_the_body_raises(fake_annotations) -> None:
+    journal = tracing.TraceJournal(maxlen=16)
+    labels = {"replica_id": "phase_raises"}
+    with pytest.raises(KeyError):
+        with tracing.phase("pg_configure", journal, labels, step=4):
+            raise KeyError("boom")
+    assert [e["name"] for e in journal.snapshot()] == ["pg_configure"]
+    assert metrics.histogram_stats("tpuft_pg_configure_seconds", **labels)["count"] == 1
+    assert [e[0] for e in fake_annotations] == ["enter", "exit"]
+
+
+def test_children_of_a_root_take_its_step(fake_annotations) -> None:
+    """The commit advances the journal's step on another thread while the
+    step still runs: what opens under the root on this thread keeps the
+    root's step, in the journal and on the annotation."""
+    journal = tracing.TraceJournal(maxlen=16)
+    with tracing.phase("optim_step", journal, step=11):
+        journal.set_step(12)  # the commit, elsewhere
+        with tracing.phase("device_sync", journal):
+            pass
+        with tracing.phase("adopt", journal, step=5):  # its own wins
+            pass
+    with tracing.phase("device_sync", journal):  # no root open: the journal's
+        pass
+    got = [(e["name"], e["step"]) for e in journal.snapshot()]
+    assert got == [("device_sync", 11), ("adopt", 5), ("step", 11), ("device_sync", 12)]
+    steps = [e[2].get("step") for e in fake_annotations if e[0] == "enter"]
+    assert steps == [None, 11, 5, None]  # the root carries step_num instead
+
+
+def test_record_phase_times_work_that_began_on_another_thread() -> None:
+    journal = tracing.TraceJournal(maxlen=16, mono=_stepping_clock(0.5))
+    labels = {"replica_id": "ring_test"}
+    start = journal._mono()
+    dur = tracing.record_phase("wire_ring", start, journal, labels, step=9)
+    assert dur == pytest.approx(0.5)
+    (event,) = journal.snapshot()
+    assert (event["name"], event["step"], event["t_mono"]) == ("wire_ring", 9, start)
+    stats = metrics.histogram_stats("tpuft_wire_stage_seconds", stage="ring", **labels)
+    assert stats["count"] == 1 and stats["sum"] == pytest.approx(0.5)
+
+
+def test_trace_span_rides_the_same_primitive(fake_annotations, tmp_path) -> None:
+    from torchft_tpu.utils.profiling import chrome_trace, trace_span
+
+    path = tmp_path / "t.json"
+    with chrome_trace(str(path)):
+        with trace_span("tpuft::test::heal", step=3, quorum_id=2, donor="a"):
+            pass
+        with tracing.phase("quorum", tracing.TraceJournal(maxlen=4), step=3):
+            pass
+    assert fake_annotations[0] == (
+        "enter", "tpuft::test::heal", {"step": 3, "quorum_id": 2}
+    )
+    spans = [e for e in json.loads(path.read_text())["traceEvents"] if e["ph"] == "X"]
+    assert [s["name"] for s in spans] == [
+        "tpuft::test::heal", "tpuft::manager::_client::_quorum",
+    ]
+    assert spans[0]["args"]["donor"] == "a" and spans[1]["args"]["step"] == 3
+
+
+# ---------------------------------------------------------------------------
+# the capture control
+# ---------------------------------------------------------------------------
+
+
+def test_capture_twice_returns_the_journals_own_events_and_growth(tmp_path) -> None:
+    journal = tracing.TraceJournal(maxlen=256)
+    labels = {"replica_id": "capture_test"}
+    journal.record("before_any_capture")
+    with tracing.phase("quorum", journal, labels, step=0):
+        pass
+    metrics.inc("tpuft_commits_total", **labels)
+    for round_ in range(2):  # any number of captures in one process
+        tracing.start_capture(str(tmp_path / f"c{round_}"), journal=journal)
+        with pytest.raises(RuntimeError, match="already running"):
+            tracing.start_capture(str(tmp_path / "nested"), journal=journal)
+        with tracing.phase("quorum", journal, labels, step=round_ + 1):
+            pass
+        with tracing.phase("sync_wait", journal, fragment=2):
+            pass
+        metrics.inc("tpuft_commits_total", 3, **labels)
+        seq_before_stop = journal._last_seq
+        got = tracing.stop_capture()
+        assert got["trace_dir"] == str(tmp_path / f"c{round_}")
+        assert list((tmp_path / f"c{round_}").glob("plugins/profile/*/*.xplane.pb"))
+        # Exactly the journal's events of the capture: no second store.
+        mine = [e for e in journal.snapshot() if e["seq"] <= seq_before_stop][-2:]
+        assert got["events"] == mine
+        assert [e["name"] for e in got["events"]] == ["quorum", "sync_wait"]
+        assert got["events"][0]["step"] == round_ + 1 and got["dropped"] == 0
+        # Growth only: one more quorum sample and three more commits, not
+        # the totals; what did not move is left out.
+        (quorum,) = [
+            c for c in got["counters"]["tpuft_quorum_seconds"] if c["labels"] == labels
+        ]
+        assert quorum["count"] == 1 and quorum["sum"] > 0
+        (commits,) = [
+            c for c in got["counters"]["tpuft_commits_total"] if c["labels"] == labels
+        ]
+        assert commits["value"] == 3
+        (wait,) = got["counters"]["tpuft_outer_sync_seconds"]
+        assert wait["labels"] == {"stage": "wait"} and wait["count"] == 1
+        assert "tpuft_pg_configure_seconds" not in got["counters"]
+        clock = got["clock"]
+        assert clock["begin_mono_ns"] < clock["end_mono_ns"] <= time.monotonic_ns()
+        json.dumps(got)  # plain data
+    with pytest.raises(RuntimeError, match="no capture"):
+        tracing.stop_capture()
+
+
+def test_capture_reports_what_the_ring_dropped(tmp_path) -> None:
+    journal = tracing.TraceJournal(maxlen=64)
+    tracing.start_capture(str(tmp_path / "c"), journal=journal)
+    for i in range(100):
+        journal.record("tick", i=i)
+    got = tracing.stop_capture()
+    assert len(got["events"]) == 64 and got["dropped"] == 36
+
+
+def test_xplane_holds_the_anchors_and_bare_phase_names(tmp_path) -> None:
+    """The trap of ISSUE 25: an annotation with keyword arguments must come
+    back under its bare name (the ids as the event's stats), or the
+    benchmark's idle-gap attribution would split by step."""
+    from jax.profiler import ProfileData
+
+    journal = tracing.TraceJournal(maxlen=64)
+    tracing.start_capture(str(tmp_path), journal=journal)
+    with tracing.phase("optim_step", journal, step=41):
+        with tracing.phase("should_commit", journal, step=41, quorum_id=6):
+            time.sleep(0.002)
+    with tracing.phase("sync_wait", journal, step=8, fragment=3):
+        pass
+    got = tracing.stop_capture()
+    (path,) = (tmp_path / "plugins" / "profile").glob("*/*.xplane.pb")
+    found = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for event in line.events:
+                if event.name.startswith("tpuft::"):
+                    found[event.name] = (dict(event.stats), event.start_ns, event.duration_ns)
+    assert set(found) == {
+        "tpuft::capture_begin", "tpuft::capture_end", "tpuft::optim::step",
+        "tpuft::manager::should_commit", "tpuft::local_sgd::wait",
+    }
+    assert found["tpuft::manager::should_commit"][0] == {"step": 41, "quorum_id": 6}
+    assert found["tpuft::optim::step"][0]["step_num"] == 41
+    assert found["tpuft::local_sgd::wait"][0] == {"step": 8, "fragment": 3}
+    # The two anchors carry the monotonic clock, so a journal instant lands
+    # on the profiler's clock: the commit span's journal start, mapped
+    # through the begin anchor, is the annotation's start to a millisecond.
+    begin_stats, begin_ns, _ = found["tpuft::capture_begin"]
+    end_stats, end_ns, _ = found["tpuft::capture_end"]
+    assert begin_stats["mono_ns"] == got["clock"]["begin_mono_ns"]
+    assert end_stats["mono_ns"] == got["clock"]["end_mono_ns"]
+    assert (end_ns - begin_ns) == pytest.approx(
+        end_stats["mono_ns"] - begin_stats["mono_ns"], abs=2e6
+    )
+    barrier = next(e for e in got["events"] if e["name"] == "commit_barrier")
+    mapped = begin_ns + (barrier["t_mono"] * 1e9 - begin_stats["mono_ns"])
+    assert mapped == pytest.approx(found["tpuft::manager::should_commit"][1], abs=2e6)
+    assert found["tpuft::manager::should_commit"][2] >= 2e6
+
+
+def test_compile_listener_journals_a_compile_at_the_current_step() -> None:
+    import jax
+    import jax.numpy as jnp
+
+    tracing.install_compile_listener()
+    tracing.install_compile_listener()  # once a process
+    journal = tracing.TraceJournal(maxlen=64)
+    journal.set_step(step=17)
+    x = jnp.arange(23, dtype=jnp.float32)
+    assert journal.snapshot() == []  # not this thread's journal yet
+    with tracing.use_journal(journal):
+        jax.jit(lambda x: x * 3 + len("a shape and a body no other test compiles"))(
+            x
+        ).block_until_ready()
+    compiles = [e for e in journal.snapshot() if e["name"] == "compile"]
+    assert compiles and all(e["step"] == 17 for e in compiles)
+    assert all(e["args"]["seconds"] > 0 for e in compiles)

@@ -800,8 +800,9 @@ def _check_r7(module: Module, reference_root: Optional[Path] = None) -> List[Fin
 _R8_SCOPE_FILE = "torchft_tpu/metrics.py"
 _R8_DOC_FILE = "METRICS.md"
 _R8_EMIT_RE = re.compile(
-    r"metrics\.(?:inc|observe|set_gauge|timer|counter|gauge|histogram)\(\s*"
-    r'"(tpuft_[a-z0-9_]+)"'
+    r"(?:metrics\.(?:inc|observe|set_gauge|timer|counter|gauge|histogram)\(\s*"
+    # tracing.PHASES: a phase's histogram is emitted by tracing.phase().
+    r'|\bhistogram=)"(tpuft_[a-z0-9_]+)"'
 )
 _R8_ROW_RE = re.compile(r"\| `(tpuft_[a-z0-9_]+)` \|")
 

@@ -77,8 +77,18 @@ class _TimeoutManager:
         deadline = time.monotonic() + delay
         with self._lock:
             heapq.heappush(self._heap, (deadline, next(self._seq), handle, callback))
+            self._note_heap()
             self._lock.notify()
         return handle
+
+    def _note_heap(self) -> None:
+        """The heap's length as a gauge (called under the lock). Cancelling
+        a handle only marks it: the entry, its callback and whatever the
+        callback holds (a finished future's result) stay until the deadline
+        pops them, and this is where that shows."""
+        from torchft_tpu import metrics  # lazy: futures stays a leaf module
+
+        metrics.set_gauge("tpuft_timeout_heap_entries", len(self._heap))
 
     def _run(self) -> None:
         while True:
@@ -93,6 +103,7 @@ class _TimeoutManager:
                     self._lock.wait(timeout=min(deadline - now, 1.0))
                     continue
                 heapq.heappop(self._heap)
+                self._note_heap()
             if not handle.cancelled:
                 try:
                     callback()

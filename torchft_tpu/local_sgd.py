@@ -27,7 +27,9 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 import jax
 import numpy as np
 
+from torchft_tpu import tracing
 from torchft_tpu.manager import Manager
+from torchft_tpu.optim import _trace_of
 from torchft_tpu.utils import netem
 from torchft_tpu.utils.transfer import prefetch_to_host
 from torchft_tpu.work import Work
@@ -222,16 +224,17 @@ class LocalSGD:
         # non-fully-addressable arrays and lose the shardings on restore).
         from torchft_tpu.parallel.mesh import ft_allreduce_sharded
 
-        self._manager.start_quorum()
-        averaged = ft_allreduce_sharded(self._manager, self.params)
-        if self._manager.should_commit():
-            self._manager.disallow_state_dict_read()
-            try:
-                self.params = averaged
-            finally:
-                self._manager.allow_state_dict_read()
-            return True
-        return False
+        with tracing.phase("perform_sync", _trace_of(self._manager)):
+            self._manager.start_quorum()
+            averaged = ft_allreduce_sharded(self._manager, self.params)
+            if self._manager.should_commit():
+                self._manager.disallow_state_dict_read()
+                try:
+                    self.params = averaged
+                finally:
+                    self._manager.allow_state_dict_read()
+                return True
+            return False
 
 
 class _Fragment:
@@ -388,33 +391,70 @@ class _Fragment:
         """Computes pseudogradients (backup − local) and launches their
         averaging; does not wait (reference :402-421)."""
         assert self._work is None, "fragment already has an allreduce in flight"
-        if self._should_quantize:
-            payload, scales = self._jit_quantize_pg(
-                self.backup, [local_leaves[i] for i in self.leaf_indices]
-            )
-            # Device arrays pass through: the d2h fetch happens on the
-            # pipeline thread, overlapping the delay window's inner steps.
-            # Participation zeroing + error funnel live in the manager.
-            self._work = self._manager.allreduce_prequantized(payload, scales)
-        else:
-            locals_ = [local_leaves[i] for i in self.leaf_indices]
-            # Launch every device→host copy before consuming any: the
-            # per-leaf np.asarray below then drains transfers already in
-            # flight instead of serializing one round trip per leaf.
-            prefetch_to_host(locals_)
-            pseudograds = [
-                backup - np.asarray(leaf)
-                for backup, leaf in zip(self.backup, locals_)
-            ]
-            self._work = self._manager.allreduce_pytree(pseudograds)
+        trace, ids = _trace_of(self._manager), self._span_ids()
+        with tracing.phase("prepare_sync", trace, **ids):
+            if self._should_quantize:
+                with tracing.phase("sync_quantize", trace, **ids):
+                    payload, scales = self._jit_quantize_pg(
+                        self.backup, [local_leaves[i] for i in self.leaf_indices]
+                    )
+                # Device arrays pass through: the d2h fetch happens on the
+                # pipeline thread, overlapping the delay window's inner steps.
+                # Participation zeroing + error funnel live in the manager.
+                with tracing.phase("sync_launch", trace, **ids):
+                    self._work = self._manager.allreduce_prequantized(
+                        payload, scales
+                    )
+            else:
+                with tracing.phase("sync_quantize", trace, **ids):
+                    locals_ = [local_leaves[i] for i in self.leaf_indices]
+                    # Launch every device→host copy before consuming any: the
+                    # per-leaf np.asarray below then drains transfers already
+                    # in flight instead of serializing one round trip per leaf.
+                    prefetch_to_host(locals_)
+                    pseudograds = [
+                        backup - np.asarray(leaf)
+                        for backup, leaf in zip(self.backup, locals_)
+                    ]
+                with tracing.phase("sync_launch", trace, **ids):
+                    self._work = self._manager.allreduce_pytree(pseudograds)
+
+    def _span_ids(self) -> Dict[str, Any]:
+        """What every span of this fragment's sync carries: the fragment and
+        the manager's step (the step the sync commits)."""
+        step = self._manager.current_step()
+        # A scripted manager's step may be no number: phase() leaves a None out.
+        return {
+            "fragment": self._fragment_id,
+            "step": step if isinstance(step, int) else None,
+        }
 
     def perform_sync(self, local_leaves: List[Any]) -> bool:
         """Waits for the allreduce, restores globals, commits, and on success
         applies the outer step + local/global merge (reference :423-476)."""
         assert self._work is not None, "perform_sync before prepare_sync"
-        averaged = self._work.wait()
-        self._work = None
+        trace, ids = _trace_of(self._manager), self._span_ids()
+        with tracing.phase("perform_sync", trace, **ids):
+            with tracing.phase("sync_wait", trace, **ids):
+                averaged = self._work.wait()
+            self._work = None
+            with tracing.phase("sync_restore", trace, **ids):
+                local_copy = self._restore_globals(local_leaves)
+            # The commit barrier must run unlocked: it can apply a healing
+            # state dict and peers' serve threads need the read lock meanwhile.
+            with tracing.phase("sync_commit", trace, **ids):
+                committed = self._manager.should_commit()
+            if not committed:
+                return False
+            if averaged is None:  # quantized-path allreduce error (already reported)
+                return False
+            with tracing.phase("sync_apply_outer", trace, **ids):
+                self._apply_outer(averaged, local_copy, local_leaves)
+            return True
 
+    def _restore_globals(self, local_leaves: List[Any]) -> List[Any]:
+        """Copies this fragment's local leaves aside and rebinds them to the
+        backups; returns the copies."""
         locals_ = [local_leaves[i] for i in self.leaf_indices]
         if not self._should_quantize:
             # Same launch-then-drain pattern as prepare_sync: this fetch sits
@@ -436,14 +476,13 @@ class _Fragment:
                 )
         finally:
             self._manager.allow_state_dict_read()
+        return local_copy
 
-        # The commit barrier must run unlocked: it can apply a healing state
-        # dict and peers' serve threads need the read lock meanwhile.
-        if not self._manager.should_commit():
-            return False
-        if averaged is None:  # quantized-path allreduce error (already reported)
-            return False
-
+    def _apply_outer(
+        self, averaged: Any, local_copy: List[Any], local_leaves: List[Any]
+    ) -> None:
+        """The outer step on the averaged pseudogradient and the
+        local/global merge, write-locked."""
         self._manager.disallow_state_dict_read()
         try:
             if self._should_quantize:
@@ -499,7 +538,6 @@ class _Fragment:
                     )
         finally:
             self._manager.allow_state_dict_read()
-        return True
 
 
 class DiLoCo:
@@ -573,6 +611,9 @@ class DiLoCo:
         self._inner_tx = inner_tx
         self._fragment_sync_delay = fragment_sync_delay
         self._local_step = 0
+        # Inner steps since construction: the number of the root span
+        # (the manager's step counts committed syncs, not inner steps).
+        self._inner_steps = 0
 
         leaves, self._treedef = jax.tree_util.tree_flatten(params)
         self._leaves = list(leaves)
@@ -651,16 +692,34 @@ class DiLoCo:
     def step(self, grads: Any) -> bool:
         """One inner step; drives the fragment prepare/sync schedule.
         Returns whether a fragment sync committed this step."""
-        # Write-lock the inner mutation (reference step pre/post hooks).
-        self._manager.disallow_state_dict_read()
-        try:
-            new_params, self.inner_opt_state = self._jit_update(
-                grads, self.inner_opt_state, self.params
-            )
-            self._leaves = list(jax.tree_util.tree_flatten(new_params)[0])
-        finally:
-            self._manager.allow_state_dict_read()
-        return self._after_inner_step()
+        with self._step_span():
+            # Write-lock the inner mutation (reference step pre/post hooks).
+            self._manager.disallow_state_dict_read()
+            try:
+                with self._dispatch_span():
+                    new_params, self.inner_opt_state = self._jit_update(
+                        grads, self.inner_opt_state, self.params
+                    )
+                self._leaves = list(jax.tree_util.tree_flatten(new_params)[0])
+            finally:
+                self._manager.allow_state_dict_read()
+            return self._after_inner_step()
+
+    def _dispatch_span(self) -> Any:
+        """The inner step's own dispatch, apart from the sync schedule behind
+        it: where the runtime holds the host (a chip near full, a queue of
+        programs), the device's gap lands here and not under a sync."""
+        return tracing.phase("inner_dispatch", _trace_of(self._manager))
+
+    def _step_span(self) -> Any:
+        """The root span of one inner step (``tpuft::local_sgd::step``,
+        numbered by the inner step): a fragment sync's spans open under it,
+        and it takes every gap of the device that none of them covers."""
+        self._inner_steps += 1
+        return tracing.phase(
+            "local_sgd_step", _trace_of(self._manager),
+            inner_step=self._inner_steps - 1,
+        )
 
     def make_step_fn(self, loss_fn: Callable[..., Any]) -> Callable[..., Any]:
         """Fuses loss/grad + inner update into ONE jitted dispatch.
@@ -686,15 +745,17 @@ class DiLoCo:
         fused_jit = jax.jit(fused)
 
         def step(*batch: Any):
-            self._manager.disallow_state_dict_read()
-            try:
-                new_leaves, self.inner_opt_state, loss = fused_jit(
-                    self._leaves, self.inner_opt_state, *batch
-                )
-                self._leaves = list(new_leaves)
-            finally:
-                self._manager.allow_state_dict_read()
-            return loss, self._after_inner_step()
+            with self._step_span():
+                self._manager.disallow_state_dict_read()
+                try:
+                    with self._dispatch_span():
+                        new_leaves, self.inner_opt_state, loss = fused_jit(
+                            self._leaves, self.inner_opt_state, *batch
+                        )
+                    self._leaves = list(new_leaves)
+                finally:
+                    self._manager.allow_state_dict_read()
+                return loss, self._after_inner_step()
 
         return step
 

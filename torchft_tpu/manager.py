@@ -636,6 +636,7 @@ class Manager:
             group_rank=self._group_rank,
         )
         self._trace.set_step(self._step, self._quorum_id)
+        tracing.install_compile_listener()
         self._trace_clock = tracing.StoreClockSampler(
             self._trace,
             owner_key=f"{self._metric_labels['replica_id']}/{self._group_rank}",
@@ -1008,7 +1009,7 @@ class Manager:
         if self.errored():
             return _DummyWork(tensor)
 
-        with trace_span("tpuft::manager::allreduce", step=self._step):
+        with tracing.phase("allreduce", step=self._step):
             return self._allreduce_impl(tensor, should_quantize, reduce_op)
 
     def _allreduce_impl(
@@ -1073,18 +1074,31 @@ class Manager:
                 )
         if self.errored():
             return _DummyWork(pytree)
-        with trace_span("tpuft::manager::allreduce_pytree", step=self._step):
-            self.wait_quorum()
-            num_participants = self.num_participants()
-            if self.is_lone_replica():
-                # Identity: SUM over one participant / 1. Resolve to host
-                # copies of the leaves (the documented numpy contract)
-                # without touching the wire.
-                return _DummyWork(
-                    jax.tree_util.tree_unflatten(
-                        treedef, [np.asarray(leaf) for leaf in leaves]
-                    )
+        with tracing.phase("allreduce_pytree", step=self._step):
+            return self._allreduce_pytree_impl(
+                pytree, leaves, treedef, should_quantize
+            )
+
+    def _allreduce_pytree_impl(
+        self, pytree: Any, leaves: List[Any], treedef: Any, should_quantize: bool
+    ) -> Work:
+        import jax
+
+        self.wait_quorum()
+        num_participants = self.num_participants()
+        if self.is_lone_replica():
+            # Identity: SUM over one participant / 1. Resolve to host
+            # copies of the leaves (the documented numpy contract)
+            # without touching the wire.
+            return _DummyWork(
+                jax.tree_util.tree_unflatten(
+                    treedef, [np.asarray(leaf) for leaf in leaves]
                 )
+            )
+        # The wire's stages, each a phase of its own (tpuft::wire::*,
+        # tpuft_wire_stage_seconds{stage}); ids as this step's.
+        trace, labels, step = self._trace, self._metric_labels, self._step
+        with tracing.phase("wire_stage", trace, labels, step=step):
             # Launch every device→host copy before completing any: the
             # per-leaf np.asarray then drains transfers that are already in
             # flight instead of serializing them.
@@ -1096,22 +1110,27 @@ class Manager:
         # Bucket same-dtype leaves (stable order). The quantized path stays
         # per-leaf: concatenation would let one fp8 block's max-abs scale
         # span parameter boundaries and crush small-magnitude leaves to 0.
-        if should_quantize:
-            buckets: Dict[Any, List[int]] = {
-                index: [index] for index in range(len(arrays))
-            }
-            flat_buffers = [a.reshape(-1) for a in arrays]
-        else:
-            buckets = {}
-            for index, array in enumerate(arrays):
-                buckets.setdefault(array.dtype, []).append(index)
-            flat_buffers = [
-                np.concatenate([arrays[i].reshape(-1) for i in members])
-                if len(members) > 1
-                else arrays[members[0]].reshape(-1)
-                for members in buckets.values()
-            ]
+        with tracing.phase("wire_bucket", trace, labels, step=step):
+            if should_quantize:
+                buckets: Dict[Any, List[int]] = {
+                    index: [index] for index in range(len(arrays))
+                }
+                flat_buffers = [a.reshape(-1) for a in arrays]
+            else:
+                buckets = {}
+                for index, array in enumerate(arrays):
+                    buckets.setdefault(array.dtype, []).append(index)
+                flat_buffers = [
+                    np.concatenate([arrays[i].reshape(-1) for i in members])
+                    if len(members) > 1
+                    else arrays[members[0]].reshape(-1)
+                    for members in buckets.values()
+                ]
         try:
+            # The ring crosses threads: it starts here and ends where the
+            # PG's worker resolves the future, so it is recorded there with
+            # this start stamp (the waiting thread annotates its own wait).
+            ring_start = trace._mono()
             if should_quantize:
                 from torchft_tpu.parallel.collectives import allreduce_quantized
 
@@ -1120,22 +1139,24 @@ class Manager:
                 work = self._pg.allreduce(flat_buffers, ReduceOp.SUM)
 
             def callback(result: List[np.ndarray]) -> Any:
-                averaged: List[Any] = [None] * len(arrays)
-                for flat, members in zip(result, buckets.values()):
-                    # Float-only by the precondition above.
-                    flat = (flat / num_participants).astype(flat.dtype)
-                    offset = 0
-                    for i in members:
-                        size = arrays[i].size
-                        # Copy: leaves must not alias one shared bucket
-                        # buffer (or the caller's input via an echo PG).
-                        averaged[i] = (
-                            flat[offset : offset + size]
-                            .reshape(arrays[i].shape)
-                            .copy()
-                        )
-                        offset += size
-                return jax.tree_util.tree_unflatten(treedef, averaged)
+                tracing.record_phase("wire_ring", ring_start, trace, labels, step=step)
+                with tracing.phase("wire_average", trace, labels, step=step):
+                    averaged: List[Any] = [None] * len(arrays)
+                    for flat, members in zip(result, buckets.values()):
+                        # Float-only by the precondition above.
+                        flat = (flat / num_participants).astype(flat.dtype)
+                        offset = 0
+                        for i in members:
+                            size = arrays[i].size
+                            # Copy: leaves must not alias one shared bucket
+                            # buffer (or the caller's input via an echo PG).
+                            averaged[i] = (
+                                flat[offset : offset + size]
+                                .reshape(arrays[i].shape)
+                                .copy()
+                            )
+                            offset += size
+                    return jax.tree_util.tree_unflatten(treedef, averaged)
 
             return self.wrap_work(work.then(callback), default=pytree)
         except Exception as e:  # noqa: BLE001
@@ -1154,7 +1175,7 @@ class Manager:
 
         if self.errored():
             return _DummyWork(None)
-        with trace_span("tpuft::manager::allreduce_prequantized"):
+        with tracing.phase("allreduce_prequantized", step=self._step):
             self.wait_quorum()
             num_participants = self.num_participants()
         if self.is_lone_replica():
@@ -1242,6 +1263,22 @@ class Manager:
         """Starts a (possibly async) quorum and readies the manager for a new
         step (reference: manager.py:534-589). Call before the forward pass."""
         schedules.point("manager.start_quorum")
+        with tracing.phase("start_quorum", self._trace, step=self._step):
+            self._begin_quorum(allow_heal, shrink_only, timeout)
+        if not self._use_async_quorum:
+            self.wait_quorum()
+            if self._healing:
+                # Eagerly apply the pending state dict so the forward pass
+                # runs against recovered parameters.
+                self._apply_pending_state_dict()
+                self._healing = False
+
+    def _begin_quorum(
+        self, allow_heal: bool, shrink_only: bool, timeout: Optional[float]
+    ) -> None:
+        """The synchronous part of :meth:`start_quorum`: the drain of what
+        the previous step left pending, the health gate, the publication
+        and the hand-over to the quorum thread."""
         if self._quorum_future is not None:
             self._quorum_future.result()
 
@@ -1282,13 +1319,6 @@ class Manager:
             shrink_only=shrink_only,
             quorum_timeout=timeout or self._quorum_timeout,
         )
-        if not self._use_async_quorum:
-            self.wait_quorum()
-            if self._healing:
-                # Eagerly apply the pending state dict so the forward pass
-                # runs against recovered parameters.
-                self._apply_pending_state_dict()
-                self._healing = False
 
     def _drain_pending_commit(self, caller: str) -> None:
         """Resolves any should_commit_async future the caller never
@@ -1313,18 +1343,16 @@ class Manager:
     def wait_quorum(self) -> None:
         """Blocks until the quorum completes; the PG is healthy after."""
         assert self._quorum_future is not None, "must call start_quorum before wait_quorum"
-        with trace_span("tpuft::manager::wait_quorum", step=self._step):
+        with tracing.phase("wait_quorum", step=self._step):
             self._quorum_future.result()
 
     def _async_quorum(
         self, allow_heal: bool, shrink_only: bool, quorum_timeout: float
     ) -> None:
         try:
-            with trace_span(
-                "tpuft::manager::_client::_quorum", step=self._step
-            ), metrics.timer(
-                "tpuft_quorum_seconds", **self._metric_labels
-            ), self._trace.span("quorum", step=self._step):
+            with tracing.phase(
+                "quorum", self._trace, self._metric_labels, step=self._step
+            ):
                 quorum = self._client._quorum(
                     group_rank=self._group_rank,
                     step=self._step,
@@ -1447,14 +1475,9 @@ class Manager:
             # the window may SHRINK — see _adapt_pipeline_depth).
             self._adapt_pipeline_depth()
             try:
-                with trace_span(
-                    "tpuft::manager::_pg::configure",
-                    quorum_id=quorum.quorum_id,
-                    step=self._step,
-                ), metrics.timer(
-                    "tpuft_pg_configure_seconds", **self._metric_labels
-                ), self._trace.span(
-                    "pg_configure", step=self._step, quorum_id=quorum.quorum_id
+                with tracing.phase(
+                    "pg_configure", self._trace, self._metric_labels,
+                    step=self._step, quorum_id=quorum.quorum_id,
                 ):
                     self._pg.configure(
                         store_prefixed_addr,
@@ -1944,16 +1967,9 @@ class Manager:
             errored=self._errored is not None,
         )
         barrier_t0 = time.perf_counter()
-        with trace_span(
-            "tpuft::manager::should_commit",
-            step=self._step,
-            quorum_id=self._quorum_id,
-        ), metrics.timer(
-            "tpuft_commit_barrier_seconds", **self._metric_labels
-        ), self._trace.span(
-            "commit_barrier",
-            step=self._step,
-            quorum_id=self._quorum_id,
+        with tracing.phase(
+            "should_commit", self._trace, self._metric_labels,
+            step=self._step, quorum_id=self._quorum_id,
             vote=local_should_commit,
         ):
             should_commit = self._client.should_commit(
@@ -2114,14 +2130,9 @@ class Manager:
         thread observes once the window covers it."""
         barrier_t0 = time.perf_counter()
         try:
-            with trace_span(
-                "tpuft::manager::speculative_commit",
-                step=step,
-                quorum_id=self._quorum_id,
-            ), metrics.timer(
-                "tpuft_commit_barrier_seconds", **self._metric_labels
-            ), self._trace.span(
-                "commit_barrier", step=step, quorum_id=self._quorum_id, vote=vote
+            with tracing.phase(
+                "speculative_commit", self._trace, self._metric_labels,
+                step=step, quorum_id=self._quorum_id, vote=vote,
             ):
                 return self._client.should_commit(
                     self._group_rank, step, vote, timeout=timeout or self._timeout

@@ -29,7 +29,6 @@ from __future__ import annotations
 import logging
 import os
 import threading
-import time
 from typing import Any, Optional
 
 import jax
@@ -83,18 +82,14 @@ def _sync_device(x: Any) -> Any:
     Calls through the module global so spies and the netem shim that rebind
     ``_bound_device`` still intercept the sync — and their emulated/observed
     latency lands in the phase histogram like the real one."""
-    start = time.perf_counter()
-    try:
-        with tracing.span("device_sync"):
-            # Gray-failure chaos seam: a punisher-armed slow_replica/
-            # wedge_device installs a persistent per-replica stall/wedge
-            # here (one env lookup when unarmed) — the injected latency
-            # lands in the phase histogram and the health scorer's EWMA
-            # exactly like a real gray device.
-            health.injected_stall("device_sync")
-            return _bound_device(x)
-    finally:
-        metrics.observe("tpuft_device_sync_seconds", time.perf_counter() - start)
+    with tracing.phase("device_sync"):
+        # Gray-failure chaos seam: a punisher-armed slow_replica/
+        # wedge_device installs a persistent per-replica stall/wedge
+        # here (one env lookup when unarmed) — the injected latency
+        # lands in the phase histogram and the health scorer's EWMA
+        # exactly like a real gray device.
+        health.injected_stall("device_sync")
+        return _bound_device(x)
 
 
 def make_microbatch_grad(loss_fn: Any, num_microbatches: int):
@@ -505,9 +500,7 @@ class Optimizer:
         params, opt_state = self.params, self.opt_state
         commit_future = self.manager.should_commit_async(timeout)
         try:
-            with metrics.timer("tpuft_update_dispatch_seconds"), _trace_of(
-                self.manager
-            ).span("update_dispatch"):
+            with tracing.phase("update_dispatch", _trace_of(self.manager)):
                 spec = self._jit_update(grads, opt_state, params)
         except BaseException:
             # The barrier is already in flight and may commit the step
@@ -566,29 +559,32 @@ class Optimizer:
         self.params/opt_state only after it returns. The mutation is
         write-locked so a concurrent checkpoint capture (donor staging on
         the quorum thread) never reads a torn params/opt pair."""
-        committed = (
-            commit_future.result()
-            if commit_future is not None
-            else self.manager.should_commit(timeout=timeout)
-        )
+        trace = _trace_of(self.manager)
+        with tracing.phase("commit_wait", trace):
+            committed = (
+                commit_future.result()
+                if commit_future is not None
+                else self.manager.should_commit(timeout=timeout)
+            )
         if not committed:
             return False
-        self.manager.disallow_state_dict_read()
-        try:
-            if self._heal_count != heal_count:
-                self.params, self.opt_state = recompute()
-            else:
-                self.params, self.opt_state = speculation
-        finally:
-            self.manager.allow_state_dict_read()
-        # Promote the just-committed state into the manager's history
-        # ring (refs only — immutable trees make holding a reference a
-        # true snapshot). The barrier already advanced the step counter.
-        self._promote_committed(
-            self._int_or_none(self.manager.current_step()),
-            self.params,
-            self.opt_state,
-        )
+        with tracing.phase("adopt", trace):
+            self.manager.disallow_state_dict_read()
+            try:
+                if self._heal_count != heal_count:
+                    self.params, self.opt_state = recompute()
+                else:
+                    self.params, self.opt_state = speculation
+            finally:
+                self.manager.allow_state_dict_read()
+            # Promote the just-committed state into the manager's history
+            # ring (refs only — immutable trees make holding a reference a
+            # true snapshot). The barrier already advanced the step counter.
+            self._promote_committed(
+                self._int_or_none(self.manager.current_step()),
+                self.params,
+                self.opt_state,
+            )
         return True
 
     # ------------------------------------------------------------------
@@ -735,48 +731,51 @@ class Optimizer:
                 "tpuft::optim::resolve_pipelined_commit",
                 step=self.manager.current_step(),
             ):
-                committed = rec.commit_future.result()
+                trace = _trace_of(self.manager)
+                with tracing.phase("commit_wait", trace, step=rec.claimed_step):
+                    committed = rec.commit_future.result()
                 rolled_back = False
                 discarded = 0
-                self.manager.disallow_state_dict_read()
-                try:
-                    if self._heal_count != rec.heal_count:
-                        # Healed mid-flight: the donor state is
-                        # authoritative; a committed step still owes its
-                        # update (pre-heal grads applied to the healed state
-                        # — reference load_state_dict + optimizer.step()
-                        # order).
-                        if committed:
-                            self.params, self.opt_state = rec.recompute()
-                    elif not committed:
-                        # Refuse to adopt: restore the pre-step state the
-                        # speculation was dispatched from, and turn every
-                        # younger in-flight slot into a discard — their
-                        # speculations chain from this refused one.
-                        self.params, self.opt_state = rec.snapshot
-                        self.rollback_count += 1
-                        rolled_back = True
-                        pending = (
-                            self._pipeline.pending()
-                            if self._pipeline is not None
-                            else ()
-                        )
-                        discarded = sum(
-                            1
-                            for r in pending
-                            if r is not rec and r.gen == rec.gen
-                        )
-                        self._speculation_gen += 1
-                        metrics.inc(
-                            "tpuft_rollbacks_total",
-                            **_replica_labels(self.manager),
-                        )
-                        metrics.histogram(
-                            "tpuft_rollback_unwind_depth",
-                            buckets=_UNWIND_DEPTH_BUCKETS,
-                        ).observe(1 + discarded)
-                finally:
-                    self.manager.allow_state_dict_read()
+                with tracing.phase("adopt", trace, step=rec.claimed_step):
+                    self.manager.disallow_state_dict_read()
+                    try:
+                        if self._heal_count != rec.heal_count:
+                            # Healed mid-flight: the donor state is
+                            # authoritative; a committed step still owes its
+                            # update (pre-heal grads applied to the healed state
+                            # — reference load_state_dict + optimizer.step()
+                            # order).
+                            if committed:
+                                self.params, self.opt_state = rec.recompute()
+                        elif not committed:
+                            # Refuse to adopt: restore the pre-step state the
+                            # speculation was dispatched from, and turn every
+                            # younger in-flight slot into a discard — their
+                            # speculations chain from this refused one.
+                            self.params, self.opt_state = rec.snapshot
+                            self.rollback_count += 1
+                            rolled_back = True
+                            pending = (
+                                self._pipeline.pending()
+                                if self._pipeline is not None
+                                else ()
+                            )
+                            discarded = sum(
+                                1
+                                for r in pending
+                                if r is not rec and r.gen == rec.gen
+                            )
+                            self._speculation_gen += 1
+                            metrics.inc(
+                                "tpuft_rollbacks_total",
+                                **_replica_labels(self.manager),
+                            )
+                            metrics.histogram(
+                                "tpuft_rollback_unwind_depth",
+                                buckets=_UNWIND_DEPTH_BUCKETS,
+                            ).observe(1 + discarded)
+                    finally:
+                        self.manager.allow_state_dict_read()
                 if committed:
                     # Ring-slot promotion: the resolved slot's committed
                     # state enters the step-labeled history instead of
@@ -876,7 +875,9 @@ class Optimizer:
         # The goodput ledger attributes this span to its `drain` bucket —
         # window-resolution time spent on the quorum thread is neither
         # quorum wait nor committed compute.
-        with _trace_of(self.manager).span("pipeline_drain", depth=len(pending)):
+        with tracing.phase(
+            "pipeline_drain", _trace_of(self.manager), depth=len(pending)
+        ):
             for rec in pending:
                 self._resolve_pipelined_record(rec)
                 rec.bound_device(raise_on_error=False)
@@ -942,6 +943,16 @@ class Optimizer:
             )
 
         def step_fn(*batch):
+            # The root span of one FT-DDP step: every gap of the device that
+            # no child span covers lands here, which is the test of the
+            # children.
+            with tracing.phase(
+                "optim_step", _trace_of(self.manager),
+                step=self._int_or_none(self.manager.current_step()),
+            ):
+                return run_step(*batch)
+
+        def run_step(*batch):
             self.begin_step()
             if on_quorum is not None:
                 import time as _time
@@ -1028,9 +1039,9 @@ class Optimizer:
         # reference keeps the pre-heal state alive for the rare
         # heal-during-barrier recompute below.
         pre_params = self.params
-        with metrics.timer("tpuft_update_dispatch_seconds"), _trace_of(
-            self.manager
-        ).span("update_dispatch", fused=True):
+        with tracing.phase(
+            "update_dispatch", _trace_of(self.manager), fused=True
+        ):
             loss, spec_params, spec_opt_state = fused(
                 self.params, self.opt_state, *batch
             )
@@ -1146,6 +1157,12 @@ class Optimizer:
         speculative_votes = manager.commit_pipeline_adaptive or depth >= 2
 
         def step_fn(*batch):
+            with tracing.phase(
+                "optim_step", _trace_of(manager), step=self._next_pipelined_step
+            ):
+                return run_step(*batch)
+
+        def run_step(*batch):
             target_depth = max(1, manager.commit_pipeline_depth)
             pipeline.set_depth(target_depth)
             # Next-step dispatch before any vote resolution: the wire

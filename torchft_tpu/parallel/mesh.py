@@ -25,6 +25,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from torchft_tpu import tracing
 from torchft_tpu.manager import Manager
 
 __all__ = ["FTMesh", "ft_init_device_mesh", "ft_allreduce_sharded"]
@@ -130,61 +131,73 @@ def ft_allreduce_sharded(
         return grads
 
     leaves, treedef = jax.tree_util.tree_flatten(grads)
+    # The stages of one sync are phases of their own (tpuft::wire::*,
+    # tpuft_wire_stage_seconds{stage}); bucketing, the ring and the average
+    # are recorded inside Manager.allreduce_pytree.
+    trace = getattr(manager, "_trace", None)
+    labels = getattr(manager, "_metric_labels", None)
+    step = manager.current_step()
+    ids = {"step": step if isinstance(step, int) else None}
 
     # Stage: per-leaf list of (device, host_shard) in index order.
     staged: List[Dict[str, Any]] = []
     flat_arrays: List[np.ndarray] = []
-    for leaf in leaves:
-        if isinstance(leaf, jax.Array) and hasattr(leaf, "addressable_shards"):
-            # Deterministic, group-independent order: by the shard's index
-            # window (device ids differ across replica groups).
-            shards = sorted(
-                leaf.addressable_shards,
-                key=lambda s: tuple(
-                    (sl.start or 0, sl.stop if sl.stop is not None else -1)
-                    for sl in s.index
-                ),
-            )
-            entry = {
-                "type": "sharded",
-                "sharding": leaf.sharding,
-                "shape": leaf.shape,
-                "dtype": leaf.dtype,
-                "devices": [s.device for s in shards],
-                "indices": [s.index for s in shards],
-                "count": len(shards),
-            }
-            staged.append(entry)
-            for s in shards:
-                flat_arrays.append(np.asarray(s.data))
-        else:
-            staged.append({"type": "plain", "count": 1})
-            flat_arrays.append(np.asarray(leaf))
+    with tracing.phase("wire_stage", trace, labels, **ids):
+        for leaf in leaves:
+            if isinstance(leaf, jax.Array) and hasattr(leaf, "addressable_shards"):
+                # Deterministic, group-independent order: by the shard's index
+                # window (device ids differ across replica groups).
+                shards = sorted(
+                    leaf.addressable_shards,
+                    key=lambda s: tuple(
+                        (sl.start or 0, sl.stop if sl.stop is not None else -1)
+                        for sl in s.index
+                    ),
+                )
+                entry = {
+                    "type": "sharded",
+                    "sharding": leaf.sharding,
+                    "shape": leaf.shape,
+                    "dtype": leaf.dtype,
+                    "devices": [s.device for s in shards],
+                    "indices": [s.index for s in shards],
+                    "count": len(shards),
+                }
+                staged.append(entry)
+                for s in shards:
+                    flat_arrays.append(np.asarray(s.data))
+            else:
+                staged.append({"type": "plain", "count": 1})
+                flat_arrays.append(np.asarray(leaf))
 
     work = manager.allreduce_pytree(flat_arrays, should_quantize=should_quantize)
-    averaged: List[np.ndarray] = work.wait()
+    # The ring's journal event and histogram sample are recorded where its
+    # future resolves (another thread); this thread's wait is annotated.
+    with tracing.annotation("wire_ring", **ids):
+        averaged: List[np.ndarray] = work.wait()
 
     # Scatter back preserving shardings.
-    out_leaves: List[Any] = []
-    cursor = 0
-    for entry, orig in zip(staged, leaves):
-        if entry["type"] == "plain":
-            host = averaged[cursor]
-            cursor += 1
-            if isinstance(orig, jax.Array):
-                out_leaves.append(jax.device_put(host, orig.sharding))
-            else:
-                out_leaves.append(host)
-            continue
-        shard_arrays = averaged[cursor : cursor + entry["count"]]
-        cursor += entry["count"]
-        buffers = [
-            jax.device_put(host, device)
-            for host, device in zip(shard_arrays, entry["devices"])
-        ]
-        out_leaves.append(
-            jax.make_array_from_single_device_arrays(
-                entry["shape"], entry["sharding"], buffers
+    with tracing.phase("wire_scatter", trace, labels, **ids):
+        out_leaves: List[Any] = []
+        cursor = 0
+        for entry, orig in zip(staged, leaves):
+            if entry["type"] == "plain":
+                host = averaged[cursor]
+                cursor += 1
+                if isinstance(orig, jax.Array):
+                    out_leaves.append(jax.device_put(host, orig.sharding))
+                else:
+                    out_leaves.append(host)
+                continue
+            shard_arrays = averaged[cursor : cursor + entry["count"]]
+            cursor += entry["count"]
+            buffers = [
+                jax.device_put(host, device)
+                for host, device in zip(shard_arrays, entry["devices"])
+            ]
+            out_leaves.append(
+                jax.make_array_from_single_device_arrays(
+                    entry["shape"], entry["sharding"], buffers
+                )
             )
-        )
     return jax.tree_util.tree_unflatten(treedef, out_leaves)

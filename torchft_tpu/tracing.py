@@ -43,17 +43,31 @@ The ring holds ``TPUFT_TRACE_SIZE`` events (default 8192).
 Journal recording NEVER takes the state-dict lock — recording sites are
 plain deque appends, safe inside any phase including the commit barrier
 (the R3 lock-discipline fixtures pin the pattern).
+
+Step-path phases are recorded through ONE primitive, :func:`phase`: a
+context manager that reads the clock once at entry and once at exit and
+feeds the three sinks the :data:`PHASES` table names for it — the
+histogram (always), this journal (when enabled) and a
+``jax.profiler.TraceAnnotation`` (a no-op unless a profiler session is
+running). :func:`start_capture` / :func:`stop_capture` start and stop such
+a session from inside the process that holds the chip and hand back what
+the journal and the metrics registry recorded meanwhile, with two clock
+anchors that lay journal instants onto the profiler's timeline.
+docs/observability.md has the span tree.
 """
 
 from __future__ import annotations
 
 import itertools
 import json
+import logging
 import os
 import threading
 import time
 from contextlib import contextmanager
-from typing import Any, Callable, Deque, Dict, Generator, List, Optional
+from typing import (
+    Any, Callable, Deque, Dict, Generator, List, NamedTuple, Optional,
+)
 
 import collections
 
@@ -79,6 +93,13 @@ __all__ = [
     "active_incident",
     "clear_incident",
     "trace_json_payload",
+    "PHASES",
+    "phase",
+    "record_phase",
+    "annotation",
+    "start_capture",
+    "stop_capture",
+    "install_compile_listener",
 ]
 
 ENV_TRACE = "TPUFT_TRACE"
@@ -191,6 +212,11 @@ class TraceJournal:
     def enabled(self) -> bool:
         return self._enabled
 
+    def set_enabled(self, enabled: bool) -> None:
+        """Switches recording from inside the process (``TPUFT_TRACE`` only
+        decides the value a journal starts with)."""
+        self._enabled = bool(enabled)
+
     # -- recording ----------------------------------------------------------
 
     def record(
@@ -214,16 +240,14 @@ class TraceJournal:
         try:
             seq = next(self._seq)
             self._last_seq = seq
-            now_wall = self._wall()
-            now_mono = self._mono()
             back = dur or 0.0
             event: Dict[str, Any] = {
                 "seq": seq,
                 "name": name,
                 "ph": ph,
                 "cat": cat,
-                "t_wall": now_wall - back if t_wall is None else t_wall,
-                "t_mono": now_mono - back if t_mono is None else t_mono,
+                "t_wall": self._wall() - back if t_wall is None else t_wall,
+                "t_mono": self._mono() - back if t_mono is None else t_mono,
                 "thread": threading.current_thread().name,
                 "step": self.step if step is None else step,
                 "quorum_id": self.quorum_id if quorum_id is None else quorum_id,
@@ -446,6 +470,410 @@ def record(name: str, **kwargs: Any) -> None:
 
 def span(name: str, **kwargs: Any):
     return current().span(name, **kwargs)
+
+
+# ---------------------------------------------------------------------------
+# phases: one recording site, three sinks
+# ---------------------------------------------------------------------------
+
+
+class _PhaseSpec(NamedTuple):
+    """Where one phase lands: its journal event, its profiler annotation and
+    its histogram (``None``: that sink has no entry for it). ``stage`` is
+    the histogram's ``stage`` label where several phases share one
+    histogram; ``root`` makes the annotation a ``StepTraceAnnotation``
+    numbered by the ``step`` id, so the profiler's own step view works."""
+
+    journal: Optional[str]
+    annotation: Optional[str]
+    histogram: Optional[str] = None
+    stage: Optional[str] = None
+    root: bool = False
+
+
+def _outer(stage: str) -> _PhaseSpec:
+    return _PhaseSpec(
+        f"sync_{stage}", f"tpuft::local_sgd::{stage}",
+        histogram="tpuft_outer_sync_seconds", stage=stage,
+    )
+
+
+def _wire(stage: str, journal: Optional[str] = None) -> _PhaseSpec:
+    return _PhaseSpec(
+        journal or f"wire_{stage}", f"tpuft::wire::{stage}",
+        histogram="tpuft_wire_stage_seconds", stage=stage,
+    )
+
+
+# Every step-path phase, by the key its recording site passes to
+# :func:`phase`. The names in all three sinks are read elsewhere, letter for
+# letter: the journal's by goodput.fold_events, the health scorer's rollup
+# and scripts/fleet_trace.py; the annotations' by the benchmark's trace
+# reduction (idle gaps by host span); the histograms' by METRICS.md (rule
+# R8 reads the ``histogram=`` entries here as emission sites).
+PHASES: Dict[str, _PhaseSpec] = {
+    # control plane (manager.py)
+    "quorum": _PhaseSpec(
+        "quorum", "tpuft::manager::_client::_quorum",
+        histogram="tpuft_quorum_seconds",
+    ),
+    "start_quorum": _PhaseSpec("start_quorum", "tpuft::manager::start_quorum"),
+    "wait_quorum": _PhaseSpec(None, "tpuft::manager::wait_quorum"),
+    "pg_configure": _PhaseSpec(
+        "pg_configure", "tpuft::manager::_pg::configure",
+        histogram="tpuft_pg_configure_seconds",
+    ),
+    "should_commit": _PhaseSpec(
+        "commit_barrier", "tpuft::manager::should_commit",
+        histogram="tpuft_commit_barrier_seconds",
+    ),
+    "speculative_commit": _PhaseSpec(
+        "commit_barrier", "tpuft::manager::speculative_commit",
+        histogram="tpuft_commit_barrier_seconds",
+    ),
+    "allreduce": _PhaseSpec(None, "tpuft::manager::allreduce"),
+    "allreduce_pytree": _PhaseSpec(None, "tpuft::manager::allreduce_pytree"),
+    "allreduce_prequantized": _PhaseSpec(
+        None, "tpuft::manager::allreduce_prequantized"
+    ),
+    # FT-DDP step protocol (optim.py)
+    "optim_step": _PhaseSpec("step", "tpuft::optim::step", root=True),
+    "device_sync": _PhaseSpec(
+        "device_sync", "tpuft::optim::device_sync",
+        histogram="tpuft_device_sync_seconds",
+    ),
+    "update_dispatch": _PhaseSpec(
+        "update_dispatch", "tpuft::optim::update_dispatch",
+        histogram="tpuft_update_dispatch_seconds",
+    ),
+    "commit_wait": _PhaseSpec("commit_wait", "tpuft::optim::commit_wait"),
+    "adopt": _PhaseSpec("adopt", "tpuft::optim::adopt"),
+    "pipeline_drain": _PhaseSpec(
+        "pipeline_drain", "tpuft::optim::pipeline_drain"
+    ),
+    # streaming DiLoCo / LocalSGD (local_sgd.py)
+    "local_sgd_step": _PhaseSpec("step", "tpuft::local_sgd::step", root=True),
+    "inner_dispatch": _PhaseSpec(
+        "inner_dispatch", "tpuft::local_sgd::inner_dispatch"
+    ),
+    "prepare_sync": _PhaseSpec("prepare_sync", "tpuft::local_sgd::prepare_sync"),
+    "perform_sync": _PhaseSpec("perform_sync", "tpuft::local_sgd::perform_sync"),
+    "sync_quantize": _outer("quantize"),
+    "sync_launch": _outer("launch"),
+    "sync_wait": _outer("wait"),
+    "sync_restore": _outer("restore"),
+    "sync_commit": _outer("commit"),
+    "sync_apply_outer": _outer("apply_outer"),
+    # replica-axis wire (parallel/mesh.py, Manager.allreduce_pytree)
+    "wire_stage": _wire("stage"),
+    # ``wire_bucket`` is ddp.py's per-bucket wire time in the journal: the
+    # concatenation into buckets is ``wire_concat`` there.
+    "wire_bucket": _wire("bucket", journal="wire_concat"),
+    "wire_ring": _wire("ring"),
+    "wire_average": _wire("average"),
+    "wire_scatter": _wire("scatter"),
+}
+
+# Fields of a phase that identify WHICH step, quorum or fragment it belongs
+# to: they go to the journal event and, as keyword arguments, to the
+# annotation, so that the spans of one step share an identifier on the
+# device trace too (the profiler stores them as the event's stats; the
+# event keeps its bare name). Any other field is a journal argument only.
+_ID_FIELDS = ("step", "quorum_id", "fragment")
+
+_span_logger = logging.getLogger("torchft_tpu.trace")
+# Operator's knob (docs/protocol.md): log every annotated span's wall time.
+_LOG_SPANS = os.environ.get("TPUFT_TRACE_LOG", "") == "1"
+# The active utils.profiling.chrome_trace capture, or None; it owns the
+# event list, this module only hands it finished spans.
+_chrome_capture: Any = None
+# (TraceAnnotation, StepTraceAnnotation) once jax.profiler is imported;
+# False where it cannot be.
+_annotation_types: Any = None
+
+
+def _annotations() -> Any:
+    global _annotation_types
+    if _annotation_types is None:
+        try:
+            import jax.profiler
+
+            _annotation_types = (
+                jax.profiler.TraceAnnotation,
+                jax.profiler.StepTraceAnnotation,
+            )
+        except Exception:  # noqa: BLE001 — profiling must never break training
+            _annotation_types = False
+    return _annotation_types
+
+
+def _feed(
+    spec: _PhaseSpec, journal: Optional[TraceJournal],
+    labels: Optional[Dict[str, Any]], ids: Dict[str, Any],
+    args: Dict[str, Any], start: float, dur: float,
+) -> None:
+    """One finished phase into its histogram and its journal."""
+    if spec.histogram is not None:
+        stage = {"stage": spec.stage} if spec.stage is not None else {}
+        metrics.observe(spec.histogram, dur, **stage, **(labels or {}))
+    if journal is not None and spec.journal is not None and journal.enabled:
+        # step and quorum_id are the event's own fields (None: the journal's
+        # current ones); any other id is an argument of the event.
+        ids = dict(ids)
+        journal.record(
+            spec.journal, ph="X", dur=dur, t_mono=start,
+            step=ids.pop("step", None), quorum_id=ids.pop("quorum_id", None),
+            **ids, **args,
+        )
+
+
+class _Span:
+    """The with-block behind :func:`phase`: one clock read at entry, one at
+    exit, and the exit feeds every sink the spec names. Never swallows the
+    body's exception, and a raising body still closes all three."""
+
+    __slots__ = (
+        "_spec", "_journal", "_labels", "_ids", "_args", "_start",
+        "_annotation", "_outer_step",
+    )
+
+    def __init__(
+        self,
+        spec: _PhaseSpec,
+        journal: Optional[TraceJournal],
+        labels: Optional[Dict[str, Any]],
+        fields: Dict[str, Any],
+    ) -> None:
+        self._spec = spec
+        self._journal = (journal or current()) if spec.journal else None
+        self._labels = labels
+        # An id the caller could not give (a scripted manager's step) is
+        # left out; the journal then stamps its own current step.
+        self._ids = {
+            k: v for k in _ID_FIELDS if (v := fields.pop(k, None)) is not None
+        }
+        self._args = fields
+        self._annotation = None
+
+    def __enter__(self) -> "_Span":
+        spec = self._spec
+        # The spans that open on this thread under a root take the root's
+        # step unless they name their own: the commit may advance the
+        # journal's step on another thread while the step is still running.
+        if spec.root:
+            self._outer_step = getattr(_TLS, "step", None)
+            _TLS.step = self._ids.get("step")
+        elif "step" not in self._ids:
+            step = getattr(_TLS, "step", None)
+            if step is not None:
+                self._ids["step"] = step
+        if spec.annotation is not None:
+            types = _annotations()
+            if types:
+                try:
+                    if spec.root:
+                        # Numbered by the step it is, which for an inner step
+                        # is not the manager's (that counts committed syncs).
+                        ids = dict(self._ids)
+                        step = ids.pop("step", 0)
+                        self._annotation = types[1](
+                            spec.annotation,
+                            step_num=self._args.get("inner_step", step), **ids,
+                        )
+                    else:
+                        self._annotation = types[0](spec.annotation, **self._ids)
+                    self._annotation.__enter__()
+                except Exception:  # noqa: BLE001 — observability must not wound
+                    self._annotation = None
+        journal = self._journal
+        self._start = journal._mono() if journal is not None else time.monotonic()
+        return self
+
+    def __exit__(self, *exc: object) -> None:
+        spec, journal = self._spec, self._journal
+        start = self._start
+        dur = (journal._mono() if journal is not None else time.monotonic()) - start
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+        if spec.root:
+            _TLS.step = self._outer_step
+        _feed(spec, journal, self._labels, self._ids, self._args, start, dur)
+        if spec.annotation is not None:
+            chrome = _chrome_capture
+            if chrome is not None:
+                chrome.add_span(
+                    spec.annotation, start, dur, {**self._ids, **self._args}
+                )
+            if _LOG_SPANS:
+                _span_logger.info("%s took %.3fms", spec.annotation, dur * 1000)
+
+
+def phase(
+    name: str,
+    journal: Optional[TraceJournal] = None,
+    labels: Optional[Dict[str, Any]] = None,
+    **fields: Any,
+) -> _Span:
+    """Times the with-body as the phase ``name`` of :data:`PHASES` into the
+    histogram, the journal and the profiler annotation that table gives it.
+
+    ``journal`` defaults to this thread's :func:`current` journal (a Manager
+    passes its own, so that its quorum thread's events land in its
+    replica's timeline); ``labels`` are the histogram's labels; ``fields``
+    named ``step``, ``quorum_id`` or ``fragment`` identify the span in the
+    journal and on the annotation, any other field is a journal argument."""
+    return _Span(PHASES[name], journal, labels, fields)
+
+
+def record_phase(
+    name: str,
+    start_mono: float,
+    journal: Optional[TraceJournal] = None,
+    labels: Optional[Dict[str, Any]] = None,
+    **fields: Any,
+) -> float:
+    """Records a phase that began on another thread at ``start_mono`` (the
+    journal's monotonic clock) and ends now: histogram and journal, no
+    annotation (that belongs to the thread that waits: :func:`annotation`).
+    Returns the duration."""
+    j = journal or current()
+    dur = j._mono() - start_mono
+    _feed(PHASES[name], j, labels, fields, {}, start_mono, dur)
+    return dur
+
+
+def annotation(name: str, **ids: Any) -> Any:
+    """The bare profiler annotation of phase ``name``, for the thread that
+    waits on work :func:`record_phase` times elsewhere."""
+    return _Span(PHASES[name]._replace(journal=None, histogram=None), None, None, ids)
+
+
+# ---------------------------------------------------------------------------
+# capture: the profiler, started and stopped inside the running process
+# ---------------------------------------------------------------------------
+
+_capture_lock = threading.Lock()
+_capture: Optional[Dict[str, Any]] = None
+
+
+def _registry_totals() -> Dict[tuple, Dict[str, float]]:
+    """{(name, label items): {"sum", "count"} | {"value"}} of every histogram
+    and counter now."""
+    snap = metrics.snapshot()
+    out: Dict[tuple, Dict[str, float]] = {}
+    for name, entries in snap["histograms"].items():
+        for e in entries:
+            out[(name, tuple(sorted(e["labels"].items())))] = {
+                "sum": e["sum"], "count": e["count"],
+            }
+    for name, entries in snap["counters"].items():
+        for e in entries:
+            out[(name, tuple(sorted(e["labels"].items())))] = {"value": e["value"]}
+    return out
+
+
+def _capture_mark(name: str) -> int:
+    """One instant annotation carrying the monotonic clock's reading: the
+    same instant on the profiler's clock (the annotation's start) and on the
+    journal's (``t_mono`` seconds = ``mono_ns`` / 1e9)."""
+    mono_ns = time.monotonic_ns()
+    types = _annotations()
+    if types:
+        with types[0](name, mono_ns=mono_ns):
+            pass
+    return mono_ns
+
+
+def start_capture(
+    log_dir: str,
+    host_level: int = 2,
+    python_level: int = 0,
+    journal: Optional[TraceJournal] = None,
+) -> None:
+    """Starts a profiler session writing under ``log_dir`` (the xplane lands
+    in ``<log_dir>/plugins/profile/<time>/*.xplane.pb``) and notes where the
+    journal and the metrics registry stand. Any number of captures may
+    follow one another in a process; starting one while another runs is an
+    error. Only the process that holds the chip can trace it."""
+    global _capture
+    import jax.profiler
+
+    j = journal or current()
+    with _capture_lock:
+        if _capture is not None:
+            raise RuntimeError(
+                f"a capture into {_capture['trace_dir']} is already running; "
+                "stop_capture() it first"
+            )
+        options = jax.profiler.ProfileOptions()
+        options.host_tracer_level = host_level
+        options.python_tracer_level = python_level  # the spans, not every Python frame
+        jax.profiler.start_trace(str(log_dir), profiler_options=options)
+        _capture = {
+            "trace_dir": str(log_dir),
+            "journal": j,
+            "seq": j._last_seq,
+            "totals": _registry_totals(),
+            "begin_mono_ns": _capture_mark("tpuft::capture_begin"),
+        }
+
+
+def stop_capture() -> Dict[str, Any]:
+    """Stops the running capture and returns plain data: ``trace_dir``;
+    ``events``, the journal's own events recorded during the capture;
+    ``counters``, the growth of every histogram (``sum``, ``count``) and
+    counter (``value``) over it, ``{name: [{"labels": {...}, ...}]}`` with
+    what did not grow left out; ``clock``, the monotonic clock at the
+    ``tpuft::capture_begin`` / ``tpuft::capture_end`` annotations; and
+    ``dropped``, events of the capture the ring had already overwritten."""
+    global _capture
+    import jax.profiler
+
+    with _capture_lock:
+        if _capture is None:
+            raise RuntimeError("no capture is running")
+        cap, _capture = _capture, None
+        end_mono_ns = _capture_mark("tpuft::capture_end")
+        jax.profiler.stop_trace()
+    j: TraceJournal = cap["journal"]
+    events = [e for e in j.snapshot() if e["seq"] > cap["seq"]]
+    before = cap["totals"]
+    counters: Dict[str, List[Dict[str, Any]]] = {}
+    for (name, labels), now in _registry_totals().items():
+        was = before.get((name, labels), {})
+        growth = {k: v - was.get(k, 0.0) for k, v in now.items()}
+        if any(growth.values()):
+            counters.setdefault(name, []).append({"labels": dict(labels), **growth})
+    return {
+        "trace_dir": cap["trace_dir"],
+        "events": events,
+        "counters": counters,
+        "clock": {"begin_mono_ns": cap["begin_mono_ns"], "end_mono_ns": end_mono_ns},
+        "dropped": max(0, (j._last_seq - cap["seq"]) - len(events)),
+    }
+
+
+_compile_listener_installed = False
+
+
+def install_compile_listener() -> None:
+    """Once a process: every backend compilation becomes a journal event
+    ``compile`` (its seconds, at the compiling thread's current step), so
+    that "which step recompiled" has an answer in the program's own record."""
+    global _compile_listener_installed
+    if _compile_listener_installed:
+        return
+    try:
+        import jax.monitoring
+
+        def on_duration(event: str, seconds: float, **_: Any) -> None:
+            if event == "/jax/core/compile/backend_compile_duration":
+                current().record("compile", cat="compile", seconds=seconds)
+
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        _compile_listener_installed = True
+    except Exception:  # noqa: BLE001 — observability must not wound
+        pass
 
 
 # ---------------------------------------------------------------------------

@@ -1,5 +1,5 @@
 """Shared utilities: profiling spans, timing helpers."""
 
-from torchft_tpu.utils.profiling import trace_span, timed
+from torchft_tpu.utils.profiling import trace_span
 
-__all__ = ["trace_span", "timed"]
+__all__ = ["trace_span"]

@@ -8,7 +8,9 @@ transfer logs (http_transport.py:31-36), and its chrome-trace export loops
 active), optionally log wall time when ``TPUFT_TRACE_LOG`` is set, and —
 when a :func:`chrome_trace` capture is active — record begin/end events
 into a self-contained ``trace.json`` loadable in ``chrome://tracing`` or
-https://ui.perfetto.dev.
+https://ui.perfetto.dev. The span itself is ``tracing.phase``'s (one
+primitive for every span of the tree); :func:`trace_span` is its form for
+a span that has an annotation name and no table entry.
 """
 
 from __future__ import annotations
@@ -17,13 +19,13 @@ import json
 import logging
 import os
 import threading
-import time
 from contextlib import contextmanager
-from typing import Generator, Iterator, List, Optional
+from typing import Any, Generator, List
+
+from torchft_tpu import tracing
 
 logger = logging.getLogger("torchft_tpu.trace")
 
-_LOG_SPANS = os.environ.get("TPUFT_TRACE_LOG", "") == "1"
 
 class _ChromeCapture:
     """One active chrome-trace capture: the event list plus per-thread
@@ -66,10 +68,6 @@ class _ChromeCapture:
             self.events.append(event)
 
 
-# Active chrome-trace capture, or None.
-_CHROME: Optional[_ChromeCapture] = None
-
-
 @contextmanager
 def chrome_trace(path: str) -> Generator[None, None, None]:
     """Captures every :func:`trace_span` in the with-body as chrome-trace
@@ -78,14 +76,13 @@ def chrome_trace(path: str) -> Generator[None, None, None]:
     nest/overlap (the previous capture is restored on exit); spans still
     open on other threads when the capture ends record into the old list
     harmlessly (they are not in the written file)."""
-    global _CHROME
     capture = _ChromeCapture()
-    previous = _CHROME
-    _CHROME = capture
+    previous = tracing._chrome_capture
+    tracing._chrome_capture = capture
     try:
         yield
     finally:
-        _CHROME = previous
+        tracing._chrome_capture = previous
         with capture.lock:
             snapshot = list(capture.events)
         # Fleet-merge metadata: stamp the trace plane's replica identity
@@ -96,8 +93,6 @@ def chrome_trace(path: str) -> Generator[None, None, None]:
         # pid with an unaligned clock.
         other_data: dict = {}
         try:
-            from torchft_tpu import tracing
-
             journal = tracing.current()
             offset_ms = (
                 round(journal.clock_offset_s * 1e3, 3)
@@ -141,33 +136,14 @@ def chrome_trace(path: str) -> Generator[None, None, None]:
         )
 
 
-@contextmanager
-def trace_span(name: str, **args: "int | float | str") -> Generator[None, None, None]:
+def trace_span(name: str, **args: "int | float | str") -> Any:
     """Marks a region on the jax profiler timeline (no-op cost when no
     capture is active) and on any active :func:`chrome_trace` capture.
     ``args`` (e.g. ``step=``, ``quorum_id=``) land in the chrome event's
-    ``args`` dict so a merged kill/heal trace stays correlatable across
-    the train-loop / quorum / op-worker threads."""
-    try:
-        import jax.profiler
-
-        annotation = jax.profiler.TraceAnnotation(name)
-    except Exception:  # noqa: BLE001  — profiling must never break training
-        annotation = None
-    chrome = _CHROME
-    start = time.monotonic() if (_LOG_SPANS or chrome is not None) else 0.0
-    if annotation is not None:
-        annotation.__enter__()
-    try:
-        yield
-    finally:
-        if annotation is not None:
-            annotation.__exit__(None, None, None)
-        elapsed = time.monotonic() - start
-        if chrome is not None:
-            chrome.add_span(name, start, elapsed, args)
-        if _LOG_SPANS:
-            logger.info("%s took %.3fms", name, elapsed * 1000)
+    ``args`` dict, and the identifiers among them on the annotation, so a
+    merged kill/heal trace stays correlatable across the train-loop /
+    quorum / op-worker threads."""
+    return tracing._Span(tracing._PhaseSpec(None, name), None, None, args)
 
 
 def heal_wall_times(kill_t: "float | None", commit_times: dict) -> "dict | None":
@@ -186,13 +162,3 @@ def heal_wall_times(kill_t: "float | None", commit_times: dict) -> "dict | None"
         role = "joiner" if idx == 1 else ("survivor" if idx == 0 else f"g{idx}")
         out[role] = round(min(after) - kill_t, 3) if after else None
     return out
-
-
-@contextmanager
-def timed(name: str) -> Iterator[None]:
-    """Always-on wall-time log for transfer-sized operations."""
-    start = time.monotonic()
-    try:
-        yield
-    finally:
-        logger.info("%s took %.3fs", name, time.monotonic() - start)
