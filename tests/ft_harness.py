@@ -584,6 +584,23 @@ def zero_ddp_train_loop(
 # ---------------------------------------------------------------------------
 
 
+def diloco_live_state(algo: Any) -> Dict[str, Any]:
+    """DiLoCo's registered state read off the live attributes, keyed as it is
+    registered with the manager: what a capture taken now must equal."""
+    user = {
+        "diloco_inner": {
+            "leaves": list(algo._leaves),
+            "opt_state": algo.inner_opt_state,
+        }
+    }
+    for frag in algo._fragments:
+        user[frag._key] = {
+            "original_parameters": list(frag.backup),
+            "outer_optimizer": frag.outer_opt_state,
+        }
+    return user
+
+
 def diloco_train_loop(
     runner: Runner,
     rank: int,
@@ -594,9 +611,12 @@ def diloco_train_loop(
     n_fragments: int = 2,
     fragment_sync_delay: int = 0,
     should_quantize: bool = False,
+    on_algo: Optional[Callable[[Runner, Manager, Any], None]] = None,
 ) -> Dict[str, Any]:
     """Streaming DiLoCo across replica groups; returns the per-fragment
-    global state for cross-group equality assertions."""
+    global state for cross-group equality assertions. ``on_algo(runner,
+    manager, algo)`` runs once per incarnation before the first step (a
+    test's place to watch what a heal captures and what it applies)."""
     from torchft_tpu.local_sgd import DiLoCo
 
     pg = FakeProcessGroupWrapper(ProcessGroupTCP(timeout=10.0))
@@ -626,6 +646,8 @@ def diloco_train_loop(
             fragment_sync_delay=fragment_sync_delay,
             should_quantize=should_quantize,
         )
+        if on_algo is not None:
+            on_algo(runner, manager, algo)
         inner_iter = 0
         failed_syncs = 0  # outer steps lost (north star: <= 1 per kill)
         while manager.current_step() < num_syncs:

@@ -12,6 +12,7 @@ import numpy as np
 import optax
 import pytest
 
+from ft_harness import diloco_live_state
 from test_manager import make_manager, make_quorum
 
 from torchft_tpu.local_sgd import DiLoCo, LocalSGD
@@ -509,3 +510,205 @@ def test_local_sgd_sync_is_one_perform_sync_span() -> None:
     spans = [e for e in journal.snapshot() if e["ph"] == "X"]
     (sync,) = [e for e in spans if e["name"] == "perform_sync"]
     assert any(_inside(e, sync) for e in spans if e["name"] == "commit_barrier")
+
+
+# -- the state is updated in place (donation) and every buffer has one owner --
+
+
+def _arrays(tree):
+    return [x for x in jax.tree_util.tree_leaves(tree) if isinstance(x, jax.Array)]
+
+
+def _host(tree):
+    """Values by COPY: ``np.asarray`` of a CPU jax.Array is a view that pins
+    its buffer, and a pinned buffer is not donated."""
+    return jax.tree_util.tree_map(
+        lambda x: np.array(x, copy=True) if hasattr(x, "shape") else x, tree
+    )
+
+
+def _assert_state_alive(algo, payloads=()) -> None:
+    """Nothing the algorithm still needs was deleted by a donated program,
+    and no fragment's backup shares a buffer with the live leaves."""
+    held = {
+        "leaves": algo._leaves,
+        "inner_opt_state": algo.inner_opt_state,
+        "payloads": list(payloads),
+    }
+    for frag in algo._fragments:
+        held[f"backup{frag._fragment_id}"] = frag.backup
+        held[f"outer{frag._fragment_id}"] = frag.outer_opt_state
+    for name, tree in held.items():
+        for x in _arrays(tree):
+            assert not x.is_deleted(), name
+    live = {id(x) for x in algo._leaves}
+    for frag in algo._fragments:
+        assert not live & {id(b) for b in frag.backup}, "a leaf IS a backup array"
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["step_grads", "make_step_fn"])
+@pytest.mark.parametrize("quantize", [True, False], ids=["quantized", "host"])
+def test_diloco_inner_step_is_in_place_and_owns_what_it_donates(quantize, fused) -> None:
+    """Every inner step deletes the leaves and inner state it replaces, and
+    never anything else: not the caller's arrays, a fragment's backup, an
+    outer state or a payload in flight: through construction, a committed
+    sync, a failed commit and the steps after it, an allreduce that gave
+    nothing, and a heal. The values are those of DiLoCo written out in
+    numpy (outer SGD at lr 1: the new global is the local state at launch)."""
+    from torchft_tpu.work import _DummyWork
+
+    lr, sync_every, delay = 0.1, 2, 1
+    # sync index -> what happens to it
+    script = {1: "refused", 3: "nothing_averaged"}
+
+    manager = scripted_manager(use_async_quorum=False)
+    syncs = [0]
+
+    def should_commit(rank, step, vote, timeout):
+        return vote and script.get(syncs[0]) != "refused"
+
+    manager._client.should_commit.side_effect = should_commit
+    payloads = []
+    for name in ("allreduce_prequantized", "allreduce_pytree"):
+        real = getattr(manager, name)
+
+        def launch(*args, _real=real):
+            if script.get(syncs[0]) == "nothing_averaged":
+                return _DummyWork(None)
+            payloads[:] = _arrays(args)
+            return _real(*args)
+
+        setattr(manager, name, launch)
+
+    p0 = make_params()
+    algo = DiLoCo(
+        manager, optax.sgd(lr), optax.sgd(1.0), p0, sync_every=sync_every,
+        fragment_sync_delay=delay, should_quantize=quantize,
+    )
+    for x in _arrays(p0):
+        assert not x.is_deleted()
+    _assert_state_alive(algo)
+
+    if fused:
+        # The gradient of sum(p * g) in p is g: the same updates as step(grads).
+        step_fn = algo.make_step_fn(
+            lambda params, g: sum(
+                (params[k] * g[k]).sum() for k in sorted(params)
+            )
+        )
+        inner_step = lambda g: step_fn(g)[1]  # noqa: E731
+    else:
+        inner_step = algo.step
+
+    keys = sorted(p0)  # the flatten order of a dict
+    local = {k: np.array(p0[k]) for k in keys}
+    backup = {k: v.copy() for k, v in local.items()}
+    pseudograd, local_step = None, 0
+    # Where the codec rounds (fp8: 3 bits of mantissa of a block's largest
+    # pseudogradient, which is at most 0.06 here), and where nothing does.
+    codec_tol = dict(rtol=0, atol=4e-3) if quantize else dict(rtol=1e-6, atol=0)
+
+    def check_values(tol) -> None:
+        for k, leaf in zip(keys, algo._leaves):
+            np.testing.assert_allclose(_host(leaf), local[k], err_msg=k, **tol)
+        for k, b in zip(keys, algo._fragments[0].backup):
+            np.testing.assert_allclose(_host(b), backup[k], err_msg=k, **tol)
+
+    def heal() -> None:
+        """What a joiner's should_commit applies: the state as the wire
+        carries it, host arrays."""
+        state = _host(manager._manager_state_dict()["user"])
+        algo._load_inner(state["diloco_inner"])
+        algo._fragments[0]._load_state(state["StreamingDiLoCoFragment_0"])
+
+    tol = dict(rtol=1e-6, atol=0)
+    for step in range(12):
+        if step == 10:
+            heal()
+            _assert_state_alive(algo)
+            check_values(tol)
+        before = _arrays(algo._leaves) + _arrays(algo.inner_opt_state)
+        grads = fixed_grads(step)
+        committed = inner_step(grads)
+        for x in before:
+            assert x.is_deleted(), f"step {step}: the old state was kept"
+        _assert_state_alive(algo, payloads)
+        for x in _arrays(p0):
+            assert not x.is_deleted(), "the caller's array was donated"
+
+        for k in keys:
+            local[k] = local[k] - np.float32(lr) * np.asarray(grads[k])
+        local_step += 1
+        if local_step == sync_every - delay:
+            pseudograd = {k: backup[k] - local[k] for k in keys}
+        if local_step == sync_every:
+            outcome = script.get(syncs[0], "committed")
+            assert committed is (outcome == "committed"), (step, outcome)
+            if outcome == "committed":
+                backup = {k: backup[k] - pseudograd[k] for k in keys}
+                tol = codec_tol
+            local = {k: v.copy() for k, v in backup.items()}
+            if outcome != "committed" and quantize:
+                # The reset is a copy: bitwise the backup, in other buffers.
+                for leaf, b in zip(algo._leaves, algo._fragments[0].backup):
+                    assert _host(leaf).tobytes() == _host(b).tobytes()
+            syncs[0] += 1
+            local_step = 0
+        else:
+            assert not committed
+        check_values(tol)
+    assert syncs[0] == 6
+
+
+@pytest.mark.parametrize("quantize", [True, False], ids=["quantized", "host"])
+def test_state_capture_survives_the_steps_after_it(quantize) -> None:
+    """A capture of the registered state is a device copy, not a reference:
+    taken between two inner steps it is still readable, and bitwise the
+    state at capture, after the delay window's steps have deleted the
+    arrays it was taken from. Each capture is counted; a run without one
+    counts nothing."""
+    from torchft_tpu import metrics
+
+    def copies(**label) -> float:
+        return metrics.counter_total("tpuft_state_snapshot_copies_total", **label)
+
+    def copied_bytes(**label) -> float:
+        return metrics.counter_total("tpuft_state_snapshot_copy_bytes_total", **label)
+
+    delay = 1
+    manager = scripted_manager(use_async_quorum=False)
+    algo = DiLoCo(
+        manager, optax.adam(0.1), optax.sgd(0.7, momentum=0.9, nesterov=True),
+        make_params(), sync_every=4, n_fragments=2, fragment_sync_delay=delay,
+        should_quantize=quantize,
+    )
+    before = copies(), copied_bytes()
+    before_inner = copies(key="diloco_inner"), copied_bytes(key="diloco_inner")
+    for step in range(9):  # four committed fragment syncs, no capture
+        algo.step(fixed_grads(step))
+    assert manager.current_step() == 4
+    assert (copies(), copied_bytes()) == before
+
+    captures = []
+    for step in range(9, 9 + 2 * (delay + 2)):
+        if step in (9, 9 + delay + 2):
+            at_capture = _host(diloco_live_state(algo))
+            captures.append((manager._manager_state_dict()["user"], at_capture))
+        algo.step(fixed_grads(step))
+    assert manager.current_step() >= 6  # fragments synced after each capture
+    for capture, at_capture in captures:
+        assert set(capture) >= set(at_capture)  # and the scripted manager's own
+        got = jax.tree_util.tree_leaves({k: capture[k] for k in at_capture})
+        want = jax.tree_util.tree_leaves(at_capture)
+        assert len(got) == len(want) > 0
+        for x, y in zip(got, want):
+            if isinstance(x, jax.Array):
+                assert not x.is_deleted()
+            assert np.asarray(x).tobytes() == np.asarray(y).tobytes()
+    inner_bytes = sum(x.nbytes for x in _arrays(captures[0][0]["diloco_inner"]))
+    assert copies(key="diloco_inner") - before_inner[0] == len(captures)
+    assert copied_bytes(key="diloco_inner") - before_inner[1] == 2 * inner_bytes
+    # The device pipeline's fragments copy their backups and outer state
+    # too; the host pipeline's are numpy copies already.
+    per_capture = 1 + (len(algo._fragments) if quantize else 0)
+    assert copies() - before[0] == per_capture * len(captures)

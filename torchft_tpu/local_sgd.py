@@ -27,7 +27,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 import jax
 import numpy as np
 
-from torchft_tpu import tracing
+from torchft_tpu import metrics, tracing
 from torchft_tpu.manager import Manager
 from torchft_tpu.optim import _trace_of
 from torchft_tpu.utils import netem
@@ -59,6 +59,35 @@ def cross_region_fleet() -> bool:
     DDP inside a region never leaves the cheap links)."""
     topo = netem.describe_topology()
     return bool(topo.get("configured")) and not topo.get("single_region", True)
+
+
+def _device_copy(tree: Any) -> Any:
+    """``tree`` with every ``jax.Array`` in it copied into a buffer of its
+    own (same sharding; anything else passes through). DiLoCo's programs
+    donate the state they replace, so a buffer that something else must
+    still read is copied before the next program is dispatched: the copy
+    is queued on the device ahead of that program and reads the old
+    values. One small program per leaf, so that leaves on different
+    device sets need no common mesh."""
+    import jax.numpy as jnp
+
+    return jax.tree_util.tree_map(
+        lambda x: jnp.copy(x) if isinstance(x, jax.Array) else x, tree
+    )
+
+
+def _snapshot(key: str, tree: Any) -> Any:
+    """A capture of registered state ``key`` that survives later steps: a
+    device copy (:func:`_device_copy`), counted. Holding a reference is
+    no snapshot here, because the next inner step deletes what it
+    refers to."""
+    nbytes = sum(
+        x.nbytes for x in jax.tree_util.tree_leaves(tree) if isinstance(x, jax.Array)
+    )
+    if nbytes:
+        metrics.inc("tpuft_state_snapshot_copies_total", key=key)
+        metrics.inc("tpuft_state_snapshot_copy_bytes_total", nbytes, key=key)
+    return _device_copy(tree)
 
 
 def _to_device_like(host: np.ndarray, like: Any) -> Any:
@@ -270,8 +299,11 @@ class _Fragment:
         self._outer_tx = outer_tx
         self._should_quantize = should_quantize
         self._alpha = fragment_update_alpha
+        self._key = f"StreamingDiLoCoFragment_{fragment_id}"
         if should_quantize:
             # Device-resident backup (HBM): no host copy in the hot path.
+            # These are the caller's own arrays, held and never donated;
+            # the live leaves are the copies (DiLoCo.__init__).
             self.backup: List[Any] = [jnp.asarray(x) for x in initial_leaves]
         else:
             # Host backup (the "CPU-pinned" analogue of the reference).
@@ -298,9 +330,7 @@ class _Fragment:
             # outer step is fused into _jit_apply_outer below.
             self._jit_outer_update = make_jit_update(outer_tx)
         self._work: Optional[Work] = None
-        manager.register_state_dict_fn(
-            f"StreamingDiLoCoFragment_{fragment_id}", self._load_state, self._save_state
-        )
+        manager.register_state_dict_fn(self._key, self._load_state, self._save_state)
 
         if should_quantize:
             self._build_device_pipeline()
@@ -340,20 +370,32 @@ class _Fragment:
             return new_backup, merged, new_state
 
         self._jit_quantize_pg = jax.jit(quantize_pseudograd)
-        self._jit_apply_outer = jax.jit(apply_outer)
+        # The fragment's local leaves (dead after the merge) and the outer
+        # state are updated in place: their buffers become the merged
+        # leaves, the new backup's and the new outer state's. The old
+        # backup is not given away: until a fragment's first sync it is
+        # the caller's array.
+        self._jit_apply_outer = jax.jit(apply_outer, donate_argnums=(3, 4))
 
     def _save_state(self) -> Dict[str, Any]:
-        # Device backups are handed over as-is: the checkpoint transport
-        # host-converts every leaf at staging time (ShardedLeaf capture for
-        # non-fully-addressable arrays — an eager np.array here would RAISE
-        # on multi-host shardings). Host backups are snapshotted since the
-        # list is rebound, never mutated, on sync.
+        # Device state is handed over as a device copy, not as references:
+        # the caller stages it after releasing the read lock, and the next
+        # outer step deletes the outer state it replaces. (The checkpoint
+        # transport host-converts every leaf at staging time: ShardedLeaf
+        # capture for non-fully-addressable arrays — an eager np.array here
+        # would RAISE on multi-host shardings.) Host backups are snapshotted
+        # since the list is rebound, never mutated, on sync; the host
+        # pipeline's outer step donates nothing.
+        if self._should_quantize:
+            return _snapshot(
+                self._key,
+                {
+                    "original_parameters": list(self.backup),
+                    "outer_optimizer": self.outer_opt_state,
+                },
+            )
         return {
-            "original_parameters": (
-                list(self.backup)
-                if self._should_quantize
-                else [np.array(b) for b in self.backup]
-            ),
+            "original_parameters": [np.array(b) for b in self.backup],
             "outer_optimizer": self.outer_opt_state,
         }
 
@@ -440,17 +482,44 @@ class _Fragment:
             self._work = None
             with tracing.phase("sync_restore", trace, **ids):
                 local_copy = self._restore_globals(local_leaves)
-            # The commit barrier must run unlocked: it can apply a healing
-            # state dict and peers' serve threads need the read lock meanwhile.
-            with tracing.phase("sync_commit", trace, **ids):
-                committed = self._manager.should_commit()
-            if not committed:
-                return False
-            if averaged is None:  # quantized-path allreduce error (already reported)
-                return False
-            with tracing.phase("sync_apply_outer", trace, **ids):
-                self._apply_outer(averaged, local_copy, local_leaves)
-            return True
+            applied = False
+            try:
+                # The commit barrier must run unlocked: it can apply a healing
+                # state dict and peers' serve threads need the read lock meanwhile.
+                with tracing.phase("sync_commit", trace, **ids):
+                    committed = self._manager.should_commit()
+                # averaged is None: quantized-path allreduce error (already reported)
+                if committed and averaged is not None:
+                    with tracing.phase("sync_apply_outer", trace, **ids):
+                        self._apply_outer(averaged, local_copy, local_leaves)
+                    applied = True
+            finally:
+                if not applied:
+                    self._disown_backup(local_leaves)
+            return applied
+
+    def _disown_backup(self, local_leaves: List[Any]) -> None:
+        """After a sync that applied nothing: the leaves that
+        ``_restore_globals`` bound to the device backup's own arrays become
+        copies of them, so that the next inner step, which deletes the
+        leaves it replaces, cannot delete the backup. One device copy of
+        the fragment, paid only here (a committed sync replaces the leaves
+        with the merged ones). A heal inside the barrier has already put
+        new arrays in both places: nothing is shared, nothing is copied."""
+        shared = [
+            (slot, i)
+            for slot, i in enumerate(self.leaf_indices)
+            if local_leaves[i] is self.backup[slot]
+        ]
+        if not shared:
+            return
+        copies = _snapshot(self._key, [self.backup[slot] for slot, _ in shared])
+        self._manager.disallow_state_dict_read()
+        try:
+            for (_, i), copy in zip(shared, copies):
+                local_leaves[i] = copy
+        finally:
+            self._manager.allow_state_dict_read()
 
     def _restore_globals(self, local_leaves: List[Any]) -> List[Any]:
         """Copies this fragment's local leaves aside and rebinds them to the
@@ -543,12 +612,42 @@ class _Fragment:
 class DiLoCo:
     """(Streaming) DiLoCo over the fault-tolerant replica axis.
 
+    **The state is updated in place.** Every device program that replaces
+    state is given the state it replaces (``jax.jit`` donation): the inner
+    step its leaves and inner optimizer state, the quantized outer step the
+    fragment's local leaves and outer optimizer state. So there is one copy
+    of (params, inner state) on the device, not three, and the host runs a
+    step ahead of the device as in the plain train step. What follows:
+
+    - an array read from :attr:`params` (or ``inner_opt_state``) is valid
+      until the next :meth:`step` / ``make_step_fn`` call and is DELETED by
+      it, as with any donated ``jax.jit``. Read :attr:`params` afresh after
+      every step; ``jnp.copy`` what must outlive one;
+    - nothing is ever rolled back to the old inner state: a failed fragment
+      sync resets the fragment to its backup, a separate tree, by a device
+      copy of that one fragment;
+    - a capture of the registered state (heal donor, publisher, checkpoint:
+      whatever calls the manager's state-dict functions) is a device copy
+      made under the read lock, not a reference: one more copy of the state
+      in HBM for as long as the caller holds the capture, nothing otherwise.
+      ``tpuft_state_snapshot_copies_total`` and
+      ``tpuft_state_snapshot_copy_bytes_total`` (label ``key``: the
+      registered key) count every such copy and the failed sync's; a
+      steady window without heals reads 0. A joiner healed at quorum step
+      S still receives the donor's state as of S, bitwise, whatever the
+      donor has stepped since.
+
     Args:
         manager: must use synchronous quorum (``use_async_quorum=False``).
         inner_tx / outer_tx: optax transforms for the local and global steps.
             ``outer_tx`` may be a list, one per fragment. The canonical outer
             optimizer is SGD with Nesterov momentum.
-        params: initial parameters (owned by this object, like Optimizer).
+        params: initial parameters. The live leaves are device COPIES of
+            them, made here: the caller's arrays are never donated. The
+            quantized pipeline's device backups ARE the caller's arrays
+            until each fragment's first committed sync replaces them (they
+            are read, never given away): leave them alive and do not
+            donate them elsewhere. The host pipeline copies them to numpy.
         sync_every: inner steps per full round of fragment syncs; must be a
             multiple of ``n_fragments``.
         n_fragments: number of streaming fragments (leaf-partitioned).
@@ -616,8 +715,13 @@ class DiLoCo:
         self._inner_steps = 0
 
         leaves, self._treedef = jax.tree_util.tree_flatten(params)
-        self._leaves = list(leaves)
-        self.inner_opt_state = inner_tx.init(params)
+        # The inner step deletes the leaves and the inner state it replaces
+        # (donation), so both are this object's own buffers from the start:
+        # the leaves are copies of the caller's arrays, the inner state is
+        # made from the copies. The caller's arrays are never donated; the
+        # device backups hold them as they are.
+        self._leaves = _device_copy(list(leaves))
+        self.inner_opt_state = inner_tx.init(self.params)
         manager.register_state_dict_fn(
             "diloco_inner", self._load_inner, self._save_inner
         )
@@ -626,7 +730,7 @@ class DiLoCo:
 
         # One fused dispatch per inner step; everything else in the inner
         # loop is pure python bookkeeping.
-        self._jit_update = make_jit_update(inner_tx)
+        self._jit_update = make_jit_update(inner_tx, donate_state=True)
 
         if fragment_fn is not None:
             partitions = fragment_fn(len(self._leaves))
@@ -645,7 +749,7 @@ class DiLoCo:
                 i,
                 part,
                 outer_txs[i],
-                [self._leaves[j] for j in part],
+                [leaves[j] for j in part],
                 should_quantize,
                 fragment_update_alpha,
             )
@@ -656,10 +760,20 @@ class DiLoCo:
 
     @property
     def params(self) -> Any:
+        """The live parameters. The arrays are valid until the next inner
+        step, which deletes them (the update is in place): read them
+        afresh after every step, and copy what must outlive one."""
         return jax.tree_util.tree_unflatten(self._treedef, self._leaves)
 
     def _save_inner(self) -> Dict[str, Any]:
-        return {"leaves": list(self._leaves), "opt_state": self.inner_opt_state}
+        # Called under the state-dict read lock; the caller stages the
+        # result after it has released the lock, while inner steps go on
+        # and delete the arrays they replace. So the capture is a device
+        # copy, dispatched here and therefore ahead of the next step.
+        return _snapshot(
+            "diloco_inner",
+            {"leaves": list(self._leaves), "opt_state": self.inner_opt_state},
+        )
 
     # tpuft: allow(lock-discipline): heal apply — runs under the state-dict writer taken by Manager._apply_pending_state_dict
     def _load_inner(self, state: Dict[str, Any]) -> None:
@@ -667,7 +781,10 @@ class DiLoCo:
         # _restore_leaf_like): a healed joiner must end up with the same
         # partitioning the donor computes with, or their jitted programs
         # diverge by an ulp. Multi-host donor captures (ShardedLeaf)
-        # reassemble against the current leaves' shardings.
+        # reassemble against the current leaves' shardings. Every restored
+        # leaf comes through the host into a buffer of its own, so the
+        # leaves share none with the restored backups (_Fragment._load_state)
+        # and the next inner step may delete them.
         old = self._leaves
         new = state["leaves"]
         if len(old) != len(new):
@@ -742,7 +859,11 @@ class DiLoCo:
             new_params = optax.apply_updates(params, updates)
             return jax.tree_util.tree_flatten(new_params)[0], new_state, loss
 
-        fused_jit = jax.jit(fused)
+        # In place: the leaves and the inner state the step replaces are
+        # given to it, as in the plain train step. An inner step is never
+        # rolled back (a failed fragment sync goes back to the fragment's
+        # backup, another tree), so nothing needs the old state.
+        fused_jit = jax.jit(fused, donate_argnums=(0, 1))
 
         def step(*batch: Any):
             with self._step_span():

@@ -188,18 +188,21 @@ def make_jit_fused_step(tx: Any, loss_fn: Any, num_microbatches: int = 1):
     return jax.jit(_fused)
 
 
-def make_jit_update(tx: Any):
+def make_jit_update(tx: Any, donate_state: bool = False):
     """One fused-dispatch optax update: (grads, opt_state, params) ->
     (new_params, new_opt_state). Shared by Optimizer/LocalSGD/DiLoCo —
     unjitted optax updates issue hundreds of tiny device ops, which dominates
-    on high-latency device links."""
+    on high-latency device links. ``donate_state`` gives ``opt_state`` and
+    ``params`` to the program (the update is in place and both inputs are
+    deleted): only for a caller that owns both and never needs the old
+    state back, as DiLoCo's inner step."""
     import optax
 
     def _update(grads: Any, opt_state: Any, params: Any):
         updates, new_state = tx.update(grads, opt_state, params)
         return optax.apply_updates(params, updates), new_state
 
-    return jax.jit(_update)
+    return jax.jit(_update, donate_argnums=(1, 2) if donate_state else ())
 
 
 def make_jit_shard_update(tx: Any):
