@@ -1,7 +1,9 @@
 """Operations and bytes the algorithms NEED, computed from shapes.
 
 Kept with the benchmark so that no PR that claims a gain can change how its
-own utilisation is counted.
+own utilisation is counted. What belongs to one architecture (its parameter
+counts, the operations a trained token requires) is in its file under
+``architectures/``; here is what several share.
 """
 
 from __future__ import annotations
@@ -9,39 +11,17 @@ from __future__ import annotations
 from typing import Any, Dict
 
 
-def parameter_counts(config: Dict[str, Any]) -> Dict[str, int]:
-    """Parameters of the model as it is run (depth cut included)."""
-    d, ffn = config["hidden_size"], config["intermediate_size"]
-    heads, kv, hd = (
-        config["num_attention_heads"], config["num_key_value_heads"], config["head_dim"],
-    )
-    layers, vocab = config["num_hidden_layers"], config["vocab_size"]
-    attention = d * heads * hd + 2 * d * kv * hd + heads * hd * d
-    mlp = 3 * d * ffn
-    norms = 2 * d
-    embedding = vocab * d
-    head = 0 if config.get("tie_word_embeddings") else vocab * d
-    return {
-        "per_layer": attention + mlp + norms,
-        "embedding": embedding,
-        "head": head,
-        "total": layers * (attention + mlp + norms) + embedding + head + d,
-        # What a matrix multiplication touches every token: the embedding
-        # table is a gather, the head (tied or not) is a matmul.
-        "matmul": layers * (attention + mlp) + vocab * d,
-    }
-
-
-def train_flops_per_token(config: Dict[str, Any], seq: int) -> float:
-    """Forward + backward operations one trained token requires:
-    6 * N_matmul + 12 * L * d * s, the PaLM convention (attention scores and
-    values counted over the whole sequence, not the causal half: the flash
-    kernels skip the masked half, so the attention term, 5.5% of the total
-    here, over-counts the work they need by up to a factor of two).
-    Recomputation under remat is not counted."""
-    counts = parameter_counts(config)
-    attention = 12 * config["num_hidden_layers"] * config["hidden_size"] * seq
-    return 6.0 * counts["matmul"] + attention
+def flash_attention_flops(config: Dict[str, Any], batch: int, seq: int) -> float:
+    """Operations causal flash attention needs for ONE training step of
+    ``batch`` sequences of ``seq`` positions. A matmul of the scores' shape
+    (s x s x head_dim, every head) is 2 * s^2 * head_dim * heads operations,
+    half of them under the causal mask. Forward two (q k^T, p v); backward
+    five: the scores once more, which the algorithm keeps nowhere and cannot
+    avoid recomputing, then dv = p^T do, dp = do v^T, dq = ds k, dk = ds^T q.
+    What the two backward kernels recompute beyond that one, and a forward
+    repeated under remat, is time and not need."""
+    per_matmul = seq * seq * config["head_dim"] * config["num_attention_heads"]
+    return 7.0 * per_matmul * config["num_hidden_layers"] * batch
 
 
 def fp8_codec_bytes(elements: int, block: int = 256) -> Dict[str, int]:

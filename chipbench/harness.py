@@ -19,9 +19,9 @@ import tempfile
 import threading
 import time
 from pathlib import Path
+from types import ModuleType
 from typing import Any, Callable, Dict, List, Optional
 
-from chipbench import flops
 from chipbench.spans import SpanLog
 from chipbench.window import run_window
 
@@ -330,7 +330,8 @@ def window_checks(losses, compiled_inside: int) -> List[str]:
 
 
 def reference_losses(system, params, group: int = 0, groups: int = 1) -> Dict[str, Any]:
-    """Float32 losses of ``group``'s first two batches (chipbench/reference.py):
+    """Float32 losses of ``group``'s first two batches (chipbench/reference.py
+    around the architecture's ``sequence_loss``):
     ``first`` under ``params``; ``second_without_update``; and ``second``
     after one reference AdamW step on the mean gradient over the first batches
     of a set of groups, one value for every set that can have taken part in
@@ -344,8 +345,8 @@ def reference_losses(system, params, group: int = 0, groups: int = 1) -> Dict[st
 
     if groups not in (1, 2):
         raise ValueError("the reference's update is written for one or two groups")
-    loss = reference.make_loss(system.config)
-    loss_after = reference.make_loss_after_first_update(system.config)
+    loss = reference.make_loss(system.architecture, system.config)
+    loss_after = reference.make_loss_after_first_update(system.architecture, system.config)
     then = system.tokens(1, group)
     out: Dict[str, Any] = {
         "first": float(loss(params, system.tokens(0, group))),
@@ -464,11 +465,12 @@ class Run:
     """One run's arguments and shared state, handed to the job."""
 
     def __init__(
-        self, cell: Dict[str, Any], config: Dict[str, Any],
+        self, cell: Dict[str, Any], config: Dict[str, Any], architecture: ModuleType,
         traffic: Dict[str, Any], seed: int, seconds: float, trace: bool,
         started: float, rehearsal: bool, out_dir: Optional[Path],
     ) -> None:
         self.cell, self.config, self.traffic = cell, config, traffic
+        self.architecture = architecture  # the file the config's model_type names
         self.seed, self.seconds, self.trace = seed, seconds, trace
         self.started, self.rehearsal, self.out_dir = started, rehearsal, out_dir
         self.chips = int(cell["chips"])
@@ -494,7 +496,7 @@ def run_one_process(run: Run, make_job: Callable[..., Any]) -> Dict[str, Any]:
     ledger = CompileLedger()
     spans = SpanLog()
     traffic = run.traffic
-    system = System(run.config, traffic, run.seed)
+    system = System(run.config, run.architecture, traffic, run.seed)
     say(f"device: {devices[0].platform} {devices[0].device_kind} x{len(devices)}; cache {cache_dir}")
 
     params = system.init_params()
@@ -578,8 +580,12 @@ def run_one_process(run: Run, make_job: Callable[..., Any]) -> Dict[str, Any]:
             "memory": {k: device.pop(k) for k in ("bytes_limit", "samples", "sample_seconds")},
             "steps_per_unit": steps_per_unit,
             "trace": trace,
-            "flops_per_token": flops.train_flops_per_token(run.config, system.seq),
+            "flops_per_token": run.architecture.train_flops_per_token(run.config, system.seq),
             "peaks": None if run.rehearsal else peaks_for(devices[0].device_kind),
+            # For the readers that count what a kernel needs from the shapes.
+            "config": run.config,
+            "batch": system.batch,
+            "seq": system.seq,
         }
         obs.update(job.observations())
         say(

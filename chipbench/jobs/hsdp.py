@@ -26,7 +26,7 @@ import traceback
 from pathlib import Path
 from typing import Any, Dict, List
 
-from chipbench import flops, harness
+from chipbench import harness
 
 GROUPS = 2
 
@@ -204,7 +204,7 @@ def _group_main(run, out_dir: Path, group: int) -> None:
     harness.enable_compile_cache()
     ledger = harness.CompileLedger()
     spans = SpanLog()
-    system = System(run.config, run.traffic, run.seed)
+    system = System(run.config, run.architecture, run.traffic, run.seed)
     say(f"{devices[0].platform} {devices[0].device_kind} x{len(devices)}, "
         f"TPU_VISIBLE_CHIPS={os.environ.get('TPU_VISIBLE_CHIPS')}")
 
@@ -331,7 +331,7 @@ def _group_main(run, out_dir: Path, group: int) -> None:
                 "step_ends": [t - fleet_open for t in step_ends[warm_steps:]],
                 "wire_bytes_per_step": wire_bytes[0],
                 "trace": trace,
-                "flops_per_token": flops.train_flops_per_token(run.config, system.seq),
+                "flops_per_token": run.architecture.train_flops_per_token(run.config, system.seq),
                 "peaks": None if run.rehearsal else harness.peaks_for(devices[0].device_kind),
             },
         }

@@ -9,12 +9,16 @@ import re
 CODEC_PROGRAM = re.compile(r"quantize_pseudograd|apply_outer")
 
 
+def flash_seconds(trace) -> float:
+    return sum(
+        s for module, rows in trace["kernels"].items()
+        if not CODEC_PROGRAM.search(module) for _, s in rows
+    )
+
+
 def read(obs):
     trace = obs.get("trace")
     if not trace or not trace.get("busy_s"):
         return None
-    seconds = sum(
-        s for module, rows in trace["kernels"].items()
-        if not CODEC_PROGRAM.search(module) for _, s in rows
-    )
+    seconds = flash_seconds(trace)
     return 100.0 * seconds / trace["busy_s"] if seconds else None

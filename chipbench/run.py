@@ -67,8 +67,14 @@ def main(argv=None) -> int:
         config = {**config, **overlay.get("config", {})}
         config["run"] = {**config["run"], **overlay.get("run", {})}
         traffic = {**traffic, **overlay.get("traffic", {}).get(cell["traffic"], {})}
+        from chipbench import reference
+
+        # A toy sequence must still be several of the reference's blocks.
+        for constant, value in overlay.get("reference", {}).items():
+            setattr(reference, constant, value)
     run = harness.Run(
-        cell, config, traffic, args.seed, args.seconds, bool(args.trace),
+        cell, config, bench.architecture(config["model_type"]), traffic,
+        args.seed, args.seconds, bool(args.trace),
         args.started if args.started is not None else started,
         args.rehearse, args.out,
     )

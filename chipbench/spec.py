@@ -5,7 +5,9 @@ per-layer metric is a file of its own, found by the name BENCHMARK.json gives
 it. A later PR adds a cell or a metric by adding files and entries, and edits
 no file that is here:
 
-    configuration  <dir>/configs/<config>.json
+    configuration  <dir>/configs/<config>.json    (names its ``model_type``)
+    architecture   <dir>/architectures/<model_type>.py  (the program's model,
+                   its float32 reference and its operation count)
     traffic mix    <dir>/traffic/<traffic>.json   (names its job)
     job            <dir>/jobs/<job>.py
     layer metric   <dir>/layer_metrics/<name>.py  (a module with ``read(obs)``)
@@ -70,6 +72,10 @@ class Benchmark:
             if entry["name"] == name:
                 return json.loads((self.root / entry["file"]).read_text())
         raise SpecError(f"configuration {name!r} is not in BENCHMARK.json")
+
+    def architecture(self, model_type: str) -> ModuleType:
+        """The module that is all the benchmark knows of one block."""
+        return load_module(self.find("architectures", f"{model_type}.py"))
 
     def traffic(self, name: str) -> Dict[str, Any]:
         return json.loads(self.find("traffic", f"{name}.json").read_text())
@@ -163,6 +169,12 @@ def problems(bench: Benchmark) -> List[str]:
                 bad(f"configuration {c['name']}: reduced key {key!r} is a width or misnamed")
         if not any(w["config"] == c["name"] for w in data["workloads"]):
             bad(f"configuration {c['name']} is used by no cell")
+        if path.is_file():
+            model_type = json.loads(path.read_text()).get("model_type")
+            try:
+                bench.find("architectures", f"{model_type}.py")
+            except SpecError as e:
+                bad(f"configuration {c['name']}: model_type {model_type!r}: {e}")
 
     pairs = set()
     for w in data["workloads"]:

@@ -6,8 +6,9 @@ later PRs add to it without editing it). Alone:
 
 They guard the yardstick, not the program: BENCHMARK.json against its
 contract, the whole-unit window, the trace reduction against a recorded
-trace, the float32 reference against models/llama.py, discovery of new cells
-and metrics by files alone, and a rehearsal of every job at toy size.
+trace, the float32 reference against models/llama.py (and its blocked form
+against its unblocked one), discovery of new cells, metrics and architectures
+by files alone, and a rehearsal of every job at toy size.
 """
 
 from __future__ import annotations
@@ -50,6 +51,20 @@ def copy_with_parked_cell(to: Path) -> Path:
         data[key] += parked[key]
     (to / "BENCHMARK.json").write_text(json.dumps(data))
     return to
+
+
+def list_cell(copy: Path, name: str, config: str, traffic: str, configs=(), metrics=("tokens_per_s",)) -> None:
+    """Writes the copy's BENCHMARK.json with a directory ``morebench``, one
+    more cell (and ``configs`` entries), listed under ``metrics``."""
+    data = json.loads((ROOT / "BENCHMARK.json").read_text())
+    data["paths"].append("morebench")
+    data["configs"] += list(configs)
+    data["workloads"].append(
+        {"name": name, "config": config, "traffic": traffic, "chips": 1, "why": "test"})
+    for metric in data["end_to_end"] + data["per_layer"]:
+        if metric["name"] in metrics:
+            metric["workloads"].append(name)
+    (copy / "BENCHMARK.json").write_text(json.dumps(data))
 
 
 def test_benchmark_json_is_sound():
@@ -236,7 +251,7 @@ def tiny_system(dtype: str):
     config = {**bench.config("mistral-7b-v0.3-1chip"), **overlay["config"]}
     config["run"] = {**config["run"], **overlay["run"], "dtype": dtype}
     traffic = {**bench.traffic("plain"), **overlay["traffic"]["plain"]}
-    return System(config, traffic, seed=2**31 + 12345)
+    return System(config, bench.architecture(config["model_type"]), traffic, seed=2**31 + 12345)
 
 
 def test_reference_agrees_with_the_program_in_float32():
@@ -252,10 +267,10 @@ def test_reference_agrees_with_the_program_in_float32():
     params = system.init_params()
     tokens = system.tokens(0)
     got = float(system.loss_fn(params, tokens))
-    want = float(reference.make_loss(system.config)(params, tokens))
+    want = float(reference.make_loss(system.architecture, system.config)(params, tokens))
     assert abs(got - want) / want < 1e-5, (got, want)
     # And it is a function of the inputs: other tokens, another loss.
-    other = float(reference.make_loss(system.config)(params, system.tokens(1)))
+    other = float(reference.make_loss(system.architecture, system.config)(params, system.tokens(1)))
     assert abs(other - want) > 1e-4
 
 
@@ -270,11 +285,11 @@ def test_reference_catches_a_lower_precision():
     params = system.init_params()
     tokens = system.tokens(0)
     got = float(system.loss_fn(params, tokens))
-    want = float(reference.make_loss(system.config)(params, tokens))
+    want = float(reference.make_loss(system.architecture, system.config)(params, tokens))
     relative = abs(got - want) / want
     assert 0 < relative < 2**-8, (got, want)
     broken = {**system.config, "rope_theta": 10.0}
-    wrong = float(reference.make_loss(broken)(params, tokens))
+    wrong = float(reference.make_loss(system.architecture, broken)(params, tokens))
     assert abs(wrong - want) / want > 1e-5
 
 
@@ -301,15 +316,112 @@ def test_reference_update_is_the_first_adamw_step():
     assert harness.reference_check(system, [want["first"], got]) == []
     assert harness.reference_check(system, [want["first"], want["second_without_update"]]) != []
     count = float(system.batch * system.seq)
-    total = reference.grad_sum(params, system.tokens(0), system.config)
+    total = reference.grad_sum(system.architecture, params, system.tokens(0), system.config)
     mine = reference.first_adamw_step(params, total, count, system.config)
     worst = max(jax.tree_util.tree_leaves(jax.tree_util.tree_map(
         lambda a, b: float(abs(a - b).max()), mine, stepped)))
     assert worst < 1e-5, worst
     for wrong in ({"learning_rate": 2 * system.config["optimizer"]["learning_rate"]}, {"weight_decay": 0.0}):
         config = {**system.config, "optimizer": {**system.config["optimizer"], **wrong}}
-        loss = float(reference.make_loss_after_first_update(config)(params, system.tokens(0), system.tokens(1)))
+        loss = float(reference.make_loss_after_first_update(system.architecture, config)(params, system.tokens(0), system.tokens(1)))
         assert abs(loss - got) / got > tol, wrong
+
+
+@pytest.mark.parametrize("query_block,head_block", [(32, 128), (128, 32), (32, 32)])
+def test_blocked_reference_equals_the_unblocked_one(query_block, head_block, monkeypatch):
+    """One sequence of 128 positions in four blocks of 32 (attention, the loss
+    head, both) against the same sequence in one block: softmax is by row, so
+    only the order of the sums differs. Loss to 1e-6 relative, gradient leaf
+    by leaf to 1e-5 of the leaf's largest entry (float32, CPU)."""
+    import jax
+    import jax.numpy as jnp
+
+    from chipbench import reference
+
+    system = tiny_system("float32")
+    params = system.init_params()
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (129,), 0, system.config["vocab_size"])
+
+    def loss_and_grad():
+        fn = lambda p: system.architecture.sequence_loss(p, tokens, system.config, recompute=True)
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(jax.value_and_grad(fn))(params)
+
+    monkeypatch.setattr(reference, "QUERY_BLOCK", 128)
+    monkeypatch.setattr(reference, "HEAD_BLOCK", 128)
+    want, want_grad = loss_and_grad()
+    monkeypatch.setattr(reference, "QUERY_BLOCK", query_block)
+    monkeypatch.setattr(reference, "HEAD_BLOCK", head_block)
+    got, got_grad = loss_and_grad()
+    assert abs(float(got) - float(want)) / float(want) < 1e-6
+    worst = jax.tree_util.tree_map(
+        lambda a, b: float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b))), got_grad, want_grad
+    )
+    assert max(jax.tree_util.tree_leaves(worst)) < 1e-5, worst
+    # A sequence that is not whole blocks is refused, not padded.
+    monkeypatch.setattr(reference, "QUERY_BLOCK", 32)
+    with pytest.raises(ValueError, match="whole blocks"):
+        reference.causal_attention(*(jnp.zeros((48, 2, 8)),) * 3)
+
+
+def test_flash_attention_flops_against_a_hand_count():
+    """2 layers, 3 heads of 8, batch 5, 16 positions: one matmul of the
+    scores' shape is 2 * 16 * 16 * 8 * 3 = 12288 operations, 6144 under the
+    causal mask; two forward and five backward: 43008 a layer and sequence."""
+    from chipbench import flops
+
+    config = {"num_hidden_layers": 2, "num_attention_heads": 3, "head_dim": 8}
+    assert flops.flash_attention_flops(config, 5, 16) == 7 * 6144 * 2 * 5 == 430080
+    # Four times the positions in a quarter of the sequences: four times the work.
+    assert flops.flash_attention_flops(config, 1, 64) == 4 * flops.flash_attention_flops(config, 4, 16)
+
+
+def test_flash_mxu_pct_reads_the_recorded_trace():
+    """The recorded plain run (three steps of batch 4 x seq 2048 at the cell's
+    widths): the kernels' seconds are flash_time_pct's, the operations
+    flops.flash_attention_flops', the peak peaks.json's."""
+    from chipbench import flops, harness
+
+    bench = spec.Benchmark(ROOT)
+    trace = trace_reduce.reduce(json.loads((HERE / "small_trace.json").read_text()))
+    config = bench.config("mistral-7b-v0.3-1chip")
+    obs = {"trace": trace, "steps": 3, "config": config, "batch": 4, "seq": 2048,
+           "peaks": harness.peaks_for("TPU v5 lite")}
+    read = bench.reader("per_layer", "flash_mxu_pct").read
+    seconds = sum(s for _, s in trace["kernels"]["jit_plain"])
+    want = 100 * 3 * flops.flash_attention_flops(config, 4, 2048) / seconds / 197e12
+    assert read(obs) == pytest.approx(want, rel=1e-12)
+    assert read(obs) == pytest.approx(25.0086, rel=1e-4) and 0 < read(obs) < 100
+    share = bench.reader("per_layer", "flash_time_pct").read(obs)
+    assert read(obs) * share == pytest.approx(  # the same seconds under both
+        100 * 100 * 3 * flops.flash_attention_flops(config, 4, 2048) / trace["busy_s"] / 197e12)
+    # Nothing to read: no trace, no peak (a rehearsal), no step, no kernel.
+    for hole in ({"trace": None}, {"peaks": None}, {"steps": 0},
+                 {"trace": {**trace, "kernels": {"jit_quantize_pseudograd": [["k", 1.0]]}}}):
+        assert read({**obs, **hole}) is None
+
+
+def test_control_the_reference_in_the_precision_below_is_not_correct():
+    """The control of the comparison that decides ``correct``: the reference
+    itself, put in the program's place with its weights in the nearest
+    precision below the one the (toy, float32) configuration states, bf16.
+    Both of its losses must fail the tolerances the sound program passes
+    (test_reference_update_is_the_first_adamw_step). At the cells' own size
+    the precision below bf16 is fp8: PERF.md section 2 has those readings."""
+    import jax
+    import jax.numpy as jnp
+
+    from chipbench import harness, reference
+
+    system = tiny_system("float32")
+    params = system.init_params()
+    system.reference = harness.reference_losses(system, params)
+    lower = jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16).astype(a.dtype), params)
+    first = reference.make_loss(system.architecture, system.config)(lower, system.tokens(0))
+    second = reference.make_loss_after_first_update(system.architecture, system.config)(
+        lower, system.tokens(0), system.tokens(1))
+    found = harness.reference_check(system, [float(first), float(second)])
+    assert len(found) == 2 and "first loss" in found[0] and "second loss" in found[1], found
 
 
 class FakeDevice:
@@ -410,6 +522,133 @@ def test_a_cell_a_traffic_mix_a_job_and_a_metric_are_added_as_files(tmp_path):
     assert after == before
 
 
+THROWAWAY_ARCHITECTURE = '''
+"""bagofwords: a block the benchmark has never seen (no attention: token
+embedding, RMSNorm, one gated residual layer, an untied head), program's
+model and float32 reference in one file."""
+import jax
+import jax.numpy as jnp
+
+from chipbench import reference
+
+
+class Model:
+    def __init__(self, config):
+        self.d, self.vocab, self.eps = config["hidden_size"], config["vocab_size"], config["rms_norm_eps"]
+        self.dtype = jnp.dtype(config["run"]["dtype"])
+
+    def init(self, key, tokens):
+        e, w, h = jax.random.split(key, 3)
+        normal = lambda k, shape: (jax.random.normal(k, shape) * shape[0] ** -0.5).astype(self.dtype)
+        return {"params": {"embed": normal(e, (self.vocab, self.d)) * self.d ** 0.5,
+                           "scale": jnp.ones((self.d,), self.dtype),
+                           "w": normal(w, (self.d, self.d)), "head": normal(h, (self.d, self.vocab))}}
+
+    def apply(self, params, inputs, targets=None):
+        p = params["params"]
+        x = p["embed"][inputs]
+        x = x + jax.nn.silu(reference.rms_norm(x, p["scale"], self.eps) @ p["w"])
+        logp = jax.nn.log_softmax((x @ p["head"]).astype(jnp.float32), axis=-1)
+        return -jnp.mean(jnp.take_along_axis(logp, targets[..., None], axis=-1))
+
+
+def build(config, seq):
+    return Model(config)
+
+
+def sequence_loss(params, tokens, config, recompute=False):
+    p = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), params["params"])
+    x = p["embed"][tokens[:-1]]
+    x = x + jax.nn.silu(reference.rms_norm(x, p["scale"], float(config["rms_norm_eps"])) @ p["w"])
+    return reference.next_token_loss_sum(x, p["head"], tokens[1:])
+
+
+def parameter_counts(config):
+    d, vocab = config["hidden_size"], config["vocab_size"]
+    return {"total": 2 * vocab * d + d * d + d, "matmul": d * d + vocab * d}
+
+
+def train_flops_per_token(config, seq):
+    return 6.0 * parameter_counts(config)["matmul"]
+'''
+
+
+def test_an_architecture_is_added_as_files(tmp_path):
+    """What a model_config PR brings: an architecture file in a directory of
+    its own, a configuration that names it, a traffic mix and a cell. The
+    benchmark finds them by name, runs the cell end to end through the plain
+    job (the program's model from ``build``, the float32 reference from
+    ``sequence_loss``, ``mfu_pct``'s count from ``train_flops_per_token``),
+    and no file of chipbench/ was edited."""
+    copy = tmp_path / "repo"
+    copy_benchmark(copy)
+    before = {p: p.read_bytes() for p in (copy / "chipbench").rglob("*") if p.is_file()}
+    extra = copy / "morebench"
+    for sub in ("configs", "traffic", "architectures"):
+        (extra / sub).mkdir(parents=True)
+    (extra / "architectures/bagofwords.py").write_text(THROWAWAY_ARCHITECTURE)
+    config = json.loads((copy / "chipbench/configs/mistral-7b-v0.3-1chip.json").read_text())
+    config.update(name="bagofwords-1chip", model_type="bagofwords")
+    (extra / "configs/bagofwords-1chip.json").write_text(json.dumps(config))
+    (extra / "traffic/plain-toy.json").write_text(json.dumps(
+        {**json.loads((copy / "chipbench/traffic/plain.json").read_text()), "batch": 2, "seq": 64}
+    ))
+    list_cell(copy, "bagofwords.plain", "bagofwords-1chip", "plain-toy", configs=[{
+        "name": "bagofwords-1chip", "source": "https://example.org/x", "why": "test",
+        "file": "morebench/configs/bagofwords-1chip.json", "reduced": [],
+    }], metrics=("tokens_per_s", "mfu_pct"))
+
+    bench = spec.Benchmark(copy)
+    assert spec.problems(bench) == []
+    architecture = bench.architecture(bench.config("bagofwords-1chip")["model_type"])
+    assert architecture.__file__ == str(extra / "architectures/bagofwords.py")
+    assert architecture.train_flops_per_token({"hidden_size": 4, "vocab_size": 10}, 64) == 6.0 * 56
+    done = run_cell("bagofwords.plain", "--trace", "0", root=copy)
+    assert done.returncode == 0, done.stderr[-3000:]
+    line = json.loads(done.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True and line["failed"] == 0 and line["attempted"] >= 1
+    assert set(line["metrics"]) == {"tokens_per_s", "peak_hbm_gib", "setup_s"}
+    assert "reference: first loss" in done.stderr and "reference: second loss" in done.stderr
+    assert {p: p.read_bytes() for p in before} == before
+
+
+def test_a_model_type_without_a_file_is_a_named_problem(tmp_path):
+    """Seen by ``spec.problems`` before anything runs: the run ends with "no
+    result" and the names, not with a traceback inside a job."""
+    copy = tmp_path / "repo"
+    copy_benchmark(copy)
+    shutil.copy(ROOT / "BENCHMARK.json", copy / "BENCHMARK.json")
+    path = copy / "chipbench/configs/mistral-7b-v0.3-1chip.json"
+    path.write_text(json.dumps({**json.loads(path.read_text()), "model_type": "olmoe"}))
+    bench = spec.Benchmark(copy)
+    found = spec.problems(bench)
+    assert len(found) == 1 and "mistral-7b-v0.3-1chip" in found[0], found
+    assert "model_type 'olmoe'" in found[0] and "architectures/olmoe.py" in found[0]
+    with pytest.raises(spec.SpecError, match="architectures/olmoe.py"):
+        bench.architecture("olmoe")
+    done = run_cell("mistral7b-1chip.plain", "--trace", "0", root=copy)
+    assert done.returncode != 0 and "Traceback" not in done.stderr
+    assert "no result: BENCHMARK.json is unsound" in done.stderr and "olmoe" in done.stderr
+    assert not any(l.startswith("{") for l in done.stdout.splitlines())
+
+
+def test_only_the_architecture_file_names_the_programs_model():
+    """Nothing of the benchmark outside architectures/ names the program's
+    model class, a parameter path of it or the dense block's width (the parked
+    hsdp job imports the program's sharding plan, which is the program's name
+    for a layout, not for a block)."""
+    import re
+
+    names = re.compile(r"[Ll]lama|w_gate|intermediate_size")
+    hits = [
+        f"{p.relative_to(ROOT)}:{n}" for p in sorted((ROOT / "chipbench").rglob("*.py"))
+        if p.parent.name != "architectures"
+        for n, text in enumerate(p.read_text().splitlines(), 1) if names.search(text)
+    ]
+    assert [h for h in hits if not h.startswith("chipbench/jobs/hsdp.py")] == []
+    assert len([h for h in hits if h.startswith("chipbench/jobs/hsdp.py")]) == 1
+
+
 # -- the jobs, rehearsed -------------------------------------------------------
 
 
@@ -434,6 +673,8 @@ def run_cell(workload: str, *extra: str, rehearse: bool = True, root: Path = ROO
     ("mistral7b-1chip.ftddp", "0"),
     ("mistral7b-1chip.diloco-fp8", "0"),
     ("mistral7b-1chip.diloco-fp8", "1"),
+    ("mistral7b-1chip.ftddp-seq8k", "0"),  # one sequence of four reference blocks
+    ("mistral7b-1chip.ftddp-seq8k", "1"),
     ("mistral7b-2x2.hsdp", "0"),  # parked: rehearsed from a copy that lists it
 ])
 def test_rehearsal_prints_the_contract_line(workload, trace, tmp_path):
@@ -458,6 +699,53 @@ def test_rehearsal_prints_the_contract_line(workload, trace, tmp_path):
         assert set(line["metrics"]) == set(allowed)
     if workload.endswith("diloco-fp8"):
         assert line["attempted"] % 8 == 0, "a window of whole rounds"
+
+
+STUCK_JOB = '''
+"""stuck: the plain job with its timed path broken underneath: the step
+computes the loss and drops the update, so the state never changes."""
+from chipbench import harness, spec
+
+plain = spec.load_module(harness.ROOT / "chipbench/jobs/plain.py")
+
+
+class Job(plain.Job):
+    def __init__(self, run, system, params, spans):
+        import jax
+
+        super().__init__(run, system, params, spans)
+        self._loss = jax.jit(system.loss_fn)
+
+    def step(self, i):
+        return self._loss(self.params, self.system.tokens(i))
+
+
+def run(run):
+    return harness.run_one_process(run, Job)
+'''
+
+
+def test_a_step_that_returns_its_state_unchanged_is_not_correct(tmp_path):
+    """The rest of a run driven past the harness's look for a chip (a
+    rehearsal), with the timed path broken where the state is produced: every
+    loss is finite, nothing compiles in the window, the first loss agrees with
+    the reference, and ``correct`` is false because the second does not."""
+    copy = tmp_path / "repo"
+    copy_benchmark(copy)
+    for sub in ("traffic", "jobs"):
+        (copy / "morebench" / sub).mkdir(parents=True)
+    (copy / "morebench/jobs/stuck.py").write_text(STUCK_JOB)
+    (copy / "morebench/traffic/stuck-toy.json").write_text(json.dumps(
+        {**json.loads((copy / "chipbench/traffic/plain.json").read_text()), "job": "stuck", "batch": 2, "seq": 64}
+    ))
+    list_cell(copy, "mistral7b-1chip.stuck", "mistral-7b-v0.3-1chip", "stuck-toy")
+    assert spec.problems(spec.Benchmark(copy)) == []
+    done = run_cell("mistral7b-1chip.stuck", "--trace", "0", root=copy)
+    assert done.returncode == 0, done.stderr[-3000:]
+    line = json.loads(done.stdout.strip().splitlines()[-1])
+    assert line["correct"] is False and line["attempted"] >= 1
+    wrong = [l for l in done.stderr.splitlines() if l.startswith("NOT CORRECT")]
+    assert len(wrong) == 1 and "second loss differs from the float32 reference" in wrong[0], wrong
 
 
 def test_off_chip_there_is_no_result():

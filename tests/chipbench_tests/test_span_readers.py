@@ -17,6 +17,7 @@ sys.path.insert(0, str(ROOT))
 from chipbench import spec, trace_reduce  # noqa: E402
 
 FTDDP, DILOCO = "mistral7b-1chip.ftddp", "mistral7b-1chip.diloco-fp8"
+SEQ8K = "mistral7b-1chip.ftddp-seq8k"
 
 
 def reader(name: str):
@@ -86,9 +87,13 @@ def test_span_metrics_are_listed_with_their_cells_and_nothing_else_moved():
     assert spec.problems(bench) == []
     assert "trace_in_run" not in bench.data  # PERF.md section 7 says why
     by_name = {m["name"]: m for m in bench.data["per_layer"]}
-    assert by_name["ft_idle_ms"]["workloads"] == [FTDDP]
+    # PR 27: the long-sequence FT-DDP cell reads the FT step's idle too, and
+    # the kernels' share of the peak comes after the two span metrics.
+    assert by_name["ft_idle_ms"]["workloads"] == [FTDDP, SEQ8K]
     assert by_name["outer_sync_idle_ms"]["workloads"] == [DILOCO]
-    assert [m["name"] for m in bench.data["per_layer"]][-2:] == ["ft_idle_ms", "outer_sync_idle_ms"]
+    assert [m["name"] for m in bench.data["per_layer"]][-3:] == [
+        "ft_idle_ms", "outer_sync_idle_ms", "flash_mxu_pct"]
+    assert by_name["flash_mxu_pct"]["workloads"] == [w["name"] for w in bench.data["workloads"]]
     for name in ("ft_idle_ms", "outer_sync_idle_ms"):
         entry = by_name[name]
         assert entry["source"] == "device_trace" and entry["moves"] == "tokens_per_s"
