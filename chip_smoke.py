@@ -51,7 +51,7 @@ GiB = 2**30
 # CONFIGS["1b"]'s): 4 of 16 layers, for every phase, so that each FT path is
 # held to the same plain reference. Sized by chipless compiles for a
 # described v5e (scripts/hbm_probe.py) against the chip's 15.75 GiB, at
-# batch 4: the plain donated step is 8.1 / 11.8 GiB at 4 / 8 layers. The FT
+# batch 4: the plain donated step is 8.1 / 11.9 GiB at 4 / 8 layers. The FT
 # paths hold more, and DiLoCo binds: its state is four parameter-sized trees
 # (leaves, inner momentum, backups, outer momentum: 5.7 GiB at 4 layers,
 # 6.6 at 6) beside a step that cannot donate (out 2.9 + temp 3.1 at 4

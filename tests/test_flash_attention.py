@@ -4,6 +4,10 @@ convention)."""
 
 from __future__ import annotations
 
+import contextlib
+from dataclasses import replace
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -221,6 +225,93 @@ def test_llama_flash_impl_trains():
     assert all(
         np.all(np.isfinite(np.asarray(g)))
         for g in jax.tree_util.tree_leaves(grads)
+    )
+
+
+def _count_pallas_calls(jaxpr) -> int:
+    """pallas_call equations in a jaxpr, nested ones (remat, custom_vjp,
+    shard_map, pjit bodies) included."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "pallas_call"
+        n += sum(
+            _count_pallas_calls(sub)
+            for sub in jax.core.jaxprs_in_params(eqn.params)
+        )
+    return n
+
+
+@pytest.mark.parametrize("mesh_axis", [None, "fsdp"], ids=["bare", "fsdp2-mesh"])
+@pytest.mark.parametrize(
+    "remat, calls", [("none", 3), ("dots", 3), ("full", 4)]
+)
+def test_remat_runs_the_forward_kernel_once_unless_full(
+    remat, calls, mesh_axis, monkeypatch
+):
+    """A GQA attention layer (projections, flash, wo, residual) under the
+    model's remat policies, Pallas backward in interpret mode. The
+    gradient's jaxpr holds forward + dq + dkv; only ``full`` may add a
+    second forward: ``dots`` keeps the kernel's named (out, lse)
+    (FLASH_OUT / FLASH_LSE) beside the dot results — plain
+    ``checkpoint_dots`` sees no dot_general in a pallas_call and reran it.
+    Gradients are the unremat'd ones bit for bit (a kept value replaces the
+    same value recomputed by the same kernel). Under a bound mesh the
+    dispatcher's shard_map sits between the remat and the names, and they
+    survive it."""
+    import torchft_tpu.ops.flash_attention as fa
+    from torchft_tpu.models.llama import (
+        CONFIGS, _flash_under_ambient_mesh, _remat_policy,
+    )
+
+    # The dispatcher imports the kernel at call time: force the interpreted
+    # Pallas backward (off-TPU it would pick the scan fallback).
+    monkeypatch.setattr(
+        fa, "flash_attention",
+        partial(flash_attention, interpret=True, use_pallas_bwd=True),
+    )
+    cfg = replace(
+        CONFIGS["tiny"], attention_impl="flash",
+        attention_block_size=32, attention_block_k=128,
+    )
+    b, s, h, kv, d, dim = 2, 64, 4, 2, 16, 32
+    keys = jax.random.split(jax.random.PRNGKey(0), 5)
+    w = {
+        "wq": jax.random.normal(keys[0], (dim, h, d)) * 0.1,
+        "wk": jax.random.normal(keys[1], (dim, kv, d)) * 0.1,
+        "wv": jax.random.normal(keys[2], (dim, kv, d)) * 0.1,
+        "wo": jax.random.normal(keys[3], (h, d, dim)) * 0.1,
+    }
+    x = jax.random.normal(keys[4], (b, s, dim))
+
+    def layer(w, x):
+        q, k, v = (
+            jnp.einsum("bsd,dhe->bshe", x, w[name])
+            for name in ("wq", "wk", "wv")
+        )
+        out = _flash_under_ambient_mesh(cfg, q, k, v, d**-0.5)
+        return x + jnp.einsum("bshe,hed->bsd", out, w["wo"])
+
+    def grad_of(f):
+        return jax.grad(lambda w, x: jnp.sum(f(w, x) ** 2), argnums=(0, 1))
+
+    remat_layer = layer
+    if remat != "none":
+        remat_layer = jax.checkpoint(layer, policy=_remat_policy(remat))
+    bound = (
+        jax.set_mesh(jax.sharding.Mesh(np.array(jax.devices()[:2]), (mesh_axis,)))
+        if mesh_axis
+        else contextlib.nullcontext()
+    )
+    with bound:
+        jaxpr = jax.make_jaxpr(grad_of(remat_layer))(w, x)
+        grads = jax.jit(grad_of(remat_layer))(w, x)
+        plain = jax.jit(grad_of(layer))(w, x)
+    assert _count_pallas_calls(jaxpr.jaxpr) == calls
+    if mesh_axis:
+        assert "shard_map" in str(jaxpr)
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_array_equal(np.asarray(a), np.asarray(b)),
+        grads, plain,
     )
 
 

@@ -380,11 +380,23 @@ def test_llama_blockwise_impl_matches_dense_model() -> None:
     )
 
 
-def test_llama_remat_matches_baseline() -> None:
+@pytest.mark.parametrize(
+    "overrides",
+    [
+        {},
+        # The bench presets' combination: the Pallas kernel (interpreted
+        # off-TPU) inside a remat'd scan cell, where "dots" also keeps the
+        # kernel's named (out, lse).
+        {"attention_impl": "flash", "scan_layers": True,
+         "attention_block_size": 16},
+    ],
+    ids=["dense-loop", "flash-scan"],
+)
+def test_llama_remat_matches_baseline(overrides) -> None:
     """remat='full'/'dots' change only the backward's memory/recompute
     schedule: same params, logits AND gradients must match the unremat
     model (allclose; fp32 tiny config)."""
-    cfg = CONFIGS["tiny"]
+    cfg = replace(CONFIGS["tiny"], **overrides)
     tokens = jnp.arange(32, dtype=jnp.int32).reshape(2, 16) % cfg.vocab_size
     base = Llama(cfg)
     params = base.init(jax.random.PRNGKey(0), tokens)
@@ -403,6 +415,39 @@ def test_llama_remat_matches_baseline() -> None:
             ),
             g1, g0,
         )
+
+
+@pytest.mark.parametrize("attention_impl", ["dense", "blockwise"])
+@pytest.mark.parametrize("scan_layers", [False, True], ids=["loop", "scan"])
+def test_dots_remat_without_flash_is_plain_checkpoint_dots(
+    attention_impl, scan_layers, monkeypatch
+) -> None:
+    """The bypass: "dots" adds the flash kernel's two names to
+    checkpoint_dots, and dense / blockwise attention never produce such a
+    name — their gradient lowers to the same program text as under plain
+    checkpoint_dots."""
+    from torchft_tpu.models import llama
+
+    cfg = replace(
+        CONFIGS["tiny"], attention_impl=attention_impl,
+        attention_block_size=8, scan_layers=scan_layers, remat="dots",
+    )
+    tokens = jnp.arange(32, dtype=jnp.int32).reshape(2, 16) % cfg.vocab_size
+    model = Llama(cfg)
+    params = model.init(jax.random.PRNGKey(0), tokens)
+
+    def lowered() -> str:
+        grad = jax.grad(
+            lambda p: cross_entropy_loss(model.apply(p, tokens), tokens)
+        )
+        return jax.jit(grad).lower(params).as_text()
+
+    with_names = lowered()
+    monkeypatch.setattr(
+        llama, "_remat_policy",
+        lambda remat: jax.checkpoint_policies.checkpoint_dots,
+    )
+    assert lowered() == with_names
 
 
 def test_llama_scan_layers_matches_loop() -> None:

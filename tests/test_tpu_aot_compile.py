@@ -70,6 +70,10 @@ def _sds(shape, dtype, chip):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
 
+def _sds_tree(tree, chip):
+    return jax.tree_util.tree_map(lambda a: _sds(a.shape, a.dtype, chip), tree)
+
+
 def _compile(fn, *args):
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -164,14 +168,11 @@ def test_plain_step_compiles_at_smoke_config(chip, monkeypatch) -> None:
     params = jax.eval_shape(
         lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((batch, seq), jnp.int32))
     )
-    on_chip = lambda tree: jax.tree_util.tree_map(  # noqa: E731
-        lambda a: _sds(a.shape, a.dtype, chip), tree
-    )
     compiled = (
         chip_smoke.make_plain_step(tx, chip_smoke.make_loss_fn(model))
         .lower(
-            on_chip(params),
-            on_chip(jax.eval_shape(tx.init, params)),
+            _sds_tree(params, chip),
+            _sds_tree(jax.eval_shape(tx.init, params), chip),
             _sds((batch, seq + 1), jnp.int32, chip),
         )
         .compile()
@@ -185,6 +186,79 @@ def test_plain_step_compiles_at_smoke_config(chip, monkeypatch) -> None:
     # The described-topology compile does not refuse an oversized program
     # by itself: hold the bytes against the v5e's 15.75 GiB here.
     assert total < 15.75 * 2**30, f"plain step needs {total / 2**30:.2f} GiB"
+
+
+# The long-sequence cell's own sizes (chipbench/traffic/ftddp-seq8k.json over
+# the Mistral configuration file), and a twin at toy widths for tier-1: the
+# same head_dim, GQA ratio, block sizes and fused-CE chunk, one eighth of the
+# widths and of the sequence.
+_TOY_WIDTHS = {
+    "hidden_size": 256, "num_attention_heads": 2, "num_key_value_heads": 1,
+    "intermediate_size": 512, "vocab_size": 4096,
+}
+
+
+@pytest.mark.parametrize(
+    "widths, seq",
+    [
+        pytest.param({}, 8192, marks=pytest.mark.slow, id="mistral-1x8192"),  # 30 s
+        pytest.param(_TOY_WIDTHS, 1024, id="toy-1x1024"),
+    ],
+)
+def test_dots_step_runs_one_flash_forward_a_layer(chip, monkeypatch, widths, seq) -> None:
+    """The FT-DDP fused step of ``mistral7b-1chip.ftddp-seq8k`` (2 scanned
+    layers, bf16, ``dots``, fused CE 4096, AdamW) compiled twice: as the
+    model builds it, and with ``dots`` meaning plain ``checkpoint_dots``
+    again. The layers stay one loop either way, so the compiled text holds
+    one Mosaic call for each kernel of a layer body: forward, dq, dkv —
+    and under plain ``checkpoint_dots`` the forward a second time, in the
+    backward's loop. Keeping (out, lse) may cost the program's temporaries
+    no more than those two arrays for each layer."""
+    import json
+    from pathlib import Path
+
+    import torchft_tpu.models.llama as llama
+    import torchft_tpu.ops.flash_attention as flash
+    from chipbench.architectures import mistral
+    from chipbench.model import System
+    from torchft_tpu.optim import make_jit_fused_step
+
+    for module in (llama, flash):
+        monkeypatch.setattr(module, "on_tpu", lambda: True)
+    config = json.loads(
+        (Path(__file__).parent.parent / "chipbench/configs/mistral-7b-v0.3-1chip.json")
+        .read_text()
+    )
+    config.update(widths)
+    assert config["run"]["remat"] == "dots" and config["run"]["scan_layers"]
+    # The benchmark's own model, loss and AdamW for the cell's traffic.
+    system = System(config, mistral, {"batch": 1, "seq": seq}, seed=0)
+    params = jax.eval_shape(system.init_params)
+    opt_state = jax.eval_shape(system.tx.init, params)
+
+    def compiled():
+        program = (
+            make_jit_fused_step(system.tx, system.loss_fn)
+            .lower(
+                _sds_tree(params, chip), _sds_tree(opt_state, chip),
+                _sds((1, seq + 1), jnp.int32, chip),
+            )
+            .compile()
+        )
+        return (
+            program.as_text().count('custom_call_target="tpu_custom_call"'),
+            program.memory_analysis().temp_size_in_bytes,
+        )
+
+    calls, temp = compiled()
+    monkeypatch.setattr(
+        llama, "_remat_policy", lambda remat: jax.checkpoint_policies.checkpoint_dots
+    )
+    calls_plain_dots, temp_plain_dots = compiled()
+    assert (calls, calls_plain_dots) == (3, 4)
+    heads, layers = config["num_attention_heads"], config["num_hidden_layers"]
+    kept = layers * seq * (config["hidden_size"] * 2 + heads * 4)  # bf16 out, f32 lse
+    assert temp - temp_plain_dots <= 1.1 * kept, (temp, temp_plain_dots, kept)
 
 
 def test_sharded_step_with_size_one_mesh_axis_compiles(v5e, monkeypatch) -> None:

@@ -91,9 +91,14 @@ class LlamaConfig:
     blockwise_min_seq: int = 2048
     # Rematerialization (gradient checkpointing): trade FLOPs for HBM so
     # long-context / 70B-class steps fit. "full" recomputes each block in
-    # the backward; "dots" keeps MXU dot outputs and recomputes the cheap
-    # elementwise/VPU work (jax.checkpoint_policies.checkpoint_dots) —
-    # usually the right TPU default when activations don't fit.
+    # the backward, the flash forward kernel included; "dots" keeps what
+    # the MXU produced — every dot_general result
+    # (jax.checkpoint_policies.checkpoint_dots) and the flash forward
+    # kernel's output and logsumexp (a Pallas call, kept by the names
+    # ops/flash_attention.py gives them: +(dim x 2 + heads x 4) bytes a
+    # token and layer, 65 MiB at 8192 x 4096) — and recomputes the cheap
+    # elementwise/VPU work. Usually the right TPU default when
+    # activations don't fit.
     remat: str = "none"
     # Vocab slab width for the fused linear+CE loss path (``targets=`` in
     # __call__): the (b, s, vocab) logits — 8 GiB at 8x2048x128k f32 —
@@ -164,7 +169,9 @@ def large_bench_config(**overrides) -> LlamaConfig:
       dim 1024, but 64-wide heads half-fill the 128-lane MXU.
     - remat="dots" + batch 4: the 15.75 GiB HBM budget, sized by
       chipless compiles for a described v5e (scripts/hbm_probe.py) —
-      batch 8 without remat needs ~29 GB.
+      batch 8 without remat needs ~29 GB. "dots" keeps the matmuls'
+      results and the flash kernel's (out, logsumexp), so a layer step
+      runs the forward kernel once, not again in the backward.
     - flash attention + scanned layers + fused CE: the long-sequence
       kernel path, O(1) HLO in depth, and no materialized logits.
 
@@ -462,7 +469,20 @@ class Block(nn.Module):
 
 
 def _remat_policy(remat: str):
-    return jax.checkpoint_policies.checkpoint_dots if remat == "dots" else None
+    """``dots`` keeps what the MXU produced: every ``dot_general`` result
+    and the flash forward kernel's (out, logsumexp), which is a Pallas call
+    and so invisible to ``checkpoint_dots`` alone — without the names the
+    backward would run the whole forward kernel a second time. ``full``
+    (None) recomputes everything, that kernel included."""
+    if remat != "dots":
+        return None
+    from torchft_tpu.ops.flash_attention import FLASH_LSE, FLASH_OUT
+
+    policies = jax.checkpoint_policies
+    return policies.save_from_both_policies(
+        policies.checkpoint_dots,
+        policies.save_only_these_names(FLASH_OUT, FLASH_LSE),
+    )
 
 
 class _ScanCell(nn.Module):

@@ -15,10 +15,18 @@ standard FlashAttention-2 two-pass recompute from the saved (out, lse)
 residuals: a dq kernel accumulating over KV blocks and a dkv kernel
 accumulating over Q blocks, with the per-row ``delta = rowsum(dO*O)``
 identity computed by XLA outside the kernels (it fuses into the
-surrounding graph). GQA is handled by emitting per-q-head dk/dv partials
-and summing over the group axis outside — keeps every output block
-written exactly once per grid pass (no cross-step output aliasing, which
-Mosaic cannot express). The scan-based blockwise backward remains the
+surrounding graph). The two residuals the forward kernel produced carry
+``checkpoint_name`` tags, ``FLASH_OUT`` and ``FLASH_LSE``: a Pallas call is
+no ``dot_general``, so a remat policy that keeps dot results alone would
+drop them and run the whole forward kernel again in the backward. The
+model's ``remat="dots"`` keeps both by name (models/llama.py
+``_remat_policy``); ``remat="full"`` and any policy that does not name them
+recompute the kernel, and without remat the names do nothing.
+:func:`flash_attention_partial` is untagged: its VJP is the ring's own.
+GQA is handled by emitting per-q-head dk/dv partials and summing over the
+group axis outside — keeps every output block written exactly once per
+grid pass (no cross-step output aliasing, which Mosaic cannot express).
+The scan-based blockwise backward remains the
 interpret/CPU fallback (``use_pallas_bwd`` selects; CPU tests run the
 Pallas backward in interpret mode explicitly). Run :func:`verify_on_chip`
 on the chip after any kernel change (the CLAUDE.md kernel-verification
@@ -45,17 +53,25 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from torchft_tpu.utils.platform import on_tpu
 
 from torchft_tpu.ops.ring_attention import _blockwise_core_bwd
 
 __all__ = [
+    "FLASH_OUT",
+    "FLASH_LSE",
     "flash_attention",
     "flash_attention_partial",
     "flash_attention_partial_bwd",
     "merge_attention_partials",
 ]
+
+# checkpoint_name tags of the forward kernel's two residuals (module
+# docstring: who keeps them).
+FLASH_OUT = "flash_out"
+FLASH_LSE = "flash_lse"
 
 _NEG_INF = -1e30
 _PAD_POS = 2**31 - 1  # position for padded rows: beyond every real query
@@ -527,6 +543,8 @@ def _flash_core(q, k, v, scale, block_q, block_k, interpret, pallas_bwd):
 
 def _flash_core_fwd(q, k, v, scale, block_q, block_k, interpret, pallas_bwd):
     out, lse = _flash_fwd(q, k, v, scale, block_q, block_k, interpret)
+    out = checkpoint_name(out, FLASH_OUT)
+    lse = checkpoint_name(lse, FLASH_LSE)
     return out, (q, k, v, out, lse)
 
 
