@@ -291,7 +291,7 @@ def bench_commit_pipeline(quick: bool = False) -> Dict[str, Any]:
     Data Parallel ML training on Mesh Networks" pays per step at 50-100 ms
     cross-DC RTT). The control plane is a scripted lone-replica manager
     (this bench must run without the native plane); the wire is the
-    lone-replica identity, the topology of bench.py's ft_ddp phase.
+    lone-replica identity, the topology of the benchmark's ``ftddp`` cells.
 
     Expectation encoded in the claims: depth 0 (the default overlapped
     ordering) pays ~RTT every step; a depth-1 window hides the RTT only

@@ -2,7 +2,7 @@
 """On-chip kernel microbenchmarks: Pallas flash attention vs XLA dense
 attention, and the fp8 wire-codec device kernels.
 
-The training bench (bench.py) measures the FT layer's overhead; this one
+The benchmark (chipbench/) measures the FT layer's overhead; this one
 measures the per-chip hot ops themselves — the "don't stop at parity"
 half of the perf story. Requires a live TPU (the kernels' compiled Mosaic
 path, not interpret mode — interpret-mode timings are meaningless).

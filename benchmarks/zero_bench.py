@@ -11,7 +11,7 @@ No training steps and no coordination plane: the bench measures the
 moments for its owned shards) and what the heal plane moves (the staged
 checkpoint's chunk sizes through the REAL part-aware HTTPTransport
 staging path, plus one live skip-parts fetch to validate the wire
-numbers). Shapes come from bench.py's representative 27M config; set
+numbers). Shapes come from a representative 27M Llama (``_bench_params``); set
 ``TPUFT_ZERO_BENCH_ELEMS`` to bench a synthetic tree of that many
 elements instead (fast smoke). Runtime well under the default-workload
 trap documented in CLAUDE.md — nothing here steps the model.
@@ -66,7 +66,7 @@ def _bench_params():
     model = Llama(config)
     tokens = jnp.zeros((2, seq), dtype=jnp.int32)
     params = model.init(jax.random.PRNGKey(0), tokens)
-    return params, "llama-27M (bench.py default config)"
+    return params, "llama-27M (dim 512, 6 layers, vocab 8192)"
 
 
 def _tree_bytes(tree) -> int:

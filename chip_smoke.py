@@ -44,7 +44,13 @@ from pathlib import Path
 REPO = Path(__file__).resolve().parent
 sys.path.insert(0, str(REPO))
 
-LOOPBACK = "127.0.0.1"
+# chip_smoke.py -> chipbench.harness -> torchft_tpu. Importing the harness
+# touches no JAX backend (tests/test_chip_bringup.py holds it to that): the
+# parent of ``--chips 4`` must stay off the chips its workers need.
+from chipbench.harness import (  # noqa: E402
+    LOOPBACK, CompileLedger, Plane, balanced_fragments,
+)
+
 GiB = 2**30
 
 # Depth is the one cut (widths, vocabulary, sequence and dtype are
@@ -137,32 +143,6 @@ def describe(config) -> str:
 # ---------------------------------------------------------------------------
 
 
-class CompileLedger:
-    """Counts backend compilations (cache retrievals included) and their
-    seconds, and the persistent cache's hits and misses."""
-
-    def __init__(self) -> None:
-        import jax.monitoring
-
-        self.compiles = 0
-        self.compile_seconds = 0.0
-        self.cache_hits = 0
-        self.cache_misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_duration(self, event: str, seconds: float, **_: object) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compiles += 1
-            self.compile_seconds += seconds
-
-    def _on_event(self, event: str, **_: object) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.cache_misses += 1
-
-
 class Phase:
     """Prints one phase's wall seconds, the compile seconds inside it and
     the device's peak bytes when it ends."""
@@ -232,9 +212,11 @@ def release_device_memory() -> str:
 # ---------------------------------------------------------------------------
 
 
-class Plane:
-    """One replica group's control plane: store, native process group,
-    manager — every address that has a parameter is loopback."""
+class DrillPlane:
+    """One of SEVERAL replica groups on a lighthouse the caller owns: store,
+    native process group, manager. ``chipbench.harness.Plane`` owns its
+    lighthouse and so holds one group; the kill/heal drill needs two on one,
+    which is all that differs."""
 
     def __init__(self, lighthouse_addr: str, replica_id: str, **manager_kwargs):
         from torchft_tpu.manager import Manager
@@ -253,6 +235,7 @@ class Plane:
             manager_bind=f"{LOOPBACK}:0",
             timeout=30.0,
             quorum_timeout=60.0,
+            min_replica_size=1,
             **manager_kwargs,
         )
 
@@ -333,29 +316,6 @@ def _leaf_abs_sums(leaves):
 
 
 _LEAF_ABS_SUMS = None
-
-
-def balanced_fragments(params, n_fragments: int):
-    """``fragment_fn`` for DiLoCo: leaves spread over fragments by bytes
-    (largest first) instead of contiguous chunks. The default split puts
-    both 263M-element vocabulary matrices and the whole MLP stack into one
-    fragment, whose outer step then needs ~14 GiB of temporaries on top of
-    the resident state — more than one chip has at these widths."""
-    import jax
-
-    sizes = [leaf.size for leaf in jax.tree_util.tree_leaves(params)]
-
-    def fragment_fn(n_leaves: int):
-        assert n_leaves == len(sizes)
-        bins = [[] for _ in range(n_fragments)]
-        load = [0] * n_fragments
-        for i in sorted(range(n_leaves), key=lambda i: -sizes[i]):
-            b = load.index(min(load))
-            bins[b].append(i)
-            load[b] += sizes[i]
-        return [sorted(b) for b in bins]
-
-    return fragment_fn
 
 
 class OneChip:
@@ -475,11 +435,11 @@ class OneChip:
             losses_not_donated=self.plain["not donated"]["losses"],
         )
 
-    def ft_ddp(self, lighthouse_addr: str) -> None:
+    def ft_ddp(self) -> None:
         from torchft_tpu.optim import Optimizer, make_jit_fused_step
 
         with self.phase("ft-ddp"):
-            plane = Plane(lighthouse_addr, "smoke_ddp", min_replica_size=1)
+            plane = Plane("smoke_ddp", 30.0)
             try:
                 opt = Optimizer(plane.manager, self.tx, self.init_params())
                 # The program make_step_fn's lone-replica path dispatches,
@@ -542,7 +502,7 @@ class OneChip:
             losses=losses, all_committed=True, vs_plain=verdict
         )
 
-    def diloco(self, lighthouse_addr: str) -> None:
+    def diloco(self) -> None:
         """Streaming DiLoCo: a cycle that compiles, then a steady one, every
         fragment sync through the quantized (fp8, Pallas) outer path."""
         import jax
@@ -554,10 +514,7 @@ class OneChip:
         with self.phase("diloco"):
             n_fragments = 2 if self.args.rehearse else 4
             sync_every = 2 * n_fragments  # 2 inner steps per fragment sync
-            plane = Plane(
-                lighthouse_addr, "smoke_diloco",
-                min_replica_size=1, use_async_quorum=False,
-            )
+            plane = Plane("smoke_diloco", 30.0, use_async_quorum=False)
             try:
                 params = self.init_params()
                 algo = DiLoCo(
@@ -636,7 +593,6 @@ class OneChip:
 
 def one_chip(args, ledger: CompileLedger, summary: dict) -> None:
     from torchft_tpu import _native
-    from torchft_tpu.coordination import LighthouseServer
 
     run = OneChip(args, ledger, summary)
     say(
@@ -654,24 +610,17 @@ def one_chip(args, ledger: CompileLedger, summary: dict) -> None:
             f"  libtpuft.so from tracked sources: {lib} "
             f"(digest {_native.source_digest()[:12]})"
         )
-        lighthouse = LighthouseServer(
-            bind=f"{LOOPBACK}:0", min_replicas=1, join_timeout_ms=100
-        )
-        say(f"  lighthouse on {lighthouse.address()}")
-    try:
-        if not args.rehearse:
-            run.kernels()  # compiled, not interpreted
-        run.plain_step()
-        run.released()
-        run.ft_ddp(lighthouse.address())
-        run.released()
-        run.diloco(lighthouse.address())
-        run.released()
-        with run.phase("kill-heal"):
-            drill = kill_heal_threads(args)
-        summary["kill-heal"].update(drill)
-    finally:
-        lighthouse.shutdown()
+    if not args.rehearse:
+        run.kernels()  # compiled, not interpreted
+    run.plain_step()
+    run.released()
+    run.ft_ddp()
+    run.released()
+    run.diloco()
+    run.released()
+    with run.phase("kill-heal"):
+        drill = kill_heal_threads(args)
+    summary["kill-heal"].update(drill)
 
 
 def kill_heal_threads(args) -> dict:
@@ -712,9 +661,8 @@ def kill_heal_threads(args) -> dict:
 
     def group_main(idx: int) -> None:
         for attempt in range(3):
-            plane = Plane(
-                lighthouse.address(), f"smoke_drill_{idx}",
-                min_replica_size=1, heartbeat_interval=0.05,
+            plane = DrillPlane(
+                lighthouse.address(), f"smoke_drill_{idx}", heartbeat_interval=0.05
             )
             try:
                 seed = args.seed if attempt == 0 else args.seed + 999
@@ -795,7 +743,6 @@ def in_slice(args, ledger: CompileLedger, summary: dict) -> None:
     import numpy as np
     import optax
 
-    from torchft_tpu.coordination import LighthouseServer
     from torchft_tpu.models.llama import (
         Llama, apply_sharding_plan, sharding_plan,
     )
@@ -823,10 +770,7 @@ def in_slice(args, ledger: CompileLedger, summary: dict) -> None:
     )
 
     with Phase("in-slice fsdp2xtp2", ledger, summary):
-        lighthouse = LighthouseServer(
-            bind=f"{LOOPBACK}:0", min_replicas=1, join_timeout_ms=100
-        )
-        plane = Plane(lighthouse.address(), "smoke_hsdp", min_replica_size=1)
+        plane = Plane("smoke_hsdp", 30.0)
         try:
             ft_mesh = ft_init_device_mesh(
                 plane.manager, (2, 2), ("fsdp", "tp"), devices=devices[:4]
@@ -918,7 +862,6 @@ def in_slice(args, ledger: CompileLedger, summary: dict) -> None:
             del opt, grads
         finally:
             plane.shutdown()
-            lighthouse.shutdown()
     gc.collect()
 
     with Phase("one-chip reference", ledger, summary):

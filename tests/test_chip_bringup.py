@@ -35,7 +35,7 @@ def _run(argv, **env):
 
 
 # ---------------------------------------------------------------------------
-# chip_smoke.py / bench.py: a rehearsal says so; no chip, no result
+# chip_smoke.py: a rehearsal says so; no chip, no result
 # ---------------------------------------------------------------------------
 
 
@@ -59,27 +59,34 @@ def test_chip_smoke_rehearsal_names_the_cpu_and_passes() -> None:
     }
 
 
-@pytest.mark.parametrize("script", ["chip_smoke.py", "bench.py"])
-def test_chip_entry_points_refuse_to_run_without_a_chip(script) -> None:
+def test_chip_smoke_refuses_to_run_without_a_chip() -> None:
     """The default invocation needs a TPU: on the CPU it exits non-zero,
     says why, and prints no result line under any device's name."""
-    proc = _run([script])
+    proc = _run(["chip_smoke.py"])
     assert proc.returncode != 0
     assert "no TPU" in proc.stderr and "cpu" in proc.stderr
-    assert '"ok"' not in proc.stdout and '"metric"' not in proc.stdout
+    assert '"ok"' not in proc.stdout
 
 
-def test_bench_cpu_run_is_named_twice_over() -> None:
-    """``--cpu`` says a CPU run is meant; the CPU itself and the config come
-    from the caller (one rule, ``cpu_by_name``, shared with the launcher).
-    Missing either, the bench refuses before it measures anything."""
-    from torchft_tpu.utils.platform import cpu_by_name
+def test_chip_smoke_imports_the_harness_and_carries_no_copy() -> None:
+    """``chip_smoke.py -> chipbench.harness -> torchft_tpu``: the smoke's
+    ledger and fragment split ARE the benchmark's, and importing the smoke
+    (the harness with it) initializes no backend — the parent of
+    ``--chips 4`` hands the chips to its workers."""
+    import chip_smoke
+    import chipbench.harness
 
-    assert cpu_by_name({"JAX_PLATFORMS": "cpu"}) and cpu_by_name({"JAX_PLATFORMS": " CPU "})
-    assert not cpu_by_name({}) and not cpu_by_name({"JAX_PLATFORMS": "cpu,tpu"})
-    proc = _run(["bench.py", "--cpu"], TPUFT_BENCH_MODEL="")
-    assert proc.returncode != 0 and "names the config" in proc.stderr
-    assert '"metric"' not in proc.stdout
+    assert chip_smoke.CompileLedger is chipbench.harness.CompileLedger
+    assert chip_smoke.balanced_fragments is chipbench.harness.balanced_fragments
+    proc = _run(
+        [
+            "-c",
+            "import chip_smoke;"
+            "from jax._src import xla_bridge;"
+            "assert not xla_bridge.backends_are_initialized()",
+        ]
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
 
 
 def test_require_tpu_exits_on_the_cpu() -> None:
@@ -145,7 +152,7 @@ def test_compile_cache_path_is_never_temp_pid_or_time(monkeypatch) -> None:
         if '"jax_compilation_cache_dir"' in py.read_text()
     ] + [
         name
-        for name in ("bench.py", "chip_smoke.py", "__graft_entry__.py")
+        for name in ("chip_smoke.py", "__graft_entry__.py")
         if '"jax_compilation_cache_dir"' in (REPO / name).read_text()
     ]
     assert setters == ["torchft_tpu/utils/platform.py"]
@@ -254,6 +261,9 @@ def test_chip_envs_refuses_more_processes_than_chips_unless_cpu_by_name(
     with pytest.raises(RuntimeError, match="a chip belongs to one process"):
         launch.chip_envs(2, {})
     assert launch.chip_envs(2, {"JAX_PLATFORMS": "cpu"}) == [{}, {}]
+    assert launch.chip_envs(2, {"JAX_PLATFORMS": " CPU "}) == [{}, {}]  # cpu_by_name's rule
+    with pytest.raises(RuntimeError, match="a chip belongs to one process"):
+        launch.chip_envs(2, {"JAX_PLATFORMS": "cpu,tpu"})  # a list is not "by name"
     assert launch.chip_envs(1, {}) == [{}]  # owns the whole host: nothing to set
     monkeypatch.setattr(launch, "local_chip_count", lambda: 0)
     assert launch.chip_envs(3, {}) == [{}, {}, {}]  # no chips: jax picks the CPU

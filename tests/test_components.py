@@ -747,9 +747,7 @@ def test_flight_recorder_ring_and_dump(tmp_path, monkeypatch) -> None:
 def test_doctor_checks_pass_and_catch_problems(monkeypatch, capsys) -> None:
     """run_checks passes on a healthy box (live lighthouse), flags unknown
     TPUFT_* vars, and KNOWN_ENV tracks every env var the tree reads."""
-    import re
     import subprocess
-    from pathlib import Path
 
     from torchft_tpu import doctor
     from torchft_tpu.coordination import LighthouseServer
@@ -778,13 +776,7 @@ def test_doctor_checks_pass_and_catch_problems(monkeypatch, capsys) -> None:
     # Drift guard: every TPUFT_* name used anywhere in the repo (package,
     # tests, benchmarks, scripts, top-level drivers) must be declared in
     # doctor.KNOWN_ENV, or doctor would cry typo on a real knob.
-    repo = Path(doctor.__file__).parent.parent
-    used = set()
-    for sub in ("torchft_tpu", "tests", "benchmarks", "scripts"):
-        for py in (repo / sub).rglob("*.py"):
-            used |= set(re.findall(r"TPUFT_[A-Z_0-9]+", py.read_text()))
-    for top in ("bench.py", "__graft_entry__.py"):
-        used |= set(re.findall(r"TPUFT_[A-Z_0-9]+", (repo / top).read_text()))
+    used = _tpuft_names_in_tree()
     # Per-pair WAN link envs embed region names (TPUFT_EMULATED_LINK_US_EU,
     # ...) so they can't be enumerated; doctor's env check carries the same
     # prefix allowance and the topology check validates them instead.
@@ -794,6 +786,38 @@ def test_doctor_checks_pass_and_catch_problems(monkeypatch, capsys) -> None:
     used = {n for n in used if not n.endswith("_")}
     missing = used - doctor.KNOWN_ENV - {"TPUFT_DEFINITELY_A_TYPO"}
     assert not missing, f"doctor.KNOWN_ENV missing: {sorted(missing)}"
+
+
+def _tpuft_names_in_tree(skip=()) -> set:
+    """Every TPUFT_* name the drift guard's directories mention, ``skip``
+    (paths relative to the repo) left out."""
+    import re
+    from pathlib import Path
+
+    from torchft_tpu import doctor
+
+    repo = Path(doctor.__file__).parent.parent
+    files = [
+        py
+        for sub in ("torchft_tpu", "tests", "benchmarks", "scripts")
+        for py in (repo / sub).rglob("*.py")
+    ] + [repo / "__graft_entry__.py"]
+    return {
+        name
+        for py in files
+        if str(py.relative_to(repo)) not in skip
+        for name in re.findall(r"TPUFT_[A-Z_0-9]+", py.read_text())
+    }
+
+
+def test_doctor_known_env_keeps_no_name_whose_reader_is_gone() -> None:
+    """The other direction of the drift guard: a name stays in the registry
+    only while some file besides the registry mentions it. A deleted entry
+    point takes its knobs out of ``doctor`` with it."""
+    from torchft_tpu import doctor
+
+    dead = doctor.KNOWN_ENV - _tpuft_names_in_tree(skip=("torchft_tpu/doctor.py",))
+    assert not dead, f"doctor.KNOWN_ENV lists names nothing reads: {sorted(dead)}"
 
 
 def test_metric_names_match_registry_table() -> None:

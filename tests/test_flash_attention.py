@@ -165,8 +165,7 @@ def test_multi_kv_block_partial_matches_dense():
 
 
 def test_pallas_backward_jits():
-    """The whole value_and_grad step jits with the fused backward (the
-    shape tested is what the bench's large config uses per block)."""
+    """The whole value_and_grad step jits with the fused backward."""
     b, s, h, kv, d = 1, 96, 4, 2, 32
     q, k, v = _qkv(b, s, h, kv, d, seed=5)
 

@@ -604,27 +604,24 @@ def test_doctor_goodput_check(monkeypatch) -> None:
     assert state == "WARN" and "trace plane off" in detail
 
 
-def test_bench_goodput_fields(monkeypatch) -> None:
-    """bench.py's JSON line carries goodput_fraction + top-2 badput
-    buckets folded over its measurement window."""
-    import bench
-
+def test_fold_then_top_badput_gives_the_windows_shares() -> None:
+    """The headline a report makes of one window: the committed share and
+    the two largest badput buckets, from ``fold_events`` and ``top_badput``
+    on the same journal."""
     j, _ = make_journal()
     span(j, "quorum", 1.0, 1.0)
     span(j, "heal_send", 2.0, 0.5)
     instant(j, "commit", 10.0)
-    monkeypatch.setattr(tracing, "default", lambda: j)
-    fields = bench._ft_goodput_fields(0.0, 10.0)
-    assert math.isclose(fields["goodput_fraction"], 0.85)
-    assert fields["badput_1_bucket"] == "quorum_wait"
-    assert math.isclose(fields["badput_1_share"], 0.1)
-    assert fields["badput_2_bucket"] == "heal_donor"
-    # trace plane off / degenerate window -> additive no-op
-    j_off, _ = make_journal(enabled=False)
-    monkeypatch.setattr(tracing, "default", lambda: j_off)
-    assert bench._ft_goodput_fields(0.0, 10.0) == {}
-    monkeypatch.setattr(tracing, "default", lambda: j)
-    assert bench._ft_goodput_fields(10.0, 10.0) == {}
+    seconds = goodput.fold_events(j._copy_ring(), 0.0, 10.0)
+    wall = sum(seconds.values())
+    assert math.isclose(wall, 10.0)
+    assert math.isclose(seconds["committed_compute"] / wall, 0.85)
+    (first, first_s), (second, second_s) = goodput.top_badput(seconds, n=2)
+    assert first == "quorum_wait" and math.isclose(first_s / wall, 0.1)
+    assert second == "heal_donor" and math.isclose(second_s / wall, 0.05)
+    # a window that collapsed folds to nothing, whatever the ring holds
+    assert sum(goodput.fold_events(j._copy_ring(), 10.0, 10.0).values()) == 0.0
+    assert sum(goodput.fold_events(j._copy_ring(), 10.0, 0.0).values()) == 0.0
 
 
 def test_manager_env_constants_registered() -> None:

@@ -384,9 +384,10 @@ def test_llama_blockwise_impl_matches_dense_model() -> None:
     "overrides",
     [
         {},
-        # The bench presets' combination: the Pallas kernel (interpreted
-        # off-TPU) inside a remat'd scan cell, where "dots" also keeps the
-        # kernel's named (out, lse).
+        # The combination every chip configuration runs (chipbench's cells,
+        # chip_smoke.py): the Pallas kernel (interpreted off-TPU) inside a
+        # remat'd scan cell, where "dots" also keeps the kernel's named
+        # (out, lse).
         {"attention_impl": "flash", "scan_layers": True,
          "attention_block_size": 16},
     ],

@@ -150,14 +150,14 @@ def test_quant_kernels_lower_for_tpu(wire, n_blocks):
 
 
 def test_flagship_flash_train_step_lowers_for_tpu(monkeypatch):
-    """Cross-lower the FULL ~445M large-bench train step (scan llama +
-    dots-remat + Pallas flash fwd/bwd + fused CE + sgd update) for a TPU
-    target — the integration-level version of the kernel gates above.
-    bench.py's large config compiles exactly this program shape on the
-    chip (TPUFT_BENCH_MODEL=large; the config comes from the shared
-    ``large_bench_config()`` so the gate cannot drift from the bench);
-    a lowering regression anywhere in that stack fails here instead of
-    on the chip. Everything is abstract (jax.eval_shape) —
+    """Cross-lower the FULL ~445M train step (scan llama + dots-remat +
+    Pallas flash fwd/bwd + fused CE + sgd update) for a TPU target — the
+    integration-level version of the kernel gates above. The config is
+    the shared ``large_bench_config()`` that scripts/hbm_probe.py sizes
+    and benchmarks/compile_bench.py compiles, and the switches are those
+    of every chip configuration (chipbench's cells, chip_smoke.py); a
+    lowering regression anywhere in that stack fails here instead of on
+    the chip. Everything is abstract (jax.eval_shape) —
     no 445M params materialize.
     """
     import optax
@@ -172,9 +172,9 @@ def test_flagship_flash_train_step_lowers_for_tpu(monkeypatch):
     monkeypatch.setattr(fa_mod, "on_tpu", lambda: True)
     monkeypatch.setattr(llama_mod, "on_tpu", lambda: True)
 
-    # The SHARED flagship definition: the gate must lower exactly the
-    # program bench.py's large mode runs (a copied config drifted when
-    # the head geometry was retuned — review finding, round 5).
+    # The SHARED definition: the gate must lower exactly the program the
+    # HBM probe sizes (a copied config drifted when the head geometry was
+    # retuned — review finding, round 5).
     config = llama_mod.large_bench_config()
     seq = config.max_seq_len
     model = Llama(config)

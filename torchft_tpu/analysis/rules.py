@@ -791,8 +791,9 @@ def _check_r7(module: Module, reference_root: Optional[Path] = None) -> List[Fin
 # --- R8: metric-doc-drift ---------------------------------------------------
 # METRICS.md is the canonical metric registry (metrics.py module docstring):
 # every metric name the package emits must have a table row, and every table
-# row must correspond to a live emission site — else dashboards, the bench's
-# ft_phase_* fields, and fleet_status cells silently drift from the code.
+# row must correspond to a live emission site — else dashboards, the
+# benchmark's counter readers, and fleet_status cells silently drift from
+# the code.
 # Anchored at torchft_tpu/metrics.py so the repo-wide scan runs exactly once
 # per analysis (the rule is a whole-tree property, not a per-module one);
 # findings anchor at the offending emission site / METRICS.md row, so the

@@ -31,8 +31,8 @@ KNOWN_ENV = {
     "TPUFT_JAX_COORDINATOR", "TPUFT_TCP_RING_MIN_MB", "TPUFT_TRACE_LOG",
     "TPUFT_NATIVE_LIB", "TPUFT_ALLOW_UNSAFE_PICKLE", "TPUFT_SOAK",
     "TPUFT_FLIGHT_RECORDER", "TPUFT_FLIGHT_RECORDER_SIZE",
-    "TPUFT_HEARTBEAT_INTERVAL", "TPUFT_INIT_SYNC", "TPUFT_STRICT_COMMIT",
-    "TPUFT_COMMIT_PIPELINE", "TPUFT_EMULATED_DEVICE_RTT_MS",
+    "TPUFT_STRICT_COMMIT", "TPUFT_COMMIT_PIPELINE",
+    "TPUFT_EMULATED_DEVICE_RTT_MS",
     # Depth-N commit pipelining: window depth (int or "auto") and the
     # adaptive controller's depth ceiling.
     "TPUFT_COMMIT_PIPELINE_DEPTH", "TPUFT_COMMIT_PIPELINE_ADAPTIVE",
@@ -76,8 +76,6 @@ KNOWN_ENV = {
     # codecs for heal chunks, serving fan-out, and the ZeRO shard legs
     # (fp32 default = bit-for-bit the pre-codec wire).
     "TPUFT_HEAL_CODEC", "TPUFT_SERVING_CODEC", "TPUFT_ZERO_CODEC",
-    "TPUFT_BENCH_MODEL", "TPUFT_BENCH_STEPS", "TPUFT_BENCH_BATCH",
-    "TPUFT_BENCH_SEQ", "TPUFT_BENCH_SYNC_EVERY", "TPUFT_BENCH_SYNC_DELAY",
     "TPUFT_EMULATED_RTT_MS", "TPUFT_EMULATED_GBPS",
     # WAN topology matrix (utils/netem.py): replica-id -> region map,
     # explicit self-region override, relay-tier region pin, and the heal

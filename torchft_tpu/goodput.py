@@ -16,8 +16,8 @@ the rest? This module adds that currency:
   retains them in a byte-budgeted :class:`metrics.WindowedSeries` ring so
   rates are queryable live, counts ``tpuft_goodput_*``, and builds the
   ``goodput`` payload each Manager pushes through the quorum store
-  (feeding fleet_status's GOODPUT column, ``scripts/goodput_report.py``,
-  and the bench line's ``goodput_fraction``).
+  (feeding fleet_status's GOODPUT column and
+  ``scripts/goodput_report.py``).
 - :class:`SloEvaluator` — declarative burn-rate alerting
   (``TPUFT_SLO_GOODPUT=0.95`` style) with the health plane's K-consecutive
   -windows hysteresis: a window "burns" when badput spends the error

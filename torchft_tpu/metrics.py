@@ -10,8 +10,7 @@ transfer) lands in process-local metrics that export on three surfaces —
 - ``prometheus_text()``: the Prometheus exposition format, served by
   :func:`start_http_server` (``$TPUFT_METRICS_PORT``) and by the
   checkpoint transport's HTTP server at ``GET /metrics``;
-- ``snapshot()``: a JSON-safe dict; ``bench.py`` merges it into its one
-  JSON line as ``ft_phase_*`` fields, the flight recorder appends it as a
+- ``snapshot()``: a JSON-safe dict; the flight recorder appends it as a
   dump trailer, and each Manager pushes it into its group store under
   ``metrics/<replica_id>/<group_rank>`` for ``scripts/fleet_status.py``;
 - direct reads: :func:`counter_total` / :func:`histogram_stats` for tests

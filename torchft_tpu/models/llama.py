@@ -153,15 +153,15 @@ CONFIGS: Dict[str, LlamaConfig] = {
 
 
 def large_bench_config(**overrides) -> LlamaConfig:
-    """The ~445M-parameter flagship benchmark config — the ONE definition.
+    """The ~445M-parameter config of the probes — the ONE definition.
 
-    Shared by bench.py's ``TPUFT_BENCH_MODEL=large`` run, the chipless
-    HBM sizing probe (scripts/hbm_probe.py), the compile-cost bench
-    (benchmarks/compile_bench.py base dims), and the Mosaic
-    cross-lowering gate (tests/test_mosaic_lowering.py) so the gate and
-    probes always track the config the bench actually runs — the config
-    used to be copied verbatim into all four files, and a retune in one
-    silently drifted the other three.
+    Shared by the chipless HBM sizing probe (scripts/hbm_probe.py), the
+    compile-cost bench (benchmarks/compile_bench.py base dims), and the
+    Mosaic cross-lowering gate (tests/test_mosaic_lowering.py), so the
+    three always size, compile and lower the same program — the config
+    used to be copied verbatim into each file, and a retune in one
+    silently drifted the others. No cell of the benchmark runs it
+    (``chipbench/`` has its own configurations).
 
     The choices (their speed on today's v5e: not measured):
 

@@ -30,10 +30,10 @@ The scan-based blockwise backward remains the
 interpret/CPU fallback (``use_pallas_bwd`` selects; CPU tests run the
 Pallas backward in interpret mode explicitly). Run :func:`verify_on_chip`
 on the chip after any kernel change (the CLAUDE.md kernel-verification
-gate — chip_smoke.py and every chip bench.py run re-execute it, forward
-and backward). In the CPU suite, tests/test_mosaic_lowering.py cross-lowers
-every kernel here for a TPU target (block-layout violations, the class
-interpret mode cannot see) and tests/test_tpu_aot_compile.py compiles them
+gate — every chip_smoke.py run re-executes it, forward and backward). In
+the CPU suite, tests/test_mosaic_lowering.py cross-lowers every kernel here
+for a TPU target (block-layout violations, the class interpret mode cannot
+see) and tests/test_tpu_aot_compile.py compiles them
 at real widths for a described v5e (VMEM limits, misaligned slices) — both
 without a chip.
 Note "auto" attention (models/llama.py) SELECTS this kernel on real TPU

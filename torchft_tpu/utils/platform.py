@@ -25,9 +25,8 @@ _CHECKOUT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 def cpu_by_name(env: Optional[Mapping[str, str]] = None) -> bool:
     """True where the caller selected the CPU by name: ``JAX_PLATFORMS=cpu``
     in ``env`` (default this process's environment). The one rule for it:
-    nothing in the repo picks the CPU in code, the launcher hands out no
-    chips under it, and ``bench.py --cpu`` runs only under it. Touches no
-    backend."""
+    nothing in the repo picks the CPU in code, and the launcher hands out
+    no chips under it. Touches no backend."""
     platforms = (os.environ if env is None else env).get("JAX_PLATFORMS", "")
     return platforms.strip().lower() == "cpu"
 
@@ -45,9 +44,9 @@ def on_tpu() -> bool:
 def require_tpu() -> Any:
     """The default device when it is a TPU; exits non-zero otherwise.
 
-    For chip scripts (``chip_smoke.py``, ``bench.py``, the kernel benches
-    and sweeps): with no chip JAX answers on the CPU, and a measurement
-    taken there must never be printed under a device's name. Runs
+    For chip scripts (``chip_smoke.py``, the kernel benches and sweeps):
+    with no chip JAX answers on the CPU, and a measurement taken there must
+    never be printed under a device's name. Runs
     in-process — the chip belongs to one process at a time, so a probe in
     a child would take it from the caller."""
     import jax
