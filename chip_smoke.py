@@ -64,9 +64,10 @@ GiB = 2**30
 # layers) — 11.7 GiB predicted, 11.5 measured on the chip. At 6 layers that
 # is 13.7 GiB before the ~1.5 GiB of finished sync payloads the manager's
 # timed futures keep alive for their timeout: no margin, so 4. (FT-DDP alone
-# — committed state + speculative state + one older version in the history
-# ring — measured 8.6 GiB at 4 layers and would fit 6; 8 is predicted at
-# 15.8.)
+# — committed state + speculative state; at depth 0 the history ring's one
+# version IS the committed state — stays under the plain phase's 5.79 GiB
+# at 4 layers, measured; it was 8.6 while the ring pinned one older
+# version.)
 SMOKE_LAYERS = 4
 BATCH, SEQ = 4, 2048
 

@@ -8,8 +8,10 @@ the FT-DDP fused step (not donated: the committed state stays beside the
 speculative one) for one described ``v5e:2x2`` device and prints what
 ``memory_analysis()`` says against the chip's 15.75 GiB — that compile does
 not itself refuse a program that is too large. It counts one program: what
-else the process keeps on the device (the manager's history ring, DiLoCo's
-backups and outer state) is added by hand. A compile that passes is a
+else the process keeps on the device (a pipelined manager's history ring of
+depth + 1 versions — at depth 0 its one version is the committed state the
+step already counts as its input — DiLoCo's backups and outer state) is
+added by hand. A compile that passes is a
 rehearsal, never a chip run.
 
 Usage (run with JAX_PLATFORMS=cpu):

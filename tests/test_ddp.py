@@ -737,6 +737,188 @@ def test_pipelined_donor_send_history_miss_falls_back_to_drained_step(
     assert opt.flush_pipeline() is True
 
 
+def test_strict_depth0_donor_send_stages_live_max_step_without_the_ring():
+    """The depth-0 twin of the two donor tests above: a strict donor's
+    live committed step IS ``quorum.max_step`` (nothing drains past it),
+    so the send stages the live state under its own label and never asks
+    the ring — which at depth 0 holds that one state and nothing older.
+    Neither the exact-serve nor the miss counter moves."""
+    from torchft_tpu import metrics as ft_metrics
+
+    manager = scripted_manager()
+    transport = manager._checkpoint_transport
+    opt = Optimizer(
+        manager, optax.sgd(0.1), {"w": jnp.array([1.0, 1.0], jnp.float32)}
+    )
+    seen = []
+
+    def spy_send(dst_ranks, step, state_dict, timeout, quorum_id=None):
+        seen.append((step, manager.current_step(), state_dict))
+
+    transport.send_checkpoint.side_effect = spy_send
+    step_fn = opt.make_step_fn(lambda p, b: jnp.sum(p["w"] * b))
+    for _ in range(2):
+        _, committed = step_fn(jnp.array([1.0, 2.0], jnp.float32))
+        assert committed
+    exact_before = ft_metrics.counter_total("tpuft_history_exact_serves_total")
+    miss_before = ft_metrics.counter_total("tpuft_history_misses_total")
+    # A joiner is assigned to heal from us inside the same era: the
+    # lighthouse's max_step is the step we reported, our live step.
+    manager._client._quorum.return_value = make_quorum(
+        quorum_id=1, replica_world_size=1, max_world_size=1,
+        recover_dst_replica_ranks=[1], max_step=2,
+    )
+    _, committed = step_fn(jnp.array([1.0, 2.0], jnp.float32))
+    assert committed
+    assert len(seen) == 1
+    staged_step, live_step, state_dict = seen[0]
+    assert staged_step == live_step == 2
+    assert state_dict["tpuft"]["step"] == 2
+    # Committed step 2 is w0 - 2 * 0.1 * [1, 2], read from the live state.
+    np.testing.assert_allclose(
+        np.asarray(state_dict["user"]["optimizer"]["params"]["w"]),
+        np.array([0.8, 0.6], np.float32),
+        rtol=1e-6,
+    )
+    assert (
+        ft_metrics.counter_total("tpuft_history_exact_serves_total")
+        == exact_before
+    )
+    assert ft_metrics.counter_total("tpuft_history_misses_total") == miss_before
+    assert manager.current_step() == 3
+    assert manager.history.resident_steps() == [3]
+
+
+# -- the strict step's two copies of the state --------------------------------
+
+
+def _state_leaves(opt):
+    return jax.tree_util.tree_leaves((opt.params, opt.opt_state))
+
+
+def _ring_state(manager, opt):
+    """The ring's version at the manager's committed step, or None."""
+    entry = manager.history.state_dict_at(
+        manager.current_step(), {opt._register_key}
+    )
+    return None if entry is None else entry["user"][opt._register_key]
+
+
+def _strict_setup(path):
+    """A depth-0 manager, an Adam optimizer over one leaf, and ``run(batch)
+    -> committed`` through one of the strict entry points: the fused
+    lone-replica step_fn, its wire branch, ``Optimizer.step``."""
+    manager = scripted_manager()
+    opt = Optimizer(
+        manager, optax.adam(0.1), {"w": jnp.array([1.0, -2.0, 3.0], jnp.float32)}
+    )
+
+    def loss_fn(p, batch):
+        return jnp.sum((p["w"] - batch) ** 2)
+
+    if path == "step":
+        grad_fn = jax.jit(jax.grad(loss_fn))
+
+        def run(batch):
+            opt.begin_step()
+            return opt.step(grad_fn(opt.params, batch))
+
+        return manager, opt, run
+    if path == "wire":
+        manager.is_lone_replica = lambda: False  # other groups participating
+    step_fn = opt.make_step_fn(loss_fn)
+    return manager, opt, lambda batch: step_fn(batch)[1]
+
+
+@pytest.mark.parametrize("path", ["lone", "wire", "step"])
+def test_strict_step_keeps_the_live_state_as_the_rings_one_version(path):
+    """Depth 0, every strict entry point (the fused lone-replica step_fn,
+    its wire branch, ``Optimizer.step``): after each commit the ring holds
+    ONE step, its arrays ARE ``opt.params`` / ``opt.opt_state`` (identity:
+    the version costs no memory) and the committed state of the step
+    before is collectible — two copies of the state while a step is in
+    flight (committed N, speculative N + 1), one between steps, where the
+    ring used to pin N - 1 as a third."""
+    import gc
+    import weakref
+
+    from torchft_tpu import metrics as ft_metrics
+
+    manager, opt, run = _strict_setup(path)
+    misses_before = ft_metrics.counter_total("tpuft_history_misses_total")
+    older = []  # weak references to every earlier committed state's leaves
+    for i in range(4):
+        previous = [weakref.ref(leaf) for leaf in _state_leaves(opt)]
+        assert run(jnp.full((3,), 0.1 * i, jnp.float32))
+        older.append(previous)
+        step = manager.current_step()
+        assert step == i + 1
+        assert manager.history.resident_steps() == [step]
+        held = _ring_state(manager, opt)
+        assert held["params"] is opt.params
+        assert held["opt_state"] is opt.opt_state
+        del held
+        assert ft_metrics.gauge_value("tpuft_history_versions", ring="state") == 1.0
+        assert ft_metrics.gauge_value(
+            "tpuft_history_bytes", ring="state"
+        ) == opt._snapshot_nbytes((opt.params, opt.opt_state))
+        gc.collect()
+        dead = [ref() is None for refs in older for ref in refs]
+        assert all(dead), f"step {step}: an older committed state is still held"
+    assert ft_metrics.counter_total("tpuft_history_misses_total") == misses_before
+
+
+@pytest.mark.parametrize("path", ["lone", "wire", "step"])
+def test_strict_refused_commit_leaves_state_n_live_and_the_rings(path):
+    """A refused commit at depth 0 keeps the committed state of N: the same
+    arrays stay ``opt.params`` / ``opt.opt_state`` AND the ring's one
+    version, the refused speculation is dropped, and the next commit moves
+    both to N + 1."""
+    import gc
+    import weakref
+
+    manager, opt, run = _strict_setup(path)
+    batch = jnp.full((3,), 0.5, jnp.float32)
+    assert run(batch)
+    live = _state_leaves(opt)
+    manager._client.should_commit.side_effect = (
+        lambda rank, step, vote, timeout: False
+    )
+    assert not run(batch)
+    assert manager.current_step() == 1
+    assert manager.history.resident_steps() == [1]
+    held = _ring_state(manager, opt)
+    assert held["params"] is opt.params and held["opt_state"] is opt.opt_state
+    assert all(a is b for a, b in zip(_state_leaves(opt), live))
+    manager._client.should_commit.side_effect = (
+        lambda rank, step, vote, timeout: vote
+    )
+    refs = [weakref.ref(leaf) for leaf in live]
+    del live, held
+    assert run(batch)
+    assert manager.current_step() == 2
+    assert manager.history.resident_steps() == [2]
+    assert _ring_state(manager, opt)["params"] is opt.params
+    gc.collect()
+    assert all(ref() is None for ref in refs)
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_pipelined_ring_still_holds_the_window(depth):
+    """The same run at depth N keeps N + 1 committed versions, as before."""
+    manager = scripted_manager(commit_pipeline_depth=depth)
+    opt = Optimizer(manager, optax.sgd(0.1), {"w": jnp.ones(2, jnp.float32)})
+    step_fn = opt.make_step_fn(lambda p, b: jnp.sum(p["w"] * b))
+    for _ in range(depth + 4):
+        step_fn(jnp.array([1.0, 2.0], jnp.float32))
+    assert opt.flush_pipeline() is True
+    newest = manager.current_step()
+    assert manager.history.max_versions == depth + 1
+    assert manager.history.resident_steps() == list(
+        range(newest - depth, newest + 1)
+    )
+
+
 def test_adaptive_depth_deepens_under_stall_and_reevaluates_per_era(monkeypatch):
     """commit_pipeline_depth="auto": a barrier RTT the current window
     cannot hide deepens it (bounded by TPUFT_COMMIT_PIPELINE_ADAPTIVE);

@@ -121,6 +121,31 @@ def test_k1_degrades_to_live_state_only(monkeypatch) -> None:
     assert hist.resident_steps() == [3]
 
 
+@pytest.mark.parametrize("depth, want", [("0", 1), ("1", 2), ("3", 4), ("auto", 5)])
+def test_doctor_names_the_ring_width_a_manager_of_that_depth_gets(
+    monkeypatch, depth, want
+) -> None:
+    """The preflight's sentence and ``Manager.__init__`` follow one rule,
+    window + 1 (one version at the default depth 0)."""
+    from test_manager import make_manager
+
+    from torchft_tpu import doctor
+
+    for name in (
+        ENV_HISTORY_MAX_VERSIONS,
+        ENV_HISTORY_BYTES,
+        "TPUFT_COMMIT_PIPELINE",
+        "TPUFT_COMMIT_PIPELINE_ADAPTIVE",
+    ):
+        monkeypatch.delenv(name, raising=False)
+    monkeypatch.setenv("TPUFT_COMMIT_PIPELINE_DEPTH", depth)
+    status, message = doctor._check_history()
+    assert status == "PASS"
+    assert f"manager keeps {want} committed version(s)" in message
+    manager, _, _, _ = make_manager()  # reads the same environment
+    assert manager.history.max_versions == want
+
+
 # ---------------------------------------------------------------------------
 # StagedVersionStore
 # ---------------------------------------------------------------------------

@@ -730,3 +730,53 @@ def test_allreduce_pytree_buckets_mixed_dtypes() -> None:
     assert np.all(np.abs(out2["small"]) > 0), "small leaf crushed by shared fp8 scale"
     np.testing.assert_allclose(out2["small"], np.full(512, 5e-5), rtol=0.1)
     np.testing.assert_allclose(out2["big"], np.full(512, 150.0), rtol=0.1)
+
+
+@pytest.mark.parametrize(
+    "depth, adaptive_env, versions_env, want",
+    [
+        (0, None, None, 1),  # strict: the live committed state, nothing older
+        (1, None, None, 2),
+        (2, None, None, 3),
+        (3, None, None, 4),
+        ("auto", None, None, 5),  # DEFAULT_ADAPTIVE_MAX_DEPTH + 1
+        ("auto", "2", None, 3),
+        (0, None, "3", 3),  # TPUFT_HISTORY_MAX_VERSIONS keeps its meaning
+        (2, None, "1", 1),
+        ("auto", "2", "1", 1),
+    ],
+)
+def test_history_ring_is_sized_by_the_commit_window(
+    monkeypatch, depth, adaptive_env, versions_env, want
+) -> None:
+    """The committed-state ring holds window + 1 versions at every depth:
+    one at depth 0 (a strict step keeps committed N and speculative N + 1,
+    never N - 1 as a third copy), depth + 1 pipelined, adaptive max + 1
+    under ``auto``; the environment's cap overrides either way."""
+    for name in (
+        "TPUFT_COMMIT_PIPELINE",
+        "TPUFT_COMMIT_PIPELINE_DEPTH",
+        "TPUFT_HISTORY_BYTES",
+    ):
+        monkeypatch.delenv(name, raising=False)
+    for name, value in (
+        ("TPUFT_COMMIT_PIPELINE_ADAPTIVE", adaptive_env),
+        ("TPUFT_HISTORY_MAX_VERSIONS", versions_env),
+    ):
+        if value is None:
+            monkeypatch.delenv(name, raising=False)
+        else:
+            monkeypatch.setenv(name, value)
+    manager, _, _, _ = make_manager(commit_pipeline_depth=depth)
+    assert manager.history.max_versions == want
+
+
+def test_strict_commit_env_keeps_the_ring_the_manager_was_built_with(
+    monkeypatch,
+) -> None:
+    """TPUFT_STRICT_COMMIT=1 is read per make_step_fn and the window may be
+    re-entered, so it never narrows a depth >= 1 manager's ring."""
+    monkeypatch.delenv("TPUFT_HISTORY_MAX_VERSIONS", raising=False)
+    monkeypatch.setenv("TPUFT_STRICT_COMMIT", "1")
+    manager, _, _, _ = make_manager(commit_pipeline_depth=2)
+    assert manager.history.max_versions == 3

@@ -893,7 +893,7 @@ def _check_history() -> Tuple[str, str]:
             depth = int(depth_raw) if depth_raw else 0
         except ValueError:
             depth = 0
-    k = hist.history_max_versions(max(1, depth) + 1)
+    k = hist.history_max_versions(depth + 1)
     serving_k = hist.history_max_versions(hist.DEFAULT_SERVING_VERSIONS)
     budget = hist.history_bytes_budget()
     budget_note = (

@@ -39,8 +39,12 @@ full (params, opt_state) copy per version is THE memory cost) and is
 capped by ``TPUFT_HISTORY_MAX_VERSIONS``. The newest committed version
 is never evicted; ``K=1`` degrades bit-for-bit to the pre-history
 behavior (only the live committed state exists). Defaults: the manager
-ring sizes itself off the commit-pipeline depth (depth+1 — the versions
-the rollback ring already held), the serving store keeps
+ring sizes itself by the commit window, window + 1 at every depth (the
+versions the rollback ring already held; ``auto`` counts its adaptive
+maximum as the window), so a strict depth-0 manager runs at ``K=1``: its
+one version IS the live committed state, held by reference at no cost in
+memory, and a strict step keeps two copies of the state (committed N,
+speculative N + 1), never a third. The serving store keeps
 :data:`DEFAULT_SERVING_VERSIONS`.
 """
 
@@ -137,6 +141,10 @@ class WeightHistory:
         self._ring = ring
         self._lock = threading.Lock()
         self._entries: "OrderedDict[int, _StateEntry]" = OrderedDict()
+
+    @property
+    def max_versions(self) -> int:
+        return self._max_versions
 
     # -- ingestion ---------------------------------------------------------
 

@@ -511,18 +511,26 @@ class Manager:
 
         # Versioned weight history (torchft_tpu/history.py): the ring of
         # committed state refs the optimizer promotes into at commit
-        # resolution. Sized off the commit-pipeline window by default —
-        # depth+1 versions are exactly what the rollback ring already
-        # held, so a deep-window donor can serve quorum.max_step EXACTLY
-        # after a drain advanced its live step past it (the PR-9
-        # "fail cleanly and retry" round becomes an immediate serve).
-        # TPUFT_HISTORY_MAX_VERSIONS / TPUFT_HISTORY_BYTES override.
+        # resolution. Sized by the commit window, window + 1 at every
+        # depth: the versions the rollback ring already held, so a
+        # deep-window donor can serve quorum.max_step EXACTLY after a
+        # drain advanced its live step past it (the PR-9 "fail cleanly
+        # and retry" round becomes an immediate serve). At depth 0 that
+        # is ONE version, the live committed state by reference: a
+        # strict step holds committed N and speculative N + 1, and
+        # nothing reads older (a strict donor's live step never passes
+        # quorum.max_step, so the donor path never consults the ring).
+        # The window is the one this manager was BUILT with:
+        # TPUFT_STRICT_COMMIT=1 over a depth >= 1 manager keeps that
+        # manager's ring (the override is read per make_step_fn, and the
+        # window may be re-entered). TPUFT_HISTORY_MAX_VERSIONS /
+        # TPUFT_HISTORY_BYTES override.
         window = (
             self._adaptive_max_depth
             if self._commit_pipeline_adaptive
             else self._commit_pipeline_depth
         )
-        self._history = WeightHistory(max_versions=max(1, int(window)) + 1)
+        self._history = WeightHistory(max_versions=window + 1)
 
         # Per-step error/heal state.
         self._errored: Optional[ExceptionWithTraceback] = None
