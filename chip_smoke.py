@@ -376,8 +376,10 @@ class OneChip:
             flash = flash_attention.verify_on_chip()
             say(
                 f"  flash vs dense: fwd {flash['max_err']:.4f} bwd "
-                f"{flash['max_err_bwd']:.4f} partial {flash['max_err_partial']:.4f}"
+                f"{flash['max_err_bwd']:.4f} partial {flash['max_err_partial']:.4f} "
+                f"zigzag {flash['max_err_zigzag']:.4f} ragged {flash['max_err_ragged']:.4f}"
             )
+            say(f"  block pairs by class of the schedule: {flash['classes']}")
             quant = quantization.verify_on_chip()
             say(
                 "  codec vs host reference: "

@@ -1,122 +1,144 @@
 #!/usr/bin/env python
-"""On-chip block-size sweep for the Pallas flash-attention kernels.
+"""On-chip block-size sweep for the Pallas flash-attention kernels, read
+from the device trace.
 
 The kernels take ``block_q``/``block_k`` at every entry point, so tuning is
-a pure measurement problem — no kernel edits. At seq 2-8k larger blocks
-than 128x128 amortize per-grid-step
-overhead (mask compare, accumulator correction, block copies) and keep the
-MXU busy longer per VMEM residency. VMEM bound: the f32 scores tile is
-block_q x block_k x 4 B — 512x1024 is 2 MB, well inside the ~16 MB budget
-even double-buffered.
+a pure measurement problem — no kernel edits. One call a (seq, block pair):
+``jax.grad`` of a loss over :func:`flash_attention`, which runs the three
+kernels of a layer step (forward, dq, dkv), at the benchmark cells' head
+geometry (32 q heads over 8 KV heads of 128, bf16) and tokens a step
+(8192: batch 4 at 2048, batch 1 at 8192). Each kernel's time is the sum of
+its Mosaic call's device durations in a profiler trace of ITERS calls
+(``chipbench/trace_reduce.py`` reads the file), so no dispatch cost and
+nothing of the XLA ops around the kernels is in it. ``mxu_pct`` is the
+operations causal attention needs for the call (``chipbench/flops.py``: 7
+matmuls, the mask's half) over the three kernels' seconds, against the
+chip's bf16 peak: what the cells report as ``flash_mxu_pct``.
 
-Timing matches benchmarks/kernel_bench.py: data-chained iterations closed
-by a value fetch, median of 3.
+Usage:
+    python scripts/flash_block_sweep.py [--tree DIR] [--pairs 512x1024,...] [seq ...]
 
-Usage: python scripts/flash_block_sweep.py [seq ...]   (default 2048 8192)
-Prints one JSON line per (seq, block_q, block_k): fwd ms + fwd/bwd ms.
+``--tree`` imports ``torchft_tpu`` from another checkout (a parent commit
+unpacked beside this one), so two trees are read the same way in one chip
+call. Prints one JSON line a (seq, block_q, block_k); default seqs 2048 8192.
 """
 
 from __future__ import annotations
 
+import argparse
 import json
 import sys
-import time
+import tempfile
 from pathlib import Path
 
-sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+ROOT = Path(__file__).resolve().parent.parent
+sys.path.append(str(ROOT))
 
-from torchft_tpu.utils.platform import require_tpu
-
-require_tpu()  # a chip script: exits non-zero when jax answers on anything else
-
-import jax
-import jax.numpy as jnp
-
-ITERS = 6
+from chipbench import trace_reduce  # noqa: E402  (no JAX at import)
+HEADS, KV_HEADS, HEAD_DIM = 32, 8, 128
+TOKENS = 8192
+PAIRS = [(bq, bk) for bq in (256, 512, 1024) for bk in (256, 512, 1024, 2048)]
+ITERS = 8
 WARMUP = 2
 
 
-def _force(x):
-    leaf = jax.tree_util.tree_leaves(x)[0]
-    float(jnp.asarray(leaf).reshape(-1)[0])
+def kernel_ms(trace: Path) -> dict:
+    """Milliseconds a call of each Mosaic kernel in the trace, by kernel.
+    XLA orders dq and dkv as it likes, so a call is told by what it returns:
+    the forward (out, logsumexp in float32), dq one array, dkv two."""
+    from jax.profiler import ProfileData
 
-
-def _timed(fn, *args, fetch=None):
-    out = None
-    for _ in range(WARMUP):
-        out = fn(*args)
-    _force(out if fetch is None else fetch(out))
-    times = []
-    for _ in range(3):
-        t0 = time.monotonic()
-        cur = args
-        for _ in range(ITERS):
-            out = fn(*cur)
-            first = jax.tree_util.tree_leaves(out)[0]
-            if hasattr(cur[0], "shape") and first.shape == cur[0].shape:
-                cur = (first.astype(cur[0].dtype),) + tuple(cur[1:])
-        _force(out if fetch is None else fetch(out))
-        times.append((time.monotonic() - t0) / ITERS)
-    return sorted(times)[1]
+    total = {}
+    for plane in ProfileData.from_file(str(trace)).planes:
+        if not trace_reduce.DEVICE_PLANE.match(plane.name):
+            continue
+        for line in plane.lines:
+            if line.name != trace_reduce.OPS_LINE:
+                continue
+            for event in line.events:
+                if trace_reduce.KERNEL_MARK not in event.name:
+                    continue
+                result = event.name.partition(" = ")[2].partition(" custom-call(")[0]
+                shapes = trace_reduce.SHAPE.findall(result)
+                kernel = (
+                    "fwd" if any(x.startswith("f32") for x in shapes)
+                    else "dkv" if len(shapes) == 2 else "dq"
+                )
+                total[kernel] = total.get(kernel, 0.0) + event.duration_ns
+    return {k: v / ITERS / 1e6 for k, v in total.items()}
 
 
 def main() -> None:
+    parser = argparse.ArgumentParser()
+    parser.add_argument("--tree", default=str(ROOT))
+    parser.add_argument("--pairs", default="")
+    parser.add_argument("seqs", nargs="*", type=int)
+    args = parser.parse_args()
+    sys.path.insert(0, args.tree)
+
+    from torchft_tpu.utils.platform import require_tpu
+
+    require_tpu()  # a chip script: exits non-zero when jax answers on anything else
+
+    import jax
+    import jax.numpy as jnp
+
+    from chipbench import flops
+    from chipbench.harness import peaks_for
     from torchft_tpu.ops.flash_attention import flash_attention
 
-    seqs = [int(a) for a in sys.argv[1:]] or [2048, 8192]
-    b, h, kv, d = 4, 8, 4, 128
-    for s in seqs:
-        kq, kk, kvk = jax.random.split(jax.random.PRNGKey(0), 3)
-        q = jax.random.normal(kq, (b, s, h, d), jnp.bfloat16)
-        k = jax.random.normal(kk, (b, s, kv, d), jnp.bfloat16)
-        v = jax.random.normal(kvk, (b, s, kv, d), jnp.bfloat16)
-        r = jax.random.normal(jax.random.PRNGKey(2), (b, s, h, d), jnp.float32)
-        for bq in (128, 256, 512):
-            for bk in (128, 256, 512, 1024):
-                if bk > s or bq > s:
-                    continue
+    peak = peaks_for(jax.devices()[0].device_kind)["bf16_tflops"] * 1e12
+    pairs = [
+        tuple(int(x) for x in p.split("x")) for p in args.pairs.split(",") if p
+    ] or PAIRS
+    geometry = {
+        "head_dim": HEAD_DIM, "num_attention_heads": HEADS, "num_hidden_layers": 1,
+    }
+    for s in args.seqs or [2048, 8192]:
+        b = max(1, TOKENS // s)
+        kq, kk, kvk, kr = jax.random.split(jax.random.PRNGKey(0), 4)
+        q = jax.random.normal(kq, (b, s, HEADS, HEAD_DIM), jnp.bfloat16)
+        k = jax.random.normal(kk, (b, s, KV_HEADS, HEAD_DIM), jnp.bfloat16)
+        v = jax.random.normal(kvk, (b, s, KV_HEADS, HEAD_DIM), jnp.bfloat16)
+        r = jax.random.normal(kr, (b, s, HEADS, HEAD_DIM), jnp.float32)
+        need = flops.flash_attention_flops(geometry, b, s)
+        for bq, bk in pairs:
+            if bq > s or bk > s:
+                continue
+            row = {"tree": args.tree, "seq": s, "batch": b, "block_q": bq, "block_k": bk}
 
-                def fwd(q, k, v, _bq=bq, _bk=bk):
-                    return flash_attention(
-                        q, k, v, block_q=_bq, block_k=_bk, interpret=False
-                    )
-
-                def loss(q, k, v, r, _bq=bq, _bk=bk):
-                    return jnp.vdot(
-                        flash_attention(
-                            q, k, v, block_q=_bq, block_k=_bk, interpret=False
-                        ).astype(jnp.float32),
-                        r,
-                    )
-
-                try:
-                    t_f = _timed(jax.jit(fwd), q, k, v)
-                    t_g = _timed(
-                        jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
-                        q, k, v, r,
-                        fetch=lambda g: g[0],
-                    )
-                except Exception as e:
-                    print(
-                        json.dumps(
-                            {
-                                "seq": s, "block_q": bq, "block_k": bk,
-                                "error": str(e).splitlines()[0][:160],
-                            }
-                        ),
-                        flush=True,
-                    )
-                    continue
-                print(
-                    json.dumps(
-                        {
-                            "seq": s, "block_q": bq, "block_k": bk,
-                            "fwd_ms": round(1e3 * t_f, 3),
-                            "fwd_bwd_ms": round(1e3 * t_g, 3),
-                        }
-                    ),
-                    flush=True,
+            def loss(q, k, v, _bq=bq, _bk=bk):
+                out = flash_attention(
+                    q, k, v, block_q=_bq, block_k=_bk, interpret=False
                 )
+                return jnp.vdot(out.astype(jnp.float32), r)
+
+            try:
+                grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+                for _ in range(WARMUP):
+                    jax.block_until_ready(grad(q, k, v))
+                with tempfile.TemporaryDirectory() as log_dir:
+                    jax.profiler.start_trace(log_dir)
+                    for _ in range(ITERS):
+                        out = grad(q, k, v)
+                    jax.block_until_ready(out)
+                    jax.profiler.stop_trace()
+                    (trace,) = Path(log_dir).rglob("*.xplane.pb")
+                    ms = kernel_ms(trace)
+            except Exception as e:  # a pair the compiler refuses is a row, not the end
+                row["error"] = str(e).splitlines()[0][:200]
+                print(json.dumps(row), flush=True)
+                continue
+            if sorted(ms) != ["dkv", "dq", "fwd"]:
+                row["error"] = f"Mosaic kernels in the trace: {sorted(ms)}"
+            else:
+                seconds = sum(ms.values()) / 1e3
+                row.update(
+                    {f"{k}_ms": round(v, 4) for k, v in ms.items()},
+                    kernels_ms=round(1e3 * seconds, 4),
+                    mxu_pct=round(100 * need / seconds / peak, 2),
+                )
+            print(json.dumps(row), flush=True)
 
 
 if __name__ == "__main__":

@@ -489,3 +489,196 @@ def test_ring_flash_pallas_backward_matches_dense(zigzag):
         np.testing.assert_allclose(
             np.asarray(gr), np.asarray(gd), atol=1e-4, err_msg=f"d{name}"
         )
+
+
+# --- causal block scheduling ------------------------------------------------
+#
+# A schedule case is (q positions, k positions, block_q, block_k): positions
+# as one sequence's 1-D arrays, or None for arange over (sq, sk).
+
+
+def _zigzag_hop(rank, src, sp=4, chunk=512):
+    """Positions of ring rank ``rank``'s queries and of the keys it holds on
+    the hop that brings rank ``src``'s shard: each shard is chunks
+    (r, 2 sp - 1 - r) of the sequence."""
+    def shard(r):
+        return np.concatenate(
+            [np.arange(c * chunk, (c + 1) * chunk) for c in (r, 2 * sp - 1 - r)]
+        ).astype(np.int32)
+
+    return shard(rank), shard(src)
+
+
+_SCHEDULES = {
+    # name: (sq, sk, q_pos, k_pos, block_q, block_k), (above, diagonal, under)
+    "causal-8192": ((8192, 8192, None, None, 512, 1024), (56, 16, 56)),
+    "causal-2048": ((2048, 2048, None, None, 512, 1024), (2, 4, 2)),
+    # q shard (1, 6) against k shard (2, 5): the low chunk sees nothing, the
+    # high chunk everything; no pair needs the mask.
+    "zigzag-hop": ((1024, 1024, *_zigzag_hop(1, 2), 256, 256), (8, 0, 8)),
+    "zigzag-self": ((1024, 1024, *_zigzag_hop(1, 1), 256, 256), (6, 4, 6)),
+    # 512 queries at positions 512.. against 1024 keys.
+    "sq-ne-sk": (
+        (512, 1024, np.arange(512, 1024, dtype=np.int32), None, 256, 256),
+        (1, 2, 5),
+    ),
+    # 300 = 4 x 64 + 44 = 2 x 128 + 44: the last q block and the last KV
+    # block are padded.
+    "ragged-300": ((300, 300, None, None, 64, 128), (6, 7, 2)),
+}
+
+
+def _schedule(sq, sk, q_pos, k_pos, block_q, block_k):
+    from torchft_tpu.ops import flash_attention as fa
+
+    qp, kp = fa._padded_positions(
+        None if q_pos is None else jnp.asarray(q_pos)[None],
+        None if k_pos is None else jnp.asarray(k_pos)[None],
+        1, sq, sk, block_q, block_k,
+    )
+    q_sched, k_sched = fa._block_schedule(qp, kp, block_q, block_k, True)
+    return np.asarray(q_sched), np.asarray(k_sched)
+
+
+def _classes(case):
+    from torchft_tpu.ops import flash_attention as fa
+
+    return np.asarray(fa._block_classes(*_schedule(*case)))[0]  # (nq, nk)
+
+
+@pytest.mark.parametrize("name", list(_SCHEDULES))
+def test_block_pairs_classify_by_position(name):
+    """The class of every (q block, KV block) pair is a pure function of the
+    positions and the block sizes: above (0), diagonal (1), under (2)."""
+    case, (above, diagonal, under) = _SCHEDULES[name]
+    classes = _classes(case)
+    counts = tuple(int(np.sum(classes == c)) for c in (0, 1, 2))
+    assert counts == (above, diagonal, under), classes
+    if name == "causal-8192":
+        assert classes.size == 128 and diagonal + under == 72
+    if name == "ragged-300":
+        # A padded row (-1) or column (INT32_MAX) is in the last q block and
+        # the last KV block: masked where needed, never taken for clear.
+        assert 2 not in classes[-1] and 2 not in classes[:, -1]
+        assert classes[-1].tolist() == [1, 1, 1]
+
+
+@pytest.mark.parametrize("name", list(_SCHEDULES))
+def test_no_block_above_the_diagonal_is_fetched(name):
+    """The index maps name, for a pair above the diagonal, the block its
+    neighbour in the walk names (forward and dq: the KV block of the step
+    before; dkv: the q block of the step after), so the pipeline sees an
+    unchanged index and copies nothing; a needed pair names its own."""
+    from torchft_tpu.ops import flash_attention as fa
+
+    case, (above, _, _) = _SCHEDULES[name]
+    q_sched, k_sched = _schedule(*case)
+    classes = _classes(case)
+    nq, nk = classes.shape
+    kv = np.array(
+        [[int(fa._kv_block(0, iq, ik, q_sched)) for ik in range(nk)] for iq in range(nq)]
+    )
+    qb = np.array(
+        [[int(fa._q_block(0, ik, iq, k_sched)) for ik in range(nk)] for iq in range(nq)]
+    )
+    seen = 0
+    for iq in range(nq):
+        for ik in range(nk):
+            if classes[iq, ik]:
+                assert kv[iq, ik] == ik and qb[iq, ik] == iq
+                continue
+            seen += 1
+            # A q block (KV block) that needs nothing names one block for
+            # the whole walk: compare inside the walk only.
+            if ik > 0:
+                assert kv[iq, ik] == kv[iq, ik - 1], (iq, ik)
+            else:
+                assert not classes[iq].any() and len(set(kv[iq])) == 1
+            if iq < nq - 1:
+                assert qb[iq, ik] == qb[iq + 1, ik], (iq, ik)
+            else:
+                assert not classes[:, ik].any() and len(set(qb[:, ik])) == 1
+    assert seen == above
+
+
+# The layouts the kernels are run on (interpret mode), an eighth of the
+# lengths above at 64 x 128 blocks; the ragged case as it is.
+_RUN_CASES = {
+    "causal": (512, 512, None, None, 64, 128),
+    "zigzag-hop": (256, 256, *_zigzag_hop(1, 2, chunk=128), 64, 128),
+    "zigzag-self": (256, 256, *_zigzag_hop(1, 1, chunk=128), 64, 128),
+    "ragged-300": _SCHEDULES["ragged-300"][0],
+}
+
+
+def _positioned_inputs(sq, sk, q_pos, k_pos, b=2, h=4, kv=2, d=16):
+    """f32 q, k, v, dO and the (b, s) position arrays of a run case."""
+    qp = jnp.broadcast_to(
+        jnp.arange(sq, dtype=jnp.int32) if q_pos is None else jnp.asarray(q_pos), (b, sq)
+    )
+    kp = jnp.broadcast_to(
+        jnp.arange(sk, dtype=jnp.int32) if k_pos is None else jnp.asarray(k_pos), (b, sk)
+    )
+    keys = jax.random.split(jax.random.PRNGKey(21), 4)
+    q = jax.random.normal(keys[0], (b, sq, h, d), jnp.float32)
+    k = jax.random.normal(keys[1], (b, sk, kv, d), jnp.float32)
+    v = jax.random.normal(keys[2], (b, sk, kv, d), jnp.float32)
+    d_out = jax.random.normal(keys[3], (b, sq, h, d), jnp.float32)
+    return q, k, v, d_out, qp, kp
+
+
+def _dense_positioned(q, k, v, qp, kp):
+    b, sq, h, d = q.shape
+    kvh = k.shape[2]
+    qg = q.reshape(b, sq, kvh, h // kvh, d)
+    scores = jnp.einsum("bskgd,btkd->bskgt", qg, k) * d**-0.5
+    mask = qp[:, :, None, None, None] >= kp[:, None, None, None, :]
+    p = jax.nn.softmax(jnp.where(mask, scores, -1e30), axis=-1)
+    p = jnp.where(mask.any(axis=-1, keepdims=True), p, 0.0)  # empty rows give 0
+    return jnp.einsum("bskgt,btkd->bskgd", p, v).reshape(b, sq, h, d)
+
+
+@pytest.mark.parametrize("name", list(_RUN_CASES))
+def test_scheduled_kernels_equal_the_masked_ones_bit_for_bit(name, monkeypatch):
+    """Forward, dq, dk, dv with the schedule as the positions give it equal,
+    bit for bit, the same kernels run with no pair classed under (every q
+    block's lowest position forced to INT32_MIN, so every needed pair takes
+    the mask: the arithmetic before the kernels had a schedule), and both
+    match dense attention under the same position mask."""
+    from torchft_tpu.ops import flash_attention as fa
+
+    *layout, block_q, block_k = _RUN_CASES[name]
+    q, k, v, d_out, qp, kp = _positioned_inputs(*layout)
+    d = q.shape[-1]
+
+    def run():
+        out, lse = fa.flash_attention_partial(
+            q, k, v, qp, kp, block_q=block_q, block_k=block_k, interpret=True
+        )
+        grads = fa.flash_attention_partial_bwd(
+            q, k, v, d_out, out, lse, qp, kp, d**-0.5, block_q, block_k, True
+        )
+        return (out, lse) + tuple(grads)
+
+    scheduled = run()
+    classes = []
+    schedule = fa._block_schedule
+
+    def all_diagonal(*args):
+        q_sched, k_sched = schedule(*args)
+        classes.append(np.asarray(fa._block_classes(q_sched, k_sched)))
+        return q_sched.at[:, fa._LO].set(np.iinfo(np.int32).min), k_sched
+
+    monkeypatch.setattr(fa, "_block_schedule", all_diagonal)
+    masked = run()
+    assert len(classes) == 2  # forward, backward
+    assert (classes[0] == 2).any(), "the case exercises no pair under the diagonal"
+    for got, want, what in zip(scheduled, masked, ("out", "lse", "dq", "dk", "dv")):
+        assert np.array_equal(np.asarray(got), np.asarray(want)), what
+
+    dense, vjp = jax.vjp(lambda q, k, v: _dense_positioned(q, k, v, qp, kp), q, k, v)
+    np.testing.assert_allclose(np.asarray(scheduled[0]), np.asarray(dense), atol=2e-5)
+    for got, want, what in zip(scheduled[2:], vjp(d_out), ("dq", "dk", "dv")):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), atol=5e-5, err_msg=what
+        )

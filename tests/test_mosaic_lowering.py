@@ -122,6 +122,69 @@ def test_flash_partial_and_partial_bwd_lower_for_tpu():
     )
 
 
+def _pallas_calls(jaxpr) -> list:
+    """pallas_call equations of a jaxpr, nested bodies (shard_map, pjit)
+    included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _pallas_calls(sub)
+    return found
+
+
+@pytest.mark.parametrize("where", ["plain", "shard_map-batch", "shard_map-positions"])
+def test_flash_kernels_lower_with_their_schedule_tables(where):
+    """Forward, dq and dkv each take the causal block schedule as two
+    scalar-prefetch operands that their index maps and bodies read from
+    SMEM, and lower for a TPU: bare; under a shard_map over the batch (the
+    model's ``_flash_under_ambient_mesh``: tables of ``arange`` that vary
+    over no axis beside data that does); and under a shard_map over the
+    sequence with the positions as arguments (a ring hop: the tables vary
+    with the shard)."""
+    from jax import shard_map
+    from jax.sharding import AbstractMesh, PartitionSpec as P
+
+    b, s, h, kv, d = 2, 1024, 4, 2, 64
+
+    def three(q, k, v, do, qp, kp):
+        out, lse = flash_attention_partial(
+            q, k, v, qp, kp, block_q=128, block_k=256, interpret=False
+        )
+        return flash_attention_partial_bwd(
+            q, k, v, do, out, lse, qp, kp,
+            scale=d**-0.5, block_q=128, block_k=256, interpret=False,
+        )
+
+    def arange(x):
+        return jnp.broadcast_to(jnp.arange(x.shape[1], dtype=jnp.int32), x.shape[:2])
+
+    data = [_sds((b, s, n, d), jnp.bfloat16) for n in (h, kv, kv, h)]
+    positions = [_sds((b, s), jnp.int32)] * 2
+    if where == "plain":
+        fn, args = three, data + positions
+    elif where == "shard_map-batch":
+        fn = shard_map(
+            lambda q, k, v, do: three(q, k, v, do, arange(q), arange(k)),
+            mesh=AbstractMesh((2,), ("fsdp",)),
+            in_specs=(P("fsdp"),) * 4, out_specs=(P("fsdp"),) * 3,
+        )
+        args = data
+    else:
+        fn = shard_map(
+            three, mesh=AbstractMesh((4,), ("sp",)),
+            in_specs=(P(None, "sp"),) * 6, out_specs=(P(None, "sp"),) * 3,
+        )
+        args = data + positions
+    traced = jax.jit(fn).trace(*args)
+    calls = _pallas_calls(traced.jaxpr.jaxpr)
+    assert len(calls) == 3
+    assert [c.params["grid_mapping"].num_index_operands for c in calls] == [2, 2, 2]
+    lowered = traced.lower(lowering_platforms=("tpu",))
+    assert lowered.as_text().count("tpu_custom_call") >= 3
+
+
 @pytest.mark.parametrize("wire", ["fp8", "int8"])
 @pytest.mark.parametrize("n_blocks", [3, 64, 1500, 2048])
 def test_quant_kernels_lower_for_tpu(wire, n_blocks):

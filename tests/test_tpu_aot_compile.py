@@ -129,6 +129,48 @@ def test_flash_partial_pair_compiles_at_ring_hop(chip) -> None:
     )
 
 
+def test_ring_flash_compiles_over_sp4(v5e) -> None:
+    """What ``chip_smoke.py --chips 4`` runs last, at its size: ring attention
+    over an sp=4 mesh of the described chips with the Pallas hops compiled,
+    forward and gradient. Inside the shard_map the hops' positions, and so
+    the kernels' schedule tables, differ by shard."""
+    import numpy as np
+    from jax import shard_map
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from torchft_tpu.ops.ring_attention import ring_attention_flash
+
+    mesh = Mesh(np.array(v5e[:4]), ("sp",))
+    spec = P(None, "sp", None, None)
+
+    def ring(q, k, v):
+        return shard_map(
+            lambda q, k, v: ring_attention_flash(
+                q, k, v, axis_name="sp", interpret=False
+            ),
+            mesh=mesh, in_specs=(spec,) * 3, out_specs=spec,
+        )(q, k, v)
+
+    q, k, v = (
+        jax.ShapeDtypeStruct(
+            (RING_B, 4 * RING_HOP, heads, D), jnp.bfloat16,
+            sharding=NamedSharding(mesh, spec),
+        )
+        for heads in (H, KV, KV)
+    )
+    forward = _compile(ring, q, k, v)
+    assert forward.as_text().count('custom_call_target="tpu_custom_call"') == 1
+    backward = _compile(
+        jax.grad(
+            lambda q, k, v: jnp.sum(ring(q, k, v).astype(jnp.float32) ** 2),
+            argnums=(0, 1, 2),
+        ),
+        q, k, v,
+    )
+    # The forward's hop loop, then dq and dkv in the backward's.
+    assert backward.as_text().count('custom_call_target="tpu_custom_call"') == 3
+
+
 @pytest.mark.parametrize("wire", ["fp8", "int8"])
 def test_codec_pair_compiles_at_fragment_size(chip, wire) -> None:
     from torchft_tpu.ops import quantization as q
@@ -188,10 +230,12 @@ def test_plain_step_compiles_at_smoke_config(chip, monkeypatch) -> None:
     assert total < 15.75 * 2**30, f"plain step needs {total / 2**30:.2f} GiB"
 
 
-# The long-sequence cell's own sizes (chipbench/traffic/ftddp-seq8k.json over
-# the Mistral configuration file), and a twin at toy widths for tier-1: the
+# The cells' own sizes (chipbench/traffic/ftddp-seq8k.json and ftddp.json over
+# the Mistral configuration file), and twins at toy widths for tier-1: the
 # same head_dim, GQA ratio, block sizes and fused-CE chunk, one eighth of the
-# widths and of the sequence.
+# widths and of the sequence. The last column is the step program's
+# temporaries as the commit before the kernels took their schedule tables
+# (8c1185e) compiled it here, in bytes.
 _TOY_WIDTHS = {
     "hidden_size": 256, "num_attention_heads": 2, "num_key_value_heads": 1,
     "intermediate_size": 512, "vocab_size": 4096,
@@ -199,21 +243,28 @@ _TOY_WIDTHS = {
 
 
 @pytest.mark.parametrize(
-    "widths, seq",
+    "widths, batch, seq, temp_before",
     [
-        pytest.param({}, 8192, marks=pytest.mark.slow, id="mistral-1x8192"),  # 30 s
-        pytest.param(_TOY_WIDTHS, 1024, id="toy-1x1024"),
+        pytest.param({}, 1, 8192, 1090004992, marks=pytest.mark.slow, id="mistral-1x8192"),  # 30 s
+        pytest.param({}, 4, 2048, 1058031104, marks=pytest.mark.slow, id="mistral-4x2048"),  # 25 s
+        pytest.param(_TOY_WIDTHS, 1, 1024, 0, id="toy-1x1024"),
+        pytest.param(_TOY_WIDTHS, 4, 256, 0, id="toy-4x256"),
     ],
 )
-def test_dots_step_runs_one_flash_forward_a_layer(chip, monkeypatch, widths, seq) -> None:
-    """The FT-DDP fused step of ``mistral7b-1chip.ftddp-seq8k`` (2 scanned
-    layers, bf16, ``dots``, fused CE 4096, AdamW) compiled twice: as the
-    model builds it, and with ``dots`` meaning plain ``checkpoint_dots``
-    again. The layers stay one loop either way, so the compiled text holds
-    one Mosaic call for each kernel of a layer body: forward, dq, dkv —
-    and under plain ``checkpoint_dots`` the forward a second time, in the
-    backward's loop. Keeping (out, lse) may cost the program's temporaries
-    no more than those two arrays for each layer."""
+def test_dots_step_runs_one_flash_forward_a_layer(
+    chip, monkeypatch, widths, batch, seq, temp_before
+) -> None:
+    """The FT-DDP fused step of ``mistral7b-1chip.ftddp-seq8k`` and of
+    ``mistral7b-1chip.ftddp`` (2 scanned layers, bf16, ``dots``, fused CE
+    4096, AdamW) compiled twice: as the model builds it, and with ``dots``
+    meaning plain ``checkpoint_dots`` again. The layers stay one loop either
+    way, so the compiled text holds one Mosaic call for each kernel of a
+    layer body: forward, dq, dkv — and under plain ``checkpoint_dots`` the
+    forward a second time, in the backward's loop. Keeping (out, lse) may
+    cost the program's temporaries no more than those two arrays for each
+    layer, and the kernels' schedule tables (two small int32 arrays a call,
+    constants where the positions are ``arange``) no more than 1 MiB over
+    what the program needed before it had them."""
     import json
     from pathlib import Path
 
@@ -232,7 +283,7 @@ def test_dots_step_runs_one_flash_forward_a_layer(chip, monkeypatch, widths, seq
     config.update(widths)
     assert config["run"]["remat"] == "dots" and config["run"]["scan_layers"]
     # The benchmark's own model, loss and AdamW for the cell's traffic.
-    system = System(config, mistral, {"batch": 1, "seq": seq}, seed=0)
+    system = System(config, mistral, {"batch": batch, "seq": seq}, seed=0)
     params = jax.eval_shape(system.init_params)
     opt_state = jax.eval_shape(system.tx.init, params)
 
@@ -241,7 +292,7 @@ def test_dots_step_runs_one_flash_forward_a_layer(chip, monkeypatch, widths, seq
             make_jit_fused_step(system.tx, system.loss_fn)
             .lower(
                 _sds_tree(params, chip), _sds_tree(opt_state, chip),
-                _sds((1, seq + 1), jnp.int32, chip),
+                _sds((batch, seq + 1), jnp.int32, chip),
             )
             .compile()
         )
@@ -257,8 +308,10 @@ def test_dots_step_runs_one_flash_forward_a_layer(chip, monkeypatch, widths, seq
     calls_plain_dots, temp_plain_dots = compiled()
     assert (calls, calls_plain_dots) == (3, 4)
     heads, layers = config["num_attention_heads"], config["num_hidden_layers"]
-    kept = layers * seq * (config["hidden_size"] * 2 + heads * 4)  # bf16 out, f32 lse
+    # bf16 out, f32 lse
+    kept = layers * batch * seq * (config["hidden_size"] * 2 + heads * 4)
     assert temp - temp_plain_dots <= 1.1 * kept, (temp, temp_plain_dots, kept)
+    assert temp <= temp_before + 2**20, (temp, temp_before)
 
 
 def test_sharded_step_with_size_one_mesh_axis_compiles(v5e, monkeypatch) -> None:
