@@ -2,9 +2,9 @@
 FT plane on loopback, the one-process run (set-up, warm-up, window, checks)
 and the observations the metric readers read.
 
-Copies of ``chip_smoke.py``'s sound pieces live here (``CompileLedger``,
-``Plane``, ``balanced_fragments``): later PRs may change the program, not the
-yardstick.
+``CompileLedger``, ``Plane`` and ``balanced_fragments`` live here and
+``chip_smoke.py`` imports them (since PR 31 it carries no copy): later PRs may
+change the program, not the yardstick.
 """
 
 from __future__ import annotations

@@ -1,6 +1,7 @@
 """ftddp: a lone replica through ``Optimizer.make_step_fn``: quorum and commit
-vote every step, no donation, the history ring. What a user runs when the
-fleet has shrunk to one group."""
+vote every step, no donation, so two copies of the state: the committed one
+(the history ring's one version at depth 0) and the speculative one. What a
+user runs when the fleet has shrunk to one group."""
 
 from __future__ import annotations
 

@@ -15,6 +15,11 @@ no file that is here:
 
 ``<dir>`` is any directory of BENCHMARK.json's ``paths``, searched in order,
 so a later PR may bring a directory of its own.
+
+``reduced`` may list depth, a dtype or a timeout the cell bends, and the counts
+a chip holds a share of (experts, heads, rows of the vocabulary: ``vocab_size``,
+an eighth of them at least); never a width. A cut is written in two places: the
+entry's ``reduced`` list, and the file's ``reduced`` and ``published`` objects.
 """
 
 from __future__ import annotations
@@ -33,6 +38,14 @@ TOP_KEYS = {
     "command", "paths", "run_seconds", "configs", "workloads", "end_to_end",
     "per_layer",
 }
+# What ``reduced`` never names. The model-configs guide, section 4: "No width
+# is ever cut. How many heads, experts or rows of the vocabulary are held here
+# may be the chip's share". Its widths are the hidden, head, feed-forward and
+# expert sizes and the "window and state sizes"; ``vocab_size`` counts rows, so
+# its letters ``size`` name no width.
+WIDTH_ENDINGS = ("_dim", "_rank")
+WIDTH_WORDS = ("size", "window", "state")
+ROW_COUNTS = ("vocab_size",)
 
 
 class SpecError(Exception):
@@ -116,6 +129,38 @@ def load_module(path: Path) -> ModuleType:
 # -- checks ------------------------------------------------------------------
 
 
+def is_width(key: str) -> bool:
+    """Whether a key of a configuration names a width, by what it is."""
+    return key not in ROW_COUNTS and (
+        key.endswith(WIDTH_ENDINGS) or any(word in key for word in WIDTH_WORDS)
+    )
+
+
+def cut_problems(key: str, sizes: Dict[str, Any]) -> List[str]:
+    """What is wrong with one allowed key of an entry's ``reduced`` as the
+    configuration's file ``sizes`` writes the cut down; empty when it is honest."""
+    written, published = sizes.get("reduced"), sizes.get("published")
+    published = published if isinstance(published, dict) else {}
+    out = []
+    if not isinstance(written, dict) or key not in written:
+        out.append(f"reduced key {key!r} is not in the file's own `reduced` object")
+    if key in published and sizes.get(key) == published[key]:
+        out.append(f"reduced key {key!r} holds its published value {published[key]!r}")
+    if key == "vocab_size":
+        held, total = sizes.get(key), published.get(key)
+        if not isinstance(held, int) or not isinstance(total, int):
+            out.append(
+                "reduced key 'vocab_size' needs the held count and `published.vocab_size`"
+                f" as whole numbers, not {held!r} and {total!r}"
+            )
+        elif not (eighth := -(-total // 8)) <= held <= total:  # equal: said above
+            out.append(
+                f"vocab_size {held} of a published {total}: a chip holds at least an"
+                f" eighth of the rows ({eighth}) and fewer than all"
+            )
+    return out
+
+
 def problems(bench: Benchmark) -> List[str]:
     """Every way this BENCHMARK.json breaks its contract that can be seen
     without running anything; empty when it is sound."""
@@ -164,13 +209,25 @@ def problems(bench: Benchmark) -> List[str]:
         if c["file"] in files:
             bad(f"configuration file {c['file']} used twice")
         files.add(c["file"])
+        sizes: Dict[str, Any] = {}
+        if path.is_file():
+            try:
+                sizes = json.loads(path.read_text())
+            except ValueError:
+                sizes = None
+            if not isinstance(sizes, dict):
+                bad(f"configuration {c['name']}: file {c['file']} holds no JSON object")
+                sizes = {}
         for key in c["reduced"]:
-            if not NAME.match(key) or key.endswith(("_dim", "_rank")) or "size" in key:
+            if not NAME.match(key) or is_width(key):
                 bad(f"configuration {c['name']}: reduced key {key!r} is a width or misnamed")
+            elif sizes:
+                for fault in cut_problems(key, sizes):
+                    bad(f"configuration {c['name']}: {fault}")
         if not any(w["config"] == c["name"] for w in data["workloads"]):
             bad(f"configuration {c['name']} is used by no cell")
-        if path.is_file():
-            model_type = json.loads(path.read_text()).get("model_type")
+        if sizes:
+            model_type = sizes.get("model_type")
             try:
                 bench.find("architectures", f"{model_type}.py")
             except SpecError as e:
