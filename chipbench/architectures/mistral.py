@@ -29,6 +29,13 @@ import jax.numpy as jnp
 
 from chipbench import reference
 
+# What flash_time_pct and flash_mxu_pct ask of a cell they list
+# (``ARCHITECTURE_SAYS`` in their files; ``spec.problems`` reads this line):
+# every Pallas call of the step programs ``build`` gives is causal flash
+# attention, and every one of ``num_hidden_layers`` layers runs it at
+# ``num_attention_heads x head_dim``.
+FLASH_ATTENTION_IN_EVERY_LAYER = True
+
 
 def build(config: Dict[str, Any], seq: int):
     """The program's model for a configuration file as it is run: an object

@@ -19,7 +19,10 @@ def flash_attention_flops(config: Dict[str, Any], batch: int, seq: int) -> float
     five: the scores once more, which the algorithm keeps nowhere and cannot
     avoid recomputing, then dv = p^T do, dp = do v^T, dq = ds k, dk = ds^T q.
     What the two backward kernels recompute beyond that one, and a forward
-    repeated under remat, is time and not need."""
+    repeated under remat, is time and not need.
+    For cells in which every one of ``num_hidden_layers`` layers runs causal
+    flash attention at ``num_attention_heads x head_dim`` and no other Pallas
+    call is in the step programs; any other cell brings a count of its own."""
     per_matmul = seq * seq * config["head_dim"] * config["num_attention_heads"]
     return 7.0 * per_matmul * config["num_hidden_layers"] * batch
 

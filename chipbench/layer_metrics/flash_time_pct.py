@@ -2,10 +2,17 @@
 window's busy device seconds. The kernels are the Pallas calls (forward, dq,
 dk/dv of ops/flash_attention.py) inside the train-step programs: every
 ``tpu_custom_call`` of the trace that is not in one of DiLoCo's codec
-programs, whose kernels codec_gbps reads."""
+programs, whose kernels codec_gbps reads.
+
+Whom it is for: a cell is listed only if every Pallas call of its step programs
+is causal flash attention and every one of its ``num_hidden_layers`` layers
+runs it at ``num_attention_heads x head_dim``; any other cell stays out of this
+list and flash_mxu_pct's and brings readers of its own for its kernels.
+``spec.problems`` holds the lists to it through the line below."""
 
 import re
 
+ARCHITECTURE_SAYS = "FLASH_ATTENTION_IN_EVERY_LAYER"
 CODEC_PROGRAM = re.compile(r"quantize_pseudograd|apply_outer")
 
 

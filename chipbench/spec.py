@@ -16,6 +16,10 @@ no file that is here:
 ``<dir>`` is any directory of BENCHMARK.json's ``paths``, searched in order,
 so a later PR may bring a directory of its own.
 
+A layer metric whose count or kernel names fit one kind of architecture only
+says so on a line of its file, ``ARCHITECTURE_SAYS = "SOME_NAME"``, and lists a
+cell only where the cell's architecture file has the line ``SOME_NAME = True``.
+
 ``reduced`` may list depth, a dtype or a timeout the cell bends, and the counts
 a chip holds a share of (experts, heads, rows of the vocabulary: ``vocab_size``,
 an eighth of them at least); never a width. A cut is written in two places: the
@@ -46,6 +50,8 @@ TOP_KEYS = {
 WIDTH_ENDINGS = ("_dim", "_rank")
 WIDTH_WORDS = ("size", "window", "state")
 ROW_COUNTS = ("vocab_size",)
+# Read as text, so that ``problems`` imports no reader and no architecture.
+READER_ASKS = re.compile(r'^ARCHITECTURE_SAYS = "([A-Z][A-Z0-9_]*)"$', re.M)
 
 
 class SpecError(Exception):
@@ -159,6 +165,17 @@ def cut_problems(key: str, sizes: Dict[str, Any]) -> List[str]:
                 f" eighth of the rows ({eighth}) and fewer than all"
             )
     return out
+
+
+def architecture_text(bench: Benchmark, workload: str) -> Optional[str]:
+    """The text of the architecture file of a cell's configuration; None where
+    one of the files on the way is missing or malformed, which ``problems``
+    says under the configuration's name."""
+    try:
+        model_type = bench.config(bench.cell(workload)["config"])["model_type"]
+        return bench.find("architectures", f"{model_type}.py").read_text()
+    except (SpecError, OSError, ValueError, KeyError, TypeError):
+        return None
 
 
 def problems(bench: Benchmark) -> List[str]:
@@ -287,9 +304,19 @@ def problems(bench: Benchmark) -> List[str]:
     for group in ("end_to_end", "per_layer"):
         for m in data[group]:
             try:
-                bench.reader_path(group, m["name"])
+                asks = READER_ASKS.findall(bench.reader_path(group, m["name"]).read_text())
             except SpecError as e:
                 bad(str(e))
+                continue
+            for cell in cells_of(m) if asks else ():
+                said = architecture_text(bench, cell)
+                for ask in asks:
+                    if said is not None and not re.search(rf"^{ask} = True$", said, re.M):
+                        bad(
+                            f"metric {m['name']} lists {cell}, whose architecture file does"
+                            f" not say `{ask} = True`: the metric's file says what that"
+                            " promises; a cell that cannot stays out of the list"
+                        )
     for m in data["end_to_end"] + data["per_layer"]:
         if not UNIT.match(m.get("unit", "")):
             bad(f"metric {m['name']}: unit {m.get('unit')!r}")

@@ -33,19 +33,29 @@ RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
 
 
-def copy_benchmark(to: Path) -> None:
+@pytest.fixture
+def bench_root() -> Path:
+    """The checkout whose BENCHMARK.json a test reads: this one. Every test of
+    this directory that reads it and starts no process takes it from here, so
+    that test_another_architecture.py can call the same test on a copy that
+    lists one more cell: a test that pins what the lists hold TODAY, where it
+    means a rule, fails there and not in the next PR's hands."""
+    return ROOT
+
+
+def copy_benchmark(to: Path, root: Path = ROOT) -> None:
     """The directories of BENCHMARK.json's ``paths``, and nothing else."""
-    for path in json.loads((ROOT / "BENCHMARK.json").read_text())["paths"]:
-        shutil.copytree(ROOT / path, to / path, ignore=shutil.ignore_patterns("__pycache__"))
+    for path in json.loads((root / "BENCHMARK.json").read_text())["paths"]:
+        shutil.copytree(root / path, to / path, ignore=shutil.ignore_patterns("__pycache__"))
 
 
-def copy_with_parked_cell(to: Path) -> Path:
+def copy_with_parked_cell(to: Path, root: Path = ROOT) -> Path:
     """A copy of the benchmark whose BENCHMARK.json also lists the parked
     four-chip cell (chipbench/fixtures/parked_hsdp.json: its entries as they
     stood before PR 24 took the cell out). The cell's files are rehearsed
     from this copy, so that the PR that lists it again finds them working."""
-    copy_benchmark(to)
-    data = json.loads((ROOT / "BENCHMARK.json").read_text())
+    copy_benchmark(to, root)
+    data = json.loads((root / "BENCHMARK.json").read_text())
     parked = json.loads((HERE / "parked_hsdp.json").read_text())
     for key in ("configs", "workloads", "end_to_end", "per_layer"):
         data[key] += parked[key]
@@ -53,10 +63,14 @@ def copy_with_parked_cell(to: Path) -> Path:
     return to
 
 
-def list_cell(copy: Path, name: str, config: str, traffic: str, configs=(), metrics=("tokens_per_s",)) -> None:
-    """Writes the copy's BENCHMARK.json with a directory ``morebench``, one
-    more cell (and ``configs`` entries), listed under ``metrics``."""
-    data = json.loads((ROOT / "BENCHMARK.json").read_text())
+def list_cell(
+    copy: Path, name: str, config: str, traffic: str, configs=(), metrics=("tokens_per_s",),
+    root: Path = ROOT,
+) -> None:
+    """Writes the copy's BENCHMARK.json (``root``'s) with a directory
+    ``morebench``, one more cell (and ``configs`` entries), listed under
+    ``metrics``."""
+    data = json.loads((root / "BENCHMARK.json").read_text())
     data["paths"].append("morebench")
     data["configs"] += list(configs)
     data["workloads"].append(
@@ -67,8 +81,8 @@ def list_cell(copy: Path, name: str, config: str, traffic: str, configs=(), metr
     (copy / "BENCHMARK.json").write_text(json.dumps(data))
 
 
-def test_benchmark_json_is_sound():
-    bench = spec.Benchmark(ROOT)
+def test_benchmark_json_is_sound(bench_root):
+    bench = spec.Benchmark(bench_root)
     assert spec.problems(bench) == []
     assert len(json.dumps(bench.data)) < 64 * 1024
     for cell in bench.data["workloads"]:
@@ -80,8 +94,8 @@ def test_benchmark_json_is_sound():
                 assert callable(bench.reader(group, metric["name"]).read), metric["name"]
 
 
-def test_problems_catches_a_moves_that_a_cell_does_not_report(tmp_path):
-    copy = copy_with_parked_cell(tmp_path / "repo")
+def test_problems_catches_a_moves_that_a_cell_does_not_report(tmp_path, bench_root):
+    copy = copy_with_parked_cell(tmp_path / "repo", bench_root)
     assert spec.problems(spec.Benchmark(copy)) == []  # the parked entries still fit
     data = json.loads((copy / "BENCHMARK.json").read_text())
     for metric in data["per_layer"]:
@@ -243,10 +257,10 @@ def test_union_counts_overlap_once():
 # -- the reference -------------------------------------------------------------
 
 
-def tiny_system(dtype: str):
+def tiny_system(dtype: str, root: Path):
     from chipbench.model import System
 
-    bench = spec.Benchmark(ROOT)
+    bench = spec.Benchmark(root)
     overlay = json.loads((HERE / "rehearsal.json").read_text())
     config = {**bench.config("mistral-7b-v0.3-1chip"), **overlay["config"]}
     config["run"] = {**config["run"], **overlay["run"], "dtype": dtype}
@@ -254,7 +268,7 @@ def tiny_system(dtype: str):
     return System(config, bench.architecture(config["model_type"]), traffic, seed=2**31 + 12345)
 
 
-def test_reference_agrees_with_the_program_in_float32():
+def test_reference_agrees_with_the_program_in_float32(bench_root):
     """Forward loss of models/llama.py (flash in interpret mode, scanned,
     remat, chunked loss) against the plain float32 reference on the same
     seeded weights and tokens. Tolerance 1e-5 relative: both sides compute in
@@ -263,7 +277,7 @@ def test_reference_agrees_with_the_program_in_float32():
     no causal mask) moves the loss by 1e-3 or more."""
     from chipbench import reference
 
-    system = tiny_system("float32")
+    system = tiny_system("float32", bench_root)
     params = system.init_params()
     tokens = system.tokens(0)
     got = float(system.loss_fn(params, tokens))
@@ -274,14 +288,14 @@ def test_reference_agrees_with_the_program_in_float32():
     assert abs(other - want) > 1e-4
 
 
-def test_reference_catches_a_lower_precision():
+def test_reference_catches_a_lower_precision(bench_root):
     """The same comparison with the program in bf16 differs by rounding alone
     (under 2^-8 at this toy size, where a mean of 128 token losses averages
     little; on the chip 8192 tokens bring it under 2e-5 and the tolerance is
     2^-12); were the rotary pairing or the mask wrong it would not."""
     from chipbench import reference
 
-    system = tiny_system("bfloat16")
+    system = tiny_system("bfloat16", bench_root)
     params = system.init_params()
     tokens = system.tokens(0)
     got = float(system.loss_fn(params, tokens))
@@ -293,7 +307,7 @@ def test_reference_catches_a_lower_precision():
     assert abs(wrong - want) / want > 1e-5
 
 
-def test_reference_update_is_the_first_adamw_step():
+def test_reference_update_is_the_first_adamw_step(bench_root):
     """The reference's hand-written first step against optax.adamw on the
     program's own gradient, float32 at toy size: the same parameters to 1e-5,
     and the second loss tells that update from none, from one of twice the
@@ -303,7 +317,7 @@ def test_reference_update_is_the_first_adamw_step():
 
     from chipbench import harness, reference
 
-    system = tiny_system("float32")
+    system = tiny_system("float32", bench_root)
     params = system.init_params()
     want = system.reference = harness.reference_losses(system, params)
     tx = system.tx
@@ -328,7 +342,7 @@ def test_reference_update_is_the_first_adamw_step():
 
 
 @pytest.mark.parametrize("query_block,head_block", [(32, 128), (128, 32), (32, 32)])
-def test_blocked_reference_equals_the_unblocked_one(query_block, head_block, monkeypatch):
+def test_blocked_reference_equals_the_unblocked_one(query_block, head_block, monkeypatch, bench_root):
     """One sequence of 128 positions in four blocks of 32 (attention, the loss
     head, both) against the same sequence in one block: softmax is by row, so
     only the order of the sums differs. Loss to 1e-6 relative, gradient leaf
@@ -338,7 +352,7 @@ def test_blocked_reference_equals_the_unblocked_one(query_block, head_block, mon
 
     from chipbench import reference
 
-    system = tiny_system("float32")
+    system = tiny_system("float32", bench_root)
     params = system.init_params()
     tokens = jax.random.randint(jax.random.PRNGKey(3), (129,), 0, system.config["vocab_size"])
 
@@ -376,13 +390,13 @@ def test_flash_attention_flops_against_a_hand_count():
     assert flops.flash_attention_flops(config, 1, 64) == 4 * flops.flash_attention_flops(config, 4, 16)
 
 
-def test_flash_mxu_pct_reads_the_recorded_trace():
+def test_flash_mxu_pct_reads_the_recorded_trace(bench_root):
     """The recorded plain run (three steps of batch 4 x seq 2048 at the cell's
     widths): the kernels' seconds are flash_time_pct's, the operations
     flops.flash_attention_flops', the peak peaks.json's."""
     from chipbench import flops, harness
 
-    bench = spec.Benchmark(ROOT)
+    bench = spec.Benchmark(bench_root)
     trace = trace_reduce.reduce(json.loads((HERE / "small_trace.json").read_text()))
     config = bench.config("mistral-7b-v0.3-1chip")
     obs = {"trace": trace, "steps": 3, "config": config, "batch": 4, "seq": 2048,
@@ -401,7 +415,7 @@ def test_flash_mxu_pct_reads_the_recorded_trace():
         assert read({**obs, **hole}) is None
 
 
-def test_control_the_reference_in_the_precision_below_is_not_correct():
+def test_control_the_reference_in_the_precision_below_is_not_correct(bench_root):
     """The control of the comparison that decides ``correct``: the reference
     itself, put in the program's place with its weights in the nearest
     precision below the one the (toy, float32) configuration states, bf16.
@@ -413,7 +427,7 @@ def test_control_the_reference_in_the_precision_below_is_not_correct():
 
     from chipbench import harness, reference
 
-    system = tiny_system("float32")
+    system = tiny_system("float32", bench_root)
     params = system.init_params()
     system.reference = harness.reference_losses(system, params)
     lower = jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16).astype(a.dtype), params)
@@ -455,10 +469,10 @@ def test_memory_gauge_takes_one_instant_and_never_clips():
     assert harness.memory_problems(over.report()) != []
 
 
-def test_seed_decides_weights_and_tokens():
+def test_seed_decides_weights_and_tokens(bench_root):
     import numpy as np
 
-    a, b = tiny_system("float32"), tiny_system("float32")
+    a, b = tiny_system("float32", bench_root), tiny_system("float32", bench_root)
     assert np.array_equal(np.asarray(a.tokens(3)), np.asarray(b.tokens(3)))
     assert not np.array_equal(np.asarray(a.tokens(3)), np.asarray(a.tokens(4)))
     assert not np.array_equal(np.asarray(a.tokens(3, 0)), np.asarray(a.tokens(3, 1)))
@@ -467,9 +481,9 @@ def test_seed_decides_weights_and_tokens():
 # -- discovery: new cells and metrics are new files ----------------------------
 
 
-def test_a_cell_a_traffic_mix_a_job_and_a_metric_are_added_as_files(tmp_path):
+def test_a_cell_a_traffic_mix_a_job_and_a_metric_are_added_as_files(tmp_path, bench_root):
     copy = tmp_path / "repo"
-    copy_benchmark(copy)
+    copy_benchmark(copy, bench_root)
     before = {
         p: p.read_bytes() for p in (copy / "chipbench").rglob("*") if p.is_file()
     }
@@ -484,7 +498,7 @@ def test_a_cell_a_traffic_mix_a_job_and_a_metric_are_added_as_files(tmp_path):
     ))
     (extra / "jobs/echo.py").write_text("def run(run):\n    return {'traffic': run.traffic}\n")
     (extra / "layer_metrics/steps_in_window.py").write_text("def read(obs):\n    return obs['steps']\n")
-    data = json.loads((ROOT / "BENCHMARK.json").read_text())
+    data = json.loads((bench_root / "BENCHMARK.json").read_text())
     data["paths"].append("morebench")
     data["configs"].append({
         "name": "throwaway-1chip", "source": "https://example.org/x", "why": "test",
@@ -511,8 +525,12 @@ def test_a_cell_a_traffic_mix_a_job_and_a_metric_are_added_as_files(tmp_path):
     assert traffic["seq"] == 8192
     assert bench.job(traffic["job"]).run(type("R", (), {"traffic": traffic})) == {"traffic": traffic}
     assert bench.config(cell["config"])["name"] == "throwaway-1chip"
+    # Its per-layer metrics: those that name no cells, which every cell
+    # reports (today's three among them), and its own, appended last.
     names = [m["name"] for m in bench.metrics_of("throwaway.seq8k", "per_layer")]
-    assert names == ["compile_s", "hbm_held_gib", "hbm_scratch_gib", "steps_in_window"]
+    assert names[:-1] == [m["name"] for m in data["per_layer"][:-1] if "workloads" not in m]
+    assert {"compile_s", "hbm_held_gib", "hbm_scratch_gib"} <= set(names[:-1])
+    assert names[-1] == "steps_in_window"
     assert bench.reader("per_layer", "steps_in_window").read({"steps": 7}) == 7
     # The old cells still see their own metrics only, and no file was edited.
     assert "steps_in_window" not in [
@@ -636,7 +654,15 @@ def test_only_the_architecture_file_names_the_programs_model():
     """Nothing of the benchmark outside architectures/ names the program's
     model class, a parameter path of it or the dense block's width (the parked
     hsdp job imports the program's sharding plan, which is the program's name
-    for a layout, not for a block)."""
+    for a layout, not for a block). What each pattern guards: ``[Ll]lama`` the
+    dense block's class and module (``models/llama.py``), ``w_gate`` a path of
+    its parameter tree, ``intermediate_size`` its feed-forward width, and with
+    it every key that holds those letters (``moe_intermediate_size`` too). So
+    a reader, a job or the harness is written for any block: a reader that
+    needs a width or a count takes it from ``obs["config"]`` by a key that its
+    architecture file names (a constant or a function of that file, found by
+    ``spec.load_module`` on the file of ``obs["config"]["model_type"]``), and
+    never spells the key itself."""
     import re
 
     names = re.compile(r"[Ll]lama|w_gate|intermediate_size")

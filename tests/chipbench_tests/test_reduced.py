@@ -18,7 +18,7 @@ sys.path.insert(0, str(ROOT))
 
 from chipbench import spec  # noqa: E402
 from test_chipbench import (  # noqa: E402
-    HERE, THROWAWAY_ARCHITECTURE, copy_benchmark, list_cell, run_cell,
+    HERE, THROWAWAY_ARCHITECTURE, bench_root, copy_benchmark, list_cell, run_cell,  # noqa: F401
 )
 
 CONFIG, CELL = "cut-1chip", "cut.plain"
@@ -26,12 +26,14 @@ CONFIG, CELL = "cut-1chip", "cut.plain"
 HELD, PUBLISHED = 64, 512
 
 
-def list_a_cut(copy: Path, reduced, sizes=None, written=None, published=None, traffic="plain") -> None:
-    """A copy of the benchmark with one more configuration in a directory of
-    its own, the Mistral file's keys but for ``sizes``, its ``reduced`` object
-    naming ``written`` and its ``published`` object holding ``published`` too,
-    listed with ``reduced`` as a cell of ``traffic``."""
-    copy_benchmark(copy)
+def list_a_cut(
+    copy: Path, reduced, sizes=None, written=None, published=None, traffic="plain", root: Path = ROOT
+) -> None:
+    """A copy of the benchmark (``root``'s) with one more configuration in a
+    directory of its own, the Mistral file's keys but for ``sizes``, its
+    ``reduced`` object naming ``written`` and its ``published`` object holding
+    ``published`` too, listed with ``reduced`` as a cell of ``traffic``."""
+    copy_benchmark(copy, root)
     (copy / "morebench/configs").mkdir(parents=True)
     config = json.loads((copy / "chipbench/configs/mistral-7b-v0.3-1chip.json").read_text())
     config.update(name=CONFIG, **(sizes or {}))
@@ -41,7 +43,7 @@ def list_a_cut(copy: Path, reduced, sizes=None, written=None, published=None, tr
     list_cell(copy, CELL, CONFIG, traffic, configs=[{
         "name": CONFIG, "source": "https://example.org/x", "why": "test",
         "file": f"morebench/configs/{CONFIG}.json", "reduced": list(reduced),
-    }])
+    }], root=root)
 
 
 def a_key(key: str, problem: bool):
@@ -83,12 +85,12 @@ SLICED = {"vocab_size": HELD}
                  ["vocab_size 513", "fewer than all"], id="vocab_size-over-the-whole"),
 ])
 def test_reduced_names_no_width_and_every_cut_is_written_down(
-    reduced, sizes, written, published, want, tmp_path
+    reduced, sizes, written, published, want, tmp_path, bench_root
 ):
     """``want``: what the ONE problem says beside the configuration's name;
     None where the entry is sound."""
     copy = tmp_path / "repo"
-    list_a_cut(copy, reduced, sizes, written, published)
+    list_a_cut(copy, reduced, sizes, written, published, root=bench_root)
     found = spec.problems(spec.Benchmark(copy))
     if want is None:
         assert found == []
