@@ -138,7 +138,7 @@ def bench_attention(results: list) -> None:
         print(json.dumps(row))
 
         # fwd+bwd through the kernel's custom VJP: the default on-chip path
-        # (fused Pallas dq/dkv backward), the scan-based blockwise backward
+        # (the fused Pallas backward), the scan-based blockwise backward
         # it replaced, and dense. The loss is a dot with a RANDOM cotangent
         # (passed as an argument, not a closed-over constant): a plain
         # ``out.sum()`` makes dO all-ones, which XLA's algebraic simplifier

@@ -380,6 +380,10 @@ class OneChip:
                 f"zigzag {flash['max_err_zigzag']:.4f} ragged {flash['max_err_ragged']:.4f}"
             )
             say(f"  block pairs by class of the schedule: {flash['classes']}")
+            say(
+                "  fused backward, q chunks a head (1: dq resident for the whole "
+                f"sequence): {flash['bwd_q_chunks']}"
+            )
             quant = quantization.verify_on_chip()
             say(
                 "  codec vs host reference: "

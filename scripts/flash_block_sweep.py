@@ -4,16 +4,17 @@ from the device trace.
 
 The kernels take ``block_q``/``block_k`` at every entry point, so tuning is
 a pure measurement problem — no kernel edits. One call a (seq, block pair):
-``jax.grad`` of a loss over :func:`flash_attention`, which runs the three
-kernels of a layer step (forward, dq, dkv), at the benchmark cells' head
-geometry (32 q heads over 8 KV heads of 128, bf16) and tokens a step
-(8192: batch 4 at 2048, batch 1 at 8192). Each kernel's time is the sum of
-its Mosaic call's device durations in a profiler trace of ITERS calls
-(``chipbench/trace_reduce.py`` reads the file), so no dispatch cost and
-nothing of the XLA ops around the kernels is in it. ``mxu_pct`` is the
-operations causal attention needs for the call (``chipbench/flops.py``: 7
-matmuls, the mask's half) over the three kernels' seconds, against the
-chip's bf16 peak: what the cells report as ``flash_mxu_pct``.
+``jax.grad`` of a loss over :func:`flash_attention`, which runs the two
+kernels of a layer step (forward and the fused backward; a ``--tree`` from
+before the backward was one call runs three: forward, dq, dkv), at the
+benchmark cells' head geometry (32 q heads over 8 KV heads of 128, bf16)
+and tokens a step (8192: batch 4 at 2048, batch 1 at 8192). Each kernel's
+time is the sum of its Mosaic call's device durations in a profiler trace of
+ITERS calls (``chipbench/trace_reduce.py`` reads the file), so no dispatch
+cost and nothing of the XLA ops around the kernels is in it. ``mxu_pct`` is
+the operations causal attention needs for the call (``chipbench/flops.py``:
+7 matmuls, the mask's half) over the kernels' seconds, against the chip's
+bf16 peak: what the cells report as ``flash_mxu_pct``.
 
 Usage:
     python scripts/flash_block_sweep.py [--tree DIR] [--pairs 512x1024,...] [seq ...]
@@ -40,12 +41,17 @@ TOKENS = 8192
 PAIRS = [(bq, bk) for bq in (256, 512, 1024) for bk in (256, 512, 1024, 2048)]
 ITERS = 8
 WARMUP = 2
+# A Mosaic call by what it returns, other than the forward's (out and the
+# logsumexp, a column): the fused backward three arrays (dq, dk, dv); the
+# two-pass backward of an older tree one (dq) and two (dk, dv).
+BACKWARD_BY_RESULTS = {1: "dq", 2: "dkv", 3: "bwd"}
+KERNEL_SETS = (["bwd", "fwd"], ["dkv", "dq", "fwd"])
 
 
 def kernel_ms(trace: Path) -> dict:
     """Milliseconds a call of each Mosaic kernel in the trace, by kernel.
-    XLA orders dq and dkv as it likes, so a call is told by what it returns:
-    the forward (out, logsumexp in float32), dq one array, dkv two."""
+    XLA orders and names the calls as it likes, so a call is told by what it
+    returns (``BACKWARD_BY_RESULTS``)."""
     from jax.profiler import ProfileData
 
     total = {}
@@ -61,8 +67,8 @@ def kernel_ms(trace: Path) -> dict:
                 result = event.name.partition(" = ")[2].partition(" custom-call(")[0]
                 shapes = trace_reduce.SHAPE.findall(result)
                 kernel = (
-                    "fwd" if any(x.startswith("f32") for x in shapes)
-                    else "dkv" if len(shapes) == 2 else "dq"
+                    "fwd" if any(x.endswith(",1]") for x in shapes)
+                    else BACKWARD_BY_RESULTS[len(shapes)]
                 )
                 total[kernel] = total.get(kernel, 0.0) + event.duration_ns
     return {k: v / ITERS / 1e6 for k, v in total.items()}
@@ -129,7 +135,7 @@ def main() -> None:
                 row["error"] = str(e).splitlines()[0][:200]
                 print(json.dumps(row), flush=True)
                 continue
-            if sorted(ms) != ["dkv", "dq", "fwd"]:
+            if sorted(ms) not in KERNEL_SETS:
                 row["error"] = f"Mosaic kernels in the trace: {sorted(ms)}"
             else:
                 seconds = sum(ms.values()) / 1e3

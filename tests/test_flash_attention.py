@@ -88,8 +88,8 @@ def test_gradients_match_dense():
     ],
 )
 def test_pallas_backward_matches_dense(b, s, h, kv, d, block):
-    """The fused dq/dkv backward kernels (interpret mode) against dense
-    attention gradients — the TPU training path's backward."""
+    """The fused backward kernel (interpret mode) against dense attention
+    gradients — the TPU training path's backward."""
     q, k, v = _qkv(b, s, h, kv, d, seed=3)
     w = jax.random.normal(jax.random.PRNGKey(11), (b, s, h, d), jnp.float32)
 
@@ -126,8 +126,9 @@ def test_multi_kv_block_forward_matches_dense(s):
 
 
 def test_multi_kv_block_pallas_backward_matches_dense():
-    # Cross-KV-block dq accumulation and the dkv pass's multi-q-block loop
-    # (nq=5, nk=3) — see the forward test above for why s must exceed 128.
+    # dq accumulating across KV blocks and dk/dv across q blocks in the one
+    # backward call (nq=5, nk=3) — see the forward test above for why s must
+    # exceed 128.
     b, s, h, kv, d = 1, 320, 2, 1, 16
     q, k, v = _qkv(b, s, h, kv, d, seed=6)
     w = jax.random.normal(jax.random.PRNGKey(13), (b, s, h, d), jnp.float32)
@@ -242,16 +243,16 @@ def _count_pallas_calls(jaxpr) -> int:
 
 @pytest.mark.parametrize("mesh_axis", [None, "fsdp"], ids=["bare", "fsdp2-mesh"])
 @pytest.mark.parametrize(
-    "remat, calls", [("none", 3), ("dots", 3), ("full", 4)]
+    "remat, calls", [("none", 2), ("dots", 2), ("full", 3)]
 )
 def test_remat_runs_the_forward_kernel_once_unless_full(
     remat, calls, mesh_axis, monkeypatch
 ):
     """A GQA attention layer (projections, flash, wo, residual) under the
     model's remat policies, Pallas backward in interpret mode. The
-    gradient's jaxpr holds forward + dq + dkv; only ``full`` may add a
-    second forward: ``dots`` keeps the kernel's named (out, lse)
-    (FLASH_OUT / FLASH_LSE) beside the dot results — plain
+    gradient's jaxpr holds the forward and the one backward call; only
+    ``full`` may add a second forward: ``dots`` keeps the kernel's named
+    (out, lse) (FLASH_OUT / FLASH_LSE) beside the dot results — plain
     ``checkpoint_dots`` sees no dot_general in a pallas_call and reran it.
     Gradients are the unremat'd ones bit for bit (a kept value replaces the
     same value recomputed by the same kernel). Under a bound mesh the
@@ -566,8 +567,8 @@ def test_block_pairs_classify_by_position(name):
 @pytest.mark.parametrize("name", list(_SCHEDULES))
 def test_no_block_above_the_diagonal_is_fetched(name):
     """The index maps name, for a pair above the diagonal, the block its
-    neighbour in the walk names (forward and dq: the KV block of the step
-    before; dkv: the q block of the step after), so the pipeline sees an
+    neighbour in the walk names (forward: the KV block of the step before;
+    backward: the q block of the step after), so the pipeline sees an
     unchanged index and copies nothing; a needed pair names its own."""
     from torchft_tpu.ops import flash_attention as fa
 
@@ -682,3 +683,124 @@ def test_scheduled_kernels_equal_the_masked_ones_bit_for_bit(name, monkeypatch):
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), atol=5e-5, err_msg=what
         )
+
+
+# --- the fused backward -------------------------------------------------------
+#
+# One Mosaic call gives dq, dk and dv. A case is a layout (sq, sk, q positions,
+# k positions, block_q, block_k), the heads (h, kv), the dtype of the inputs and
+# of the gradients, and how many q blocks of dq the call may keep resident
+# in VMEM (None: the module's own limit, which holds every sequence here whole).
+
+_F32, _BF16 = jnp.float32, jnp.bfloat16
+_PERMUTED = np.random.default_rng(5).permutation(512).astype(np.int32)
+_BWD_CASES = {
+    "causal-gqa4": ((512, 512, None, None, 64, 128), (4, 1), _F32, _F32, None),
+    "causal-gqa4-bf16": ((512, 512, None, None, 64, 128), (4, 1), _BF16, _BF16, None),
+    "bf16-in-f32-out": ((512, 512, None, None, 64, 128), (4, 2), _BF16, _F32, None),
+    "ragged-600": ((600, 600, None, None, 128, 256), (4, 2), _F32, _F32, None),
+    # 256 queries at positions 256.. against 512 keys.
+    "sq-ne-sk": (
+        (256, 512, np.arange(256, 512, dtype=np.int32), None, 64, 128),
+        (4, 2), _F32, _F32, None,
+    ),
+    # A hop with no diagonal pair: every pair is wholly above or under.
+    "zigzag-hop": (_RUN_CASES["zigzag-hop"], (4, 2), _F32, _F32, None),
+    # Queries and keys shuffled alike: every block spans the sequence, so
+    # every pair reads diagonal.
+    "permuted": ((512, 512, _PERMUTED, _PERMUTED, 64, 128), (4, 2), _F32, _F32, None),
+    # Longer than the resident budget: 8 q blocks as 4 chunks of 2; 5 (ragged,
+    # padded to 6) as 3 of 2; 4 as 4 of 1, where two chunks of the zigzag hop
+    # need no KV block at all.
+    "chunked-causal": ((512, 512, None, None, 64, 128), (4, 1), _F32, _F32, 2),
+    "chunked-causal-bf16": ((512, 512, None, None, 64, 128), (4, 1), _BF16, _BF16, 2),
+    "chunked-ragged-600": ((600, 600, None, None, 128, 256), (4, 2), _F32, _F32, 2),
+    "chunked-zigzag-hop": (_RUN_CASES["zigzag-hop"], (4, 2), _F32, _F32, 1),
+}
+_BWD_CHUNKS = {
+    "chunked-causal": 4, "chunked-causal-bf16": 4, "chunked-ragged-600": 3,
+    "chunked-zigzag-hop": 4,
+}
+
+
+@pytest.mark.parametrize("name", list(_BWD_CASES))
+def test_fused_backward_matches_dense_and_blockwise(name):
+    """dq, dk, dv of the one backward call (interpret mode) against the dense
+    gradients under the same position mask and, where the layout is plain
+    causal, against the scan-based ``_blockwise_core_bwd``: GQA 4:1, a ragged
+    length, ``sq != sk``, a zigzag hop without a diagonal pair, a layout that
+    is all diagonal, gradients in float32 and in bfloat16, and sequences
+    longer than the resident dq accumulator (the call's VMEM set small
+    through the function's own argument), which are walked in chunks of q
+    blocks: dq is then bit for bit the resident call's, and dk, dv are sums
+    of one more partial a chunk."""
+    from torchft_tpu.ops import flash_attention as fa
+    from torchft_tpu.ops.ring_attention import _blockwise_core_bwd
+
+    (sq, sk, q_pos, k_pos, block_q, block_k), (h, kv), dtype, out_dtype, resident = (
+        _BWD_CASES[name]
+    )
+    d = 16
+    q, k, v, d_out, qp, kp = (
+        x if x.dtype == jnp.int32 else x.astype(dtype)
+        for x in _positioned_inputs(sq, sk, q_pos, k_pos, h=h, kv=kv, d=d)
+    )
+    b = q.shape[0]
+    tile = (
+        block_q, block_k, d,
+        jnp.dtype(dtype).itemsize, jnp.dtype(out_dtype).itemsize,
+    )
+    budget = {}
+    if resident is not None:
+        budget["vmem_bytes"] = fa._bwd_vmem_bytes(resident * block_q, *tile)
+    chunks, _ = fa._q_chunks(sq, budget.get("vmem_bytes", fa._MAX_VMEM_BYTES), *tile)
+    assert chunks == _BWD_CHUNKS.get(name, 1)
+    counts = fa._class_counts(sq, sk, block_q, block_k, qp, kp)
+    if "zigzag-hop" in name:
+        assert counts["diagonal"] == 0 and counts["above"] and counts["under"]
+    if name == "permuted":
+        assert counts["above"] == counts["under"] == 0
+
+    out, lse = fa.flash_attention_partial(
+        q, k, v, qp, kp, block_q=block_q, block_k=block_k, interpret=True
+    )
+
+    def backward(**kw):
+        return fa.flash_attention_partial_bwd(
+            q, k, v, d_out, out, lse, qp, kp, d**-0.5, block_q, block_k, True,
+            out_dtype=out_dtype, **kw,
+        )
+
+    grads = backward(**budget)
+    assert all(g.dtype == out_dtype for g in grads)
+    assert [g.shape for g in grads] == [q.shape, k.shape, v.shape]
+
+    # The references compute in float32 from the same (rounded) inputs.
+    wide = [x.astype(_F32) for x in (q, k, v)]
+    _, vjp = jax.vjp(lambda q, k, v: _dense_positioned(q, k, v, qp, kp), *wide)
+    references = {"dense": vjp(d_out.astype(_F32))}
+    if q_pos is None and k_pos is None:
+        references["blockwise"] = _blockwise_core_bwd(
+            d**-0.5, block_k,
+            (*wide, out.astype(_F32), lse.reshape(b, sq, kv, h // kv)),
+            d_out.astype(_F32),
+        )
+    # bf16: the kernel rounds p and ds to the inputs' dtype before each
+    # matmul and (bf16 out) the per-head partials before the group sum;
+    # gradients of up to 8 here, so 0.03 is an ulp of bf16.
+    atol = 5e-5 if dtype == _F32 else 0.03 if out_dtype == _F32 else 0.05
+    for ref_name, reference in references.items():
+        for got, want, what in zip(grads, reference, ("dq", "dk", "dv")):
+            np.testing.assert_allclose(
+                np.asarray(got.astype(_F32)), np.asarray(want), atol=atol,
+                err_msg=f"{what} against {ref_name}",
+            )
+
+    if resident is not None:
+        whole = backward()
+        assert np.array_equal(np.asarray(grads[0]), np.asarray(whole[0])), "dq"
+        for got, want, what in zip(grads[1:], whole[1:], ("dk", "dv")):
+            np.testing.assert_allclose(
+                np.asarray(got.astype(_F32)), np.asarray(want.astype(_F32)),
+                atol=2e-5 if out_dtype == _F32 else 0.07, err_msg=what,
+            )

@@ -122,6 +122,35 @@ def test_flash_partial_and_partial_bwd_lower_for_tpu():
     )
 
 
+@pytest.mark.parametrize(
+    "b,s,grid",
+    [
+        pytest.param(1, 8192, (1, 32, 1, 8, 16), id="cells-1x8192"),
+        pytest.param(4, 2048, (4, 32, 1, 2, 4), id="cells-4x2048"),
+        pytest.param(1, 65536, (1, 32, 2, 64, 64), id="over-budget-1x65536"),
+    ],
+)
+def test_flash_backward_lowers_at_the_cells_geometry(b, s, grid):
+    # The benchmark cells' attention (32 q heads over 8 KV heads of 128) at
+    # the default blocks, and a sequence over the VMEM a call may hold, which
+    # the one backward call walks in two chunks of q blocks: its grid is
+    # (batch, q heads, q chunks, KV blocks, q blocks of a chunk).
+    h, kv, d = 32, 8, 128
+    q = _sds((b, s, h, d), jnp.bfloat16)
+    k = _sds((b, s, kv, d), jnp.bfloat16)
+    lse = _sds((b, s, h), jnp.float32)
+    traced = jax.jit(
+        lambda q, k, v, do, out, lse: flash_attention_partial_bwd(
+            q, k, v, do, out, lse, None, None,
+            scale=d**-0.5, block_q=512, block_k=1024, interpret=False,
+            out_dtype=jnp.bfloat16,
+        )
+    ).trace(q, k, k, q, q, lse)
+    (call,) = _pallas_calls(traced.jaxpr.jaxpr)
+    assert call.params["grid_mapping"].grid == grid
+    traced.lower(lowering_platforms=("tpu",))
+
+
 def _pallas_calls(jaxpr) -> list:
     """pallas_call equations of a jaxpr, nested bodies (shard_map, pjit)
     included."""
@@ -136,9 +165,9 @@ def _pallas_calls(jaxpr) -> list:
 
 @pytest.mark.parametrize("where", ["plain", "shard_map-batch", "shard_map-positions"])
 def test_flash_kernels_lower_with_their_schedule_tables(where):
-    """Forward, dq and dkv each take the causal block schedule as two
-    scalar-prefetch operands that their index maps and bodies read from
-    SMEM, and lower for a TPU: bare; under a shard_map over the batch (the
+    """The forward and the backward call each take the causal block
+    schedule as two scalar-prefetch operands that their index maps and
+    bodies read from SMEM, and lower for a TPU: bare; under a shard_map over the batch (the
     model's ``_flash_under_ambient_mesh``: tables of ``arange`` that vary
     over no axis beside data that does); and under a shard_map over the
     sequence with the positions as arguments (a ring hop: the tables vary
@@ -148,7 +177,7 @@ def test_flash_kernels_lower_with_their_schedule_tables(where):
 
     b, s, h, kv, d = 2, 1024, 4, 2, 64
 
-    def three(q, k, v, do, qp, kp):
+    def both(q, k, v, do, qp, kp):
         out, lse = flash_attention_partial(
             q, k, v, qp, kp, block_q=128, block_k=256, interpret=False
         )
@@ -163,26 +192,26 @@ def test_flash_kernels_lower_with_their_schedule_tables(where):
     data = [_sds((b, s, n, d), jnp.bfloat16) for n in (h, kv, kv, h)]
     positions = [_sds((b, s), jnp.int32)] * 2
     if where == "plain":
-        fn, args = three, data + positions
+        fn, args = both, data + positions
     elif where == "shard_map-batch":
         fn = shard_map(
-            lambda q, k, v, do: three(q, k, v, do, arange(q), arange(k)),
+            lambda q, k, v, do: both(q, k, v, do, arange(q), arange(k)),
             mesh=AbstractMesh((2,), ("fsdp",)),
             in_specs=(P("fsdp"),) * 4, out_specs=(P("fsdp"),) * 3,
         )
         args = data
     else:
         fn = shard_map(
-            three, mesh=AbstractMesh((4,), ("sp",)),
+            both, mesh=AbstractMesh((4,), ("sp",)),
             in_specs=(P(None, "sp"),) * 6, out_specs=(P(None, "sp"),) * 3,
         )
         args = data + positions
     traced = jax.jit(fn).trace(*args)
     calls = _pallas_calls(traced.jaxpr.jaxpr)
-    assert len(calls) == 3
-    assert [c.params["grid_mapping"].num_index_operands for c in calls] == [2, 2, 2]
+    assert len(calls) == 2
+    assert [c.params["grid_mapping"].num_index_operands for c in calls] == [2, 2]
     lowered = traced.lower(lowering_platforms=("tpu",))
-    assert lowered.as_text().count("tpu_custom_call") >= 3
+    assert lowered.as_text().count("tpu_custom_call") >= 2
 
 
 @pytest.mark.parametrize("wire", ["fp8", "int8"])

@@ -18,6 +18,7 @@ cannot read back, and warns about.
 from __future__ import annotations
 
 import os
+import re
 from dataclasses import replace
 
 import jax
@@ -167,8 +168,74 @@ def test_ring_flash_compiles_over_sp4(v5e) -> None:
         ),
         q, k, v,
     )
-    # The forward's hop loop, then dq and dkv in the backward's.
-    assert backward.as_text().count('custom_call_target="tpu_custom_call"') == 3
+    # The forward's hop loop, then the backward's: a hop's dq, dk and dv
+    # come from one call.
+    assert backward.as_text().count('custom_call_target="tpu_custom_call"') == 2
+
+
+# The benchmark cells' attention (chipbench/configs/mistral-7b-v0.3-1chip.json:
+# 32 q heads over 8 KV heads of 128) at their traffic: the call fits the VMEM
+# it gets without asking and states no limit. A ring hop of the same 8192 rows,
+# whose gradients leave in float32 and whose positions are arguments, a hop of
+# a 128k sequence over sp=4, and the cells' rows in 1024 x 1024 blocks (the
+# sweep's best pair, whose probabilities outgrow the registers by 3 MiB) need
+# more and state it. 65,536 rows are over the most a call holds and are walked
+# in two chunks.
+CELL_H, CELL_KV, CELL_D = 32, 8, 128
+
+
+@pytest.mark.parametrize(
+    "batch, seq, blocks, hop, chunks, states_limit",
+    [
+        pytest.param(1, 8192, (512, 1024), False, 1, False, id="cells-1x8192"),
+        pytest.param(4, 2048, (512, 1024), False, 1, False, id="cells-4x2048"),
+        pytest.param(
+            1, 8192, (1024, 1024), False, 1, True, id="cells-1x8192-1024x1024"
+        ),
+        pytest.param(1, 8192, (512, 1024), True, 1, True, id="ring-hop-8192-f32"),
+        pytest.param(1, 32768, (512, 1024), True, 1, True, id="ring-hop-32768-f32"),
+        pytest.param(1, 65536, (512, 1024), False, 2, True, id="over-budget-1x65536"),
+    ],
+)
+def test_flash_backward_compiles_at_the_cells_geometry(
+    chip, batch, seq, blocks, hop, chunks, states_limit
+) -> None:
+    """The one backward call under the VMEM limit it works out from its
+    shapes, at the default 512 x 1024 blocks and at 1024 x 1024."""
+    from torchft_tpu.ops import flash_attention as fa
+
+    out_dtype = jnp.float32 if hop else jnp.bfloat16
+    tile = (*blocks, CELL_D, 2, jnp.dtype(out_dtype).itemsize)
+    nc, nqc = fa._q_chunks(seq, fa._MAX_VMEM_BYTES, *tile)
+    need = fa._bwd_vmem_bytes(nqc * blocks[0], *tile)
+    assert nc == chunks and need <= fa._MAX_VMEM_BYTES
+    assert (need > fa._SCOPED_VMEM_BYTES) == states_limit
+    q = _sds((batch, seq, CELL_H, CELL_D), jnp.bfloat16, chip)
+    k = _sds((batch, seq, CELL_KV, CELL_D), jnp.bfloat16, chip)
+    lse = _sds((batch, seq, CELL_H), jnp.float32, chip)
+    pos = _sds((batch, seq), jnp.int32, chip)
+
+    def backward(q, k, v, d_out, out, lse, qp, kp):
+        if not hop:
+            qp = kp = None
+        return fa.flash_attention_partial_bwd(
+            q, k, v, d_out, out, lse, qp, kp, CELL_D**-0.5, *blocks, False,
+            out_dtype=out_dtype,
+        )
+
+    compiled = _compile(backward, q, k, k, q, q, lse, pos, pos)
+    (call,) = [
+        line for line in compiled.as_text().splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+    ]
+    # What the call states (nothing: XLA sees a call like any other and tiles
+    # the program's other fusions as it did) and what Mosaic used of it.
+    stated, used = (
+        [int(size) for size in re.findall(r'"size":"(\d+)"', configs)]
+        for configs in re.findall(r'"(?:used_)?scoped_memory_configs":\[([^\]]*)\]', call)
+    )
+    assert stated == ([need] if states_limit else [])
+    assert used and used[0] <= (need if states_limit else fa._SCOPED_VMEM_BYTES)
 
 
 @pytest.mark.parametrize("wire", ["fp8", "int8"])
@@ -259,12 +326,14 @@ def test_dots_step_runs_one_flash_forward_a_layer(
     4096, AdamW) compiled twice: as the model builds it, and with ``dots``
     meaning plain ``checkpoint_dots`` again. The layers stay one loop either
     way, so the compiled text holds one Mosaic call for each kernel of a
-    layer body: forward, dq, dkv — and under plain ``checkpoint_dots`` the
+    layer body: forward and the one backward (dq, dk and dv from a single
+    recomputation of the scores) — and under plain ``checkpoint_dots`` the
     forward a second time, in the backward's loop. Keeping (out, lse) may
     cost the program's temporaries no more than those two arrays for each
     layer, and the kernels' schedule tables (two small int32 arrays a call,
     constants where the positions are ``arange``) no more than 1 MiB over
-    what the program needed before it had them."""
+    what the program needed before it had them. No Mosaic call of the step
+    states a VMEM limit (ops/flash_attention.py ``_SCOPED_VMEM_BYTES``)."""
     import json
     from pathlib import Path
 
@@ -296,17 +365,21 @@ def test_dots_step_runs_one_flash_forward_a_layer(
             )
             .compile()
         )
-        return (
-            program.as_text().count('custom_call_target="tpu_custom_call"'),
-            program.memory_analysis().temp_size_in_bytes,
-        )
+        calls = [
+            line for line in program.as_text().splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line
+        ]
+        # No call of a cell's step program states a VMEM limit: one that did
+        # made XLA tile the loss head's backward matmul 3 ms a step slower.
+        assert all('"scoped_memory_configs":[]' in line for line in calls)
+        return len(calls), program.memory_analysis().temp_size_in_bytes
 
     calls, temp = compiled()
     monkeypatch.setattr(
         llama, "_remat_policy", lambda remat: jax.checkpoint_policies.checkpoint_dots
     )
     calls_plain_dots, temp_plain_dots = compiled()
-    assert (calls, calls_plain_dots) == (3, 4)
+    assert (calls, calls_plain_dots) == (2, 3)
     heads, layers = config["num_attention_heads"], config["num_hidden_layers"]
     # bf16 out, f32 lse
     kept = layers * batch * seq * (config["hidden_size"] * 2 + heads * 4)

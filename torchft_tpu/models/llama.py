@@ -71,13 +71,12 @@ class LlamaConfig:
     sp_axis: str = "sp"
     attention_block_size: int = 512
     # KV-block length for the flash path only (the kernel's sequential
-    # accumulation axis). scripts/flash_block_sweep.py on the TPU v5e, PR 35
-    # (the kernels with their causal block schedule, 32/8 heads of 128,
-    # 8192 tokens a call; forward + dq + dkv, ms a layer at 1 x 8192 and at
-    # 4 x 2048): 512x1024 19.10 and 6.70; 512x512 24.76 and 7.80; 256x1024
-    # 24.51 and 8.32; 512x2048 19.70 and 7.69; 1024x1024 17.76 and 6.33,
-    # the one pair under it at both lengths, at twice the VMEM (1024x2048
-    # is refused for VMEM). None = attention_block_size.
+    # accumulation axis). scripts/flash_block_sweep.py on the TPU v5e (32/8
+    # heads of 128, 8192 tokens a call, at 1 x 8192 and at 4 x 2048; PERF.md
+    # section 5 has the table, PR 35's and PR 42's): 512x1024 is under every
+    # smaller pair and under 512x2048; 1024x1024 is the one pair under it at
+    # both lengths, with more VMEM than a call gets unasked at 8192 rows.
+    # None = attention_block_size.
     attention_block_k: Optional[int] = 1024
     # Mosaic kernels cannot be auto-partitioned by XLA SPMD: under a
     # jit-with-mesh (fsdp/tp/dp sharded train step) the flash path must

@@ -11,11 +11,17 @@ TPU with the last axis minor, which makes cross-grid-step VMEM scratch the
 canonical accumulation pattern).
 
 Forward AND backward are fused Pallas kernels on TPU. The backward is the
-standard FlashAttention-2 two-pass recompute from the saved (out, lse)
-residuals: a dq kernel accumulating over KV blocks and a dkv kernel
-accumulating over Q blocks, with the per-row ``delta = rowsum(dO*O)``
-identity computed by XLA outside the kernels (it fuses into the
-surrounding graph). The two residuals the forward kernel produced carry
+FlashAttention-2 recompute from the saved (out, lse) residuals as ONE
+kernel: it visits each needed (q block, KV block) pair once, recomputes the
+pair's probabilities once and adds to all three gradients, five matmuls a
+pair. Its grid walks q blocks innermost, so dk and dv accumulate in
+(block_k, d) scratch across the inner axis, and dq across the outer one in
+a float32 scratch that holds every q row of the head, written out once a
+head through an output block that is resident as long (a sequence whose
+rows do not fit is walked in chunks of q blocks, ``_q_chunks``). The
+per-row ``delta = rowsum(dO*O)`` identity is computed by XLA outside the
+kernel (it fuses into the surrounding graph).
+The two residuals the forward kernel produced carry
 ``checkpoint_name`` tags, ``FLASH_OUT`` and ``FLASH_LSE``: a Pallas call is
 no ``dot_general``, so a remat policy that keeps dot results alone would
 drop them and run the whole forward kernel again in the backward. The
@@ -23,7 +29,7 @@ model's ``remat="dots"`` keeps both by name (models/llama.py
 ``_remat_policy``); ``remat="full"`` and any policy that does not name them
 recompute the kernel, and without remat the names do nothing.
 :func:`flash_attention_partial` is untagged: its VJP is the ring's own.
-All three kernels walk a dense (q block, KV block) grid and know where the
+Both kernels walk a dense (q block, KV block) grid and know where the
 causal diagonal runs through it: XLA reduces the position arrays to each
 block's lowest and highest position once a call (:func:`_block_schedule`),
 the two small tables ride in as scalar prefetch, and a grid step reads its
@@ -37,7 +43,9 @@ ring's zigzag hops, ``sq != sk`` and ragged lengths schedule by the same two
 comparisons as the plain causal call.
 GQA is handled by emitting per-q-head dk/dv partials and summing over the
 group axis outside — keeps every output block written exactly once per
-grid pass (no cross-step output aliasing, which Mosaic cannot express).
+grid pass (no cross-step output aliasing, which Mosaic cannot express; the
+resident dq block is the forward's pattern, an accumulator written out
+when its block index moves on, and not aliasing).
 The scan-based blockwise backward remains the
 interpret/CPU fallback (``use_pallas_bwd`` selects; CPU tests run the
 Pallas backward in interpret mode explicitly). Run :func:`verify_on_chip`
@@ -152,8 +160,8 @@ def _block_schedule(qp, kp, block_q, block_k, interpret):
     (b, 3, nq) and ``k_sched`` (b, 3, nk) int32, XLA's work once a call,
     the kernels' scalar prefetch. Rows _LO and _HI hold each block's lowest
     and highest position. Row _EDGE of ``q_sched`` is the last KV block the
-    q block needs (forward and dq walk KV blocks innermost) and of
-    ``k_sched`` the first q block the KV block needs (dkv walks q blocks
+    q block needs (the forward walks KV blocks innermost) and of ``k_sched``
+    the first q block the KV block needs (the backward walks q blocks
     innermost): the index maps stop there, so a step beyond the edge names
     the block already in VMEM and the pipeline copies nothing."""
     b = qp.shape[0]
@@ -190,30 +198,28 @@ def _block_classes(q_sched, k_sched):
 
 
 def _kv_block(ib, iq, ik, q_sched):
-    """KV block that step (iq, ik) of forward and dq names."""
+    """KV block that step (iq, ik) of the forward names."""
     return jnp.minimum(ik, q_sched[ib, _EDGE, iq])
 
 
 def _q_block(ib, ik, iq, k_sched):
-    """q block that step (ik, iq) of dkv names."""
+    """q block that step (ik, iq) of the backward names."""
     return jnp.maximum(iq, k_sched[ib, _EDGE, ik])
 
 
 def _block_specs(block_q, block_k, d, group, q_of, k_of):
-    """BlockSpecs of one pass over a grid (b, h, third axis, fourth axis),
-    by kind of operand: ``q`` (a q head's rows: q, dO, out, dq), ``kv`` (a KV
-    head's rows, shared by the q heads of its group), ``col`` (a q head's
-    per-row scalars: lse, delta), ``qp`` and ``kp`` (the positions). ``q_of``
-    and ``k_of`` give the q block and the KV block a grid step names, from
-    the step's (ib, third, fourth) and the two schedule tables."""
+    """BlockSpecs of one pass over a grid (b, h, further axes), by kind of
+    operand: ``q`` (a q head's rows: q, dO, out), ``kv`` (a KV head's rows,
+    shared by the q heads of its group), ``col`` (a q head's per-row
+    scalars: lse, delta), ``qp`` and ``kp`` (the positions). ``q_of`` and
+    ``k_of`` give the q block and the KV block a grid step names, from the
+    step's (ib, further axes) and the two schedule tables."""
     from jax.experimental import pallas as pl
 
     def spec(block, index):
         return pl.BlockSpec(
             block,
-            lambda ib, ih, i2, i3, qs, ks: index(
-                ib, ih, q_of(ib, i2, i3, qs, ks), k_of(ib, i2, i3, qs, ks)
-            ),
+            lambda ib, ih, *rest: index(ib, ih, q_of(ib, *rest), k_of(ib, *rest)),
         )
 
     return {
@@ -225,16 +231,6 @@ def _block_specs(block_q, block_k, d, group, q_of, k_of):
         "qp": spec((None, block_q, 1), lambda ib, ih, jq, jk: (ib, jq, 0)),
         "kp": spec((None, 1, block_k), lambda ib, ih, jq, jk: (ib, 0, jk)),
     }
-
-
-def _kv_innermost_specs(block_q, block_k, d, group):
-    """The specs of forward and dq: KV blocks innermost, the KV index stops
-    at the q block's edge."""
-    return _block_specs(
-        block_q, block_k, d, group,
-        lambda ib, iq, ik, qs, ks: iq,
-        lambda ib, iq, ik, qs, ks: _kv_block(ib, iq, ik, qs),
-    )
 
 
 def _when_needed(qs_ref, ks_ref, ib, iq, ik, update):
@@ -369,7 +365,12 @@ def _flash_fwd(
     kt = k.transpose(0, 2, 1, 3)  # (b, kv_heads, sk_p, d)
     vt = v.transpose(0, 2, 1, 3)
 
-    spec = _kv_innermost_specs(block_q, block_k, d, group)
+    # KV blocks innermost; the KV index stops at the q block's edge.
+    spec = _block_specs(
+        block_q, block_k, d, group,
+        lambda ib, iq, ik, qs, ks: iq,
+        lambda ib, iq, ik, qs, ks: _kv_block(ib, iq, ik, qs),
+    )
     inputs = (q_sched, k_sched, qt, kt, vt, qp, kp)
     out, lse = pl.pallas_call(
         partial(_fwd_kernel, scale=scale, nk=nk),
@@ -400,21 +401,47 @@ def _flash_fwd(
     return out, lse.reshape(b, sq, kv_heads, group)
 
 
-def _bwd_dq_kernel(
+# VMEM of one backward call, in bytes (the TPU v5e has 128 MiB a core). A
+# Mosaic call gets _SCOPED_VMEM_BYTES without asking, and asking is not free:
+# with a limit stated on the call, XLA tiles other fusions of the same program
+# differently (PERF.md section 6, PR 42: a matmul of the loss head 3 ms a step
+# slower in every cell). So the call states a limit only where its shapes need
+# more, and holds at most _MAX_VMEM_BYTES: a sequence whose dq rows do not fit
+# that is walked in chunks of q blocks (:func:`_q_chunks`).
+_SCOPED_VMEM_BYTES = 16 * 2**20
+_MAX_VMEM_BYTES = 64 * 2**20
+
+
+def _bwd_kernel(
     qs_ref, ks_ref,
     q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, qp_ref, kp_ref,
-    dq_ref, dq_acc_ref, *, scale: float, nk: int,
+    dq_ref, dk_ref, dv_ref, dq_acc_ref, dk_acc_ref, dv_acc_ref,
+    *, scale: float, nk: int, nqc: int, block_q: int,
 ):
-    """dQ pass: grid (b, h, nq, nk), KV axis innermost; dq accumulates in
-    VMEM scratch across the KV blocks of one q block (FlashAttention-2
-    backward, probabilities recomputed from the saved logsumexp)."""
+    """One step of the backward: grid (b, h, q chunk, nk, nqc), the chunk's
+    q blocks innermost. A needed (q block, KV block) pair recomputes its
+    probabilities once from the saved logsumexp and adds to all three
+    gradients (FlashAttention-2 backward, five matmuls a pair). dk and dv
+    accumulate in (block_k, d) scratch across the q blocks of one KV block
+    and leave as PER-Q-HEAD, per-chunk partials: the GQA group sum happens
+    outside, so every output block is written exactly once. dq accumulates
+    across the KV blocks in a scratch that holds the whole chunk's rows, a
+    step adding into its q block's; the dq output block is the chunk's too,
+    so it stays in VMEM for the chunk and goes out once, cast."""
     from jax.experimental import pallas as pl
 
-    ib, iq, ik = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    ib, ic, ik, jq = (pl.program_id(axis) for axis in (0, 2, 3, 4))
+    iq = ic * nqc + jq
+    rows = pl.ds(pl.multiple_of(jq * block_q, block_q), block_q)
+
+    @pl.when(jq == 0)
+    def _init_dkv():
+        dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
+        dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
 
     @pl.when(ik == 0)
-    def _init():
-        dq_acc_ref[...] = jnp.zeros_like(dq_acc_ref)
+    def _init_dq():
+        dq_acc_ref[rows, :] = jnp.zeros((block_q, dq_acc_ref.shape[1]), jnp.float32)
 
     def _update(masked):
         q = q_ref[...]
@@ -431,54 +458,6 @@ def _bwd_dq_kernel(
             # p from the saved lse; masked entries exactly 0 (also kills
             # padded q rows, whose position is -1 — below every key).
             p = jnp.where(qp_ref[...] >= kp_ref[...], p, 0.0)
-        dp = jax.lax.dot_general(
-            do_ref[...], v_ref[...], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (block_q, block_k) f32
-        ds = p * (dp - dl_ref[...]) * scale
-        dq_acc_ref[...] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    _when_needed(qs_ref, ks_ref, ib, iq, ik, _update)
-
-    @pl.when(ik == nk - 1)
-    def _finalize():
-        dq_ref[...] = dq_acc_ref[...].astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(
-    qs_ref, ks_ref,
-    q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, qp_ref, kp_ref,
-    dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *, scale: float, nq: int,
-):
-    """dK/dV pass: grid (b, h, nk, nq), Q axis innermost; dk/dv accumulate
-    in VMEM scratch across the q blocks of one KV block. Outputs are
-    PER-Q-HEAD partials (b, sk, h, d) — the GQA group sum happens outside
-    so every output block is written exactly once."""
-    from jax.experimental import pallas as pl
-
-    ib, ik, iq = pl.program_id(0), pl.program_id(2), pl.program_id(3)
-
-    @pl.when(iq == 0)
-    def _init():
-        dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
-        dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
-
-    def _update(masked):
-        q = q_ref[...]
-        k = k_ref[...]
-        scores = (
-            jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            * scale
-        )  # (block_q, block_k) f32
-        p = jnp.exp(scores - lse_ref[...])
-        if masked:
-            p = jnp.where(qp_ref[...] >= kp_ref[...], p, 0.0)
         do = do_ref[...]
         dv_acc_ref[...] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -487,19 +466,67 @@ def _bwd_dkv_kernel(
         dp = jax.lax.dot_general(
             do, v_ref[...], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - dl_ref[...]) * scale
+        )  # (block_q, block_k) f32
+        ds = (p * (dp - dl_ref[...]) * scale).astype(q.dtype)
         dk_acc_ref[...] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # (block_k, d)
+        dq_acc_ref[rows, :] += jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # (block_q, d)
 
     _when_needed(qs_ref, ks_ref, ib, iq, ik, _update)
 
-    @pl.when(iq == nq - 1)
-    def _finalize():
+    @pl.when(jq == nqc - 1)
+    def _finalize_dkv():
         dk_ref[...] = dk_acc_ref[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_acc_ref[...].astype(dv_ref.dtype)
+
+    @pl.when(ik == nk - 1)
+    def _finalize_dq():
+        dq_ref[rows, :] = dq_acc_ref[rows, :].astype(dq_ref.dtype)
+
+
+def _bwd_vmem_bytes(q_rows, block_q, block_k, d, in_bytes, out_bytes):
+    """VMEM the backward call needs with ``q_rows`` rows of dq resident, from
+    its shapes: every pipelined block twice (a (block_q, 1) column and the
+    (1, block_k) row pad to whole tiles), the three accumulators, and what
+    Mosaic keeps of a pair's (block_q, block_k) probabilities outside the
+    registers. That last term is a bound, not a derivation: the v5e's
+    compiler reports what a call used (``used_scoped_memory_configs`` in the
+    compiled text), and over 4096 to 57,344 rows of 128, blocks from
+    256 x 512 to 1024 x 2048 and gradients in bf16 and float32 the sum reads
+    0.4 MiB or more over it (13.4 MiB used of 15.6 estimated at the cells'
+    8192 rows in 512 x 1024); tests/test_tpu_aot_compile.py holds the sum
+    over the compiler's account at the shapes it compiles."""
+    width = _next_multiple(d, 128)
+    blocks = (
+        2 * block_q * width * in_bytes  # q, dO
+        + 2 * block_k * width * in_bytes  # k, v
+        + 3 * block_q * 128 * 4  # lse, delta, qp
+        + 8 * block_k * 4  # kp
+        + 2 * block_k * width * out_bytes  # dk, dv
+        + q_rows * width * out_bytes  # dq
+    )
+    scratch = (q_rows + 2 * block_k) * width * 4
+    pair = block_q * block_k * (3 + in_bytes)
+    return 2 * blocks + scratch + pair
+
+
+def _q_chunks(sq, vmem_bytes, block_q, *tile):
+    """(nc, nqc): the backward walks a head's q blocks as nc chunks of nqc,
+    the fewest chunks whose dq rows (accumulator and output block) fit
+    ``vmem_bytes`` beside the call's tiles, evened out. One chunk is the
+    whole sequence resident. ``tile``: the rest of :func:`_bwd_vmem_bytes`'s
+    arguments."""
+    tiles = _bwd_vmem_bytes(0, block_q, *tile)
+    fit = (vmem_bytes - tiles) // (_bwd_vmem_bytes(block_q, block_q, *tile) - tiles)
+    nq = -(-sq // block_q)
+    nc = -(-nq // max(1, fit))
+    nqc = -(-nq // nc)
+    return -(-nq // nqc), nqc
 
 
 def flash_attention_partial_bwd(
@@ -508,6 +535,7 @@ def flash_attention_partial_bwd(
     scale, block_q, block_k, interpret,
     delta=None,
     out_dtype=None,
+    vmem_bytes=_MAX_VMEM_BYTES,
 ):
     """Fused Pallas backward PARTIAL over an arbitrary KV block: the ring
     backward building block (and, with arange positions, the full causal
@@ -521,10 +549,16 @@ def flash_attention_partial_bwd(
     across hops). Returns (dq_partial, dk, dv) in ``out_dtype`` (default
     f32 — ring callers accumulate partials across hops in f32 and cast
     once at the end; the single-block full-causal caller passes the input
-    dtype so the kernels cast in VMEM and halve the gradient writeback for
+    dtype so the kernel casts in VMEM and halves the gradient writeback for
     bf16 models). dk/dv are group-summed. Padding: q rows pad with
     position -1 (below every key → zero contribution to every gradient);
-    KV rows pad with _PAD_POS (above every query → likewise zero)."""
+    KV rows pad with _PAD_POS (above every query → likewise zero).
+
+    One Mosaic call (:func:`_bwd_kernel`). Its float32 dq accumulator holds
+    all the q rows of a head where the call then fits ``vmem_bytes``; a
+    longer sequence is walked in equal chunks of as many q blocks as fit,
+    each chunk one more set of dk/dv partials for the group sum. The choice
+    is by shape alone; the argument is for tests, which make it small."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -542,7 +576,11 @@ def flash_attention_partial_bwd(
             d_out.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
         )  # (b, sq, h)
 
-    pad_q = (-sq) % block_q
+    tile = (block_q, block_k, d, q.dtype.itemsize, jnp.dtype(out_dtype).itemsize)
+    nc, nqc = _q_chunks(sq, vmem_bytes, *tile)
+    q_rows = nqc * block_q
+    need = _bwd_vmem_bytes(q_rows, *tile)
+    pad_q = nc * q_rows - sq
     pad_k = (-sk) % block_k
     if pad_q:
         q = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0)))
@@ -552,16 +590,15 @@ def flash_attention_partial_bwd(
     if pad_k:
         k = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
-    nq = (sq + pad_q) // block_q
     nk = (sk + pad_k) // block_k
     qp, kp = _padded_positions(
-        q_positions, k_positions, b, sq, sk, block_q, block_k
+        q_positions, k_positions, b, sq, sk, q_rows, block_k
     )
     q_sched, k_sched = _block_schedule(qp, kp, block_q, block_k, interpret)
     qp = qp.reshape(b, sq + pad_q, 1)
     kp = kp.reshape(b, 1, sk + pad_k)
     # Same heads-major transposition as _flash_fwd (see comment there): the
-    # kernels see (b, h, seq, d) / (b, h, seq, 1) so seq and d are the block
+    # kernel sees (b, h, seq, d) / (b, h, seq, 1) so seq and d are the block
     # minor dims Mosaic requires.
     qt = q.transpose(0, 2, 1, 3)  # (b, h, sq_p, d)
     kt = k.transpose(0, 2, 1, 3)  # (b, kv_heads, sk_p, d)
@@ -571,64 +608,66 @@ def flash_attention_partial_bwd(
     delta_col = delta.reshape(b, sq + pad_q, h, 1).transpose(0, 2, 1, 3)
     inputs = (q_sched, k_sched, qt, kt, vt, dot, lse_col, delta_col, qp, kp)
 
-    # q, k, v, dO, lse, delta, qp, kp by kind of spec (_block_specs).
-    operands = ("q", "kv", "kv", "q", "col", "col", "qp", "kp")
-    # dQ pass: KV blocks innermost, as in the forward.
-    spec = _kv_innermost_specs(block_q, block_k, d, group)
-    dq = pl.pallas_call(
-        partial(_bwd_dq_kernel, scale=scale, nk=nk),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, h, nq, nk),
-            in_specs=[spec[kind] for kind in operands],
-            out_specs=[spec["q"]],
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        ),
-        out_shape=[_out_struct((b, h, sq + pad_q, d), out_dtype, inputs)],
-        interpret=interpret,
-    )(*inputs)[0]
-    dq = dq.transpose(0, 2, 1, 3)  # (b, sq_p, h, d)
-
-    # dK/dV pass: the two inner grid axes swapped (KV outer, Q innermost) so
-    # the accumulators persist across q blocks; the q index starts at the KV
-    # block's edge. The outputs are per q head, so not the "kv" spec.
+    # The chunk's q blocks innermost, so the dk/dv accumulators persist
+    # across them; the q index starts at the KV block's edge and stays in
+    # the chunk.
     spec = _block_specs(
         block_q, block_k, d, group,
-        lambda ib, ik, iq, qs, ks: _q_block(ib, ik, iq, ks),
-        lambda ib, ik, iq, qs, ks: ik,
+        lambda ib, ic, ik, jq, qs, ks: jnp.minimum(
+            _q_block(ib, ik, ic * nqc + jq, ks), ic * nqc + nqc - 1
+        ),
+        lambda ib, ic, ik, jq, qs, ks: ik,
+    )
+    # The outputs are per q head (dk, dv: and per chunk), so not "q" / "kv".
+    dq_out = pl.BlockSpec(
+        (None, None, q_rows, d), lambda ib, ih, ic, ik, jq, qs, ks: (ib, ih, ic, 0)
     )
     dkv_out = pl.BlockSpec(
-        (None, None, block_k, d), lambda ib, ih, ik, iq, qs, ks: (ib, ih, ik, 0)
+        (None, None, None, block_k, d),
+        lambda ib, ih, ic, ik, jq, qs, ks: (ib, ih, ic, ik, 0),
     )
-    dk_h, dv_h = pl.pallas_call(
-        partial(_bwd_dkv_kernel, scale=scale, nq=nq),
+    dq, dk_h, dv_h = pl.pallas_call(
+        partial(_bwd_kernel, scale=scale, nk=nk, nqc=nqc, block_q=block_q),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b, h, nk, nq),
-            in_specs=[spec[kind] for kind in operands],
-            out_specs=[dkv_out, dkv_out],
+            grid=(b, h, nc, nk, nqc),
+            # q, k, v, dO, lse, delta, qp, kp by kind of spec (_block_specs).
+            in_specs=[
+                spec[kind]
+                for kind in ("q", "kv", "kv", "q", "col", "col", "qp", "kp")
+            ],
+            out_specs=[dq_out, dkv_out, dkv_out],
             scratch_shapes=[
+                pltpu.VMEM((q_rows, d), jnp.float32),
                 pltpu.VMEM((block_k, d), jnp.float32),
                 pltpu.VMEM((block_k, d), jnp.float32),
             ],
         ),
         out_shape=[
-            _out_struct((b, h, sk + pad_k, d), out_dtype, inputs),
-            _out_struct((b, h, sk + pad_k, d), out_dtype, inputs),
+            _out_struct((b, h, sq + pad_q, d), out_dtype, inputs),
+            _out_struct((b, h, nc, sk + pad_k, d), out_dtype, inputs),
+            _out_struct((b, h, nc, sk + pad_k, d), out_dtype, inputs),
         ],
+        compiler_params=(
+            pltpu.CompilerParams(vmem_limit_bytes=need)
+            if need > _SCOPED_VMEM_BYTES
+            else None
+        ),
         interpret=interpret,
     )(*inputs)
-    dk_h = dk_h.transpose(0, 2, 1, 3)  # (b, sk_p, h, d)
-    dv_h = dv_h.transpose(0, 2, 1, 3)
+    dq = dq.transpose(0, 2, 1, 3)  # (b, sq_p, h, d)
+    dk_h = dk_h.transpose(0, 3, 1, 2, 4)  # (b, sk_p, h, nc, d)
+    dv_h = dv_h.transpose(0, 3, 1, 2, 4)
 
     if pad_q:
         dq = dq[:, :sq]
     if pad_k:
         dk_h = dk_h[:, :sk]
         dv_h = dv_h[:, :sk]
-    # GQA group sum of the per-q-head partials (one XLA reduction).
-    dk = dk_h.reshape(b, sk, kv_heads, group, d).sum(axis=3)
-    dv = dv_h.reshape(b, sk, kv_heads, group, d).sum(axis=3)
+    # Sum of the per-q-head, per-chunk partials over the GQA group and the
+    # chunks (one XLA reduction).
+    dk = dk_h.reshape(b, sk, kv_heads, group * nc, d).sum(axis=3)
+    dv = dv_h.reshape(b, sk, kv_heads, group * nc, d).sum(axis=3)
     return dq, dk, dv
 
 
@@ -740,22 +779,23 @@ def flash_attention(
     use_pallas_bwd: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Fused causal GQA attention on one device: Pallas forward AND
-    FlashAttention-2-style Pallas backward (dq + dkv kernels recomputing
-    probabilities from the saved logsumexp).
+    FlashAttention-2-style Pallas backward (one kernel that recomputes the
+    probabilities from the saved logsumexp once a block pair and gives dq,
+    dk and dv).
 
     Shapes: q (b, s, h, d); k/v (b, s, kv_heads, d); h % kv_heads == 0.
     The sequence is padded to block multiples internally; outputs are
     returned in the original length. The default blocks are 512x1024:
     scripts/flash_block_sweep.py, read from the device trace on the TPU
-    v5e (PR 35; models/llama.py has the table beside
-    ``attention_block_k``), puts every smaller pair and 512x2048 over it
-    at 2048 and at 8192, and 1024x1024 5-7% under it at twice the VMEM.
+    v5e (PERF.md section 5 has the tables, PR 35's and PR 42's), puts every
+    smaller pair and 512x2048 over it at 2048 and at 8192, and 1024x1024
+    under it with more VMEM than a call gets unasked at 8192 rows.
     Oversized blocks clamp to the padded sequence below, so short
     sequences are unaffected. ``interpret=None`` auto-selects interpret
     mode off-TPU so the same call works in CPU tests.
     ``use_pallas_bwd=None`` picks the fused backward exactly when the
     forward compiles (on TPU); CPU tests pass True to exercise the
-    backward kernels in interpret mode, and False forces the scan-based
+    backward kernel in interpret mode, and False forces the scan-based
     blockwise fallback.
     """
     b, s, h, d = q.shape
@@ -816,9 +856,11 @@ def verify_on_chip() -> dict:
 
         python -c "from torchft_tpu.ops.flash_attention import verify_on_chip; print(verify_on_chip())"
 
-    Returns the largest error of each case and, under ``classes``, how many
+    Returns the largest error of each case; under ``classes``, how many
     block pairs of the case the schedule classed above, on and under the
-    diagonal: how often the scheduling engaged.
+    diagonal: how often the scheduling engaged; and under ``bwd_q_chunks``
+    the path each case's backward calls took: 1 is dq resident in VMEM for
+    the whole sequence, more is that many chunks of q blocks a head.
     """
     from torchft_tpu.models.llama import causal_attention
 
@@ -827,7 +869,7 @@ def verify_on_chip() -> dict:
         raise RuntimeError(f"no TPU attached (devices()[0] is {dev})")
     b, s, h, kv, d = 2, 256, 4, 2, 64
     scale = d**-0.5
-    classes = {}
+    classes, chunks = {}, {}
 
     def qkv(sq, sk, seed=0):
         kq, kk, kvk = jax.random.split(jax.random.PRNGKey(seed), 3)
@@ -880,11 +922,14 @@ def verify_on_chip() -> dict:
             )
 
         classes[case] = _class_counts(sq, sq, block_q, block_k)
+        chunks[case], _ = _q_chunks(  # bf16 in, bf16 out
+            sq, _MAX_VMEM_BYTES, *_block_sizes(block_q, block_k, sq, sq), d, 2, 2
+        )
         q, k, v = qkv(sq, sq)
         return errors(q, k, v, dense(q, k, v), grads(dense, q, k, v))
 
-    @partial(jax.jit, static_argnums=(3, 4))
-    def hop_errors(q, qp, shards, block_q, block_k):
+    @partial(jax.jit, static_argnums=(3, 4, 5))
+    def hop_errors(q, qp, shards, block_q, block_k, vmem_bytes):
         merged = lse = None
         for k, v, kp in shards:
             o, l = flash_attention_partial(
@@ -901,6 +946,7 @@ def verify_on_chip() -> dict:
             dq_p, dk, dv = flash_attention_partial_bwd(
                 q, k, v, d_out.astype(q.dtype), merged.astype(q.dtype), lse,
                 qp, kp, scale, block_q, block_k, False,
+                vmem_bytes=vmem_bytes,
             )
             dq, dks, dvs = dq + dq_p, dks + [dk], dvs + [dv]
 
@@ -924,7 +970,7 @@ def verify_on_chip() -> dict:
             vjp(d_out.astype(ref.dtype)),
         )
 
-    def hops(q, qp, shards, block_q, block_k, case):
+    def hops(q, qp, shards, block_q, block_k, case, vmem_bytes=_MAX_VMEM_BYTES):
         """The ring's building blocks against dense attention under the same
         position mask: one :func:`flash_attention_partial` a KV shard, merged
         by logsumexp, then one :func:`flash_attention_partial_bwd` a shard
@@ -933,7 +979,10 @@ def verify_on_chip() -> dict:
             _class_counts(q.shape[1], k.shape[1], block_q, block_k, qp, kp)
             for k, _, kp in shards
         ]
-        return hop_errors(q, qp, shards, block_q, block_k)
+        chunks[case], _ = _q_chunks(  # bf16 in, f32 out
+            q.shape[1], vmem_bytes, block_q, block_k, d, 2, 4
+        )
+        return hop_errors(q, qp, shards, block_q, block_k, vmem_bytes)
 
     err, _ = full(s, 128, 128, "causal")
     err = check("forward", err, 0.05)  # bf16 tolerance
@@ -984,6 +1033,14 @@ def verify_on_chip() -> dict:
     )
     err_z = check("ZIGZAG", err_z, 0.05)
     err_zb = check("ZIGZAG BACKWARD", err_zb, 0.25)
+    # The same hops with room for one q block of dq: a sequence longer than
+    # the resident accumulator, walked as four chunks a head.
+    _, err_cb = hops(
+        q, at(1), [(k0, v0, at(1)), (k1, v1, at(0)), (k2, v2, at(2))],
+        128, 128, "zigzag-chunked",
+        vmem_bytes=_bwd_vmem_bytes(128, 128, 128, d, 2, 4),
+    )
+    err_cb = check("CHUNKED BACKWARD", err_cb, 0.25)
 
     # A ragged causal length over several blocks: 600 = 4 x 128 + 88 =
     # 2 x 256 + 88, so the last q block and the last KV block are padded and
@@ -999,8 +1056,10 @@ def verify_on_chip() -> dict:
         "max_err_partial_bwd": err_pb,
         "max_err_zigzag": err_z,
         "max_err_zigzag_bwd": err_zb,
+        "max_err_chunked_bwd": err_cb,
         "max_err_ragged": err_r,
         "max_err_ragged_bwd": err_rb,
         "classes": classes,
+        "bwd_q_chunks": chunks,
         "ok": True,
     }

@@ -449,10 +449,10 @@ def _ring_bwd_loop(axis_name, dq0, k, v, k_pos, per_hop):
 def _ring_flash_bwd_pallas(
     axis_name, scale, block_q, block_k, interpret, residuals, d_out
 ):
-    """Ring backward with the fused Pallas dq/dkv kernels as the per-hop
+    """Ring backward with the fused Pallas backward kernel as the per-hop
     block compute: each hop runs flash_attention_partial_bwd with the
     GLOBAL logsumexp (and the hop-invariant delta = rowsum(dO·O), computed
-    once). The kernels' position-driven causal block skip gives zigzag
+    once). The kernel's position-driven causal block skip gives zigzag
     layouts their balance on the backward too."""
     from torchft_tpu.ops.flash_attention import flash_attention_partial_bwd
 
@@ -562,7 +562,7 @@ def ring_attention_flash(
     unaffected. Same shapes/semantics as :func:`ring_attention`. The
     backward is a true ring backward from the saved (out, lse); on TPU
     (``use_pallas_bwd=None`` → when the forward compiles) each hop runs
-    the fused dq/dkv kernels (flash_attention_partial_bwd), with the
+    the fused backward kernel (flash_attention_partial_bwd), with the
     einsum ring backward as the interpret/CPU fallback."""
     axis_index = jax.lax.axis_index(axis_name)
     b, s_local, h, d = q.shape
