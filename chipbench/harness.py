@@ -416,29 +416,32 @@ class HostPulse:
 
 
 class Tracer:
-    """``jax.profiler`` around the window of a ``--trace 1`` run; the trace is
-    reduced to a small dictionary and its files are deleted."""
+    """The program's capture control (``tracing.start_capture`` /
+    ``stop_capture``: the profiler, host spans and no Python frames) around a
+    traced window; the trace is reduced to a small dictionary and its files
+    are deleted. ``capture`` is what ``stop_capture()`` returned, less the
+    directory: the journal's ``events`` and the registry's ``counters`` of
+    the window, the ``clock`` anchors and ``dropped``."""
 
     def __init__(self, enabled: bool) -> None:
         self.enabled = enabled
         self.dir: Optional[str] = None
+        self.capture: Optional[Dict[str, Any]] = None
 
     def __enter__(self) -> "Tracer":
         if self.enabled:
-            import jax
+            from torchft_tpu import tracing
 
             self.dir = tempfile.mkdtemp(prefix="chipbench_trace_")
-            options = jax.profiler.ProfileOptions()
-            options.python_tracer_level = 0  # the spans, not every Python frame
-            options.host_tracer_level = 2
-            jax.profiler.start_trace(self.dir, profiler_options=options)
+            tracing.start_capture(self.dir)
         return self
 
     def __exit__(self, *exc: object) -> None:
         if self.enabled:
-            import jax
+            from torchft_tpu import tracing
 
-            jax.profiler.stop_trace()
+            self.capture = tracing.stop_capture()
+            del self.capture["trace_dir"]
 
     def reduce(self, keep_in: Optional[Path] = None) -> Optional[Dict[str, Any]]:
         if not self.enabled or self.dir is None:
@@ -466,21 +469,27 @@ class Run:
 
     def __init__(
         self, cell: Dict[str, Any], config: Dict[str, Any], architecture: ModuleType,
-        traffic: Dict[str, Any], seed: int, seconds: float, trace: bool,
+        traffic: Dict[str, Any], seed: int, seconds: float, trace: int,
         started: float, rehearsal: bool, out_dir: Optional[Path],
     ) -> None:
         self.cell, self.config, self.traffic = cell, config, traffic
         self.architecture = architecture  # the file the config's model_type names
-        self.seed, self.seconds, self.trace = seed, seconds, trace
+        self.seed, self.seconds = seed, seconds
+        # ``--trace``: 1 traces the one window; 2 measures as 0 does and then
+        # traces a tail of the same traffic in the same process.
+        self.trace, self.tail = trace == 1, trace == 2
         self.started, self.rehearsal, self.out_dir = started, rehearsal, out_dir
         self.chips = int(cell["chips"])
 
+    def traced_seconds(self) -> float:
+        """A traced window is short (traces are large and the tracer slows
+        the host): ``trace_seconds`` of the traffic mix."""
+        return min(self.seconds, float(self.traffic.get("trace_seconds", self.seconds)))
+
     def window_seconds(self) -> float:
-        """A traced run measures a short window (traces are large and the
-        tracer slows the host); the untraced run the whole of ``--seconds``."""
-        if self.trace:
-            return min(self.seconds, float(self.traffic.get("trace_seconds", self.seconds)))
-        return self.seconds
+        """The window a run's end-to-end numbers come from: the traced one
+        under ``--trace 1``, else the whole of ``--seconds``."""
+        return self.traced_seconds() if self.trace else self.seconds
 
 
 def run_one_process(run: Run, make_job: Callable[..., Any]) -> Dict[str, Any]:
@@ -501,7 +510,6 @@ def run_one_process(run: Run, make_job: Callable[..., Any]) -> Dict[str, Any]:
 
     params = system.init_params()
     system.reference = reference_losses(system, params)
-    gauge = MemoryGauge(devices)
     job = make_job(run, system, params, spans)
     del params
     problems: List[str] = []
@@ -535,81 +543,118 @@ def run_one_process(run: Run, make_job: Callable[..., Any]) -> Dict[str, Any]:
         for unit in range(int(traffic["warmup_units"])):
             run_unit(unit)
         fetch()
-        warm_steps = len(losses)
         problems += reference_check(system, [float(x) for x in losses[:2]])
         gc.collect()
         gc.freeze()  # the warmed-up heap is not walked again inside the window
 
         compile_setup = ledger.snapshot()
-        counters_before = counter_sums()
-        clocks_before = host_clocks()
-        with HostPulse(gauge.sample) as pulse, Tracer(run.trace) as tracer:
-            window = run_window(
-                run_unit, fetch, run.window_seconds(), int(traffic.get("min_units", 1))
-            )
-        clocks = {k: v - clocks_before[k] for k, v in host_clocks().items()}
-        setup_s = window.opened - run.started
-        counters = counter_deltas(counters_before, counter_sums())
-        compiled_inside = ledger.compiles - compile_setup["count"]
-        trace = tracer.reduce(run.out_dir)
 
-        steps = len(losses) - warm_steps
-        values = np.asarray(jax.device_get(losses), dtype=np.float64)
-        problems += window_checks(values, compiled_inside)
-        failed, job_problems = job.check(warm_steps, steps, window.units)
-        problems += job_problems
-        device = gauge.report()
-        problems += memory_problems(device)
-        obs: Dict[str, Any] = {
-            "chips": run.chips,
-            "tokens": steps * system.tokens_per_step,
-            "window_s": window.seconds,
-            "steps": steps,
-            "units": window.units,
-            "setup_s": setup_s,
-            "arrays_peak_bytes": device.pop("arrays_peak_bytes"),
-            "held_peak_bytes": device.pop("held_peak_bytes"),
-            "scratch_peak_bytes": device.pop("scratch_peak_bytes"),
-            "compile": compile_setup,
-            "counters": counters,
-            "spans": spans.totals(window.opened, window.closed),
-            "step_ends": [t - window.opened for t in step_ends[warm_steps:]],
-            "host_stall_s": pulse.longest,
-            "host_stall_at_s": pulse.longest_at - window.opened,
-            "host_clocks": clocks,
-            "memory": {k: device.pop(k) for k in ("bytes_limit", "samples", "sample_seconds")},
-            "steps_per_unit": steps_per_unit,
-            "trace": trace,
-            "flops_per_token": run.architecture.train_flops_per_token(run.config, system.seq),
-            "peaks": None if run.rehearsal else peaks_for(devices[0].device_kind),
-            # For the readers that count what a kernel needs from the shapes.
-            "config": run.config,
-            "batch": system.batch,
-            "seq": system.seq,
-        }
-        obs.update(job.observations())
-        say(
-            f"window: {steps} steps in {window.units} units, {window.seconds:.3f}s "
-            f"between fetches, setup {setup_s:.1f}s, compile in set-up "
-            f"{compile_setup['seconds']:.1f}s in {compile_setup['count']} "
-            f"(cache hits {compile_setup['cache_hits']}, misses {compile_setup['cache_misses']}); "
-            f"slowest steps {slowest_steps(obs['step_ends'])}; longest host "
-            f"oversleep {1e3 * pulse.longest:.0f}ms at {obs['host_stall_at_s']:.1f}s; "
-            f"clocks over the window {json.dumps({k: round(v, 3) for k, v in clocks.items()})}; "
-            f"{obs['memory']['samples']} memory samples took {1e3 * obs['memory']['sample_seconds']:.1f}ms"
-        )
-        if run.out_dir is not None:
-            run.out_dir.mkdir(parents=True, exist_ok=True)
-            name = f"series_{run.cell['name']}_{run.seed}_{int(run.trace)}_{os.getpid()}.json"
-            (run.out_dir / name).write_text(json.dumps({
-                "step_ends": obs["step_ends"], "losses": values.tolist()[warm_steps:],
-                "window_s": window.seconds, "steps": steps, "obs": {
-                    k: v for k, v in obs.items() if k not in ("step_ends", "trace")
-                },
-            }))
+        def measure(seconds: float, traced: bool, measured: Optional[Dict[str, Any]]):
+            """One window of whole units and everything read from it: its
+            observations, failed operations, problems and device report.
+            Called once for the measured window and, under ``--trace 2``,
+            once more for the traced tail (``measured``: what the first gave).
+            The steps of a window pass the job's checks from its own offset."""
+            first = len(losses)
+            gauge = MemoryGauge(devices)
+            compiled_before = ledger.compiles
+            counters_before = counter_sums()
+            clocks_before = host_clocks()
+            with HostPulse(gauge.sample) as pulse, Tracer(traced) as tracer:
+                window = run_window(
+                    run_unit, fetch, seconds, int(traffic.get("min_units", 1))
+                )
+            clocks = {k: v - clocks_before[k] for k, v in host_clocks().items()}
+            # Set-up ends where the measured window opens; a tail has none.
+            setup_s = measured["setup_s"] if measured else window.opened - run.started
+            counters = counter_deltas(counters_before, counter_sums())
+            compiled_inside = ledger.compiles - compiled_before
+            trace = tracer.reduce(run.out_dir)
+
+            steps = len(losses) - first
+            values = np.asarray(jax.device_get(losses), dtype=np.float64)
+            faults = window_checks(values, compiled_inside)
+            failed, job_problems = job.check(first, steps, window.units)
+            faults += job_problems
+            device = gauge.report()
+            faults += memory_problems(device)
+            obs: Dict[str, Any] = {
+                "chips": run.chips,
+                "tokens": steps * system.tokens_per_step,
+                "window_s": window.seconds,
+                "steps": steps,
+                "units": window.units,
+                "setup_s": setup_s,
+                "arrays_peak_bytes": device.pop("arrays_peak_bytes"),
+                "held_peak_bytes": device.pop("held_peak_bytes"),
+                "scratch_peak_bytes": device.pop("scratch_peak_bytes"),
+                "compile": compile_setup,
+                "counters": counters,
+                "spans": spans.totals(window.opened, window.closed),
+                "step_ends": [t - window.opened for t in step_ends[first:]],
+                "host_stall_s": pulse.longest,
+                "host_stall_at_s": pulse.longest_at - window.opened,
+                "host_clocks": clocks,
+                "memory": {k: device.pop(k) for k in ("bytes_limit", "samples", "sample_seconds")},
+                "steps_per_unit": steps_per_unit,
+                "trace": trace,
+                "flops_per_token": run.architecture.train_flops_per_token(run.config, system.seq),
+                "peaks": None if run.rehearsal else peaks_for(devices[0].device_kind),
+                # For the readers that count what a kernel needs from the shapes.
+                "config": run.config,
+                "batch": system.batch,
+                "seq": system.seq,
+            }
+            if measured is not None:  # the traced tail of ``--trace 2``
+                obs["capture"] = tracer.capture
+                obs["measured"] = {k: measured[k] for k in ("tokens", "window_s", "steps", "units")}
+            obs.update(job.observations())
+            say(
+                f"window: {steps} steps in {window.units} units, {window.seconds:.3f}s "
+                f"between fetches, setup {setup_s:.1f}s, compile in set-up "
+                f"{compile_setup['seconds']:.1f}s in {compile_setup['count']} "
+                f"(cache hits {compile_setup['cache_hits']}, misses {compile_setup['cache_misses']}); "
+                f"slowest steps {slowest_steps(obs['step_ends'])}; longest host "
+                f"oversleep {1e3 * pulse.longest:.0f}ms at {obs['host_stall_at_s']:.1f}s; "
+                f"clocks over the window {json.dumps({k: round(v, 3) for k, v in clocks.items()})}; "
+                f"{obs['memory']['samples']} memory samples took {1e3 * obs['memory']['sample_seconds']:.1f}ms"
+            )
+            if run.out_dir is not None:
+                run.out_dir.mkdir(parents=True, exist_ok=True)
+                level = 1 if run.trace else 2 if traced else 0
+                name = f"series_{run.cell['name']}_{run.seed}_{level}_{os.getpid()}.json"
+                (run.out_dir / name).write_text(json.dumps({
+                    "step_ends": obs["step_ends"], "losses": values.tolist()[first:],
+                    "window_s": window.seconds, "steps": steps, "obs": {
+                        k: v for k, v in obs.items() if k not in ("step_ends", "trace")
+                    },
+                }))
+            return obs, failed, faults, device
+
+        obs, failed, faults, device = measure(run.window_seconds(), run.trace, None)
+        problems += faults
+        tail = None
+        if run.tail:
+            # Every number of the measured window is taken. One capture is
+            # thrown away, so that the profiler's first start falls into no
+            # number; then the same traffic once more, traced.
+            cold = Tracer(True)
+            with cold:
+                pass
+            shutil.rmtree(cold.dir, ignore_errors=True)
+            tail, _, faults, tail_device = measure(run.traced_seconds(), True, obs)
+            # ``correct``, ``attempted`` and ``failed`` are the measured
+            # window's; what the tail's own checks found is said beside them.
+            for fault in faults:
+                say(f"TRACED TAIL NOT CORRECT: {fault}")
+            # The line's peak is the whole run's.
+            device["memory_peak_bytes"] = max(
+                device["memory_peak_bytes"], tail_device["memory_peak_bytes"]
+            )
     finally:
         job.close()
     return {
         "correct": not problems, "problems": problems,
-        "attempted": steps, "failed": failed, "obs": obs, "device": device,
+        "attempted": obs["steps"], "failed": failed, "obs": obs, "device": device,
+        "tail": tail,
     }

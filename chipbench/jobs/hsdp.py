@@ -39,6 +39,11 @@ def run(run) -> Dict[str, Any]:
 
     if int(run.traffic["groups"]) != GROUPS:
         raise ValueError(f"the hsdp job runs {GROUPS} groups")
+    if run.tail:
+        raise SystemExit(
+            "no result: --trace 2 measures and traces in ONE process, and this job's "
+            "chips are held by worker processes of their own; it takes --trace 0 or 1"
+        )
     extra_env = {"TPUFT_LOG": os.environ.get("TPUFT_LOG", "warn")}
     if run.rehearsal:
         extra_env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"

@@ -1,8 +1,9 @@
 """The benchmark's own spans around its calls into each layer.
 
 Kept in memory on the host's monotonic clock and, while a profiler trace is
-running, mirrored as ``jax.profiler.TraceAnnotation`` so that the device
-trace's idle gaps can be attributed to what the host was doing. Spans inside
+running (``harness.Tracer`` starts it through the program's capture control),
+mirrored as ``jax.profiler.TraceAnnotation`` so that the device trace's idle
+gaps can be attributed to what the host was doing. Spans inside
 the program are the program's (``tpuft::...``); these carry the prefix
 ``chipbench/``.
 """
@@ -15,6 +16,9 @@ from typing import Dict, Iterator, List, Tuple
 
 
 class SpanLog:
+    """Reads ``time.monotonic``, the clock of the program's own ``_Span``, so a
+    capture's ``clock`` anchors lay both kinds of span onto one timeline."""
+
     def __init__(self) -> None:
         self.spans: List[Tuple[str, float, float]] = []
 

@@ -42,6 +42,9 @@ TOP_KEYS = {
     "command", "paths", "run_seconds", "configs", "workloads", "end_to_end",
     "per_layer",
 }
+# May stand beside them: ``"trace_in_run": true`` says that the per-layer
+# numbers are taken by ``--trace 2``, in the run that measures.
+OPTIONAL_TOP_KEYS = {"trace_in_run"}
 # What ``reduced`` never names. The model-configs guide, section 4: "No width
 # is ever cut. How many heads, experts or rows of the vocabulary are held here
 # may be the chip's share". Its widths are the hidden, head, feed-forward and
@@ -186,9 +189,11 @@ def problems(bench: Benchmark) -> List[str]:
     def bad(msg: str) -> None:
         out.append(msg)
 
-    if set(data) != TOP_KEYS:
+    if set(data) - OPTIONAL_TOP_KEYS != TOP_KEYS:
         bad(f"top-level keys {sorted(data)} != {sorted(TOP_KEYS)}")
         return out
+    if not isinstance(data.get("trace_in_run", False), bool):
+        bad(f"trace_in_run must be true or false, not {data['trace_in_run']!r}")
     if not (isinstance(data["run_seconds"], int) and 1 <= data["run_seconds"] <= 51):
         bad("run_seconds must be a whole number from 1 to 51")
     for p in data["paths"]:

@@ -94,6 +94,25 @@ def test_benchmark_json_is_sound(bench_root):
                 assert callable(bench.reader(group, metric["name"]).read), metric["name"]
 
 
+@pytest.mark.parametrize("edit,want", [
+    ({"trace_in_run": True}, None),
+    ({"trace_in_run": False}, None),
+    ({"trace_in_run": "yes"}, "trace_in_run must be true or false"),
+    ({"trace_in_run": True, "stray": 1}, "top-level keys"),
+])
+def test_trace_in_run_is_an_optional_boolean_and_no_other_key_is_taken(edit, want, tmp_path, bench_root):
+    copy = tmp_path / "repo"
+    copy_benchmark(copy, bench_root)
+    data = json.loads((bench_root / "BENCHMARK.json").read_text())
+    data.pop("trace_in_run", None)
+    (copy / "BENCHMARK.json").write_text(json.dumps({**data, **edit}))
+    found = spec.problems(spec.Benchmark(copy))
+    if want is None:
+        assert found == []
+    else:
+        assert len(found) == 1 and want in found[0], found
+
+
 def test_problems_catches_a_moves_that_a_cell_does_not_report(tmp_path, bench_root):
     copy = copy_with_parked_cell(tmp_path / "repo", bench_root)
     assert spec.problems(spec.Benchmark(copy)) == []  # the parked entries still fit
@@ -702,6 +721,11 @@ def run_cell(workload: str, *extra: str, rehearse: bool = True, root: Path = ROO
     ("mistral7b-1chip.ftddp-seq8k", "0"),  # one sequence of four reference blocks
     ("mistral7b-1chip.ftddp-seq8k", "1"),
     ("mistral7b-2x2.hsdp", "0"),  # parked: rehearsed from a copy that lists it
+    # One process measures and then traces: both kinds of metric on one line.
+    ("mistral7b-1chip.plain", "2"),
+    ("mistral7b-1chip.ftddp", "2"),
+    ("mistral7b-1chip.diloco-fp8", "2"),
+    ("mistral7b-1chip.ftddp-seq8k", "2"),
 ])
 def test_rehearsal_prints_the_contract_line(workload, trace, tmp_path):
     root = ROOT
@@ -715,14 +739,26 @@ def test_rehearsal_prints_the_contract_line(workload, trace, tmp_path):
     assert set(line["device"]) == DEVICE_KEYS
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] >= 1
     bench = spec.Benchmark(root)
-    group = "per_layer" if trace == "1" else "end_to_end"
-    allowed = {m["name"]: m["unit"] for m in bench.metrics_of(workload, group)}
+    groups = {"0": ["end_to_end"], "1": ["per_layer"], "2": ["end_to_end", "per_layer"]}[trace]
+    allowed = {m["name"]: m["unit"] for g in groups for m in bench.metrics_of(workload, g)}
     assert line["metrics"], "no metric on the line"
     for name, entry in line["metrics"].items():
         assert set(entry) == {"value", "unit"} and entry["unit"] == allowed[name]
         assert isinstance(entry["value"], float)
+    if trace in ("0", "2"):
+        assert {m["name"] for m in bench.metrics_of(workload, "end_to_end")} <= set(line["metrics"])
     if trace == "0":
         assert set(line["metrics"]) == set(allowed)
+    if trace == "1":  # as before PR 43: the capture's readers find nothing to read
+        assert not {"ft_step_host_ms", "outer_sync_host_ms", "trace_overhead_pct"} & set(line["metrics"])
+    if trace == "2":
+        # What needs no chip is there: the capture's readers, and the counters
+        # and clocks of the measured window. (A ``breakdown``, ``busy_s`` and
+        # the device-trace metrics need a TPU plane in the trace: none here.)
+        by_source = {m["name"]: m["source"] for m in bench.metrics_of(workload, "per_layer")}
+        want = {n for n, source in by_source.items() if source != "device_trace"} - {"mfu_pct"}
+        assert want <= set(line["metrics"]), sorted(want - set(line["metrics"]))
+        assert {"trace_overhead_pct", "host_stall_ms", "compile_s"} <= want
     if workload.endswith("diloco-fp8"):
         assert line["attempted"] % 8 == 0, "a window of whole rounds"
 
@@ -749,6 +785,69 @@ class Job(plain.Job):
 def run(run):
     return harness.run_one_process(run, Job)
 '''
+
+
+def test_trace_2_measures_as_trace_0_does_and_only_then_starts_a_capture(monkeypatch, capsys):
+    """The measured part of a ``--trace 2`` run makes the same calls in the
+    same order as a ``--trace 0`` run, up to and including the taking of its
+    numbers (``gauge.report()`` last); no capture starts before that. Then one
+    capture is thrown away, and one more wraps a second window."""
+    import jax
+
+    from chipbench import harness
+    from torchft_tpu import tracing
+
+    run_py = spec.load_module(ROOT / "chipbench/run.py")
+
+    calls = []
+
+    def logged(owner, name):
+        inner = getattr(owner, name)
+
+        def wrapper(*args, **kwargs):
+            calls.append(name)
+            return inner(*args, **kwargs)
+
+        monkeypatch.setattr(owner, name, wrapper)
+
+    for owner, name in (
+        (harness, "run_window"), (harness, "counter_sums"), (harness, "host_clocks"),
+        (harness, "window_checks"), (harness, "memory_problems"),
+        (harness.MemoryGauge, "report"), (harness.HostPulse, "__enter__"),
+        (harness.HostPulse, "__exit__"), (jax, "device_get"),
+        (tracing, "start_capture"), (tracing, "stop_capture"),
+    ):
+        logged(owner, name)
+    monkeypatch.setenv("TPUFT_LOG", "warn")
+
+    def run(trace: str):
+        del calls[:]
+        argv = ["--workload", "mistral7b-1chip.plain", "--seed", "11", "--seconds", "0.2",
+                "--trace", trace, "--rehearse", str(HERE / "rehearsal.json")]
+        assert run_py.main(argv) == 0
+        line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+        assert line["correct"] is True
+        return list(calls), line
+
+    plain, line0 = run("0")
+    both, line2 = run("2")
+    assert "start_capture" not in plain and plain[-2:] == ["report", "memory_problems"]
+    assert plain.count("run_window") == 1
+    assert both[: len(plain)] == plain
+    tail = both[len(plain):]
+    assert tail[:2] == ["start_capture", "stop_capture"]  # thrown away
+    again = tail[2:]
+    assert again.count("start_capture") == again.count("stop_capture") == again.count("run_window") == 1
+    assert again.index("start_capture") < again.index("run_window") < again.index("stop_capture")
+    assert again[-2:] == ["report", "memory_problems"]
+    assert set(line0["metrics"]) < set(line2["metrics"])
+
+
+def test_trace_2_on_the_parked_job_is_no_result(tmp_path):
+    root = copy_with_parked_cell(tmp_path / "repo")
+    done = run_cell("mistral7b-2x2.hsdp", "--trace", "2", root=root)
+    assert done.returncode != 0 and "no result: --trace 2" in done.stderr
+    assert not any(l.startswith("{") for l in done.stdout.splitlines())
 
 
 def test_a_step_that_returns_its_state_unchanged_is_not_correct(tmp_path):

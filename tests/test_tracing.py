@@ -523,7 +523,9 @@ def test_phase_feeds_the_sinks_its_table_row_names(name, fake_annotations) -> No
                 spec.histogram, stage=spec.stage, **labels
             )
             assert staged["count"] >= 1
-    assert spec.annotation is not None
+    if spec.annotation is None:  # a journal-only row (heal, ZeRO, ddp's buckets)
+        assert fake_annotations == [] and spec.journal is not None
+        return
     enter, leave = fake_annotations
     assert enter[:2] == ("enter", spec.annotation) and leave[0] == "exit"
     # The ids ride on the annotation; the free-form argument does not.
@@ -709,6 +711,118 @@ def test_xplane_holds_the_anchors_and_bare_phase_names(tmp_path) -> None:
     mapped = begin_ns + (barrier["t_mono"] * 1e9 - begin_stats["mono_ns"])
     assert mapped == pytest.approx(found["tpuft::manager::should_commit"][1], abs=2e6)
     assert found["tpuft::manager::should_commit"][2] >= 2e6
+
+
+def test_two_captures_in_one_process_through_the_harness(tmp_path, monkeypatch) -> None:
+    """The benchmark's ``Tracer`` is a caller of the capture control: a
+    capture thrown away and then one kept, as ``--trace 2`` makes them, each
+    with its own events and growth and nothing of the other's."""
+    from chipbench import harness
+
+    monkeypatch.setattr("tempfile.tempdir", str(tmp_path))
+    journal = tracing.current()
+    labels = {"replica_id": "harness_capture"}
+    with tracing.phase("device_sync", journal, labels, step=1):
+        pass  # before any capture: in neither
+    kept = []
+    for step in (2, 3):
+        with harness.Tracer(True) as tracer:
+            with tracing.phase("device_sync", journal, labels, step=step):
+                pass
+        kept.append(tracer.capture)
+        assert tracer.reduce() is None and not os.path.exists(tracer.dir)
+    for step, capture in zip((2, 3), kept):
+        assert "trace_dir" not in capture and capture["dropped"] == 0
+        assert [(e["name"], e["step"]) for e in capture["events"]] == [("device_sync", step)]
+        (sync,) = [
+            c for c in capture["counters"]["tpuft_device_sync_seconds"] if c["labels"] == labels
+        ]
+        assert sync["count"] == 1
+        json.dumps(capture)
+    assert kept[0]["clock"]["end_mono_ns"] <= kept[1]["clock"]["begin_mono_ns"]
+    with harness.Tracer(False) as off:  # --trace 0: the control is never called
+        pass
+    assert off.capture is None and off.reduce() is None
+
+
+def test_wait_quorum_is_a_journal_event_inside_its_root_on_the_roots_thread() -> None:
+    """Every child of the step's root is in the journal, so the root's self
+    time (its duration less what its children on its thread cover) can be
+    computed from the events alone. Only a wait that waits is a span: the
+    accessors' looks at a quorum that is there record nothing."""
+    journal = tracing.TraceJournal(maxlen=256)
+    with tracing.use_journal(journal):
+        manager, client, _, _ = make_manager(
+            pg=ProcessGroupDummy(), min_replica_size=1, use_async_quorum=True
+        )
+        quorum = make_quorum(
+            quorum_id=4, replica_rank=0, replica_world_size=1, max_rank=0, max_world_size=1
+        )
+
+        def slow_quorum(**_):
+            time.sleep(0.02)
+            return quorum
+
+        client._quorum.side_effect = slow_quorum
+        with tracing.phase("optim_step", journal, step=0):
+            manager.start_quorum()
+            manager.wait_quorum()
+            manager.wait_quorum()  # resolved: no second span
+            assert manager.num_participants() == 1  # an accessor: none either
+    events = {e["name"]: e for e in journal.snapshot() if e["ph"] == "X"}
+    assert [e["name"] for e in journal.snapshot()].count("wait_quorum") == 1
+    root, wait, rpc = events["step"], events["wait_quorum"], events["quorum"]
+    assert wait["thread"] == root["thread"] == threading.current_thread().name
+    assert rpc["thread"] != root["thread"]
+    assert root["t_mono"] <= wait["t_mono"]
+    assert wait["t_mono"] + wait["dur"] <= root["t_mono"] + root["dur"]
+    assert wait["step"] == 0 and wait["dur"] >= 0.01
+    # The root's children on its thread cover all of it but its self time.
+    children = [e for e in (events["start_quorum"], wait) if e["thread"] == root["thread"]]
+    self_time = root["dur"] - sum(e["dur"] for e in children)
+    assert 0 <= self_time < 0.01
+    # What reads the journal by name reads what it read: the wait is in no
+    # bucket of the goodput fold and in no phase of the rollup.
+    from torchft_tpu import goodput
+
+    assert "wait_quorum" not in dict(goodput.SPAN_BUCKETS)
+    assert "wait_quorum" not in journal.phase_rollup()[-1]["phases"]
+    assert "quorum" in journal.phase_rollup()[-1]["phases"]
+
+
+# The rollup's list as it stood beside PHASES until PR 43 folded it in.
+_PHASE_SPANS_BEFORE_THE_FOLD = (
+    "quorum", "pg_configure", "wire_bucket", "device_sync", "update_dispatch",
+    "commit_barrier", "heal_send", "heal_recv", "zero_rebalance", "pipeline_drain",
+)
+
+
+def test_phase_rollup_of_a_recorded_ring_is_what_it_was_before_the_fold() -> None:
+    assert not hasattr(tracing, "PHASE_SPANS")
+    assert tracing.ROLLUP_SPANS == frozenset(_PHASE_SPANS_BEFORE_THE_FOLD)
+    assert tracing.ROLLUP_SPANS == {
+        spec.journal for spec in tracing.PHASES.values() if spec.rollup
+    }
+    journal = tracing.TraceJournal(maxlen=512)
+    names = sorted(
+        {spec.journal for spec in tracing.PHASES.values() if spec.journal}
+        | set(_PHASE_SPANS_BEFORE_THE_FOLD) | {"vote_send", "quorum_ready"}
+    )
+    for step in (3, 4, 5):
+        for n, name in enumerate(names):
+            for repeat in range(2):
+                journal.record(name, ph="X", dur=0.001 * (n + 1), step=step, quorum_id=9)
+        journal.record("commit" if step != 4 else "commit_failed", step=step)
+    ring = journal._copy_ring()
+    want = []
+    for step in (3, 4, 5):
+        phases = {}
+        for event in ring:
+            if event["step"] == step and event["ph"] == "X" and event["name"] in _PHASE_SPANS_BEFORE_THE_FOLD:
+                phases[event["name"]] = round(phases.get(event["name"], 0.0) + event["dur"], 6)
+        want.append({"step": step, "quorum_id": 9, "phases": phases, "committed": step != 4})
+    assert journal.phase_rollup() == want
+    assert set(want[0]["phases"]) == set(_PHASE_SPANS_BEFORE_THE_FOLD)
 
 
 def test_compile_listener_journals_a_compile_at_the_current_step() -> None:

@@ -1350,9 +1350,15 @@ class Manager:
 
     def wait_quorum(self) -> None:
         """Blocks until the quorum completes; the PG is healthy after."""
-        assert self._quorum_future is not None, "must call start_quorum before wait_quorum"
-        with tracing.phase("wait_quorum", step=self._step):
-            self._quorum_future.result()
+        future = self._quorum_future
+        assert future is not None, "must call start_quorum before wait_quorum"
+        if future.done():
+            # An accessor's look at a quorum that is already there (every
+            # ``num_participants()`` comes through here): no wait, so no span.
+            future.result()
+            return
+        with tracing.phase("wait_quorum", self._trace, step=self._step):
+            future.result()
 
     def _async_quorum(
         self, allow_heal: bool, shrink_only: bool, quorum_timeout: float
@@ -1598,8 +1604,9 @@ class Manager:
                         step=serve_step,
                     ), metrics.timer(
                         "tpuft_heal_send_seconds", **self._metric_labels
-                    ), self._trace.span(
+                    ), tracing.phase(
                         "heal_send",
+                        self._trace,
                         step=serve_step,
                         quorum_id=quorum.quorum_id,
                         dst_ranks=str(list(quorum.recover_dst_replica_ranks)),
@@ -1711,8 +1718,9 @@ class Manager:
                 step=quorum.max_step,
             ), metrics.timer(
                 "tpuft_heal_recv_seconds", **self._metric_labels
-            ), self._trace.span(
+            ), tracing.phase(
                 "heal_recv",
+                self._trace,
                 step=quorum.max_step,
                 quorum_id=quorum.quorum_id,
                 donor=src_addr,
@@ -1943,9 +1951,12 @@ class Manager:
         # A second async barrier with the first still unobserved would
         # silently drop the first's tracking (and any stored exception) on
         # overwrite — drain it with the same semantics start_quorum uses.
-        self._drain_pending_commit("should_commit_async")
-        future = _TrackedCommitFuture(self._executor.submit(self.should_commit, timeout))
-        self._pending_commit_future = future
+        # A span of its own: the hand-over to the executor runs on the
+        # caller's thread, under the step's root (0.1 ms a step on the chip).
+        with tracing.phase("commit_submit", self._trace, step=self._step):
+            self._drain_pending_commit("should_commit_async")
+            future = _TrackedCommitFuture(self._executor.submit(self.should_commit, timeout))
+            self._pending_commit_future = future
         return future
 
     def should_commit(self, timeout: Optional[float] = None) -> bool:

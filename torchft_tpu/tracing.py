@@ -111,21 +111,6 @@ ENV_CLOCK = "TPUFT_TRACE_CLOCK"
 CLOCK_REF_KEY = "trace/clockref"
 STORE_PREFIX = "trace"
 
-# Span names the per-step phase rollup aggregates (the STRAGGLER/LAG feed
-# for scripts/fleet_status.py and --explain-step's phase deltas).
-PHASE_SPANS = (
-    "quorum",
-    "pg_configure",
-    "wire_bucket",
-    "device_sync",
-    "update_dispatch",
-    "commit_barrier",
-    "heal_send",
-    "heal_recv",
-    "zero_rebalance",
-    "pipeline_drain",
-)
-
 
 def _enabled_from_env() -> bool:
     return os.environ.get(ENV_TRACE, "1") != "0"
@@ -366,7 +351,7 @@ class TraceJournal:
                 {"step": step, "quorum_id": event.get("quorum_id"),
                  "phases": {}, "committed": None},
             )
-            if event.get("ph") == "X" and name in PHASE_SPANS:
+            if event.get("ph") == "X" and name in ROLLUP_SPANS:
                 slot["phases"][name] = round(
                     slot["phases"].get(name, 0.0) + float(event.get("dur", 0.0)), 6
                 )
@@ -482,13 +467,17 @@ class _PhaseSpec(NamedTuple):
     its histogram (``None``: that sink has no entry for it). ``stage`` is
     the histogram's ``stage`` label where several phases share one
     histogram; ``root`` makes the annotation a ``StepTraceAnnotation``
-    numbered by the ``step`` id, so the profiler's own step view works."""
+    numbered by the ``step`` id, so the profiler's own step view works;
+    ``rollup`` puts the journal event into the per-step phase rollup (the
+    STRAGGLER/LAG feed of scripts/fleet_status.py and ``--explain-step``'s
+    phase deltas)."""
 
     journal: Optional[str]
     annotation: Optional[str]
     histogram: Optional[str] = None
     stage: Optional[str] = None
     root: bool = False
+    rollup: bool = False
 
 
 def _outer(stage: str) -> _PhaseSpec:
@@ -515,22 +504,28 @@ PHASES: Dict[str, _PhaseSpec] = {
     # control plane (manager.py)
     "quorum": _PhaseSpec(
         "quorum", "tpuft::manager::_client::_quorum",
-        histogram="tpuft_quorum_seconds",
+        histogram="tpuft_quorum_seconds", rollup=True,
     ),
     "start_quorum": _PhaseSpec("start_quorum", "tpuft::manager::start_quorum"),
-    "wait_quorum": _PhaseSpec(None, "tpuft::manager::wait_quorum"),
+    # The caller's wait for the quorum thread: with it every child of a
+    # step's root is in the journal, so the root's self time is computable
+    # from the events alone. No reader of ``quorum`` reads this name.
+    "wait_quorum": _PhaseSpec("wait_quorum", "tpuft::manager::wait_quorum"),
     "pg_configure": _PhaseSpec(
         "pg_configure", "tpuft::manager::_pg::configure",
-        histogram="tpuft_pg_configure_seconds",
+        histogram="tpuft_pg_configure_seconds", rollup=True,
     ),
     "should_commit": _PhaseSpec(
         "commit_barrier", "tpuft::manager::should_commit",
-        histogram="tpuft_commit_barrier_seconds",
+        histogram="tpuft_commit_barrier_seconds", rollup=True,
     ),
     "speculative_commit": _PhaseSpec(
         "commit_barrier", "tpuft::manager::speculative_commit",
-        histogram="tpuft_commit_barrier_seconds",
+        histogram="tpuft_commit_barrier_seconds", rollup=True,
     ),
+    # ``should_commit_async``'s hand-over of the vote to the executor, on the
+    # caller's thread (the vote itself is ``should_commit``, on the executor).
+    "commit_submit": _PhaseSpec("commit_submit", "tpuft::manager::commit_submit"),
     "allreduce": _PhaseSpec(None, "tpuft::manager::allreduce"),
     "allreduce_pytree": _PhaseSpec(None, "tpuft::manager::allreduce_pytree"),
     "allreduce_prequantized": _PhaseSpec(
@@ -540,16 +535,16 @@ PHASES: Dict[str, _PhaseSpec] = {
     "optim_step": _PhaseSpec("step", "tpuft::optim::step", root=True),
     "device_sync": _PhaseSpec(
         "device_sync", "tpuft::optim::device_sync",
-        histogram="tpuft_device_sync_seconds",
+        histogram="tpuft_device_sync_seconds", rollup=True,
     ),
     "update_dispatch": _PhaseSpec(
         "update_dispatch", "tpuft::optim::update_dispatch",
-        histogram="tpuft_update_dispatch_seconds",
+        histogram="tpuft_update_dispatch_seconds", rollup=True,
     ),
     "commit_wait": _PhaseSpec("commit_wait", "tpuft::optim::commit_wait"),
     "adopt": _PhaseSpec("adopt", "tpuft::optim::adopt"),
     "pipeline_drain": _PhaseSpec(
-        "pipeline_drain", "tpuft::optim::pipeline_drain"
+        "pipeline_drain", "tpuft::optim::pipeline_drain", rollup=True
     ),
     # streaming DiLoCo / LocalSGD (local_sgd.py)
     "local_sgd_step": _PhaseSpec("step", "tpuft::local_sgd::step", root=True),
@@ -572,7 +567,19 @@ PHASES: Dict[str, _PhaseSpec] = {
     "wire_ring": _wire("ring"),
     "wire_average": _wire("average"),
     "wire_scatter": _wire("scatter"),
+    # Journal-only rows: the heal and ZeRO planes keep their annotation and
+    # histogram on ``utils.profiling.trace_span`` / ``metrics.timer`` beside
+    # the phase, and ddp.py / collectives.py record ``wire_bucket`` after the
+    # fact from their own clock pair around ``work.wait()``. They stand here
+    # so that ONE table names every span the rollup sums.
+    "heal_send": _PhaseSpec("heal_send", None, rollup=True),
+    "heal_recv": _PhaseSpec("heal_recv", None, rollup=True),
+    "zero_rebalance": _PhaseSpec("zero_rebalance", None, rollup=True),
+    "bucket_wire": _PhaseSpec("wire_bucket", None, rollup=True),
 }
+
+# Journal span names the per-step phase rollup sums.
+ROLLUP_SPANS = frozenset(spec.journal for spec in PHASES.values() if spec.rollup)
 
 # Fields of a phase that identify WHICH step, quorum or fragment it belongs
 # to: they go to the journal event and, as keyword arguments, to the
@@ -630,7 +637,9 @@ def _feed(
 class _Span:
     """The with-block behind :func:`phase`: one clock read at entry, one at
     exit, and the exit feeds every sink the spec names. Never swallows the
-    body's exception, and a raising body still closes all three."""
+    body's exception, and a raising body still closes all three. The clock
+    is ``time.monotonic`` (the journal's), which the benchmark's own
+    ``SpanLog.span`` reads too."""
 
     __slots__ = (
         "_spec", "_journal", "_labels", "_ids", "_args", "_start",

@@ -533,16 +533,18 @@ class ZeroOptimizer(Optimizer):
         if pg_world <= 1:
             # Alone on the wire: no exchange partner. Keep fresh held
             # shards, bootstrap the rest from the replicated params.
-            with _trace_of(self.manager).span(
-                "zero_rebalance", owned=len(owned), wire=False
+            with tracing.phase(
+                "zero_rebalance", _trace_of(self.manager),
+                owned=len(owned), wire=False,
             ):
                 self._adopt_rebalanced(
                     state, owned, {}, key, labels, ranks_identical=True
                 )
             return
         try:
-            with _trace_of(self.manager).span(
-                "zero_rebalance", owned=len(owned), wire=True
+            with tracing.phase(
+                "zero_rebalance", _trace_of(self.manager),
+                owned=len(owned), wire=True,
             ):
                 self._rebalance_over_wire(
                     state, owners, owned, pg_rank, key, labels
