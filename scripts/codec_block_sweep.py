@@ -10,7 +10,13 @@ blocks per grid step), as a measurement problem, in both directions
 
 A chip script: it needs a TPU and exits non-zero without one.
 
-Output: one JSON line per (wire, direction, rows_per_tile) on stdout and
+The leaf-layout kernels (a leaf read and written as it lies, its blocks
+straight into the fragment's payload) have two: the tile of a grid step,
+``tile_rows`` x ``chunk_segments`` blocks; they are swept at the benchmark
+cell's leaf shapes, quantize reading two bf16 operands.
+
+Output: one JSON line per (wire, direction, rows_per_tile), and per (leaf
+shape, direction, tile_rows, chunk_segments), on stdout and
 the full table to chiprun_out/CODEC_BLOCK_SWEEP.json, each row carrying
 ``gbps`` (bytes READ+WRITTEN per second — the roofline currency) and
 ``hbm_fraction`` = gbps / the chip's ~819 GB/s HBM. If no tile reaches
@@ -34,6 +40,11 @@ OUT = REPO / "chiprun_out" / "CODEC_BLOCK_SWEEP.json"
 # v5e HBM bandwidth (819 GB/s nominal); the denominator of hbm_fraction.
 HBM_GBPS = 819.0
 TILE_CANDIDATES = (256, 512, 1024, 2048, 4096, 8192)
+# The leaf-layout kernels: the cell's widest leaves, and tiles up to 2M values.
+LEAF_SHAPES = ((2, 4096, 14336), (32768, 4096), (4096, 32768), (2, 32, 128, 4096))
+LEAF_TILE_ROWS = (32, 64, 128, 256, 512, 1024, 2048)
+LEAF_CHUNK_SEGMENTS = (1, 2, 4, 8, 16)
+LEAF_MAX_TILE_VALUES = 2 * 1024 * 1024
 ITERS = 8
 WARMUP = 2
 
@@ -130,6 +141,8 @@ def main() -> None:
                 print(json.dumps(row))
                 if gbps > best["gbps"]:
                     best = row
+    leaf_rows = leaf_sweep(timed)
+    rows += leaf_rows
     artifact = {
         "bench": "codec_block_sweep",
         "total_mb": total_mb,
@@ -139,6 +152,14 @@ def main() -> None:
         "device": str(dev.device_kind),
         "rows": rows,
         "best": best,
+        "leaf_best": {
+            f"{shape} {direction}": max(
+                (r for r in leaf_rows
+                 if r.get("shape") == list(shape) and r.get("direction") == direction),
+                key=lambda r: r["gbps"], default=None,
+            )
+            for shape in LEAF_SHAPES for direction in ("quantize", "dequantize")
+        },
         "target_gbps": 100.0,
         "target_met": best.get("gbps", 0.0) >= 100.0,
         "ts": time.time(),
@@ -153,6 +174,71 @@ def main() -> None:
     OUT.parent.mkdir(exist_ok=True)
     OUT.write_text(json.dumps(artifact, indent=2) + "\n")
     print(json.dumps({"best": best, "target_met": artifact["target_met"]}))
+
+
+def leaf_sweep(timed):
+    """The leaf-layout kernels over their tile, fp8, at LEAF_SHAPES."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from torchft_tpu.ops import quantization as q
+
+    rows = []
+    key = jax.random.PRNGKey(0)
+    for shape in LEAF_SHAPES:
+        a, b = (
+            (jax.random.normal(k, shape, jnp.float32) * 0.02).astype(jnp.bfloat16)
+            for k in jax.random.split(key)
+        )
+        n = int(np.prod(shape))
+        blocks = n // q.BLOCK
+        moved = {
+            "quantize": 2 * a.nbytes + n + 4 * blocks,
+            "dequantize": n + 4 * blocks + 4 * n,
+        }
+        tuned = q.leaf_block_view(shape)
+        for tile_rows in LEAF_TILE_ROWS:
+            for segments in LEAF_CHUNK_SEGMENTS:
+                if (
+                    shape[-2] % tile_rows
+                    or (shape[-1] // q.BLOCK) % segments
+                    or tile_rows * segments * q.BLOCK > LEAF_MAX_TILE_VALUES
+                ):
+                    continue
+                view = q.leaf_block_view(shape, tile_rows, segments)
+                row = {
+                    "kernel": "leaf", "wire": "fp8", "shape": list(shape),
+                    "tile_rows": tile_rows, "chunk_segments": segments,
+                    "tuned": view == tuned,
+                }
+                try:
+                    quantize = jax.jit(
+                        lambda x, y, v=view: q.quantize_leaf_pallas(
+                            x, y, v, v.n_blocks, wire="fp8"
+                        )
+                    )
+                    payload, scales = quantize(a, b)
+                    times = {
+                        "quantize": timed(quantize, a, b),
+                        "dequantize": timed(
+                            jax.jit(lambda p, s, v=view: q.dequantize_leaf_pallas(p, s, v)),
+                            payload, scales,
+                        ),
+                    }
+                except Exception as e:  # noqa: BLE001 — a failing tile is data
+                    rows.append({**row, "error": f"{type(e).__name__}: {e}"[:200]})
+                    print(json.dumps(rows[-1]))
+                    continue
+                for direction, dt in times.items():
+                    gbps = moved[direction] / dt / 1e9
+                    rows.append({
+                        **row, "direction": direction, "ms": round(dt * 1e3, 3),
+                        "gbps": round(gbps, 2),
+                        "hbm_fraction": round(gbps / HBM_GBPS, 4),
+                    })
+                    print(json.dumps(rows[-1]))
+    return rows
 
 
 if __name__ == "__main__":

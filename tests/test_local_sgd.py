@@ -14,6 +14,7 @@ import pytest
 
 from ft_harness import diloco_live_state
 from test_manager import make_manager, make_quorum
+from test_quantization import interpreted_kernels  # noqa: F401  (a fixture)
 
 from torchft_tpu.local_sgd import DiLoCo, LocalSGD
 from torchft_tpu.parallel.process_group import ProcessGroupDummy
@@ -712,3 +713,147 @@ def test_state_capture_survives_the_steps_after_it(quantize) -> None:
     # too; the host pipeline's are numpy copies already.
     per_capture = 1 + (len(algo._fragments) if quantize else 0)
     assert copies() - before[0] == per_capture * len(captures)
+
+
+# -- the quantized sync's two programs against the flat formulation ----------
+
+
+def _flat_sync_programs(leaves, outer_tx, alpha):
+    """The formulation the leaf-layout codec replaced, kept here as the plain
+    reference: the pseudogradient concatenated into one flat float32 array,
+    quantized in blocks, and the decoded flat array sliced back a leaf."""
+    from torchft_tpu.ops import quantization as q
+
+    sizes = [int(np.prod(leaf.shape)) for leaf in leaves]
+    shapes = [tuple(leaf.shape) for leaf in leaves]
+    dtypes = [leaf.dtype for leaf in leaves]
+    offsets = np.cumsum([0] + sizes)
+
+    def quantize_pseudograd(backup_leaves, local_leaves):
+        flat = jnp.concatenate([
+            (b.astype(jnp.float32) - l.astype(jnp.float32)).reshape(-1)
+            for b, l in zip(backup_leaves, local_leaves)
+        ])
+        return q.quantize_blocks_device(flat)
+
+    def apply_outer(payload, scales, backup_leaves, local_leaves, outer_state):
+        flat = q.dequantize_blocks_device(payload, scales)[: sum(sizes)]
+        avg_pg = [
+            flat[offsets[i] : offsets[i + 1]].reshape(shapes[i]).astype(dtypes[i])
+            for i in range(len(sizes))
+        ]
+        updates, new_state = outer_tx.update(avg_pg, outer_state, backup_leaves)
+        new_backup = optax.apply_updates(backup_leaves, updates)
+        merged = [
+            (g.astype(jnp.float32) * (1.0 - alpha)
+             + l.astype(jnp.float32) * alpha).astype(g.dtype)
+            for g, l in zip(new_backup, local_leaves)
+        ]
+        return new_backup, merged, new_state
+
+    return jax.jit(quantize_pseudograd), jax.jit(apply_outer)
+
+
+def _bits(tree):
+    return [np.asarray(x).tobytes() for x in jax.tree_util.tree_leaves(tree)]
+
+
+@pytest.mark.parametrize("impl", ["jnp", "pallas-interpret"])
+@pytest.mark.parametrize("alpha", [0.0, 0.5])
+@pytest.mark.parametrize("ragged", [False, True], ids=["whole-blocks", "flat-tail"])
+def test_quantized_sync_is_bit_for_bit_the_flat_formulation(
+    ragged, alpha, impl, request
+) -> None:
+    """Two rounds of a quantized fragment sync (the second with momentum in
+    the outer state): the wire's payload and scales, the new backup, the
+    merged leaves and the outer state are bit for bit what the flat
+    formulation's two programs give on the same inputs (the wire carries
+    the same blocks), and the counter grows by the static element counts of
+    the two paths."""
+    from torchft_tpu import metrics
+
+    if impl == "pallas-interpret":
+        request.getfixturevalue("interpreted_kernels")
+    rng = np.random.default_rng(7)
+    # Tree order is the keys': the leaves of no 32-row tile come last, as the
+    # codec's flat tail does, so both formulations cut the same blocks.
+    params = {
+        "a_embed": jnp.asarray(rng.normal(0, 1, (64, 512)), jnp.bfloat16),
+        "b_stack": jnp.asarray(rng.normal(0, 1, (2, 32, 256)), jnp.bfloat16),
+        "c_heads": jnp.asarray(rng.normal(0, 1, (2, 32, 2, 128)), jnp.bfloat16),
+        "x_norm": jnp.ones((2, 256), jnp.float32),
+    }
+    if ragged:
+        params["y_bias"] = jnp.asarray(rng.normal(0, 1, (7, 100)), jnp.bfloat16)
+        params["z_scale"] = jnp.asarray(rng.normal(0, 1, (5,)), jnp.float32)
+    outer_tx = optax.sgd(0.7, momentum=0.9, nesterov=True)
+    manager = scripted_manager(use_async_quorum=False)
+    algo = DiLoCo(
+        manager, optax.sgd(0.1), outer_tx, params, sync_every=2,
+        should_quantize=True, fragment_update_alpha=alpha,
+    )
+    (fragment,) = algo._fragments
+    calls = []
+    apply_outer = fragment._jit_apply_outer
+
+    def spy(*args):
+        host = jax.tree_util.tree_map(np.asarray, args)  # before the donation
+        out = apply_outer(*args)
+        calls.append((host, jax.tree_util.tree_map(np.asarray, out)))
+        return out
+
+    fragment._jit_apply_outer = spy
+    counted = lambda path: metrics.counter_total(  # noqa: E731
+        "tpuft_codec_elements_total", path=path
+    )
+    before = counted("leaf"), counted("flat")
+    for step in range(4):
+        grads = jax.tree_util.tree_map(
+            lambda p: jnp.asarray(rng.normal(0, 0.5, p.shape), p.dtype), params
+        )
+        committed = algo.step(grads)
+        assert committed == (step % 2 == 1)
+    assert len(calls) == 2
+
+    flat_quantize, flat_apply = _flat_sync_programs(
+        jax.tree_util.tree_leaves(params), outer_tx, alpha
+    )
+    for (payload, scales, backup, local, state), out in calls:
+        # The wire holds the flat formulation's blocks, each with its scale,
+        # in another order (tests/test_quantization.py pins which).
+        want_payload, want_scales = flat_quantize(backup, local)
+        blocks = lambda p, s: sorted(  # noqa: E731
+            row.tobytes() + scale.tobytes() for row, scale in zip(np.asarray(p), np.asarray(s))
+        )
+        assert blocks(payload, scales) == blocks(want_payload, want_scales)
+        want = flat_apply(want_payload, want_scales, backup, local, state)
+        assert _bits(out) == _bits(want)
+    # perform_sync left the programs' outputs in place.
+    new_backup, merged, new_state = calls[-1][1]
+    assert _bits(fragment.backup) == _bits(new_backup)
+    assert _bits(algo.params) == _bits(merged)
+    assert _bits(fragment.outer_opt_state) == _bits(new_state)
+    leaf = sum(p.size for k, p in params.items() if k < "x")
+    flat = sum(p.size for k, p in params.items() if k >= "x")
+    assert (counted("leaf") - before[0], counted("flat") - before[1]) == (
+        2 * leaf, 2 * flat
+    )
+
+
+def test_a_ragged_fragment_counts_all_flat() -> None:
+    """No leaf of ``make_params`` holds a whole block: the codec takes the
+    flat path for all of it, and the counter says so."""
+    from torchft_tpu import metrics
+
+    counted = lambda path: metrics.counter_total(  # noqa: E731
+        "tpuft_codec_elements_total", path=path
+    )
+    before = counted("leaf"), counted("flat")
+    manager = scripted_manager(use_async_quorum=False)
+    algo = DiLoCo(
+        manager, optax.sgd(0.1), optax.sgd(0.7), make_params(), sync_every=1,
+        should_quantize=True,
+    )
+    assert algo.step(fixed_grads(0))
+    assert counted("leaf") == before[0]
+    assert counted("flat") - before[1] == 5

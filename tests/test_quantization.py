@@ -389,3 +389,255 @@ def test_device_codec_int4_through_wire_allreduce(store_server) -> None:
     finally:
         for pg in pgs:
             pg.shutdown()
+
+
+# -- the leaf-layout codec: a leaf is quantized in the layout it has ----------
+
+LEAF_KERNELS = (
+    "quantize_leaf_pallas", "dequantize_leaf_pallas",
+    "quantize_blocks_pallas", "dequantize_blocks_pallas",
+)
+
+
+@pytest.fixture
+def interpreted_kernels(monkeypatch):
+    """The TPU branches of the device codec on the CPU: ``on_tpu()`` answers
+    yes and every Pallas kernel runs interpreted."""
+    import functools
+
+    monkeypatch.setattr(q, "on_tpu", lambda: True)
+    for name in LEAF_KERNELS:
+        monkeypatch.setattr(
+            q, name, functools.partial(getattr(q, name), interpret=True)
+        )
+
+
+def _leaf_case(name: str):
+    """(backup, local) leaves of one case; values of mixed magnitude."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(11)
+
+    def pair(shape, dtype, scale=1.0):
+        backup = jnp.asarray(rng.normal(0, scale, shape), dtype)
+        local = jnp.asarray(rng.normal(0, scale, shape), dtype)
+        return backup, local
+
+    if name == "2d":
+        pairs = [pair((64, 512), jnp.bfloat16)]
+    elif name == "stacked-3d":  # three row tiles of 32, three chunks of one block
+        pairs = [pair((2, 96, 768), jnp.bfloat16), pair((3, 32, 256), jnp.float32, 1e-3)]
+    elif name == "merged-heads":  # 256-blocks span two (heads, 128) rows
+        pairs = [pair((2, 64, 4, 128), jnp.bfloat16)]
+    elif name == "one-row":  # no 32 rows: the flat path, whole blocks or not
+        pairs = [pair((1024,), jnp.float32), pair((1, 256), jnp.bfloat16)]
+    elif name == "zero-block":
+        backup, local = pair((32, 768), jnp.float32)
+        local = local.at[3, 256:512].set(backup[3, 256:512])  # difference 0
+        pairs = [(backup, local)]
+    elif name == "larger-tiles-first":  # the second leaf's run leads the wire
+        pairs = [pair((32, 256), jnp.bfloat16), pair((128, 2048), jnp.bfloat16)]
+    elif name == "flat-tail":
+        pairs = [
+            pair((7, 100), jnp.bfloat16),
+            pair((32, 512), jnp.bfloat16),
+            pair((2, 32, 256), jnp.float32, 30.0),
+            pair((5,), jnp.float32, 1e-2),
+        ]
+    else:
+        raise KeyError(name)
+    return [b for b, _ in pairs], [l for _, l in pairs]
+
+
+LEAF_CASES = [
+    "2d", "stacked-3d", "merged-heads", "one-row", "zero-block",
+    "larger-tiles-first", "flat-tail",
+]
+
+
+def _wire_index(view):
+    """Where block (l, row, segment) of a leaf sits in its run of the wire,
+    written out by hand: tiles row-major over (leading, row tile, chunk),
+    and inside a tile all rows of its first segment, then the next."""
+    *lead, rows, cols = view.shape
+    segments = cols // q.BLOCK
+    tiles, chunks = rows // view.tile_rows, segments // view.chunk_segments
+    index = np.empty((int(np.prod(lead, dtype=int)), rows, segments), np.int64)
+    for l in range(index.shape[0]):
+        for r in range(rows):
+            for seg in range(segments):
+                tile = (l * tiles + r // view.tile_rows) * chunks + seg // view.chunk_segments
+                index[l, r, seg] = (
+                    (tile * view.chunk_segments + seg % view.chunk_segments)
+                    * view.tile_rows + r % view.tile_rows
+                )
+    return index.reshape(-1)
+
+
+@pytest.mark.parametrize("impl", ["jnp", "pallas-interpret"])
+@pytest.mark.parametrize("wire", ["fp8", "int8"])
+@pytest.mark.parametrize("case", LEAF_CASES)
+def test_leaf_layout_codec_matches_flat_blocks(case, wire, impl, request) -> None:
+    """The tree codec reading the leaves as they lie gives, block for block
+    and bit for bit, the scales, payload and decoded leaves of the flat
+    device codec on the flattened float32 pseudogradient (the formulation it
+    replaces), which are the host codec's ``quantize_blocks`` to a rounding
+    of the scale (XLA divides by the format's maximum through a reciprocal).
+    Only where a block sits on the wire differs: a leaf of 32-row tiles of
+    whole blocks has a run of its own, larger tiles first, the rest follow
+    flat."""
+    import jax
+    import jax.numpy as jnp
+
+    if impl == "pallas-interpret":
+        request.getfixturevalue("interpreted_kernels")
+    backup, local = _leaf_case(case)
+    quantize, dequantize = q.make_tree_fp8_codec(backup, wire=wire)
+    payload, scales = quantize(backup, local)
+
+    # The flat formulation, jitted as local_sgd.py's program was, a leaf.
+    def flat_codec(b, l):
+        flat = (b.astype(jnp.float32) - l.astype(jnp.float32)).reshape(-1)
+        blocks, block_scales = q.quantize_blocks_device(flat, wire=wire)
+        return flat, blocks, block_scales, q.dequantize_blocks_device(blocks, block_scales)
+
+    # Larger tiles first, written out here as the codec's rule is.
+    views = q.tree_codec_views([b.shape for b in backup], wire)
+    wire_order = sorted(
+        (i for i, v in enumerate(views) if v is not None),
+        key=lambda i: -views[i].tile_blocks,
+    )
+    assert [(i, at) for i, _, at in q.tree_codec_runs([b.shape for b in backup], wire)] == [
+        (i, sum(views[k].n_blocks for k in wire_order[:n])) for n, i in enumerate(wire_order)
+    ]
+    at = 0
+    for i in wire_order:
+        flat, blocks, block_scales, values = jax.jit(flat_codec)(backup[i], local[i])
+        index = at + _wire_index(views[i])
+        np.testing.assert_array_equal(np.asarray(scales)[index], np.asarray(block_scales))
+        np.testing.assert_array_equal(
+            np.asarray(payload).astype(np.float32)[index],
+            np.asarray(blocks).astype(np.float32),
+        )
+        host_scales = q.quantize_blocks(np.asarray(flat), wire=wire)[1]
+        np.testing.assert_allclose(np.asarray(block_scales), host_scales, rtol=2e-7)
+        at += views[i].n_blocks
+    tail = [i for i, v in enumerate(views) if v is None]
+    assert (case in ("one-row", "flat-tail")) == bool(tail)
+    if tail:
+        flat, blocks, block_scales, tail_values = jax.jit(flat_codec)(
+            jnp.concatenate([backup[i].astype(jnp.float32).reshape(-1) for i in tail]),
+            jnp.concatenate([local[i].astype(jnp.float32).reshape(-1) for i in tail]),
+        )
+        np.testing.assert_array_equal(np.asarray(scales)[at:], np.asarray(block_scales))
+        np.testing.assert_array_equal(
+            np.asarray(payload).astype(np.float32)[at:],
+            np.asarray(blocks).astype(np.float32),
+        )
+        at += blocks.shape[0]
+    assert payload.shape == (at, q.BLOCK) and scales.shape == (at,)
+    if case == "zero-block":
+        zero = _wire_index(views[0])[3 * 3 + 1]  # row 3, second block of three
+        assert np.asarray(scales)[zero] == 1.0
+        assert not np.asarray(payload).astype(np.float32)[zero].any()
+
+    restored = dequantize(payload, scales)
+    for i, (leaf, got) in enumerate(zip(backup, restored)):
+        assert got.shape == leaf.shape and got.dtype == leaf.dtype
+        if i in tail:
+            continue
+        values = jax.jit(flat_codec)(backup[i], local[i])[3]
+        want = values[: leaf.size].reshape(leaf.shape).astype(leaf.dtype)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    offset = 0
+    for i in tail:
+        leaf = backup[i]
+        want = tail_values[offset : offset + leaf.size].reshape(leaf.shape)
+        np.testing.assert_array_equal(
+            np.asarray(restored[i]), np.asarray(want.astype(leaf.dtype))
+        )
+        offset += leaf.size
+
+
+@pytest.mark.parametrize("tile", [(32, 1), (32, 4), (96, 2)], ids=str)
+@pytest.mark.parametrize("with_minus", [True, False])
+def test_leaf_kernels_fill_their_run_of_the_payload(tile, with_minus) -> None:
+    """The kernels at tiles other than the tuned one (the sweep's
+    parameters), with leading dimensions on the grid and a run that starts
+    past another leaf's: the blocks land in wire order from ``base_block``
+    on, every other row of the payload is left as it was, and the pair
+    round-trips bit for bit as the flat kernels do."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(5)
+    shape = (3, 96, 1024)
+    view = q.leaf_block_view(shape, *tile)
+    assert (view.tile_rows, view.chunk_segments) == tile and view.shape == shape
+    x = jnp.asarray(rng.normal(0, 4, shape), jnp.bfloat16)
+    y = jnp.asarray(rng.normal(0, 4, shape), jnp.bfloat16) if with_minus else None
+    base = 2 * view.tile_blocks
+    total = base + view.n_blocks + 64
+    before = jnp.full((total, q.BLOCK), 0.5, jnp.float8_e4m3fn)
+    payload, scales = q.quantize_leaf_pallas(
+        x, y, view, total, base, before, interpret=True, wire="fp8"
+    )
+    assert scales.shape == (view.n_blocks // view.tile_blocks, *tile)
+    got = np.asarray(payload).astype(np.float32)
+    assert (got[:base] == 0.5).all() and (got[base + view.n_blocks :] == 0.5).all()
+
+    data = x.astype(jnp.float32) - (y.astype(jnp.float32) if with_minus else 0)
+    flat_payload, flat_scales = q.quantize_blocks_pallas(
+        data.reshape(-1, q.BLOCK), interpret=True, wire="fp8"
+    )
+    index = _wire_index(view)
+    np.testing.assert_array_equal(
+        np.asarray(scales).transpose(0, 2, 1).reshape(-1)[index], np.asarray(flat_scales)
+    )
+    np.testing.assert_array_equal(
+        got[base + index], np.asarray(flat_payload).astype(np.float32)
+    )
+    values = q.dequantize_leaf_pallas(payload, scales, view, base, interpret=True)
+    np.testing.assert_array_equal(
+        np.asarray(values).reshape(-1, q.BLOCK),
+        np.asarray(q.dequantize_blocks_pallas(flat_payload, flat_scales, interpret=True)),
+    )
+    np.testing.assert_allclose(np.asarray(values), np.asarray(data), rtol=0.07, atol=0.1)
+    # The jnp order (the codec off the TPU) is the kernels' order.
+    np.testing.assert_array_equal(
+        np.asarray(q._wire_order(data, view)), np.asarray(data).reshape(-1, q.BLOCK)[np.argsort(index)]
+    )
+    np.testing.assert_array_equal(
+        np.asarray(q._leaf_order(q._wire_order(data, view), view)), np.asarray(data)
+    )
+
+
+@pytest.mark.parametrize(
+    "shape, view",
+    [
+        ((2, 4096, 14336), ((2, 4096, 14336), 256, 8)),
+        ((2, 4096, 8, 128), ((2, 4096, 1024), 512, 4)),
+        ((2, 32, 128, 4096), ((2, 32, 128, 4096), 128, 8)),
+        ((32768, 4096), ((32768, 4096), 256, 8)),
+        ((96, 768), ((96, 768), 32, 1)),
+        ((2, 4096, 100), None),  # a row of 409,600 is 1600 blocks, but 2 rows are no tile
+        ((64, 4, 64), ((64, 256), 64, 1)),
+        ((4096,), None),
+        ((3, 256), None),
+        ((7, 100), None),
+        ((0, 256), None),
+        ((), None),
+    ],
+)
+def test_leaf_block_view(shape, view) -> None:
+    got = q.leaf_block_view(shape)
+    assert got == (view and q.LeafView(*view))
+    if got:
+        assert got.n_blocks * q.BLOCK == int(np.prod(shape))
+        assert got.n_blocks == int(np.prod(got.grid)) * got.tile_blocks
+    elements = q.tree_codec_elements([np.zeros(shape, np.float32)], wire="fp8")
+    size = int(np.prod(shape))
+    assert elements == ({"leaf": size, "flat": 0} if view else {"leaf": 0, "flat": size})
+    # Packed int4 has no leaf-layout kernel: everything rides the flat path.
+    assert q.tree_codec_elements([np.zeros(shape, np.float32)], wire="int4") == {
+        "leaf": 0, "flat": size
+    }

@@ -254,6 +254,61 @@ def test_codec_pair_compiles_at_fragment_size(chip, wire) -> None:
     )
 
 
+# The benchmark cell's layer stack as models/llama.py shapes it (two scanned
+# layers of chipbench/configs/mistral-7b-v0.3-1chip.json): 310.4M values, of
+# which the two DenseGeneral kernels that end in (heads, 128) are merged in
+# bf16 and the float32 norm scale rides the flat tail.
+CELL_FRAGMENT = (
+    ((2, 4096, 14336), jnp.bfloat16),
+    ((2, 14336, 4096), jnp.bfloat16),
+    ((2, 4096, 32, 128), jnp.bfloat16),
+    ((2, 4096, 8, 128), jnp.bfloat16),
+    ((2, 32, 128, 4096), jnp.bfloat16),
+    ((2, 4096), jnp.float32),
+)
+
+
+def test_fragment_sync_programs_compile_at_the_cells_geometry(chip, monkeypatch) -> None:
+    """DiLoCo's two sync programs for the cell's leaves: each holds its
+    Mosaic calls (one a leaf that goes leaf-wise, one for the flat tail),
+    and neither copies the fragment through flat float32 arrays. The flat
+    formulation read 34.6 + 44.4 bytes accessed a value by the compiler's
+    count here, with 8.3 and 7.4 bytes a value of temporaries (two flat
+    float32 copies of the fragment); a later edit that brings those copies
+    back fails this test."""
+    import optax
+
+    from torchft_tpu import local_sgd
+    from torchft_tpu.ops import quantization as q
+
+    monkeypatch.setattr(q, "on_tpu", lambda: True)
+    leaves = [_sds(shape, dtype, chip) for shape, dtype in CELL_FRAGMENT]
+    values = sum(leaf.size for leaf in leaves)
+    assert q.tree_codec_elements(leaves) == {"leaf": values - 2 * 4096, "flat": 2 * 4096}
+    outer_tx = optax.sgd(0.7, momentum=0.9, nesterov=True)
+    quantize_pseudograd, apply_outer = local_sgd._device_sync_programs(
+        leaves, outer_tx, 0.0
+    )
+    payload, scales = jax.eval_shape(quantize_pseudograd, leaves, leaves)
+    assert payload.shape == (-(-values // q.BLOCK), q.BLOCK)
+    programs = {
+        "quantize_pseudograd": quantize_pseudograd.lower(leaves, leaves).compile(),
+        "apply_outer": apply_outer.lower(
+            _sds(payload.shape, payload.dtype, chip),
+            _sds(scales.shape, scales.dtype, chip),
+            leaves, leaves, _sds_tree(jax.eval_shape(outer_tx.init, leaves), chip),
+        ).compile(),
+    }
+    accessed = 0.0
+    for name, compiled in programs.items():
+        text = compiled.as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == len(leaves), name
+        temporaries = compiled.memory_analysis().temp_size_in_bytes / values
+        assert temporaries <= 4.5, f"{name}: {temporaries:.2f} bytes a value of temporaries"
+        accessed += compiled.cost_analysis()["bytes accessed"] / values
+    assert accessed <= 34, f"{accessed:.1f} bytes accessed a value by the two programs"
+
+
 @pytest.mark.slow  # 10-15 s: the tier-1 gate (-m 'not slow') is near its limit
 def test_plain_step_compiles_at_smoke_config(chip, monkeypatch) -> None:
     """The whole plain SGD-momentum step chip_smoke.py runs, with its state

@@ -266,6 +266,47 @@ class LocalSGD:
             return False
 
 
+def _device_sync_programs(leaves: Sequence[Any], outer_tx: Any, alpha: float):
+    """The two jitted programs of a quantized fragment sync, for leaves of
+    these shapes and dtypes: ``quantize_pseudograd(backup, local)`` gives the
+    wire's (payload, scales) of ``backup - local``, and ``apply_outer(payload,
+    scales, backup, local, outer_state)`` the new backup, the merged leaves
+    and the new outer state. The codec reads and writes each leaf in the
+    layout it has wherever its shape gives whole blocks
+    (``make_tree_fp8_codec``), so neither program copies the fragment
+    through a flat float32 array."""
+    import jax.numpy as jnp
+
+    from torchft_tpu.ops.quantization import make_tree_fp8_codec
+
+    quantize, dequantize = make_tree_fp8_codec(leaves)
+
+    # The programs' names are read from outside (the benchmark finds
+    # jit_quantize_pseudograd and jit_apply_outer in the device trace).
+    def quantize_pseudograd(backup_leaves, local_leaves):
+        return quantize(backup_leaves, local_leaves)
+
+    def apply_outer(payload, scales, backup_leaves, local_leaves, outer_state):
+        import optax
+
+        avg_pg = dequantize(payload, scales)
+        updates, new_state = outer_tx.update(avg_pg, outer_state, backup_leaves)
+        new_backup = optax.apply_updates(backup_leaves, updates)
+        merged = [
+            (g.astype(jnp.float32) * (1.0 - alpha)
+             + l.astype(jnp.float32) * alpha).astype(g.dtype)
+            for g, l in zip(new_backup, local_leaves)
+        ]
+        return new_backup, merged, new_state
+
+    # The fragment's local leaves (dead after the merge) and the outer
+    # state are updated in place: their buffers become the merged
+    # leaves, the new backup's and the new outer state's. The old
+    # backup is not given away: until a fragment's first sync it is
+    # the caller's array.
+    return jax.jit(quantize_pseudograd), jax.jit(apply_outer, donate_argnums=(3, 4))
+
+
 class _Fragment:
     """One model fragment's DiLoCo state: the backup of the last-synced
     global parameters, the outer optimizer state, and the in-flight
@@ -337,45 +378,14 @@ class _Fragment:
 
     def _build_device_pipeline(self) -> None:
         """Jitted device kernels for the quantized path (shared fp8 codec)."""
-        import jax.numpy as jnp
+        from torchft_tpu.ops.quantization import tree_codec_elements
 
-        from torchft_tpu.ops.quantization import make_tree_fp8_codec
-
-        _, dequantize = make_tree_fp8_codec(self.backup)
-        outer_tx = self._outer_tx
-        alpha = self._alpha
-
-        def quantize_pseudograd(backup_leaves, local_leaves):
-            from torchft_tpu.ops.quantization import quantize_blocks_device
-
-            flat = jnp.concatenate(
-                [
-                    (b.astype(jnp.float32) - l.astype(jnp.float32)).reshape(-1)
-                    for b, l in zip(backup_leaves, local_leaves)
-                ]
-            )
-            return quantize_blocks_device(flat)
-
-        def apply_outer(payload, scales, backup_leaves, local_leaves, outer_state):
-            import optax
-
-            avg_pg = dequantize(payload, scales)
-            updates, new_state = outer_tx.update(avg_pg, outer_state, backup_leaves)
-            new_backup = optax.apply_updates(backup_leaves, updates)
-            merged = [
-                (g.astype(jnp.float32) * (1.0 - alpha)
-                 + l.astype(jnp.float32) * alpha).astype(g.dtype)
-                for g, l in zip(new_backup, local_leaves)
-            ]
-            return new_backup, merged, new_state
-
-        self._jit_quantize_pg = jax.jit(quantize_pseudograd)
-        # The fragment's local leaves (dead after the merge) and the outer
-        # state are updated in place: their buffers become the merged
-        # leaves, the new backup's and the new outer state's. The old
-        # backup is not given away: until a fragment's first sync it is
-        # the caller's array.
-        self._jit_apply_outer = jax.jit(apply_outer, donate_argnums=(3, 4))
+        self._jit_quantize_pg, self._jit_apply_outer = _device_sync_programs(
+            self.backup, self._outer_tx, self._alpha
+        )
+        # How many of the fragment's elements the codec takes in the leaves'
+        # own layout and how many flat: static, and counted at every sync.
+        self._codec_elements = tree_codec_elements(self.backup)
 
     def _save_state(self) -> Dict[str, Any]:
         # Device state is handed over as a device copy, not as references:
@@ -440,6 +450,8 @@ class _Fragment:
                     payload, scales = self._jit_quantize_pg(
                         self.backup, [local_leaves[i] for i in self.leaf_indices]
                     )
+                for path, elements in self._codec_elements.items():
+                    metrics.inc("tpuft_codec_elements_total", elements, path=path)
                 # Device arrays pass through: the d2h fetch happens on the
                 # pipeline thread, overlapping the delay window's inner steps.
                 # Participation zeroing + error funnel live in the manager.
