@@ -1,0 +1,38 @@
+"""expert_time_pct: device seconds in the grouped expert product's kernels
+over the window's busy device seconds. The kernels are the Pallas calls of the
+step programs that the cell's architecture file names (``EXPERT_KERNEL``, a
+pattern over their names in the device trace: the scope the program traces them
+under), forward and both gradients; the routing around them (the sort of the
+rows by expert, the gather and the weighted sum back) is XLA's and not in it.
+The layer is dropless, so these seconds follow the rows that ARRIVED, which the
+routing decides and no reader sees: there is no share of the peak beside this
+one (PERF.md section 7, PR 46).
+
+Whom it is for: a cell whose architecture file has ``EXPERT_KERNEL``; where the
+program has no such call (a parent without the kernels) nothing is read."""
+
+from pathlib import Path
+
+from chipbench.spec import load_module
+
+
+def architecture_of(obs):
+    here = Path(__file__).resolve().parents[1]
+    return load_module(here / "architectures" / (obs["config"]["model_type"] + ".py"))
+
+
+def expert_seconds(trace, architecture) -> float:
+    named = getattr(architecture, "EXPERT_KERNEL", None)
+    if named is None:
+        return 0.0
+    return sum(
+        s for rows in trace["kernels"].values() for name, s in rows if named.search(name)
+    )
+
+
+def read(obs):
+    trace = obs.get("trace")
+    if not trace or not trace.get("busy_s"):
+        return None
+    seconds = expert_seconds(trace, architecture_of(obs))
+    return 100.0 * seconds / trace["busy_s"] if seconds else None

@@ -1,0 +1,138 @@
+#!/usr/bin/env python
+"""Beside the benchmark's comparison, for the cell
+``keye-vl2-30b-a3b-1chip.ftddp-seq8k``, at the cell's own size and on the chip:
+
+- how far the PROGRAM's key selection (bf16 hidden states, float32 indexer) is
+  from the float32 reference's, layer by layer: the share of a layer's
+  selected (query, key) pairs that the other side did not select. The two
+  differ because the hidden states differ by bf16 rounding and a key near a
+  query's threshold then falls on the other side of it;
+- the rows each held expert receives (``models.keye.router_load``), so that the
+  cell's ``why`` can say how near uniform the routing of seeded weights is;
+- the control of ``reference_tolerance``: the float32 reference with every
+  weight rounded to fp8 (e4m3, one scale a tensor: the precision below bf16),
+  its two losses put through ``harness.reference_check`` as a run's are. It
+  has to come out NOT correct; the script exits 1 where it does not.
+
+    python scripts/keye_selection_check.py SEED [SEED ...]        (needs a TPU)
+    JAX_PLATFORMS=cpu python scripts/keye_selection_check.py --rehearse 7
+
+One JSON line a seed on stdout; PERF.md section 6 (PR 46) has the readings.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parent.parent
+sys.path.insert(0, str(ROOT))
+
+CELL = "keye-vl2-30b-a3b-1chip.ftddp-seq8k"
+
+
+def fp8(tree):
+    import jax
+    import jax.numpy as jnp
+
+    def leaf(a):
+        scale = jnp.maximum(jnp.max(jnp.abs(a.astype(jnp.float32))), 1e-30) / 448.0
+        low = (a.astype(jnp.float32) / scale).astype(jnp.float8_e4m3fn).astype(jnp.float32)
+        return (low * scale).astype(a.dtype)
+
+    return jax.tree_util.tree_map(leaf, tree)
+
+
+def check(bench, config, traffic, seed: int) -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from chipbench.model import System
+    from torchft_tpu.models.keye import router_load
+
+    architecture = bench.architecture(config["model_type"])
+    system = System(config, architecture, traffic, seed)
+    params, tokens = system.init_params(), system.tokens(0)
+    out = {"seed": seed, "device": jax.devices()[0].device_kind}
+
+    @jax.jit
+    def program(params, tokens):
+        _, seen = system.model.apply(params, tokens[:, :-1], mutable=["intermediates"])
+        return seen["intermediates"]["layers"]["block"]["attn"]["selection"][0][:, 0]
+
+    @jax.jit
+    def plain(params, tokens):
+        with jax.default_matmul_precision("highest"):
+            return architecture.selections(params, tokens[0], config)
+
+    @jax.jit
+    def differing(mine, theirs):
+        only = jnp.sum(mine & ~theirs, axis=(1, 2))
+        return only, jnp.sum(theirs, axis=(1, 2))
+
+    only, selected = differing(program(params, tokens), plain(params, tokens))
+    out["pairs_selected_by_layer"] = np.asarray(selected).tolist()
+    out["share_of_pairs_that_differ_by_layer"] = (np.asarray(only) / np.asarray(selected)).tolist()
+
+    rows = np.asarray(router_load(system.model, params, tokens[:, :-1]))
+    expected = system.tokens_per_step * config["num_experts_per_tok"] / config["num_experts"]
+    out["rows_by_held_expert"] = {
+        "expected": expected, "min": int(rows.min()), "max": int(rows.max()),
+        "mean": float(rows.mean()), "by_layer": rows.tolist(),
+    }
+
+    out["fp8_control"] = control(system, params)
+    return out
+
+
+def control(system, params) -> dict:
+    """The float32 reference on fp8 weights, held to the cell's limits by the
+    harness's own comparison: ``problems`` names each loss that is not within
+    its limit, and there has to be one."""
+    from chipbench import harness, reference
+
+    system.reference = harness.reference_losses(system, params)
+    low = fp8(params)
+    first = float(reference.make_loss(system.architecture, system.config)(low, system.tokens(0)))
+    second = float(reference.make_loss_after_first_update(system.architecture, system.config)(
+        low, system.tokens(0), system.tokens(1)))
+    problems = harness.reference_check(system, [first, second])
+    return {
+        "first": first, "second": second,
+        "problems": [p for p in problems if "loss differs" in p],
+    }
+
+
+def main() -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("seeds", type=int, nargs="+")
+    parser.add_argument("--rehearse", action="store_true", help="toy size, any platform")
+    args = parser.parse_args()
+
+    from chipbench import harness, reference, spec
+
+    bench = spec.Benchmark(ROOT)
+    cell = bench.cell(CELL)
+    config, traffic = bench.config(cell["config"]), bench.traffic(cell["traffic"])
+    if args.rehearse:
+        overlay = json.loads((ROOT / "chipbench/fixtures/rehearsal-keye.json").read_text())
+        config = {**config, **overlay["config"]}
+        config["run"] = {**config["run"], **overlay["run"]}
+        traffic = {**traffic, **overlay["traffic"][cell["traffic"]]}
+        for constant, value in overlay["reference"].items():
+            setattr(reference, constant, value)
+    harness.require_devices(1, args.rehearse)
+    harness.enable_compile_cache()
+    passed = 0
+    for seed in args.seeds:
+        out = check(bench, config, traffic, seed)
+        print(json.dumps(out), flush=True)
+        passed += not out["fp8_control"]["problems"]
+    return 1 if passed else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

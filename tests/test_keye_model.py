@@ -1,0 +1,360 @@
+"""models/keye.py and its two ops against plain float32 mathematics, at a small
+size on seeded weights (CPU): the model against the benchmark's float32
+reference (chipbench/architectures/KeyeVL2.py, written from the equations),
+loss and every leaf's gradient; the expert layer's shares against the uncut
+layer; the selection against a sorted top-k; the grouped product against a loop
+over experts; a sliced vocabulary through the fused loss; and that a lower
+precision in the indexer or the experts is not within the small-size tolerance.
+
+    JAX_PLATFORMS=cpu python -m pytest tests/test_keye_model.py -q
+"""
+
+from __future__ import annotations
+
+import json
+import sys
+from dataclasses import replace
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT))
+
+from chipbench import reference, spec  # noqa: E402
+from torchft_tpu.models.keye import ExpertLayer, Keye, KeyeConfig, router_load  # noqa: E402
+from torchft_tpu.ops.cross_entropy import chunked_cross_entropy  # noqa: E402
+from torchft_tpu.ops.grouped_matmul import grouped_matmul  # noqa: E402
+from torchft_tpu.ops import sparse_attention as tiled  # noqa: E402
+from torchft_tpu.ops.sparse_attention import select_topk, sparse_attention  # noqa: E402
+
+ARCHITECTURE = spec.load_module(ROOT / "chipbench/architectures/KeyeVL2.py")
+SEQ, BATCH = 64, 2
+# Float32 on both sides: they differ in the order of their sums.
+TOLERANCE = 1e-5
+
+
+def toy_config() -> dict:
+    """The cell's configuration file under its rehearsal overlay: every key the
+    architecture file reads, at a toy size."""
+    config = json.loads((ROOT / "chipbench/configs/keye-vl2-30b-a3b-ep8-1chip.json").read_text())
+    overlay = json.loads((ROOT / "chipbench/fixtures/rehearsal-keye.json").read_text())
+    config = {**config, **overlay["config"]}
+    config["run"] = {**config["run"], **overlay["run"]}
+    return config
+
+
+@pytest.fixture(scope="module")
+def toy():
+    config = toy_config()
+    model = ARCHITECTURE.build(config, SEQ)
+    tokens = jax.random.randint(jax.random.PRNGKey(7), (BATCH, SEQ + 1), 0, config["vocab_size"])
+    params = model.init(jax.random.PRNGKey(3), tokens[:, :-1])
+    return config, model, params, tokens
+
+
+@pytest.fixture(autouse=True)
+def small_reference_blocks(monkeypatch):
+    """A toy sequence is still two blocks of the reference's attention and head."""
+    monkeypatch.setattr(reference, "QUERY_BLOCK", 32)
+    monkeypatch.setattr(reference, "HEAD_BLOCK", 32)
+
+
+def program_loss(model, params, tokens):
+    return model.apply(params, tokens[:, :-1], targets=tokens[:, 1:])
+
+
+def relative(a, b) -> float:
+    return float(jnp.linalg.norm(a - b) / (jnp.linalg.norm(b) + 1e-30))
+
+
+def test_the_loss_agrees_with_the_float32_reference(toy):
+    config, model, params, tokens = toy
+    want = reference.make_loss(ARCHITECTURE, config)(params, tokens)
+    got = program_loss(model, params, tokens)
+    assert abs(float(got) - float(want)) / float(want) < TOLERANCE
+
+
+def by_path(tree) -> dict:
+    return {
+        "/".join(str(k.key) for k in path): leaf
+        for path, leaf in jax.tree_util.tree_leaves_with_path(tree)
+    }
+
+
+@pytest.fixture(scope="module")
+def both_gradients(toy):
+    config, model, params, tokens = toy
+    got = jax.grad(lambda p: program_loss(model, p, tokens))(params)
+    with jax.default_matmul_precision("highest"):
+        total = reference.grad_sum(ARCHITECTURE, params, tokens, config)
+    want = jax.tree_util.tree_map(lambda g: g / (BATCH * SEQ), total)
+    return by_path(got), by_path(want)
+
+
+LEAVES = [
+    "final_norm/scale", "lm_head/kernel", "tok_embed/embedding",
+    "layers/block/attn_norm/scale", "layers/block/mlp_norm/scale",
+    "layers/block/attn/wq/kernel", "layers/block/attn/wk/kernel", "layers/block/attn/wv/kernel",
+    "layers/block/attn/wo/kernel", "layers/block/attn/q_norm/scale", "layers/block/attn/k_norm/scale",
+    "layers/block/moe/router/kernel", "layers/block/moe/w_gate", "layers/block/moe/w_up",
+    "layers/block/moe/w_down",
+]
+INDEXER_LEAVES = [
+    "layers/block/attn/indexer/wq/kernel", "layers/block/attn/indexer/wk/kernel",
+    "layers/block/attn/indexer/weights/kernel", "layers/block/attn/indexer/k_norm/scale",
+    "layers/block/attn/indexer/k_norm/bias",
+]
+
+
+def test_the_leaves_tested_are_all_the_leaves(toy):
+    assert sorted("params/" + name for name in LEAVES + INDEXER_LEAVES) == sorted(by_path(toy[2]))
+
+
+@pytest.mark.parametrize("leaf", LEAVES)
+def test_a_leafs_gradient_agrees_with_the_float32_reference(leaf, both_gradients):
+    got, want = both_gradients
+    assert float(jnp.linalg.norm(want["params/" + leaf])) > 0
+    assert relative(got["params/" + leaf], want["params/" + leaf]) < 1e-4
+
+
+@pytest.mark.parametrize("leaf", INDEXER_LEAVES)
+def test_the_indexers_leaves_get_gradient_exactly_zero(leaf, both_gradients):
+    """The selection carries no gradient: ``stop_gradient`` in the program, and
+    ``jax.grad`` of the reference, which has none, gives the same zero."""
+    got, want = both_gradients
+    assert not np.any(np.asarray(got["params/" + leaf]))
+    assert not np.any(np.asarray(want["params/" + leaf]))
+
+
+def test_the_eight_shares_add_up_to_the_uncut_expert_layer():
+    """The parts of the result that the eight shares give add up to the uncut
+    layer's (the residual is outside the layer, so it is counted once), and the
+    uncut layer is the reference's sum over all experts."""
+    whole = KeyeConfig(
+        dim=32, moe_hidden=24, num_experts=16, experts_per_token=4, num_local_experts=16,
+        dtype=jnp.float32, n_heads=2, n_kv_heads=1, head_dim=16,
+    )
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, 32))
+    params = ExpertLayer(whole).init(jax.random.PRNGKey(1), x)
+    uncut = ExpertLayer(whole).apply(params, x)
+    parts = []
+    for share in range(8):
+        held = slice(2 * share, 2 * share + 2)
+        mine = {"params": {**params["params"], **{
+            name: params["params"][name][held] for name in ("w_gate", "w_up", "w_down")
+        }}}
+        cut = replace(whole, num_local_experts=2, expert_share=share)
+        parts.append(ExpertLayer(cut).apply(mine, x))
+    assert relative(sum(parts), uncut) < 1e-6
+    assert all(float(jnp.linalg.norm(p)) > 0 for p in parts)
+    config = {"num_local_experts": 16, "expert_share": 0, "num_experts_per_tok": 4}
+    weights = {"router": params["params"]["router"]["kernel"], **{
+        name: params["params"][name] for name in ("w_gate", "w_up", "w_down")
+    }}
+    want = jnp.stack([ARCHITECTURE._experts(row, weights, config) for row in x])
+    assert relative(uncut, want) < 1e-5
+
+
+def sorted_topk(scores: np.ndarray, allowed: np.ndarray, topk: int) -> np.ndarray:
+    """The definition, row by row: allowed keys by falling score, earlier key
+    first among equals, the first ``topk`` of them."""
+    out = np.zeros(scores.shape, bool)
+    for row in range(scores.shape[0]):
+        keys = [s for s in range(scores.shape[1]) if allowed[row, s]]
+        keys.sort(key=lambda s: (-scores[row, s], s))
+        out[row, keys[:topk]] = True
+    return out
+
+
+@pytest.mark.parametrize("case", ["random", "ties", "all-equal", "zeros-of-both-signs"])
+def test_the_selection_is_the_topk_with_ties_to_the_earlier_key(case):
+    rows, keys, topk = 48, 48, 16
+    scores = np.array(jax.random.normal(jax.random.PRNGKey(5), (rows, keys)), np.float32)
+    if case == "ties":
+        scores = np.round(scores * 2) / 2  # a handful of distinct values
+    elif case == "all-equal":
+        scores[:] = 0.25
+    elif case == "zeros-of-both-signs":
+        scores = np.where(scores > 0.3, scores, np.where(scores > 0, 0.0, -0.0)).astype(np.float32)
+    causal = np.tril(np.ones((rows, keys), bool))
+    got = np.asarray(select_topk(jnp.asarray(scores), jnp.asarray(causal), topk))
+    assert np.array_equal(got, sorted_topk(scores, causal, topk))
+    assert np.array_equal(got[:topk], causal[:topk])  # rows under topk select all
+    assert (got.sum(axis=1) == np.minimum(np.arange(rows) + 1, topk)).all()
+
+
+def test_the_programs_selection_is_the_references(toy):
+    """Layer by layer on the same weights and tokens: the program's selected
+    set (its tiled radix select) is the reference's (``lax.top_k``)."""
+    config, model, params, tokens = toy
+    _, seen = model.apply(params, tokens[:1, :-1], mutable=["intermediates"])
+    got = seen["intermediates"]["layers"]["block"]["attn"]["selection"][0][:, 0]
+    with jax.default_matmul_precision("highest"):
+        want = ARCHITECTURE.selections(params, tokens[0], config)
+    assert got.shape == want.shape == (config["num_hidden_layers"], SEQ, SEQ)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    topk = config["sa_config"]["topk"]
+    assert (np.asarray(got).sum(axis=-1) == np.minimum(np.arange(SEQ) + 1, topk)).all()
+    assert topk < SEQ  # some queries do select
+
+
+@pytest.mark.parametrize("tiles", [1, 3, 4, 7, 16, 17])
+def test_the_tiles_fall_into_runs_that_share_a_key_length(tiles):
+    """At most ``KEY_GROUPS`` runs, in order, every tile in exactly one."""
+    runs = tiled._tile_groups(tiles)
+    assert 1 <= len(runs) <= min(tiled.KEY_GROUPS, tiles)
+    assert [t for lo, hi in runs for t in range(lo, hi)] == list(range(tiles))
+    lengths = [hi - lo for lo, hi in runs]
+    assert len(set(lengths[:-1])) <= 1 and lengths[-1] <= lengths[0]
+
+
+@pytest.mark.parametrize("key_groups", [1, 3, 8])
+def test_selected_attention_is_the_same_however_the_tiles_share_their_keys(key_groups, monkeypatch):
+    b, s, h, kv, d, j, e = 2, 64, 4, 2, 16, 2, 8
+    keys = jax.random.split(jax.random.PRNGKey(11), 6)
+    q, k, v = (jax.random.normal(key, (b, s, n, d)) for key, n in zip(keys, (h, kv, kv)))
+    qi = jax.random.normal(keys[3], (b, s, j, e))
+    ki = jax.random.normal(keys[4], (b, s, e))
+    w = jax.random.normal(keys[5], (b, s, j))
+
+    def run(groups):
+        monkeypatch.setattr(tiled, "KEY_GROUPS", groups)
+        assert len(tiled._tile_groups(s // 8)) == groups
+        return sparse_attention(q, k, v, qi, ki, w, topk=12, scale=d**-0.5, block=8, return_selection=True)
+
+    (got, again), (want, chosen) = run(key_groups), run(tiled.KEY_GROUPS)
+    assert np.array_equal(np.asarray(chosen), np.asarray(again))
+    assert relative(got, want) < 1e-6
+
+
+@pytest.mark.parametrize("use_pallas", [False, True], ids=["ragged_dot", "megablox-interpreted"])
+def test_the_grouped_product_is_a_loop_over_experts(use_pallas):
+    """With an expert that receives no row and one that receives all the rest,
+    rows that belong elsewhere, and both gradients."""
+    m, k, n = 256, 64, 128
+    lhs = jax.random.normal(jax.random.PRNGKey(0), (m, k))
+    rhs = jax.random.normal(jax.random.PRNGKey(1), (4, k, n))
+    for sizes in ([40, 0, 100, 20, 96], [0, 0, 256, 0, 0], [0, 0, 0, 0, 256]):
+        group_sizes = jnp.asarray(sizes, jnp.int32)
+
+        def product(lhs, rhs):
+            return grouped_matmul(lhs, rhs, group_sizes, use_pallas=use_pallas, interpret=True)
+
+        def loop(lhs, rhs):
+            out, start = jnp.zeros((m, n)), 0
+            for expert, size in enumerate(sizes[:4]):
+                out = out.at[start:start + size].set(lhs[start:start + size] @ rhs[expert])
+                start += size
+            return out
+
+        assert relative(product(lhs, rhs), loop(lhs, rhs)) < 1e-5 or not any(sizes[:4])
+        assert not np.any(np.asarray(product(lhs, rhs))[sum(sizes[:4]):])
+        if any(sizes[:4]):
+            scalar = lambda f: lambda lhs, rhs: jnp.sum(jnp.sin(f(lhs, rhs)))
+            got = jax.grad(scalar(product), argnums=(0, 1))(lhs, rhs)
+            want = jax.grad(scalar(loop), argnums=(0, 1))(lhs, rhs)
+            assert relative(got[0], want[0]) < 1e-5 and relative(got[1], want[1]) < 1e-5
+
+
+def test_router_load_counts_the_rows_of_each_held_expert(toy):
+    config, model, params, tokens = toy
+    rows = np.asarray(router_load(model, params, tokens[:, :-1]))
+    assert rows.shape == (config["num_hidden_layers"], config["num_local_experts"])
+    expected = BATCH * SEQ * config["num_experts_per_tok"] / config["num_experts"]
+    assert rows.sum() > 0 and abs(rows.mean() - expected) < expected  # near uniform, not equal
+    assert rows.max() <= BATCH * SEQ  # a token chooses an expert once
+
+
+def test_a_sliced_vocabulary_of_18992_goes_through_the_fused_loss():
+    """18,992 rows are a multiple neither of the chunk (4,096) nor of 128: the
+    tail slab is padded and masked, value and both gradients as the dense loss."""
+    vocab, d, n = 18992, 16, 24
+    x = jax.random.normal(jax.random.PRNGKey(0), (n, d))
+    w = jax.random.normal(jax.random.PRNGKey(1), (d, vocab)) * 0.1
+    targets = jax.random.randint(jax.random.PRNGKey(2), (n,), 0, vocab).at[0].set(vocab - 1)
+
+    def dense(x, w):
+        logp = jax.nn.log_softmax(x @ w, axis=-1)
+        return -jnp.mean(jnp.take_along_axis(logp, targets[:, None], axis=-1))
+
+    fused = lambda x, w: chunked_cross_entropy(x, w, targets, 4096)
+    assert abs(float(fused(x, w)) - float(dense(x, w))) < 1e-5
+    got, want = jax.grad(fused, argnums=(0, 1))(x, w), jax.grad(dense, argnums=(0, 1))(x, w)
+    assert got[1].shape == (d, vocab)
+    assert relative(got[0], want[0]) < 1e-5 and relative(got[1], want[1]) < 1e-5
+
+
+def rounded(tree, names, dtype):
+    def leaf(path, a):
+        name = "/".join(str(k.key) for k in path)
+        return a.astype(dtype).astype(a.dtype) if any(n in name for n in names) else a
+    return jax.tree_util.tree_map_with_path(leaf, tree)
+
+
+@pytest.mark.parametrize("what", ["indexer", "experts"])
+def test_a_lower_precision_is_not_within_the_small_size_tolerance(what, toy):
+    """The reference on the stored weights against the program with the
+    indexer's, or the expert layer's (router and experts), arithmetic in
+    bfloat16: outside the tolerance the float32 program is held to, so the
+    tolerance would catch it. On weights whose branches weigh as much as the
+    stream they write into (initialised for the toy's own depth, embeddings of
+    norm one): with the cell's initialisation two toy layers move the loss too
+    little for any precision to show."""
+    config, model, _, tokens = toy
+    model = Keye(replace(model.config, init_depth=None))
+    params = model.init(jax.random.PRNGKey(3), tokens[:, :-1])
+    params["params"]["tok_embed"]["embedding"] /= config["hidden_size"] ** 0.5
+    want = float(reference.make_loss(ARCHITECTURE, config)(params, tokens))
+    assert abs(float(program_loss(model, params, tokens)) - want) / want < TOLERANCE
+    if what == "indexer":
+        low = Keye(replace(model.config, indexer_dtype=jnp.bfloat16))
+        got = float(program_loss(low, rounded(params, ["indexer"], jnp.bfloat16), tokens))
+    else:
+        got = float(program_loss(model, rounded(params, ["moe/"], jnp.bfloat16), tokens))
+    assert abs(got - want) / want > 3 * TOLERANCE
+
+
+
+NORMS = [
+    "final_norm/scale", "layers/block/attn_norm/scale", "layers/block/mlp_norm/scale",
+    "layers/block/attn/q_norm/scale", "layers/block/attn/k_norm/scale",
+    "layers/block/attn/indexer/k_norm/scale", "layers/block/attn/indexer/k_norm/bias",
+]
+
+
+@pytest.mark.parametrize("norm_dtype", [None, jnp.bfloat16], ids=["default", "bfloat16"])
+def test_the_norms_are_stored_in_norm_dtype_and_every_other_leaf_in_dtype(norm_dtype, toy):
+    """float32 unless a configuration says otherwise, as models/llama.py keeps
+    its scales; the cell's file says bfloat16 and lists it as a departure."""
+    _, model, _, tokens = toy
+    cfg = replace(model.config, dtype=jnp.bfloat16)
+    if norm_dtype is not None:
+        cfg = replace(cfg, norm_dtype=norm_dtype)
+    assert KeyeConfig().norm_dtype == jnp.float32
+    shapes = jax.eval_shape(Keye(cfg).init, jax.random.PRNGKey(0), tokens[:, :-1])
+    for name, leaf in by_path(shapes).items():
+        norm = name.removeprefix("params/") in NORMS
+        assert leaf.dtype == ((norm_dtype or jnp.float32) if norm else jnp.bfloat16), name
+
+
+@pytest.mark.parametrize("norm_dtype,moves", [(jnp.float32, True), (jnp.bfloat16, False)], ids=["float32", "bfloat16"])
+def test_an_adamw_step_of_3e_4_moves_a_scale_only_where_it_is_stored_in_float32(norm_dtype, moves, toy):
+    """What the cell's third departure says: in bfloat16 the norms stay where
+    they started, in float32 (the model's default) they train."""
+    import optax
+
+    _, model, _, tokens = toy
+    model = Keye(replace(model.config, norm_dtype=norm_dtype))
+    params = model.init(jax.random.PRNGKey(3), tokens[:, :-1])
+    grads = jax.grad(lambda p: program_loss(model, p, tokens))(params)
+    tx = optax.adamw(3e-4, weight_decay=0.1)
+    updates, _ = tx.update(grads, tx.init(params), params)
+    after = by_path(optax.apply_updates(params, updates))
+    before = by_path(params)
+    for name in ("final_norm/scale", "layers/block/attn_norm/scale", "layers/block/attn/q_norm/scale"):
+        changed = bool(np.any(np.asarray(after["params/" + name] != before["params/" + name])))
+        assert changed is moves, name

@@ -481,3 +481,53 @@ def test_sharded_step_with_size_one_mesh_axis_compiles(v5e, monkeypatch) -> None
     with jax.set_mesh(mesh):
         compiled = jax.jit(jax.value_and_grad(loss_fn)).lower(params, tokens).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_grouped_expert_product_compiles_at_the_keye_cells_geometry(chip) -> None:
+    """The expert layer's three grouped products and their gradients at the
+    cell ``keye-vl2-30b-a3b-1chip.ftddp-seq8k``'s shapes: the dropless row
+    buffer of 8192 tokens x 8 choices, 16 held experts of 2048 x 768, a 17th
+    group of rows that belong elsewhere: megablox's ``gmm`` and ``tgmm``."""
+    from torchft_tpu.ops.grouped_matmul import grouped_matmul
+
+    rows, d, f, held = 8192 * 8, 2048, 768, 16
+
+    def loss(x, w_gate, w_up, w_down, sizes):
+        product = lambda a, w: grouped_matmul(a, w, sizes, use_pallas=True)
+        hidden = jax.nn.silu(product(x, w_gate)) * product(x, w_up)
+        return jnp.sum(product(hidden, w_down).astype(jnp.float32))
+
+    text = _compile(
+        jax.grad(loss, argnums=(0, 1, 2, 3)),
+        _sds((rows, d), jnp.bfloat16, chip), _sds((held, d, f), jnp.bfloat16, chip),
+        _sds((held, d, f), jnp.bfloat16, chip), _sds((held, f, d), jnp.bfloat16, chip),
+        _sds((held + 1,), jnp.int32, chip),
+    ).as_text()
+    # Three forward, up to three for the rows' gradient, three (tgmm) for the
+    # weights'; by whatever name, each holds "gmm" (what the benchmark's
+    # ``expert_time_pct`` finds them by).
+    calls = re.findall(r"%(\S+) = \S+ custom-call\([^\n]*tpu_custom_call", text)
+    assert len(calls) >= 8 and all("gmm" in name for name in calls), calls
+    assert sum("tgmm" in name for name in calls) == 3
+
+
+def test_selected_attention_fits_the_chip_at_the_keye_cells_geometry(chip) -> None:
+    """One layer's attention under the selection, forward and backward, at
+    1 x 8192 with 32 / 4 heads of 128, 16 indexer heads of 64 and top-2048:
+    plain XLA (no Mosaic call), and its temporaries are a tile's, not the
+    (heads, s, s) scores: under 4 GiB where those would be 8."""
+    from torchft_tpu.ops.sparse_attention import sparse_attention
+
+    s, h, kv, d, j, e = 8192, 32, 4, 128, 16, 64
+
+    def loss(q, k, v, qi, ki, w):
+        out, _ = sparse_attention(q, k, v, qi, ki, w, topk=2048, scale=d**-0.5)
+        return jnp.sum(out.astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        _sds((1, s, h, d), jnp.bfloat16, chip), _sds((1, s, kv, d), jnp.bfloat16, chip),
+        _sds((1, s, kv, d), jnp.bfloat16, chip), _sds((1, s, j, e), jnp.float32, chip),
+        _sds((1, s, e), jnp.float32, chip), _sds((1, s, j), jnp.float32, chip),
+    ).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30

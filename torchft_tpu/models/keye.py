@@ -1,0 +1,328 @@
+"""Sparse-expert decoder with a learned key selection (the language model of
+the ``KeyeVL2`` family), as ONE CHIP'S SHARE of an expert-parallel layer.
+
+Per layer, pre-norm:
+
+- attention: grouped-query heads of a ``head_dim`` that is its own number (not
+  ``dim / n_heads``), an RMSNorm over each head of q and k, rotary (rotate-half;
+  text-only positions, so the family's three rotary sections see one position
+  and the embedding is the plain one). Each query attends to the ``topk``
+  earlier keys an indexer scores highest (ops/sparse_attention.py): the
+  indexer has ``indexer_heads`` query heads and one key head of
+  ``indexer_head_dim``, a LayerNorm on its key, rotary on both, a learned
+  weight a head, and runs in ``indexer_dtype`` (float32) whatever ``dtype`` is.
+  The selection carries no gradient, so the language-model loss gives the
+  indexer's leaves gradient zero; the term that trains an indexer in the
+  published mechanism is not built.
+- experts: a softmax router over ALL ``num_experts``, the ``experts_per_token``
+  largest renormalised to one. The layer HOLDS ``num_local_experts`` of them,
+  experts ``expert_share * num_local_experts ..``, and computes their part of
+  the result: the rows routed here, sorted by expert, through one grouped
+  product a matrix (ops/grouped_matmul.py). Dropless: the row buffer is the
+  worst case, every token's every choice. What the absent experts would add
+  is left out and nothing stands in for their chips: on one chip the layer
+  runs without its exchange. ``num_local_experts == num_experts`` is the whole
+  layer.
+
+Rotary and the output head with its fused loss are models/llama.py's, the
+norm is its arithmetic with the scale stored in ``norm_dtype`` (float32, as
+there, unless a configuration says otherwise); the layer stack is scanned and rematerialised the same way (the ``dots``
+policy keeps the projections' products, not a tile's scores). ``router_load``
+counts the rows each held expert receives.
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+from functools import partial
+from typing import Any, Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from torchft_tpu.models.llama import _LMHead, apply_rope
+from torchft_tpu.ops.flash_attention import FLASH_OUT
+from torchft_tpu.ops.grouped_matmul import grouped_matmul
+from torchft_tpu.ops.sparse_attention import sparse_attention
+
+__all__ = ["KeyeConfig", "Keye", "router_load", "route"]
+
+
+@dataclass(frozen=True)
+class KeyeConfig:
+    vocab_size: int = 151936
+    dim: int = 2048
+    n_layers: int = 48
+    n_heads: int = 32
+    n_kv_heads: int = 4
+    head_dim: int = 128
+    moe_hidden: int = 768
+    num_experts: int = 128  # the router's width
+    experts_per_token: int = 8
+    num_local_experts: int = 128  # held here
+    expert_share: int = 0  # which share: experts share * local .. + local - 1
+    indexer_heads: int = 16
+    indexer_head_dim: int = 64
+    indexer_dtype: Any = jnp.float32
+    topk: int = 2048
+    # Tile of queries the index scores and the attention are computed in
+    # (ops/sparse_attention.py).
+    select_block: int = 512
+    rope_theta: float = 1e7
+    norm_eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+    # What the norms' scales (and the indexer's LayerNorm) are STORED in. In
+    # bfloat16 an optimizer step under half a unit of the stored value is lost:
+    # 3e-4 never moves a scale of 1.0, and the norms stay where they started.
+    norm_dtype: Any = jnp.float32
+    remat: str = "none"  # "none" | "full" | "dots", as models/llama.py
+    loss_vocab_chunk: Optional[int] = None
+    scan_layers: bool = False
+    # The depth the projections INTO the residual stream (``wo``, ``w_down``)
+    # are initialised for: lecun-normal over sqrt(2 x depth), the scaled
+    # initialisation of deep pre-norm stacks. None = ``n_layers``. A model cut
+    # in depth names the depth it was cut from. Without the scaling a stack at
+    # initialisation loses rank within two layers (attention's average over
+    # thousands of keys passes what the tokens share and averages away what
+    # tells them apart): near-tied index scores, one token to the router, and
+    # a first optimizer step whose effect on the loss has either sign (PERF.md
+    # section 6, PR 46). It says nothing of a stack once it trains.
+    init_depth: Optional[int] = None
+
+    def __post_init__(self) -> None:
+        if self.remat not in ("none", "full", "dots"):
+            raise ValueError(f"remat={self.remat!r} is not one of ('none', 'full', 'dots')")
+        if self.n_heads % self.n_kv_heads:
+            raise ValueError(f"{self.n_heads} query heads over {self.n_kv_heads} key heads")
+        held = (self.expert_share + 1) * self.num_local_experts
+        if self.expert_share < 0 or held > self.num_experts:
+            raise ValueError(
+                f"share {self.expert_share} of {self.num_local_experts} experts "
+                f"is not inside {self.num_experts}"
+            )
+
+
+class RMSNorm(nn.Module):
+    """models/llama.py's RMSNorm (float32 accumulation) with the dtype its
+    scale is stored in as a field (``KeyeConfig.norm_dtype``)."""
+
+    eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), self.param_dtype)
+        x32 = x.astype(jnp.float32)
+        normed = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
+        return (normed * scale.astype(jnp.float32)).astype(self.dtype)
+
+
+def _dense(cfg: KeyeConfig, accumulate_as: Any = None):
+    """A bias-free projection stored and multiplied in ``dtype``; with
+    ``accumulate_as`` the products come out in that (wider) dtype unrounded."""
+    into = {}
+    if accumulate_as is not None:
+        into["dot_general"] = partial(jax.lax.dot_general, preferred_element_type=accumulate_as)
+    return partial(nn.DenseGeneral, use_bias=False, dtype=cfg.dtype, param_dtype=cfg.dtype, **into)
+
+
+def _into_residual(cfg: KeyeConfig, **axes):
+    """The initialiser of a projection into the residual stream (see
+    ``KeyeConfig.init_depth``)."""
+    depth = cfg.init_depth or cfg.n_layers
+    return nn.initializers.variance_scaling(1.0 / (2 * depth), "fan_in", "truncated_normal", **axes)
+
+
+class Indexer(nn.Module):
+    """(qI, kI, w) in ``indexer_dtype`` from the layer's normed input: products
+    of the stored weights accumulated in that dtype, never rounded to
+    ``dtype`` on the way to the scores."""
+
+    config: KeyeConfig
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray, positions: jnp.ndarray):
+        cfg = self.config
+        wide = cfg.indexer_dtype
+        heads, width = cfg.indexer_heads, cfg.indexer_head_dim
+        dense = _dense(cfg, wide)
+        x = jax.lax.stop_gradient(x)
+        qi = dense(features=(heads, width), name="wq")(x).astype(wide)
+        ki = dense(features=width, name="wk")(x).astype(wide)
+        w = dense(features=heads, name="weights")(x).astype(wide)
+        ki = nn.LayerNorm(epsilon=cfg.norm_eps, dtype=wide, param_dtype=cfg.norm_dtype, name="k_norm")(ki)
+        qi = apply_rope(qi, positions, cfg.rope_theta)
+        ki = apply_rope(ki[:, :, None, :], positions, cfg.rope_theta)[:, :, 0, :]
+        return qi, ki, w * (heads**-0.5 * width**-0.5)
+
+
+class SparseAttention(nn.Module):
+    config: KeyeConfig
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray, positions: jnp.ndarray) -> jnp.ndarray:
+        cfg = self.config
+        dense = _dense(cfg)
+        head_norm = partial(RMSNorm, cfg.norm_eps, cfg.dtype, cfg.norm_dtype)
+        q = dense(features=(cfg.n_heads, cfg.head_dim), name="wq")(x)
+        k = dense(features=(cfg.n_kv_heads, cfg.head_dim), name="wk")(x)
+        v = dense(features=(cfg.n_kv_heads, cfg.head_dim), name="wv")(x)
+        q = apply_rope(head_norm(name="q_norm")(q), positions, cfg.rope_theta)
+        k = apply_rope(head_norm(name="k_norm")(k), positions, cfg.rope_theta)
+        qi, ki, w = Indexer(cfg, name="indexer")(x, positions)
+        watched = self.is_mutable_collection("intermediates")
+        out, chosen = sparse_attention(
+            q, k, v, qi, ki, w, topk=cfg.topk, scale=cfg.head_dim**-0.5,
+            block=cfg.select_block, return_selection=watched,
+        )
+        if watched:
+            self.sow("intermediates", "selection", chosen)
+        # Kept under remat="dots" by the name the flash kernel's output has:
+        # the layer's backward then recomputes a tile once, not twice.
+        out = checkpoint_name(out, FLASH_OUT)
+        return dense(features=cfg.dim, axis=(-2, -1), kernel_init=_into_residual(cfg), name="wo")(out)
+
+
+def route(probs: jnp.ndarray, cfg: KeyeConfig):
+    """probs (n, num_experts) -> for each of the n x experts_per_token choices,
+    in the order the grouped product wants them: ``order`` (which choice sits
+    in each row: choices sorted by held expert, those for experts held
+    elsewhere last), ``gates`` (n, k) renormalised over the k chosen, and
+    ``group_sizes`` (num_local_experts + 1,), rows by held expert and, last,
+    the rows that belong elsewhere."""
+    local = cfg.num_local_experts
+    top, experts = jax.lax.top_k(probs, cfg.experts_per_token)
+    gates = top / jnp.sum(top, axis=-1, keepdims=True)
+    mine = experts - cfg.expert_share * local
+    group = jnp.where((mine >= 0) & (mine < local), mine, local).reshape(-1)
+    order = jnp.argsort(group, stable=True)
+    group_sizes = jnp.bincount(group, length=local + 1).astype(jnp.int32)
+    return order, gates, group_sizes
+
+
+class ExpertLayer(nn.Module):
+    config: KeyeConfig
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+        cfg = self.config
+        b, s, d = x.shape
+        n, k, local, f = b * s, cfg.experts_per_token, cfg.num_local_experts, cfg.moe_hidden
+        axes = dict(in_axis=-2, out_axis=-1, batch_axis=0)
+        init = nn.initializers.lecun_normal(**axes)
+        w_gate = self.param("w_gate", init, (local, d, f), cfg.dtype)
+        w_up = self.param("w_up", init, (local, d, f), cfg.dtype)
+        w_down = self.param("w_down", _into_residual(cfg, **axes), (local, f, d), cfg.dtype)
+        with jax.named_scope("tpuft::expert_layer"):
+            flat = x.reshape(n, d)
+            logits = _dense(cfg, jnp.float32)(
+                features=cfg.num_experts, name="router"
+            )(flat)
+            probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+            order, gates, group_sizes = route(probs, cfg)
+            self.sow("intermediates", "rows_by_expert", group_sizes[:local])
+            rows = flat[order // k]  # (n * k, d), sorted by held expert
+            product = partial(grouped_matmul, group_sizes=group_sizes)
+            hidden = nn.silu(product(rows, w_gate)) * product(rows, w_up)
+            out = product(hidden, w_down)
+            # Back to (token, choice) order; the rows of experts held elsewhere
+            # came out zero, so each token sums its held experts' parts.
+            back = jnp.zeros((n * k,), jnp.int32).at[order].set(jnp.arange(n * k, dtype=jnp.int32))
+            out = out[back].reshape(n, k, d).astype(jnp.float32) * gates[..., None]
+            return jnp.sum(out, axis=1).astype(cfg.dtype).reshape(b, s, d)
+
+
+class Block(nn.Module):
+    config: KeyeConfig
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray, positions: jnp.ndarray) -> jnp.ndarray:
+        cfg = self.config
+        norm = partial(RMSNorm, cfg.norm_eps, cfg.dtype, cfg.norm_dtype)
+        x = x + SparseAttention(cfg, name="attn")(norm(name="attn_norm")(x), positions)
+        return x + ExpertLayer(cfg, name="moe")(norm(name="mlp_norm")(x))
+
+
+def _remat_policy(remat: str):
+    """``dots`` keeps what the projections' matmuls produced and the attention
+    output (by the name the flash kernel's has), as models/llama.py's policy
+    does, but only the dots WITHOUT batch dimensions: a tile's index scores
+    and attention scores are batched dots, and kept they would be the whole
+    (heads, s, s) arrays the tiles exist to avoid (a policy reaches through
+    the tiles' own ``jax.checkpoint``: 36 GB at 6 layers x 8192)."""
+    if remat != "dots":
+        return None
+    policies = jax.checkpoint_policies
+    return policies.save_from_both_policies(
+        policies.checkpoint_dots_with_no_batch_dims,
+        policies.save_only_these_names(FLASH_OUT),
+    )
+
+
+class _ScanCell(nn.Module):
+    """One Block in ``(carry, broadcast) -> (carry, out)`` shape for
+    ``nn.scan``, as models/llama.py's: parameters under ``layers/block`` with
+    a leading layer axis."""
+
+    config: KeyeConfig
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray, positions: jnp.ndarray):
+        return Block(self.config, name="block")(x, positions), None
+
+
+class Keye(nn.Module):
+    """``apply(params, tokens)`` returns logits over the held vocabulary;
+    ``apply(params, tokens, targets=targets)`` the mean token cross-entropy,
+    through the fused head where ``loss_vocab_chunk`` is set."""
+
+    config: KeyeConfig
+
+    @nn.compact
+    def __call__(
+        self, tokens: jnp.ndarray, positions: Optional[jnp.ndarray] = None,
+        targets: Optional[jnp.ndarray] = None,
+    ) -> jnp.ndarray:
+        cfg = self.config
+        if positions is None:
+            positions = jnp.broadcast_to(jnp.arange(tokens.shape[1]), tokens.shape)
+        # Unit-variance embeddings: the residual stream starts at the scale the
+        # normed branches write at (see ``KeyeConfig.init_depth``).
+        x = nn.Embed(
+            cfg.vocab_size, cfg.dim, dtype=cfg.dtype, param_dtype=cfg.dtype,
+            embedding_init=nn.initializers.normal(1.0), name="tok_embed",
+        )(tokens)
+        if cfg.scan_layers:
+            cell = _ScanCell
+            if cfg.remat != "none":
+                cell = nn.remat(cell, policy=_remat_policy(cfg.remat), prevent_cse=False)
+            stack = nn.scan(
+                cell, variable_axes={"params": 0, "intermediates": 0},
+                split_rngs={"params": True}, length=cfg.n_layers, in_axes=nn.broadcast,
+            )
+            x, _ = stack(cfg, name="layers")(x, positions)
+        else:
+            block = Block
+            if cfg.remat != "none":
+                block = nn.remat(Block, policy=_remat_policy(cfg.remat))
+            for layer in range(cfg.n_layers):
+                x = block(cfg, name=f"layer_{layer}")(x, positions)
+        x = RMSNorm(cfg.norm_eps, cfg.dtype, cfg.norm_dtype, name="final_norm")(x)
+        head = _LMHead(cfg, name="lm_head")
+        return head(x, targets) if targets is not None else head(x).astype(jnp.float32)
+
+
+def router_load(model: Keye, params: Any, tokens: jnp.ndarray) -> jnp.ndarray:
+    """Rows each held expert receives for ``tokens`` (b, s), by layer:
+    (n_layers, num_local_experts). Dropless, so they are all computed; their
+    expectation is ``b * s * experts_per_token / num_experts`` each."""
+    _, seen = model.apply(params, tokens, mutable=["intermediates"])
+    seen = seen["intermediates"]
+    if model.config.scan_layers:
+        return seen["layers"]["block"]["moe"]["rows_by_expert"][0]
+    return jnp.stack([
+        seen[f"layer_{i}"]["moe"]["rows_by_expert"][0] for i in range(model.config.n_layers)
+    ])

@@ -1,0 +1,137 @@
+"""Attention under a learned key selection, in plain tiled XLA.
+
+Each query attends to the ``topk`` earlier keys an indexer scores highest
+(all of them while it has no more than ``topk``), one selection a query and
+shared by every head:
+
+    I[t, s] = sum_j w[t, j] * relu(qI[t, j] . kI[s])        s <= t
+    S_t     = the topk largest I[t, s], ties to the earlier key
+    out[t]  = softmax over S_t of (q[t] . k[s]) * scale, times v
+
+The index scores and the threshold are computed in float32 at the highest
+matmul precision whatever the model's dtype: a key that flips in or out of
+``S_t`` is a discontinuity of the output and not a rounding of it. The
+selection carries no gradient (top-k is piecewise constant).
+
+The path, which is also the CPU path and the oracle of any kernel to come:
+queries in tiles of ``block``; a tile scores itself against the keys up to
+its group's last position, finds each row's threshold by a radix select over
+the scores' bits (32 counting passes, exact, no sort), and attends under the
+mask. A tile is one ``jax.checkpoint``: the backward recomputes its scores and
+selection and keeps nothing of a tile but its inputs. Tiles run one at a time
+(``lax.map``), in ``KEY_GROUPS`` groups that share a key length, so that a
+tile early in the sequence does not pay for the keys after it: with 4 groups
+the pairs computed are 1.18 times the causal half, with 1 group twice.
+"""
+
+from __future__ import annotations
+
+from functools import partial
+from typing import List, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+__all__ = ["index_scores", "select_topk", "sparse_attention"]
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+# Key lengths the tiles of a sequence share (fewer where it has fewer tiles).
+KEY_GROUPS = 4
+
+
+def index_scores(qi: jnp.ndarray, ki: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+    """qi (b, t, j, e), ki (b, s, e), w (b, t, j), all float32 ->
+    I (b, t, s) = sum_j w * relu(qi . ki), float32 at the highest precision."""
+    dots = jnp.einsum("btje,bse->btjs", qi, ki, precision=_HIGHEST)
+    return jnp.sum(w[..., None] * jax.nn.relu(dots), axis=2)
+
+
+def _ordered(x: jnp.ndarray) -> jnp.ndarray:
+    """float32 -> uint32 that sorts as the floats do; every finite score maps
+    above 0, which is left to the keys a query may not see."""
+    x = jnp.where(x == 0, 0.0, x)  # -0.0 and 0.0 tie
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+
+def select_topk(scores: jnp.ndarray, allowed: jnp.ndarray, topk: int) -> jnp.ndarray:
+    """scores (..., s) float32, allowed (..., s) bool -> bool (..., s): per row
+    the ``topk`` largest allowed scores (all of them where fewer are allowed);
+    of equal scores the earlier position wins."""
+    keys = jnp.where(allowed, _ordered(scores), jnp.uint32(0))
+    want = jnp.minimum(jnp.sum(allowed, axis=-1), topk)
+
+    def narrow(i, threshold):
+        candidate = threshold | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        enough = jnp.sum(keys >= candidate[..., None], axis=-1) >= want
+        return jnp.where(enough, candidate, threshold)
+
+    # The largest value that `want` keys reach: the want-th largest key.
+    threshold = jax.lax.fori_loop(0, 32, narrow, jnp.zeros(want.shape, jnp.uint32))
+    above = keys > threshold[..., None]
+    tied = keys == threshold[..., None]
+    room = want - jnp.sum(above, axis=-1)
+    return allowed & (above | (tied & (jnp.cumsum(tied, axis=-1) <= room[..., None])))
+
+
+def _tile(first, q, qi, w, k, v, ki, *, scale: float, topk: int, with_selection: bool):
+    """One tile of queries, positions ``first .. first + t - 1``, against the
+    keys ``0 .. s - 1``. q (b, t, h, d); k, v (b, s, kv, d). Returns the
+    attention output (b, t, h, d) and, where asked for, the selection
+    (b, t, s)."""
+    b, t, h, d = q.shape
+    s, kv = k.shape[1], k.shape[2]
+    at = first + jnp.arange(t)
+    causal = jnp.broadcast_to(at[:, None] >= jnp.arange(s)[None, :], (b, t, s))
+    with jax.named_scope("tpuft::indexer"):
+        chosen = select_topk(index_scores(qi, ki, w), causal, topk)
+    with jax.named_scope("tpuft::sparse_attention"):
+        grouped = q.reshape(b, t, kv, h // kv, d)
+        scores = jnp.einsum("btkgd,bskd->bkgts", grouped, k).astype(jnp.float32) * scale
+        scores = jnp.where(chosen[:, None, None], scores, -jnp.inf)
+        probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+        out = jnp.einsum("bkgts,bskd->btkgd", probs, v).reshape(b, t, h, d)
+    return out, chosen if with_selection else None
+
+
+def _tile_groups(tiles: int) -> List[Tuple[int, int]]:
+    """``tiles`` query tiles in at most ``KEY_GROUPS`` runs ``(lo, hi)`` of as
+    equal a length as whole tiles allow; the tiles of a run see the keys of
+    tiles ``0 .. hi - 1``."""
+    per_group = -(-tiles // min(KEY_GROUPS, tiles))
+    return [(lo, min(lo + per_group, tiles)) for lo in range(0, tiles, per_group)]
+
+
+def sparse_attention(
+    q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+    qi: jnp.ndarray, ki: jnp.ndarray, w: jnp.ndarray,
+    *, topk: int, scale: float, block: int = 512, return_selection: bool = False,
+) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
+    """q (b, s, h, d); k, v (b, s, kv, d), positions encoded; the indexer's
+    qi (b, s, j, e), ki (b, s, e), w (b, s, j) in float32. Returns (out
+    (b, s, h, d), selection (b, s, s) bool or None). ``qi``, ``ki`` and ``w``
+    get no gradient."""
+    b, s, h, d = q.shape
+    block = min(block, s)
+    if s % block:
+        raise ValueError(f"a sequence of {s} positions is not whole tiles of {block}")
+    qi, ki, w = (jax.lax.stop_gradient(x.astype(jnp.float32)) for x in (qi, ki, w))
+    tiles = s // block
+    tiled = lambda x: jnp.moveaxis(x.reshape(b, tiles, block, *x.shape[2:]), 1, 0)
+    rows = (jnp.arange(0, s, block), tiled(q), tiled(qi), tiled(w))
+    outs, chosen = [], []
+    for lo, hi in _tile_groups(tiles):
+        width = hi * block  # no tile of this group sees a later key
+        one = jax.checkpoint(
+            partial(_tile, scale=scale, topk=topk, with_selection=return_selection),
+            prevent_cse=False,
+        )
+        keys = (k[:, :width], v[:, :width], ki[:, :width])
+        out, sel = jax.lax.map(
+            lambda args: one(*args, *keys), tuple(x[lo:hi] for x in rows)
+        )
+        outs.append(out)
+        if return_selection:
+            chosen.append(jnp.pad(sel, ((0, 0), (0, 0), (0, 0), (0, s - width))))
+    untile = lambda xs: jnp.moveaxis(jnp.concatenate(xs), 0, 1).reshape(b, s, *xs[0].shape[3:])
+    return untile(outs), untile(chosen) if return_selection else None
