@@ -377,7 +377,8 @@ class OneChip:
             say(
                 f"  flash vs dense: fwd {flash['max_err']:.4f} bwd "
                 f"{flash['max_err_bwd']:.4f} partial {flash['max_err_partial']:.4f} "
-                f"zigzag {flash['max_err_zigzag']:.4f} ragged {flash['max_err_ragged']:.4f}"
+                f"zigzag {flash['max_err_zigzag']:.4f} ragged {flash['max_err_ragged']:.4f} "
+                f"selected {flash['max_err_selected']:.4f} / {flash['max_err_selected_bwd']:.4f}"
             )
             say(f"  block pairs by class of the schedule: {flash['classes']}")
             say(

@@ -70,7 +70,8 @@ def check(bench, config, traffic, seed: int) -> dict:
 
     @jax.jit
     def differing(mine, theirs):
-        only = jnp.sum(mine & ~theirs, axis=(1, 2))
+        # On a TPU the program's selection is the kernels' int8 operand.
+        only = jnp.sum((mine != 0) & ~theirs, axis=(1, 2))
         return only, jnp.sum(theirs, axis=(1, 2))
 
     only, selected = differing(program(params, tokens), plain(params, tokens))

@@ -804,3 +804,133 @@ def test_fused_backward_matches_dense_and_blockwise(name):
                 np.asarray(got.astype(_F32)), np.asarray(want.astype(_F32)),
                 atol=2e-5 if out_dtype == _F32 else 0.07, err_msg=what,
             )
+
+
+# ---------------------------------------------------------------------------
+# Under a selection: the kernels with the operand against the tiled XLA path
+# of ops/sparse_attention.py, which is their oracle (and what runs off a TPU)
+# ---------------------------------------------------------------------------
+
+# name: (s, q heads, kv heads, topk, the indexer's taste, tile of the
+# selection, block_q, block_k). "recent": index scores that fall with the
+# distance, so a query's keys are its latest ``topk``: from row block_k + topk
+# on, a row's first KV blocks hold none of its keys (its running max stays at
+# the sentinel until one arrives), and with nothing else mixed in, whole
+# (block_q, block_k) blocks of the operand that the causal schedule needs
+# are zero. "mixed": every other row so, the rest random.
+_SELECTED_CASES = {
+    "gqa-group-of-8": (256, 8, 1, 24, "random", 32, 32, 128),
+    "a-head-a-group": (256, 2, 2, 24, "random", 32, 32, 128),
+    "topk-over-the-sequence": (256, 4, 2, 300, "random", 32, 32, 128),
+    "first-kv-blocks-hold-no-key-of-a-row": (384, 4, 2, 24, "mixed", 32, 32, 128),
+    "a-block-of-the-operand-all-zero": (384, 4, 2, 24, "recent", 32, 32, 128),
+    "sq-not-a-multiple-of-the-block": (200, 4, 2, 24, "random", 8, 64, 128),
+}
+
+
+def _indexer_inputs(b, s, taste):
+    """qi (b, s, 1, 4), ki (b, s, 4), w (b, s, 1) float32. A key is a point
+    on a quarter circle by its position beside two random numbers; a "recent"
+    row asks for the circle alone, so its ``relu(qi . ki)`` is the cosine of
+    an angle that grows with the distance, a "random" row for the random
+    half alone; "mixed": odd rows recent, even rows random."""
+    keys = jax.random.split(jax.random.PRNGKey(31), 2)
+    angle = jnp.arange(s) * (np.pi / 2 / s)
+    circle = jnp.broadcast_to(jnp.stack([jnp.cos(angle), jnp.sin(angle)], -1), (b, s, 2))
+    ki = jnp.concatenate([circle, jax.random.normal(keys[1], (b, s, 2))], -1)
+    recent = jnp.concatenate([circle, jnp.zeros((b, s, 2))], -1)
+    random = jnp.concatenate([jnp.zeros((b, s, 2)), jax.random.normal(keys[0], (b, s, 2))], -1)
+    rows = {
+        "recent": jnp.ones((s,), bool), "random": jnp.zeros((s,), bool),
+        "mixed": jnp.arange(s) % 2 == 1,
+    }[taste]
+    qi = jnp.where(rows[None, :, None], recent, random)
+    return qi[:, :, None, :], ki, jnp.ones((b, s, 1))
+
+
+@pytest.mark.parametrize("name", list(_SELECTED_CASES))
+def test_selected_kernels_match_the_tiled_path(name):
+    """Output and the gradients into q, k and v: ``select_keys`` then
+    ``flash_attention(..., selection=...)`` (both kernels interpreted)
+    against ``sparse_attention``, and the operand against its selection."""
+    from torchft_tpu.ops.sparse_attention import select_keys, sparse_attention
+
+    s, h, kv, topk, taste, tile, block_q, block_k = _SELECTED_CASES[name]
+    b, d = 2, 16
+    q, k, v = _qkv(b, s, h, kv, d, seed=4)
+    qi, ki, w = _indexer_inputs(b, s, taste)
+    weight = jax.random.normal(jax.random.PRNGKey(9), (b, s, h, d))
+
+    def tiled(q, k, v):
+        return sparse_attention(
+            q, k, v, qi, ki, w, topk=topk, scale=d**-0.5, block=tile,
+            return_selection=True,
+        )
+
+    selection = select_keys(qi, ki, w, topk=topk, block=tile)
+    assert selection.dtype == jnp.int8 and selection.shape == (b, s, s)
+    want, chosen = tiled(q, k, v)
+    assert np.array_equal(np.asarray(selection), np.asarray(chosen))
+    picked = np.asarray(selection[0])
+    assert (picked.sum(axis=1) == np.minimum(np.arange(s) + 1, topk)).all()
+    blocks = picked[: s // block_q * block_q, : s // block_k * block_k].reshape(
+        s // block_q, block_q, s // block_k, block_k
+    )
+    if taste == "mixed":
+        assert (picked[block_k + topk:, :block_k].sum(axis=1) == 0).any()
+        assert blocks.any(axis=(1, 3))[-1].all()  # and yet no needed block is empty
+    if taste == "recent":
+        assert not blocks.any(axis=(1, 3))[-1, 0]  # needed by the schedule, all zero
+
+    def kernels(q, k, v):
+        return flash_attention(
+            q, k, v, block_q=block_q, block_k=block_k, interpret=True,
+            selection=selection,
+        )
+
+    np.testing.assert_allclose(np.asarray(kernels(q, k, v)), np.asarray(want), atol=2e-5)
+    got_grads = jax.grad(lambda *x: jnp.sum(kernels(*x) * weight), argnums=(0, 1, 2))(q, k, v)
+    want_grads = jax.grad(lambda *x: jnp.sum(tiled(*x)[0] * weight), argnums=(0, 1, 2))(q, k, v)
+    for got, ref, what in zip(got_grads, want_grads, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=5e-5, err_msg=what)
+
+    if topk >= s:
+        # The selection is the causal mask: the position-masked call, bit for
+        # bit (a pair under the diagonal loses a select that was true all over).
+        plain = partial(
+            flash_attention, block_q=block_q, block_k=block_k, interpret=True,
+            use_pallas_bwd=True,
+        )
+        assert np.array_equal(np.asarray(kernels(q, k, v)), np.asarray(plain(q, k, v)))
+        plain_grads = jax.grad(lambda *x: jnp.sum(plain(*x) * weight), argnums=(0, 1, 2))(q, k, v)
+        for got, ref in zip(got_grads, plain_grads):
+            assert np.array_equal(np.asarray(got), np.asarray(ref))
+
+
+def test_a_row_that_selects_nothing_comes_out_zero_with_zero_gradients():
+    """No selection ``select_keys`` makes has such a row (a query sees itself),
+    but the operand is any int8 array: rows 3 and 40 select nothing, and the
+    kernels give them zero output and take no gradient through them."""
+    b, s, h, kv, d = 1, 64, 2, 1, 16
+    q, k, v = _qkv(b, s, h, kv, d, seed=6)
+    selection = jnp.tril(jnp.ones((s, s), jnp.int8)).at[jnp.array([3, 40])].set(0)[None]
+
+    def kernels(q, k, v):
+        return flash_attention(
+            q, k, v, block_q=32, block_k=128, interpret=True, selection=selection
+        )
+
+    out = kernels(q, k, v)
+    assert not np.any(np.asarray(out[0, [3, 40]])) and np.all(np.isfinite(np.asarray(out)))
+    dq, dk, dv = jax.grad(lambda *x: jnp.sum(kernels(*x) ** 2), argnums=(0, 1, 2))(q, k, v)
+    assert not np.any(np.asarray(dq[0, [3, 40]]))
+    assert all(np.all(np.isfinite(np.asarray(g))) for g in (dq, dk, dv))
+
+
+def test_the_selection_takes_no_scan_backward_and_no_other_shape():
+    q, k, v = _qkv(1, 64, 2, 1, 16)
+    selection = jnp.ones((1, 64, 64), jnp.int8)
+    with pytest.raises(ValueError, match="scan-based backward"):
+        flash_attention(q, k, v, interpret=True, use_pallas_bwd=False, selection=selection)
+    with pytest.raises(ValueError, match="selection"):
+        flash_attention(q, k, v, interpret=True, selection=selection[:, :32])

@@ -25,6 +25,7 @@ ROOT = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT))
 
 from chipbench import reference, spec  # noqa: E402
+from torchft_tpu.models import keye  # noqa: E402
 from torchft_tpu.models.keye import ExpertLayer, Keye, KeyeConfig, router_load  # noqa: E402
 from torchft_tpu.ops.cross_entropy import chunked_cross_entropy  # noqa: E402
 from torchft_tpu.ops.grouped_matmul import grouped_matmul  # noqa: E402
@@ -119,6 +120,67 @@ def test_a_leafs_gradient_agrees_with_the_float32_reference(leaf, both_gradients
     got, want = both_gradients
     assert float(jnp.linalg.norm(want["params/" + leaf])) > 0
     assert relative(got["params/" + leaf], want["params/" + leaf]) < 1e-4
+
+
+def _steer_onto_the_kernels(patch) -> None:
+    """The path a TPU takes, on the CPU: the model is told it is on one and
+    its flash kernels are interpreted (the test steers; the program has no
+    option for it)."""
+    from functools import partial
+
+    patch.setattr(keye, "on_tpu", lambda: True)
+    patch.setattr(keye, "flash_attention", partial(keye.flash_attention, interpret=True))
+
+
+@pytest.fixture
+def kernel_path(monkeypatch):
+    _steer_onto_the_kernels(monkeypatch)
+
+
+@pytest.fixture(scope="module")
+def kernel_path_gradients(toy, both_gradients):
+    config, model, params, tokens = toy
+    with pytest.MonkeyPatch.context() as patch:
+        _steer_onto_the_kernels(patch)
+        got = jax.grad(lambda p: program_loss(model, p, tokens))(params)
+    return by_path(got), both_gradients[1]
+
+
+def test_the_loss_through_the_kernels_agrees_with_the_float32_reference(toy, kernel_path):
+    config, model, params, tokens = toy
+    want = reference.make_loss(ARCHITECTURE, config)(params, tokens)
+    jaxpr = str(jax.make_jaxpr(lambda p: program_loss(model, p, tokens))(params))
+    assert "pallas_call" in jaxpr  # the path under test is the kernels'
+    assert abs(float(program_loss(model, params, tokens)) - float(want)) / float(want) < TOLERANCE
+
+
+@pytest.mark.parametrize("leaf", LEAVES)
+def test_a_leafs_gradient_through_the_kernels_agrees_with_the_float32_reference(
+    leaf, kernel_path_gradients
+):
+    got, want = kernel_path_gradients
+    assert relative(got["params/" + leaf], want["params/" + leaf]) < 1e-4
+
+
+@pytest.mark.parametrize("leaf", INDEXER_LEAVES)
+def test_the_indexers_leaves_get_gradient_exactly_zero_through_the_kernels(
+    leaf, kernel_path_gradients
+):
+    assert not np.any(np.asarray(kernel_path_gradients[0]["params/" + leaf]))
+
+
+def test_the_kernels_operand_is_the_tiled_paths_selection(toy, kernel_path):
+    """What a watcher is shown on the kernel path is the operand itself, int8,
+    and it is the selection the tiled path makes (and so the reference's)."""
+    config, model, params, tokens = toy
+    _, seen = model.apply(params, tokens[:1, :-1], mutable=["intermediates"])
+    got = seen["intermediates"]["layers"]["block"]["attn"]["selection"][0][:, 0]
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(keye, "on_tpu", lambda: False)
+        _, seen = model.apply(params, tokens[:1, :-1], mutable=["intermediates"])
+    want = seen["intermediates"]["layers"]["block"]["attn"]["selection"][0][:, 0]
+    assert got.dtype == jnp.int8 and want.dtype == jnp.bool_
+    assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
 @pytest.mark.parametrize("leaf", INDEXER_LEAVES)

@@ -163,6 +163,103 @@ def _pallas_calls(jaxpr) -> list:
     return found
 
 
+def _kernel_refs(call) -> list:
+    """(shape, dtype) of every ref the kernel body of a pallas_call sees: the
+    scalar-prefetch tables, the operands' blocks, the outputs' blocks, the
+    scratch."""
+    return [(v.aval.shape, str(v.aval.dtype)) for v in call.params["jaxpr"].invars]
+
+
+def _cell_gradient(kv, with_selection):
+    """The traced gradient of ``flash_attention`` at 1 x 8192 with 32 q heads
+    of 128 over ``kv`` KV heads, default blocks; its two pallas_calls."""
+    b, s, h, d = 1, 8192, 32, 128
+    q = _sds((b, s, h, d), jnp.bfloat16)
+    k = _sds((b, s, kv, d), jnp.bfloat16)
+    selection = _sds((b, s, s), jnp.int8)
+
+    def loss(q, k, v, selection):
+        out = flash_attention(
+            q, k, v, interpret=False, use_pallas_bwd=True,
+            selection=selection if with_selection else None,
+        )
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    traced = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q, k, k, selection)
+    forward, backward = _pallas_calls(traced.jaxpr.jaxpr)
+    return traced, forward, backward
+
+
+_BF16, _F32, _I32 = "bfloat16", "float32", "int32"
+_TABLES = [((1, 3, 16), _I32), ((1, 3, 8), _I32)]
+_FWD_REST = [  # out and the logsumexp; acc, running max and sum
+    ((512, 128), _BF16), ((512, 1), _F32),
+    ((512, 128), _F32), ((512, 1), _F32), ((512, 1), _F32),
+]
+_BWD_REST = [  # dq (the head's rows), dk, dv; their three accumulators
+    ((8192, 128), _BF16), ((1024, 128), _BF16), ((1024, 128), _BF16),
+    ((8192, 128), _F32), ((1024, 128), _F32), ((1024, 128), _F32),
+]
+_QKV = [((512, 128), _BF16), ((1024, 128), _BF16), ((1024, 128), _BF16)]
+_BWD_ROWS = [((512, 128), _BF16), ((512, 1), _F32), ((512, 1), _F32)]  # dO, lse, delta
+_POSITIONS = [((512, 1), _I32), ((1, 1024), _I32)]
+_SELECTION = [((512, 1024), "int8")]
+
+
+def test_without_a_selection_the_calls_are_the_ones_the_mistral_cells_run():
+    """The contract with every caller that passes no selection (the four
+    Mistral cells, chip_smoke.py, the ring hops): operand for operand, the
+    grid, the blocks and the scratch the two calls had before the argument
+    existed (read off the commit before it, at the cells' 1 x 8192 geometry),
+    and no int8 anywhere."""
+    traced, forward, backward = _cell_gradient(kv=8, with_selection=False)
+    assert forward.params["grid_mapping"].grid == (1, 32, 16, 8)
+    assert backward.params["grid_mapping"].grid == (1, 32, 1, 8, 16)
+    assert _kernel_refs(forward) == _TABLES + _QKV + _POSITIONS + _FWD_REST
+    assert _kernel_refs(backward) == _TABLES + _QKV + _BWD_ROWS + _POSITIONS + _BWD_REST
+    assert [(v.aval.shape, str(v.aval.dtype)) for v in forward.invars] == _TABLES + [
+        ((1, 32, 8192, 128), _BF16), ((1, 8, 8192, 128), _BF16), ((1, 8, 8192, 128), _BF16),
+        ((1, 8192, 1), _I32), ((1, 1, 8192), _I32),
+    ]
+    assert len(backward.invars) == 10
+    # No int8 tensor among the lowered module's values (">" is no letter of
+    # the base64 the Mosaic bodies are written in).
+    assert "xi8>" not in traced.lower(lowering_platforms=("tpu",)).as_text()
+
+
+def test_selected_flash_kernels_lower_for_tpu_at_the_keye_cells_geometry():
+    """With the operand (32 q heads over 4 KV heads, as the cell
+    ``keye-vl2-30b-a3b-1chip.ftddp-seq8k`` runs them): the same grids, the
+    two position blocks gone and one (512, 1024) int8 block of the selection
+    in their place, in the forward and in the one backward call, and the pair
+    lowers for a TPU (the int8 block's (32, 128) tiling)."""
+    traced, forward, backward = _cell_gradient(kv=4, with_selection=True)
+    assert forward.params["grid_mapping"].grid == (1, 32, 16, 8)
+    assert backward.params["grid_mapping"].grid == (1, 32, 1, 8, 16)
+    assert _kernel_refs(forward) == _TABLES + _QKV + _SELECTION + _FWD_REST
+    assert _kernel_refs(backward) == _TABLES + _QKV + _BWD_ROWS + _SELECTION + _BWD_REST
+    assert (forward.invars[-1].aval.shape, backward.invars[-1].aval.shape) == ((1, 8192, 8192),) * 2
+    assert "xi8>" in traced.lower(lowering_platforms=("tpu",)).as_text()
+
+
+@pytest.mark.parametrize("s, block_q", [(200, 64), (40, 512), (600, 48)])
+def test_selected_flash_kernels_lower_with_ragged_lengths_and_blocks(s, block_q):
+    """The selection's block has block_q rows of int8, whose sublane tile is
+    32: a block_q the bf16 rule alone would leave at 48 is rounded to 64, and
+    a sequence shorter than a block pads to a multiple of 32."""
+    b, h, kv, d = 2, 4, 2, 64
+    q = _sds((b, s, h, d), jnp.bfloat16)
+    k = _sds((b, s, kv, d), jnp.bfloat16)
+
+    def loss(q, k, v, selection):
+        out = flash_attention(
+            q, k, v, block_q=block_q, block_k=128, interpret=False, selection=selection
+        )
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    _lower_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, k, k, _sds((b, s, s), jnp.int8))
+
+
 @pytest.mark.parametrize("where", ["plain", "shard_map-batch", "shard_map-positions"])
 def test_flash_kernels_lower_with_their_schedule_tables(where):
     """The forward and the backward call each take the causal block

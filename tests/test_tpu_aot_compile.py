@@ -81,6 +81,21 @@ def _compile(fn, *args):
     return compiled
 
 
+def _mosaic_calls(compiled) -> list:
+    """(name, the limits it states, the bytes it used) of every Mosaic call
+    in a compiled program's text."""
+    calls = []
+    for line in compiled.as_text().splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        stated, used = (
+            [int(size) for size in re.findall(r'"size":"(\d+)"', configs)]
+            for configs in re.findall(r'"(?:used_)?scoped_memory_configs":\[([^\]]*)\]', line)
+        )
+        calls.append((line.split(" = ")[0].strip().lstrip("%"), stated, used))
+    return calls
+
+
 def _qkv(chip, b=B, sq=S, sk=S):
     return (
         _sds((b, sq, H, D), jnp.bfloat16, chip),
@@ -224,16 +239,9 @@ def test_flash_backward_compiles_at_the_cells_geometry(
         )
 
     compiled = _compile(backward, q, k, k, q, q, lse, pos, pos)
-    (call,) = [
-        line for line in compiled.as_text().splitlines()
-        if 'custom_call_target="tpu_custom_call"' in line
-    ]
     # What the call states (nothing: XLA sees a call like any other and tiles
     # the program's other fusions as it did) and what Mosaic used of it.
-    stated, used = (
-        [int(size) for size in re.findall(r'"size":"(\d+)"', configs)]
-        for configs in re.findall(r'"(?:used_)?scoped_memory_configs":\[([^\]]*)\]', call)
-    )
+    ((_, stated, used),) = _mosaic_calls(compiled)
     assert stated == ([need] if states_limit else [])
     assert used and used[0] <= (need if states_limit else fa._SCOPED_VMEM_BYTES)
 
@@ -531,3 +539,118 @@ def test_selected_attention_fits_the_chip_at_the_keye_cells_geometry(chip) -> No
     ).compile()
     assert "tpu_custom_call" not in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
+
+
+def test_selected_flash_kernels_compile_at_the_keye_cells_geometry(chip) -> None:
+    """The forward and the one backward call with the selection as an operand,
+    at 1 x 8192 with 32 / 4 heads of 128 and the default 512 x 1024 blocks:
+    neither states a VMEM limit (a stated one retiles other fusions of the
+    step: ops/flash_attention.py ``_SCOPED_VMEM_BYTES``), both fit the 16 MiB
+    a call gets unasked, and the backward's estimate, which knows of the
+    operand's block, is over what the compiler used and not over those
+    16 MiB: exactly at them, so a block of this call that grows brings a
+    stated limit and fails here."""
+    from torchft_tpu.ops import flash_attention as fa
+
+    s, h, kv, d = 8192, 32, 4, 128
+    need = fa._bwd_vmem_bytes(s, 512, 1024, d, 2, 2, True)
+    assert fa._bwd_vmem_bytes(s, 512, 1024, d, 2, 2) < need <= fa._SCOPED_VMEM_BYTES
+
+    def loss(q, k, v, selection):
+        out = fa.flash_attention(q, k, v, interpret=False, selection=selection)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1, 2)),
+        _sds((1, s, h, d), jnp.bfloat16, chip), _sds((1, s, kv, d), jnp.bfloat16, chip),
+        _sds((1, s, kv, d), jnp.bfloat16, chip), _sds((1, s, s), jnp.int8, chip),
+    )
+    calls = _mosaic_calls(compiled)
+    assert len(calls) == 2 and all(stated == [] for _, stated, _ in calls), calls
+    assert max(used[0] for _, _, used in calls) <= need, calls
+
+
+# The cell ``keye-vl2-30b-a3b-1chip.ftddp-seq8k``'s own size, and a twin at toy
+# widths for tier-1: the same head_dim, GQA group of 8, block sizes and four
+# key groups, an eighth of the sequence.
+_KEYE_TOY = {
+    "hidden_size": 256, "num_attention_heads": 8, "num_key_value_heads": 1,
+    "moe_intermediate_size": 128, "num_experts": 16, "num_experts_per_tok": 2,
+    "num_local_experts": 2, "vocab_size": 2048, "num_hidden_layers": 2,
+    "sa_config": {
+        "indexer_head_dim": 64, "indexer_num_heads": 2, "indexer_num_kv_heads": 1,
+        "kv_chunk_size": 128, "q_chunk_size": 128, "topk": 256,
+    },
+}
+
+
+@pytest.mark.parametrize(
+    "widths, seq",
+    [
+        pytest.param({}, 8192, marks=pytest.mark.slow, id="keye-1x8192"),  # 2 x 40 s
+        pytest.param(_KEYE_TOY, 1024, id="toy-1x1024"),
+    ],
+)
+def test_dots_step_of_the_keye_cell_selects_once_and_runs_one_flash_pair_a_layer(
+    chip, monkeypatch, widths, seq
+) -> None:
+    """The FT-DDP fused step of the KeyeVL2 cell (scanned layers, bf16,
+    ``dots``, AdamW) compiled for a described v5e as the model builds it on a
+    TPU: the layers stay one loop, so the compiled text holds one Mosaic call
+    for each kernel of a layer body: beside the expert layer's (``gmm``), the
+    flash forward and the ONE backward, each with the selection as its
+    operand, and neither stating a VMEM limit; and the index scores are
+    computed once a key group, in the forward's loop body alone. With a
+    policy that keeps the dots and none of the three names, the backward's
+    loop holds the forward call and the selection a second time: what the
+    names are kept for."""
+    import json
+    from pathlib import Path
+
+    import torchft_tpu.models.keye as keye
+    import torchft_tpu.ops.flash_attention as flash
+    import torchft_tpu.ops.grouped_matmul as grouped
+    from chipbench import spec
+    from chipbench.model import System
+    from torchft_tpu.ops.sparse_attention import KEY_GROUPS
+    from torchft_tpu.optim import make_jit_fused_step
+
+    for module in (keye, flash, grouped):
+        monkeypatch.setattr(module, "on_tpu", lambda: True)
+    root = Path(__file__).parent.parent
+    config = json.loads(
+        (root / "chipbench/configs/keye-vl2-30b-a3b-ep8-1chip.json").read_text()
+    )
+    config.update(widths)
+    assert config["run"]["remat"] == "dots" and config["run"]["scan_layers"]
+    architecture = spec.load_module(root / "chipbench/architectures/KeyeVL2.py")
+    system = System(config, architecture, {"batch": 1, "seq": seq}, seed=0)
+    params = jax.eval_shape(system.init_params)
+    opt_state = jax.eval_shape(system.tx.init, params)
+
+    def compiled():
+        program = (
+            make_jit_fused_step(system.tx, system.loss_fn)
+            .lower(
+                _sds_tree(params, chip), _sds_tree(opt_state, chip),
+                _sds((1, seq + 1), jnp.int32, chip),
+            )
+            .compile()
+        )
+        calls = _mosaic_calls(program)
+        assert all(stated == [] for _, stated, _ in calls), calls
+        flash_calls = [name for name, _, _ in calls if not architecture.EXPERT_KERNEL.search(name)]
+        index_scores = [
+            line for line in program.as_text().splitlines()
+            if "tpuft::indexer" in line and " convolution(" in line
+        ]
+        return len(flash_calls), len(index_scores), sum(
+            "rematted_computation" in line for line in index_scores
+        )
+
+    assert compiled() == (2, KEY_GROUPS, 0)
+    monkeypatch.setattr(
+        keye, "_remat_policy",
+        lambda remat: jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
+    )
+    assert compiled() == (3, 2 * KEY_GROUPS, KEY_GROUPS)

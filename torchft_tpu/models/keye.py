@@ -7,8 +7,10 @@ Per layer, pre-norm:
   ``dim / n_heads``), an RMSNorm over each head of q and k, rotary (rotate-half;
   text-only positions, so the family's three rotary sections see one position
   and the embedding is the plain one). Each query attends to the ``topk``
-  earlier keys an indexer scores highest (ops/sparse_attention.py): the
-  indexer has ``indexer_heads`` query heads and one key head of
+  earlier keys an indexer scores highest (ops/sparse_attention.py; on a TPU
+  the selection is made once as an int8 array and the flash kernels of
+  ops/flash_attention.py attend under it, elsewhere plain tiled XLA does
+  both): the indexer has ``indexer_heads`` query heads and one key head of
   ``indexer_head_dim``, a LayerNorm on its key, rotary on both, a learned
   weight a head, and runs in ``indexer_dtype`` (float32) whatever ``dtype`` is.
   The selection carries no gradient, so the language-model loss gives the
@@ -26,8 +28,10 @@ Per layer, pre-norm:
 
 Rotary and the output head with its fused loss are models/llama.py's, the
 norm is its arithmetic with the scale stored in ``norm_dtype`` (float32, as
-there, unless a configuration says otherwise); the layer stack is scanned and rematerialised the same way (the ``dots``
-policy keeps the projections' products, not a tile's scores). ``router_load``
+there, unless a configuration says otherwise); the layer stack is scanned and
+rematerialised the same way (the ``dots`` policy keeps the projections'
+products, the flash kernel's output and logsumexp and the selection, not a
+tile's scores). ``router_load``
 counts the rows each held expert receives.
 """
 
@@ -43,9 +47,10 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from torchft_tpu.models.llama import _LMHead, apply_rope
-from torchft_tpu.ops.flash_attention import FLASH_OUT
+from torchft_tpu.ops.flash_attention import FLASH_LSE, FLASH_OUT, flash_attention
 from torchft_tpu.ops.grouped_matmul import grouped_matmul
-from torchft_tpu.ops.sparse_attention import sparse_attention
+from torchft_tpu.ops.sparse_attention import SELECTION, select_keys, sparse_attention
+from torchft_tpu.utils.platform import on_tpu
 
 __all__ = ["KeyeConfig", "Keye", "router_load", "route"]
 
@@ -174,15 +179,22 @@ class SparseAttention(nn.Module):
         k = apply_rope(head_norm(name="k_norm")(k), positions, cfg.rope_theta)
         qi, ki, w = Indexer(cfg, name="indexer")(x, positions)
         watched = self.is_mutable_collection("intermediates")
-        out, chosen = sparse_attention(
-            q, k, v, qi, ki, w, topk=cfg.topk, scale=cfg.head_dim**-0.5,
-            block=cfg.select_block, return_selection=watched,
-        )
+        scale = cfg.head_dim**-0.5
+        if on_tpu():
+            # The selection once, as the kernels' operand (and what a watcher
+            # is shown: int8); they name their own residuals.
+            chosen = select_keys(qi, ki, w, topk=cfg.topk, block=cfg.select_block)
+            out = flash_attention(q, k, v, scale=scale, selection=chosen)
+        else:
+            out, chosen = sparse_attention(
+                q, k, v, qi, ki, w, topk=cfg.topk, scale=scale,
+                block=cfg.select_block, return_selection=watched,
+            )
+            # Kept under remat="dots" by the name the flash kernel's output
+            # has: the layer's backward then recomputes a tile once, not twice.
+            out = checkpoint_name(out, FLASH_OUT)
         if watched:
             self.sow("intermediates", "selection", chosen)
-        # Kept under remat="dots" by the name the flash kernel's output has:
-        # the layer's backward then recomputes a tile once, not twice.
-        out = checkpoint_name(out, FLASH_OUT)
         return dense(features=cfg.dim, axis=(-2, -1), kernel_init=_into_residual(cfg), name="wo")(out)
 
 
@@ -247,18 +259,21 @@ class Block(nn.Module):
 
 
 def _remat_policy(remat: str):
-    """``dots`` keeps what the projections' matmuls produced and the attention
-    output (by the name the flash kernel's has), as models/llama.py's policy
-    does, but only the dots WITHOUT batch dimensions: a tile's index scores
-    and attention scores are batched dots, and kept they would be the whole
-    (heads, s, s) arrays the tiles exist to avoid (a policy reaches through
-    the tiles' own ``jax.checkpoint``: 36 GB at 6 layers x 8192)."""
+    """``dots`` keeps what the projections' matmuls produced and the flash
+    kernel's output and logsumexp by their names, as models/llama.py's policy
+    does (a dropped logsumexp is a second forward call in the backward), and
+    the selection by its own (one int8 (s, s) a layer: dropped, the backward
+    would score and select again); but only the dots WITHOUT batch
+    dimensions: a tile's index scores and attention scores are batched dots,
+    and kept they would be the whole (heads, s, s) arrays the tiles exist to
+    avoid (a policy reaches through the tiles' own ``jax.checkpoint``: 36 GB
+    at 6 layers x 8192)."""
     if remat != "dots":
         return None
     policies = jax.checkpoint_policies
     return policies.save_from_both_policies(
         policies.checkpoint_dots_with_no_batch_dims,
-        policies.save_only_these_names(FLASH_OUT),
+        policies.save_only_these_names(FLASH_OUT, FLASH_LSE, SELECTION),
     )
 
 

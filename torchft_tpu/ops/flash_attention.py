@@ -41,6 +41,21 @@ compare and select; a pair the DIAGONAL crosses, and any pair with a padded
 row or column, takes the mask. The classes follow the positions alone, so a
 ring's zigzag hops, ``sq != sk`` and ragged lengths schedule by the same two
 comparisons as the plain causal call.
+A call may bring a SELECTION, a (b, s, s) int8 operand that says which keys
+each query attends to (``flash_attention(..., selection=...)``; the learned
+key selection of models/keye.py, made once a layer step by
+ops/sparse_attention.py ``select_keys``): every pair the schedule needs then
+masks by its (block_q, block_k) block of the operand in place of the
+position compare, in the forward and in the one backward call, which read
+it through one more BlockSpec built from the same block indices; pairs above
+the diagonal are skipped as ever. What decides is the presence of the operand
+in the call, and the bodies are specialised at trace time: a call without it
+lowers to the Mosaic call it was before the argument existed, operand for
+operand (tests/test_mosaic_lowering.py holds that). Which attention runs
+where: models/llama.py calls the kernels with no selection where
+``attention_impl`` says (``auto``: on a TPU at long sequences), ring
+attention calls the partial pair a hop, models/keye.py calls them with the
+selection on a TPU and ops/sparse_attention.py's tiled XLA path elsewhere.
 GQA is handled by emitting per-q-head dk/dv partials and summing over the
 group axis outside — keeps every output block written exactly once per
 grid pass (no cross-step output aliasing, which Mosaic cannot express; the
@@ -211,7 +226,8 @@ def _block_specs(block_q, block_k, d, group, q_of, k_of):
     """BlockSpecs of one pass over a grid (b, h, further axes), by kind of
     operand: ``q`` (a q head's rows: q, dO, out), ``kv`` (a KV head's rows,
     shared by the q heads of its group), ``col`` (a q head's per-row
-    scalars: lse, delta), ``qp`` and ``kp`` (the positions). ``q_of`` and
+    scalars: lse, delta), ``qp`` and ``kp`` (the positions), ``sel`` (the
+    pair's block of a selection, shared by every head). ``q_of`` and
     ``k_of`` give the q block and the KV block a grid step names, from the
     step's (ib, further axes) and the two schedule tables."""
     from jax.experimental import pallas as pl
@@ -230,18 +246,47 @@ def _block_specs(block_q, block_k, d, group, q_of, k_of):
         "col": spec((None, None, block_q, 1), lambda ib, ih, jq, jk: (ib, ih, jq, 0)),
         "qp": spec((None, block_q, 1), lambda ib, ih, jq, jk: (ib, jq, 0)),
         "kp": spec((None, 1, block_k), lambda ib, ih, jq, jk: (ib, 0, jk)),
+        "sel": spec((None, block_q, block_k), lambda ib, ih, jq, jk: (ib, jq, jk)),
     }
 
 
-def _when_needed(qs_ref, ks_ref, ib, iq, ik, update):
+def _mask_operands(selection, qp, kp, pad_q, pad_k):
+    """The operands a needed pair masks by, with their kinds of spec
+    (:func:`_block_specs`): the selection, padded with zeros like q and k, or
+    without one the two position arrays. Positions ride as 3-D so each block
+    is a 2-D tile (a column for q, a row for k, so that the in-kernel compare
+    broadcasts without a transpose)."""
+    if selection is not None:
+        return (jnp.pad(selection, ((0, 0), (0, pad_q), (0, pad_k))),), ("sel",)
+    b = qp.shape[0]
+    return (qp.reshape(b, -1, 1), kp.reshape(b, 1, -1)), ("qp", "kp")
+
+
+def _allowed(mask_refs):
+    """(block_q, block_k) bool of a pair from the call's mask operands
+    (:func:`_mask_operands`): the one block of the selection, which already
+    implies that the key is no later than the query, or the two positions'
+    compare. Which it is, is the call's operands and so known at trace
+    time."""
+    if len(mask_refs) == 1:
+        return mask_refs[0][...] != 0
+    qp_ref, kp_ref = mask_refs
+    return qp_ref[...] >= kp_ref[...]
+
+
+def _when_needed(qs_ref, ks_ref, ib, iq, ik, update, mask_refs):
     """Runs ``update(masked)`` for the pair's class: not at all above the
-    diagonal, without the mask under it, with it on it."""
+    diagonal, without the mask under it, with it on it; where the mask is a
+    selection (:func:`_allowed`) every needed pair takes it."""
     from jax.experimental import pallas as pl
 
     needed, under = _pair_class(
         qs_ref[ib, _LO, iq], qs_ref[ib, _HI, iq],
         ks_ref[ib, _LO, ik], ks_ref[ib, _HI, ik],
     )
+    if len(mask_refs) == 1:
+        pl.when(needed)(partial(update, True))
+        return
     pl.when(under)(partial(update, False))
     pl.when(jnp.logical_and(needed, jnp.logical_not(under)))(partial(update, True))
 
@@ -252,23 +297,18 @@ def _fwd_kernel(
     q_ref,
     k_ref,
     v_ref,
-    qp_ref,
-    kp_ref,
-    o_ref,
-    lse_ref,
-    acc_ref,
-    m_ref,
-    l_ref,
-    *,
+    *rest,
     scale: float,
     nk: int,
 ):
     """One (batch, head, q-block, kv-block) grid step.
 
     Refs: the schedule tables qs (b, 3, nq) and ks (b, 3, nk) in SMEM
-    (:func:`_block_schedule`); q (block_q, d); k/v (block_k, d); positions
-    qp (block_q, 1) and kp (1, block_k) int32 — explicit arrays, not iota,
-    so permuted layouts (ring/zigzag shards) mask correctly; o (block_q, d);
+    (:func:`_block_schedule`); q (block_q, d); k/v (block_k, d); the mask
+    operands (:func:`_allowed`): positions qp (block_q, 1) and kp
+    (1, block_k) int32 — explicit arrays, not iota, so permuted layouts
+    (ring/zigzag shards) mask correctly — or the pair's (block_q, block_k)
+    int8 block of a selection; o (block_q, d);
     lse (block_q, 1) — scalars-per-row ride as a column, rank-1 tiled
     outputs fail Mosaic lowering (see ops/quantization.py). Scratch acc
     (block_q, d) f32, m/l (block_q, 1) f32 persist across the kv grid axis
@@ -276,6 +316,7 @@ def _fwd_kernel(
     """
     from jax.experimental import pallas as pl
 
+    *mask_refs, o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
     ib, iq, ik = pl.program_id(0), pl.program_id(2), pl.program_id(3)
 
     @pl.when(ik == 0)
@@ -295,7 +336,7 @@ def _fwd_kernel(
             * scale
         )  # (block_q, block_k) f32
         if masked:
-            scores = jnp.where(qp_ref[...] >= kp_ref[...], scores, _NEG_INF)
+            scores = jnp.where(_allowed(mask_refs), scores, _NEG_INF)
 
         m_prev = m_ref[...]  # (block_q, 1)
         m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
@@ -311,7 +352,7 @@ def _fwd_kernel(
         acc_ref[...] = acc_ref[...] * correction + pv
         m_ref[...] = m_new
 
-    _when_needed(qs_ref, ks_ref, ib, iq, ik, _update)
+    _when_needed(qs_ref, ks_ref, ib, iq, ik, _update, mask_refs)
 
     @pl.when(ik == nk - 1)
     def _finalize():
@@ -327,7 +368,7 @@ def _fwd_kernel(
 
 def _flash_fwd(
     q, k, v, scale, block_q, block_k, interpret,
-    q_positions=None, k_positions=None,
+    q_positions=None, k_positions=None, selection=None,
 ):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -350,10 +391,7 @@ def _flash_fwd(
         q_positions, k_positions, b, sq, sk, block_q, block_k
     )
     q_sched, k_sched = _block_schedule(qp, kp, block_q, block_k, interpret)
-    # Positions ride as 3-D so each block is a 2-D tile (a column for q, a
-    # row for k — so the in-kernel compare broadcasts without a transpose).
-    qp = qp.reshape(b, sq + pad_q, 1)
-    kp = kp.reshape(b, 1, sk + pad_k)
+    mask, mask_kinds = _mask_operands(selection, qp, kp, pad_q, pad_k)
 
     # Kernels run on (b, heads, seq, d): Mosaic requires the last two BLOCK
     # dims be (mult-of-8, mult-of-128-or-whole-dim), so seq and head_dim must
@@ -371,13 +409,13 @@ def _flash_fwd(
         lambda ib, iq, ik, qs, ks: iq,
         lambda ib, iq, ik, qs, ks: _kv_block(ib, iq, ik, qs),
     )
-    inputs = (q_sched, k_sched, qt, kt, vt, qp, kp)
+    inputs = (q_sched, k_sched, qt, kt, vt, *mask)
     out, lse = pl.pallas_call(
         partial(_fwd_kernel, scale=scale, nk=nk),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, h, nq, nk),
-            in_specs=[spec["q"], spec["kv"], spec["kv"], spec["qp"], spec["kp"]],
+            in_specs=[spec[kind] for kind in ("q", "kv", "kv", *mask_kinds)],
             out_specs=[spec["q"], spec["col"]],
             scratch_shapes=[
                 pltpu.VMEM((block_q, d), jnp.float32),
@@ -414,9 +452,8 @@ _MAX_VMEM_BYTES = 64 * 2**20
 
 def _bwd_kernel(
     qs_ref, ks_ref,
-    q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, qp_ref, kp_ref,
-    dq_ref, dk_ref, dv_ref, dq_acc_ref, dk_acc_ref, dv_acc_ref,
-    *, scale: float, nk: int, nqc: int, block_q: int,
+    q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *rest,
+    scale: float, nk: int, nqc: int, block_q: int,
 ):
     """One step of the backward: grid (b, h, q chunk, nk, nqc), the chunk's
     q blocks innermost. A needed (q block, KV block) pair recomputes its
@@ -427,9 +464,12 @@ def _bwd_kernel(
     outside, so every output block is written exactly once. dq accumulates
     across the KV blocks in a scratch that holds the whole chunk's rows, a
     step adding into its q block's; the dq output block is the chunk's too,
-    so it stays in VMEM for the chunk and goes out once, cast."""
+    so it stays in VMEM for the chunk and goes out once, cast. ``rest``: the
+    mask operands (:func:`_allowed`), then the three outputs and the three
+    accumulators."""
     from jax.experimental import pallas as pl
 
+    *mask_refs, dq_ref, dk_ref, dv_ref, dq_acc_ref, dk_acc_ref, dv_acc_ref = rest
     ib, ic, ik, jq = (pl.program_id(axis) for axis in (0, 2, 3, 4))
     iq = ic * nqc + jq
     rows = pl.ds(pl.multiple_of(jq * block_q, block_q), block_q)
@@ -457,7 +497,7 @@ def _bwd_kernel(
         if masked:
             # p from the saved lse; masked entries exactly 0 (also kills
             # padded q rows, whose position is -1 — below every key).
-            p = jnp.where(qp_ref[...] >= kp_ref[...], p, 0.0)
+            p = jnp.where(_allowed(mask_refs), p, 0.0)
         do = do_ref[...]
         dv_acc_ref[...] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -477,7 +517,7 @@ def _bwd_kernel(
             preferred_element_type=jnp.float32,
         )  # (block_q, d)
 
-    _when_needed(qs_ref, ks_ref, ib, iq, ik, _update)
+    _when_needed(qs_ref, ks_ref, ib, iq, ik, _update, mask_refs)
 
     @pl.when(jq == nqc - 1)
     def _finalize_dkv():
@@ -489,10 +529,14 @@ def _bwd_kernel(
         dq_ref[rows, :] = dq_acc_ref[rows, :].astype(dq_ref.dtype)
 
 
-def _bwd_vmem_bytes(q_rows, block_q, block_k, d, in_bytes, out_bytes):
+def _bwd_vmem_bytes(
+    q_rows, block_q, block_k, d, in_bytes, out_bytes, selected=False
+):
     """VMEM the backward call needs with ``q_rows`` rows of dq resident, from
     its shapes: every pipelined block twice (a (block_q, 1) column and the
-    (1, block_k) row pad to whole tiles), the three accumulators, and what
+    (1, block_k) row pad to whole tiles; ``selected``: the int8 block of a
+    selection rides in place of the two position blocks), the three
+    accumulators, and what
     Mosaic keeps of a pair's (block_q, block_k) probabilities outside the
     registers. That last term is a bound, not a derivation: the v5e's
     compiler reports what a call used (``used_scoped_memory_configs`` in the
@@ -502,11 +546,12 @@ def _bwd_vmem_bytes(q_rows, block_q, block_k, d, in_bytes, out_bytes):
     8192 rows in 512 x 1024); tests/test_tpu_aot_compile.py holds the sum
     over the compiler's account at the shapes it compiles."""
     width = _next_multiple(d, 128)
+    mask = block_q * block_k if selected else block_q * 128 * 4 + 8 * block_k * 4
     blocks = (
         2 * block_q * width * in_bytes  # q, dO
         + 2 * block_k * width * in_bytes  # k, v
-        + 3 * block_q * 128 * 4  # lse, delta, qp
-        + 8 * block_k * 4  # kp
+        + 2 * block_q * 128 * 4  # lse, delta
+        + mask  # the selection's block, or qp and kp
         + 2 * block_k * width * out_bytes  # dk, dv
         + q_rows * width * out_bytes  # dq
     )
@@ -536,6 +581,7 @@ def flash_attention_partial_bwd(
     delta=None,
     out_dtype=None,
     vmem_bytes=_MAX_VMEM_BYTES,
+    selection=None,
 ):
     """Fused Pallas backward PARTIAL over an arbitrary KV block: the ring
     backward building block (and, with arange positions, the full causal
@@ -558,7 +604,10 @@ def flash_attention_partial_bwd(
     all the q rows of a head where the call then fits ``vmem_bytes``; a
     longer sequence is walked in equal chunks of as many q blocks as fit,
     each chunk one more set of dk/dv partials for the group sum. The choice
-    is by shape alone; the argument is for tests, which make it small."""
+    is by shape alone; the argument is for tests, which make it small.
+
+    ``selection`` (b, sq, sk) int8: what the forward was given
+    (:func:`flash_attention`); a needed pair then masks by its block of it."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -566,7 +615,8 @@ def flash_attention_partial_bwd(
     sk = k.shape[1]
     kv_heads = k.shape[2]
     group = h // kv_heads
-    block_q, block_k = _block_sizes(block_q, block_k, sq, sk)
+    selected = selection is not None
+    block_q, block_k = _block_sizes(block_q, block_k, sq, sk, selected)
     if out_dtype is None:
         out_dtype = jnp.float32
 
@@ -576,7 +626,10 @@ def flash_attention_partial_bwd(
             d_out.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
         )  # (b, sq, h)
 
-    tile = (block_q, block_k, d, q.dtype.itemsize, jnp.dtype(out_dtype).itemsize)
+    tile = (
+        block_q, block_k, d, q.dtype.itemsize, jnp.dtype(out_dtype).itemsize,
+        selected,
+    )
     nc, nqc = _q_chunks(sq, vmem_bytes, *tile)
     q_rows = nqc * block_q
     need = _bwd_vmem_bytes(q_rows, *tile)
@@ -595,8 +648,7 @@ def flash_attention_partial_bwd(
         q_positions, k_positions, b, sq, sk, q_rows, block_k
     )
     q_sched, k_sched = _block_schedule(qp, kp, block_q, block_k, interpret)
-    qp = qp.reshape(b, sq + pad_q, 1)
-    kp = kp.reshape(b, 1, sk + pad_k)
+    mask, mask_kinds = _mask_operands(selection, qp, kp, pad_q, pad_k)
     # Same heads-major transposition as _flash_fwd (see comment there): the
     # kernel sees (b, h, seq, d) / (b, h, seq, 1) so seq and d are the block
     # minor dims Mosaic requires.
@@ -606,7 +658,7 @@ def flash_attention_partial_bwd(
     dot = d_out.transpose(0, 2, 1, 3)  # (b, h, sq_p, d)
     lse_col = lse.reshape(b, sq + pad_q, h, 1).transpose(0, 2, 1, 3)
     delta_col = delta.reshape(b, sq + pad_q, h, 1).transpose(0, 2, 1, 3)
-    inputs = (q_sched, k_sched, qt, kt, vt, dot, lse_col, delta_col, qp, kp)
+    inputs = (q_sched, k_sched, qt, kt, vt, dot, lse_col, delta_col, *mask)
 
     # The chunk's q blocks innermost, so the dk/dv accumulators persist
     # across them; the q index starts at the KV block's edge and stays in
@@ -631,10 +683,11 @@ def flash_attention_partial_bwd(
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, h, nc, nk, nqc),
-            # q, k, v, dO, lse, delta, qp, kp by kind of spec (_block_specs).
+            # q, k, v, dO, lse, delta and the mask by kind of spec
+            # (_block_specs).
             in_specs=[
                 spec[kind]
-                for kind in ("q", "kv", "kv", "q", "col", "col", "qp", "kp")
+                for kind in ("q", "kv", "kv", "q", "col", "col", *mask_kinds)
             ],
             out_specs=[dq_out, dkv_out, dkv_out],
             scratch_shapes=[
@@ -671,44 +724,57 @@ def flash_attention_partial_bwd(
     return dq, dk, dv
 
 
-def _flash_bwd(q, k, v, out, lse, d_out, scale, block_q, block_k, interpret):
+def _flash_bwd(
+    q, k, v, selection, out, lse, d_out, scale, block_q, block_k, interpret
+):
     """Full-causal fused backward: the partial backward with arange
     positions and a single all-KV block set."""
     dq, dk, dv = flash_attention_partial_bwd(
         q, k, v, d_out, out, lse, None, None,
         scale, block_q, block_k, interpret,
         out_dtype=q.dtype,  # no cross-call accumulation: cast in VMEM
+        selection=selection,
     )
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_core(q, k, v, scale, block_q, block_k, interpret, pallas_bwd):
-    return _flash_fwd(q, k, v, scale, block_q, block_k, interpret)[0]
+@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_core(
+    q, k, v, selection, scale, block_q, block_k, interpret, pallas_bwd
+):
+    return _flash_fwd(
+        q, k, v, scale, block_q, block_k, interpret, selection=selection
+    )[0]
 
 
-def _flash_core_fwd(q, k, v, scale, block_q, block_k, interpret, pallas_bwd):
-    out, lse = _flash_fwd(q, k, v, scale, block_q, block_k, interpret)
+def _flash_core_fwd(
+    q, k, v, selection, scale, block_q, block_k, interpret, pallas_bwd
+):
+    out, lse = _flash_fwd(
+        q, k, v, scale, block_q, block_k, interpret, selection=selection
+    )
     out = checkpoint_name(out, FLASH_OUT)
     lse = checkpoint_name(lse, FLASH_LSE)
-    return out, (q, k, v, out, lse)
+    return out, (q, k, v, selection, out, lse)
 
 
 def _flash_core_bwd(
     scale, block_q, block_k, interpret, pallas_bwd, residuals, d_out
 ):
-    q, k, v, out, lse = residuals
+    q, k, v, selection, out, lse = residuals
     if pallas_bwd:
         b, s, h, d = q.shape
         # Residual lse is (b, s, kv, group); the kernels index it per
-        # q-head h = kvh * group + g — the exact inverse reshape.
-        return _flash_bwd(
-            q, k, v, out, lse.reshape(b, s, h), d_out,
+        # q-head h = kvh * group + g — the exact inverse reshape. The
+        # selection is no function of q, k or v here: no cotangent.
+        return *_flash_bwd(
+            q, k, v, selection, out, lse.reshape(b, s, h), d_out,
             scale, block_q, block_k, interpret,
-        )
+        ), None
     # Scan-based flash backward (recompute per KV block from the saved
-    # logsumexp) — shared with blockwise_attention; the CPU/fallback path.
-    return _blockwise_core_bwd(scale, block_k, residuals, d_out)
+    # logsumexp) — shared with blockwise_attention; the CPU/fallback path,
+    # which knows no selection (flash_attention never sends it one).
+    return *_blockwise_core_bwd(scale, block_k, (q, k, v, out, lse), d_out), None
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
@@ -777,6 +843,7 @@ def flash_attention(
     block_k: int = 1024,
     interpret: Optional[bool] = None,
     use_pallas_bwd: Optional[bool] = None,
+    selection: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Fused causal GQA attention on one device: Pallas forward AND
     FlashAttention-2-style Pallas backward (one kernel that recomputes the
@@ -797,6 +864,16 @@ def flash_attention(
     forward compiles (on TPU); CPU tests pass True to exercise the
     backward kernel in interpret mode, and False forces the scan-based
     blockwise fallback.
+
+    ``selection`` (b, s, s) int8, nonzero where query t attends to key s,
+    one selection for all heads and never a key later than its query
+    (ops/sparse_attention.py ``select_keys`` makes it): with it, every block
+    pair the causal schedule needs masks by its (block_q, block_k) block of
+    the operand in place of the position compare, in the forward and in the
+    one backward call, which is then always the Pallas one. A row with no
+    selected key comes out zero. The operand gets no gradient. Without it the
+    kernels are the calls they are without this argument, operand for
+    operand.
     """
     b, s, h, d = q.shape
     kv_heads = k.shape[2]
@@ -809,11 +886,20 @@ def flash_attention(
         # helpers: only the default device's platform says whether Mosaic
         # can compile the kernel.
         interpret = not on_tpu()
+    if selection is not None:
+        if selection.shape != (b, s, k.shape[1]):
+            raise ValueError(
+                f"selection {selection.shape} for q {q.shape} and k {k.shape}"
+            )
+        if use_pallas_bwd is False:
+            raise ValueError("the scan-based backward takes no selection")
+        selection = selection.astype(jnp.int8)
+        use_pallas_bwd = True
     if use_pallas_bwd is None:
         use_pallas_bwd = not interpret
-    block_q, block_k = _block_sizes(block_q, block_k, s, s)
+    block_q, block_k = _block_sizes(block_q, block_k, s, s, selection is not None)
     return _flash_core(
-        q, k, v, float(scale), block_q, block_k, bool(interpret),
+        q, k, v, selection, float(scale), block_q, block_k, bool(interpret),
         bool(use_pallas_bwd),
     )
 
@@ -822,16 +908,18 @@ def _next_multiple(n: int, m: int) -> int:
     return ((n + m - 1) // m) * m
 
 
-def _block_sizes(block_q, block_k, sq, sk):
+def _block_sizes(block_q, block_k, sq, sk, selected=False):
     """The block sizes every entry point runs: block_q aligned to 16 — the
-    bf16 sublane tile (and a multiple of f32's 8); block_k to 128 — the LANE
-    tile, because the kp position row rides as a (1, block_k) block whose
-    last dim must be a 128-multiple or the whole padded dim; then oversized
-    blocks clamped to the padded sequence. A ragged block would pass
-    interpret-mode tests and fail Mosaic lowering on the chip
+    bf16 sublane tile (and a multiple of f32's 8), or to 32, the int8 one,
+    where the call has a selection, whose block has block_q rows; block_k to
+    128 — the LANE tile, because the kp position row rides as a (1, block_k)
+    block whose last dim must be a 128-multiple or the whole padded dim; then
+    oversized blocks clamped to the padded sequence. A ragged block would
+    pass interpret-mode tests and fail Mosaic lowering on the chip
     (tests/test_mosaic_lowering.py pins this)."""
+    rows = 32 if selected else 16
     return (
-        min(_next_multiple(int(block_q), 16), _next_multiple(sq, 16)),
+        min(_next_multiple(int(block_q), rows), _next_multiple(sq, rows)),
         min(_next_multiple(int(block_k), 128), _next_multiple(sk, 128)),
     )
 
@@ -856,7 +944,9 @@ def verify_on_chip() -> dict:
 
         python -c "from torchft_tpu.ops.flash_attention import verify_on_chip; print(verify_on_chip())"
 
-    Returns the largest error of each case; under ``classes``, how many
+    The last case runs both kernels under a selection
+    (``flash_attention(..., selection=...)``). Returns the largest error of
+    each case; under ``classes``, how many
     block pairs of the case the schedule classed above, on and under the
     diagonal: how often the scheduling engaged; and under ``bwd_q_chunks``
     the path each case's backward calls took: 1 is dq resident in VMEM for
@@ -928,6 +1018,19 @@ def verify_on_chip() -> dict:
         q, k, v = qkv(sq, sq)
         return errors(q, k, v, dense(q, k, v), grads(dense, q, k, v))
 
+    def dense_under(mask, q, k, v):
+        """Attention in float32 under a (b, sq, sk) bool mask, one mask for
+        all heads; a row that sees nothing comes out zero."""
+        sq = q.shape[1]
+        qg = q.astype(jnp.float32).reshape(b, sq, kv, h // kv, d)
+        sc = jnp.einsum("bskgd,btkd->bskgt", qg, k.astype(jnp.float32)) * scale
+        mask = mask[:, :, None, None, :]
+        pr = jax.nn.softmax(jnp.where(mask, sc, _NEG_INF), axis=-1)
+        pr = jnp.where(mask.any(axis=-1, keepdims=True), pr, 0.0)
+        return jnp.einsum(
+            "bskgt,btkd->bskgd", pr, v.astype(jnp.float32)
+        ).reshape(b, sq, h, d)
+
     @partial(jax.jit, static_argnums=(3, 4, 5))
     def hop_errors(q, qp, shards, block_q, block_k, vmem_bytes):
         merged = lse = None
@@ -950,21 +1053,12 @@ def verify_on_chip() -> dict:
             )
             dq, dks, dvs = dq + dq_p, dks + [dk], dvs + [dv]
 
-        def dense(q, k, v):
-            kp = jnp.concatenate([kp for _, _, kp in shards], axis=1)
-            sq = q.shape[1]
-            qg = q.astype(jnp.float32).reshape(b, sq, kv, h // kv, d)
-            sc = jnp.einsum("bskgd,btkd->bskgt", qg, k.astype(jnp.float32)) * scale
-            mask = qp[:, :, None, None, None] >= kp[:, None, None, None, :]
-            pr = jax.nn.softmax(jnp.where(mask, sc, _NEG_INF), axis=-1)
-            pr = jnp.where(mask.any(axis=-1, keepdims=True), pr, 0.0)
-            return jnp.einsum(
-                "bskgt,btkd->bskgd", pr, v.astype(jnp.float32)
-            ).reshape(b, sq, h, d)
-
+        kp_all = jnp.concatenate([kp for _, _, kp in shards], axis=1)
         k_all = jnp.concatenate([k for k, _, _ in shards], axis=1)
         v_all = jnp.concatenate([v for _, v, _ in shards], axis=1)
-        ref, vjp = jax.vjp(dense, q, k_all, v_all)
+        ref, vjp = jax.vjp(
+            partial(dense_under, qp[:, :, None] >= kp_all[:, None, :]), q, k_all, v_all
+        )
         return worst([merged], [ref]), worst(
             [dq, jnp.concatenate(dks, axis=1), jnp.concatenate(dvs, axis=1)],
             vjp(d_out.astype(ref.dtype)),
@@ -1048,6 +1142,38 @@ def verify_on_chip() -> dict:
     err_r, err_rb = full(600, 128, 256, "ragged")
     err_r = check("RAGGED", err_r, 0.05)
     err_rb = check("RAGGED BACKWARD", err_rb, 0.25)
+
+    # Under a selection, at the default blocks (what models/keye.py runs):
+    # 256 keys a query of 2048, by ops/sparse_attention.py's select_topk on
+    # random scores; every other row prefers its latest keys, so from row
+    # 1280 on those rows have no key in the first KV block (their running
+    # max leaves the sentinel only in the second).
+    from torchft_tpu.ops.sparse_attention import select_topk
+
+    ss, topk = 2048, 256
+    at = jnp.arange(ss)
+    near = (at[:, None] - at[None, :] < topk) & (at[:, None] % 2 == 1)
+    index = jax.random.normal(jax.random.PRNGKey(5), (b, ss, ss)) + 16.0 * near
+    causal = jnp.broadcast_to(at[:, None] >= at[None, :], (b, ss, ss))
+    selection = select_topk(index, causal, topk).astype(jnp.int8)
+    classes["selected"] = _class_counts(ss, ss, 512, 1024)
+
+    @jax.jit
+    def selected_errors(q, k, v, selection, d_out):
+        got, vjp = jax.vjp(
+            lambda q, k, v: flash_attention(
+                q, k, v, interpret=False, selection=selection
+            ),
+            q, k, v,
+        )
+        ref, ref_vjp = jax.vjp(partial(dense_under, selection != 0), q, k, v)
+        return worst([got], [ref]), worst(vjp(d_out.astype(got.dtype)), ref_vjp(d_out))
+
+    q, k, v = qkv(ss, ss, seed=21)
+    d_out = jax.random.normal(jax.random.PRNGKey(22), q.shape, jnp.float32)
+    err_s, err_sb = selected_errors(q, k, v, selection, d_out)
+    err_s = check("SELECTED", err_s, 0.05)
+    err_sb = check("SELECTED BACKWARD", err_sb, 0.25)
     return {
         "device": str(dev),
         "max_err": err,
@@ -1059,6 +1185,8 @@ def verify_on_chip() -> dict:
         "max_err_chunked_bwd": err_cb,
         "max_err_ragged": err_r,
         "max_err_ragged_bwd": err_rb,
+        "max_err_selected": err_s,
+        "max_err_selected_bwd": err_sb,
         "classes": classes,
         "bwd_q_chunks": chunks,
         "ok": True,

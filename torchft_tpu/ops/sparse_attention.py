@@ -1,4 +1,5 @@
-"""Attention under a learned key selection, in plain tiled XLA.
+"""Attention under a learned key selection: the selection itself, and the
+attention in plain tiled XLA.
 
 Each query attends to the ``topk`` earlier keys an indexer scores highest
 (all of them while it has no more than ``topk``), one selection a query and
@@ -13,15 +14,24 @@ matmul precision whatever the model's dtype: a key that flips in or out of
 ``S_t`` is a discontinuity of the output and not a rounding of it. The
 selection carries no gradient (top-k is piecewise constant).
 
-The path, which is also the CPU path and the oracle of any kernel to come:
-queries in tiles of ``block``; a tile scores itself against the keys up to
-its group's last position, finds each row's threshold by a radix select over
-the scores' bits (32 counting passes, exact, no sort), and attends under the
-mask. A tile is one ``jax.checkpoint``: the backward recomputes its scores and
-selection and keeps nothing of a tile but its inputs. Tiles run one at a time
-(``lax.map``), in ``KEY_GROUPS`` groups that share a key length, so that a
-tile early in the sequence does not pay for the keys after it: with 4 groups
-the pairs computed are 1.18 times the causal half, with 1 group twice.
+Two paths, and models/keye.py says which runs where. Both walk the queries in
+tiles of ``block``, one at a time (``lax.map``), in ``KEY_GROUPS`` groups that
+share a key length, so that a tile early in the sequence does not pay for the
+keys after it (with 4 groups the pairs computed are 1.18 times the causal
+half, with 1 group twice); a tile scores itself against the keys up to its
+group's last position and finds each row's threshold by a radix select over
+the scores' bits (32 counting passes, exact, no sort).
+
+- ON THE CHIP the selection is an operand: :func:`select_keys` makes it once
+  a layer step as one (b, s, s) int8 array, named ``SELECTION`` for a remat
+  policy to keep, and ops/flash_attention.py's forward and its one backward
+  call read it block by block (``flash_attention(..., selection=...)``):
+  the attention scores never leave VMEM and the backward makes no selection
+  again.
+- ELSEWHERE, and as the oracle of the kernels' tests, :func:`sparse_attention`:
+  a tile selects and then attends under the mask in plain XLA. A tile is one
+  ``jax.checkpoint``: the backward recomputes its scores and selection and
+  keeps nothing of a tile but its inputs.
 """
 
 from __future__ import annotations
@@ -31,8 +41,14 @@ from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
-__all__ = ["index_scores", "select_topk", "sparse_attention"]
+__all__ = ["SELECTION", "index_scores", "select_keys", "select_topk", "sparse_attention"]
+
+# checkpoint_name tag of the selection :func:`select_keys` returns: kept by
+# name (models/keye.py ``_remat_policy``), a layer's backward reads the
+# operand its forward read and selects nothing again.
+SELECTION = "key_selection"
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 # Key lengths the tiles of a sequence share (fewer where it has fewer tiles).
@@ -74,17 +90,24 @@ def select_topk(scores: jnp.ndarray, allowed: jnp.ndarray, topk: int) -> jnp.nda
     return allowed & (above | (tied & (jnp.cumsum(tied, axis=-1) <= room[..., None])))
 
 
+def _tile_selection(first, qi, w, ki, topk: int) -> jnp.ndarray:
+    """(b, t, s) bool: the keys of ``0 .. s - 1`` that each query of one
+    tile, positions ``first .. first + t - 1``, selects."""
+    b, t, s = qi.shape[0], qi.shape[1], ki.shape[1]
+    at = first + jnp.arange(t)
+    causal = jnp.broadcast_to(at[:, None] >= jnp.arange(s)[None, :], (b, t, s))
+    with jax.named_scope("tpuft::indexer"):
+        return select_topk(index_scores(qi, ki, w), causal, topk)
+
+
 def _tile(first, q, qi, w, k, v, ki, *, scale: float, topk: int, with_selection: bool):
     """One tile of queries, positions ``first .. first + t - 1``, against the
     keys ``0 .. s - 1``. q (b, t, h, d); k, v (b, s, kv, d). Returns the
     attention output (b, t, h, d) and, where asked for, the selection
     (b, t, s)."""
     b, t, h, d = q.shape
-    s, kv = k.shape[1], k.shape[2]
-    at = first + jnp.arange(t)
-    causal = jnp.broadcast_to(at[:, None] >= jnp.arange(s)[None, :], (b, t, s))
-    with jax.named_scope("tpuft::indexer"):
-        chosen = select_topk(index_scores(qi, ki, w), causal, topk)
+    kv = k.shape[2]
+    chosen = _tile_selection(first, qi, w, ki, topk)
     with jax.named_scope("tpuft::sparse_attention"):
         grouped = q.reshape(b, t, kv, h // kv, d)
         scores = jnp.einsum("btkgd,bskd->bkgts", grouped, k).astype(jnp.float32) * scale
@@ -102,6 +125,49 @@ def _tile_groups(tiles: int) -> List[Tuple[int, int]]:
     return [(lo, min(lo + per_group, tiles)) for lo in range(0, tiles, per_group)]
 
 
+def _tiled(x: jnp.ndarray, tiles: int) -> jnp.ndarray:
+    """(b, tiles * block, ...) -> (tiles, b, block, ...)."""
+    b = x.shape[0]
+    return jnp.moveaxis(x.reshape(b, tiles, -1, *x.shape[2:]), 1, 0)
+
+
+def _untiled(xs: List[jnp.ndarray]) -> jnp.ndarray:
+    """The groups' (tiles, b, block, ...) -> (b, all their tiles * block, ...)."""
+    x = jnp.moveaxis(jnp.concatenate(xs), 0, 1)
+    return x.reshape(x.shape[0], -1, *x.shape[3:])
+
+
+def _indexer_inputs(s: int, block: int, *xs: jnp.ndarray):
+    """The tile (``block``, or the sequence where that is shorter), how many
+    there are, and the indexer's arrays in float32 with no gradient."""
+    block = min(block, s)
+    if s % block:
+        raise ValueError(f"a sequence of {s} positions is not whole tiles of {block}")
+    return block, s // block, *(jax.lax.stop_gradient(x.astype(jnp.float32)) for x in xs)
+
+
+def select_keys(
+    qi: jnp.ndarray, ki: jnp.ndarray, w: jnp.ndarray, *, topk: int, block: int = 512
+) -> jnp.ndarray:
+    """The indexer's qi (b, s, j, e), ki (b, s, e), w (b, s, j) -> the
+    selection as an array, (b, s, s) int8: 1 where query t attends to key s,
+    never a later key. The arithmetic is :func:`sparse_attention`'s own, tile
+    by tile; nothing is checkpointed (no gradient passes: the inputs stop it),
+    and the result carries the name ``SELECTION``."""
+    s = qi.shape[1]
+    block, tiles, qi, ki, w = _indexer_inputs(s, block, qi, ki, w)
+    rows = (jnp.arange(0, s, block), _tiled(qi, tiles), _tiled(w, tiles))
+    chosen = []
+    for lo, hi in _tile_groups(tiles):
+        keys = ki[:, : hi * block]  # no tile of this group sees a later key
+        sel = jax.lax.map(
+            lambda args: _tile_selection(*args, keys, topk).astype(jnp.int8),
+            tuple(x[lo:hi] for x in rows),
+        )
+        chosen.append(jnp.pad(sel, ((0, 0), (0, 0), (0, 0), (0, s - hi * block))))
+    return checkpoint_name(_untiled(chosen), SELECTION)
+
+
 def sparse_attention(
     q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     qi: jnp.ndarray, ki: jnp.ndarray, w: jnp.ndarray,
@@ -111,14 +177,9 @@ def sparse_attention(
     qi (b, s, j, e), ki (b, s, e), w (b, s, j) in float32. Returns (out
     (b, s, h, d), selection (b, s, s) bool or None). ``qi``, ``ki`` and ``w``
     get no gradient."""
-    b, s, h, d = q.shape
-    block = min(block, s)
-    if s % block:
-        raise ValueError(f"a sequence of {s} positions is not whole tiles of {block}")
-    qi, ki, w = (jax.lax.stop_gradient(x.astype(jnp.float32)) for x in (qi, ki, w))
-    tiles = s // block
-    tiled = lambda x: jnp.moveaxis(x.reshape(b, tiles, block, *x.shape[2:]), 1, 0)
-    rows = (jnp.arange(0, s, block), tiled(q), tiled(qi), tiled(w))
+    s = q.shape[1]
+    block, tiles, qi, ki, w = _indexer_inputs(s, block, qi, ki, w)
+    rows = (jnp.arange(0, s, block), *(_tiled(x, tiles) for x in (q, qi, w)))
     outs, chosen = [], []
     for lo, hi in _tile_groups(tiles):
         width = hi * block  # no tile of this group sees a later key
@@ -133,5 +194,4 @@ def sparse_attention(
         outs.append(out)
         if return_selection:
             chosen.append(jnp.pad(sel, ((0, 0), (0, 0), (0, 0), (0, s - width))))
-    untile = lambda xs: jnp.moveaxis(jnp.concatenate(xs), 0, 1).reshape(b, s, *xs[0].shape[3:])
-    return untile(outs), untile(chosen) if return_selection else None
+    return _untiled(outs), _untiled(chosen) if return_selection else None
