@@ -8,13 +8,18 @@
   differ because the hidden states differ by bf16 rounding and a key near a
   query's threshold then falls on the other side of it;
 - the rows each held expert receives (``models.keye.router_load``), so that the
-  cell's ``why`` can say how near uniform the routing of seeded weights is;
+  cell's ``why`` can say how near uniform the routing of seeded weights is,
+  and the row count each layer's expert dispatch runs at for them
+  (``models.keye.dispatch_rows``: a rung of ``ops.grouped_matmul.dispatch_rungs``);
+  with ``--steps 0 30 60`` both again after that many of the cell's own AdamW
+  steps on its own batches, and the layer steps by rung over the steps asked
+  for: the routing drifts as the cell trains on random tokens;
 - the control of ``reference_tolerance``: the float32 reference with every
   weight rounded to fp8 (e4m3, one scale a tensor: the precision below bf16),
   its two losses put through ``harness.reference_check`` as a run's are. It
   has to come out NOT correct; the script exits 1 where it does not.
 
-    python scripts/keye_selection_check.py SEED [SEED ...]        (needs a TPU)
+    python scripts/keye_selection_check.py SEED [SEED ...] [--steps N ...]   (needs a TPU)
     JAX_PLATFORMS=cpu python scripts/keye_selection_check.py --rehearse 7
 
 One JSON line a seed on stdout; PERF.md section 6 (PR 46) has the readings.
@@ -45,13 +50,56 @@ def fp8(tree):
     return jax.tree_util.tree_map(leaf, tree)
 
 
-def check(bench, config, traffic, seed: int) -> dict:
+def routing_of(system):
+    """(params, tokens) -> rows by held expert and the rung each layer's
+    dispatch takes for them; one compiled program for every call."""
+    import jax
+    import numpy as np
+
+    from torchft_tpu.models.keye import dispatch_rows, router_load
+
+    config = system.config
+    expected = system.tokens_per_step * config["num_experts_per_tok"] / config["num_experts"]
+    seen = jax.jit(lambda params, tokens: (
+        router_load(system.model, params, tokens), dispatch_rows(system.model, params, tokens)
+    ))
+
+    def routing(params, tokens) -> dict:
+        rows, taken = (np.asarray(a) for a in seen(params, tokens[:, :-1]))
+        return {
+            "expected": expected, "min": int(rows.min()), "max": int(rows.max()),
+            "mean": float(rows.mean()), "by_layer": rows.tolist(),
+            "held_rows_by_layer": rows.sum(axis=1).tolist(),
+            "dispatch_rows_by_layer": taken.tolist(),
+        }
+
+    return routing
+
+
+def routing_after(system, routing, params, steps) -> dict:
+    """``routing`` of batch N on the state N plain AdamW steps in (the cell's
+    optimizer on its own batches 0 .. N - 1), for each N of ``steps``; and the
+    count of those layer steps by the rung they take."""
+    from torchft_tpu.optim import make_jit_fused_step
+
+    step = make_jit_fused_step(system.tx, system.loss_fn)
+    opt_state, at, by_rung = system.tx.init(params), {}, {}
+    for n in range(max(steps) + 1):
+        if n in steps:
+            at[n] = routing(params, system.tokens(n))
+            for rung in at[n]["dispatch_rows_by_layer"]:
+                by_rung[rung] = by_rung.get(rung, 0) + 1
+        if n < max(steps):
+            _, params, opt_state = step(params, opt_state, system.tokens(n))
+    return {"at_step": at, "layer_steps_by_rung": dict(sorted(by_rung.items()))}
+
+
+def check(bench, config, traffic, seed: int, steps) -> dict:
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from chipbench.model import System
-    from torchft_tpu.models.keye import router_load
 
     architecture = bench.architecture(config["model_type"])
     system = System(config, architecture, traffic, seed)
@@ -78,14 +126,11 @@ def check(bench, config, traffic, seed: int) -> dict:
     out["pairs_selected_by_layer"] = np.asarray(selected).tolist()
     out["share_of_pairs_that_differ_by_layer"] = (np.asarray(only) / np.asarray(selected)).tolist()
 
-    rows = np.asarray(router_load(system.model, params, tokens[:, :-1]))
-    expected = system.tokens_per_step * config["num_experts_per_tok"] / config["num_experts"]
-    out["rows_by_held_expert"] = {
-        "expected": expected, "min": int(rows.min()), "max": int(rows.max()),
-        "mean": float(rows.mean()), "by_layer": rows.tolist(),
-    }
-
+    routing = routing_of(system)
+    out["rows_by_held_expert"] = routing(params, tokens)
     out["fp8_control"] = control(system, params)
+    if steps:
+        out["routing_after_steps"] = routing_after(system, routing, params, steps)
     return out
 
 
@@ -110,6 +155,10 @@ def control(system, params) -> dict:
 def main() -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("seeds", type=int, nargs="+")
+    parser.add_argument(
+        "--steps", type=int, nargs="+", default=[],
+        help="also the routing after each of these many AdamW steps of the cell",
+    )
     parser.add_argument("--rehearse", action="store_true", help="toy size, any platform")
     args = parser.parse_args()
 
@@ -129,7 +178,7 @@ def main() -> int:
     harness.enable_compile_cache()
     passed = 0
     for seed in args.seeds:
-        out = check(bench, config, traffic, seed)
+        out = check(bench, config, traffic, seed, sorted(set(args.steps)))
         print(json.dumps(out), flush=True)
         passed += not out["fp8_control"]["problems"]
     return 1 if passed else 0

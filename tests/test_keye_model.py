@@ -2,7 +2,8 @@
 size on seeded weights (CPU): the model against the benchmark's float32
 reference (chipbench/architectures/KeyeVL2.py, written from the equations),
 loss and every leaf's gradient; the expert layer's shares against the uncut
-layer; the selection against a sorted top-k; the grouped product against a loop
+layer, and every rung of its dispatch's ladder of row counts against the worst
+case; the selection against a sorted top-k; the grouped product against a loop
 over experts; a sliced vocabulary through the fused loss; and that a lower
 precision in the indexer or the experts is not within the small-size tolerance.
 
@@ -26,9 +27,12 @@ sys.path.insert(0, str(ROOT))
 
 from chipbench import reference, spec  # noqa: E402
 from torchft_tpu.models import keye  # noqa: E402
-from torchft_tpu.models.keye import ExpertLayer, Keye, KeyeConfig, router_load  # noqa: E402
+from torchft_tpu.models.keye import (  # noqa: E402
+    ExpertLayer, Keye, KeyeConfig, dispatch_rows, router_load,
+)
+from torchft_tpu.ops import grouped_matmul as grouped  # noqa: E402
 from torchft_tpu.ops.cross_entropy import chunked_cross_entropy  # noqa: E402
-from torchft_tpu.ops.grouped_matmul import grouped_matmul  # noqa: E402
+from torchft_tpu.ops.grouped_matmul import dispatch_rungs, grouped_matmul  # noqa: E402
 from torchft_tpu.ops import sparse_attention as tiled  # noqa: E402
 from torchft_tpu.ops.sparse_attention import select_topk, sparse_attention  # noqa: E402
 
@@ -329,6 +333,122 @@ def test_router_load_counts_the_rows_of_each_held_expert(toy):
     expected = BATCH * SEQ * config["num_experts_per_tok"] / config["num_experts"]
     assert rows.sum() > 0 and abs(rows.mean() - expected) < expected  # near uniform, not equal
     assert rows.max() <= BATCH * SEQ  # a token chooses an expert once
+
+
+def test_dispatch_rows_is_the_smallest_rung_that_holds_each_layers_rows(toy):
+    config, model, params, tokens = toy
+    rungs = dispatch_rungs(
+        BATCH * SEQ, config["num_experts_per_tok"], config["num_local_experts"], config["num_experts"]
+    )
+    assert len(rungs) > 1  # the toy's share is a quarter: a ladder
+    taken = np.asarray(dispatch_rows(model, params, tokens[:, :-1]))
+    held = np.asarray(router_load(model, params, tokens[:, :-1])).sum(axis=1)
+    assert taken.shape == (config["num_hidden_layers"],)
+    assert [int(t) for t in taken] == [min(r for r in rungs if r >= h) for h in held]
+
+
+@pytest.mark.parametrize(
+    "n, k, local, experts, rungs",
+    [
+        (8192, 8, 16, 128, (16384, 32768, 65536)),  # the cell: E = 8192
+        (256, 4, 4, 32, (256, 512, 1024)),
+        (48, 4, 2, 16, (64, 128, 192)),  # E = 24: 48 and 96 up to the row tile of 192 rows, 64
+        (8192, 8, 32, 128, (32768, 65536)),  # 4E is the worst case
+        (8192, 8, 64, 128, (65536,)),  # half held: 2E is
+        (8192, 8, 128, 128, (65536,)),
+        (48, 4, 16, 16, (192,)),
+    ],
+)
+def test_the_rungs_are_twice_and_four_times_the_uniform_share_then_the_worst_case(
+    n, k, local, experts, rungs
+):
+    assert dispatch_rungs(n, k, local, experts) == rungs
+    assert (len(rungs) == 1) == (local * 2 >= experts)
+
+
+# A layer of 256 tokens x 4 choices that holds 4 of 32 experts: a uniform
+# router would send it E = 128 rows, and its rungs are 256, 512 and 1,024.
+LADDER = KeyeConfig(
+    dim=48, moe_hidden=24, num_experts=32, experts_per_token=4, num_local_experts=4,
+    dtype=jnp.float32, n_heads=2, n_kv_heads=1, head_dim=16,
+)
+LADDER_TOKENS = 256
+
+
+def steered_layer(held_rows: int):
+    """(params, x) of an ``ExpertLayer(LADDER)`` whose router sends exactly
+    ``held_rows`` of the 1,024 choices to held experts: the router reads a
+    token's logits off its first 32 features (an identity block over a little
+    noise), and x carries, for each token, high scores for as many held
+    experts as its part of ``held_rows`` and for experts held elsewhere for
+    the rest of its four choices."""
+    n, k, local, experts = LADDER_TOKENS, 4, 4, 32
+    rng = np.random.default_rng(held_rows)
+    held_of = np.full(n, held_rows // n) + (np.arange(n) < held_rows % n)
+    logits = rng.uniform(-1.0, 0.0, (n, experts)).astype(np.float32)
+    for t in range(n):
+        mine = rng.permutation(local)[: held_of[t]]
+        others = local + rng.permutation(experts - local)[: k - held_of[t]]
+        logits[t, np.concatenate([mine, others])] = rng.uniform(2.0, 3.0, k)
+    x = np.concatenate([logits, rng.normal(size=(n, 16)).astype(np.float32)], axis=1)
+    params = ExpertLayer(LADDER).init(jax.random.PRNGKey(1), jnp.asarray(x[None]))
+    kernel = np.concatenate([np.eye(experts), 0.01 * rng.normal(size=(16, experts))])
+    params["params"]["router"]["kernel"] = jnp.asarray(kernel, jnp.float32)
+    return params, jnp.asarray(x[None])
+
+
+@pytest.mark.parametrize("use_pallas", [False, True], ids=["ragged_dot", "megablox-interpreted"])
+@pytest.mark.parametrize(
+    "held_rows, rung",
+    [(0, 256), (128, 256), (256, 256), (257, 512), (513, 1024), (1024, 1024)],
+    ids=["no-row", "E", "2E", "2E+1", "4E+1", "every-choice"],
+)
+def test_every_rung_is_the_worst_case_path(held_rows, rung, use_pallas, monkeypatch):
+    """Output and the gradient of every leaf and of the input on the rung the
+    routing lands on, against the same layer with the worst case as its only
+    rung (no conditional, plain autodiff): the held rows and their order are
+    the same on both, so the arithmetic is, and so are the bits; but for the
+    expert weights' gradients through ``ragged_dot``, whose sum over a group's
+    rows the CPU blocks by the length of the buffer: float32 rounding."""
+    from functools import partial
+
+    monkeypatch.setattr(
+        grouped, "grouped_matmul", partial(grouped_matmul, use_pallas=use_pallas, interpret=True)
+    )
+    params, x = steered_layer(held_rows)
+    layer = ExpertLayer(LADDER)
+    _, seen = layer.apply(params, x, mutable=["intermediates"])
+    seen = seen["intermediates"]
+    assert int(seen["rows_by_expert"][0].sum()) == held_rows
+    assert int(seen["dispatch_rows"][0]) == rung
+
+    def loss(params, x):
+        return jnp.sum(jnp.sin(layer.apply(params, x)))
+
+    got = layer.apply(params, x), jax.grad(loss, argnums=(0, 1))(params, x)
+    monkeypatch.setattr(grouped, "dispatch_rungs", lambda n, k, local, experts: (n * k,))
+    # (megablox's own kernels hold conditionals)
+    assert use_pallas or "cond" not in str(jax.make_jaxpr(loss)(params, x))
+    want = layer.apply(params, x), jax.grad(loss, argnums=(0, 1))(params, x)
+    assert bool(jnp.any(want[0])) == bool(held_rows)
+    mine, theirs = (jax.tree_util.tree_leaves_with_path(side) for side in (got, want))
+    assert len(mine) == 6  # the output, the router, three expert weights, the input
+    for (path, a), (_, b) in zip(mine, theirs):
+        name = jax.tree_util.keystr(path)
+        if "['w_" in name and not use_pallas:
+            assert relative(a, b) < 1e-6, name
+        else:
+            assert np.array_equal(np.asarray(a), np.asarray(b)), name
+        assert held_rows == 0 or np.any(np.asarray(b)), name
+
+
+def test_the_uncut_layer_has_one_path_and_a_cut_one_a_conditional():
+    x = jnp.zeros((1, LADDER_TOKENS, LADDER.dim))
+    for cfg, conditional in ((LADDER, True), (replace(LADDER, num_local_experts=32), False)):
+        layer = ExpertLayer(cfg)
+        params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
+        program = str(jax.make_jaxpr(jax.grad(lambda p: jnp.sum(layer.apply(p, x))))(params))
+        assert ("cond" in program) is conditional
 
 
 def test_a_sliced_vocabulary_of_18992_goes_through_the_fused_loss():

@@ -654,3 +654,109 @@ def test_dots_step_of_the_keye_cell_selects_once_and_runs_one_flash_pair_a_layer
         lambda remat: jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
     )
     assert compiled() == (3, 2 * KEY_GROUPS, KEY_GROUPS)
+
+
+def _computations(text: str) -> dict:
+    """{computation: its instruction lines} of a compiled program's text."""
+    found, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            found[name] = []
+        elif name is not None and line.startswith("  "):
+            found[name].append(line)
+    return found
+
+
+def _reached_from(computations: dict, root: str) -> set:
+    """``root`` and every computation its instructions call, however deep."""
+    seen, todo = set(), [root]
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for line in computations[name]:
+            todo += [c for c in re.findall(r"%([\w.\-]+)", line.split(" = ", 1)[-1]) if c in computations]
+    return seen
+
+
+def test_dots_step_of_a_keye_share_keeps_every_worst_case_row_inside_the_last_rung(
+    chip, monkeypatch
+) -> None:
+    """The FT-DDP fused step of a small KeyeVL2 (two scanned layers, ``dots``,
+    2 of 16 experts held, 1,024 tokens of 8 choices: the expert dispatch's
+    rungs are 2,048, 4,096 and the worst case 8,192) compiled for a described
+    v5e. The program holds two conditionals, one in the forward's loop body
+    and one in the backward's (the forward that ``dots`` runs again needs no
+    product: its residuals are the layer's arguments), each over the three
+    rungs; every op whose result has the worst case's 8,192 rows and a feature
+    width lies inside a conditional's LAST branch; no conditional hands out an
+    array with a rung's row count (nothing a rung sizes crosses from forward
+    to backward); and a rung's layer step is 12 ``gmm`` / ``tgmm`` calls, 3 in
+    the forward's branch and 9 in the backward's, not 15."""
+    import json
+    from pathlib import Path
+
+    import torchft_tpu.models.keye as keye
+    import torchft_tpu.ops.flash_attention as flash
+    import torchft_tpu.ops.grouped_matmul as grouped
+    from chipbench import spec
+    from chipbench.model import System
+    from torchft_tpu.optim import make_jit_fused_step
+
+    for module in (keye, flash, grouped):
+        monkeypatch.setattr(module, "on_tpu", lambda: True)
+    root = Path(__file__).parent.parent
+    config = json.loads(
+        (root / "chipbench/configs/keye-vl2-30b-a3b-ep8-1chip.json").read_text()
+    )
+    config.update({**_KEYE_TOY, "num_experts_per_tok": 8})
+    seq, k, widths = 1024, 8, (config["hidden_size"], config["moe_intermediate_size"])
+    rungs = grouped.dispatch_rungs(seq, k, config["num_local_experts"], config["num_experts"])
+    assert rungs == (2048, 4096, 8192)
+    architecture = spec.load_module(root / "chipbench/architectures/KeyeVL2.py")
+    system = System(config, architecture, {"batch": 1, "seq": seq}, seed=0)
+    params = jax.eval_shape(system.init_params)
+    text = (
+        make_jit_fused_step(system.tx, system.loss_fn)
+        .lower(
+            _sds_tree(params, chip), _sds_tree(jax.eval_shape(system.tx.init, params), chip),
+            _sds((1, seq + 1), jnp.int32, chip),
+        )
+        .compile()
+        .as_text()
+    )
+    computations = _computations(text)
+    conditionals = [
+        line for lines in computations.values() for line in lines if " conditional(" in line
+    ]
+    assert len(conditionals) == 2
+    inside_last, expert_calls = set(), []
+    for line in conditionals:
+        result = line.split(" = ", 1)[1].split(" conditional(")[0]
+        handed_out = {int(n) for dims in re.findall(r"\[([\d,]+)\]", result) for n in dims.split(",")}
+        assert not handed_out & set(rungs), result
+        branches = re.search(r"branch_computations=\{([^}]*)\}", line).group(1)
+        branches = [name.strip().lstrip("%") for name in branches.split(",")]
+        assert len(branches) == len(rungs)
+        reached = [_reached_from(computations, name) for name in branches]
+        inside_last |= reached[-1]
+        expert_calls.append([
+            sum(
+                'custom_call_target="tpu_custom_call"' in line
+                and bool(architecture.EXPERT_KERNEL.search(line.split(" = ")[0]))
+                for name in names for line in computations[name]
+            )
+            for names in reached
+        ])
+    assert sorted(expert_calls) == [[3, 3, 3], [9, 9, 9]]
+    worst_case = re.compile(r" = \S*\[%d,(?:%d|%d)\]" % (rungs[-1], *widths))
+    rows = [
+        (name, line) for name, lines in computations.items() for line in lines
+        if worst_case.search(line)
+    ]
+    assert rows and all(name in inside_last for name, _ in rows), [
+        line[:160] for name, line in rows if name not in inside_last
+    ]

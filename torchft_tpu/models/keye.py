@@ -20,11 +20,13 @@ Per layer, pre-norm:
   largest renormalised to one. The layer HOLDS ``num_local_experts`` of them,
   experts ``expert_share * num_local_experts ..``, and computes their part of
   the result: the rows routed here, sorted by expert, through one grouped
-  product a matrix (ops/grouped_matmul.py). Dropless: the row buffer is the
-  worst case, every token's every choice. What the absent experts would add
-  is left out and nothing stands in for their chips: on one chip the layer
-  runs without its exchange. ``num_local_experts == num_experts`` is the whole
-  layer.
+  product a matrix (ops/grouped_matmul.py ``routed_experts``). Dropless and
+  exact: the row buffer follows the rows that arrive, up a short ladder of
+  row counts chosen each layer step from the router's own tally, whose last
+  rung is the worst case, every token's every choice. What the absent
+  experts would add is left out and nothing stands in for their chips: on
+  one chip the layer runs without its exchange. ``num_local_experts ==
+  num_experts`` is the whole layer, one path at the worst case.
 
 Rotary and the output head with its fused loss are models/llama.py's, the
 norm is its arithmetic with the scale stored in ``norm_dtype`` (float32, as
@@ -32,7 +34,8 @@ there, unless a configuration says otherwise); the layer stack is scanned and
 rematerialised the same way (the ``dots`` policy keeps the projections'
 products, the flash kernel's output and logsumexp and the selection, not a
 tile's scores). ``router_load``
-counts the rows each held expert receives.
+counts the rows each held expert receives, ``dispatch_rows`` the row count
+each layer's dispatch ran at.
 """
 
 from __future__ import annotations
@@ -48,11 +51,11 @@ from jax.ad_checkpoint import checkpoint_name
 
 from torchft_tpu.models.llama import _LMHead, apply_rope
 from torchft_tpu.ops.flash_attention import FLASH_LSE, FLASH_OUT, flash_attention
-from torchft_tpu.ops.grouped_matmul import grouped_matmul
+from torchft_tpu.ops.grouped_matmul import routed_experts
 from torchft_tpu.ops.sparse_attention import SELECTION, select_keys, sparse_attention
 from torchft_tpu.utils.platform import on_tpu
 
-__all__ = ["KeyeConfig", "Keye", "router_load", "route"]
+__all__ = ["KeyeConfig", "Keye", "router_load", "dispatch_rows", "route"]
 
 
 @dataclass(frozen=True)
@@ -222,7 +225,7 @@ class ExpertLayer(nn.Module):
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
         cfg = self.config
         b, s, d = x.shape
-        n, k, local, f = b * s, cfg.experts_per_token, cfg.num_local_experts, cfg.moe_hidden
+        n, local, f = b * s, cfg.num_local_experts, cfg.moe_hidden
         axes = dict(in_axis=-2, out_axis=-1, batch_axis=0)
         init = nn.initializers.lecun_normal(**axes)
         w_gate = self.param("w_gate", init, (local, d, f), cfg.dtype)
@@ -236,15 +239,12 @@ class ExpertLayer(nn.Module):
             probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
             order, gates, group_sizes = route(probs, cfg)
             self.sow("intermediates", "rows_by_expert", group_sizes[:local])
-            rows = flat[order // k]  # (n * k, d), sorted by held expert
-            product = partial(grouped_matmul, group_sizes=group_sizes)
-            hidden = nn.silu(product(rows, w_gate)) * product(rows, w_up)
-            out = product(hidden, w_down)
-            # Back to (token, choice) order; the rows of experts held elsewhere
-            # came out zero, so each token sums its held experts' parts.
-            back = jnp.zeros((n * k,), jnp.int32).at[order].set(jnp.arange(n * k, dtype=jnp.int32))
-            out = out[back].reshape(n, k, d).astype(jnp.float32) * gates[..., None]
-            return jnp.sum(out, axis=1).astype(cfg.dtype).reshape(b, s, d)
+            out, rows = routed_experts(
+                flat, order, gates, group_sizes, w_gate, w_up, w_down,
+                num_experts=cfg.num_experts, activation=nn.silu,
+            )
+            self.sow("intermediates", "dispatch_rows", rows)
+            return out.astype(cfg.dtype).reshape(b, s, d)
 
 
 class Block(nn.Module):
@@ -330,14 +330,23 @@ class Keye(nn.Module):
         return head(x, targets) if targets is not None else head(x).astype(jnp.float32)
 
 
+def _sown_by_layer(model: Keye, params: Any, tokens: jnp.ndarray, name: str) -> jnp.ndarray:
+    _, seen = model.apply(params, tokens, mutable=["intermediates"])
+    seen = seen["intermediates"]
+    if model.config.scan_layers:
+        return seen["layers"]["block"]["moe"][name][0]
+    return jnp.stack([seen[f"layer_{i}"]["moe"][name][0] for i in range(model.config.n_layers)])
+
+
 def router_load(model: Keye, params: Any, tokens: jnp.ndarray) -> jnp.ndarray:
     """Rows each held expert receives for ``tokens`` (b, s), by layer:
     (n_layers, num_local_experts). Dropless, so they are all computed; their
     expectation is ``b * s * experts_per_token / num_experts`` each."""
-    _, seen = model.apply(params, tokens, mutable=["intermediates"])
-    seen = seen["intermediates"]
-    if model.config.scan_layers:
-        return seen["layers"]["block"]["moe"]["rows_by_expert"][0]
-    return jnp.stack([
-        seen[f"layer_{i}"]["moe"]["rows_by_expert"][0] for i in range(model.config.n_layers)
-    ])
+    return _sown_by_layer(model, params, tokens, "rows_by_expert")
+
+
+def dispatch_rows(model: Keye, params: Any, tokens: jnp.ndarray) -> jnp.ndarray:
+    """The row count each layer's expert dispatch runs at for ``tokens``
+    (b, s): (n_layers,), each a rung of ops/grouped_matmul.py
+    ``dispatch_rungs``, the smallest that holds the layer's held rows."""
+    return _sown_by_layer(model, params, tokens, "dispatch_rows")
