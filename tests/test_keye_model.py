@@ -3,7 +3,9 @@ size on seeded weights (CPU): the model against the benchmark's float32
 reference (chipbench/architectures/KeyeVL2.py, written from the equations),
 loss and every leaf's gradient; the expert layer's shares against the uncut
 layer, and every rung of its dispatch's ladder of row counts against the worst
-case; the selection against a sorted top-k; the grouped product against a loop
+case; the sum by token's kernel, interpreted, against the scatter-add it
+replaces, and the gradient through the dispatch against the parent's body; the
+selection against a sorted top-k; the grouped product against a loop
 over experts; a sliced vocabulary through the fused loss; and that a lower
 precision in the indexer or the experts is not within the small-size tolerance.
 
@@ -440,6 +442,174 @@ def test_every_rung_is_the_worst_case_path(held_rows, rung, use_pallas, monkeypa
         else:
             assert np.array_equal(np.asarray(a), np.asarray(b)), name
         assert held_rows == 0 or np.any(np.asarray(b)), name
+
+
+# 512 tokens of 8 choices over 128 experts, 8 held: a uniform router sends
+# E = 256 rows, the rungs are 512, 1,024 and 4,096, and the sum by token walks
+# 4 blocks of 128 tokens over 2, 4 and 16 tiles of 256 rows.
+SUM_TOKENS, SUM_CHOICES, SUM_HELD, SUM_EXPERTS, SUM_WIDTH = 512, 8, 8, 128, 64
+SUM_RUNGS = (512, 1024, 4096)
+
+
+def routed(routing: str):
+    """(order, gates, group_sizes) as ``keye.route`` gives them, for a routing
+    made by hand: which expert each of a token's eight choices names."""
+    n, k, local, experts = SUM_TOKENS, SUM_CHOICES, SUM_HELD, SUM_EXPERTS
+    rng = np.random.default_rng(len(routing))
+    elsewhere = lambda count: local + rng.permutation(experts - local)[:count]
+    chosen = np.stack([elsewhere(k) for _ in range(n)])  # no held row at all
+    if routing == "uniform":
+        chosen = np.stack([rng.permutation(experts)[:k] for _ in range(n)])
+    elif routing == "collapsed":  # every token's first two choices: held experts 0 and 1
+        chosen[:, :2] = [0, 1]
+    elif routing == "every-row":
+        chosen = np.stack([rng.permutation(local) for _ in range(n)])
+    elif routing == "off-tile":  # 300 held rows: not a multiple of the 256-row tile
+        chosen[:300, 0] = rng.integers(0, local, 300)
+    elif routing == "eight-of-a-token":  # token 77's eight choices all held, few others
+        chosen[77] = rng.permutation(local)
+        chosen[::5, 3] = 2
+    group = np.where(chosen < local, chosen, local).reshape(-1)
+    order = np.argsort(group, kind="stable").astype(np.int32)
+    gates = rng.uniform(0.05, 1.0, (n, k)).astype(np.float32)
+    gates /= gates.sum(axis=1, keepdims=True)
+    sizes = np.bincount(group, minlength=local + 1).astype(np.int32)
+    return jnp.asarray(order), jnp.asarray(gates), jnp.asarray(sizes)
+
+
+HELD_ROWS = {
+    "uniform": None, "collapsed": 1024, "none": 0, "every-row": 4096, "off-tile": 300,
+    "eight-of-a-token": 8 + 103,
+}
+SUM_CASES = [
+    (routing, rung) for routing, held in HELD_ROWS.items() for rung in SUM_RUNGS
+    if rung >= (held or 0)
+]
+
+
+@pytest.mark.parametrize("routing, rung", SUM_CASES, ids=[f"{r}-{c}" for r, c in SUM_CASES])
+def test_the_sum_by_token_kernel_is_the_scatter_add_it_replaces(routing, rung):
+    """The Mosaic kernel, interpreted, against ``.at[token].add`` in float32 on
+    the held rows ALONE: bf16 rows with float32 weights into float32 (the
+    forward's return to token order) and with unit weights into bf16 (the
+    transpose of the gather), and float32 rows. The rows past the held total,
+    which fill the rung, are zero as the grouped product leaves them, and add
+    nothing to the tokens they name."""
+    order, gates, sizes = routed(routing)
+    held = int(sizes[:SUM_HELD].sum())
+    assert HELD_ROWS[routing] in (None, held) and held <= rung
+    assert routing != "uniform" or 150 < held < 400
+    chosen = order[:rung]
+    token, weights = chosen // SUM_CHOICES, gates.reshape(-1)[chosen]
+    rows = jax.random.normal(jax.random.PRNGKey(rung), (rung, SUM_WIDTH))
+    rows = jnp.where(jnp.arange(rung)[:, None] < held, rows, 0.0)
+    if routing == "eight-of-a-token":
+        assert int(jnp.sum(token[:held] == 77)) == 8
+
+    def scatter_add(rows, weights):
+        return jnp.zeros((SUM_TOKENS, SUM_WIDTH), jnp.float32).at[token[:held]].add(
+            rows[:held].astype(jnp.float32) * weights[:held, None]
+        )
+
+    for dtype in (jnp.bfloat16, jnp.float32):
+        x = rows.astype(dtype)
+        got = grouped._token_sum_pallas(x, weights, token, SUM_TOKENS, jnp.float32, interpret=True)
+        want = scatter_add(x, weights)
+        assert got.dtype == jnp.float32 and bool(jnp.any(want)) == bool(held)
+        assert float(jnp.max(jnp.abs(got - want))) <= 2e-6 * max(1.0, float(jnp.max(jnp.abs(want))))
+        unit = grouped._token_sum_pallas(x, None, token, SUM_TOKENS, dtype, interpret=True)
+        want = scatter_add(x, jnp.ones_like(weights)).astype(dtype)
+        assert unit.dtype == dtype
+        assert float(jnp.max(jnp.abs((unit - want).astype(jnp.float32)))) <= 2e-6 * max(
+            1.0, float(jnp.max(jnp.abs(want)))
+        ) + (2.0**-7 * float(jnp.max(jnp.abs(want))) if dtype == jnp.bfloat16 else 0.0)
+    # The CPU path of the same function is the scatter-add itself.
+    assert np.array_equal(
+        np.asarray(grouped.sum_by_token(rows, weights, token, SUM_TOKENS)),
+        np.asarray(jnp.zeros((SUM_TOKENS, SUM_WIDTH)).at[token].add(rows * weights[:, None])),
+    )
+
+
+def interpret_the_sums(monkeypatch):
+    """Both sums by token in the Mosaic kernel, interpreted, on the CPU."""
+    from functools import partial
+
+    monkeypatch.setattr(grouped, "_token_sum", partial(grouped._token_sum_pallas, interpret=True))
+
+
+def parents_experts_at(rows, activation, flat, order, gates, group_sizes, w_gate, w_up, w_down):
+    """``_experts_at`` as PR 50 left it: the gather and the two scatter-adds
+    as plain XLA under plain autodiff. The oracle of the pair of functions."""
+    from functools import partial
+
+    n, k = gates.shape
+    chosen = order[:rows]
+    token = chosen // k
+    product = partial(grouped_matmul, group_sizes=group_sizes[: w_gate.shape[0]], use_pallas=False)
+    x = flat[token]
+    out = product(activation(product(x, w_gate)) * product(x, w_up), w_down)
+    weighted = out.astype(jnp.float32) * gates.reshape(-1)[chosen][:, None]
+    return jnp.zeros((n, flat.shape[1]), jnp.float32).at[token].add(weighted)
+
+
+@pytest.mark.parametrize("routing", ["uniform", "collapsed", "eight-of-a-token", "none"])
+def test_the_gradient_through_routed_experts_is_the_parents(routing, monkeypatch):
+    """Output and ``jax.grad`` of the rows, the gates and the three weights
+    through ``routed_experts`` with both sums in the interpreted kernel,
+    against the parent's body at the worst case under plain autodiff."""
+    interpret_the_sums(monkeypatch)
+    order, gates, sizes = routed(routing)
+    keys = jax.random.split(jax.random.PRNGKey(5), 5)
+    flat = jax.random.normal(keys[0], (SUM_TOKENS, SUM_WIDTH))
+    weights = [
+        jax.random.normal(key, shape) * shape[1] ** -0.5
+        for key, shape in zip(keys[1:], [(SUM_HELD, SUM_WIDTH, 32)] * 2 + [(SUM_HELD, 32, SUM_WIDTH)])
+    ]
+    cotangent = jax.random.normal(keys[4], (SUM_TOKENS, SUM_WIDTH))
+
+    def ladder(flat, gates, *weights):
+        out, rung = grouped.routed_experts(
+            flat, order, gates, sizes, *weights, num_experts=SUM_EXPERTS, activation=jax.nn.silu
+        )
+        return jnp.sum(out * cotangent), (out, rung)
+
+    def parent(flat, gates, *weights):
+        out = parents_experts_at(
+            SUM_TOKENS * SUM_CHOICES, jax.nn.silu, flat, order, gates, sizes, *weights
+        )
+        return jnp.sum(out * cotangent), (out, None)
+
+    argnums = (0, 1, 2, 3, 4)
+    (_, (got, rung)), d_got = jax.value_and_grad(ladder, argnums, has_aux=True)(flat, gates, *weights)
+    (_, (want, _)), d_want = jax.value_and_grad(parent, argnums, has_aux=True)(flat, gates, *weights)
+    held = int(sizes[:SUM_HELD].sum())
+    assert int(rung) == min(r for r in SUM_RUNGS if r >= held)
+    assert relative(got, want) < TOLERANCE and bool(jnp.any(want)) == bool(held)
+    for name, a, b in zip(("rows", "gates", "w_gate", "w_up", "w_down"), d_got, d_want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert relative(a, b) < TOLERANCE, name
+        assert not held or bool(jnp.any(b)), name
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["scatter-add", "kernel-interpreted"])
+def test_rows_of_and_sum_by_token_are_each_others_transpose(kernel, monkeypatch):
+    """<rows_of(x), y> == <x, sum_by_token(y)> with unit weights, by the
+    functions themselves and by each one's backward rule."""
+    if kernel:
+        interpret_the_sums(monkeypatch)
+    order, _, _ = routed("uniform")
+    token = order[:1024] // SUM_CHOICES
+    x = jax.random.normal(jax.random.PRNGKey(0), (SUM_TOKENS, SUM_WIDTH))
+    y = jax.random.normal(jax.random.PRNGKey(1), (1024, SUM_WIDTH))
+    ones = jnp.ones((1024,))
+    inner = lambda a, b: float(np.vdot(np.asarray(a, np.float64), np.asarray(b, np.float64)))
+    summed = grouped.sum_by_token(y, ones, token, SUM_TOKENS)
+    assert abs(inner(grouped.rows_of(x, token), y) - inner(x, summed)) < 1e-3
+    (d_x,) = jax.vjp(lambda x: grouped.rows_of(x, token), x)[1](y)
+    assert relative(d_x, summed) < 1e-6
+    d_y, d_ones = jax.vjp(lambda y, w: grouped.sum_by_token(y, w, token, SUM_TOKENS), y, ones)[1](x)
+    assert np.array_equal(np.asarray(d_y), np.asarray(grouped.rows_of(x, token)))
+    assert relative(d_ones, jnp.sum(x[token] * y, axis=1)) < 1e-6
 
 
 def test_the_uncut_layer_has_one_path_and_a_cut_one_a_conditional():

@@ -597,9 +597,10 @@ def test_dots_step_of_the_keye_cell_selects_once_and_runs_one_flash_pair_a_layer
     """The FT-DDP fused step of the KeyeVL2 cell (scanned layers, bf16,
     ``dots``, AdamW) compiled for a described v5e as the model builds it on a
     TPU: the layers stay one loop, so the compiled text holds one Mosaic call
-    for each kernel of a layer body: beside the expert layer's (``gmm``), the
-    flash forward and the ONE backward, each with the selection as its
-    operand, and neither stating a VMEM limit; and the index scores are
+    for each kernel of a layer body: beside the expert layer's (``gmm`` and the
+    sum by token, ``sum_by_token``: one a rung in each of its two
+    conditionals), the flash forward and the ONE backward, each with the
+    selection as its operand, and neither stating a VMEM limit; and the index scores are
     computed once a key group, in the forward's loop body alone. With a
     policy that keeps the dots and none of the three names, the backward's
     loop holds the forward call and the selection a second time: what the
@@ -639,7 +640,12 @@ def test_dots_step_of_the_keye_cell_selects_once_and_runs_one_flash_pair_a_layer
         )
         calls = _mosaic_calls(program)
         assert all(stated == [] for _, stated, _ in calls), calls
-        flash_calls = [name for name, _, _ in calls if not architecture.EXPERT_KERNEL.search(name)]
+        sums = [name for name, _, _ in calls if "sum_by_token" in name]
+        assert len(sums) == 6 and not any(architecture.EXPERT_KERNEL.search(name) for name in sums)
+        flash_calls = [
+            name for name, _, _ in calls
+            if not architecture.EXPERT_KERNEL.search(name) and name not in sums
+        ]
         index_scores = [
             line for line in program.as_text().splitlines()
             if "tpuft::indexer" in line and " convolution(" in line
@@ -694,8 +700,13 @@ def test_dots_step_of_a_keye_share_keeps_every_worst_case_row_inside_the_last_ru
     rungs; every op whose result has the worst case's 8,192 rows and a feature
     width lies inside a conditional's LAST branch; no conditional hands out an
     array with a rung's row count (nothing a rung sizes crosses from forward
-    to backward); and a rung's layer step is 12 ``gmm`` / ``tgmm`` calls, 3 in
-    the forward's branch and 9 in the backward's, not 15."""
+    to backward); a rung's layer step is 12 ``gmm`` / ``tgmm`` calls, 3 in
+    the forward's branch and 9 in the backward's, not 15, and two sums by
+    token, one Mosaic call each (``sum_by_token``, which the expert kernel's
+    name does not find); no branch holds a scatter into an array of feature
+    width; and nothing a branch computes, here or at the cell's own shapes,
+    has a result shape that ``selected_attention_seconds`` takes for the
+    selection's under the cell's ``sa_config``."""
     import json
     from pathlib import Path
 
@@ -712,6 +723,7 @@ def test_dots_step_of_a_keye_share_keeps_every_worst_case_row_inside_the_last_ru
     config = json.loads(
         (root / "chipbench/configs/keye-vl2-30b-a3b-ep8-1chip.json").read_text()
     )
+    cell = dict(config)
     config.update({**_KEYE_TOY, "num_experts_per_tok": 8})
     seq, k, widths = 1024, 8, (config["hidden_size"], config["moe_intermediate_size"])
     rungs = grouped.dispatch_rungs(seq, k, config["num_local_experts"], config["num_experts"])
@@ -733,7 +745,7 @@ def test_dots_step_of_a_keye_share_keeps_every_worst_case_row_inside_the_last_ru
         line for lines in computations.values() for line in lines if " conditional(" in line
     ]
     assert len(conditionals) == 2
-    inside_last, expert_calls = set(), []
+    inside_last, expert_calls, sums, inside = set(), [], [], set()
     for line in conditionals:
         result = line.split(" = ", 1)[1].split(" conditional(")[0]
         handed_out = {int(n) for dims in re.findall(r"\[([\d,]+)\]", result) for n in dims.split(",")}
@@ -751,7 +763,56 @@ def test_dots_step_of_a_keye_share_keeps_every_worst_case_row_inside_the_last_ru
             )
             for names in reached
         ])
+        inside |= set().union(*reached)
+        sums.append([
+            sum(
+                'custom_call_target="tpu_custom_call"' in line
+                and "sum_by_token" in line.split(" = ")[0]
+                and not architecture.EXPERT_KERNEL.search(line.split(" = ")[0])
+                for name in names for line in computations[name]
+            )
+            for names in reached
+        ])
     assert sorted(expert_calls) == [[3, 3, 3], [9, 9, 9]]
+    assert sums == [[1, 1, 1], [1, 1, 1]]
+    feature_wide = re.compile(r" = \S*\[(?:\d+,)*(?:%d|%d)\]\S* scatter\(" % widths)
+    scatters = [
+        line[:160] for name in inside for line in computations[name] if feature_wide.search(line)
+    ]
+    assert not scatters, scatters
+    # What the selection's reader would take for its own: the branches' ops
+    # here, and every array the pair of functions and their backward rules
+    # make at the cell's shapes and rungs.
+    from chipbench import trace_reduce
+
+    ops = [(trace_reduce.short_name(line.strip()), 1.0) for name in inside for line in computations[name]]
+    tokens, dim = 8192, cell["hidden_size"]
+
+    def both_ways(flat, x, w, t):
+        rows, gather_back = jax.vjp(lambda flat: grouped.rows_of(flat, t), flat)
+        out, sum_back = jax.vjp(lambda x, w: grouped.sum_by_token(x, w, t, tokens), x, w)
+        return rows, out, gather_back(x), sum_back(out)
+
+    def eqns_of(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for inner in jax.core.jaxprs_in_params(eqn.params):
+                yield from eqns_of(inner)
+
+    for rows in grouped.dispatch_rungs(
+        tokens, cell["num_experts_per_tok"], cell["num_local_experts"], cell["num_experts"]
+    ):
+        made = jax.make_jaxpr(both_ways)(*(jax.ShapeDtypeStruct(*a) for a in (
+            ((tokens, dim), jnp.bfloat16), ((rows, dim), jnp.bfloat16), ((rows,), jnp.float32),
+            ((rows,), jnp.int32),
+        )))
+        ops += [
+            (f"{eqn.primitive.name} {v.aval.str_short(short_dtypes=True)}".replace("i32", "s32"), 1.0)
+            for eqn in eqns_of(made.jaxpr) for v in eqn.outvars if hasattr(v.aval, "shape")
+        ]
+    made = {name.split(" ", 1)[1] for name, _ in ops}
+    assert len(ops) > 300 and {"s32[16384]", "f32[8192,2048]", "bf16[4,16384,2048]"} <= made
+    assert architecture.selected_attention_seconds({"ops": ops}, cell, tokens) == 0.0
     worst_case = re.compile(r" = \S*\[%d,(?:%d|%d)\]" % (rungs[-1], *widths))
     rows = [
         (name, line) for name, lines in computations.items() for line in lines

@@ -22,10 +22,30 @@ rule after those functions' differentiated forms (``jvp_jit_gmm__.<n>``,
 the ops' metadata and not those names. Every name holds ``gmm``: chipbench's
 ``expert_time_pct`` reads them by that.
 
+``rows_of(flat, token)`` and ``sum_by_token(rows, weights, token, n)`` carry
+rows between token order and any other: the first is the gather
+``flat[token]``, the second each token's float32 sum of ``weights[r] *
+rows[r]`` over the rows that name it, and each is the other's transpose, so
+each is the other's backward rule (with unit weights, rounded once to the
+rows' dtype; the sum's own rule is a gather of the cotangent, times the weight
+for the rows and dotted with the row for the weights, 16,384 rows at a time so
+that the gather's float32 rows are never a whole rung's). On a TPU the sum is one
+XLA gather of the rows INTO token order, where a block of tokens owns one
+contiguous run of rows, and one Mosaic call, ``sum_by_token.<n>`` in a device
+trace (no ``gmm`` in it): it walks blocks of 128 tokens, visits the aligned
+256-row tiles that hold a block's rows by tables that ride in as scalar
+prefetch, and adds a tile by ONE product on the MXU with the (tokens, rows)
+matrix that holds a row's weight where the row is the token's: three bf16
+terms of the float32 weight against bf16 rows, every product exact, float32
+accumulation in VMEM, each output block written once. XLA's scatter-add, which
+both sums were, permutes the rows into a second float32 buffer first and runs
+at a tenth of HBM's rate; elsewhere than a TPU the sum is that ``.at[token]
+.add`` still.
+
 ``routed_experts`` is the expert layer around the product, from a router's
 output to the tokens' sums (models/keye.py calls it with its router's):
-gather the sorted rows, gate and up products, the activation, the down
-product, each row weighted by its gate and added to its token's float32 sum.
+``rows_of`` the sorted rows, gate and up products, the activation, the down
+product, ``sum_by_token`` of its rows with the gates as weights.
 It runs at a ROW COUNT chosen each call from ``group_sizes``. The held
 experts' rows sort first, so the first ``C`` sorted rows hold every row this
 chip computes whenever the held total is at most ``C``; the counts it may run
@@ -47,10 +67,12 @@ from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from torchft_tpu.utils.platform import on_tpu
 
-__all__ = ["grouped_matmul", "dispatch_rungs", "routed_experts"]
+__all__ = ["grouped_matmul", "dispatch_rungs", "routed_experts", "rows_of", "sum_by_token"]
 
 
 def _tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
@@ -113,6 +135,191 @@ def dispatch_rungs(n: int, k: int, local: int, experts: int) -> Tuple[int, ...]:
     return tuple(sorted(rung for rung in rungs if rung < worst)) + (worst,)
 
 
+# What a grid step of the sum by token is to its block of tokens.
+_FIRST, _LAST, _VALID = 1, 2, 4
+
+
+def _token_block(n: int) -> int:
+    return next(t for t in (128, 64, 32, 16, 8, n) if n % t == 0)
+
+
+def _column_block(d: int) -> int:
+    return next((t for t in (2048, 1024, 512, 256, 128) if d % t == 0), d)
+
+
+def _visits(token: jnp.ndarray, n: int, token_block: int, row_tile: int):
+    """The (token block, row tile) pairs the kernel walks, for ``token``
+    ascending: a block visits the aligned tiles that hold its rows, and a
+    block with no row one tile (which adds nothing: none of its rows names a
+    token of the block), so every block is written. Three (blocks + tiles,)
+    int32 tables: the block, the tile, and ``_FIRST | _LAST | _VALID``; the
+    steps past the last visit repeat it with no flag and copy nothing."""
+    blocks, tiles = n // token_block, token.shape[0] // row_tile
+    edges = jnp.arange(blocks + 1, dtype=jnp.int32) * token_block
+    start = jnp.searchsorted(token, edges, method="compare_all").astype(jnp.int32)
+    lo = jnp.minimum(start[:-1] // row_tile, tiles - 1)
+    hi = jnp.maximum((start[1:] - 1) // row_tile, lo)
+    end = jnp.cumsum(hi - lo + 1, dtype=jnp.int32)
+    first = end - (hi - lo + 1)
+    step = jnp.arange(blocks + tiles, dtype=jnp.int32)
+    block = jnp.minimum(
+        jnp.searchsorted(end, step, side="right", method="compare_all").astype(jnp.int32),
+        blocks - 1,
+    )
+    valid = step < end[-1]
+    tile = jnp.where(valid, lo[block] + step - first[block], hi[-1])
+    flags = (_FIRST * (step == first[block]) + _LAST * (step == end[block] - 1) + _VALID) * valid
+    return block, tile, flags.astype(jnp.int32)
+
+
+def _token_sum_kernel(weighted, block_ref, tile_ref, flags_ref, token_ref, *refs):
+    """One visit: the tile's rows that name a token of the block, each times
+    its weight, added to the token's float32 sum. The sum is a product on the
+    MXU with a (tokens, rows) matrix that holds a row's weight where the row is
+    the token's and zero elsewhere. Against bf16 rows a float32 weight goes as
+    three bf16 terms (8 + 8 + 8 bits: the whole of it) and a unit weight as
+    one, so every product is exact and only the float32 additions round;
+    float32 rows take the MXU's float32 passes."""
+    del tile_ref
+    weight_ref = refs[0] if weighted else None
+    rows_ref, out_ref, acc_ref = refs[-3:]
+    step = pl.program_id(1)
+    flags = flags_ref[step]
+    tokens = acc_ref.shape[0]
+
+    @pl.when(flags & _FIRST != 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(flags & _VALID != 0)
+    def _():
+        at = token_ref[...] - block_ref[step] * tokens  # (1, rows)
+        mine = at == jax.lax.broadcasted_iota(jnp.int32, (tokens, at.shape[1]), 0)
+        weight = jnp.where(mine, weight_ref[...] if weighted else 1.0, 0.0)
+        rows = rows_ref[...]
+        if rows.dtype != jnp.bfloat16:
+            acc_ref[...] += jnp.dot(
+                weight, rows.astype(jnp.float32),
+                preferred_element_type=jnp.float32, precision=jax.lax.Precision.HIGHEST,
+            )
+            return
+        terms = []
+        for _ in range(3 if weighted else 1):
+            terms.append(weight.astype(jnp.bfloat16))
+            weight = weight - terms[-1].astype(jnp.float32)
+        product = jnp.dot(
+            jnp.concatenate(terms, axis=0), rows, preferred_element_type=jnp.float32
+        )
+        parts = [product[i * tokens : (i + 1) * tokens] for i in range(len(terms))]
+        acc_ref[...] += sum(reversed(parts))  # the smallest term first
+
+    @pl.when(flags & _LAST != 0)
+    def _():
+        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+
+def _token_sum_pallas(rows, weights, token, n, dtype, interpret=False):
+    """``_token_sum`` on a TPU: the rows to token order by one XLA gather, then
+    one Mosaic call over the visits of ``_visits``, each output block written
+    once. ``weights`` None is a weight of one a row."""
+    count, d = rows.shape
+    row_tile, token_block, columns = _row_tile(count), _token_block(n), _column_block(d)
+    weighted = weights is not None
+    # The weights ride in the sort: a gather of 16,384 scalars costs ten sorts.
+    token, by_token, *weights = jax.lax.sort(
+        (token, jnp.arange(count, dtype=jnp.int32), *([weights] if weighted else [])), num_keys=1
+    )
+    tables = _visits(token, n, token_block, row_tile)
+    by_tile = pl.BlockSpec((None, 1, row_tile), lambda j, v, block, tile, flags: (tile[v], 0, 0))
+    return pl.pallas_call(
+        partial(_token_sum_kernel, weighted),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(d // columns, tables[0].shape[0]),
+            in_specs=[by_tile] * (1 + weighted) + [
+                pl.BlockSpec((row_tile, columns), lambda j, v, block, tile, flags: (tile[v], j)),
+            ],
+            out_specs=pl.BlockSpec(
+                (token_block, columns), lambda j, v, block, tile, flags: (block[v], j)
+            ),
+            scratch_shapes=[pltpu.VMEM((token_block, columns), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((n, d), dtype),
+        name="sum_by_token",
+        interpret=interpret,
+    )(*tables, *(a.reshape(-1, 1, row_tile) for a in (token, *weights)), rows[by_token])
+
+
+def _token_sum(rows, weights, token, n, dtype):
+    """(n, d) in ``dtype``: each token's float32 sum of ``weights[r] * rows[r]``
+    over the rows r with ``token[r]`` its index, rounded once."""
+    if on_tpu():
+        return _token_sum_pallas(rows, weights, token, n, dtype)
+    rows = rows.astype(jnp.float32)
+    if weights is not None:
+        rows = rows * weights[:, None]
+    return jnp.zeros((n, rows.shape[1]), jnp.float32).at[token].add(rows).astype(dtype)
+
+
+@jax.custom_vjp
+def rows_of(flat: jnp.ndarray, token: jnp.ndarray) -> jnp.ndarray:
+    """``flat[token]``: (rows, d), the token's row of ``flat`` (n, d) in each
+    row. Its transpose is ``sum_by_token`` with unit weights, and that is its
+    backward rule, rounded once to ``flat.dtype``."""
+    return flat[token]
+
+
+def _rows_of_fwd(flat, token):
+    return flat[token], (flat, token)  # flat for its shape and dtype alone
+
+
+def _rows_of_bwd(residuals, d_rows):
+    flat, token = residuals
+    return _token_sum(d_rows, None, token, flat.shape[0], flat.dtype), None
+
+
+rows_of.defvjp(_rows_of_fwd, _rows_of_bwd)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def sum_by_token(rows: jnp.ndarray, weights: jnp.ndarray, token: jnp.ndarray, n: int):
+    """(n, d) float32: each token's sum of ``weights[r] * rows[r]`` (float32
+    products, float32 sums) over the rows r with ``token[r]`` its index; a
+    token no row names is zero. On a TPU one gather of the rows into token
+    order and one Mosaic call (``sum_by_token`` in a device trace), elsewhere
+    ``.at[token].add``. The transpose of ``rows_of`` in ``rows``; its backward
+    rule is a gather of the cotangent by token, times the weight for the rows
+    and dotted with the row for the weights, 16,384 rows at a time."""
+    return _token_sum(rows, weights, token, n, jnp.float32)
+
+
+def _sum_by_token_fwd(rows, weights, token, n):
+    return _token_sum(rows, weights, token, n, jnp.float32), (rows, weights, token)
+
+
+def _sum_by_token_bwd(n, residuals, d_out):
+    rows, weights, token = residuals
+    count = rows.shape[0]
+    chunk = next(c for c in (16384, 4096, 1024, 256, count) if count % c == 0)
+
+    # A chunk of rows at a time: XLA fuses the float32 gather of the cotangent
+    # into neither of its uses, and a whole rung's (512 MiB at the worst case)
+    # would be the largest temporary of a step.
+    def of_chunk(operands):
+        rows, weights, token = operands
+        d_weighted = d_out[token]
+        d_rows = (d_weighted * weights[:, None]).astype(rows.dtype)
+        return d_rows, jnp.sum(d_weighted * rows.astype(jnp.float32), axis=1)
+
+    d_rows, d_weights = jax.lax.map(
+        of_chunk, tuple(a.reshape(-1, chunk, *a.shape[1:]) for a in residuals)
+    )
+    return d_rows.reshape(rows.shape), d_weights.reshape(count).astype(weights.dtype), None
+
+
+sum_by_token.defvjp(_sum_by_token_fwd, _sum_by_token_bwd)
+
+
 def _experts_at(
     rows: int, activation: Callable, flat, order, gates, group_sizes, w_gate, w_up, w_down,
 ) -> jnp.ndarray:
@@ -124,10 +331,9 @@ def _experts_at(
     # The held groups alone: the rows past them, which fill the rung, are
     # nobody's, come out zero and add nothing to their tokens.
     product = partial(grouped_matmul, group_sizes=group_sizes[: w_gate.shape[0]])
-    x = flat[token]  # (rows, d), sorted by held expert
+    x = rows_of(flat, token)  # (rows, d), sorted by held expert
     out = product(activation(product(x, w_gate)) * product(x, w_up), w_down)
-    weighted = out.astype(jnp.float32) * gates.reshape(-1)[chosen][:, None]
-    return jnp.zeros((n, flat.shape[1]), jnp.float32).at[token].add(weighted)
+    return sum_by_token(out, gates.reshape(-1)[chosen], token, n)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(0, 1))
