@@ -85,7 +85,7 @@ def bench_dispatch_floor(results: list) -> None:
 
 
 def bench_attention(results: list) -> None:
-    from torchft_tpu.models.llama import causal_attention
+    from torchft_tpu.ops.attention import causal_attention
     from torchft_tpu.ops.flash_attention import flash_attention
 
     b, h, kv, d = 4, 8, 4, 128

@@ -48,12 +48,12 @@ def steer_to_chip_branches() -> None:
     """Code that asks ``on_tpu()`` sees the CPU during a described-topology
     compile and would take its interpret/jnp branches: steer it here, in
     the probe, not with an option of the program."""
-    import torchft_tpu.models.llama as llama
+    import torchft_tpu.ops.attention as attention
     import torchft_tpu.ops.flash_attention as flash
     import torchft_tpu.ops.quantization as quant
     import torchft_tpu.utils.platform as platform
 
-    for module in (platform, llama, flash, quant):
+    for module in (platform, attention, flash, quant):
         module.on_tpu = lambda: True
 
 

@@ -32,6 +32,33 @@ if str(REPO_ROOT) not in sys.path:
 import pytest  # noqa: E402
 
 
+@pytest.fixture
+def golden_param_tree():
+    """``check(name, params)``: every leaf's path, shape, dtype and values
+    (sha256 of their bytes) are ``name``'s in
+    tests/fixtures/model_param_trees.json, written at the parent of PR 52.
+    The benchmark's float32 references read a model's tree by path and its
+    seeds make the weights, so a change of the model layer holds them all."""
+    import hashlib
+    import json
+
+    import numpy as np
+
+    golden = json.loads((REPO_ROOT / "tests/fixtures/model_param_trees.json").read_text())
+
+    def check(name: str, params) -> None:
+        got = {
+            "/".join(str(k.key) for k in path): [
+                list(leaf.shape), str(leaf.dtype),
+                hashlib.sha256(np.asarray(leaf).tobytes()).hexdigest(),
+            ]
+            for path, leaf in jax.tree_util.tree_leaves_with_path(params)
+        }
+        assert got == golden[name]
+
+    return check
+
+
 # Turn NativeToolchainMissing (no cmake/ninja, no prebuilt libtpuft.so)
 # into a skip with a clear reason, wherever it surfaces — fixture setup or
 # the test body. Everything else passes through untouched.

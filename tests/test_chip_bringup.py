@@ -385,9 +385,8 @@ def test_flash_gradients_under_fsdp_tp_mesh_match_unsharded() -> None:
     over the manual axes (jax 0.9 checks it)."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from torchft_tpu.models.llama import LlamaConfig, _flash_under_ambient_mesh
+    from torchft_tpu.ops.attention import flash_under_mesh
 
-    config = LlamaConfig(attention_block_size=16, attention_block_k=128)
     b, s_, h, kv, d = 4, 32, 4, 2, 16
     kq, kk, kv_ = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(kq, (b, s_, h, d), jnp.float32)
@@ -395,7 +394,8 @@ def test_flash_gradients_under_fsdp_tp_mesh_match_unsharded() -> None:
     v = jax.random.normal(kv_, (b, s_, kv, d), jnp.float32)
 
     def loss(q, k, v):
-        return jnp.sum(_flash_under_ambient_mesh(config, q, k, v, d**-0.5) ** 2)
+        out = flash_under_mesh(q, k, v, scale=d**-0.5, block_q=16, block_k=128)
+        return jnp.sum(out**2)
 
     grad = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
     want_loss, want = grad(q, k, v)  # no mesh bound: the plain kernel call

@@ -111,8 +111,9 @@ def test_llama_fused_loss_matches_materialized(tied) -> None:
 
 
 def test_llama_head_param_layout_unchanged() -> None:
-    """_LMHead keeps the nn.Dense param contract the sharding plan and
-    existing checkpoints rely on: lm_head/kernel, (dim, vocab), cfg dtype."""
+    """LMHead (models/decoder.py) keeps the nn.Dense param contract the
+    sharding plan and existing checkpoints rely on: lm_head/kernel,
+    (dim, vocab), cfg dtype."""
     cfg = CONFIGS["tiny"]
     model = Llama(cfg)
     tokens = jnp.zeros((1, 8), jnp.int32)

@@ -5,7 +5,6 @@ convention)."""
 from __future__ import annotations
 
 import contextlib
-from dataclasses import replace
 from functools import partial
 
 import jax
@@ -13,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from torchft_tpu.models.llama import causal_attention
+from torchft_tpu.ops.attention import causal_attention
 from torchft_tpu.ops.flash_attention import flash_attention
 
 
@@ -258,20 +257,15 @@ def test_remat_runs_the_forward_kernel_once_unless_full(
     same value recomputed by the same kernel). Under a bound mesh the
     dispatcher's shard_map sits between the remat and the names, and they
     survive it."""
-    import torchft_tpu.ops.flash_attention as fa
-    from torchft_tpu.models.llama import (
-        CONFIGS, _flash_under_ambient_mesh, _remat_policy,
-    )
+    from torchft_tpu.models.decoder import remat_policy
+    from torchft_tpu.ops import attention
+    from torchft_tpu.ops.flash_attention import FLASH_LSE, FLASH_OUT
 
-    # The dispatcher imports the kernel at call time: force the interpreted
-    # Pallas backward (off-TPU it would pick the scan fallback).
+    # The dispatcher calls its module's name for the kernel: force the
+    # interpreted Pallas backward (off-TPU it would pick the scan fallback).
     monkeypatch.setattr(
-        fa, "flash_attention",
+        attention, "flash_attention",
         partial(flash_attention, interpret=True, use_pallas_bwd=True),
-    )
-    cfg = replace(
-        CONFIGS["tiny"], attention_impl="flash",
-        attention_block_size=32, attention_block_k=128,
     )
     b, s, h, kv, d, dim = 2, 64, 4, 2, 16, 32
     keys = jax.random.split(jax.random.PRNGKey(0), 5)
@@ -288,7 +282,7 @@ def test_remat_runs_the_forward_kernel_once_unless_full(
             jnp.einsum("bsd,dhe->bshe", x, w[name])
             for name in ("wq", "wk", "wv")
         )
-        out = _flash_under_ambient_mesh(cfg, q, k, v, d**-0.5)
+        out = attention.flash_under_mesh(q, k, v, scale=d**-0.5, block_q=32, block_k=128)
         return x + jnp.einsum("bshe,hed->bsd", out, w["wo"])
 
     def grad_of(f):
@@ -296,7 +290,11 @@ def test_remat_runs_the_forward_kernel_once_unless_full(
 
     remat_layer = layer
     if remat != "none":
-        remat_layer = jax.checkpoint(layer, policy=_remat_policy(remat))
+        # models/llama.py's line
+        policy = remat_policy(
+            remat, jax.checkpoint_policies.checkpoint_dots, FLASH_OUT, FLASH_LSE
+        )
+        remat_layer = jax.checkpoint(layer, policy=policy)
     bound = (
         jax.set_mesh(jax.sharding.Mesh(np.array(jax.devices()[:2]), (mesh_axis,)))
         if mesh_axis

@@ -14,10 +14,10 @@ from torchft_tpu.models.llama import (
     Llama,
     LlamaConfig,
     apply_sharding_plan,
-    causal_attention,
     cross_entropy_loss,
     sharding_plan,
 )
+from torchft_tpu.ops.attention import causal_attention, flash_under_mesh
 from torchft_tpu.ops.ring_attention import ring_attention_sharded
 
 
@@ -309,7 +309,6 @@ def test_blockwise_attention_matches_dense() -> None:
     """blockwise_attention (lax.scan over KV blocks, online softmax) is
     numerically equivalent to dense causal attention — forward and grad —
     including non-block-multiple sequence lengths and GQA."""
-    from torchft_tpu.models.llama import causal_attention
     from torchft_tpu.ops.ring_attention import blockwise_attention
 
     # ONE case carrying every property at once (GQA h != kv AND a
@@ -444,10 +443,7 @@ def test_dots_remat_without_flash_is_plain_checkpoint_dots(
         return jax.jit(grad).lower(params).as_text()
 
     with_names = lowered()
-    monkeypatch.setattr(
-        llama, "_remat_policy",
-        lambda remat: jax.checkpoint_policies.checkpoint_dots,
-    )
+    monkeypatch.setattr(llama, "remat_policy", lambda remat, dots, *names: dots)
     assert lowered() == with_names
 
 
@@ -558,36 +554,59 @@ def test_all_fit_levers_compose_in_one_step() -> None:
     np.testing.assert_allclose(float(loss), float(full_loss), rtol=1e-5)
 
 
-def test_flash_shard_maps_itself_under_ambient_mesh(monkeypatch):
+def _selected_attention_oracle(q, k, v, selection, scale):
+    """Dense attention over the keys ``selection`` (b, s, s) marks."""
+    b, s, h, d = q.shape
+    kv = k.shape[2]
+    scores = jnp.einsum("bskgd,btkd->bkgst", q.reshape(b, s, kv, h // kv, d), k) * scale
+    scores = jnp.where(selection[:, None, None] != 0, scores, -jnp.inf)
+    out = jnp.einsum("bkgst,btkd->bskgd", jax.nn.softmax(scores, axis=-1), v)
+    return out.reshape(b, s, h, d)
+
+
+@pytest.mark.parametrize("selected", [False, True], ids=["causal", "selection"])
+def test_flash_shard_maps_itself_under_ambient_mesh(selected):
     """Under a bound mesh (jax.set_mesh — the sharded-train-step context)
     the flash dispatcher must shard_map the Pallas kernel over the
     batch/head axes itself: XLA SPMD refuses to partition Mosaic custom
     calls, so the bare kernel call fails to lower inside jit-with-mesh
     (test_mosaic_lowering.py's 8B gate pins the lowering half; this test
     pins numerics — the mapped kernel must match dense attention
-    exactly where each (batch, head) shard computes independently)."""
-    from torchft_tpu.models.llama import (
-        _flash_under_ambient_mesh, causal_attention,
-    )
-
-    cfg = replace(
-        CONFIGS["tiny"], attention_impl="flash",
-        flash_batch_axes=("dp", "fsdp"), flash_tp_axis="tp",
-    )
+    exactly where each (batch, head) shard computes independently). A
+    selection of keys, one (b, s, s) operand for all heads, goes with the
+    batch."""
     b, s, h, kv, d = 4, 128, 4, 2, 64
-    kq, kk, kvk = jax.random.split(jax.random.PRNGKey(0), 3)
+    kq, kk, kvk, ks = jax.random.split(jax.random.PRNGKey(0), 4)
     q = jax.random.normal(kq, (b, s, h, d), jnp.float32)
     k = jax.random.normal(kk, (b, s, kv, d), jnp.float32)
     v = jax.random.normal(kvk, (b, s, kv, d), jnp.float32)
+    causal = jnp.tril(jnp.ones((s, s), bool))
 
-    mesh = jax.make_mesh((4, 2), ("fsdp", "tp"))
-    with jax.set_mesh(mesh):
-        out = jax.jit(
-            lambda q, k, v: _flash_under_ambient_mesh(cfg, q, k, v, d**-0.5)
-        )(q, k, v)
-    ref = causal_attention(q, k, v, scale=d**-0.5)
+    def selection_for(rows):
+        """Half the earlier keys at random, and always the query's own."""
+        if not selected:
+            return None
+        some = jax.random.bernoulli(ks, 0.5, (rows, s, s)) | jnp.eye(s, dtype=bool)
+        return (some & causal).astype(jnp.int8)
+
+    def dispatched(q, k, v, selection):
+        with jax.set_mesh(jax.make_mesh((4, 2), ("fsdp", "tp"))):
+            return jax.jit(
+                lambda *qkv, selection: flash_under_mesh(
+                    *qkv, scale=d**-0.5, selection=selection,
+                    batch_axes=("dp", "fsdp"), tp_axis="tp",
+                )
+            )(q, k, v, selection=selection)
+
+    def oracle(q, k, v, selection):
+        if selection is None:
+            return causal_attention(q, k, v, scale=d**-0.5)
+        return _selected_attention_oracle(q, k, v, selection, d**-0.5)
+
+    chosen = selection_for(b)
     np.testing.assert_allclose(
-        np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5
+        np.asarray(dispatched(q, k, v, chosen)), np.asarray(oracle(q, k, v, chosen)),
+        rtol=2e-5, atol=2e-5,
     )
 
     # Non-dividing dims must still compute correctly: the axes stay
@@ -597,13 +616,10 @@ def test_flash_shard_maps_itself_under_ambient_mesh(monkeypatch):
     # tp=2.
     q3 = jax.random.normal(kq, (3, s, 3, d), jnp.float32)
     k3 = jax.random.normal(kk, (3, s, 3, d), jnp.float32)
-    with jax.set_mesh(mesh):
-        out3 = jax.jit(
-            lambda q, k, v: _flash_under_ambient_mesh(cfg, q, k, v, d**-0.5)
-        )(q3, k3, k3)
-    ref3 = causal_attention(q3, k3, k3, scale=d**-0.5)
+    chosen3 = selection_for(3)
     np.testing.assert_allclose(
-        np.asarray(out3), np.asarray(ref3), rtol=2e-5, atol=2e-5
+        np.asarray(dispatched(q3, k3, k3, chosen3)), np.asarray(oracle(q3, k3, k3, chosen3)),
+        rtol=2e-5, atol=2e-5,
     )
 
 
@@ -614,32 +630,27 @@ def test_flash_mesh_fallback_keeps_largest_dividing_subset(caplog):
     over fsdp logs a once-per-shape warning."""
     import logging as _logging
 
-    from torchft_tpu.models import llama as llama_mod
-    from torchft_tpu.models.llama import (
-        _flash_under_ambient_mesh, causal_attention,
-    )
+    from torchft_tpu.ops import attention
 
-    cfg = replace(
-        CONFIGS["tiny"], attention_impl="flash",
-        flash_batch_axes=("dp", "fsdp"), flash_tp_axis="tp",
-    )
     s, h, kv, d = 128, 4, 2, 64
+
+    def dispatch(q, k, v):
+        return flash_under_mesh(
+            q, k, v, scale=d**-0.5, batch_axes=("dp", "fsdp"), tp_axis="tp"
+        )
+
     kq, kk, kvk = jax.random.split(jax.random.PRNGKey(3), 3)
     q = jax.random.normal(kq, (2, s, h, d), jnp.float32)
     k = jax.random.normal(kk, (2, s, kv, d), jnp.float32)
     v = jax.random.normal(kvk, (2, s, kv, d), jnp.float32)
 
-    llama_mod._FLASH_REPLICATION_WARNED.clear()
+    attention._FLASH_REPLICATION_WARNED.clear()
     mesh = jax.make_mesh((2, 2, 2), ("dp", "fsdp", "tp"))
-    with caplog.at_level(_logging.WARNING, logger="torchft_tpu.models.llama"):
+    with caplog.at_level(_logging.WARNING, logger="torchft_tpu.ops.attention"):
         with jax.set_mesh(mesh):
-            out = jax.jit(
-                lambda q, k, v: _flash_under_ambient_mesh(cfg, q, k, v, d**-0.5)
-            )(q, k, v)
+            out = jax.jit(dispatch)(q, k, v)
             # Same shape again: the warning must not repeat.
-            jax.jit(
-                lambda q, k, v: _flash_under_ambient_mesh(cfg, q, k, v, d**-0.5)
-            )(q, k, v)
+            jax.jit(lambda q, k, v: dispatch(q, k, v))(q, k, v)
     ref = causal_attention(q, k, v, scale=d**-0.5)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5
@@ -652,19 +663,19 @@ def test_flash_mesh_fallback_keeps_largest_dividing_subset(caplog):
 def test_largest_dividing_subset_selection():
     """The pure fallback helper: keeps the max-shard-count dividing subset
     in spec order; all-or-nothing only when nothing divides."""
-    from torchft_tpu.models.llama import _largest_dividing_subset
+    from torchft_tpu.ops.attention import largest_dividing_subset
 
     sizes = {"dp": 2, "fsdp": 4}
-    assert _largest_dividing_subset(("dp", "fsdp"), sizes, 8) == ("dp", "fsdp")
-    assert _largest_dividing_subset(("dp", "fsdp"), sizes, 4) == ("fsdp",)
-    assert _largest_dividing_subset(("dp", "fsdp"), sizes, 2) == ("dp",)
-    assert _largest_dividing_subset(("dp", "fsdp"), sizes, 3) == ()
+    assert largest_dividing_subset(("dp", "fsdp"), sizes, 8) == ("dp", "fsdp")
+    assert largest_dividing_subset(("dp", "fsdp"), sizes, 4) == ("fsdp",)
+    assert largest_dividing_subset(("dp", "fsdp"), sizes, 2) == ("dp",)
+    assert largest_dividing_subset(("dp", "fsdp"), sizes, 3) == ()
     # Ties prefer more axes (finer layout): 4 rows on 2x2 -> both axes.
-    assert _largest_dividing_subset(
+    assert largest_dividing_subset(
         ("dp", "fsdp"), {"dp": 2, "fsdp": 2}, 4
     ) == ("dp", "fsdp")
     # Order in the result is spec order regardless of subset enumeration.
-    assert _largest_dividing_subset(
+    assert largest_dividing_subset(
         ("a", "b", "c"), {"a": 3, "b": 2, "c": 2}, 12
     ) == ("a", "b", "c")
 
@@ -675,11 +686,6 @@ def test_flash_dispatcher_is_inert_inside_callers_shard_map():
     call (a nested map over local shapes would mis-divide them — caught
     by comparing AxisType.Manual, which its first version string-compared
     wrong)."""
-    from torchft_tpu.models.llama import (
-        _flash_under_ambient_mesh, causal_attention,
-    )
-
-    cfg = replace(CONFIGS["tiny"], attention_impl="flash")
     b, s, h, kv, d = 8, 128, 4, 2, 64
     kq, kk, kvk = jax.random.split(jax.random.PRNGKey(1), 3)
     q = jax.random.normal(kq, (b, s, h, d), jnp.float32)
@@ -694,7 +700,7 @@ def test_flash_dispatcher_is_inert_inside_callers_shard_map():
     spec = P("fsdp", None, "tp", None)
     out = jax.jit(
         jax.shard_map(
-            lambda q, k, v: _flash_under_ambient_mesh(cfg, q, k, v, d**-0.5),
+            lambda q, k, v: flash_under_mesh(q, k, v, scale=d**-0.5),
             mesh=mesh,
             in_specs=(spec, spec, spec),
             out_specs=spec,
@@ -707,3 +713,93 @@ def test_flash_dispatcher_is_inert_inside_callers_shard_map():
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5
     )
+
+
+# ---------------------------------------------------------------------------
+# models/decoder.py: the seam a new architecture is written against
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("scan_layers", [False, True], ids=["loop", "scan"])
+def test_llama_parameter_tree_is_the_golden(scan_layers, golden_param_tree) -> None:
+    """As the model made it before the stack, norm and head were
+    models/decoder.py's (tests/conftest.py ``golden_param_tree``)."""
+    model = Llama(replace(CONFIGS["tiny"], scan_layers=scan_layers))
+    params = model.init(jax.random.PRNGKey(0), jnp.zeros((2, 64), jnp.int32))
+    golden_param_tree("llama-scan" if scan_layers else "llama-loop", params)
+
+
+@pytest.mark.parametrize("remat", ["none", "dots", "full"])
+@pytest.mark.parametrize("scan_layers", [False, True], ids=["loop", "scan"])
+def test_a_block_defined_here_runs_through_layer_stack(scan_layers, remat) -> None:
+    """What a new architecture writes is its block: ``layer_stack`` gives a
+    three-line block the scanned or looped, rematerialised stack, its leaves
+    under ``layers/block/`` with a leading layer axis or under ``layer_<i>/``,
+    and the same function either way."""
+    from dataclasses import dataclass
+
+    import flax.linen as nn
+
+    from torchft_tpu.models.decoder import layer_stack, remat_policy
+
+    @dataclass(frozen=True)
+    class Config:
+        n_layers: int = 3
+        scan_layers: bool = False
+        remat: str = "none"
+
+    class Block(nn.Module):
+        config: Config
+
+        @nn.compact
+        def __call__(self, x, positions):
+            return x + nn.Dense(x.shape[-1], name="mix")(jnp.tanh(x)) * positions[..., None]
+
+    class Model(nn.Module):
+        config: Config
+
+        @nn.compact
+        def __call__(self, x, positions):
+            policy = remat_policy(self.config.remat, jax.checkpoint_policies.checkpoint_dots)
+            return layer_stack(Block, self.config, policy, x, positions)
+
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 5, 8))
+    positions = jnp.broadcast_to(jnp.arange(5) / 5.0, (2, 5))
+    model = Model(Config(scan_layers=scan_layers, remat=remat))
+    params = model.init(jax.random.PRNGKey(0), x, positions)
+    shapes = {
+        "/".join(str(k.key) for k in path): list(leaf.shape)
+        for path, leaf in jax.tree_util.tree_leaves_with_path(params)
+    }
+    if scan_layers:
+        assert shapes == {
+            "params/layers/block/mix/kernel": [3, 8, 8], "params/layers/block/mix/bias": [3, 8],
+        }
+    else:
+        assert shapes == {
+            f"params/layer_{i}/mix/{leaf}": shape
+            for i in range(3) for leaf, shape in (("kernel", [8, 8]), ("bias", [8]))
+        }
+
+    def value_and_grads(model, params):
+        def loss(p):
+            return jnp.sum(model.apply(p, x, positions) ** 2)
+
+        return jax.jit(jax.value_and_grad(loss))(params)
+
+    def looped(tree):
+        """A scanned stack's tree in the looped stack's layout."""
+        if not scan_layers:
+            return tree
+        stacked = tree["params"]["layers"]["block"]
+        return {"params": {
+            f"layer_{i}": jax.tree_util.tree_map(lambda a: a[i], stacked) for i in range(3)
+        }}
+
+    # The plain looped stack over the same weights is the same function.
+    value, grads = value_and_grads(model, params)
+    want, want_grads = value_and_grads(Model(Config()), looped(params))
+    np.testing.assert_allclose(float(value), float(want), rtol=1e-6)
+    for got, wanted in zip(*map(jax.tree_util.tree_leaves, (looped(grads), want_grads))):
+        assert float(jnp.linalg.norm(wanted)) > 0
+        np.testing.assert_allclose(np.asarray(got), np.asarray(wanted), rtol=1e-5, atol=1e-6)

@@ -265,7 +265,7 @@ def test_flash_kernels_lower_with_their_schedule_tables(where):
     """The forward and the backward call each take the causal block
     schedule as two scalar-prefetch operands that their index maps and
     bodies read from SMEM, and lower for a TPU: bare; under a shard_map over the batch (the
-    model's ``_flash_under_ambient_mesh``: tables of ``arange`` that vary
+    model's ``ops.attention.flash_under_mesh``: tables of ``arange`` that vary
     over no axis beside data that does); and under a shard_map over the
     sequence with the positions as arguments (a ring hop: the tables vary
     with the shard)."""
@@ -352,6 +352,7 @@ def test_flagship_flash_train_step_lowers_for_tpu(monkeypatch):
     import optax
 
     from torchft_tpu.models import llama as llama_mod
+    from torchft_tpu.ops import attention as attention_mod
     from torchft_tpu.ops import flash_attention as fa_mod
     from torchft_tpu.models.llama import Llama, LlamaConfig
 
@@ -359,7 +360,7 @@ def test_flagship_flash_train_step_lowers_for_tpu(monkeypatch):
     # lower the real Mosaic program, so pretend the chip is attached for
     # the trace (lowering still targets TPU via lowering_platforms).
     monkeypatch.setattr(fa_mod, "on_tpu", lambda: True)
-    monkeypatch.setattr(llama_mod, "on_tpu", lambda: True)
+    monkeypatch.setattr(attention_mod, "on_tpu", lambda: True)
 
     # The SHARED definition: the gate must lower exactly the program the
     # HBM probe sizes (a copied config drifted when the head geometry was
@@ -461,7 +462,7 @@ def test_8b_sharded_flash_train_step_lowers_for_tpu(monkeypatch):
     real pod: Mosaic block-mapping violations at 8B shapes, and the
     "Mosaic kernels cannot be automatically partitioned" lowering error
     the flash path hits under jit-with-mesh unless it shard_maps itself
-    (models/llama.py _flash_under_ambient_mesh — found by exactly this
+    (ops/attention.py flash_under_mesh — found by exactly this
     lowering, round 5). Everything is abstract: 8.03B params eval_shape
     only, and the scanned stack keeps the lowered module ~0.2 MB."""
     from dataclasses import replace
@@ -470,14 +471,14 @@ def test_8b_sharded_flash_train_step_lowers_for_tpu(monkeypatch):
 
     from jax.sharding import AbstractMesh, NamedSharding, PartitionSpec as P
 
-    from torchft_tpu.models import llama as llama_mod
     from torchft_tpu.models.llama import (
         CONFIGS, Llama, plan_shardings, sharding_plan,
     )
+    from torchft_tpu.ops import attention as attention_mod
     from torchft_tpu.ops import flash_attention as fa_mod
 
     monkeypatch.setattr(fa_mod, "on_tpu", lambda: True)
-    monkeypatch.setattr(llama_mod, "on_tpu", lambda: True)
+    monkeypatch.setattr(attention_mod, "on_tpu", lambda: True)
 
     cfg = replace(
         CONFIGS["8b"], scan_layers=True, remat="dots", loss_vocab_chunk=4096,

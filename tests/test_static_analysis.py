@@ -400,3 +400,52 @@ def test_cli_inprocess_contract() -> None:
             os.environ.pop("TPUFT_ANALYSIS_REFERENCE", None)
         else:
             os.environ["TPUFT_ANALYSIS_REFERENCE"] = old
+
+
+# ---------------------------------------------------------------------------
+# the layering of models/ over ops/ (an ``ast`` walk, nothing imported)
+# ---------------------------------------------------------------------------
+
+
+def _imports(path: Path):
+    """(module, name) of every import in a file, function bodies included;
+    ``import a.b`` gives (a.b, None)."""
+    import ast
+
+    for node in ast.walk(ast.parse(path.read_text())):
+        if isinstance(node, ast.Import):
+            yield from ((alias.name, None) for alias in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            module = "." * node.level + (node.module or "")
+            yield from ((module, alias.name) for alias in node.names)
+
+
+def test_ops_import_nothing_of_models() -> None:
+    """A kernel's oracle, its dispatch under a mesh and the choice of kernel
+    are ops/'s own (ops/attention.py): no module there reaches up into a
+    model file, at import time or inside a function."""
+    package = REPO_ROOT / "torchft_tpu"
+    reaching = [
+        f"{path.name}: {module}"
+        for path in sorted((package / "ops").glob("*.py"))
+        for module, name in _imports(path)
+        if "models" in f"{module}.{name}".split(".")
+    ]
+    assert reaching == []
+
+
+@pytest.mark.parametrize("model", ["keye.py", "llama.py"])
+def test_a_model_file_imports_no_private_name_and_makes_no_platform_choice(model) -> None:
+    """What a model shares with another is public (models/decoder.py), and
+    which kernel runs where is ops/'s to say: a model file imports no
+    underscore name from anywhere in the package, and none of the names a
+    kernel choice is made with."""
+    chosen_with = {"on_tpu", "flash_attention", "select_keys", "shard_map", "checkpoint_name"}
+    names = [
+        (module, name)
+        for module, name in _imports(REPO_ROOT / "torchft_tpu/models" / model)
+        if module.startswith(("torchft_tpu", ".", "jax"))
+    ]
+    assert names, "the walk found no import at all"
+    assert [n for n in names if (n[1] or "").startswith("_")] == []
+    assert [n for n in names if n[1] in chosen_with] == []

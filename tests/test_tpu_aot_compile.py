@@ -320,15 +320,16 @@ def test_fragment_sync_programs_compile_at_the_cells_geometry(chip, monkeypatch)
 @pytest.mark.slow  # 10-15 s: the tier-1 gate (-m 'not slow') is near its limit
 def test_plain_step_compiles_at_smoke_config(chip, monkeypatch) -> None:
     """The whole plain SGD-momentum step chip_smoke.py runs, with its state
-    inside one chip's HBM. The model asks ``on_tpu()`` and would see the CPU
+    inside one chip's HBM. ops/ asks ``on_tpu()`` and would see the CPU
     here: the test steers it, the program grows no option for it."""
     import optax
 
     import chip_smoke
     import torchft_tpu.models.llama as llama
+    import torchft_tpu.ops.attention as attention
     import torchft_tpu.ops.flash_attention as flash
 
-    for module in (llama, flash):
+    for module in (attention, flash):
         monkeypatch.setattr(module, "on_tpu", lambda: True)
     config, batch, seq = chip_smoke.smoke_config(rehearse=False)
     assert replace(config, n_layers=16, max_seq_len=8192) == replace(
@@ -401,12 +402,13 @@ def test_dots_step_runs_one_flash_forward_a_layer(
     from pathlib import Path
 
     import torchft_tpu.models.llama as llama
+    import torchft_tpu.ops.attention as attention
     import torchft_tpu.ops.flash_attention as flash
     from chipbench.architectures import mistral
     from chipbench.model import System
     from torchft_tpu.optim import make_jit_fused_step
 
-    for module in (llama, flash):
+    for module in (attention, flash):
         monkeypatch.setattr(module, "on_tpu", lambda: True)
     config = json.loads(
         (Path(__file__).parent.parent / "chipbench/configs/mistral-7b-v0.3-1chip.json")
@@ -438,9 +440,7 @@ def test_dots_step_runs_one_flash_forward_a_layer(
         return len(calls), program.memory_analysis().temp_size_in_bytes
 
     calls, temp = compiled()
-    monkeypatch.setattr(
-        llama, "_remat_policy", lambda remat: jax.checkpoint_policies.checkpoint_dots
-    )
+    monkeypatch.setattr(llama, "remat_policy", lambda remat, dots, *names: dots)
     calls_plain_dots, temp_plain_dots = compiled()
     assert (calls, calls_plain_dots) == (2, 3)
     heads, layers = config["num_attention_heads"], config["num_hidden_layers"]
@@ -460,9 +460,10 @@ def test_sharded_step_with_size_one_mesh_axis_compiles(v5e, monkeypatch) -> None
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     import torchft_tpu.models.llama as llama
+    import torchft_tpu.ops.attention as attention
     import torchft_tpu.ops.flash_attention as flash
 
-    for module in (llama, flash):
+    for module in (attention, flash):
         monkeypatch.setattr(module, "on_tpu", lambda: True)
     mesh = Mesh(np.array(v5e[:2]).reshape(2, 1), ("fsdp", "tp"))
     config = replace(
@@ -611,12 +612,13 @@ def test_dots_step_of_the_keye_cell_selects_once_and_runs_one_flash_pair_a_layer
     import torchft_tpu.models.keye as keye
     import torchft_tpu.ops.flash_attention as flash
     import torchft_tpu.ops.grouped_matmul as grouped
+    import torchft_tpu.ops.sparse_attention as sparse
     from chipbench import spec
     from chipbench.model import System
     from torchft_tpu.ops.sparse_attention import KEY_GROUPS
     from torchft_tpu.optim import make_jit_fused_step
 
-    for module in (keye, flash, grouped):
+    for module in (sparse, flash, grouped):
         monkeypatch.setattr(module, "on_tpu", lambda: True)
     root = Path(__file__).parent.parent
     config = json.loads(
@@ -655,10 +657,7 @@ def test_dots_step_of_the_keye_cell_selects_once_and_runs_one_flash_pair_a_layer
         )
 
     assert compiled() == (2, KEY_GROUPS, 0)
-    monkeypatch.setattr(
-        keye, "_remat_policy",
-        lambda remat: jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
-    )
+    monkeypatch.setattr(keye, "remat_policy", lambda remat, dots, *names: dots)
     assert compiled() == (3, 2 * KEY_GROUPS, KEY_GROUPS)
 
 
@@ -710,14 +709,14 @@ def test_dots_step_of_a_keye_share_keeps_every_worst_case_row_inside_the_last_ru
     import json
     from pathlib import Path
 
-    import torchft_tpu.models.keye as keye
     import torchft_tpu.ops.flash_attention as flash
     import torchft_tpu.ops.grouped_matmul as grouped
+    import torchft_tpu.ops.sparse_attention as sparse
     from chipbench import spec
     from chipbench.model import System
     from torchft_tpu.optim import make_jit_fused_step
 
-    for module in (keye, flash, grouped):
+    for module in (sparse, flash, grouped):
         monkeypatch.setattr(module, "on_tpu", lambda: True)
     root = Path(__file__).parent.parent
     config = json.loads(

@@ -7,10 +7,10 @@ Per layer, pre-norm:
   ``dim / n_heads``), an RMSNorm over each head of q and k, rotary (rotate-half;
   text-only positions, so the family's three rotary sections see one position
   and the embedding is the plain one). Each query attends to the ``topk``
-  earlier keys an indexer scores highest (ops/sparse_attention.py; on a TPU
-  the selection is made once as an int8 array and the flash kernels of
-  ops/flash_attention.py attend under it, elsewhere plain tiled XLA does
-  both): the indexer has ``indexer_heads`` query heads and one key head of
+  earlier keys an indexer scores highest (ops/sparse_attention.py
+  ``selected_attention``: on a TPU the selection is made once as an int8
+  array and the flash kernels attend under it, elsewhere plain tiled XLA
+  does both): the indexer has ``indexer_heads`` query heads and one key head of
   ``indexer_head_dim``, a LayerNorm on its key, rotary on both, a learned
   weight a head, and runs in ``indexer_dtype`` (float32) whatever ``dtype`` is.
   The selection carries no gradient, so the language-model loss gives the
@@ -28,12 +28,11 @@ Per layer, pre-norm:
   one chip the layer runs without its exchange. ``num_local_experts ==
   num_experts`` is the whole layer, one path at the worst case.
 
-Rotary and the output head with its fused loss are models/llama.py's, the
-norm is its arithmetic with the scale stored in ``norm_dtype`` (float32, as
-there, unless a configuration says otherwise); the layer stack is scanned and
-rematerialised the same way (the ``dots`` policy keeps the projections'
-products, the flash kernel's output and logsumexp and the selection, not a
-tile's scores). ``router_load``
+Rotary, the norm (its scale stored in ``norm_dtype``: float32 unless a
+configuration says otherwise), the output head with its fused loss and the
+scanned, rematerialised layer stack are models/decoder.py's (the ``dots``
+policy keeps the projections' products, the flash kernel's output and
+logsumexp and the selection, not a tile's scores). ``router_load``
 counts the rows each held expert receives, ``dispatch_rows`` the row count
 each layer's dispatch ran at.
 """
@@ -47,13 +46,11 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
 
-from torchft_tpu.models.llama import _LMHead, apply_rope
-from torchft_tpu.ops.flash_attention import FLASH_LSE, FLASH_OUT, flash_attention
+from torchft_tpu.models.decoder import LMHead, RMSNorm, apply_rope, layer_stack, remat_policy
+from torchft_tpu.ops.flash_attention import FLASH_LSE, FLASH_OUT
 from torchft_tpu.ops.grouped_matmul import routed_experts
-from torchft_tpu.ops.sparse_attention import SELECTION, select_keys, sparse_attention
-from torchft_tpu.utils.platform import on_tpu
+from torchft_tpu.ops.sparse_attention import SELECTION, selected_attention
 
 __all__ = ["KeyeConfig", "Keye", "router_load", "dispatch_rows", "route"]
 
@@ -112,22 +109,6 @@ class KeyeConfig:
             )
 
 
-class RMSNorm(nn.Module):
-    """models/llama.py's RMSNorm (float32 accumulation) with the dtype its
-    scale is stored in as a field (``KeyeConfig.norm_dtype``)."""
-
-    eps: float = 1e-6
-    dtype: Any = jnp.bfloat16
-    param_dtype: Any = jnp.float32
-
-    @nn.compact
-    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), self.param_dtype)
-        x32 = x.astype(jnp.float32)
-        normed = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
-        return (normed * scale.astype(jnp.float32)).astype(self.dtype)
-
-
 def _dense(cfg: KeyeConfig, accumulate_as: Any = None):
     """A bias-free projection stored and multiplied in ``dtype``; with
     ``accumulate_as`` the products come out in that (wider) dtype unrounded."""
@@ -182,20 +163,10 @@ class SparseAttention(nn.Module):
         k = apply_rope(head_norm(name="k_norm")(k), positions, cfg.rope_theta)
         qi, ki, w = Indexer(cfg, name="indexer")(x, positions)
         watched = self.is_mutable_collection("intermediates")
-        scale = cfg.head_dim**-0.5
-        if on_tpu():
-            # The selection once, as the kernels' operand (and what a watcher
-            # is shown: int8); they name their own residuals.
-            chosen = select_keys(qi, ki, w, topk=cfg.topk, block=cfg.select_block)
-            out = flash_attention(q, k, v, scale=scale, selection=chosen)
-        else:
-            out, chosen = sparse_attention(
-                q, k, v, qi, ki, w, topk=cfg.topk, scale=scale,
-                block=cfg.select_block, return_selection=watched,
-            )
-            # Kept under remat="dots" by the name the flash kernel's output
-            # has: the layer's backward then recomputes a tile once, not twice.
-            out = checkpoint_name(out, FLASH_OUT)
+        out, chosen = selected_attention(
+            q, k, v, qi, ki, w, topk=cfg.topk, scale=cfg.head_dim**-0.5,
+            block=cfg.select_block, return_selection=watched,
+        )
         if watched:
             self.sow("intermediates", "selection", chosen)
         return dense(features=cfg.dim, axis=(-2, -1), kernel_init=_into_residual(cfg), name="wo")(out)
@@ -258,37 +229,6 @@ class Block(nn.Module):
         return x + ExpertLayer(cfg, name="moe")(norm(name="mlp_norm")(x))
 
 
-def _remat_policy(remat: str):
-    """``dots`` keeps what the projections' matmuls produced and the flash
-    kernel's output and logsumexp by their names, as models/llama.py's policy
-    does (a dropped logsumexp is a second forward call in the backward), and
-    the selection by its own (one int8 (s, s) a layer: dropped, the backward
-    would score and select again); but only the dots WITHOUT batch
-    dimensions: a tile's index scores and attention scores are batched dots,
-    and kept they would be the whole (heads, s, s) arrays the tiles exist to
-    avoid (a policy reaches through the tiles' own ``jax.checkpoint``: 36 GB
-    at 6 layers x 8192)."""
-    if remat != "dots":
-        return None
-    policies = jax.checkpoint_policies
-    return policies.save_from_both_policies(
-        policies.checkpoint_dots_with_no_batch_dims,
-        policies.save_only_these_names(FLASH_OUT, FLASH_LSE, SELECTION),
-    )
-
-
-class _ScanCell(nn.Module):
-    """One Block in ``(carry, broadcast) -> (carry, out)`` shape for
-    ``nn.scan``, as models/llama.py's: parameters under ``layers/block`` with
-    a leading layer axis."""
-
-    config: KeyeConfig
-
-    @nn.compact
-    def __call__(self, x: jnp.ndarray, positions: jnp.ndarray):
-        return Block(self.config, name="block")(x, positions), None
-
-
 class Keye(nn.Module):
     """``apply(params, tokens)`` returns logits over the held vocabulary;
     ``apply(params, tokens, targets=targets)`` the mean token cross-entropy,
@@ -310,23 +250,21 @@ class Keye(nn.Module):
             cfg.vocab_size, cfg.dim, dtype=cfg.dtype, param_dtype=cfg.dtype,
             embedding_init=nn.initializers.normal(1.0), name="tok_embed",
         )(tokens)
-        if cfg.scan_layers:
-            cell = _ScanCell
-            if cfg.remat != "none":
-                cell = nn.remat(cell, policy=_remat_policy(cfg.remat), prevent_cse=False)
-            stack = nn.scan(
-                cell, variable_axes={"params": 0, "intermediates": 0},
-                split_rngs={"params": True}, length=cfg.n_layers, in_axes=nn.broadcast,
-            )
-            x, _ = stack(cfg, name="layers")(x, positions)
-        else:
-            block = Block
-            if cfg.remat != "none":
-                block = nn.remat(Block, policy=_remat_policy(cfg.remat))
-            for layer in range(cfg.n_layers):
-                x = block(cfg, name=f"layer_{layer}")(x, positions)
+        # ``dots`` keeps what the projections' matmuls produced, the flash
+        # kernel's output and logsumexp and the selection by their names (one
+        # int8 (s, s) a layer: dropped, the backward would score and select
+        # again); but only the dots WITHOUT batch dimensions: a tile's index
+        # scores and attention scores are batched dots, and kept they would be
+        # the whole (heads, s, s) arrays the tiles exist to avoid (a policy
+        # reaches through the tiles' own ``jax.checkpoint``: 36 GB at 6 layers
+        # x 8192).
+        policy = remat_policy(
+            cfg.remat, jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
+            FLASH_OUT, FLASH_LSE, SELECTION,
+        )
+        x = layer_stack(Block, cfg, policy, x, positions)
         x = RMSNorm(cfg.norm_eps, cfg.dtype, cfg.norm_dtype, name="final_norm")(x)
-        head = _LMHead(cfg, name="lm_head")
+        head = LMHead(cfg.dim, cfg.vocab_size, cfg.dtype, cfg.loss_vocab_chunk, name="lm_head")
         return head(x, targets) if targets is not None else head(x).astype(jnp.float32)
 
 

@@ -25,8 +25,8 @@ The two residuals the forward kernel produced carry
 ``checkpoint_name`` tags, ``FLASH_OUT`` and ``FLASH_LSE``: a Pallas call is
 no ``dot_general``, so a remat policy that keeps dot results alone would
 drop them and run the whole forward kernel again in the backward. The
-model's ``remat="dots"`` keeps both by name (models/llama.py
-``_remat_policy``); ``remat="full"`` and any policy that does not name them
+models' ``remat="dots"`` keeps both by name (models/decoder.py
+``remat_policy``); ``remat="full"`` and any policy that does not name them
 recompute the kernel, and without remat the names do nothing.
 :func:`flash_attention_partial` is untagged: its VJP is the ring's own.
 Both kernels walk a dense (q block, KV block) grid and know where the
@@ -71,7 +71,7 @@ for a TPU target (block-layout violations, the class interpret mode cannot
 see) and tests/test_tpu_aot_compile.py compiles them
 at real widths for a described v5e (VMEM limits, misaligned slices) — both
 without a chip.
-Note "auto" attention (models/llama.py) SELECTS this kernel on real TPU
+Note "auto" attention (ops/attention.py) SELECTS this kernel on real TPU
 for long sequences, so a kernel edit reaches default-configured runs:
 never ship one without the on-chip gate.
 
@@ -852,11 +852,8 @@ def flash_attention(
 
     Shapes: q (b, s, h, d); k/v (b, s, kv_heads, d); h % kv_heads == 0.
     The sequence is padded to block multiples internally; outputs are
-    returned in the original length. The default blocks are 512x1024:
-    scripts/flash_block_sweep.py, read from the device trace on the TPU
-    v5e (PERF.md section 5 has the tables, PR 35's and PR 42's), puts every
-    smaller pair and 512x2048 over it at 2048 and at 8192, and 1024x1024
-    under it with more VMEM than a call gets unasked at 8192 rows.
+    returned in the original length. The default blocks are 512x1024
+    (``LlamaConfig.attention_block_k`` says what measured them).
     Oversized blocks clamp to the padded sequence below, so short
     sequences are unaffected. ``interpret=None`` auto-selects interpret
     mode off-TPU so the same call works in CPU tests.
@@ -952,7 +949,7 @@ def verify_on_chip() -> dict:
     the path each case's backward calls took: 1 is dq resident in VMEM for
     the whole sequence, more is that many chunks of q blocks a head.
     """
-    from torchft_tpu.models.llama import causal_attention
+    from torchft_tpu.ops.attention import causal_attention
 
     dev = jax.devices()[0]
     if dev.platform != "tpu":

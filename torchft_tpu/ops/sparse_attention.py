@@ -14,7 +14,7 @@ matmul precision whatever the model's dtype: a key that flips in or out of
 ``S_t`` is a discontinuity of the output and not a rounding of it. The
 selection carries no gradient (top-k is piecewise constant).
 
-Two paths, and models/keye.py says which runs where. Both walk the queries in
+Two paths, and :func:`selected_attention` says which runs where. Both walk the queries in
 tiles of ``block``, one at a time (``lax.map``), in ``KEY_GROUPS`` groups that
 share a key length, so that a tile early in the sequence does not pay for the
 keys after it (with 4 groups the pairs computed are 1.18 times the causal
@@ -43,10 +43,17 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-__all__ = ["SELECTION", "index_scores", "select_keys", "select_topk", "sparse_attention"]
+from torchft_tpu.ops.attention import flash_under_mesh
+from torchft_tpu.ops.flash_attention import FLASH_OUT
+from torchft_tpu.utils.platform import on_tpu
+
+__all__ = [
+    "SELECTION", "index_scores", "select_keys", "select_topk", "selected_attention",
+    "sparse_attention",
+]
 
 # checkpoint_name tag of the selection :func:`select_keys` returns: kept by
-# name (models/keye.py ``_remat_policy``), a layer's backward reads the
+# name (models/keye.py's remat line), a layer's backward reads the
 # operand its forward read and selects nothing again.
 SELECTION = "key_selection"
 
@@ -195,3 +202,24 @@ def sparse_attention(
         if return_selection:
             chosen.append(jnp.pad(sel, ((0, 0), (0, 0), (0, 0), (0, s - width))))
     return _untiled(outs), _untiled(chosen) if return_selection else None
+
+
+def selected_attention(
+    q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+    qi: jnp.ndarray, ki: jnp.ndarray, w: jnp.ndarray,
+    *, topk: int, scale: float, block: int = 512, return_selection: bool = False,
+) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
+    """:func:`sparse_attention`'s arguments and results by the path of the
+    platform. On a TPU the selection once, as the flash kernels' operand (they
+    name their own residuals; what a watcher is shown is the operand itself,
+    int8, asked for or not); elsewhere the tiled path, its output kept under
+    ``remat="dots"`` by the name the flash kernel's output has: the layer's
+    backward then recomputes a tile once, not twice."""
+    if on_tpu():
+        chosen = select_keys(qi, ki, w, topk=topk, block=block)
+        return flash_under_mesh(q, k, v, scale=scale, selection=chosen), chosen
+    out, chosen = sparse_attention(
+        q, k, v, qi, ki, w, topk=topk, scale=scale, block=block,
+        return_selection=return_selection,
+    )
+    return checkpoint_name(out, FLASH_OUT), chosen
