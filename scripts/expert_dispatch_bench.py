@@ -43,19 +43,18 @@ WARMUP = 2
 
 
 def routing(name: str, rng):
-    """(order, gates, group_sizes) as ``models.keye.route`` gives them."""
+    """(order, gates, group_sizes) as ``models.experts.route`` gives them."""
     import jax.numpy as jnp
     import numpy as np
 
-    from torchft_tpu.models.keye import KeyeConfig, route
+    from torchft_tpu.models.experts import route
 
     logits = rng.standard_normal((TOKENS, EXPERTS)).astype(np.float32)
     if name == "collapsed":
         logits[:, :2] += 10.0
         logits[:, 2:HELD] -= 20.0
     probs = jnp.exp(jnp.asarray(logits) - jnp.max(jnp.asarray(logits), axis=1, keepdims=True))
-    cfg = KeyeConfig(num_experts=EXPERTS, experts_per_token=CHOICES, num_local_experts=HELD)
-    return route(probs / jnp.sum(probs, axis=1, keepdims=True), cfg)
+    return route(probs / jnp.sum(probs, axis=1, keepdims=True), CHOICES, HELD, 0)
 
 
 def main() -> None:

@@ -50,16 +50,19 @@ def fp8(tree):
     return jax.tree_util.tree_map(leaf, tree)
 
 
-def routing_of(system):
+def routing_of(system, expected=None):
     """(params, tokens) -> rows by held expert and the rung each layer's
-    dispatch takes for them; one compiled program for every call."""
+    dispatch takes for them; one compiled program for every call.
+    ``expected``: the rows a held expert receives under uniform routing (None:
+    from this cell's keys)."""
     import jax
     import numpy as np
 
-    from torchft_tpu.models.keye import dispatch_rows, router_load
+    from torchft_tpu.models.experts import dispatch_rows, router_load
 
     config = system.config
-    expected = system.tokens_per_step * config["num_experts_per_tok"] / config["num_experts"]
+    if expected is None:
+        expected = system.tokens_per_step * config["num_experts_per_tok"] / config["num_experts"]
     seen = jax.jit(lambda params, tokens: (
         router_load(system.model, params, tokens), dispatch_rows(system.model, params, tokens)
     ))

@@ -932,3 +932,161 @@ def test_the_selection_takes_no_scan_backward_and_no_other_shape():
         flash_attention(q, k, v, interpret=True, use_pallas_bwd=False, selection=selection)
     with pytest.raises(ValueError, match="selection"):
         flash_attention(q, k, v, interpret=True, selection=selection[:, :32])
+
+
+# --- a window: the second edge of the schedule --------------------------------
+#
+# Query t sees key u iff 0 <= t - u < window (the query's own position counts).
+
+
+def _needed_pairs_closed_form(s, window):
+    """(query, key) pairs a window allows in a sequence of ``s``."""
+    full = min(s, window)
+    return full * (full + 1) // 2 + (s - full) * full
+
+
+# name: (b, s, heads, kv heads, head_dim, block_q, block_k, window)
+_WINDOW_CASES = {
+    "multiple-of-both-blocks": (1, 512, 4, 2, 16, 64, 128, 256),
+    "multiple-of-neither": (2, 300, 4, 2, 16, 64, 128, 100),
+    "longer-than-the-sequence": (1, 200, 4, 2, 16, 64, 128, 1000),
+    "exactly-the-sequence": (1, 256, 2, 1, 16, 64, 128, 256),
+    "shorter-than-a-block": (1, 320, 4, 4, 16, 64, 128, 20),
+    "one-key": (1, 160, 2, 2, 16, 32, 128, 1),
+    "group-of-7": (1, 384, 14, 2, 32, 64, 128, 144),
+    "ragged-with-a-padded-tail": (1, 333, 7, 1, 16, 48, 128, 129),
+}
+
+
+@pytest.mark.parametrize("name", list(_WINDOW_CASES))
+def test_windowed_kernels_match_dense_forward_and_gradients(name):
+    """The forward and the one backward call under a window (interpret mode),
+    against dense attention under the same mask: the output and the three
+    gradients; and the scan-based backward, the CPU's own, agrees too."""
+    b, s, h, kv, d, block_q, block_k, window = _WINDOW_CASES[name]
+    q, k, v = _qkv(b, s, h, kv, d, seed=31)
+    d_out = jax.random.normal(jax.random.PRNGKey(32), q.shape, jnp.float32)
+    dense, dense_vjp = jax.vjp(
+        lambda q, k, v: causal_attention(q, k, v, d**-0.5, window), q, k, v
+    )
+    want = (dense, *dense_vjp(d_out))
+    for pallas_bwd in (True, False):
+        out, vjp = jax.vjp(
+            lambda q, k, v: flash_attention(
+                q, k, v, block_q=block_q, block_k=block_k, interpret=True,
+                use_pallas_bwd=pallas_bwd, window=window,
+            ),
+            q, k, v,
+        )
+        for got, ref, what in zip((out, *vjp(d_out)), want, ("out", "dq", "dk", "dv")):
+            np.testing.assert_allclose(
+                np.asarray(got), np.asarray(ref), atol=5e-5, err_msg=f"{what} ({pallas_bwd})"
+            )
+
+
+def test_a_window_that_covers_the_sequence_is_the_causal_call():
+    """``window >= s`` changes nothing, and is not a call of another name: the
+    traced program holds the causal call's tables of three rows."""
+    q, k, v = _qkv(1, 128, 2, 1, 16, seed=5)
+    call = lambda window: jax.make_jaxpr(
+        lambda q, k, v: flash_attention(
+            q, k, v, block_q=32, block_k=128, interpret=True, window=window
+        )
+    )(q, k, v)
+    assert str(call(128)) == str(call(None)) == str(call(4096))
+    assert str(call(127)) != str(call(None))
+
+
+@pytest.mark.parametrize(
+    "s, window, block_q, block_k",
+    [(2048, 512, 128, 256), (1000, 300, 64, 128), (512, 512, 64, 128), (768, 64, 128, 128)],
+)
+def test_the_windows_pairs_by_class_add_up_to_the_closed_form(s, window, block_q, block_k):
+    """Every (query, key) pair the window allows lies in a block pair the
+    schedule needs (inside: all of its pairs allowed; diagonal or edge: some),
+    none in a pair it skips (above or behind); counted pair by pair, the
+    allowed ones are the closed form."""
+    from torchft_tpu.ops import flash_attention as fa
+
+    qp, kp = fa._padded_positions(None, None, 1, s, s, block_q, block_k)
+    tables = fa._block_schedule(qp, kp, block_q, block_k, True, window)
+    classes = np.asarray(fa._block_classes(*tables, window))[0]
+    at_q = np.asarray(qp[0]).reshape(-1, block_q)
+    at_k = np.asarray(kp[0]).reshape(-1, block_k)
+    total = 0
+    for iq in range(classes.shape[0]):
+        for ik in range(classes.shape[1]):
+            apart = at_q[iq][:, None].astype(np.int64) - at_k[ik][None, :]
+            allowed = int(np.sum((apart >= 0) & (apart < window) & (at_q[iq][:, None] >= 0)))
+            if classes[iq, ik] == 0:
+                assert allowed == 0, (iq, ik)
+            if classes[iq, ik] == 2:
+                assert allowed == block_q * block_k, (iq, ik)
+            total += allowed
+    assert total == _needed_pairs_closed_form(s, window)
+    counts = fa._class_counts(s, s, block_q, block_k, window=window)
+    causal = fa._class_counts(s, s, block_q, block_k)
+    assert counts["above"] == causal["above"]
+    assert counts["behind"] + counts["edge"] + counts["under"] == causal["under"]
+    assert counts["behind"] == int(np.sum(classes == 0)) - causal["above"]
+
+
+def test_the_cells_windowed_layers_walk_under_half_of_the_causal_block_pairs():
+    """At 1 x 16,384 with a window of 4,096 in 512 x 1,024 blocks: the closed
+    form (43.7% of causal attention's pairs) and the block pairs by class."""
+    from torchft_tpu.ops import flash_attention as fa
+
+    s, window = 16384, 4096
+    assert _needed_pairs_closed_form(s, window) == 58_722_304
+    assert round(100 * 58_722_304 / (s * (s + 1) // 2), 1) == 43.7
+    counts = fa._class_counts(s, s, 512, 1024, window=window)
+    assert counts == {"above": 240, "diagonal": 32, "under": 84, "behind": 132, "edge": 24}
+    causal = fa._class_counts(s, s, 512, 1024)
+    assert causal == {"above": 240, "diagonal": 32, "under": 240}
+    # 140 block pairs walked of the causal call's 272: 51.5% of its blocks
+    # for 43.7% of its pairs (the edge's blocks are computed whole).
+    assert counts["diagonal"] + counts["under"] + counts["edge"] == 140
+
+
+@pytest.mark.parametrize("s, window, block_q, block_k", [(1024, 256, 64, 128), (300, 100, 64, 128)])
+def test_no_block_behind_the_window_is_fetched(s, window, block_q, block_k):
+    """Under a window the index maps stop on both sides: a step before a q
+    block's first needed KV block names that block, a step after its last
+    names the last (the backward's q blocks likewise), so a skipped pair names
+    the block its neighbour in the walk holds; a needed pair names its own."""
+    from torchft_tpu.ops import flash_attention as fa
+
+    qp, kp = fa._padded_positions(None, None, 1, s, s, block_q, block_k)
+    q_sched, k_sched = (
+        np.asarray(t) for t in fa._block_schedule(qp, kp, block_q, block_k, True, window)
+    )
+    assert q_sched.shape[1] == k_sched.shape[1] == 4
+    classes = np.asarray(fa._block_classes(q_sched, k_sched, window))[0]
+    nq, nk = classes.shape
+    for iq in range(nq):
+        needed = np.flatnonzero(classes[iq])
+        named = [int(fa._kv_block(0, iq, ik, q_sched, True)) for ik in range(nk)]
+        assert named == [int(np.clip(ik, needed[0], needed[-1])) for ik in range(nk)]
+    for ik in range(nk):
+        needed = np.flatnonzero(classes[:, ik])
+        named = [int(fa._q_block(0, ik, iq, k_sched, True)) for iq in range(nq)]
+        assert named == [int(np.clip(iq, needed[0], needed[-1])) for iq in range(nq)]
+    assert int(np.sum(classes == 0)) > int(np.sum(np.triu(np.ones((nq, nk)), 1)) // 2)
+
+
+def test_a_window_and_a_selection_do_not_meet_and_the_blockwise_path_takes_a_window():
+    from torchft_tpu.ops.attention import attend
+
+    q, k, v = _qkv(1, 96, 4, 2, 16, seed=8)
+    with pytest.raises(ValueError, match="selection"):
+        flash_attention(
+            q, k, v, interpret=True, window=8, selection=jnp.ones((1, 96, 96), jnp.int8)
+        )
+    with pytest.raises(ValueError, match="window"):
+        flash_attention(q, k, v, interpret=True, window=0)
+    dense = causal_attention(q, k, v, 16**-0.5, 40)
+    for impl in ("dense", "blockwise", "flash"):
+        out = attend(q, k, v, scale=16**-0.5, impl=impl, block_size=32, window=40)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(dense), atol=2e-5, err_msg=impl)
+    with pytest.raises(ValueError, match="ring"):
+        attend(q, k, v, scale=16**-0.5, impl="ring", window=40)

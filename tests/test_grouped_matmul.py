@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from torchft_tpu.models.keye import ExpertLayer, KeyeConfig
+from torchft_tpu.models.keye import KeyeConfig, expert_layer
 from torchft_tpu.ops import grouped_matmul as grouped
 from torchft_tpu.ops.grouped_matmul import dispatch_rungs, grouped_matmul
 
@@ -86,7 +86,7 @@ LADDER_TOKENS = 256
 
 
 def steered_layer(held_rows: int):
-    """(params, x) of an ``ExpertLayer(LADDER)`` whose router sends exactly
+    """(params, x) of an ``expert_layer(LADDER)`` whose router sends exactly
     ``held_rows`` of the 1,024 choices to held experts: the router reads a
     token's logits off its first 32 features (an identity block over a little
     noise), and x carries, for each token, high scores for as many held
@@ -101,7 +101,7 @@ def steered_layer(held_rows: int):
         others = local + rng.permutation(experts - local)[: k - held_of[t]]
         logits[t, np.concatenate([mine, others])] = rng.uniform(2.0, 3.0, k)
     x = np.concatenate([logits, rng.normal(size=(n, 16)).astype(np.float32)], axis=1)
-    params = ExpertLayer(LADDER).init(jax.random.PRNGKey(1), jnp.asarray(x[None]))
+    params = expert_layer(LADDER).init(jax.random.PRNGKey(1), jnp.asarray(x[None]))
     kernel = np.concatenate([np.eye(experts), 0.01 * rng.normal(size=(16, experts))])
     params["params"]["router"]["kernel"] = jnp.asarray(kernel, jnp.float32)
     return params, jnp.asarray(x[None])
@@ -126,7 +126,7 @@ def test_every_rung_is_the_worst_case_path(held_rows, rung, use_pallas, monkeypa
         grouped, "grouped_matmul", partial(grouped_matmul, use_pallas=use_pallas, interpret=True)
     )
     params, x = steered_layer(held_rows)
-    layer = ExpertLayer(LADDER)
+    layer = expert_layer(LADDER)
     _, seen = layer.apply(params, x, mutable=["intermediates"])
     seen = seen["intermediates"]
     assert int(seen["rows_by_expert"][0].sum()) == held_rows
@@ -160,7 +160,7 @@ SUM_RUNGS = (512, 1024, 4096)
 
 
 def routed(routing: str):
-    """(order, gates, group_sizes) as ``keye.route`` gives them, for a routing
+    """(order, gates, group_sizes) as ``models.experts.route`` gives them, for a routing
     made by hand: which expert each of a token's eight choices names."""
     n, k, local, experts = SUM_TOKENS, SUM_CHOICES, SUM_HELD, SUM_EXPERTS
     rng = np.random.default_rng(len(routing))

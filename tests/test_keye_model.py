@@ -28,7 +28,7 @@ sys.path.insert(0, str(ROOT))
 
 from chipbench import reference, spec  # noqa: E402
 from torchft_tpu.models.keye import (  # noqa: E402
-    ExpertLayer, Keye, KeyeConfig, dispatch_rows, router_load,
+    Keye, KeyeConfig, dispatch_rows, expert_layer, router_load,
 )
 from torchft_tpu.ops import attention  # noqa: E402
 from torchft_tpu.ops.cross_entropy import chunked_cross_entropy  # noqa: E402
@@ -215,8 +215,8 @@ def test_the_eight_shares_add_up_to_the_uncut_expert_layer():
         dtype=jnp.float32, n_heads=2, n_kv_heads=1, head_dim=16,
     )
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, 32))
-    params = ExpertLayer(whole).init(jax.random.PRNGKey(1), x)
-    uncut = ExpertLayer(whole).apply(params, x)
+    params = expert_layer(whole).init(jax.random.PRNGKey(1), x)
+    uncut = expert_layer(whole).apply(params, x)
     parts = []
     for share in range(8):
         held = slice(2 * share, 2 * share + 2)
@@ -224,7 +224,7 @@ def test_the_eight_shares_add_up_to_the_uncut_expert_layer():
             name: params["params"][name][held] for name in ("w_gate", "w_up", "w_down")
         }}}
         cut = replace(whole, num_local_experts=2, expert_share=share)
-        parts.append(ExpertLayer(cut).apply(mine, x))
+        parts.append(expert_layer(cut).apply(mine, x))
     assert relative(sum(parts), uncut) < 1e-6
     assert all(float(jnp.linalg.norm(p)) > 0 for p in parts)
     config = {"num_local_experts": 16, "expert_share": 0, "num_experts_per_tok": 4}
@@ -278,7 +278,7 @@ def test_the_uncut_layer_has_one_path_and_a_cut_one_a_conditional():
     )
     x = jnp.zeros((1, 256, cut.dim))
     for cfg, conditional in ((cut, True), (replace(cut, num_local_experts=32), False)):
-        layer = ExpertLayer(cfg)
+        layer = expert_layer(cfg)
         params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
         program = str(jax.make_jaxpr(jax.grad(lambda p: jnp.sum(layer.apply(p, x))))(params))
         assert ("cond" in program) is conditional

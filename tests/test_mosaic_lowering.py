@@ -515,3 +515,38 @@ def test_8b_sharded_flash_train_step_lowers_for_tpu(monkeypatch):
             .lower(lowering_platforms=("tpu",))
         )
     assert "tpu_custom_call" in lowered.as_text()
+
+
+def test_windowed_flash_kernels_lower_for_tpu_at_the_smallthinker_cells_geometry():
+    """With a window of 4,096 at 1 x 16,384 (28 q heads over 4 KV heads of
+    128, as the cell ``smallthinker-21b-a3b-1chip.ftddp-seq16k`` runs its
+    windowed layers): the causal call's grids and operands but for the two
+    schedule tables, which gain a fourth row (the far edge), the calls carry
+    names of their own, and the pair lowers for a TPU. The same call without
+    the window keeps its three rows and no name."""
+    from torchft_tpu.ops.flash_attention import WINDOW_BWD, WINDOW_FWD
+
+    b, s, h, kv, d = 1, 16384, 28, 4, 128
+    q = _sds((b, s, h, d), jnp.bfloat16)
+    k = _sds((b, s, kv, d), jnp.bfloat16)
+
+    def gradient(window):
+        def loss(q, k, v):
+            out = flash_attention(q, k, v, interpret=False, use_pallas_bwd=True, window=window)
+            return jnp.sum(out.astype(jnp.float32) ** 2)
+
+        traced = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q, k, k)
+        return (traced, *_pallas_calls(traced.jaxpr.jaxpr))
+
+    traced, forward, backward = gradient(4096)
+    assert forward.params["grid_mapping"].grid == (1, 28, 32, 16)
+    assert backward.params["grid_mapping"].grid == (1, 28, 1, 16, 32)
+    assert _kernel_refs(forward)[:2] == [((1, 4, 32), _I32), ((1, 4, 16), _I32)]
+    assert _kernel_refs(backward)[:2] == [((1, 4, 32), _I32), ((1, 4, 16), _I32)]
+    assert _kernel_refs(forward)[2:] == _QKV + _POSITIONS + _FWD_REST
+    assert [call.params["name"] for call in (forward, backward)] == [WINDOW_FWD, WINDOW_BWD]
+    traced.lower(lowering_platforms=("tpu",))
+    _, forward, backward = gradient(None)
+    assert _kernel_refs(forward)[:2] == [((1, 3, 32), _I32), ((1, 3, 16), _I32)]
+    assert _kernel_refs(backward)[:2] == [((1, 3, 32), _I32), ((1, 3, 16), _I32)]
+    assert not {WINDOW_FWD, WINDOW_BWD} & {call.params["name"] for call in (forward, backward)}

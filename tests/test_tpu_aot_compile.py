@@ -820,3 +820,149 @@ def test_dots_step_of_a_keye_share_keeps_every_worst_case_row_inside_the_last_ru
     assert rows and all(name in inside_last for name, _ in rows), [
         line[:160] for name, line in rows if name not in inside_last
     ]
+
+
+def test_windowed_flash_kernels_compile_at_the_smallthinker_cells_geometry(chip) -> None:
+    """The forward and the one backward call under a window of 4,096 at
+    1 x 16,384 with 28 / 4 heads of 128 and the default 512 x 1024 blocks,
+    compiled for a described v5e: the two calls carry their own names in the
+    compiled text (what a device trace will call them), the forward fits the
+    16 MiB a call gets unasked, and the backward, whose float32 dq holds the
+    head's 16,384 rows, states the limit it works out from its shapes."""
+    from torchft_tpu.ops import flash_attention as fa
+
+    s, h, kv, d = 16384, 28, 4, 128
+    need = fa._bwd_vmem_bytes(s, 512, 1024, d, 2, 2)
+    assert fa._SCOPED_VMEM_BYTES < need <= fa._MAX_VMEM_BYTES
+    assert fa._q_chunks(s, fa._MAX_VMEM_BYTES, 512, 1024, d, 2, 2) == (1, 32)
+
+    def loss(q, k, v):
+        out = fa.flash_attention(q, k, v, interpret=False, window=4096)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1, 2)),
+        _sds((1, s, h, d), jnp.bfloat16, chip), _sds((1, s, kv, d), jnp.bfloat16, chip),
+        _sds((1, s, kv, d), jnp.bfloat16, chip),
+    )
+    calls = _mosaic_calls(compiled)
+    # Differentiated on its own a call's name is wrapped (``jvp_..._``); in the
+    # model's step it is the bare name and a number (the test below).
+    (forward,) = [call for call in calls if fa.WINDOW_FWD in call[0]]
+    (backward,) = [call for call in calls if fa.WINDOW_BWD in call[0]]
+    assert len(calls) == 2 and backward[1] == [need]
+    assert forward[2][0] <= fa._SCOPED_VMEM_BYTES and backward[2][0] <= need
+
+
+# The cell ``smallthinker-21b-a3b-1chip.ftddp-seq16k``'s own size, and a twin
+# at toy widths for tier-1: the same head_dim, GQA group of 7, block sizes,
+# period of four kinds and a window of a quarter of the sequence.
+_SMALLTHINKER_TOY = {
+    "hidden_size": 256, "num_attention_heads": 7, "num_key_value_heads": 1,
+    "moe_ffn_hidden_size": 128, "router_width": 16, "moe_num_active_primary_experts": 3,
+    "moe_num_primary_experts": 2, "vocab_size": 2048, "sliding_window_size": 1024,
+}
+
+
+@pytest.mark.parametrize(
+    "widths, seq",
+    [
+        pytest.param({}, 16384, marks=pytest.mark.slow, id="smallthinker-1x16384"),  # 2 min
+        pytest.param(_SMALLTHINKER_TOY, 4096, id="toy-1x4096"),
+    ],
+)
+def test_dots_step_of_the_smallthinker_cell_is_one_traced_period_and_fits(
+    chip, monkeypatch, widths, seq
+) -> None:
+    """The FT-DDP fused step of the smallthinker cell (eight layers scanned as
+    two periods of four kinds, bf16, ``dots``, AdamW) compiled for a described
+    v5e as the model builds it on a TPU: the period stays one loop body, so the
+    compiled text holds the period's Mosaic calls once: one forward and one
+    backward call of the full layer under its scope's name, three forward and
+    three backward calls under the window's own names, and the routed layer's
+    beside them; the names are the ones the architecture file's patterns find.
+    At the cell's own size the program's arguments, results and temporaries
+    come to under 14 of the chip's 15.75 GiB (13.72: PERF.md section 6, PR 54)."""
+    import json
+    from pathlib import Path
+
+    import torchft_tpu.ops.attention as attention
+    import torchft_tpu.ops.flash_attention as flash
+    import torchft_tpu.ops.grouped_matmul as grouped
+    from chipbench import spec
+    from chipbench.model import System
+    from torchft_tpu.optim import make_jit_fused_step
+
+    for module in (attention, flash, grouped):
+        monkeypatch.setattr(module, "on_tpu", lambda: True)
+    root = Path(__file__).parent.parent
+    config = json.loads(
+        (root / "chipbench/configs/smallthinker-21b-a3b-ep8-1chip.json").read_text()
+    )
+    config.update(widths)
+    assert config["run"]["remat"] == "dots" and config["run"]["scan_layers"]
+    architecture = spec.load_module(root / "chipbench/architectures/smallthinker.py")
+    system = System(config, architecture, {"batch": 1, "seq": seq}, seed=0)
+    assert system.model.config.period == 4 and system.model.config.n_layers == 8
+    params = jax.eval_shape(system.init_params)
+    assert sorted(params["params"]["layers"]) == [f"block_{kind}" for kind in range(4)]
+    opt_state = jax.eval_shape(system.tx.init, params)
+    program = (
+        make_jit_fused_step(system.tx, system.loss_fn)
+        .lower(
+            _sds_tree(params, chip), _sds_tree(opt_state, chip),
+            _sds((1, seq + 1), jnp.int32, chip),
+        )
+        .compile()
+    )
+    names = [name for name, _, _ in _mosaic_calls(program)]
+    attention_calls = [n for n in names if architecture.ATTENTION_KERNEL.search(n)]
+    window_calls = [n for n in names if architecture.WINDOW_KERNEL.search(n)]
+    assert len(attention_calls) == 8 and len(window_calls) == 6, names
+    assert sum(n.startswith(flash.WINDOW_FWD) for n in window_calls) == 3
+    assert all(
+        architecture.EXPERT_KERNEL.search(n) for n in names if n not in attention_calls
+    ), names
+    assert not [n for n in attention_calls if architecture.EXPERT_KERNEL.search(n)]
+    if not widths:
+        memory = program.memory_analysis()
+        total = (
+            memory.argument_size_in_bytes + memory.output_size_in_bytes
+            + memory.temp_size_in_bytes - memory.alias_size_in_bytes
+        )
+        assert total < 14 * 2**30, total / 2**30
+
+
+@pytest.mark.slow  # four minutes: two float32 programs of eight written-out layers
+def test_the_smallthinker_cells_reference_programs_fit_the_chip(chip) -> None:
+    """The float32 reference's loss and its update at the cell's own size
+    (1 x 16,384, eight layers, attention in blocks of 512 queries a key-value
+    group), compiled for a described v5e: 3.13 and 14.06 GiB (PERF.md
+    section 6, PR 54), both inside the chip's 15.75 with the bf16 weights
+    they are given."""
+    import json
+    from pathlib import Path
+
+    from chipbench import reference, spec
+    from chipbench.model import System
+
+    root = Path(__file__).parent.parent
+    config = json.loads(
+        (root / "chipbench/configs/smallthinker-21b-a3b-ep8-1chip.json").read_text()
+    )
+    architecture = spec.load_module(root / "chipbench/architectures/smallthinker.py")
+    system = System(config, architecture, {"batch": 1, "seq": 16384}, seed=0)
+    params = _sds_tree(jax.eval_shape(system.init_params), chip)
+    tokens = _sds((1, 16385), jnp.int32, chip)
+
+    def total(compiled):
+        memory = compiled.memory_analysis()
+        return (
+            memory.argument_size_in_bytes + memory.output_size_in_bytes
+            + memory.temp_size_in_bytes - memory.alias_size_in_bytes
+        )
+
+    loss = reference.make_loss(architecture, config).lower(params, tokens).compile()
+    assert total(loss) < 4 * 2**30
+    update = reference.make_loss_after_first_update(architecture, config)
+    assert total(update.lower(params, tokens, tokens).compile()) < 14.5 * 2**30

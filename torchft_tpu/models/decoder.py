@@ -1,8 +1,10 @@
 """What every decoder here shares, written once: rotary, the norm, the output
-head with its fused loss, the ``dots`` remat rule and the layer stack. A model
-file (models/llama.py, models/keye.py) is a config, a block and a top-level
+head with its fused loss, the ``dots`` remat rule and the layer stack (of one
+kind of block, or of a period of kinds). A model file (models/llama.py,
+models/keye.py, models/smallthinker.py) is a config, a block and a top-level
 module of embedding, :func:`layer_stack`, final norm and head; its attention
-asks ops/ for a kernel (ops/attention.py, ops/sparse_attention.py).
+asks ops/ for a kernel (ops/attention.py, ops/sparse_attention.py), and a
+routed expert layer is models/experts.py's.
 """
 
 from __future__ import annotations
@@ -15,7 +17,10 @@ import jax.numpy as jnp
 
 from torchft_tpu.ops.cross_entropy import chunked_cross_entropy
 
-__all__ = ["apply_rope", "RMSNorm", "LMHead", "remat_policy", "layer_stack"]
+__all__ = [
+    "apply_rope", "RMSNorm", "LMHead", "into_residual", "remat_policy", "layer_stack",
+    "sown_by_layer",
+]
 
 
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
@@ -69,6 +74,15 @@ class LMHead(nn.Module):
         return chunked_cross_entropy(x, kernel, targets, self.loss_vocab_chunk)
 
 
+def into_residual(depth: int, **axes):
+    """The initialiser of a projection INTO the residual stream (an attention's
+    output projection, a gated unit's way back) of a pre-norm stack ``depth``
+    layers deep: lecun-normal over sqrt(2 x depth), the scaled initialisation
+    of deep pre-norm stacks (``KeyeConfig.init_depth`` says what it is for).
+    ``axes``: ``variance_scaling``'s, for a kernel with more than two."""
+    return nn.initializers.variance_scaling(1.0 / (2 * depth), "fan_in", "truncated_normal", **axes)
+
+
 def remat_policy(remat: str, dots: Any, *names: str):
     """The policy of ``remat`` for :func:`layer_stack`. ``dots`` keeps what
     the MXU produced, the ``dot_general`` results the model's ``dots`` policy
@@ -96,30 +110,94 @@ class _ScanCell(nn.Module):
         return self.block(self.config, name="block")(x, positions), None
 
 
-def layer_stack(block: Any, cfg: Any, policy: Any, x: jnp.ndarray, positions: jnp.ndarray):
+class _PeriodCell(nn.Module):
+    """One period of blocks, kinds 0 .. period - 1 one after the other, in
+    the shape ``nn.scan`` wants; params live under ``<stack>/block_<kind>``
+    with a leading axis of periods."""
+
+    block: Any  # the block's class (rematerialised already, where asked)
+    config: Any
+    period: int
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray, positions: jnp.ndarray):
+        for kind in range(self.period):
+            x = self.block(self.config, kind, name=f"block_{kind}")(x, positions)
+        return x, None
+
+
+def _scanned(cell: Any, length: int):
+    """``cell`` scanned ``length`` times: params and intermediates gain a
+    leading axis, positions are broadcast."""
+    return nn.scan(
+        cell,
+        variable_axes={"params": 0, "intermediates": 0},
+        split_rngs={"params": True},
+        length=length,
+        in_axes=nn.broadcast,
+    )
+
+
+def layer_stack(
+    block: Any, cfg: Any, policy: Any, x: jnp.ndarray, positions: jnp.ndarray, period: int = 1
+):
     """``cfg.n_layers`` of ``block(cfg)(x, positions) -> x``, called inside the
     model's ``__call__``. ``cfg.scan_layers``: one ``lax.scan`` over the stack,
     one traced and compiled block for the whole depth (O(1) HLO size and
     compile time in depth), leaves under ``layers/block/...`` with a leading
     layer axis (as is what a block sows into ``intermediates``); otherwise
     inlined copies under ``layer_<i>/...``. ``cfg.remat`` other than ``none``
-    rematerialises each block under ``policy`` (:func:`remat_policy`)."""
+    rematerialises each block under ``policy`` (:func:`remat_policy`).
+
+    ``period`` > 1: the depth repeats a period of that many KINDS of block,
+    layer i of kind ``i % period``, built ``block(cfg, kind)``. Scanned, the
+    scan is over the periods: one traced period for the whole depth, leaves
+    under ``layers/block_<kind>/...`` with a leading axis of ``n_layers /
+    period``, each block of the period rematerialised on its own. Inlined,
+    ``layer_<i>`` as before. A stack of one kind is the tree it always was:
+    the two scanned paths below (the whole cell rematerialised for one kind,
+    each block of the period for several) are kept apart for that alone, so
+    that a one-kind stack keeps its parameter names and its compiled program
+    (heals, checkpoints and the benchmark's cells read both)."""
+    if period > 1:
+        if cfg.n_layers % period:
+            raise ValueError(f"{cfg.n_layers} layers are not whole periods of {period}")
+        if cfg.remat != "none":
+            block = nn.remat(block, policy=policy, prevent_cse=not cfg.scan_layers)
+        if cfg.scan_layers:
+            stack = _scanned(_PeriodCell, cfg.n_layers // period)
+            return stack(block, cfg, period, name="layers")(x, positions)[0]
+        for layer in range(cfg.n_layers):
+            x = block(cfg, layer % period, name=f"layer_{layer}")(x, positions)
+        return x
     if cfg.scan_layers:
         cell = _ScanCell
         if cfg.remat != "none":
             # prevent_cse is safe (and standard) under scan: the loop
             # boundary already blocks the CSE remat would otherwise fight.
             cell = nn.remat(cell, policy=policy, prevent_cse=False)
-        stack = nn.scan(
-            cell,
-            variable_axes={"params": 0, "intermediates": 0},
-            split_rngs={"params": True},
-            length=cfg.n_layers,
-            in_axes=nn.broadcast,
-        )
-        return stack(block, cfg, name="layers")(x, positions)[0]
+        return _scanned(cell, cfg.n_layers)(block, cfg, name="layers")(x, positions)[0]
     if cfg.remat != "none":
         block = nn.remat(block, policy=policy)
     for layer in range(cfg.n_layers):
         x = block(cfg, name=f"layer_{layer}")(x, positions)
     return x
+
+
+def sown_by_layer(
+    model: nn.Module, params: Any, tokens: jnp.ndarray, module: str, name: str
+) -> jnp.ndarray:
+    """What the blocks' submodule ``module`` sowed into ``intermediates`` under
+    ``name`` for ``tokens`` (b, s), by layer: (n_layers, ...), from any layout
+    of :func:`layer_stack`'s tree."""
+    n_layers = model.config.n_layers
+    _, seen = model.apply(params, tokens, mutable=["intermediates"])
+    seen = seen["intermediates"]
+    if "layers" not in seen:
+        return jnp.stack([seen[f"layer_{i}"][module][name][0] for i in range(n_layers)])
+    stack = seen["layers"]
+    if "block" in stack:
+        return stack["block"][module][name][0]
+    kinds = [stack[f"block_{kind}"][module][name][0] for kind in range(len(stack))]
+    by_period = jnp.stack(kinds, axis=1)  # (periods, period, ...)
+    return by_period.reshape(n_layers, *by_period.shape[2:])

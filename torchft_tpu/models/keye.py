@@ -16,25 +16,18 @@ Per layer, pre-norm:
   The selection carries no gradient, so the language-model loss gives the
   indexer's leaves gradient zero; the term that trains an indexer in the
   published mechanism is not built.
-- experts: a softmax router over ALL ``num_experts``, the ``experts_per_token``
-  largest renormalised to one. The layer HOLDS ``num_local_experts`` of them,
-  experts ``expert_share * num_local_experts ..``, and computes their part of
-  the result: the rows routed here, sorted by expert, through one grouped
-  product a matrix (ops/grouped_matmul.py ``routed_experts``). Dropless and
-  exact: the row buffer follows the rows that arrive, up a short ladder of
-  row counts chosen each layer step from the router's own tally, whose last
-  rung is the worst case, every token's every choice. What the absent
-  experts would add is left out and nothing stands in for their chips: on
-  one chip the layer runs without its exchange. ``num_local_experts ==
-  num_experts`` is the whole layer, one path at the worst case.
+- experts: models/experts.py ``RoutedExperts``, the routed layer as one
+  chip's share (a softmax router over ALL ``num_experts``, the
+  ``experts_per_token`` largest renormalised to one, ``num_local_experts``
+  of them held here), its router on the layer's own normed input and SiLU
+  in the gated unit.
 
 Rotary, the norm (its scale stored in ``norm_dtype``: float32 unless a
 configuration says otherwise), the output head with its fused loss and the
 scanned, rematerialised layer stack are models/decoder.py's (the ``dots``
 policy keeps the projections' products, the flash kernel's output and
-logsumexp and the selection, not a tile's scores). ``router_load``
-counts the rows each held expert receives, ``dispatch_rows`` the row count
-each layer's dispatch ran at.
+logsumexp and the selection, not a tile's scores). ``router_load`` and
+``dispatch_rows`` are models/experts.py's.
 """
 
 from __future__ import annotations
@@ -47,12 +40,14 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from torchft_tpu.models.decoder import LMHead, RMSNorm, apply_rope, layer_stack, remat_policy
+from torchft_tpu.models.decoder import (
+    LMHead, RMSNorm, apply_rope, into_residual, layer_stack, remat_policy,
+)
+from torchft_tpu.models.experts import RoutedExperts, dispatch_rows, router_load
 from torchft_tpu.ops.flash_attention import FLASH_LSE, FLASH_OUT
-from torchft_tpu.ops.grouped_matmul import routed_experts
 from torchft_tpu.ops.sparse_attention import SELECTION, selected_attention
 
-__all__ = ["KeyeConfig", "Keye", "router_load", "dispatch_rows", "route"]
+__all__ = ["KeyeConfig", "Keye", "expert_layer", "router_load", "dispatch_rows"]
 
 
 @dataclass(frozen=True)
@@ -118,13 +113,6 @@ def _dense(cfg: KeyeConfig, accumulate_as: Any = None):
     return partial(nn.DenseGeneral, use_bias=False, dtype=cfg.dtype, param_dtype=cfg.dtype, **into)
 
 
-def _into_residual(cfg: KeyeConfig, **axes):
-    """The initialiser of a projection into the residual stream (see
-    ``KeyeConfig.init_depth``)."""
-    depth = cfg.init_depth or cfg.n_layers
-    return nn.initializers.variance_scaling(1.0 / (2 * depth), "fan_in", "truncated_normal", **axes)
-
-
 class Indexer(nn.Module):
     """(qI, kI, w) in ``indexer_dtype`` from the layer's normed input: products
     of the stored weights accumulated in that dtype, never rounded to
@@ -169,53 +157,20 @@ class SparseAttention(nn.Module):
         )
         if watched:
             self.sow("intermediates", "selection", chosen)
-        return dense(features=cfg.dim, axis=(-2, -1), kernel_init=_into_residual(cfg), name="wo")(out)
+        init = into_residual(cfg.init_depth or cfg.n_layers)
+        return dense(features=cfg.dim, axis=(-2, -1), kernel_init=init, name="wo")(out)
 
 
-def route(probs: jnp.ndarray, cfg: KeyeConfig):
-    """probs (n, num_experts) -> for each of the n x experts_per_token choices,
-    in the order the grouped product wants them: ``order`` (which choice sits
-    in each row: choices sorted by held expert, those for experts held
-    elsewhere last), ``gates`` (n, k) renormalised over the k chosen, and
-    ``group_sizes`` (num_local_experts + 1,), rows by held expert and, last,
-    the rows that belong elsewhere."""
-    local = cfg.num_local_experts
-    top, experts = jax.lax.top_k(probs, cfg.experts_per_token)
-    gates = top / jnp.sum(top, axis=-1, keepdims=True)
-    mine = experts - cfg.expert_share * local
-    group = jnp.where((mine >= 0) & (mine < local), mine, local).reshape(-1)
-    order = jnp.argsort(group, stable=True)
-    group_sizes = jnp.bincount(group, length=local + 1).astype(jnp.int32)
-    return order, gates, group_sizes
-
-
-class ExpertLayer(nn.Module):
-    config: KeyeConfig
-
-    @nn.compact
-    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
-        cfg = self.config
-        b, s, d = x.shape
-        n, local, f = b * s, cfg.num_local_experts, cfg.moe_hidden
-        axes = dict(in_axis=-2, out_axis=-1, batch_axis=0)
-        init = nn.initializers.lecun_normal(**axes)
-        w_gate = self.param("w_gate", init, (local, d, f), cfg.dtype)
-        w_up = self.param("w_up", init, (local, d, f), cfg.dtype)
-        w_down = self.param("w_down", _into_residual(cfg, **axes), (local, f, d), cfg.dtype)
-        with jax.named_scope("tpuft::expert_layer"):
-            flat = x.reshape(n, d)
-            logits = _dense(cfg, jnp.float32)(
-                features=cfg.num_experts, name="router"
-            )(flat)
-            probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-            order, gates, group_sizes = route(probs, cfg)
-            self.sow("intermediates", "rows_by_expert", group_sizes[:local])
-            out, rows = routed_experts(
-                flat, order, gates, group_sizes, w_gate, w_up, w_down,
-                num_experts=cfg.num_experts, activation=nn.silu,
-            )
-            self.sow("intermediates", "dispatch_rows", rows)
-            return out.astype(cfg.dtype).reshape(b, s, d)
+def expert_layer(cfg: KeyeConfig, **module) -> RoutedExperts:
+    """The routed layer of this configuration: SiLU, the projection back into
+    the residual stream initialised for ``init_depth``."""
+    depth = cfg.init_depth or cfg.n_layers
+    return RoutedExperts(
+        dim=cfg.dim, hidden=cfg.moe_hidden, num_experts=cfg.num_experts,
+        experts_per_token=cfg.experts_per_token, num_local_experts=cfg.num_local_experts,
+        expert_share=cfg.expert_share, activation=nn.silu, dtype=cfg.dtype,
+        down_init=into_residual(depth, in_axis=-2, out_axis=-1, batch_axis=0), **module,
+    )
 
 
 class Block(nn.Module):
@@ -226,7 +181,7 @@ class Block(nn.Module):
         cfg = self.config
         norm = partial(RMSNorm, cfg.norm_eps, cfg.dtype, cfg.norm_dtype)
         x = x + SparseAttention(cfg, name="attn")(norm(name="attn_norm")(x), positions)
-        return x + ExpertLayer(cfg, name="moe")(norm(name="mlp_norm")(x))
+        return x + expert_layer(cfg, name="moe")(norm(name="mlp_norm")(x))
 
 
 class Keye(nn.Module):
@@ -266,25 +221,3 @@ class Keye(nn.Module):
         x = RMSNorm(cfg.norm_eps, cfg.dtype, cfg.norm_dtype, name="final_norm")(x)
         head = LMHead(cfg.dim, cfg.vocab_size, cfg.dtype, cfg.loss_vocab_chunk, name="lm_head")
         return head(x, targets) if targets is not None else head(x).astype(jnp.float32)
-
-
-def _sown_by_layer(model: Keye, params: Any, tokens: jnp.ndarray, name: str) -> jnp.ndarray:
-    _, seen = model.apply(params, tokens, mutable=["intermediates"])
-    seen = seen["intermediates"]
-    if model.config.scan_layers:
-        return seen["layers"]["block"]["moe"][name][0]
-    return jnp.stack([seen[f"layer_{i}"]["moe"][name][0] for i in range(model.config.n_layers)])
-
-
-def router_load(model: Keye, params: Any, tokens: jnp.ndarray) -> jnp.ndarray:
-    """Rows each held expert receives for ``tokens`` (b, s), by layer:
-    (n_layers, num_local_experts). Dropless, so they are all computed; their
-    expectation is ``b * s * experts_per_token / num_experts`` each."""
-    return _sown_by_layer(model, params, tokens, "rows_by_expert")
-
-
-def dispatch_rows(model: Keye, params: Any, tokens: jnp.ndarray) -> jnp.ndarray:
-    """The row count each layer's expert dispatch runs at for ``tokens``
-    (b, s): (n_layers,), each a rung of ops/grouped_matmul.py
-    ``dispatch_rungs``, the smallest that holds the layer's held rows."""
-    return _sown_by_layer(model, params, tokens, "dispatch_rows")

@@ -23,15 +23,20 @@ from torchft_tpu.utils.platform import on_tpu
 __all__ = ["attend", "causal_attention", "flash_under_mesh"]
 
 
-def causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, scale: float) -> jnp.ndarray:
+def causal_attention(
+    q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, scale: float, window: Optional[int] = None
+) -> jnp.ndarray:
     """Grouped-query causal attention; fp32 softmax on the VPU, matmuls in
-    the input dtype on the MXU. Shapes: q (b,s,h,d); k,v (b,s,kv,d)."""
+    the input dtype on the MXU. Shapes: q (b,s,h,d); k,v (b,s,kv,d). With a
+    ``window`` query t sees key u iff ``0 <= t - u < window``."""
     b, s, h, d = q.shape
     kv_heads = k.shape[2]
     group = h // kv_heads
     q = q.reshape(b, s, kv_heads, group, d)
     scores = jnp.einsum("bskgd,btkd->bkgst", q, k).astype(jnp.float32) * scale
     mask = jnp.tril(jnp.ones((s, s), dtype=bool))
+    if window is not None:
+        mask &= ~jnp.tril(jnp.ones((s, s), dtype=bool), -window)
     scores = jnp.where(mask[None, None, None, :, :], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bkgst,btkd->bskgd", probs, v)
@@ -104,13 +109,13 @@ def _warn_flash_replicated(
 
 
 def flash_under_mesh(
-    q, k, v, *, scale: float, selection=None,
+    q, k, v, *, scale: float, selection=None, window: Optional[int] = None,
     batch_axes: Tuple[str, ...] = ("dp", "fsdp"), tp_axis: Optional[str] = "tp",
     **blocks: int,
 ):
-    """``flash_attention(q, k, v, scale=, selection=, **blocks)`` (``blocks``:
-    its ``block_q`` and ``block_k``), shard_mapped over the ambient mesh's
-    data/tensor axes when one is bound.
+    """``flash_attention(q, k, v, scale=, selection=, window=, **blocks)``
+    (``blocks``: its ``block_q`` and ``block_k``), shard_mapped over the
+    ambient mesh's data/tensor axes when one is bound.
 
     XLA SPMD cannot partition a Mosaic custom call ("Mosaic kernels
     cannot be automatically partitioned") — so inside a sharded train
@@ -140,7 +145,9 @@ def flash_under_mesh(
     XLA's own "wrap the call in a shard_map" error."""
 
     def call(q, k, v, selection=None):
-        return flash_attention(q, k, v, scale=scale, selection=selection, **blocks)
+        return flash_attention(
+            q, k, v, scale=scale, selection=selection, window=window, **blocks
+        )
 
     operands = (q, k, v) if selection is None else (q, k, v, selection)
     mesh = jax.sharding.get_abstract_mesh()
@@ -193,9 +200,12 @@ def attend(
     sp_axis: str = "sp", ring_use_flash: bool = False, blockwise_min_seq: int = 2048,
     block_size: int = 512, block_k: Optional[int] = None,
     batch_axes: Tuple[str, ...] = ("dp", "fsdp"), tp_axis: Optional[str] = "tp",
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Causal grouped-query attention of q (b, s, h, d) over k, v
-    (b, s, kv, d), positions encoded, by the path ``impl`` names:
+    (b, s, kv, d), positions encoded, by the path ``impl`` names; with a
+    ``window`` each query over the ``window`` latest keys up to its own
+    (flash, blockwise and dense take it; the ring does not):
 
     - ``ring`` (ops/ring_attention.py over ``sp_axis``; its per-hop compute
       through the flash kernels where ``ring_use_flash``), which ``auto``
@@ -212,13 +222,15 @@ def attend(
     """
     s = q.shape[1]
     if impl == "ring" or (impl == "auto" and sp_axis_in_mesh(sp_axis)):
+        if window is not None:
+            raise ValueError("ring attention takes no window")
         ring = ring_attention_flash if ring_use_flash else ring_attention
         return ring(q, k, v, axis_name=sp_axis, scale=scale)
     if impl == "flash" or (impl == "auto" and s >= blockwise_min_seq and on_tpu()):
         return flash_under_mesh(
-            q, k, v, scale=scale, batch_axes=batch_axes, tp_axis=tp_axis,
+            q, k, v, scale=scale, window=window, batch_axes=batch_axes, tp_axis=tp_axis,
             block_q=block_size, block_k=block_k or block_size,
         )
     if impl == "blockwise" or (impl == "auto" and s >= blockwise_min_seq):
-        return blockwise_attention(q, k, v, scale=scale, block_size=block_size)
-    return causal_attention(q, k, v, scale)
+        return blockwise_attention(q, k, v, scale=scale, block_size=block_size, window=window)
+    return causal_attention(q, k, v, scale, window)

@@ -56,6 +56,18 @@ where: models/llama.py calls the kernels with no selection where
 ``attention_impl`` says (``auto``: on a TPU at long sequences), ring
 attention calls the partial pair a hop, models/keye.py calls them with the
 selection on a TPU and ops/sparse_attention.py's tiled XLA path elsewhere.
+A call may bring a WINDOW instead (``flash_attention(..., window=...)``; the
+windowed layers of models/smallthinker.py): query t attends to key u iff
+``0 <= t - u < window``. That is a second edge through the grid, and the
+schedule knows it as it knows the diagonal: a pair wholly BEHIND the window is
+neither fetched nor computed, a pair the window's EDGE crosses takes the mask
+(both compares), a pair wholly INSIDE runs bare (:func:`_pair_class`); the two
+tables gain a fourth row, the first KV block a q block needs and the last q
+block a KV block needs, so the index maps stop on both sides; forward and the
+one backward call alike. The two calls carry names of their own
+(``WINDOW_FWD``, ``WINDOW_BWD``), which is what a device trace tells them from
+the causal calls by; a call without a window has three-row tables and no name,
+the module it was.
 GQA is handled by emitting per-q-head dk/dv partials and summing over the
 group axis outside — keeps every output block written exactly once per
 grid pass (no cross-step output aliasing, which Mosaic cannot express; the
@@ -97,6 +109,8 @@ from torchft_tpu.ops.ring_attention import _blockwise_core_bwd
 __all__ = [
     "FLASH_OUT",
     "FLASH_LSE",
+    "WINDOW_FWD",
+    "WINDOW_BWD",
     "flash_attention",
     "flash_attention_partial",
     "flash_attention_partial_bwd",
@@ -107,6 +121,12 @@ __all__ = [
 # docstring: who keeps them).
 FLASH_OUT = "flash_out"
 FLASH_LSE = "flash_lse"
+
+# What XLA names the two Mosaic calls of a call with a window (a device
+# trace's kernel names hold them); calls without one keep the name their
+# caller's scope gives them.
+WINDOW_FWD = "window_attn_fwd"
+WINDOW_BWD = "window_attn_bwd"
 
 _NEG_INF = -1e30
 _PAD_POS = 2**31 - 1  # position for padded rows: beyond every real query
@@ -123,8 +143,9 @@ def _out_struct(shape, dtype, inputs):
         return jax.ShapeDtypeStruct(shape, dtype)
 
 
-# Rows of the two schedule tables (:func:`_block_schedule`).
-_LO, _HI, _EDGE = 0, 1, 2
+# Rows of the two schedule tables (:func:`_block_schedule`); the last only
+# where the call has a window.
+_LO, _HI, _EDGE, _FAR_EDGE = 0, 1, 2, 3
 
 
 def _padded_positions(q_positions, k_positions, b, sq, sk, block_q, block_k):
@@ -148,13 +169,23 @@ def _padded_positions(q_positions, k_positions, b, sq, sk, block_q, block_k):
     return qp, kp
 
 
-def _pair_class(q_lo, q_hi, k_lo, k_hi):
+def _pair_class(q_lo, q_hi, k_lo, k_hi, window=None):
     """(needed, under) of a (q block, KV block) pair from the blocks' lowest
     and highest positions. Not needed is ABOVE the diagonal: the mask is
     false all over it. Under: the mask is true all over it. Needed and not
-    under is DIAGONAL: the mask has to be applied. Scalars in the kernels,
-    arrays in :func:`_block_classes`."""
-    return k_lo <= q_hi, k_hi <= q_lo
+    under is DIAGONAL: the mask has to be applied. With a ``window`` (query t
+    sees key u iff ``0 <= t - u < window``) a second edge runs through the
+    grid: a pair whose nearest (query, key) is already ``window`` apart lies
+    BEHIND it and is not needed, and only a pair whose farthest is still
+    inside it is under (INSIDE); the window's EDGE takes the mask like the
+    diagonal. Scalars in the kernels, arrays in :func:`_block_classes`."""
+    needed, under = k_lo <= q_hi, k_hi <= q_lo
+    if window is None:
+        return needed, under
+    return (
+        jnp.logical_and(needed, q_lo - k_hi < window),
+        jnp.logical_and(under, q_hi - k_lo < window),
+    )
 
 
 def _typed_unvarying(x):
@@ -170,7 +201,7 @@ def _typed_unvarying(x):
     return jax.pure_callback(lambda a: a, jax.ShapeDtypeStruct(x.shape, x.dtype), x)
 
 
-def _block_schedule(qp, kp, block_q, block_k, interpret):
+def _block_schedule(qp, kp, block_q, block_k, interpret, window=None):
     """Causal block schedule from the padded positions: ``q_sched``
     (b, 3, nq) and ``k_sched`` (b, 3, nk) int32, XLA's work once a call,
     the kernels' scalar prefetch. Rows _LO and _HI hold each block's lowest
@@ -178,7 +209,10 @@ def _block_schedule(qp, kp, block_q, block_k, interpret):
     q block needs (the forward walks KV blocks innermost) and of ``k_sched``
     the first q block the KV block needs (the backward walks q blocks
     innermost): the index maps stop there, so a step beyond the edge names
-    the block already in VMEM and the pipeline copies nothing."""
+    the block already in VMEM and the pipeline copies nothing. With a
+    ``window`` the needed blocks end on the other side too, and both tables
+    have a fourth row, _FAR_EDGE: the FIRST KV block a q block needs and the
+    LAST q block a KV block needs, where the index maps stop as well."""
     b = qp.shape[0]
     qb = qp.reshape(b, -1, block_q)
     kb = kp.reshape(b, -1, block_k)
@@ -186,39 +220,48 @@ def _block_schedule(qp, kp, block_q, block_k, interpret):
     k_lo, k_hi = kb.min(axis=2), kb.max(axis=2)
     nq, nk = q_lo.shape[1], k_lo.shape[1]
     needed, _ = _pair_class(
-        q_lo[:, :, None], q_hi[:, :, None], k_lo[:, None, :], k_hi[:, None, :]
+        q_lo[:, :, None], q_hi[:, :, None], k_lo[:, None, :], k_hi[:, None, :],
+        window,
     )  # (b, nq, nk)
-    k_last = jnp.max(
-        jnp.where(needed, jnp.arange(nk, dtype=jnp.int32), 0), axis=2
-    )
-    q_first = jnp.min(
-        jnp.where(needed, jnp.arange(nq, dtype=jnp.int32)[:, None], nq - 1),
-        axis=1,
-    )
-    q_sched = jnp.stack([q_lo, q_hi, k_last], axis=1)
-    k_sched = jnp.stack([k_lo, k_hi, q_first], axis=1)
+    at_k = jnp.arange(nk, dtype=jnp.int32)
+    at_q = jnp.arange(nq, dtype=jnp.int32)[:, None]
+    k_last = jnp.max(jnp.where(needed, at_k, 0), axis=2)
+    q_first = jnp.min(jnp.where(needed, at_q, nq - 1), axis=1)
+    q_rows, k_rows = [q_lo, q_hi, k_last], [k_lo, k_hi, q_first]
+    if window is not None:
+        q_rows.append(jnp.min(jnp.where(needed, at_k, nk - 1), axis=2))
+        k_rows.append(jnp.max(jnp.where(needed, at_q, 0), axis=1))
+    q_sched = jnp.stack(q_rows, axis=1)
+    k_sched = jnp.stack(k_rows, axis=1)
     if interpret:
         return _typed_unvarying(q_sched), _typed_unvarying(k_sched)
     return q_sched, k_sched
 
 
-def _block_classes(q_sched, k_sched):
-    """(b, nq, nk) int32 of the schedule's pairs: 0 above, 1 diagonal,
-    2 under. What the kernels decide a step at a time, as one array."""
+def _block_classes(q_sched, k_sched, window=None):
+    """(b, nq, nk) int32 of the schedule's pairs: 0 not needed (above the
+    diagonal or behind the window), 1 masked (the diagonal or the window's
+    edge crosses it), 2 under (inside). What the kernels decide a step at a
+    time, as one array."""
     needed, under = _pair_class(
         q_sched[:, _LO, :, None], q_sched[:, _HI, :, None],
         k_sched[:, _LO, None, :], k_sched[:, _HI, None, :],
+        window,
     )
     return needed.astype(jnp.int32) + under.astype(jnp.int32)
 
 
-def _kv_block(ib, iq, ik, q_sched):
+def _kv_block(ib, iq, ik, q_sched, windowed=False):
     """KV block that step (iq, ik) of the forward names."""
+    if windowed:
+        return jnp.clip(ik, q_sched[ib, _FAR_EDGE, iq], q_sched[ib, _EDGE, iq])
     return jnp.minimum(ik, q_sched[ib, _EDGE, iq])
 
 
-def _q_block(ib, ik, iq, k_sched):
+def _q_block(ib, ik, iq, k_sched, windowed=False):
     """q block that step (ik, iq) of the backward names."""
+    if windowed:
+        return jnp.clip(iq, k_sched[ib, _EDGE, ik], k_sched[ib, _FAR_EDGE, ik])
     return jnp.maximum(iq, k_sched[ib, _EDGE, ik])
 
 
@@ -262,27 +305,34 @@ def _mask_operands(selection, qp, kp, pad_q, pad_k):
     return (qp.reshape(b, -1, 1), kp.reshape(b, 1, -1)), ("qp", "kp")
 
 
-def _allowed(mask_refs):
+def _allowed(mask_refs, window=None):
     """(block_q, block_k) bool of a pair from the call's mask operands
     (:func:`_mask_operands`): the one block of the selection, which already
     implies that the key is no later than the query, or the two positions'
-    compare. Which it is, is the call's operands and so known at trace
-    time."""
+    compare, with a ``window`` both of its compares. Which it is, is the
+    call's operands and so known at trace time."""
     if len(mask_refs) == 1:
         return mask_refs[0][...] != 0
     qp_ref, kp_ref = mask_refs
-    return qp_ref[...] >= kp_ref[...]
+    if window is None:
+        return qp_ref[...] >= kp_ref[...]
+    # A padded row (-1) against a padded column (_PAD_POS) is -2**31: no
+    # difference of two positions leaves int32.
+    apart = qp_ref[...] - kp_ref[...]
+    return jnp.logical_and(apart >= 0, apart < window)
 
 
-def _when_needed(qs_ref, ks_ref, ib, iq, ik, update, mask_refs):
+def _when_needed(qs_ref, ks_ref, ib, iq, ik, update, mask_refs, window=None):
     """Runs ``update(masked)`` for the pair's class: not at all above the
-    diagonal, without the mask under it, with it on it; where the mask is a
+    diagonal (or behind a window), without the mask under it (inside), with
+    it on it (and on the window's edge); where the mask is a
     selection (:func:`_allowed`) every needed pair takes it."""
     from jax.experimental import pallas as pl
 
     needed, under = _pair_class(
         qs_ref[ib, _LO, iq], qs_ref[ib, _HI, iq],
         ks_ref[ib, _LO, ik], ks_ref[ib, _HI, ik],
+        window,
     )
     if len(mask_refs) == 1:
         pl.when(needed)(partial(update, True))
@@ -300,6 +350,7 @@ def _fwd_kernel(
     *rest,
     scale: float,
     nk: int,
+    window: Optional[int] = None,
 ):
     """One (batch, head, q-block, kv-block) grid step.
 
@@ -336,7 +387,7 @@ def _fwd_kernel(
             * scale
         )  # (block_q, block_k) f32
         if masked:
-            scores = jnp.where(_allowed(mask_refs), scores, _NEG_INF)
+            scores = jnp.where(_allowed(mask_refs, window), scores, _NEG_INF)
 
         m_prev = m_ref[...]  # (block_q, 1)
         m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
@@ -352,7 +403,7 @@ def _fwd_kernel(
         acc_ref[...] = acc_ref[...] * correction + pv
         m_ref[...] = m_new
 
-    _when_needed(qs_ref, ks_ref, ib, iq, ik, _update, mask_refs)
+    _when_needed(qs_ref, ks_ref, ib, iq, ik, _update, mask_refs, window)
 
     @pl.when(ik == nk - 1)
     def _finalize():
@@ -368,7 +419,7 @@ def _fwd_kernel(
 
 def _flash_fwd(
     q, k, v, scale, block_q, block_k, interpret,
-    q_positions=None, k_positions=None, selection=None,
+    q_positions=None, k_positions=None, selection=None, window=None,
 ):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -390,8 +441,9 @@ def _flash_fwd(
     qp, kp = _padded_positions(
         q_positions, k_positions, b, sq, sk, block_q, block_k
     )
-    q_sched, k_sched = _block_schedule(qp, kp, block_q, block_k, interpret)
+    q_sched, k_sched = _block_schedule(qp, kp, block_q, block_k, interpret, window)
     mask, mask_kinds = _mask_operands(selection, qp, kp, pad_q, pad_k)
+    windowed = window is not None
 
     # Kernels run on (b, heads, seq, d): Mosaic requires the last two BLOCK
     # dims be (mult-of-8, mult-of-128-or-whole-dim), so seq and head_dim must
@@ -403,15 +455,16 @@ def _flash_fwd(
     kt = k.transpose(0, 2, 1, 3)  # (b, kv_heads, sk_p, d)
     vt = v.transpose(0, 2, 1, 3)
 
-    # KV blocks innermost; the KV index stops at the q block's edge.
+    # KV blocks innermost; the KV index stops at the q block's edge (under a
+    # window, at both of its edges).
     spec = _block_specs(
         block_q, block_k, d, group,
         lambda ib, iq, ik, qs, ks: iq,
-        lambda ib, iq, ik, qs, ks: _kv_block(ib, iq, ik, qs),
+        lambda ib, iq, ik, qs, ks: _kv_block(ib, iq, ik, qs, windowed),
     )
     inputs = (q_sched, k_sched, qt, kt, vt, *mask)
     out, lse = pl.pallas_call(
-        partial(_fwd_kernel, scale=scale, nk=nk),
+        partial(_fwd_kernel, scale=scale, nk=nk, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, h, nq, nk),
@@ -427,6 +480,7 @@ def _flash_fwd(
             _out_struct((b, h, sq + pad_q, d), q.dtype, inputs),
             _out_struct((b, h, sq + pad_q, 1), jnp.float32, inputs),
         ],
+        name=WINDOW_FWD if windowed else None,
         interpret=interpret,
     )(*inputs)
     out = out.transpose(0, 2, 1, 3)  # back to (b, sq_p, h, d)
@@ -453,7 +507,7 @@ _MAX_VMEM_BYTES = 64 * 2**20
 def _bwd_kernel(
     qs_ref, ks_ref,
     q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *rest,
-    scale: float, nk: int, nqc: int, block_q: int,
+    scale: float, nk: int, nqc: int, block_q: int, window: Optional[int] = None,
 ):
     """One step of the backward: grid (b, h, q chunk, nk, nqc), the chunk's
     q blocks innermost. A needed (q block, KV block) pair recomputes its
@@ -497,7 +551,7 @@ def _bwd_kernel(
         if masked:
             # p from the saved lse; masked entries exactly 0 (also kills
             # padded q rows, whose position is -1 — below every key).
-            p = jnp.where(_allowed(mask_refs), p, 0.0)
+            p = jnp.where(_allowed(mask_refs, window), p, 0.0)
         do = do_ref[...]
         dv_acc_ref[...] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -517,7 +571,7 @@ def _bwd_kernel(
             preferred_element_type=jnp.float32,
         )  # (block_q, d)
 
-    _when_needed(qs_ref, ks_ref, ib, iq, ik, _update, mask_refs)
+    _when_needed(qs_ref, ks_ref, ib, iq, ik, _update, mask_refs, window)
 
     @pl.when(jq == nqc - 1)
     def _finalize_dkv():
@@ -582,6 +636,7 @@ def flash_attention_partial_bwd(
     out_dtype=None,
     vmem_bytes=_MAX_VMEM_BYTES,
     selection=None,
+    window=None,
 ):
     """Fused Pallas backward PARTIAL over an arbitrary KV block: the ring
     backward building block (and, with arange positions, the full causal
@@ -607,7 +662,9 @@ def flash_attention_partial_bwd(
     is by shape alone; the argument is for tests, which make it small.
 
     ``selection`` (b, sq, sk) int8: what the forward was given
-    (:func:`flash_attention`); a needed pair then masks by its block of it."""
+    (:func:`flash_attention`); a needed pair then masks by its block of it.
+    ``window``: the forward's too; the pairs behind it are skipped and the
+    pairs on its edge masked, as there."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -647,8 +704,9 @@ def flash_attention_partial_bwd(
     qp, kp = _padded_positions(
         q_positions, k_positions, b, sq, sk, q_rows, block_k
     )
-    q_sched, k_sched = _block_schedule(qp, kp, block_q, block_k, interpret)
+    q_sched, k_sched = _block_schedule(qp, kp, block_q, block_k, interpret, window)
     mask, mask_kinds = _mask_operands(selection, qp, kp, pad_q, pad_k)
+    windowed = window is not None
     # Same heads-major transposition as _flash_fwd (see comment there): the
     # kernel sees (b, h, seq, d) / (b, h, seq, 1) so seq and d are the block
     # minor dims Mosaic requires.
@@ -661,14 +719,16 @@ def flash_attention_partial_bwd(
     inputs = (q_sched, k_sched, qt, kt, vt, dot, lse_col, delta_col, *mask)
 
     # The chunk's q blocks innermost, so the dk/dv accumulators persist
-    # across them; the q index starts at the KV block's edge and stays in
-    # the chunk.
+    # across them; the q index starts at the KV block's edge (under a window
+    # it ends at the other one too) and stays in the chunk.
+    def q_of(ib, ic, ik, jq, qs, ks):
+        block = _q_block(ib, ik, ic * nqc + jq, ks, windowed)
+        if windowed:
+            return jnp.clip(block, ic * nqc, ic * nqc + nqc - 1)
+        return jnp.minimum(block, ic * nqc + nqc - 1)
+
     spec = _block_specs(
-        block_q, block_k, d, group,
-        lambda ib, ic, ik, jq, qs, ks: jnp.minimum(
-            _q_block(ib, ik, ic * nqc + jq, ks), ic * nqc + nqc - 1
-        ),
-        lambda ib, ic, ik, jq, qs, ks: ik,
+        block_q, block_k, d, group, q_of, lambda ib, ic, ik, jq, qs, ks: ik
     )
     # The outputs are per q head (dk, dv: and per chunk), so not "q" / "kv".
     dq_out = pl.BlockSpec(
@@ -679,7 +739,9 @@ def flash_attention_partial_bwd(
         lambda ib, ih, ic, ik, jq, qs, ks: (ib, ih, ic, ik, 0),
     )
     dq, dk_h, dv_h = pl.pallas_call(
-        partial(_bwd_kernel, scale=scale, nk=nk, nqc=nqc, block_q=block_q),
+        partial(
+            _bwd_kernel, scale=scale, nk=nk, nqc=nqc, block_q=block_q, window=window
+        ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, h, nc, nk, nqc),
@@ -706,6 +768,7 @@ def flash_attention_partial_bwd(
             if need > _SCOPED_VMEM_BYTES
             else None
         ),
+        name=WINDOW_BWD if windowed else None,
         interpret=interpret,
     )(*inputs)
     dq = dq.transpose(0, 2, 1, 3)  # (b, sq_p, h, d)
@@ -725,7 +788,8 @@ def flash_attention_partial_bwd(
 
 
 def _flash_bwd(
-    q, k, v, selection, out, lse, d_out, scale, block_q, block_k, interpret
+    q, k, v, selection, out, lse, d_out, scale, block_q, block_k, interpret,
+    window=None,
 ):
     """Full-causal fused backward: the partial backward with arange
     positions and a single all-KV block set."""
@@ -734,24 +798,27 @@ def _flash_bwd(
         scale, block_q, block_k, interpret,
         out_dtype=q.dtype,  # no cross-call accumulation: cast in VMEM
         selection=selection,
+        window=window,
     )
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def _flash_core(
-    q, k, v, selection, scale, block_q, block_k, interpret, pallas_bwd
+    q, k, v, selection, scale, block_q, block_k, interpret, pallas_bwd, window
 ):
     return _flash_fwd(
-        q, k, v, scale, block_q, block_k, interpret, selection=selection
+        q, k, v, scale, block_q, block_k, interpret, selection=selection,
+        window=window,
     )[0]
 
 
 def _flash_core_fwd(
-    q, k, v, selection, scale, block_q, block_k, interpret, pallas_bwd
+    q, k, v, selection, scale, block_q, block_k, interpret, pallas_bwd, window
 ):
     out, lse = _flash_fwd(
-        q, k, v, scale, block_q, block_k, interpret, selection=selection
+        q, k, v, scale, block_q, block_k, interpret, selection=selection,
+        window=window,
     )
     out = checkpoint_name(out, FLASH_OUT)
     lse = checkpoint_name(lse, FLASH_LSE)
@@ -759,7 +826,7 @@ def _flash_core_fwd(
 
 
 def _flash_core_bwd(
-    scale, block_q, block_k, interpret, pallas_bwd, residuals, d_out
+    scale, block_q, block_k, interpret, pallas_bwd, window, residuals, d_out
 ):
     q, k, v, selection, out, lse = residuals
     if pallas_bwd:
@@ -769,12 +836,12 @@ def _flash_core_bwd(
         # selection is no function of q, k or v here: no cotangent.
         return *_flash_bwd(
             q, k, v, selection, out, lse.reshape(b, s, h), d_out,
-            scale, block_q, block_k, interpret,
+            scale, block_q, block_k, interpret, window,
         ), None
     # Scan-based flash backward (recompute per KV block from the saved
     # logsumexp) — shared with blockwise_attention; the CPU/fallback path,
     # which knows no selection (flash_attention never sends it one).
-    return *_blockwise_core_bwd(scale, block_k, (q, k, v, out, lse), d_out), None
+    return *_blockwise_core_bwd(scale, block_k, (q, k, v, out, lse), d_out, window), None
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
@@ -844,6 +911,7 @@ def flash_attention(
     interpret: Optional[bool] = None,
     use_pallas_bwd: Optional[bool] = None,
     selection: Optional[jnp.ndarray] = None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Fused causal GQA attention on one device: Pallas forward AND
     FlashAttention-2-style Pallas backward (one kernel that recomputes the
@@ -871,6 +939,15 @@ def flash_attention(
     selected key comes out zero. The operand gets no gradient. Without it the
     kernels are the calls they are without this argument, operand for
     operand.
+
+    ``window``: query t attends to key u iff ``0 <= t - u < window``, the
+    query's own position counted: ``window`` keys in all. The schedule then
+    has a second edge (:func:`_pair_class`): pairs behind the window are
+    neither fetched nor computed, pairs its edge crosses are masked, pairs
+    inside run bare, in both calls, which carry names of their own
+    (``WINDOW_FWD``, ``WINDOW_BWD``) so that a device trace tells them from
+    the causal calls. A window that covers the sequence is the causal call,
+    and no window with a selection.
     """
     b, s, h, d = q.shape
     kv_heads = k.shape[2]
@@ -890,14 +967,20 @@ def flash_attention(
             )
         if use_pallas_bwd is False:
             raise ValueError("the scan-based backward takes no selection")
+        if window is not None:
+            raise ValueError("a selection already says what a window would")
         selection = selection.astype(jnp.int8)
         use_pallas_bwd = True
+    if window is not None:
+        if window < 1:
+            raise ValueError(f"a window of {window} keys")
+        window = int(window) if window < s else None
     if use_pallas_bwd is None:
         use_pallas_bwd = not interpret
     block_q, block_k = _block_sizes(block_q, block_k, s, s, selection is not None)
     return _flash_core(
         q, k, v, selection, float(scale), block_q, block_k, bool(interpret),
-        bool(use_pallas_bwd),
+        bool(use_pallas_bwd), window,
     )
 
 
@@ -921,17 +1004,37 @@ def _block_sizes(block_q, block_k, sq, sk, selected=False):
     )
 
 
-def _class_counts(sq, sk, block_q, block_k, q_positions=None, k_positions=None):
+def _class_counts(
+    sq, sk, block_q, block_k, q_positions=None, k_positions=None, window=None
+):
     """How many (q block, KV block) pairs of one call over these lengths,
     blocks and (b, s) positions the schedule classes above the diagonal, on
-    it and under it (the first batch row's)."""
+    it and under it (the first batch row's). With a ``window`` the pairs it
+    takes off the walk are counted apart as ``behind``, those its edge alone
+    crosses as ``edge`` (``diagonal`` keeps every masked pair the causal
+    compare masks, a corner both lines cross included) and ``under`` reads
+    inside both."""
     block_q, block_k = _block_sizes(block_q, block_k, sq, sk)
     qp, kp = _padded_positions(q_positions, k_positions, 1, sq, sk, block_q, block_k)
-    classes = _block_classes(*_block_schedule(qp[:1], kp[:1], block_q, block_k, False))
-    return {
-        name: int(jnp.sum(classes == c))
+
+    def classes(window):
+        tables = _block_schedule(qp[:1], kp[:1], block_q, block_k, False, window)
+        return _block_classes(*tables, window)
+
+    causal = classes(None)
+    counts = {
+        name: int(jnp.sum(causal == c))
         for c, name in enumerate(("above", "diagonal", "under"))
     }
+    if window is None:
+        return counts
+    mine = classes(window)
+    behind = int(jnp.sum((mine == 0) & (causal > 0)))
+    edge = int(jnp.sum((mine == 1) & (causal == 2)))
+    counts["behind"], counts["edge"] = behind, edge
+    counts["diagonal"] = int(jnp.sum((mine == 1) & (causal == 1)))
+    counts["under"] = int(jnp.sum(mine == 2))
+    return counts
 
 
 def verify_on_chip() -> dict:
@@ -941,8 +1044,9 @@ def verify_on_chip() -> dict:
 
         python -c "from torchft_tpu.ops.flash_attention import verify_on_chip; print(verify_on_chip())"
 
-    The last case runs both kernels under a selection
-    (``flash_attention(..., selection=...)``). Returns the largest error of
+    One case runs both kernels under a selection
+    (``flash_attention(..., selection=...)``), the last four under a window
+    (``window=``). Returns the largest error of
     each case; under ``classes``, how many
     block pairs of the case the schedule classed above, on and under the
     diagonal: how often the scheduling engaged; and under ``bwd_q_chunks``
@@ -1171,6 +1275,48 @@ def verify_on_chip() -> dict:
     err_s, err_sb = selected_errors(q, k, v, selection, d_out)
     err_s = check("SELECTED", err_s, 0.05)
     err_sb = check("SELECTED BACKWARD", err_sb, 0.25)
+
+    # Under a window, against dense attention under the same mask: a window
+    # that is a multiple of neither block, in several small blocks; then, at
+    # the default blocks with 14 query heads over 2 KV heads of 128 (the group
+    # of 7 models/smallthinker.py runs), a window of whole blocks, one shorter
+    # than a block and one over a ragged length.
+    def windowed(sq, window, block_q, block_k, case, heads=h, kv_heads=kv, width=d):
+        def attend(q, k, v):
+            return flash_attention(
+                q, k, v, block_q=block_q, block_k=block_k, interpret=False, window=window
+            )
+
+        def dense(q, k, v):
+            return causal_attention(q, k, v, width**-0.5, window)
+
+        keys = jax.random.split(jax.random.PRNGKey(31), 4)
+        q = jax.random.normal(keys[0], (b, sq, heads, width), jnp.bfloat16)
+        k = jax.random.normal(keys[1], (b, sq, kv_heads, width), jnp.bfloat16)
+        v = jax.random.normal(keys[2], (b, sq, kv_heads, width), jnp.bfloat16)
+        d_out = jax.random.normal(keys[3], q.shape, jnp.float32)
+
+        @jax.jit
+        def errors(q, k, v, d_out):
+            got, vjp = jax.vjp(attend, q, k, v)
+            ref, ref_vjp = jax.vjp(dense, q, k, v)
+            d_out = d_out.astype(got.dtype)
+            return worst([got], [ref]), worst(vjp(d_out), ref_vjp(d_out))
+
+        classes[case] = _class_counts(sq, sq, block_q, block_k, window=window)
+        return errors(q, k, v, d_out)
+
+    window_errors = {}
+    for case, args, more in (
+        ("window-300", (1000, 300, 128, 256), {}),
+        ("window-1024-group7", (3072, 1024, 512, 1024), dict(heads=14, kv_heads=2, width=128)),
+        ("window-100-group7", (2048, 100, 512, 1024), dict(heads=14, kv_heads=2, width=128)),
+        ("window-700-ragged", (2500, 700, 512, 1024), dict(heads=14, kv_heads=2, width=128)),
+    ):
+        err_w, err_wb = windowed(*args, case, **more)
+        window_errors[case] = (
+            check(f"WINDOW {case}", err_w, 0.05), check(f"WINDOW BACKWARD {case}", err_wb, 0.25),
+        )
     return {
         "device": str(dev),
         "max_err": err,
@@ -1184,6 +1330,8 @@ def verify_on_chip() -> dict:
         "max_err_ragged_bwd": err_rb,
         "max_err_selected": err_s,
         "max_err_selected_bwd": err_sb,
+        "max_err_window": {case: errs[0] for case, errs in window_errors.items()},
+        "max_err_window_bwd": {case: errs[1] for case, errs in window_errors.items()},
         "classes": classes,
         "bwd_q_chunks": chunks,
         "ok": True,

@@ -39,6 +39,14 @@ __all__ = [
 _NEG_INF = -1e30
 
 
+def _visible(q_pos, k_pos, window):
+    """(b, sq, 1, 1, sk) bool: key u is no later than query t and, with a
+    ``window``, fewer than ``window`` positions before it."""
+    apart = q_pos[:, :, None, None, None] - k_pos[:, None, None, None, :]
+    seen = apart >= 0
+    return seen if window is None else seen & (apart < window)
+
+
 def _block_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -49,6 +57,7 @@ def _block_attention(
     acc: jnp.ndarray,
     row_max: jnp.ndarray,
     row_sum: jnp.ndarray,
+    window: Optional[int] = None,
 ):
     """One flash-style block update.
 
@@ -56,8 +65,7 @@ def _block_attention(
     acc: (b, sq, kv, g, d) f32; row_max/row_sum: (b, sq, kv, g) f32.
     """
     scores = jnp.einsum("bskgd,btkd->bskgt", q, k).astype(jnp.float32) * scale
-    causal = q_pos[:, :, None, None, None] >= k_pos[:, None, None, None, :]
-    scores = jnp.where(causal, scores, _NEG_INF)
+    scores = jnp.where(_visible(q_pos, k_pos, window), scores, _NEG_INF)
 
     block_max = jnp.max(scores, axis=-1)
     new_max = jnp.maximum(row_max, block_max)
@@ -78,6 +86,7 @@ def blockwise_attention(
     v: jnp.ndarray,
     scale: Optional[float] = None,
     block_size: int = 512,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Memory-bounded causal GQA attention on ONE device.
 
@@ -93,12 +102,13 @@ def blockwise_attention(
     Shapes: q (b, s, h, d); k/v (b, s, kv_heads, d). The sequence is padded
     to a multiple of ``block_size``; padded KV positions are masked out by
     the causal position comparison (their positions sit beyond every real
-    query).
+    query). ``window``: a query sees the ``window`` latest keys up to its
+    own; every block is still walked (this is the CPU path).
     """
     b, s, h, d = q.shape
     if scale is None:
         scale = d**-0.5
-    return _blockwise_core(q, k, v, float(scale), int(block_size))
+    return _blockwise_core(q, k, v, float(scale), int(block_size), window)
 
 
 def _blockwise_blocks(k: jnp.ndarray, v: jnp.ndarray, block_size: int):
@@ -118,7 +128,7 @@ def _blockwise_blocks(k: jnp.ndarray, v: jnp.ndarray, block_size: int):
     return k_blocks, v_blocks, kp_blocks, n_blocks, pad
 
 
-def _blockwise_fwd_impl(q, k, v, scale: float, block_size: int):
+def _blockwise_fwd_impl(q, k, v, scale: float, block_size: int, window=None):
     b, s, h, d = q.shape
     kv_heads = k.shape[2]
     group = h // kv_heads
@@ -134,7 +144,7 @@ def _blockwise_fwd_impl(q, k, v, scale: float, block_size: int):
         acc, row_max, row_sum = carry
         k_blk, v_blk, kp_blk = blk
         acc, row_max, row_sum = _block_attention(
-            qg, k_blk, v_blk, q_pos, kp_blk, scale, acc, row_max, row_sum
+            qg, k_blk, v_blk, q_pos, kp_blk, scale, acc, row_max, row_sum, window
         )
         return (acc, row_max, row_sum), None
 
@@ -150,17 +160,17 @@ def _blockwise_fwd_impl(q, k, v, scale: float, block_size: int):
 from functools import partial as _partial
 
 
-@_partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _blockwise_core(q, k, v, scale: float, block_size: int):
-    return _blockwise_fwd_impl(q, k, v, scale, block_size)[0]
+@_partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _blockwise_core(q, k, v, scale: float, block_size: int, window=None):
+    return _blockwise_fwd_impl(q, k, v, scale, block_size, window)[0]
 
 
-def _blockwise_core_fwd(q, k, v, scale: float, block_size: int):
-    out, lse = _blockwise_fwd_impl(q, k, v, scale, block_size)
+def _blockwise_core_fwd(q, k, v, scale: float, block_size: int, window=None):
+    out, lse = _blockwise_fwd_impl(q, k, v, scale, block_size, window)
     return out, (q, k, v, out, lse)
 
 
-def _blockwise_core_bwd(scale: float, block_size: int, residuals, d_out):
+def _blockwise_core_bwd(scale: float, block_size: int, residuals, d_out, window=None):
     q, k, v, out, lse = residuals
     b, s, h, d = q.shape
     kv_heads = k.shape[2]
@@ -181,9 +191,9 @@ def _blockwise_core_bwd(scale: float, block_size: int, residuals, d_out):
         k32 = k_blk.astype(jnp.float32)
         v32 = v_blk.astype(jnp.float32)
         scores = jnp.einsum("bskgd,btkd->bskgt", qg, k32) * scale
-        causal = q_pos[:, :, None, None, None] >= kp_blk[:, None, None, None, :]
         # p rebuilt from the saved logsumexp; masked entries exactly 0.
-        p = jnp.where(causal, jnp.exp(scores - lse[..., None]), 0.0)
+        seen = _visible(q_pos, kp_blk, window)
+        p = jnp.where(seen, jnp.exp(scores - lse[..., None]), 0.0)
         dv_blk = jnp.einsum("bskgt,bskgd->btkd", p, dog)
         dp = jnp.einsum("bskgd,btkd->bskgt", dog, v32)
         ds = p * (dp - delta[..., None]) * scale
@@ -210,7 +220,12 @@ def _blockwise_core_bwd(scale: float, block_size: int, residuals, d_out):
     )
 
 
-_blockwise_core.defvjp(_blockwise_core_fwd, _blockwise_core_bwd)
+_blockwise_core.defvjp(
+    _blockwise_core_fwd,
+    lambda scale, block_size, window, residuals, d_out: _blockwise_core_bwd(
+        scale, block_size, residuals, d_out, window
+    ),
+)
 
 
 def ring_attention(
