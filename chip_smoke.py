@@ -380,6 +380,11 @@ class OneChip:
                 f"zigzag {flash['max_err_zigzag']:.4f} ragged {flash['max_err_ragged']:.4f} "
                 f"selected {flash['max_err_selected']:.4f} / {flash['max_err_selected_bwd']:.4f}"
             )
+            say(
+                "  the grid of needed pairs vs float32, no element apart from the "
+                f"walk over every pair: {flash['max_err_listed']} backward "
+                f"{flash['max_err_listed_bwd']}"
+            )
             say(f"  block pairs by class of the schedule: {flash['classes']}")
             say(
                 "  fused backward, q chunks a head (1: dq resident for the whole "

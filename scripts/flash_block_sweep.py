@@ -17,7 +17,16 @@ the operations causal attention needs for the call (``chipbench/flops.py``:
 bf16 peak: what the cells report as ``flash_mxu_pct``.
 
 Usage:
-    python scripts/flash_block_sweep.py [--tree DIR] [--pairs 512x1024,...] [seq ...]
+    python scripts/flash_block_sweep.py [--tree DIR] [--pairs 512x1024,...]
+        [--heads 28x4] [--window 4096] [seq ...]
+
+``--heads`` (q heads x KV heads) and ``--window`` give another cell's calls:
+28x4 at 16384 with and without a window of 4096 is a layer of
+``smallthinker-21b-a3b-1chip.ftddp-seq16k`` (``mxu_pct`` then counts the
+(query, key) pairs the window allows, as that cell's
+``window_attention_flops`` does). A row says how many grid
+steps a head's forward takes (``steps``, where the tree's ``_class_counts``
+tells).
 
 ``--tree`` imports ``torchft_tpu`` from another checkout (a parent commit
 unpacked beside this one), so two trees are read the same way in one chip
@@ -78,6 +87,8 @@ def main() -> None:
     parser = argparse.ArgumentParser()
     parser.add_argument("--tree", default=str(ROOT))
     parser.add_argument("--pairs", default="")
+    parser.add_argument("--heads", default=f"{HEADS}x{KV_HEADS}")
+    parser.add_argument("--window", type=int, default=None)
     parser.add_argument("seqs", nargs="*", type=int)
     args = parser.parse_args()
     sys.path.insert(0, args.tree)
@@ -91,31 +102,44 @@ def main() -> None:
 
     from chipbench import flops
     from chipbench.harness import peaks_for
-    from torchft_tpu.ops.flash_attention import flash_attention
+    from torchft_tpu.ops.flash_attention import _class_counts, flash_attention
 
     peak = peaks_for(jax.devices()[0].device_kind)["bf16_tflops"] * 1e12
     pairs = [
         tuple(int(x) for x in p.split("x")) for p in args.pairs.split(",") if p
     ] or PAIRS
+    heads, kv_heads = (int(x) for x in args.heads.split("x"))
     geometry = {
-        "head_dim": HEAD_DIM, "num_attention_heads": HEADS, "num_hidden_layers": 1,
+        "head_dim": HEAD_DIM, "num_attention_heads": heads, "num_hidden_layers": 1,
     }
+    more = {} if args.window is None else {"window": args.window}
     for s in args.seqs or [2048, 8192]:
         b = max(1, TOKENS // s)
         kq, kk, kvk, kr = jax.random.split(jax.random.PRNGKey(0), 4)
-        q = jax.random.normal(kq, (b, s, HEADS, HEAD_DIM), jnp.bfloat16)
-        k = jax.random.normal(kk, (b, s, KV_HEADS, HEAD_DIM), jnp.bfloat16)
-        v = jax.random.normal(kvk, (b, s, KV_HEADS, HEAD_DIM), jnp.bfloat16)
-        r = jax.random.normal(kr, (b, s, HEADS, HEAD_DIM), jnp.float32)
+        q = jax.random.normal(kq, (b, s, heads, HEAD_DIM), jnp.bfloat16)
+        k = jax.random.normal(kk, (b, s, kv_heads, HEAD_DIM), jnp.bfloat16)
+        v = jax.random.normal(kvk, (b, s, kv_heads, HEAD_DIM), jnp.bfloat16)
+        r = jax.random.normal(kr, (b, s, heads, HEAD_DIM), jnp.float32)
         need = flops.flash_attention_flops(geometry, b, s)
+        if args.window is not None and args.window < s:
+            # The (query, key) pairs the window allows over the s^2 / 2 the
+            # causal count takes.
+            w = args.window
+            need *= (w * (w + 1) / 2 + (s - w) * w) / (s * s / 2)
         for bq, bk in pairs:
             if bq > s or bk > s:
                 continue
-            row = {"tree": args.tree, "seq": s, "batch": b, "block_q": bq, "block_k": bk}
+            row = {
+                "tree": args.tree, "seq": s, "batch": b, "block_q": bq, "block_k": bk,
+                "heads": args.heads, **more,
+            }
+            counts = _class_counts(s, s, bq, bk, **more)
+            if "steps" in counts:
+                row["steps"] = counts["steps"]
 
             def loss(q, k, v, _bq=bq, _bk=bk):
                 out = flash_attention(
-                    q, k, v, block_q=_bq, block_k=_bk, interpret=False
+                    q, k, v, block_q=_bq, block_k=_bk, interpret=False, **more
                 )
                 return jnp.vdot(out.astype(jnp.float32), r)
 

@@ -11,8 +11,10 @@ at its own size and seed, and the control of its limits:
 - the (q block, KV block) pairs the flash kernels' schedule classes above the
   diagonal, on it, under it and, in a windowed layer, behind the window and on
   its edge (``ops.flash_attention._class_counts``), for the two kinds of layer
-  at the cell's sequence and the model's blocks, beside the closed form of the
-  (query, key) pairs each kind needs;
+  at the cell's sequence and the model's blocks, with the grid ``steps`` a
+  head's forward call takes (the needed pairs alone, since PR 55: the model's
+  calls bring no position arrays), beside the closed form of the (query, key)
+  pairs each kind needs;
 - the fp8 control: the float32 reference with every weight in fp8 (e4m3, one
   scale a tensor) through ``harness.reference_check`` under the cell's limits.
   It has to come out NOT correct; the script exits 1 where it does not.
@@ -39,8 +41,9 @@ OVERLAY = ROOT / "chipbench/fixtures/rehearsal-smallthinker.json"
 
 
 def pair_classes(system, architecture) -> dict:
-    """The schedule's block pairs by class for a full and for a windowed layer
-    of the cell, and the (query, key) pairs each needs by the closed form."""
+    """The schedule's block pairs by class and the grid steps of a head's
+    forward (``block_pairs["steps"]``) for a full and for a windowed layer of
+    the cell, and the (query, key) pairs each needs by the closed form."""
     from torchft_tpu.ops.flash_attention import _class_counts
 
     cfg, seq = system.model.config, system.seq

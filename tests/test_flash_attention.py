@@ -1040,11 +1040,14 @@ def test_the_cells_windowed_layers_walk_under_half_of_the_causal_block_pairs():
     assert _needed_pairs_closed_form(s, window) == 58_722_304
     assert round(100 * 58_722_304 / (s * (s + 1) // 2), 1) == 43.7
     counts = fa._class_counts(s, s, 512, 1024, window=window)
-    assert counts == {"above": 240, "diagonal": 32, "under": 84, "behind": 132, "edge": 24}
+    assert counts == {
+        "above": 240, "diagonal": 32, "under": 84, "behind": 132, "edge": 24, "steps": 140,
+    }
     causal = fa._class_counts(s, s, 512, 1024)
-    assert causal == {"above": 240, "diagonal": 32, "under": 240}
+    assert causal == {"above": 240, "diagonal": 32, "under": 240, "steps": 272}
     # 140 block pairs walked of the causal call's 272: 51.5% of its blocks
-    # for 43.7% of its pairs (the edge's blocks are computed whole).
+    # for 43.7% of its pairs (the edge's blocks are computed whole). They are
+    # the grid steps a head takes, too.
     assert counts["diagonal"] + counts["under"] + counts["edge"] == 140
 
 
@@ -1090,3 +1093,253 @@ def test_a_window_and_a_selection_do_not_meet_and_the_blockwise_path_takes_a_win
         np.testing.assert_allclose(np.asarray(out), np.asarray(dense), atol=2e-5, err_msg=impl)
     with pytest.raises(ValueError, match="ring"):
         attend(q, k, v, scale=16**-0.5, impl="ring", window=40)
+
+
+# --- the grid of needed pairs -------------------------------------------------
+#
+# A call without position arrays (``flash_attention``: the sequence's own
+# positions) knows every pair's class while tracing, and its grid is the list
+# of the needed pairs; a call with them (a ring hop) walks every pair.
+
+# name: (s, block_q, block_k, window, q blocks of a backward chunk or None for
+# one chunk), (steps of a head's forward, steps of a backward chunk)
+_STEP_TABLES = {
+    # The cells' geometries: 4 x 2048 and 1 x 8192 (the Mistral and Keye
+    # cells), 1 x 16,384 without and with the window of 4,096 (SmallThinker).
+    "cells-2048": ((2048, 512, 1024, None, None), (6, 6)),
+    "cells-8192": ((8192, 512, 1024, None, None), (72, 72)),
+    "cells-16384": ((16384, 512, 1024, None, None), (272, 272)),
+    "cells-16384-window-4096": ((16384, 512, 1024, 4096, None), (140, 140)),
+    # Ragged: 300 = 4 x 64 + 44 = 2 x 128 + 44, the last q block and the last
+    # KV block padded; 1000 under a window that is a multiple of neither.
+    "ragged-300": ((300, 64, 128, None, None), (9, 9)),
+    "ragged-1000-window-300": ((1000, 64, 128, 300, None), (52, 52)),
+    "window-shorter-than-a-block": ((768, 128, 128, 20, None), (11, 11)),
+    # Chunks of q blocks (a sequence over the VMEM budget): 16 q blocks as 4
+    # chunks of 4, the longest chunk's steps for all (the last chunk's 30
+    # pairs; under the window 12 pairs and the 4 KV blocks that need nothing
+    # of the chunk); 5 q blocks padded to 6, the sixth wholly padding.
+    "chunked-8192": ((8192, 512, 1024, None, 4), (72, 30)),
+    "chunked-8192-window-2048": ((8192, 512, 1024, 2048, 4), (42, 16)),
+    "chunked-ragged-600": ((600, 128, 256, None, 2), (9, 5)),
+    "chunked-ragged-600-window-200": ((600, 128, 256, 200, 2), (9, 5)),
+}
+
+
+def _step_tables(name):
+    from torchft_tpu.ops import flash_attention as fa
+
+    (s, block_q, block_k, window, nqc), _ = _STEP_TABLES[name]
+    nq = -(-s // block_q)
+    nqc = nqc or nq
+    forward = fa._fwd_steps(fa._own_classes(s, s, block_q, block_q, block_k, window))
+    classes = fa._own_classes(s, s, nqc * block_q, block_q, block_k, window)
+    backward, n = fa._bwd_steps(classes, nqc)
+    return forward, backward.reshape(4, -1, n), classes, nqc
+
+
+@pytest.mark.parametrize("name", list(_STEP_TABLES))
+def test_the_step_tables_list_the_needed_pairs_in_the_order_of_the_dense_walk(name):
+    """The trace-time classes are the ones the schedule gives from the
+    position arrays; the forward's table lists exactly the needed pairs, q
+    blocks outer and a q block's KV blocks ascending, with each pair's class
+    and each q block's first and last step flagged once; the backward's lists
+    them chunk by chunk, KV blocks outer and the chunk's q blocks ascending,
+    each KV block's first and last step and each q block's first and last
+    visit flagged once; what is listed beside them has class 0 and names a
+    block the walk already holds (or a q block of padding, once)."""
+    from torchft_tpu.ops import flash_attention as fa
+
+    (s, block_q, block_k, window, _), (n_forward, n_backward) = _STEP_TABLES[name]
+    forward, backward, classes, nqc = _step_tables(name)
+    nc, nk = backward.shape[1], classes.shape[1]
+    qp, kp = fa._padded_positions(None, None, 1, s, s, nqc * block_q, block_k)
+    tables = fa._block_schedule(qp, kp, block_q, block_k, True, window)
+    assert np.array_equal(classes, np.asarray(fa._block_classes(*tables, window))[0])
+
+    # Forward: the q blocks the unchunked call has (no block wholly padding).
+    own = classes[: -(-s // block_q)]
+    assert forward.dtype == np.int32 and forward.shape == (4, n_forward)
+    assert [tuple(pair) for pair in forward[: fa._CLASS].T] == [
+        (iq, ik) for iq in range(own.shape[0]) for ik in range(nk) if own[iq, ik]
+    ]
+    assert np.array_equal(forward[fa._CLASS], own[forward[fa._IQ], forward[fa._IK]])
+    for iq in range(own.shape[0]):
+        flags = forward[fa._FLAGS][forward[fa._IQ] == iq]
+        assert flags[0] & fa._ROW_FIRST and flags[-1] & fa._ROW_LAST
+        assert np.sum(flags & fa._ROW_FIRST != 0) == np.sum(flags & fa._ROW_LAST != 0) == 1
+    assert fa._class_counts(s, s, block_q, block_k, window=window)["steps"] == n_forward
+
+    # Backward, chunk by chunk.
+    assert backward.shape == (4, nc, n_backward)
+    assert max(
+        np.sum(classes[c * nqc : (c + 1) * nqc] > 0) for c in range(nc)
+    ) <= n_backward
+    for c in range(nc):
+        iq, ik, kind, flags = backward[:, c]
+        assert np.all((c * nqc <= iq) & (iq < (c + 1) * nqc))
+        assert np.array_equal(kind, classes[iq, ik] * (kind > 0))
+        needed = [(i, k) for i, k, cls in zip(iq, ik, kind) if cls]
+        assert needed == [
+            (i, k) for k in range(nk) for i in range(c * nqc, (c + 1) * nqc) if classes[i, k]
+        ]
+        # KV blocks never go back, and a KV block's steps are one run: first
+        # and last flagged once for each of the nk, needed or not.
+        assert np.all(np.diff(ik) >= 0) and set(ik) == set(range(nk))
+        for k in range(nk):
+            mine = flags[ik == k]
+            assert np.sum(mine & fa._ROW_FIRST != 0) == np.sum(mine & fa._ROW_LAST != 0) == 1
+            first = np.flatnonzero(mine & fa._ROW_FIRST)[0]
+            last = np.flatnonzero(mine & fa._ROW_LAST)[0]
+            assert first == 0 and np.all(kind[ik == k][last + 1 :] == 0)
+        # Every q block of the chunk has its dq rows begun once, before its
+        # first needed pair, and ended once, after its last.
+        for block in range(c * nqc, (c + 1) * nqc):
+            at = np.flatnonzero(iq == block)
+            begun = at[flags[at] & fa._Q_FIRST != 0]
+            ended = at[flags[at] & fa._Q_LAST != 0]
+            assert len(begun) == len(ended) == 1
+            work = at[kind[at] > 0]
+            if len(work):
+                assert begun[0] <= work[0] and work[-1] <= ended[0]
+        # A step of class 0 names the KV block and (but for a q block of
+        # padding, listed for its rows of dq) the q block of the step before.
+        for at in np.flatnonzero(kind == 0):
+            padding = not classes[iq[at]].any()
+            assert padding or at == 0 or iq[at] == iq[at - 1], (c, at)
+
+
+@pytest.mark.parametrize(
+    "s, window, steps",
+    [(2048, None, 6), (8192, None, 72), (16384, None, 272), (16384, 4096, 140)],
+)
+def test_the_count_of_steps_says_which_walk_a_call_takes(s, window, steps):
+    """``_class_counts(...)["steps"]``: the grid steps of a head's forward.
+    Without position arrays, the needed pairs (diagonal + under + edge); with
+    them, every pair of the grid; and the needed pairs no longer where their
+    table would outgrow SMEM (by shape alone)."""
+    from torchft_tpu.ops import flash_attention as fa
+
+    counts = fa._class_counts(s, s, 512, 1024, window=window)
+    assert counts["steps"] == steps
+    assert steps == counts["diagonal"] + counts["under"] + counts.get("edge", 0)
+    at = jnp.arange(s, dtype=jnp.int32)[None]
+    dense = fa._class_counts(s, s, 512, 1024, at, at, window=window)
+    assert dense["steps"] == (s // 512) * (s // 1024)
+    assert {k: v for k, v in dense.items() if k != "steps"} == {
+        k: v for k, v in counts.items() if k != "steps"
+    }
+
+
+def test_a_step_table_over_the_smem_budget_falls_back_to_the_dense_walk(monkeypatch):
+    from torchft_tpu.ops import flash_attention as fa
+
+    assert fa._class_counts(131072, 131072, 512, 1024)["steps"] == 16512
+    assert 16 * 16512 <= fa._MAX_TABLE_BYTES < 16 * 65792
+    assert fa._class_counts(262144, 262144, 512, 1024)["steps"] == 512 * 256
+    # The same choice in the calls themselves, at a size the interpreter runs.
+    q, k, v = _qkv(1, 256, 2, 1, 16, seed=2)
+    call = lambda: str(jax.make_jaxpr(
+        lambda q, k, v: jax.grad(lambda q: jnp.sum(flash_attention(
+            q, k, v, block_q=64, block_k=128, interpret=True, use_pallas_bwd=True
+        )))(q)
+    )(q, k, v))
+    listed = call()
+    monkeypatch.setattr(fa, "_MAX_TABLE_BYTES", 16 * 6 - 1)
+    dense = call()
+    assert "grid=(1, 2, 6)" in listed and "grid=(1, 2, 1, 6)" in listed
+    assert "grid=(1, 2, 4, 2)" in dense and "grid=(1, 2, 1, 2, 4)" in dense
+
+
+def _random_selection(b, s, topk, seed):
+    """(b, s, s) int8: ``topk`` keys a query, never one later than it."""
+    scores = jax.random.normal(jax.random.PRNGKey(seed), (b, s, s))
+    at = jnp.arange(s)
+    causal = at[:, None] >= at[None, :]
+    scores = jnp.where(causal, scores, -jnp.inf)
+    kth = jnp.sort(scores, axis=-1)[..., -topk][..., None]
+    return (causal & (scores >= kth)).astype(jnp.int8)
+
+
+# name: (s, block_q, block_k), (q heads, kv heads), window, selection's topk,
+# q blocks of dq resident in the backward (None: all, one chunk)
+_LISTED_CASES = {
+    "causal-gqa4": ((512, 64, 128), (4, 1), None, None, None),
+    "causal-ragged-300": ((300, 64, 128), (4, 2), None, None, None),
+    "window-160": ((512, 64, 128), (4, 2), 160, None, None),
+    "window-100-ragged-300": ((300, 64, 128), (4, 2), 100, None, None),
+    "window-20-shorter-than-a-block": ((384, 64, 128), (2, 2), 20, None, None),
+    "selection-gqa4": ((256, 32, 128), (4, 1), None, 24, None),
+    "selection-ragged-200": ((200, 32, 128), (4, 2), None, 24, None),
+    "chunked-causal": ((512, 64, 128), (4, 1), None, None, 2),
+    "chunked-ragged-600": ((600, 128, 256), (4, 2), None, None, 2),
+    "chunked-window-200-ragged-600": ((600, 128, 256), (4, 2), 200, None, 2),
+    "chunked-selection": ((384, 32, 128), (4, 2), None, 24, 4),
+}
+
+
+@pytest.mark.parametrize("name", list(_LISTED_CASES))
+def test_the_listed_walk_equals_the_dense_walk_bit_for_bit(name):
+    """out, lse, dq, dk, dv of a call without position arrays (the grid of
+    needed pairs) equal, bit for bit, the same call given the sequence's own
+    positions as arrays (the grid of every pair): the same pairs in the same
+    order into every accumulator. Causal, under a window, under a selection,
+    GQA, ragged lengths, and the backward in chunks of q blocks (its VMEM set
+    small); and both match dense attention under the same mask."""
+    from torchft_tpu.ops import flash_attention as fa
+
+    (s, block_q, block_k), (h, kv), window, topk, resident = _LISTED_CASES[name]
+    b, d = 2, 16
+    q, k, v = _qkv(b, s, h, kv, d, seed=41)
+    d_out = jax.random.normal(jax.random.PRNGKey(42), q.shape, jnp.float32)
+    selection = None if topk is None else _random_selection(b, s, topk, seed=43)
+    at = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
+    budget = {}
+    if resident is not None:
+        tile = (block_q, block_k, d, 4, 4, selection is not None)
+        budget["vmem_bytes"] = fa._bwd_vmem_bytes(resident * block_q, *tile)
+        assert fa._q_chunks(s, budget["vmem_bytes"], *tile)[0] > 1
+
+    def run(qp, kp):
+        out, lse = fa._flash_fwd(
+            q, k, v, d**-0.5, block_q, block_k, True, qp, kp,
+            selection=selection, window=window,
+        )
+        grads = fa.flash_attention_partial_bwd(
+            q, k, v, d_out, out, lse.reshape(b, s, h), qp, kp,
+            d**-0.5, block_q, block_k, True,
+            selection=selection, window=window, **budget,
+        )
+        return (out, lse, *grads)
+
+    def grids(qp, kp):
+        return [
+            eqn.params["grid_mapping"].grid
+            for eqn in jax.make_jaxpr(lambda: run(qp, kp))().eqns
+            if eqn.primitive.name == "pallas_call"
+        ]
+
+    listed, dense = run(None, None), run(at, at)
+    (fwd_listed, bwd_listed), (fwd_dense, bwd_dense) = grids(None, None), grids(at, at)
+    assert len(fwd_listed) == 3 and len(fwd_dense) == 4
+    assert len(bwd_listed) == 4 and len(bwd_dense) == 5
+    assert fwd_listed[2] < fwd_dense[2] * fwd_dense[3]
+    for got, want, what in zip(listed, dense, ("out", "lse", "dq", "dk", "dv")):
+        assert np.array_equal(np.asarray(got), np.asarray(want)), what
+
+    if selection is not None:
+        mask = selection != 0
+    else:
+        apart = at[:, :, None] - at[:, None, :]
+        mask = (apart >= 0) & (apart < (window or s))
+
+    def reference(q, k, v):
+        qg = q.reshape(b, s, kv, h // kv, d)
+        scores = jnp.einsum("bskgd,btkd->bskgt", qg, k) * d**-0.5
+        p = jax.nn.softmax(jnp.where(mask[:, :, None, None, :], scores, -1e30), axis=-1)
+        return jnp.einsum("bskgt,btkd->bskgd", p, v).reshape(b, s, h, d)
+
+    ref, vjp = jax.vjp(reference, q, k, v)
+    np.testing.assert_allclose(np.asarray(listed[0]), np.asarray(ref), atol=2e-5)
+    for got, want, what in zip(listed[2:], vjp(d_out), ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=5e-5, err_msg=what)

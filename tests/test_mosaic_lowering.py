@@ -123,31 +123,50 @@ def test_flash_partial_and_partial_bwd_lower_for_tpu():
 
 
 @pytest.mark.parametrize(
-    "b,s,grid",
+    "b,s,own,grid,tables",
     [
-        pytest.param(1, 8192, (1, 32, 1, 8, 16), id="cells-1x8192"),
-        pytest.param(4, 2048, (4, 32, 1, 2, 4), id="cells-4x2048"),
-        pytest.param(1, 65536, (1, 32, 2, 64, 64), id="over-budget-1x65536"),
+        pytest.param(1, 8192, True, (1, 32, 1, 72), [(4, 72)], id="cells-1x8192"),
+        pytest.param(4, 2048, True, (4, 32, 1, 6), [(4, 6)], id="cells-4x2048"),
+        # Chunk 0 (q blocks 0..63) needs 1056 pairs and one empty step for each
+        # of the 32 KV blocks behind it, chunk 1 needs 2048 + 1056: both walk
+        # the longer's 3104 steps.
+        pytest.param(
+            1, 65536, True, (1, 32, 2, 3104), [(4, 2 * 3104)], id="over-budget-1x65536"
+        ),
+        # With position arrays (a ring hop's) the walk is over every pair, as
+        # it was: (batch, q heads, q chunks, KV blocks, q blocks of a chunk).
+        pytest.param(
+            1, 8192, False, (1, 32, 1, 8, 16), [(1, 3, 16), (1, 3, 8)],
+            id="positions-1x8192",
+        ),
+        pytest.param(
+            1, 65536, False, (1, 32, 2, 64, 64), [(1, 3, 128), (1, 3, 64)],
+            id="positions-over-budget-1x65536",
+        ),
     ],
 )
-def test_flash_backward_lowers_at_the_cells_geometry(b, s, grid):
+def test_flash_backward_lowers_at_the_cells_geometry(b, s, own, grid, tables):
     # The benchmark cells' attention (32 q heads over 8 KV heads of 128) at
     # the default blocks, and a sequence over the VMEM a call may hold, which
-    # the one backward call walks in two chunks of q blocks: its grid is
-    # (batch, q heads, q chunks, KV blocks, q blocks of a chunk).
+    # the one backward call walks in two chunks of q blocks. A call whose
+    # positions are the sequence's own steps the needed pairs alone: its grid
+    # is (batch, q heads, q chunks, steps of a chunk) and its one table the
+    # steps of every chunk.
     h, kv, d = 32, 8, 128
     q = _sds((b, s, h, d), jnp.bfloat16)
     k = _sds((b, s, kv, d), jnp.bfloat16)
     lse = _sds((b, s, h), jnp.float32)
+    positions = [None, None] if own else [_sds((b, s), jnp.int32)] * 2
     traced = jax.jit(
-        lambda q, k, v, do, out, lse: flash_attention_partial_bwd(
-            q, k, v, do, out, lse, None, None,
+        lambda q, k, v, do, out, lse, *positions: flash_attention_partial_bwd(
+            q, k, v, do, out, lse, *(positions or (None, None)),
             scale=d**-0.5, block_q=512, block_k=1024, interpret=False,
             out_dtype=jnp.bfloat16,
         )
-    ).trace(q, k, k, q, q, lse)
+    ).trace(q, k, k, q, q, lse, *(p for p in positions if p is not None))
     (call,) = _pallas_calls(traced.jaxpr.jaxpr)
     assert call.params["grid_mapping"].grid == grid
+    assert _kernel_refs(call)[: len(tables)] == [(shape, "int32") for shape in tables]
     traced.lower(lowering_platforms=("tpu",))
 
 
@@ -191,7 +210,7 @@ def _cell_gradient(kv, with_selection):
 
 
 _BF16, _F32, _I32 = "bfloat16", "float32", "int32"
-_TABLES = [((1, 3, 16), _I32), ((1, 3, 8), _I32)]
+_TABLES = [((4, 72), _I32)]  # the step table of the 72 needed pairs of 16 x 8
 _FWD_REST = [  # out and the logsumexp; acc, running max and sum
     ((512, 128), _BF16), ((512, 1), _F32),
     ((512, 128), _F32), ((512, 1), _F32), ((512, 1), _F32),
@@ -208,20 +227,21 @@ _SELECTION = [((512, 1024), "int8")]
 
 def test_without_a_selection_the_calls_are_the_ones_the_mistral_cells_run():
     """The contract with every caller that passes no selection (the four
-    Mistral cells, chip_smoke.py, the ring hops): operand for operand, the
-    grid, the blocks and the scratch the two calls had before the argument
-    existed (read off the commit before it, at the cells' 1 x 8192 geometry),
-    and no int8 anywhere."""
+    Mistral cells, chip_smoke.py): operand for operand, the blocks and the
+    scratch the two calls had before the argument existed (read off the
+    commit before it, at the cells' 1 x 8192 geometry), and no int8 anywhere.
+    The grid is the 72 needed pairs of the 128, and the one table their list
+    (``flash_attention`` brings no position arrays)."""
     traced, forward, backward = _cell_gradient(kv=8, with_selection=False)
-    assert forward.params["grid_mapping"].grid == (1, 32, 16, 8)
-    assert backward.params["grid_mapping"].grid == (1, 32, 1, 8, 16)
+    assert forward.params["grid_mapping"].grid == (1, 32, 72)
+    assert backward.params["grid_mapping"].grid == (1, 32, 1, 72)
     assert _kernel_refs(forward) == _TABLES + _QKV + _POSITIONS + _FWD_REST
     assert _kernel_refs(backward) == _TABLES + _QKV + _BWD_ROWS + _POSITIONS + _BWD_REST
     assert [(v.aval.shape, str(v.aval.dtype)) for v in forward.invars] == _TABLES + [
         ((1, 32, 8192, 128), _BF16), ((1, 8, 8192, 128), _BF16), ((1, 8, 8192, 128), _BF16),
         ((1, 8192, 1), _I32), ((1, 1, 8192), _I32),
     ]
-    assert len(backward.invars) == 10
+    assert len(backward.invars) == 9
     # No int8 tensor among the lowered module's values (">" is no letter of
     # the base64 the Mosaic bodies are written in).
     assert "xi8>" not in traced.lower(lowering_platforms=("tpu",)).as_text()
@@ -234,8 +254,8 @@ def test_selected_flash_kernels_lower_for_tpu_at_the_keye_cells_geometry():
     in their place, in the forward and in the one backward call, and the pair
     lowers for a TPU (the int8 block's (32, 128) tiling)."""
     traced, forward, backward = _cell_gradient(kv=4, with_selection=True)
-    assert forward.params["grid_mapping"].grid == (1, 32, 16, 8)
-    assert backward.params["grid_mapping"].grid == (1, 32, 1, 8, 16)
+    assert forward.params["grid_mapping"].grid == (1, 32, 72)
+    assert backward.params["grid_mapping"].grid == (1, 32, 1, 72)
     assert _kernel_refs(forward) == _TABLES + _QKV + _SELECTION + _FWD_REST
     assert _kernel_refs(backward) == _TABLES + _QKV + _BWD_ROWS + _SELECTION + _BWD_REST
     assert (forward.invars[-1].aval.shape, backward.invars[-1].aval.shape) == ((1, 8192, 8192),) * 2
@@ -260,15 +280,22 @@ def test_selected_flash_kernels_lower_with_ragged_lengths_and_blocks(s, block_q)
     _lower_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, k, k, _sds((b, s, s), jnp.int8))
 
 
-@pytest.mark.parametrize("where", ["plain", "shard_map-batch", "shard_map-positions"])
+@pytest.mark.parametrize(
+    "where", ["plain", "shard_map-batch", "shard_map-positions", "shard_map-batch-own"]
+)
 def test_flash_kernels_lower_with_their_schedule_tables(where):
     """The forward and the backward call each take the causal block
     schedule as two scalar-prefetch operands that their index maps and
-    bodies read from SMEM, and lower for a TPU: bare; under a shard_map over the batch (the
-    model's ``ops.attention.flash_under_mesh``: tables of ``arange`` that vary
+    bodies read from SMEM, and lower for a TPU: bare; under a shard_map over the batch
+    (tables of ``arange`` that vary
     over no axis beside data that does); and under a shard_map over the
     sequence with the positions as arguments (a ring hop: the tables vary
-    with the shard)."""
+    with the shard). With position arrays the grids are over every pair,
+    (2, 4, 8, 4) and (2, 4, 1, 4, 8) at these 8 x 4 blocks, whatever the
+    arrays hold. Without them (``own``: the model's
+    ``ops.attention.flash_under_mesh`` under its shard_map over the batch) each
+    call takes its one step table, a constant that varies over no axis, and
+    steps the 20 needed pairs."""
     from jax import shard_map
     from jax.sharding import AbstractMesh, PartitionSpec as P
 
@@ -282,6 +309,15 @@ def test_flash_kernels_lower_with_their_schedule_tables(where):
             q, k, v, do, out, lse, qp, kp,
             scale=d**-0.5, block_q=128, block_k=256, interpret=False,
         )
+
+    def own(q, k, v, do):
+        out, vjp = jax.vjp(
+            lambda q, k, v: flash_attention(
+                q, k, v, block_q=128, block_k=256, interpret=False, use_pallas_bwd=True
+            ),
+            q, k, v,
+        )
+        return vjp(do)
 
     def arange(x):
         return jnp.broadcast_to(jnp.arange(x.shape[1], dtype=jnp.int32), x.shape[:2])
@@ -297,6 +333,12 @@ def test_flash_kernels_lower_with_their_schedule_tables(where):
             in_specs=(P("fsdp"),) * 4, out_specs=(P("fsdp"),) * 3,
         )
         args = data
+    elif where == "shard_map-batch-own":
+        fn = shard_map(
+            own, mesh=AbstractMesh((2,), ("fsdp",)),
+            in_specs=(P("fsdp"),) * 4, out_specs=(P("fsdp"),) * 3,
+        )
+        args = data
     else:
         fn = shard_map(
             both, mesh=AbstractMesh((4,), ("sp",)),
@@ -306,7 +348,14 @@ def test_flash_kernels_lower_with_their_schedule_tables(where):
     traced = jax.jit(fn).trace(*args)
     calls = _pallas_calls(traced.jaxpr.jaxpr)
     assert len(calls) == 2
-    assert [c.params["grid_mapping"].num_index_operands for c in calls] == [2, 2]
+    tables, grids = {
+        "plain": (2, [(2, 4, 8, 4), (2, 4, 1, 4, 8)]),
+        "shard_map-batch": (2, [(1, 4, 8, 4), (1, 4, 1, 4, 8)]),
+        "shard_map-positions": (2, [(2, 4, 2, 1), (2, 4, 1, 1, 2)]),
+        "shard_map-batch-own": (1, [(1, 4, 20), (1, 4, 1, 20)]),
+    }[where]
+    assert [c.params["grid_mapping"].num_index_operands for c in calls] == [tables] * 2
+    assert [c.params["grid_mapping"].grid for c in calls] == grids
     lowered = traced.lower(lowering_platforms=("tpu",))
     assert lowered.as_text().count("tpu_custom_call") >= 2
 
@@ -520,11 +569,13 @@ def test_8b_sharded_flash_train_step_lowers_for_tpu(monkeypatch):
 def test_windowed_flash_kernels_lower_for_tpu_at_the_smallthinker_cells_geometry():
     """With a window of 4,096 at 1 x 16,384 (28 q heads over 4 KV heads of
     128, as the cell ``smallthinker-21b-a3b-1chip.ftddp-seq16k`` runs its
-    windowed layers): the causal call's grids and operands but for the two
-    schedule tables, which gain a fourth row (the far edge), the calls carry
-    names of their own, and the pair lowers for a TPU. The same call without
-    the window keeps its three rows and no name."""
-    from torchft_tpu.ops.flash_attention import WINDOW_BWD, WINDOW_FWD
+    windowed layers): the grids are the 140 pairs of the 32 x 16 the window
+    needs and the one table their list, the operands after it the causal
+    call's, the calls carry names of their own, and the pair lowers for a TPU.
+    The same call without the window steps the diagonal's 272 and has no name.
+    Given position arrays, the windowed pair walks every pair as it did, its
+    two schedule tables with their fourth row (the far edge)."""
+    from torchft_tpu.ops.flash_attention import WINDOW_BWD, WINDOW_FWD, _flash_fwd
 
     b, s, h, kv, d = 1, 16384, 28, 4, 128
     q = _sds((b, s, h, d), jnp.bfloat16)
@@ -539,14 +590,31 @@ def test_windowed_flash_kernels_lower_for_tpu_at_the_smallthinker_cells_geometry
         return (traced, *_pallas_calls(traced.jaxpr.jaxpr))
 
     traced, forward, backward = gradient(4096)
+    assert forward.params["grid_mapping"].grid == (1, 28, 140)
+    assert backward.params["grid_mapping"].grid == (1, 28, 1, 140)
+    assert _kernel_refs(forward)[0] == _kernel_refs(backward)[0] == ((4, 140), _I32)
+    assert _kernel_refs(forward)[1:] == _QKV + _POSITIONS + _FWD_REST
+    assert [call.params["name"] for call in (forward, backward)] == [WINDOW_FWD, WINDOW_BWD]
+    traced.lower(lowering_platforms=("tpu",))
+    _, forward, backward = gradient(None)
+    assert forward.params["grid_mapping"].grid == (1, 28, 272)
+    assert backward.params["grid_mapping"].grid == (1, 28, 1, 272)
+    assert _kernel_refs(forward)[0] == _kernel_refs(backward)[0] == ((4, 272), _I32)
+    assert not {WINDOW_FWD, WINDOW_BWD} & {call.params["name"] for call in (forward, backward)}
+
+    def hop(q, k, v, do, qp, kp):
+        out, lse = _flash_fwd(q, k, v, d**-0.5, 512, 1024, False, qp, kp, window=4096)
+        return flash_attention_partial_bwd(
+            q, k, v, do, out, lse.reshape(b, s, h), qp, kp, d**-0.5, 512, 1024, False,
+            window=4096,
+        )
+
+    positions = _sds((b, s), jnp.int32)
+    traced = jax.jit(hop).trace(q, k, k, q, positions, positions)
+    forward, backward = _pallas_calls(traced.jaxpr.jaxpr)
     assert forward.params["grid_mapping"].grid == (1, 28, 32, 16)
     assert backward.params["grid_mapping"].grid == (1, 28, 1, 16, 32)
     assert _kernel_refs(forward)[:2] == [((1, 4, 32), _I32), ((1, 4, 16), _I32)]
     assert _kernel_refs(backward)[:2] == [((1, 4, 32), _I32), ((1, 4, 16), _I32)]
-    assert _kernel_refs(forward)[2:] == _QKV + _POSITIONS + _FWD_REST
     assert [call.params["name"] for call in (forward, backward)] == [WINDOW_FWD, WINDOW_BWD]
     traced.lower(lowering_platforms=("tpu",))
-    _, forward, backward = gradient(None)
-    assert _kernel_refs(forward)[:2] == [((1, 3, 32), _I32), ((1, 3, 16), _I32)]
-    assert _kernel_refs(backward)[:2] == [((1, 3, 32), _I32), ((1, 3, 16), _I32)]
-    assert not {WINDOW_FWD, WINDOW_BWD} & {call.params["name"] for call in (forward, backward)}

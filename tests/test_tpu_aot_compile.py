@@ -246,6 +246,37 @@ def test_flash_backward_compiles_at_the_cells_geometry(
     assert used and used[0] <= (need if states_limit else fa._SCOPED_VMEM_BYTES)
 
 
+@pytest.mark.parametrize(
+    "seq, steps",
+    [
+        pytest.param(131072, 16512, id="listed-131072"),
+        pytest.param(262144, 512 * 256, id="dense-262144"),
+    ],
+)
+def test_the_step_table_fits_smem_where_the_call_lists_its_pairs(chip, seq, steps) -> None:
+    """A call without position arrays steps the needed pairs alone, its table
+    a scalar-prefetch operand of 16 bytes a step that rides in SMEM whole:
+    264 KB at 131,072 rows, which the compiler takes, forward and backward
+    (the backward in chunks, each as long as the longest). At 262,144 the
+    table would be the 1,052,672 bytes the compiler refuses (the v5e's SMEM is
+    1 MiB); over ``_MAX_TABLE_BYTES`` the call walks every pair, by shape
+    alone, and compiles as it did."""
+    from torchft_tpu.ops import flash_attention as fa
+
+    assert fa._class_counts(seq, seq, 512, 1024)["steps"] == steps
+    assert (16 * steps <= fa._MAX_TABLE_BYTES) == (seq == 131072)
+    q = _sds((1, seq, 1, CELL_D), jnp.bfloat16, chip)
+    lse = _sds((1, seq, 1), jnp.float32, chip)
+    _compile(lambda q, k, v: fa.flash_attention(q, k, v, interpret=False), q, q, q)
+    _compile(
+        lambda q, k, v, d_out, out, lse: fa.flash_attention_partial_bwd(
+            q, k, v, d_out, out, lse, None, None, CELL_D**-0.5, 512, 1024, False,
+            out_dtype=jnp.bfloat16,
+        ),
+        q, q, q, q, q, lse,
+    )
+
+
 @pytest.mark.parametrize("wire", ["fp8", "int8"])
 def test_codec_pair_compiles_at_fragment_size(chip, wire) -> None:
     from torchft_tpu.ops import quantization as q

@@ -14,8 +14,8 @@ Forward AND backward are fused Pallas kernels on TPU. The backward is the
 FlashAttention-2 recompute from the saved (out, lse) residuals as ONE
 kernel: it visits each needed (q block, KV block) pair once, recomputes the
 pair's probabilities once and adds to all three gradients, five matmuls a
-pair. Its grid walks q blocks innermost, so dk and dv accumulate in
-(block_k, d) scratch across the inner axis, and dq across the outer one in
+pair. It walks q blocks innermost, so dk and dv accumulate in
+(block_k, d) scratch across a KV block's steps, and dq across the KV blocks in
 a float32 scratch that holds every q row of the head, written out once a
 head through an output block that is resident as long (a sequence whose
 rows do not fit is walked in chunks of q blocks, ``_q_chunks``). The
@@ -29,18 +29,39 @@ models' ``remat="dots"`` keeps both by name (models/decoder.py
 ``remat_policy``); ``remat="full"`` and any policy that does not name them
 recompute the kernel, and without remat the names do nothing.
 :func:`flash_attention_partial` is untagged: its VJP is the ring's own.
-Both kernels walk a dense (q block, KV block) grid and know where the
-causal diagonal runs through it: XLA reduces the position arrays to each
-block's lowest and highest position once a call (:func:`_block_schedule`),
-the two small tables ride in as scalar prefetch, and a grid step reads its
-pair's class from SMEM before it begins. A pair ABOVE the diagonal (every key
-later than every query) runs no matmul, and its index maps name the block the
-neighbouring needed step holds, so the pipeline copies nothing for it; a pair
-UNDER it (every key at or before every query) runs its matmuls without the
-compare and select; a pair the DIAGONAL crosses, and any pair with a padded
-row or column, takes the mask. The classes follow the positions alone, so a
-ring's zigzag hops, ``sq != sk`` and ragged lengths schedule by the same two
-comparisons as the plain causal call.
+Both kernels know where the causal diagonal runs through the (q block, KV
+block) grid. A pair ABOVE the diagonal (every key later than every query) runs
+no matmul and fetches nothing; a pair UNDER it (every key at or before every
+query) runs its matmuls without the compare and select; a pair the DIAGONAL
+crosses, and any pair with a padded row or column, takes the mask. The classes
+follow the positions alone (:func:`_pair_class`), and HOW a call walks the grid
+follows from whether it was given them (:class:`_Walk`):
+- ``flash_attention`` brings no position arrays: the positions are the
+  sequence's own, every pair's class is known while tracing, and the grid is
+  the LIST of the needed pairs: (b, h, steps) in the forward, (b, h, q chunks,
+  steps of a chunk) in the backward, in the order a dense walk would visit them
+  (a q block's KV blocks ascending; in the backward a KV block's q blocks). One
+  int32 step table, a numpy constant passed as scalar prefetch
+  (:func:`_fwd_steps`, :func:`_bwd_steps`), gives each step its pair, its class
+  and where its accumulators begin and end; the index maps read the pair from
+  it. No grid step is spent on a pair that needs nothing, and the step before
+  a new row is a needed one, so the row's first fetch hides behind its
+  matmuls. The table is 16 bytes a step in SMEM (4.4 KB at 16,384 rows in
+  512 x 1024 blocks, 264 KB at 131,072); a call whose table would pass
+  ``_MAX_TABLE_BYTES`` (over 131,072 rows at those blocks) takes the walk
+  below instead, by its shape alone.
+- ``flash_attention_partial`` / ``flash_attention_partial_bwd`` with position
+  ARRAYS (a ring's zigzag hops, ``sq != sk``): the needed pairs are data, so
+  the grid is DENSE, every pair a step: XLA reduces the arrays to each block's
+  lowest and highest position once a call (:func:`_block_schedule`), the two
+  small tables ride in as scalar prefetch, a grid step reads its pair's class
+  from SMEM before it begins, and the index maps of a pair that needs nothing
+  name the block the neighbouring needed step holds, so the pipeline copies
+  nothing for it.
+Both walks run the same kernel bodies on the same pairs in the same order, so
+their results are the same bits (tests/test_flash_attention.py and
+:func:`verify_on_chip` hold that); ``_class_counts(...)["steps"]`` says which
+walk a call takes.
 A call may bring a SELECTION, a (b, s, s) int8 operand that says which keys
 each query attends to (``flash_attention(..., selection=...)``; the learned
 key selection of models/keye.py, made once a layer step by
@@ -60,14 +81,14 @@ A call may bring a WINDOW instead (``flash_attention(..., window=...)``; the
 windowed layers of models/smallthinker.py): query t attends to key u iff
 ``0 <= t - u < window``. That is a second edge through the grid, and the
 schedule knows it as it knows the diagonal: a pair wholly BEHIND the window is
-neither fetched nor computed, a pair the window's EDGE crosses takes the mask
-(both compares), a pair wholly INSIDE runs bare (:func:`_pair_class`); the two
-tables gain a fourth row, the first KV block a q block needs and the last q
-block a KV block needs, so the index maps stop on both sides; forward and the
-one backward call alike. The two calls carry names of their own
-(``WINDOW_FWD``, ``WINDOW_BWD``), which is what a device trace tells them from
-the causal calls by; a call without a window has three-row tables and no name,
-the module it was.
+neither fetched nor computed (in the listed walk it is no step at all), a pair
+the window's EDGE crosses takes the mask (both compares), a pair wholly INSIDE
+runs bare (:func:`_pair_class`); the dense walk's two tables gain a fourth row,
+the first KV block a q block needs and the last q block a KV block needs, so
+its index maps stop on both sides; forward and the one backward call alike.
+The two calls carry names of their own (``WINDOW_FWD``, ``WINDOW_BWD``), which
+is what a device trace tells them from the causal calls by; a call without a
+window has no name.
 GQA is handled by emitting per-q-head dk/dv partials and summing over the
 group axis outside — keeps every output block written exactly once per
 grid pass (no cross-step output aliasing, which Mosaic cannot express; the
@@ -96,10 +117,11 @@ sequence across chips) as the per-chip kernel.
 from __future__ import annotations
 
 from functools import partial
-from typing import Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from torchft_tpu.utils.platform import on_tpu
@@ -178,14 +200,12 @@ def _pair_class(q_lo, q_hi, k_lo, k_hi, window=None):
     grid: a pair whose nearest (query, key) is already ``window`` apart lies
     BEHIND it and is not needed, and only a pair whose farthest is still
     inside it is under (INSIDE); the window's EDGE takes the mask like the
-    diagonal. Scalars in the kernels, arrays in :func:`_block_classes`."""
+    diagonal. Scalars in the kernels, arrays in :func:`_block_classes`, numpy
+    arrays while tracing in :func:`_own_classes`."""
     needed, under = k_lo <= q_hi, k_hi <= q_lo
     if window is None:
         return needed, under
-    return (
-        jnp.logical_and(needed, q_lo - k_hi < window),
-        jnp.logical_and(under, q_hi - k_lo < window),
-    )
+    return needed & (q_lo - k_hi < window), under & (q_hi - k_lo < window)
 
 
 def _typed_unvarying(x):
@@ -265,6 +285,245 @@ def _q_block(ib, ik, iq, k_sched, windowed=False):
     return jnp.maximum(iq, k_sched[ib, _EDGE, ik])
 
 
+# Rows of a step table (:func:`_fwd_steps`, :func:`_bwd_steps`) and the bits of
+# its _FLAGS row: the step is the first / the last of its row of the walk (a q
+# block's KV blocks in the forward, a KV block's q blocks in the backward), and
+# in the backward the first / the last that names its q block.
+_IQ, _IK, _CLASS, _FLAGS = 0, 1, 2, 3
+_ROW_FIRST, _ROW_LAST, _Q_FIRST, _Q_LAST = 1, 2, 4, 8
+
+
+def _own_classes(sq, sk, q_rows, block_q, block_k, window=None):
+    """(nq, nk) numpy classes of the pairs (:func:`_block_classes`) of a call
+    whose positions are the sequence's own, made while tracing: what
+    :func:`_padded_positions` and :func:`_block_schedule` give for no
+    position arrays, the q rows padded to a multiple of ``q_rows``."""
+
+    def blocks(n, multiple, block, fill):
+        at = np.full(_next_multiple(n, multiple), fill, np.int32)
+        at[:n] = np.arange(n)
+        at = at.reshape(-1, block)
+        return at.min(axis=1), at.max(axis=1)
+
+    q_lo, q_hi = blocks(sq, q_rows, block_q, -1)
+    k_lo, k_hi = blocks(sk, block_k, block_k, _PAD_POS)
+    needed, under = _pair_class(
+        q_lo[:, None], q_hi[:, None], k_lo[None, :], k_hi[None, :], window
+    )
+    return needed.astype(np.int32) + under
+
+
+def _row_steps(listed):
+    """(row, column, flags) of the steps of a walk over the (rows, columns)
+    bool ``listed``, three numpy arrays: rows outer, a row's listed columns
+    ascending, its first and last step flagged. A row that lists nothing
+    still takes one step, at the column the step before it named, so it is
+    begun and ended and the pipeline copies nothing for it."""
+    rows, columns, flags = [], [], []
+    held = 0
+    for row, mine in enumerate(listed):
+        mine = np.flatnonzero(mine)
+        if not mine.size:
+            mine = np.array([held])
+        mark = np.zeros(mine.size, np.int64)
+        mark[0] |= _ROW_FIRST
+        mark[-1] |= _ROW_LAST
+        rows.append(np.full(mine.size, row))
+        columns.append(mine)
+        flags.append(mark)
+        held = mine[-1]
+    return np.concatenate(rows), np.concatenate(columns), np.concatenate(flags)
+
+
+def _fwd_steps(classes):
+    """The forward's step table from the (nq, nk) ``classes``: (4, n_steps)
+    int32, a column a grid step, in the order the dense walk visits the
+    needed pairs (q blocks outer, a q block's KV blocks ascending). Rows _IQ
+    and _IK name the pair, _CLASS is its class, _FLAGS says where the q
+    block's accumulators begin and end. 16 bytes a step in SMEM: 4.4 KB at
+    16,384 rows in 512 x 1024 blocks, 264 KB at 131,072."""
+    iq, ik, flags = _row_steps(classes > 0)
+    return np.stack([iq, ik, classes[iq, ik], flags]).astype(np.int32)
+
+
+def _bwd_steps(classes, nqc):
+    """The backward's step table from the (nc * nqc, nk) ``classes``:
+    (4, nc * n) int32, chunk by chunk ``n`` steps each, and ``n``. KV blocks
+    outer and the chunk's needed q blocks ascending inside one, as the dense
+    walk visits them; _FLAGS says where dk and dv begin and end (the KV
+    block's first and last step) and where the q block's dq rows do (the
+    first and the last step that names it: under a window neither is at the
+    first or the last KV block). A KV block that needs nothing of the chunk
+    takes one step of class 0, which writes its zero partials; a q block
+    wholly of padding is listed once, for its rows of dq; a chunk with fewer
+    steps than the longest ends in steps of class 0 that name the blocks it
+    already holds."""
+    chunks = []
+    for first in range(0, classes.shape[0], nqc):
+        mine = classes[first : first + nqc]
+        listed = mine > 0
+        listed[~listed.any(axis=1), 0] = True
+        ik, jq, flags = _row_steps(listed.T)
+        for block in np.unique(jq):
+            named = np.flatnonzero(jq == block)
+            flags[named[0]] |= _Q_FIRST
+            flags[named[-1]] |= _Q_LAST
+        chunks.append(np.stack([first + jq, ik, mine[jq, ik], flags]))
+    n = max(steps.shape[1] for steps in chunks)
+    for c, steps in enumerate(chunks):
+        rest = np.repeat(steps[:, -1:], n - steps.shape[1], axis=1)
+        rest[[_CLASS, _FLAGS]] = 0
+        chunks[c] = np.concatenate([steps, rest], axis=1)
+    return np.concatenate(chunks, axis=1).astype(np.int32), n
+
+
+class _Walk(NamedTuple):
+    """How a call steps through its (q block, KV block) pairs. ``tables``: its
+    scalar prefetch. ``grid``: the grid's axes after (b, h). ``q_of`` and
+    ``k_of``: the q block and the KV block a step names, from (ib, the step's
+    further axes, the tables). ``step``: called in the kernel with the
+    tables' refs, what the body needs to know of its step."""
+
+    tables: tuple
+    grid: tuple
+    q_of: Callable
+    k_of: Callable
+    step: Callable
+
+
+def _scheduled_class(qs_ref, ks_ref, ib, iq, ik, window):
+    """(needed, under) of pair (iq, ik) in a kernel of the dense walk, from
+    the schedule tables in SMEM."""
+    return _pair_class(
+        qs_ref[ib, _LO, iq], qs_ref[ib, _HI, iq],
+        ks_ref[ib, _LO, ik], ks_ref[ib, _HI, ik],
+        window,
+    )
+
+
+def _dense_fwd_walk(qp, kp, block_q, block_k, interpret, window):
+    """The forward over every pair, grid (nq, nk), the classes read from the
+    positions' schedule a step at a time: for calls with position arrays,
+    whose needed pairs are data. A step beyond a q block's edge names the KV
+    block at the edge (under a window, at either edge)."""
+    from jax.experimental import pallas as pl
+
+    q_sched, k_sched = _block_schedule(qp, kp, block_q, block_k, interpret, window)
+    nq, nk = q_sched.shape[2], k_sched.shape[2]
+    windowed = window is not None
+
+    def step(qs_ref, ks_ref):
+        ib, iq, ik = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+        return ik == 0, ik == nk - 1, *_scheduled_class(qs_ref, ks_ref, ib, iq, ik, window)
+
+    return _Walk(
+        (q_sched, k_sched), (nq, nk),
+        lambda ib, iq, ik, qs, ks: iq,
+        lambda ib, iq, ik, qs, ks: _kv_block(ib, iq, ik, qs, windowed),
+        step,
+    )
+
+
+def _listed_fwd_walk(steps):
+    """The forward over the needed pairs alone, grid (n_steps,), for calls
+    whose positions are the sequence's own: the classes are then known while
+    tracing and the walk is a constant, the table ``steps``
+    (:func:`_fwd_steps`)."""
+    from jax.experimental import pallas as pl
+
+    def step(steps_ref):
+        at = pl.program_id(2)
+        flags, kind = steps_ref[_FLAGS, at], steps_ref[_CLASS, at]
+        return flags & _ROW_FIRST != 0, flags & _ROW_LAST != 0, kind > 0, kind == 2
+
+    return _Walk(
+        (jnp.asarray(steps),), (steps.shape[1],),
+        lambda ib, at, steps: steps[_IQ, at],
+        lambda ib, at, steps: steps[_IK, at],
+        step,
+    )
+
+
+def _dense_bwd_walk(qp, kp, block_q, block_k, interpret, window, nqc):
+    """The backward over every pair, grid (nc, nk, nqc): the dense forward
+    walk's other half. The q index starts at the KV block's edge (under a
+    window it ends at the other one too) and stays in the chunk."""
+    from jax.experimental import pallas as pl
+
+    q_sched, k_sched = _block_schedule(qp, kp, block_q, block_k, interpret, window)
+    nq, nk = q_sched.shape[2], k_sched.shape[2]
+    windowed = window is not None
+
+    def q_of(ib, ic, ik, jq, qs, ks):
+        block = _q_block(ib, ik, ic * nqc + jq, ks, windowed)
+        if windowed:
+            return jnp.clip(block, ic * nqc, ic * nqc + nqc - 1)
+        return jnp.minimum(block, ic * nqc + nqc - 1)
+
+    def step(qs_ref, ks_ref):
+        ib, ic, ik, jq = (pl.program_id(axis) for axis in (0, 2, 3, 4))
+        pair = _scheduled_class(qs_ref, ks_ref, ib, ic * nqc + jq, ik, window)
+        return jq, jq == 0, jq == nqc - 1, ik == 0, ik == nk - 1, *pair
+
+    return _Walk(
+        (q_sched, k_sched), (nq // nqc, nk, nqc),
+        q_of, lambda ib, ic, ik, jq, qs, ks: ik, step,
+    )
+
+
+def _listed_bwd_walk(steps, n, nqc):
+    """The backward over the needed pairs alone, grid (nc, n): the listed
+    forward walk's other half, its table ``steps`` of ``n`` a chunk
+    (:func:`_bwd_steps`)."""
+    from jax.experimental import pallas as pl
+
+    def step(steps_ref):
+        ic, at = pl.program_id(2), pl.program_id(2) * n + pl.program_id(3)
+        flags, kind = steps_ref[_FLAGS, at], steps_ref[_CLASS, at]
+        return (
+            steps_ref[_IQ, at] - ic * nqc,
+            flags & _ROW_FIRST != 0, flags & _ROW_LAST != 0,
+            flags & _Q_FIRST != 0, flags & _Q_LAST != 0,
+            kind > 0, kind == 2,
+        )
+
+    return _Walk(
+        (jnp.asarray(steps),), (steps.shape[1] // n, n),
+        lambda ib, ic, at, steps: steps[_IQ, ic * n + at],
+        lambda ib, ic, at, steps: steps[_IK, ic * n + at],
+        step,
+    )
+
+
+# A step table rides in SMEM whole, and the v5e has 1 MiB of it for a call's
+# prefetched operands (its compiler refuses 1,052,672 bytes, the forward's
+# table at 262,144 rows in 512 x 1024 blocks, and takes the 264 KB of
+# 131,072). A call whose table would be larger walks every pair instead.
+_MAX_TABLE_BYTES = 512 * 2**10
+
+
+def _fwd_walk(own, qp, kp, sq, sk, block_q, block_k, interpret, window):
+    """The walk of a forward call, by what it was given: without position
+    arrays (``own``: the positions are the sequence's) the needed pairs
+    alone, if their table fits; with them, or over that size, every pair."""
+    if own:
+        steps = _fwd_steps(_own_classes(sq, sk, block_q, block_q, block_k, window))
+        if steps.nbytes <= _MAX_TABLE_BYTES:
+            return _listed_fwd_walk(steps)
+    return _dense_fwd_walk(qp, kp, block_q, block_k, interpret, window)
+
+
+def _bwd_walk(own, qp, kp, sq, sk, block_q, block_k, interpret, window, nqc):
+    """The walk of a backward call: :func:`_fwd_walk`'s choice, by the
+    backward's own table."""
+    if own:
+        classes = _own_classes(sq, sk, nqc * block_q, block_q, block_k, window)
+        steps, n = _bwd_steps(classes, nqc)
+        if steps.nbytes <= _MAX_TABLE_BYTES:
+            return _listed_bwd_walk(steps, n, nqc)
+    return _dense_bwd_walk(qp, kp, block_q, block_k, interpret, window, nqc)
+
+
 def _block_specs(block_q, block_k, d, group, q_of, k_of):
     """BlockSpecs of one pass over a grid (b, h, further axes), by kind of
     operand: ``q`` (a q head's rows: q, dO, out), ``kv`` (a KV head's rows,
@@ -322,18 +581,13 @@ def _allowed(mask_refs, window=None):
     return jnp.logical_and(apart >= 0, apart < window)
 
 
-def _when_needed(qs_ref, ks_ref, ib, iq, ik, update, mask_refs, window=None):
+def _when_needed(needed, under, update, mask_refs):
     """Runs ``update(masked)`` for the pair's class: not at all above the
     diagonal (or behind a window), without the mask under it (inside), with
     it on it (and on the window's edge); where the mask is a
     selection (:func:`_allowed`) every needed pair takes it."""
     from jax.experimental import pallas as pl
 
-    needed, under = _pair_class(
-        qs_ref[ib, _LO, iq], qs_ref[ib, _HI, iq],
-        ks_ref[ib, _LO, ik], ks_ref[ib, _HI, ik],
-        window,
-    )
     if len(mask_refs) == 1:
         pl.when(needed)(partial(update, True))
         return
@@ -342,35 +596,32 @@ def _when_needed(qs_ref, ks_ref, ib, iq, ik, update, mask_refs, window=None):
 
 
 def _fwd_kernel(
-    qs_ref,
-    ks_ref,
-    q_ref,
-    k_ref,
-    v_ref,
-    *rest,
-    scale: float,
-    nk: int,
-    window: Optional[int] = None,
+    *refs, scale: float, n_tables: int, step: Callable, window: Optional[int] = None
 ):
-    """One (batch, head, q-block, kv-block) grid step.
+    """One grid step of the forward: one (q block, KV block) pair of a
+    (batch, head), the pairs in the order of the call's walk (:class:`_Walk`:
+    ``step`` is its, and the first ``n_tables`` refs), a q block's KV blocks
+    one after another.
 
-    Refs: the schedule tables qs (b, 3, nq) and ks (b, 3, nk) in SMEM
-    (:func:`_block_schedule`); q (block_q, d); k/v (block_k, d); the mask
+    Refs: the walk's tables in SMEM (the schedule tables qs (b, 3, nq) and
+    ks (b, 3, nk) of :func:`_block_schedule`, or the one step table of
+    :func:`_fwd_steps`); q (block_q, d); k/v (block_k, d); the mask
     operands (:func:`_allowed`): positions qp (block_q, 1) and kp
     (1, block_k) int32 — explicit arrays, not iota, so permuted layouts
     (ring/zigzag shards) mask correctly — or the pair's (block_q, block_k)
     int8 block of a selection; o (block_q, d);
     lse (block_q, 1) — scalars-per-row ride as a column, rank-1 tiled
     outputs fail Mosaic lowering (see ops/quantization.py). Scratch acc
-    (block_q, d) f32, m/l (block_q, 1) f32 persist across the kv grid axis
-    (innermost, sequential on TPU).
+    (block_q, d) f32, m/l (block_q, 1) f32 persist across the q block's
+    steps (the grid's last axis, sequential on TPU).
     """
     from jax.experimental import pallas as pl
 
-    *mask_refs, o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
-    ib, iq, ik = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    tables, refs = refs[:n_tables], refs[n_tables:]
+    q_ref, k_ref, v_ref, *mask_refs, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
+    first, last, needed, under = step(*tables)
 
-    @pl.when(ik == 0)
+    @pl.when(first)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
@@ -403,9 +654,9 @@ def _fwd_kernel(
         acc_ref[...] = acc_ref[...] * correction + pv
         m_ref[...] = m_new
 
-    _when_needed(qs_ref, ks_ref, ib, iq, ik, _update, mask_refs, window)
+    _when_needed(needed, under, _update, mask_refs)
 
-    @pl.when(ik == nk - 1)
+    @pl.when(last)
     def _finalize():
         # Rows whose running max never left the sentinel saw only masked
         # scores: their p = exp(score - m) degenerated to 1 (the classic
@@ -436,14 +687,12 @@ def _flash_fwd(
     if pad_k:
         k = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
-    nq = (sq + pad_q) // block_q
-    nk = (sk + pad_k) // block_k
     qp, kp = _padded_positions(
         q_positions, k_positions, b, sq, sk, block_q, block_k
     )
-    q_sched, k_sched = _block_schedule(qp, kp, block_q, block_k, interpret, window)
+    own = q_positions is None and k_positions is None
+    walk = _fwd_walk(own, qp, kp, sq, sk, block_q, block_k, interpret, window)
     mask, mask_kinds = _mask_operands(selection, qp, kp, pad_q, pad_k)
-    windowed = window is not None
 
     # Kernels run on (b, heads, seq, d): Mosaic requires the last two BLOCK
     # dims be (mult-of-8, mult-of-128-or-whole-dim), so seq and head_dim must
@@ -455,19 +704,16 @@ def _flash_fwd(
     kt = k.transpose(0, 2, 1, 3)  # (b, kv_heads, sk_p, d)
     vt = v.transpose(0, 2, 1, 3)
 
-    # KV blocks innermost; the KV index stops at the q block's edge (under a
-    # window, at both of its edges).
-    spec = _block_specs(
-        block_q, block_k, d, group,
-        lambda ib, iq, ik, qs, ks: iq,
-        lambda ib, iq, ik, qs, ks: _kv_block(ib, iq, ik, qs, windowed),
-    )
-    inputs = (q_sched, k_sched, qt, kt, vt, *mask)
+    spec = _block_specs(block_q, block_k, d, group, walk.q_of, walk.k_of)
+    inputs = (*walk.tables, qt, kt, vt, *mask)
     out, lse = pl.pallas_call(
-        partial(_fwd_kernel, scale=scale, nk=nk, window=window),
+        partial(
+            _fwd_kernel, scale=scale, n_tables=len(walk.tables), step=walk.step,
+            window=window,
+        ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, h, nq, nk),
+            num_scalar_prefetch=len(walk.tables),
+            grid=(b, h, *walk.grid),
             in_specs=[spec[kind] for kind in ("q", "kv", "kv", *mask_kinds)],
             out_specs=[spec["q"], spec["col"]],
             scratch_shapes=[
@@ -480,7 +726,7 @@ def _flash_fwd(
             _out_struct((b, h, sq + pad_q, d), q.dtype, inputs),
             _out_struct((b, h, sq + pad_q, 1), jnp.float32, inputs),
         ],
-        name=WINDOW_FWD if windowed else None,
+        name=WINDOW_FWD if window is not None else None,
         interpret=interpret,
     )(*inputs)
     out = out.transpose(0, 2, 1, 3)  # back to (b, sq_p, h, d)
@@ -505,35 +751,38 @@ _MAX_VMEM_BYTES = 64 * 2**20
 
 
 def _bwd_kernel(
-    qs_ref, ks_ref,
-    q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *rest,
-    scale: float, nk: int, nqc: int, block_q: int, window: Optional[int] = None,
+    *refs,
+    scale: float, n_tables: int, step: Callable, block_q: int,
+    window: Optional[int] = None,
 ):
-    """One step of the backward: grid (b, h, q chunk, nk, nqc), the chunk's
-    q blocks innermost. A needed (q block, KV block) pair recomputes its
-    probabilities once from the saved logsumexp and adds to all three
+    """One grid step of the backward: one (q block, KV block) pair of a
+    (batch, head, q chunk), the pairs in the order of the call's walk
+    (:class:`_Walk`: ``step`` is its, and the first ``n_tables`` refs), a KV
+    block's q blocks of the chunk one after another. A needed pair recomputes
+    its probabilities once from the saved logsumexp and adds to all three
     gradients (FlashAttention-2 backward, five matmuls a pair). dk and dv
     accumulate in (block_k, d) scratch across the q blocks of one KV block
     and leave as PER-Q-HEAD, per-chunk partials: the GQA group sum happens
     outside, so every output block is written exactly once. dq accumulates
     across the KV blocks in a scratch that holds the whole chunk's rows, a
     step adding into its q block's; the dq output block is the chunk's too,
-    so it stays in VMEM for the chunk and goes out once, cast. ``rest``: the
-    mask operands (:func:`_allowed`), then the three outputs and the three
-    accumulators."""
+    so it stays in VMEM for the chunk and goes out once, cast. Refs after
+    the tables: q, k, v, dO, lse, delta, the mask operands
+    (:func:`_allowed`), then the three outputs and the three accumulators."""
     from jax.experimental import pallas as pl
 
+    tables, refs = refs[:n_tables], refs[n_tables:]
+    q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *rest = refs
     *mask_refs, dq_ref, dk_ref, dv_ref, dq_acc_ref, dk_acc_ref, dv_acc_ref = rest
-    ib, ic, ik, jq = (pl.program_id(axis) for axis in (0, 2, 3, 4))
-    iq = ic * nqc + jq
+    jq, kv_first, kv_last, q_first, q_last, needed, under = step(*tables)
     rows = pl.ds(pl.multiple_of(jq * block_q, block_q), block_q)
 
-    @pl.when(jq == 0)
+    @pl.when(kv_first)
     def _init_dkv():
         dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
 
-    @pl.when(ik == 0)
+    @pl.when(q_first)
     def _init_dq():
         dq_acc_ref[rows, :] = jnp.zeros((block_q, dq_acc_ref.shape[1]), jnp.float32)
 
@@ -571,14 +820,14 @@ def _bwd_kernel(
             preferred_element_type=jnp.float32,
         )  # (block_q, d)
 
-    _when_needed(qs_ref, ks_ref, ib, iq, ik, _update, mask_refs, window)
+    _when_needed(needed, under, _update, mask_refs)
 
-    @pl.when(jq == nqc - 1)
+    @pl.when(kv_last)
     def _finalize_dkv():
         dk_ref[...] = dk_acc_ref[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_acc_ref[...].astype(dv_ref.dtype)
 
-    @pl.when(ik == nk - 1)
+    @pl.when(q_last)
     def _finalize_dq():
         dq_ref[rows, :] = dq_acc_ref[rows, :].astype(dq_ref.dtype)
 
@@ -700,13 +949,12 @@ def flash_attention_partial_bwd(
     if pad_k:
         k = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
-    nk = (sk + pad_k) // block_k
     qp, kp = _padded_positions(
         q_positions, k_positions, b, sq, sk, q_rows, block_k
     )
-    q_sched, k_sched = _block_schedule(qp, kp, block_q, block_k, interpret, window)
+    own = q_positions is None and k_positions is None
+    walk = _bwd_walk(own, qp, kp, sq, sk, block_q, block_k, interpret, window, nqc)
     mask, mask_kinds = _mask_operands(selection, qp, kp, pad_q, pad_k)
-    windowed = window is not None
     # Same heads-major transposition as _flash_fwd (see comment there): the
     # kernel sees (b, h, seq, d) / (b, h, seq, 1) so seq and d are the block
     # minor dims Mosaic requires.
@@ -716,35 +964,26 @@ def flash_attention_partial_bwd(
     dot = d_out.transpose(0, 2, 1, 3)  # (b, h, sq_p, d)
     lse_col = lse.reshape(b, sq + pad_q, h, 1).transpose(0, 2, 1, 3)
     delta_col = delta.reshape(b, sq + pad_q, h, 1).transpose(0, 2, 1, 3)
-    inputs = (q_sched, k_sched, qt, kt, vt, dot, lse_col, delta_col, *mask)
+    inputs = (*walk.tables, qt, kt, vt, dot, lse_col, delta_col, *mask)
 
-    # The chunk's q blocks innermost, so the dk/dv accumulators persist
-    # across them; the q index starts at the KV block's edge (under a window
-    # it ends at the other one too) and stays in the chunk.
-    def q_of(ib, ic, ik, jq, qs, ks):
-        block = _q_block(ib, ik, ic * nqc + jq, ks, windowed)
-        if windowed:
-            return jnp.clip(block, ic * nqc, ic * nqc + nqc - 1)
-        return jnp.minimum(block, ic * nqc + nqc - 1)
-
-    spec = _block_specs(
-        block_q, block_k, d, group, q_of, lambda ib, ic, ik, jq, qs, ks: ik
-    )
-    # The outputs are per q head (dk, dv: and per chunk), so not "q" / "kv".
+    spec = _block_specs(block_q, block_k, d, group, walk.q_of, walk.k_of)
+    # The outputs are per q head (dk, dv: and per chunk), so not "q" / "kv";
+    # the chunk is the walk's first axis.
     dq_out = pl.BlockSpec(
-        (None, None, q_rows, d), lambda ib, ih, ic, ik, jq, qs, ks: (ib, ih, ic, 0)
+        (None, None, q_rows, d), lambda ib, ih, ic, *rest: (ib, ih, ic, 0)
     )
     dkv_out = pl.BlockSpec(
         (None, None, None, block_k, d),
-        lambda ib, ih, ic, ik, jq, qs, ks: (ib, ih, ic, ik, 0),
+        lambda ib, ih, ic, *rest: (ib, ih, ic, walk.k_of(ib, ic, *rest), 0),
     )
     dq, dk_h, dv_h = pl.pallas_call(
         partial(
-            _bwd_kernel, scale=scale, nk=nk, nqc=nqc, block_q=block_q, window=window
+            _bwd_kernel, scale=scale, n_tables=len(walk.tables), step=walk.step,
+            block_q=block_q, window=window,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, h, nc, nk, nqc),
+            num_scalar_prefetch=len(walk.tables),
+            grid=(b, h, *walk.grid),
             # q, k, v, dO, lse, delta and the mask by kind of spec
             # (_block_specs).
             in_specs=[
@@ -768,7 +1007,7 @@ def flash_attention_partial_bwd(
             if need > _SCOPED_VMEM_BYTES
             else None
         ),
-        name=WINDOW_BWD if windowed else None,
+        name=WINDOW_BWD if window is not None else None,
         interpret=interpret,
     )(*inputs)
     dq = dq.transpose(0, 2, 1, 3)  # (b, sq_p, h, d)
@@ -1013,7 +1252,9 @@ def _class_counts(
     takes off the walk are counted apart as ``behind``, those its edge alone
     crosses as ``edge`` (``diagonal`` keeps every masked pair the causal
     compare masks, a corner both lines cross included) and ``under`` reads
-    inside both."""
+    inside both. ``steps``: the grid steps a head takes in one forward call,
+    which says which walk the call takes: the needed pairs alone where it
+    has no position arrays, every pair where it has."""
     block_q, block_k = _block_sizes(block_q, block_k, sq, sk)
     qp, kp = _padded_positions(q_positions, k_positions, 1, sq, sk, block_q, block_k)
 
@@ -1026,6 +1267,9 @@ def _class_counts(
         name: int(jnp.sum(causal == c))
         for c, name in enumerate(("above", "diagonal", "under"))
     }
+    own = q_positions is None and k_positions is None
+    walk = _fwd_walk(own, qp[:1], kp[:1], sq, sk, block_q, block_k, False, window)
+    counts["steps"] = int(np.prod(walk.grid))
     if window is None:
         return counts
     mine = classes(window)
@@ -1045,11 +1289,14 @@ def verify_on_chip() -> dict:
         python -c "from torchft_tpu.ops.flash_attention import verify_on_chip; print(verify_on_chip())"
 
     One case runs both kernels under a selection
-    (``flash_attention(..., selection=...)``), the last four under a window
-    (``window=``). Returns the largest error of
+    (``flash_attention(..., selection=...)``), four under a window
+    (``window=``), and the last four (``listed``) are the grid of needed pairs
+    at the longest cell's size, each also compared to the bit with the walk
+    over every pair. Returns the largest error of
     each case; under ``classes``, how many
     block pairs of the case the schedule classed above, on and under the
-    diagonal: how often the scheduling engaged; and under ``bwd_q_chunks``
+    diagonal, and the grid ``steps`` a head's forward takes: how often the
+    scheduling engaged; and under ``bwd_q_chunks``
     the path each case's backward calls took: 1 is dq resident in VMEM for
     the whole sequence, more is that many chunks of q blocks a head.
     """
@@ -1317,6 +1564,97 @@ def verify_on_chip() -> dict:
         window_errors[case] = (
             check(f"WINDOW {case}", err_w, 0.05), check(f"WINDOW BACKWARD {case}", err_wb, 0.25),
         )
+    # The grid of needed pairs (what ``flash_attention`` walks: it brings no
+    # position arrays) at the size the cell
+    # ``smallthinker-21b-a3b-1chip.ftddp-seq16k`` runs it, 1 x 16,384 with 28
+    # query heads over 4 KV heads of 128, full and under its window of 4,096; a
+    # ragged length under a window that is a multiple of no block; and a
+    # selection of 512 keys a query of 4,096. Each against attention in
+    # float32 under the same mask, a query head at a time (the scores of 28
+    # heads at once are 30 GB), and against the same two kernels walking every
+    # pair (the call given the sequence's own positions as arrays): every
+    # accumulator adds the same terms in the same order, so nothing differs.
+    def listed(case, sq, window=None, topk=None, heads=28, kv_heads=4, width=128):
+        rows = jnp.arange(sq, dtype=jnp.int32)
+        keys = jax.random.split(jax.random.PRNGKey(41), 5)
+        q = jax.random.normal(keys[0], (1, sq, heads, width), jnp.bfloat16)
+        k = jax.random.normal(keys[1], (1, sq, kv_heads, width), jnp.bfloat16)
+        v = jax.random.normal(keys[2], (1, sq, kv_heads, width), jnp.bfloat16)
+        d_out = jax.random.normal(keys[3], q.shape, jnp.bfloat16)
+        apart = rows[:, None] - rows[None, :]
+        mask = (apart >= 0) & (apart < (window or sq))
+        selection = None
+        if topk is not None:
+            index = jax.random.normal(keys[4], (1, sq, sq))
+            mask = select_topk(index, mask[None], topk)[0]
+            selection = mask[None].astype(jnp.int8)
+        blocks = _block_sizes(512, 1024, sq, sq, topk is not None)
+
+        @jax.jit
+        def needed_pairs(q, k, v, d_out):
+            out, vjp = jax.vjp(
+                lambda q, k, v: flash_attention(
+                    q, k, v, interpret=False, selection=selection, window=window
+                ),
+                q, k, v,
+            )
+            return (out, *vjp(d_out))
+
+        @jax.jit
+        def every_pair(q, k, v, d_out):
+            at = rows[None]
+            out, lse = _flash_fwd(
+                q, k, v, width**-0.5, *blocks, False, at, at,
+                selection=selection, window=window,
+            )
+            return out, *flash_attention_partial_bwd(
+                q, k, v, d_out, out, lse.reshape(1, sq, heads), at, at,
+                width**-0.5, *blocks, False,
+                out_dtype=q.dtype, selection=selection, window=window,
+            )
+
+        @jax.jit
+        def reference(q, k, v, d_out):
+            def head(i):
+                def attend(q, k, v):
+                    scores = jnp.einsum("sd,td->st", q, k) * width**-0.5
+                    probs = jax.nn.softmax(jnp.where(mask, scores, _NEG_INF), axis=-1)
+                    return jnp.einsum("st,td->sd", probs, v)
+
+                mine = [
+                    x[0, :, j].astype(jnp.float32)
+                    for x, j in ((q, i), (k, i // (heads // kv_heads)), (v, i // (heads // kv_heads)))
+                ]
+                out, vjp = jax.vjp(attend, *mine)
+                return (out, *vjp(d_out[0, :, i].astype(jnp.float32)))
+
+            out, dq, dk, dv = jax.lax.map(head, jnp.arange(heads))  # (heads, sq, width)
+            group = lambda x: x.reshape(kv_heads, -1, sq, width).sum(axis=1)
+            return [x.transpose(1, 0, 2)[None] for x in (out, dq, group(dk), group(dv))]
+
+        got, dense_walk = needed_pairs(q, k, v, d_out), every_pair(q, k, v, d_out)
+        want = reference(q, k, v, d_out)
+        classes[case] = _class_counts(sq, sq, *blocks, window=window)
+        differing = sum(int(jnp.sum(a != b)) for a, b in zip(got, dense_walk))
+        if differing:
+            raise AssertionError(
+                f"on-chip flash {case}: {differing} elements differ between the "
+                "grid of needed pairs and the walk over every pair"
+            )
+        return (
+            check(f"LISTED {case}", worst(got[:1], want[:1]), 0.05),
+            check(f"LISTED BACKWARD {case}", worst(got[1:], want[1:]), 0.25),
+        )
+
+    listed_errors = {
+        case: listed(case, *args, **more)
+        for case, args, more in (
+            ("listed-16384", (16384,), {}),
+            ("listed-16384-window-4096", (16384, 4096), {}),
+            ("listed-ragged-5000-window-1300", (5000, 1300), {}),
+            ("listed-4096-selection-512", (4096,), dict(topk=512)),
+        )
+    }
     return {
         "device": str(dev),
         "max_err": err,
@@ -1332,6 +1670,8 @@ def verify_on_chip() -> dict:
         "max_err_selected_bwd": err_sb,
         "max_err_window": {case: errs[0] for case, errs in window_errors.items()},
         "max_err_window_bwd": {case: errs[1] for case, errs in window_errors.items()},
+        "max_err_listed": {case: errs[0] for case, errs in listed_errors.items()},
+        "max_err_listed_bwd": {case: errs[1] for case, errs in listed_errors.items()},
         "classes": classes,
         "bwd_q_chunks": chunks,
         "ok": True,
