@@ -18,15 +18,22 @@ bf16 peak: what the cells report as ``flash_mxu_pct``.
 
 Usage:
     python scripts/flash_block_sweep.py [--tree DIR] [--pairs 512x1024,...]
-        [--heads 28x4] [--window 4096] [seq ...]
+        [--heads 28x4] [--window 4096] [--whole ROWS] [seq ...]
 
 ``--heads`` (q heads x KV heads) and ``--window`` give another cell's calls:
 28x4 at 16384 with and without a window of 4096 is a layer of
 ``smallthinker-21b-a3b-1chip.ftddp-seq16k`` (``mxu_pct`` then counts the
 (query, key) pairs the window allows, as that cell's
 ``window_attention_flops`` does). A row says how many grid
-steps a head's forward takes (``steps``, where the tree's ``_class_counts``
-tells).
+steps a head's forward takes (``steps``), how many of them compute one half
+of their KV block alone (``halves``; each where the tree's ``_class_counts``
+tells), and each kernel's mean microseconds a step (``fwd_step_us``,
+``bwd_step_us``: its time over batch x heads x steps). ``--whole ROWS`` names
+a file of the rows this script printed for a tree whose steps all compute
+their whole block (the parent of the half steps): a row with ``halves`` then
+also gives what a half step costs, ``fwd_half_step_us`` / ``bwd_half_step_us``,
+that tree's mean step less what each half step saved
+(``(its ms - mine) / (batch x heads x halves)``).
 
 ``--tree`` imports ``torchft_tpu`` from another checkout (a parent commit
 unpacked beside this one), so two trees are read the same way in one chip
@@ -83,12 +90,34 @@ def kernel_ms(trace: Path) -> dict:
     return {k: v / ITERS / 1e6 for k, v in total.items()}
 
 
+def step_costs(row: dict, whole: dict | None) -> dict:
+    """Mean microseconds a grid step of each kernel of ``row``, and against
+    the row ``whole`` of a tree without half steps (same call), what a half
+    step costs (module docstring)."""
+    if "steps" not in row:
+        return {}
+    calls = row["batch"] * int(row["heads"].partition("x")[0])
+    costs = {}
+    for kernel in ("fwd", "bwd"):
+        ms = row.get(f"{kernel}_ms")
+        if ms is None:
+            continue
+        costs[f"{kernel}_step_us"] = round(1e3 * ms / (calls * row["steps"]), 4)
+        theirs = (whole or {}).get(f"{kernel}_ms")
+        if theirs is not None and row.get("halves"):
+            costs[f"{kernel}_half_step_us"] = round(
+                1e3 * (theirs / whole["steps"] - (theirs - ms) / row["halves"]) / calls, 4
+            )
+    return costs
+
+
 def main() -> None:
     parser = argparse.ArgumentParser()
     parser.add_argument("--tree", default=str(ROOT))
     parser.add_argument("--pairs", default="")
     parser.add_argument("--heads", default=f"{HEADS}x{KV_HEADS}")
     parser.add_argument("--window", type=int, default=None)
+    parser.add_argument("--whole", default=None)
     parser.add_argument("seqs", nargs="*", type=int)
     args = parser.parse_args()
     sys.path.insert(0, args.tree)
@@ -113,6 +142,13 @@ def main() -> None:
         "head_dim": HEAD_DIM, "num_attention_heads": heads, "num_hidden_layers": 1,
     }
     more = {} if args.window is None else {"window": args.window}
+    whole = {}
+    if args.whole:
+        for line in Path(args.whole).read_text().splitlines():
+            if line.startswith("{"):
+                row = json.loads(line)
+                key = (row["seq"], row["block_q"], row["block_k"], row["heads"], row.get("window"))
+                whole[key] = row
     for s in args.seqs or [2048, 8192]:
         b = max(1, TOKENS // s)
         kq, kk, kvk, kr = jax.random.split(jax.random.PRNGKey(0), 4)
@@ -134,8 +170,7 @@ def main() -> None:
                 "heads": args.heads, **more,
             }
             counts = _class_counts(s, s, bq, bk, **more)
-            if "steps" in counts:
-                row["steps"] = counts["steps"]
+            row.update({key: counts[key] for key in ("steps", "halves") if key in counts})
 
             def loss(q, k, v, _bq=bq, _bk=bk):
                 out = flash_attention(
@@ -168,6 +203,7 @@ def main() -> None:
                     kernels_ms=round(1e3 * seconds, 4),
                     mxu_pct=round(100 * need / seconds / peak, 2),
                 )
+                row.update(step_costs(row, whole.get((s, bq, bk, args.heads, args.window))))
             print(json.dumps(row), flush=True)
 
 

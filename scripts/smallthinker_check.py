@@ -13,7 +13,9 @@ at its own size and seed, and the control of its limits:
   its edge (``ops.flash_attention._class_counts``), for the two kinds of layer
   at the cell's sequence and the model's blocks, with the grid ``steps`` a
   head's forward call takes (the needed pairs alone, since PR 55: the model's
-  calls bring no position arrays), beside the closed form of the (query, key)
+  calls bring no position arrays) and how many of them compute one half of
+  their KV block alone (``halves``, since PR 56: 16 of a full layer's 272, 28
+  of a windowed layer's 140), beside the closed form of the (query, key)
   pairs each kind needs;
 - the fp8 control: the float32 reference with every weight in fp8 (e4m3, one
   scale a tensor) through ``harness.reference_check`` under the cell's limits.
@@ -41,9 +43,11 @@ OVERLAY = ROOT / "chipbench/fixtures/rehearsal-smallthinker.json"
 
 
 def pair_classes(system, architecture) -> dict:
-    """The schedule's block pairs by class and the grid steps of a head's
-    forward (``block_pairs["steps"]``) for a full and for a windowed layer of
-    the cell, and the (query, key) pairs each needs by the closed form."""
+    """The schedule's block pairs by class, the grid steps of a head's
+    forward (``block_pairs["steps"]``) and those of them that run one half of
+    their KV block (``block_pairs["halves"]``) for a full and for a windowed
+    layer of the cell, and the (query, key) pairs each needs by the closed
+    form."""
     from torchft_tpu.ops.flash_attention import _class_counts
 
     cfg, seq = system.model.config, system.seq

@@ -1042,12 +1042,14 @@ def test_the_cells_windowed_layers_walk_under_half_of_the_causal_block_pairs():
     counts = fa._class_counts(s, s, 512, 1024, window=window)
     assert counts == {
         "above": 240, "diagonal": 32, "under": 84, "behind": 132, "edge": 24, "steps": 140,
+        "halves": 28,
     }
     causal = fa._class_counts(s, s, 512, 1024)
-    assert causal == {"above": 240, "diagonal": 32, "under": 240, "steps": 272}
+    assert causal == {"above": 240, "diagonal": 32, "under": 240, "steps": 272, "halves": 16}
     # 140 block pairs walked of the causal call's 272: 51.5% of its blocks
-    # for 43.7% of its pairs (the edge's blocks are computed whole). They are
-    # the grid steps a head takes, too.
+    # for 43.7% of its pairs (of the diagonal's and the edge's blocks, 28 are
+    # computed by the half that needs it, the other 28 whole). They are the
+    # grid steps a head takes, too.
     assert counts["diagonal"] + counts["under"] + counts["edge"] == 140
 
 
@@ -1155,7 +1157,10 @@ def test_the_step_tables_list_the_needed_pairs_in_the_order_of_the_dense_walk(na
     nc, nk = backward.shape[1], classes.shape[1]
     qp, kp = fa._padded_positions(None, None, 1, s, s, nqc * block_q, block_k)
     tables = fa._block_schedule(qp, kp, block_q, block_k, True, window)
-    assert np.array_equal(classes, np.asarray(fa._block_classes(*tables, window))[0])
+    # A pair computed by one half is one the schedule masks whole.
+    assert np.array_equal(
+        np.where(classes > 2, 1, classes), np.asarray(fa._block_classes(*tables, window))[0]
+    )
 
     # Forward: the q blocks the unchunked call has (no block wholly padding).
     own = classes[: -(-s // block_q)]
@@ -1225,10 +1230,80 @@ def test_the_count_of_steps_says_which_walk_a_call_takes(s, window, steps):
     assert steps == counts["diagonal"] + counts["under"] + counts.get("edge", 0)
     at = jnp.arange(s, dtype=jnp.int32)[None]
     dense = fa._class_counts(s, s, 512, 1024, at, at, window=window)
-    assert dense["steps"] == (s // 512) * (s // 1024)
-    assert {k: v for k, v in dense.items() if k != "steps"} == {
-        k: v for k, v in counts.items() if k != "steps"
+    assert dense["steps"] == (s // 512) * (s // 1024) and dense["halves"] == 0
+    assert {k: v for k, v in dense.items() if k not in ("steps", "halves")} == {
+        k: v for k, v in counts.items() if k not in ("steps", "halves")
     }
+
+
+# name: (sq, sk, block_q, block_k, window), (steps of a head's forward, those
+# of them that compute one half of their KV block alone)
+_HALF_CLASSES = {
+    # The cells' geometries, 512 x 1024: an even q block's diagonal pair needs
+    # its left half alone; under the window of 4,096 an odd q block's first
+    # pair (q blocks 9, 11, ... 31) needs its right half alone.
+    "cells-16384": ((16384, 16384, 512, 1024, None), (272, 16)),
+    "cells-16384-window-4096": ((16384, 16384, 512, 1024, 4096), (140, 28)),
+    "cells-8192": ((8192, 8192, 512, 1024, None), (72, 8)),
+    "cells-2048": ((2048, 2048, 512, 1024, None), (6, 2)),
+    # Ragged, the last KV block 904 real keys of 1,024, under a window that is
+    # a multiple of no block; a window that is no multiple of a half block.
+    "ragged-5000-window-1300": ((5000, 5000, 512, 1024, 1300), (24, 8)),
+    "window-1000-of-8192": ((8192, 8192, 512, 1024, 1000), (30, 15)),
+    # 1,536 keys = 1,024 + 512: the last KV block's right half is all padding,
+    # and its left half lies wholly under the queries from 1,536 on: a bare
+    # half, which takes more queries than keys.
+    "more-queries-than-keys": ((2560, 1536, 256, 1024, None), (16, 8)),
+    # A half of 64 or 192 keys is no whole lane tiles: every step computes
+    # its block.
+    "block-k-128": ((1000, 1000, 64, 128, 300), (52, 0)),
+    "block-k-384": ((1536, 1536, 128, 384, None), (30, 0)),
+}
+
+
+@pytest.mark.parametrize("name", list(_HALF_CLASSES))
+def test_a_pair_with_one_half_needed_is_classed_by_that_half(name):
+    """``_own_classes`` against the mask itself, pair by pair: a class of 3 to
+    6 names the one half that holds allowed (query, key) pairs, masked (3, 5)
+    where it holds disallowed ones too and bare (4, 6) where it does not, and
+    the other half holds none; a pair of class 1 or 2 holds allowed pairs in
+    both halves; ``_class_counts`` counts the former as ``halves``, and none
+    where a half block is no multiple of the 128 lanes."""
+    from torchft_tpu.ops import flash_attention as fa
+
+    (sq, sk, block_q, block_k, window), (steps, halves) = _HALF_CLASSES[name]
+    classes = fa._own_classes(sq, sk, block_q, block_q, block_k, window)
+    counts = fa._class_counts(sq, sk, block_q, block_k, window=window)
+    assert (counts["steps"], counts["halves"]) == (steps, halves)
+    assert int(np.sum(classes > 0)) == steps and int(np.sum(classes > 2)) == halves
+    if block_k % 256:
+        assert classes.max() <= 2
+        return
+    qp, kp = fa._padded_positions(None, None, 1, sq, sk, block_q, block_k)
+    at_q = np.asarray(qp[0]).reshape(-1, block_q)
+    at_k = np.asarray(kp[0]).reshape(-1, 2, block_k // 2)
+    seen = set()
+    for iq, ik in zip(*np.nonzero(classes)):
+        apart = at_q[iq][:, None, None].astype(np.int64) - at_k[ik][None]
+        allowed = (apart >= 0) & (apart < (window or sq)) & (at_q[iq][:, None, None] >= 0)
+        left, right = (int(n) for n in allowed.sum(axis=(0, 2)))
+        kind, full = int(classes[iq, ik]), block_q * block_k // 2
+        seen.add(kind)
+        if kind <= 2:
+            # (A q block with padded rows reads -1 as its lowest position, so
+            # the schedule cannot tell what lies behind a window for it.)
+            padded = at_q[iq].min() < 0
+            assert padded or (left and right), (iq, ik)
+            assert (kind == 2) == (left + right == 2 * full), (iq, ik)
+            continue
+        masked, span = fa._body_of(kind)
+        mine, other = (left, right) if span == fa._LEFT else (right, left)
+        assert other == 0 and mine and masked == (mine < full), (iq, ik, kind)
+    if name == "cells-16384-window-4096":
+        assert seen == {1, 2, 3, 5}
+        assert [iq for iq, ik in zip(*np.nonzero(classes == 5))] == list(range(9, 32, 2))
+    if name == "more-queries-than-keys":
+        assert seen == {1, 2, 3, 4}
 
 
 def test_a_step_table_over_the_smem_budget_falls_back_to_the_dense_walk(monkeypatch):
@@ -1249,6 +1324,48 @@ def test_a_step_table_over_the_smem_budget_falls_back_to_the_dense_walk(monkeypa
     dense = call()
     assert "grid=(1, 2, 6)" in listed and "grid=(1, 2, 1, 6)" in listed
     assert "grid=(1, 2, 4, 2)" in dense and "grid=(1, 2, 1, 2, 4)" in dense
+
+
+def test_a_bare_half_runs_without_the_mask():
+    """More queries than keys, the keys ending with the left half of their
+    last KV block: for the queries from there on that half lies wholly under
+    the diagonal and runs bare (class 4), the right half, all padding, not at
+    all. Forward and backward against the walk over every pair, bit for bit,
+    and against dense attention."""
+    from torchft_tpu.ops import flash_attention as fa
+
+    b, sq, sk, h, kv, d, block_q, block_k = 2, 512, 384, 4, 2, 16, 64, 256
+    classes = fa._own_classes(sq, sk, block_q, block_q, block_k)
+    assert set(classes[:, 1]) == {0, 3, 4} and np.sum(classes == 4) == 2
+    q, _, _ = _qkv(b, sq, h, kv, d, seed=51)
+    _, k, v = _qkv(b, sk, h, kv, d, seed=52)
+    d_out = jax.random.normal(jax.random.PRNGKey(53), q.shape, jnp.float32)
+    at_q = jnp.broadcast_to(jnp.arange(sq, dtype=jnp.int32), (b, sq))
+    at_k = jnp.broadcast_to(jnp.arange(sk, dtype=jnp.int32), (b, sk))
+
+    def run(qp, kp):
+        out, lse = fa._flash_fwd(q, k, v, d**-0.5, block_q, block_k, True, qp, kp)
+        grads = fa.flash_attention_partial_bwd(
+            q, k, v, d_out, out, lse.reshape(b, sq, h), qp, kp,
+            d**-0.5, block_q, block_k, True,
+        )
+        return (out, lse, *grads)
+
+    listed, dense = run(None, None), run(at_q, at_k)
+    for got, want, what in zip(listed, dense, ("out", "lse", "dq", "dk", "dv")):
+        assert np.array_equal(np.asarray(got), np.asarray(want)), what
+
+    def reference(q, k, v):
+        qg = q.reshape(b, sq, kv, h // kv, d)
+        scores = jnp.einsum("bskgd,btkd->bskgt", qg, k) * d**-0.5
+        mask = (at_q[:, :, None] >= at_k[:, None, :])[:, :, None, None, :]
+        p = jax.nn.softmax(jnp.where(mask, scores, -1e30), axis=-1)
+        return jnp.einsum("bskgt,btkd->bskgd", p, v).reshape(b, sq, h, d)
+
+    ref, vjp = jax.vjp(reference, q, k, v)
+    np.testing.assert_allclose(np.asarray(listed[0]), np.asarray(ref), atol=2e-5)
+    for got, want, what in zip(listed[2:], vjp(d_out), ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=5e-5, err_msg=what)
 
 
 def _random_selection(b, s, topk, seed):
@@ -1275,6 +1392,17 @@ _LISTED_CASES = {
     "chunked-ragged-600": ((600, 128, 256), (4, 2), None, None, 2),
     "chunked-window-200-ragged-600": ((600, 128, 256), (4, 2), 200, None, 2),
     "chunked-selection": ((384, 32, 128), (4, 2), None, 24, 4),
+    # KV blocks of two halves of 128 lanes: the pairs the diagonal, the
+    # window's edge or the sequence's end cuts between the halves run the
+    # needed half alone in the listed walk, and whole in the dense one.
+    "halves-causal-gqa4": ((1024, 128, 256), (4, 1), None, None, None),
+    "halves-causal-ragged-900": ((900, 128, 256), (4, 2), None, None, None),
+    "halves-window-384": ((1024, 128, 256), (4, 2), 384, None, None),
+    "halves-window-300-ragged-900": ((900, 128, 256), (4, 2), 300, None, None),
+    "halves-window-50-shorter-than-a-half": ((768, 128, 256), (2, 2), 50, None, None),
+    "halves-selection-gqa4": ((512, 64, 256), (4, 1), None, 24, None),
+    "halves-selection-ragged-600": ((600, 64, 256), (4, 2), None, 24, None),
+    "halves-chunked-selection": ((768, 64, 256), (4, 2), None, 24, 4),
 }
 
 
@@ -1283,12 +1411,16 @@ def test_the_listed_walk_equals_the_dense_walk_bit_for_bit(name):
     """out, lse, dq, dk, dv of a call without position arrays (the grid of
     needed pairs) equal, bit for bit, the same call given the sequence's own
     positions as arrays (the grid of every pair): the same pairs in the same
-    order into every accumulator. Causal, under a window, under a selection,
-    GQA, ragged lengths, and the backward in chunks of q blocks (its VMEM set
-    small); and both match dense attention under the same mask."""
+    order into every accumulator, and where the listed walk computes one half
+    of a KV block alone, the other half's terms are exact zeros. Causal, under
+    a window, under a selection, GQA, ragged lengths, and the backward in
+    chunks of q blocks (its VMEM set small); and both match dense attention
+    under the same mask."""
     from torchft_tpu.ops import flash_attention as fa
 
     (s, block_q, block_k), (h, kv), window, topk, resident = _LISTED_CASES[name]
+    halves = fa._class_counts(s, s, block_q, block_k, window=window)["halves"]
+    assert (halves > 0) == (block_k == 256)
     b, d = 2, 16
     q, k, v = _qkv(b, s, h, kv, d, seed=41)
     d_out = jax.random.normal(jax.random.PRNGKey(42), q.shape, jnp.float32)

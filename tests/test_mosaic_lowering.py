@@ -618,3 +618,101 @@ def test_windowed_flash_kernels_lower_for_tpu_at_the_smallthinker_cells_geometry
     assert _kernel_refs(backward)[:2] == [((1, 4, 32), _I32), ((1, 4, 16), _I32)]
     assert [call.params["name"] for call in (forward, backward)] == [WINDOW_FWD, WINDOW_BWD]
     traced.lower(lowering_platforms=("tpu",))
+
+
+def _update_bodies(call) -> list:
+    """The scores' shape of every update body a kernel traces, sorted: a
+    ``pl.when`` is a ``cond`` of the kernel's jaxpr, and the bodies that run a
+    pair's matmuls are the ones that hold a ``dot_general``, the first of
+    them the scores'."""
+    def first_dot(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general":
+                return eqn.outvars[0].aval.shape
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                if (shape := first_dot(sub)) is not None:
+                    return shape
+        return None
+
+    bodies = []
+    for eqn in call.params["jaxpr"].eqns:
+        if eqn.primitive.name == "cond":
+            shapes = [first_dot(branch.jaxpr) for branch in eqn.params["branches"]]
+            bodies += [shape for shape in shapes if shape is not None]
+    return sorted(bodies)
+
+
+_WHOLE_STEP, _HALF_STEP = (512, 1024), (512, 512)
+
+
+@pytest.mark.parametrize(
+    "b, s, h, kv, window, selected, positions, halves, bodies",
+    [
+        # The cells' calls: masked and bare on the whole block, and the left
+        # half alone where the diagonal enters a block at an even q block;
+        # under the window the right half alone too, where its edge enters at
+        # an odd one; under a selection every step is masked, so one body a span.
+        pytest.param(
+            1, 16384, 28, 4, None, False, False, 16, [_HALF_STEP] + [_WHOLE_STEP] * 2,
+            id="cells-1x16384",
+        ),
+        pytest.param(
+            1, 16384, 28, 4, 4096, False, False, 28, [_HALF_STEP] * 2 + [_WHOLE_STEP] * 2,
+            id="cells-1x16384-window-4096",
+        ),
+        pytest.param(
+            1, 8192, 32, 8, None, False, False, 8, [_HALF_STEP] + [_WHOLE_STEP] * 2,
+            id="cells-1x8192",
+        ),
+        pytest.param(
+            1, 8192, 32, 4, None, True, False, 8, [_HALF_STEP, _WHOLE_STEP],
+            id="cells-1x8192-selection",
+        ),
+        pytest.param(
+            4, 2048, 32, 8, None, False, False, 2, [_HALF_STEP] + [_WHOLE_STEP] * 2,
+            id="cells-4x2048",
+        ),
+        # A ring hop's calls (position arrays): the walk over every pair and
+        # its two bodies on the whole block, as before the halves existed.
+        pytest.param(
+            1, 8192, 32, 8, None, False, True, 0, [_WHOLE_STEP] * 2, id="positions-1x8192"
+        ),
+        pytest.param(
+            1, 16384, 28, 4, 4096, False, True, 0, [_WHOLE_STEP] * 2,
+            id="positions-1x16384-window-4096",
+        ),
+    ],
+)
+def test_a_call_traces_the_half_steps_its_table_holds_and_lowers(
+    b, s, h, kv, window, selected, positions, halves, bodies
+):
+    """At the cells' real shapes, default blocks: a call without position
+    arrays traces one update body for each class its step table holds (the
+    scores of a half step are 512 x 512), the forward and the one backward
+    alike, and lowers for a TPU (the static slices of k, v, dk, dv rows and of
+    the key positions' or the selection's lanes are whole tiles); a call with
+    them traces the two bodies it always did."""
+    from torchft_tpu.ops import flash_attention as fa
+
+    d = 128
+    q = _sds((b, s, h, d), jnp.bfloat16)
+    k = _sds((b, s, kv, d), jnp.bfloat16)
+    at = [_sds((b, s), jnp.int32)] * 2 if positions else []
+    selection = _sds((b, s, s), jnp.int8) if selected else None
+
+    def both(q, k, v, do, selection, *at):
+        qp, kp = at or (None, None)
+        out, lse = fa._flash_fwd(
+            q, k, v, d**-0.5, 512, 1024, False, qp, kp, selection=selection, window=window
+        )
+        return fa.flash_attention_partial_bwd(
+            q, k, v, do, out, lse.reshape(b, s, h), qp, kp, d**-0.5, 512, 1024, False,
+            out_dtype=jnp.bfloat16, selection=selection, window=window,
+        )
+
+    own = [jnp.arange(s, dtype=jnp.int32)[None]] * 2 if positions else [None, None]
+    assert fa._class_counts(s, s, 512, 1024, *own, window=window)["halves"] == halves
+    traced = jax.jit(both).trace(q, k, k, q, selection, *at)
+    forward, backward = _pallas_calls(traced.jaxpr.jaxpr)
+    assert _update_bodies(forward) == _update_bodies(backward) == bodies
+    traced.lower(lowering_platforms=("tpu",))

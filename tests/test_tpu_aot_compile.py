@@ -247,6 +247,51 @@ def test_flash_backward_compiles_at_the_cells_geometry(
 
 
 @pytest.mark.parametrize(
+    "batch, seq, heads, kv_heads, window, selected, halves",
+    [
+        pytest.param(1, 16384, 28, 4, None, False, 16, id="cells-1x16384"),
+        pytest.param(1, 16384, 28, 4, 4096, False, 28, id="cells-1x16384-window-4096"),
+        pytest.param(1, 8192, 32, 8, None, False, 8, id="cells-1x8192"),
+        pytest.param(1, 8192, 32, 4, None, True, 8, id="cells-1x8192-selection"),
+        pytest.param(4, 2048, 32, 8, None, False, 2, id="cells-4x2048"),
+    ],
+)
+def test_the_half_steps_compile_at_the_cells_geometries(
+    chip, batch, seq, heads, kv_heads, window, selected, halves
+) -> None:
+    """The forward and the one backward call of every cell's attention, whose
+    step tables hold pairs computed by one half of their KV block: the static
+    half slices of k, v, the mask's lanes and the dk / dv accumulators
+    compile for a described v5e, each call uses no more VMEM than it may (the
+    16 MiB it gets unasked, or the limit the backward states), and the
+    backward's estimate from its shapes is still over the compiler's own
+    account."""
+    from torchft_tpu.ops import flash_attention as fa
+
+    assert fa._class_counts(seq, seq, 512, 1024, window=window)["halves"] == halves
+    need = fa._bwd_vmem_bytes(seq, 512, 1024, CELL_D, 2, 2, selected)
+    selection = _sds((batch, seq, seq), jnp.int8, chip) if selected else None
+
+    def loss(q, k, v, selection):
+        out = fa.flash_attention(q, k, v, interpret=False, selection=selection, window=window)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1, 2)),
+        _sds((batch, seq, heads, CELL_D), jnp.bfloat16, chip),
+        _sds((batch, seq, kv_heads, CELL_D), jnp.bfloat16, chip),
+        _sds((batch, seq, kv_heads, CELL_D), jnp.bfloat16, chip),
+        selection,
+    )
+    forward, backward = sorted(_mosaic_calls(compiled), key=lambda call: call[2][0])
+    # (Beside a call that states a limit, the text gives one that states none
+    # the 16 MiB it gets unasked.)
+    assert forward[1] in ([], [fa._SCOPED_VMEM_BYTES]) and forward[2][0] <= fa._SCOPED_VMEM_BYTES
+    assert backward[1] == ([need] if need > fa._SCOPED_VMEM_BYTES else [])
+    assert backward[2][0] <= need
+
+
+@pytest.mark.parametrize(
     "seq, steps",
     [
         pytest.param(131072, 16512, id="listed-131072"),

@@ -49,7 +49,26 @@ follows from whether it was given them (:class:`_Walk`):
   matmuls. The table is 16 bytes a step in SMEM (4.4 KB at 16,384 rows in
   512 x 1024 blocks, 264 KB at 131,072); a call whose table would pass
   ``_MAX_TABLE_BYTES`` (over 131,072 rows at those blocks) takes the walk
-  below instead, by its shape alone.
+  below instead, by its shape alone. In this walk a step's class also says
+  WHICH HALF of its KV block is needed. The table classes each pair's two
+  halves of ``block_k // 2`` keys with the same :func:`_pair_class`
+  (:func:`_own_classes`): where the diagonal enters a 512 x 1024 block at an
+  even q block its right half lies wholly above it, where a window's edge
+  enters at an odd one its left half lies wholly behind, where the sequence
+  ends in a left half the right half is padding. Such a pair takes the class
+  of the half that is left (3 or 4 the left half masked or bare, 5 or 6 the
+  right), and the bodies run that step on that half alone: static slices of
+  ``block_k // 2`` rows of k, v and the dk / dv accumulators and as many lanes
+  of the key positions or the selection's block, so half the matmuls, half
+  the exponentials, no second softmax update. A pair that needs both halves
+  keeps class 1 or 2 and stays one whole step; the table's size, order and
+  flags are what they were. A kernel traces a body only for the classes its
+  table holds (three or four of the six). The halves engage where a half
+  block is whole lane tiles (``block_k`` a multiple of 256): 16 of a head's
+  272 steps at 1 x 16,384, 28 of 140 under a window of 4,096, 8 of 72 at
+  1 x 8192, 2 of 6 at 2048, in the default blocks;
+  ``_class_counts(...)["halves"]`` counts them. What the half left out would
+  have added is exact zeros, so the results are the walk below's.
 - ``flash_attention_partial`` / ``flash_attention_partial_bwd`` with position
   ARRAYS (a ring's zigzag hops, ``sq != sk``): the needed pairs are data, so
   the grid is DENSE, every pair a step: XLA reduces the arrays to each block's
@@ -57,7 +76,9 @@ follows from whether it was given them (:class:`_Walk`):
   small tables ride in as scalar prefetch, a grid step reads its pair's class
   from SMEM before it begins, and the index maps of a pair that needs nothing
   name the block the neighbouring needed step holds, so the pipeline copies
-  nothing for it.
+  nothing for it. Every needed pair is computed whole: a half's class would
+  be four more compares of scalars a step and a table row more, for hops
+  whose blocks the diagonal seldom crosses.
 Both walks run the same kernel bodies on the same pairs in the same order, so
 their results are the same bits (tests/test_flash_attention.py and
 :func:`verify_on_chip` hold that); ``_class_counts(...)["steps"]`` says which
@@ -292,12 +313,35 @@ def _q_block(ib, ik, iq, k_sched, windowed=False):
 _IQ, _IK, _CLASS, _FLAGS = 0, 1, 2, 3
 _ROW_FIRST, _ROW_LAST, _Q_FIRST, _Q_LAST = 1, 2, 4, 8
 
+# How much of its KV block a step computes. A class of the _CLASS row is
+# 2 * span + (1 masked, 2 bare), 0 still a step that needs nothing: 1 and 2
+# the whole block, 3 and 4 its left half alone, 5 and 6 its right half alone.
+_WHOLE, _LEFT, _RIGHT = 0, 1, 2
+
+
+def _body_of(kind):
+    """(masked, span) of a class over 0."""
+    return kind % 2 == 1, (kind - 1) // 2
+
+
+def _columns(span, block_k):
+    """The static slice of a KV block's ``block_k`` rows (of k, v, dk, dv) or
+    lanes (of the key positions, of a selection's block) that ``span`` names."""
+    half = block_k // 2
+    return (slice(None), slice(0, half), slice(half, block_k))[span]
+
 
 def _own_classes(sq, sk, q_rows, block_q, block_k, window=None):
-    """(nq, nk) numpy classes of the pairs (:func:`_block_classes`) of a call
-    whose positions are the sequence's own, made while tracing: what
-    :func:`_padded_positions` and :func:`_block_schedule` give for no
-    position arrays, the q rows padded to a multiple of ``q_rows``."""
+    """(nq, nk) numpy classes of the pairs of a call whose positions are the
+    sequence's own, made while tracing: what :func:`_padded_positions` and
+    :func:`_block_schedule` give for no position arrays (0, 1 and 2 of
+    :func:`_block_classes`), the q rows padded to a multiple of ``q_rows``.
+    Where a KV block is two halves of whole lane tiles, each half is classed by
+    the same :func:`_pair_class`, and a pair of which ONE half is needed (the
+    diagonal, the window's edge or the sequence's end runs between the two)
+    takes that half's class, 3 to 6 (``_LEFT``, ``_RIGHT``): the kernels then
+    compute that half alone. A pair that needs both halves keeps its class and
+    stays one step: two would update the softmax twice and save nothing."""
 
     def blocks(n, multiple, block, fill):
         at = np.full(_next_multiple(n, multiple), fill, np.int32)
@@ -306,11 +350,21 @@ def _own_classes(sq, sk, q_rows, block_q, block_k, window=None):
         return at.min(axis=1), at.max(axis=1)
 
     q_lo, q_hi = blocks(sq, q_rows, block_q, -1)
-    k_lo, k_hi = blocks(sk, block_k, block_k, _PAD_POS)
-    needed, under = _pair_class(
-        q_lo[:, None], q_hi[:, None], k_lo[None, :], k_hi[None, :], window
-    )
-    return needed.astype(np.int32) + under
+
+    def classes(columns):
+        k_lo, k_hi = blocks(sk, block_k, columns, _PAD_POS)
+        needed, under = _pair_class(
+            q_lo[:, None], q_hi[:, None], k_lo[None, :], k_hi[None, :], window
+        )
+        return needed.astype(np.int32) + under
+
+    whole = classes(block_k)
+    if block_k % 256:
+        return whole
+    halves = classes(block_k // 2)
+    left, right = halves[:, 0::2], halves[:, 1::2]
+    whole = np.where((left > 0) & (right == 0), 2 * _LEFT + left, whole)
+    return np.where((right > 0) & (left == 0), 2 * _RIGHT + right, whole)
 
 
 def _row_steps(listed):
@@ -382,7 +436,8 @@ class _Walk(NamedTuple):
     scalar prefetch. ``grid``: the grid's axes after (b, h). ``q_of`` and
     ``k_of``: the q block and the KV block a step names, from (ib, the step's
     further axes, the tables). ``step``: called in the kernel with the
-    tables' refs, what the body needs to know of its step."""
+    tables' refs, what the body needs to know of its step, the last of it the
+    step's ``cases`` (:func:`_when_needed`)."""
 
     tables: tuple
     grid: tuple
@@ -391,14 +446,29 @@ class _Walk(NamedTuple):
     step: Callable
 
 
-def _scheduled_class(qs_ref, ks_ref, ib, iq, ik, window):
-    """(needed, under) of pair (iq, ik) in a kernel of the dense walk, from
-    the schedule tables in SMEM."""
-    return _pair_class(
+def _scheduled_cases(qs_ref, ks_ref, ib, iq, ik, window):
+    """The cases of pair (iq, ik) in a kernel of the dense walk, from the
+    schedule tables in SMEM: the whole block, bare under the diagonal (inside
+    a window), masked where it is needed and not under."""
+    needed, under = _pair_class(
         qs_ref[ib, _LO, iq], qs_ref[ib, _HI, iq],
         ks_ref[ib, _LO, ik], ks_ref[ib, _HI, ik],
         window,
     )
+    return [
+        (under, False, _WHOLE),
+        (jnp.logical_and(needed, jnp.logical_not(under)), True, _WHOLE),
+    ]
+
+
+def _listed_cases(kind, steps):
+    """The cases of a step of class ``kind`` in a kernel of a listed walk: one
+    for each class over 0 that the table ``steps`` holds, so a call traces the
+    bodies its steps run and no other."""
+    return [
+        (kind == held, *_body_of(held))
+        for held in np.unique(steps[_CLASS]).tolist() if held
+    ]
 
 
 def _dense_fwd_walk(qp, kp, block_q, block_k, interpret, window):
@@ -414,7 +484,7 @@ def _dense_fwd_walk(qp, kp, block_q, block_k, interpret, window):
 
     def step(qs_ref, ks_ref):
         ib, iq, ik = pl.program_id(0), pl.program_id(2), pl.program_id(3)
-        return ik == 0, ik == nk - 1, *_scheduled_class(qs_ref, ks_ref, ib, iq, ik, window)
+        return ik == 0, ik == nk - 1, _scheduled_cases(qs_ref, ks_ref, ib, iq, ik, window)
 
     return _Walk(
         (q_sched, k_sched), (nq, nk),
@@ -433,8 +503,8 @@ def _listed_fwd_walk(steps):
 
     def step(steps_ref):
         at = pl.program_id(2)
-        flags, kind = steps_ref[_FLAGS, at], steps_ref[_CLASS, at]
-        return flags & _ROW_FIRST != 0, flags & _ROW_LAST != 0, kind > 0, kind == 2
+        flags, cases = steps_ref[_FLAGS, at], _listed_cases(steps_ref[_CLASS, at], steps)
+        return flags & _ROW_FIRST != 0, flags & _ROW_LAST != 0, cases
 
     return _Walk(
         (jnp.asarray(steps),), (steps.shape[1],),
@@ -462,8 +532,8 @@ def _dense_bwd_walk(qp, kp, block_q, block_k, interpret, window, nqc):
 
     def step(qs_ref, ks_ref):
         ib, ic, ik, jq = (pl.program_id(axis) for axis in (0, 2, 3, 4))
-        pair = _scheduled_class(qs_ref, ks_ref, ib, ic * nqc + jq, ik, window)
-        return jq, jq == 0, jq == nqc - 1, ik == 0, ik == nk - 1, *pair
+        cases = _scheduled_cases(qs_ref, ks_ref, ib, ic * nqc + jq, ik, window)
+        return jq, jq == 0, jq == nqc - 1, ik == 0, ik == nk - 1, cases
 
     return _Walk(
         (q_sched, k_sched), (nq // nqc, nk, nqc),
@@ -479,12 +549,12 @@ def _listed_bwd_walk(steps, n, nqc):
 
     def step(steps_ref):
         ic, at = pl.program_id(2), pl.program_id(2) * n + pl.program_id(3)
-        flags, kind = steps_ref[_FLAGS, at], steps_ref[_CLASS, at]
+        flags = steps_ref[_FLAGS, at]
         return (
             steps_ref[_IQ, at] - ic * nqc,
             flags & _ROW_FIRST != 0, flags & _ROW_LAST != 0,
             flags & _Q_FIRST != 0, flags & _Q_LAST != 0,
-            kind > 0, kind == 2,
+            _listed_cases(steps_ref[_CLASS, at], steps),
         )
 
     return _Walk(
@@ -564,35 +634,40 @@ def _mask_operands(selection, qp, kp, pad_q, pad_k):
     return (qp.reshape(b, -1, 1), kp.reshape(b, 1, -1)), ("qp", "kp")
 
 
-def _allowed(mask_refs, window=None):
-    """(block_q, block_k) bool of a pair from the call's mask operands
-    (:func:`_mask_operands`): the one block of the selection, which already
-    implies that the key is no later than the query, or the two positions'
-    compare, with a ``window`` both of its compares. Which it is, is the
-    call's operands and so known at trace time."""
+def _allowed(mask_refs, columns, window=None):
+    """(block_q, keys) bool of the keys ``columns`` of a pair
+    (:func:`_columns`) from the call's mask operands (:func:`_mask_operands`):
+    the one block of the selection, which already implies that the key is no
+    later than the query, or the two positions' compare, with a ``window``
+    both of its compares. Which it is, is the call's operands and so known at
+    trace time."""
     if len(mask_refs) == 1:
-        return mask_refs[0][...] != 0
+        return mask_refs[0][:, columns] != 0
     qp_ref, kp_ref = mask_refs
     if window is None:
-        return qp_ref[...] >= kp_ref[...]
+        return qp_ref[...] >= kp_ref[:, columns]
     # A padded row (-1) against a padded column (_PAD_POS) is -2**31: no
     # difference of two positions leaves int32.
-    apart = qp_ref[...] - kp_ref[...]
+    apart = qp_ref[...] - kp_ref[:, columns]
     return jnp.logical_and(apart >= 0, apart < window)
 
 
-def _when_needed(needed, under, update, mask_refs):
-    """Runs ``update(masked)`` for the pair's class: not at all above the
+def _when_needed(cases, update, mask_refs):
+    """Runs ``update(masked, span)`` for the step's class, one of ``cases``,
+    each (whether this step is of it, masked, span): not at all above the
     diagonal (or behind a window), without the mask under it (inside), with
-    it on it (and on the window's edge); where the mask is a
-    selection (:func:`_allowed`) every needed pair takes it."""
+    it on it (and on the window's edge), on the whole KV block or on the one
+    half that needs it (``span``: ``_WHOLE``, ``_LEFT``, ``_RIGHT``); where
+    the mask is a selection (:func:`_allowed`) every needed pair takes it, so
+    a span's two cases are one."""
     from jax.experimental import pallas as pl
 
-    if len(mask_refs) == 1:
-        pl.when(needed)(partial(update, True))
-        return
-    pl.when(under)(partial(update, False))
-    pl.when(jnp.logical_and(needed, jnp.logical_not(under)))(partial(update, True))
+    bodies = {}
+    for mine, masked, span in cases:
+        body = masked or len(mask_refs) == 1, span
+        bodies[body] = jnp.logical_or(bodies[body], mine) if body in bodies else mine
+    for body, mine in bodies.items():
+        pl.when(mine)(partial(update, *body))
 
 
 def _fwd_kernel(
@@ -619,7 +694,7 @@ def _fwd_kernel(
 
     tables, refs = refs[:n_tables], refs[n_tables:]
     q_ref, k_ref, v_ref, *mask_refs, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
-    first, last, needed, under = step(*tables)
+    first, last, cases = step(*tables)
 
     @pl.when(first)
     def _init():
@@ -627,34 +702,38 @@ def _fwd_kernel(
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    def _update(masked):
+    def _update(masked, span):
+        # The keys of the step: the KV block's, or those of its needed half
+        # (the other's are all masked: probabilities of exactly 0 and a maximum
+        # that cannot win, so leaving them out changes no result).
+        keys = _columns(span, k_ref.shape[0])
         q = q_ref[...]
-        k = k_ref[...]
+        k = k_ref[keys, :]
         scores = (
             jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
             * scale
-        )  # (block_q, block_k) f32
+        )  # (block_q, keys) f32
         if masked:
-            scores = jnp.where(_allowed(mask_refs, window), scores, _NEG_INF)
+            scores = jnp.where(_allowed(mask_refs, keys, window), scores, _NEG_INF)
 
         m_prev = m_ref[...]  # (block_q, 1)
         m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
         correction = jnp.exp(m_prev - m_new)
-        p = jnp.exp(scores - m_new)  # (block_q, block_k) f32
+        p = jnp.exp(scores - m_new)  # (block_q, keys) f32
         l_ref[...] = l_ref[...] * correction + jnp.sum(p, axis=1, keepdims=True)
         pv = jax.lax.dot_general(
             p.astype(v_ref.dtype),
-            v_ref[...],
+            v_ref[keys, :],
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         acc_ref[...] = acc_ref[...] * correction + pv
         m_ref[...] = m_new
 
-    _when_needed(needed, under, _update, mask_refs)
+    _when_needed(cases, _update, mask_refs)
 
     @pl.when(last)
     def _finalize():
@@ -774,7 +853,7 @@ def _bwd_kernel(
     tables, refs = refs[:n_tables], refs[n_tables:]
     q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *rest = refs
     *mask_refs, dq_ref, dk_ref, dv_ref, dq_acc_ref, dk_acc_ref, dv_acc_ref = rest
-    jq, kv_first, kv_last, q_first, q_last, needed, under = step(*tables)
+    jq, kv_first, kv_last, q_first, q_last, cases = step(*tables)
     rows = pl.ds(pl.multiple_of(jq * block_q, block_q), block_q)
 
     @pl.when(kv_first)
@@ -786,41 +865,44 @@ def _bwd_kernel(
     def _init_dq():
         dq_acc_ref[rows, :] = jnp.zeros((block_q, dq_acc_ref.shape[1]), jnp.float32)
 
-    def _update(masked):
+    def _update(masked, span):
+        # The keys of the step (the forward's ``_update``): a half that needs
+        # nothing adds exact zeros to dq and to its own rows of dk and dv.
+        keys = _columns(span, k_ref.shape[0])
         q = q_ref[...]
-        k = k_ref[...]
+        k = k_ref[keys, :]
         scores = (
             jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
             * scale
-        )  # (block_q, block_k) f32
+        )  # (block_q, keys) f32
         p = jnp.exp(scores - lse_ref[...])
         if masked:
             # p from the saved lse; masked entries exactly 0 (also kills
             # padded q rows, whose position is -1 — below every key).
-            p = jnp.where(_allowed(mask_refs, window), p, 0.0)
+            p = jnp.where(_allowed(mask_refs, keys, window), p, 0.0)
         do = do_ref[...]
-        dv_acc_ref[...] += jax.lax.dot_general(
+        dv_acc_ref[keys, :] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # (block_k, d)
+        )  # (keys, d)
         dp = jax.lax.dot_general(
-            do, v_ref[...], (((1,), (1,)), ((), ())),
+            do, v_ref[keys, :], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # (block_q, block_k) f32
+        )  # (block_q, keys) f32
         ds = (p * (dp - dl_ref[...]) * scale).astype(q.dtype)
-        dk_acc_ref[...] += jax.lax.dot_general(
+        dk_acc_ref[keys, :] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # (block_k, d)
+        )  # (keys, d)
         dq_acc_ref[rows, :] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # (block_q, d)
 
-    _when_needed(needed, under, _update, mask_refs)
+    _when_needed(cases, _update, mask_refs)
 
     @pl.when(kv_last)
     def _finalize_dkv():
@@ -1254,7 +1336,10 @@ def _class_counts(
     compare masks, a corner both lines cross included) and ``under`` reads
     inside both. ``steps``: the grid steps a head takes in one forward call,
     which says which walk the call takes: the needed pairs alone where it
-    has no position arrays, every pair where it has."""
+    has no position arrays, every pair where it has. ``halves``: how many of
+    those steps compute one half of their KV block alone (:func:`_own_classes`;
+    0 in the walk over every pair, and where a half block is no whole lane
+    tiles)."""
     block_q, block_k = _block_sizes(block_q, block_k, sq, sk)
     qp, kp = _padded_positions(q_positions, k_positions, 1, sq, sk, block_q, block_k)
 
@@ -1270,6 +1355,8 @@ def _class_counts(
     own = q_positions is None and k_positions is None
     walk = _fwd_walk(own, qp[:1], kp[:1], sq, sk, block_q, block_k, False, window)
     counts["steps"] = int(np.prod(walk.grid))
+    listed = len(walk.tables) == 1  # its one table, the steps; the dense walk has two
+    counts["halves"] = int(np.sum(np.asarray(walk.tables[0][_CLASS]) > 2)) if listed else 0
     if window is None:
         return counts
     mine = classes(window)
@@ -1290,12 +1377,15 @@ def verify_on_chip() -> dict:
 
     One case runs both kernels under a selection
     (``flash_attention(..., selection=...)``), four under a window
-    (``window=``), and the last four (``listed``) are the grid of needed pairs
-    at the longest cell's size, each also compared to the bit with the walk
-    over every pair. Returns the largest error of
+    (``window=``), and the last five (``listed``) are the grid of needed pairs
+    at the cells' sizes, some of its steps computing one half of their KV
+    block alone, each also compared to the bit with the walk over every pair,
+    whole (``listed_differs_from_every_pair``: differing elements by output).
+    Returns the largest error of
     each case; under ``classes``, how many
     block pairs of the case the schedule classed above, on and under the
-    diagonal, and the grid ``steps`` a head's forward takes: how often the
+    diagonal, the grid ``steps`` a head's forward takes and how many of them
+    run one half (``halves``): how often the
     scheduling engaged; and under ``bwd_q_chunks``
     the path each case's backward calls took: 1 is dq resident in VMEM for
     the whole sequence, more is that many chunks of q blocks a head.
@@ -1568,18 +1658,21 @@ def verify_on_chip() -> dict:
     # position arrays) at the size the cell
     # ``smallthinker-21b-a3b-1chip.ftddp-seq16k`` runs it, 1 x 16,384 with 28
     # query heads over 4 KV heads of 128, full and under its window of 4,096; a
-    # ragged length under a window that is a multiple of no block; and a
-    # selection of 512 keys a query of 4,096. Each against attention in
+    # ragged length under a window that is a multiple of no block; a
+    # selection of 512 keys a query of 4,096; and the Mistral cells' 4 x 2,048
+    # at 32 / 8 heads. In each, some steps compute one half of their KV block
+    # alone (``halves`` under ``classes``). Each against attention in
     # float32 under the same mask, a query head at a time (the scores of 28
     # heads at once are 30 GB), and against the same two kernels walking every
-    # pair (the call given the sequence's own positions as arrays): every
-    # accumulator adds the same terms in the same order, so nothing differs.
-    def listed(case, sq, window=None, topk=None, heads=28, kv_heads=4, width=128):
+    # pair whole (the call given the sequence's own positions as arrays): every
+    # accumulator adds the same terms in the same order, but for the exact
+    # zeros of the halves left out, so nothing differs.
+    def listed(case, sq, window=None, topk=None, heads=28, kv_heads=4, width=128, batch=1):
         rows = jnp.arange(sq, dtype=jnp.int32)
         keys = jax.random.split(jax.random.PRNGKey(41), 5)
-        q = jax.random.normal(keys[0], (1, sq, heads, width), jnp.bfloat16)
-        k = jax.random.normal(keys[1], (1, sq, kv_heads, width), jnp.bfloat16)
-        v = jax.random.normal(keys[2], (1, sq, kv_heads, width), jnp.bfloat16)
+        q = jax.random.normal(keys[0], (batch, sq, heads, width), jnp.bfloat16)
+        k = jax.random.normal(keys[1], (batch, sq, kv_heads, width), jnp.bfloat16)
+        v = jax.random.normal(keys[2], (batch, sq, kv_heads, width), jnp.bfloat16)
         d_out = jax.random.normal(keys[3], q.shape, jnp.bfloat16)
         apart = rows[:, None] - rows[None, :]
         mask = (apart >= 0) & (apart < (window or sq))
@@ -1587,65 +1680,63 @@ def verify_on_chip() -> dict:
         if topk is not None:
             index = jax.random.normal(keys[4], (1, sq, sq))
             mask = select_topk(index, mask[None], topk)[0]
-            selection = mask[None].astype(jnp.int8)
+            selection = jnp.broadcast_to(mask[None].astype(jnp.int8), (batch, sq, sq))
         blocks = _block_sizes(512, 1024, sq, sq, topk is not None)
 
-        @jax.jit
-        def needed_pairs(q, k, v, d_out):
-            out, vjp = jax.vjp(
-                lambda q, k, v: flash_attention(
-                    q, k, v, interpret=False, selection=selection, window=window
-                ),
-                q, k, v,
-            )
-            return (out, *vjp(d_out))
-
-        @jax.jit
-        def every_pair(q, k, v, d_out):
-            at = rows[None]
+        @partial(jax.jit, static_argnums=4)
+        def walk(q, k, v, d_out, own):
+            at = None if own else jnp.broadcast_to(rows, (batch, sq))
             out, lse = _flash_fwd(
                 q, k, v, width**-0.5, *blocks, False, at, at,
                 selection=selection, window=window,
             )
-            return out, *flash_attention_partial_bwd(
-                q, k, v, d_out, out, lse.reshape(1, sq, heads), at, at,
-                width**-0.5, *blocks, False,
+            lse = lse.reshape(batch, sq, heads)
+            return out, lse, *flash_attention_partial_bwd(
+                q, k, v, d_out, out, lse, at, at, width**-0.5, *blocks, False,
                 out_dtype=q.dtype, selection=selection, window=window,
             )
 
         @jax.jit
         def reference(q, k, v, d_out):
             def head(i):
+                ib, ih = i // heads, i % heads
+
                 def attend(q, k, v):
                     scores = jnp.einsum("sd,td->st", q, k) * width**-0.5
                     probs = jax.nn.softmax(jnp.where(mask, scores, _NEG_INF), axis=-1)
                     return jnp.einsum("st,td->sd", probs, v)
 
                 mine = [
-                    x[0, :, j].astype(jnp.float32)
-                    for x, j in ((q, i), (k, i // (heads // kv_heads)), (v, i // (heads // kv_heads)))
+                    x[ib, :, j].astype(jnp.float32)
+                    for x, j in ((q, ih), (k, ih // (heads // kv_heads)), (v, ih // (heads // kv_heads)))
                 ]
                 out, vjp = jax.vjp(attend, *mine)
-                return (out, *vjp(d_out[0, :, i].astype(jnp.float32)))
+                return (out, *vjp(d_out[ib, :, ih].astype(jnp.float32)))
 
-            out, dq, dk, dv = jax.lax.map(head, jnp.arange(heads))  # (heads, sq, width)
-            group = lambda x: x.reshape(kv_heads, -1, sq, width).sum(axis=1)
-            return [x.transpose(1, 0, 2)[None] for x in (out, dq, group(dk), group(dv))]
+            # (batch x heads, sq, width) each
+            out, dq, dk, dv = jax.lax.map(head, jnp.arange(batch * heads))
+            group = lambda x: x.reshape(batch, kv_heads, -1, sq, width).sum(axis=2)
+            per_head = lambda x: x.reshape(batch, heads, sq, width)
+            return [x.transpose(0, 2, 1, 3) for x in (per_head(out), per_head(dq), group(dk), group(dv))]
 
-        got, dense_walk = needed_pairs(q, k, v, d_out), every_pair(q, k, v, d_out)
+        got, every_pair = walk(q, k, v, d_out, True), walk(q, k, v, d_out, False)
         want = reference(q, k, v, d_out)
         classes[case] = _class_counts(sq, sq, *blocks, window=window)
-        differing = sum(int(jnp.sum(a != b)) for a, b in zip(got, dense_walk))
-        if differing:
+        walks_differ[case] = {
+            name: int(jnp.sum(a != b))
+            for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), got, every_pair)
+        }
+        if any(walks_differ[case].values()):
             raise AssertionError(
-                f"on-chip flash {case}: {differing} elements differ between the "
-                "grid of needed pairs and the walk over every pair"
+                f"on-chip flash {case}: elements differ between the grid of needed "
+                f"pairs and the walk over every pair: {walks_differ[case]}"
             )
         return (
             check(f"LISTED {case}", worst(got[:1], want[:1]), 0.05),
-            check(f"LISTED BACKWARD {case}", worst(got[1:], want[1:]), 0.25),
+            check(f"LISTED BACKWARD {case}", worst(got[2:], want[1:]), 0.25),
         )
 
+    walks_differ = {}
     listed_errors = {
         case: listed(case, *args, **more)
         for case, args, more in (
@@ -1653,6 +1744,7 @@ def verify_on_chip() -> dict:
             ("listed-16384-window-4096", (16384, 4096), {}),
             ("listed-ragged-5000-window-1300", (5000, 1300), {}),
             ("listed-4096-selection-512", (4096,), dict(topk=512)),
+            ("listed-4x2048", (2048,), dict(heads=32, kv_heads=8, batch=4)),
         )
     }
     return {
@@ -1672,6 +1764,7 @@ def verify_on_chip() -> dict:
         "max_err_window_bwd": {case: errs[1] for case, errs in window_errors.items()},
         "max_err_listed": {case: errs[0] for case, errs in listed_errors.items()},
         "max_err_listed_bwd": {case: errs[1] for case, errs in listed_errors.items()},
+        "listed_differs_from_every_pair": walks_differ,
         "classes": classes,
         "bwd_q_chunks": chunks,
         "ok": True,
