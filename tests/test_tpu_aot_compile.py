@@ -1042,3 +1042,124 @@ def test_the_smallthinker_cells_reference_programs_fit_the_chip(chip) -> None:
     assert total(loss) < 4 * 2**30
     update = reference.make_loss_after_first_update(architecture, config)
     assert total(update.lower(params, tokens, tokens).compile()) < 14.5 * 2**30
+
+
+# The cell ``granite-4.0-h-micro-1chip.ftddp-seq8k``'s own size, and a twin at
+# toy widths for the slow marker's other side: the same head widths (64 and
+# 64), state, chunk and period of ten.
+_GRANITE_TOY = {
+    "hidden_size": 256, "intermediate_size": 512, "shared_intermediate_size": 512,
+    "num_attention_heads": 4, "num_key_value_heads": 1, "mamba_n_heads": 8, "vocab_size": 2048,
+}
+
+
+@pytest.mark.parametrize(
+    "widths, seq",
+    [
+        pytest.param({}, 8192, id="granite-1x8192"),  # under a minute
+        pytest.param(_GRANITE_TOY, 2048, id="toy-1x2048"),
+    ],
+)
+def test_dots_step_of_the_granite_cell_recomputes_its_period_and_fits(
+    chip, monkeypatch, widths, seq
+) -> None:
+    """The FT-DDP fused step of the granite cell (one period of ten layers,
+    nine Mamba-2 and one attention, bf16, ``dots``, AdamW) compiled for a
+    described v5e as the model builds it on a TPU. The scan of ONE period is
+    inlined by XLA, and the remat barrier must survive that: at the cell's own
+    size the program's arguments, results and temporaries come to under 14.5 of
+    the chip's 15.75 GiB (13.49: PERF.md section 6, PR 57; 17.55 where CSE
+    merges the recomputation with the forward). The one attention layer's two
+    Mosaic calls are the only ones, under its scope's name; the scan's scopes
+    reach the compiled text; and the architecture file's shapes find the scan's
+    ops in it and none of the projections'."""
+    import json
+    from pathlib import Path
+
+    import torchft_tpu.ops.attention as attention
+    import torchft_tpu.ops.flash_attention as flash
+    from chipbench import spec
+    from chipbench.model import System
+    from torchft_tpu.optim import make_jit_fused_step
+
+    for module in (attention, flash):
+        monkeypatch.setattr(module, "on_tpu", lambda: True)
+    root = Path(__file__).parent.parent
+    config = json.loads((root / "chipbench/configs/granite-4.0-h-micro-1chip.json").read_text())
+    config.update(widths)
+    assert config["run"]["remat"] == "dots" and config["run"]["scan_layers"]
+    architecture = spec.load_module(root / "chipbench/architectures/granitemoehybrid.py")
+    system = System(config, architecture, {"batch": 1, "seq": seq}, seed=0)
+    assert system.model.config.period == system.model.config.n_layers == 10
+    params = jax.eval_shape(system.init_params)
+    assert sorted(params["params"]["layers"]) == sorted(f"block_{kind}" for kind in range(10))
+    opt_state = jax.eval_shape(system.tx.init, params)
+    program = (
+        make_jit_fused_step(system.tx, system.loss_fn)
+        .lower(
+            _sds_tree(params, chip), _sds_tree(opt_state, chip),
+            _sds((1, seq + 1), jnp.int32, chip),
+        )
+        .compile()
+    )
+    names = [name for name, _, _ in _mosaic_calls(program)]
+    assert len(names) == 2 and all("tpuft__nope_attention" in n for n in names), names
+    text = program.as_text()
+    for scope in ("mamba::in_proj", "mamba::conv", "ssd::intra_chunk", "ssd::chunk_states",
+                  "ssd::inter_chunk", "ssd::state_out", "mamba::gated_norm", "mamba::out_proj"):
+        assert f"tpuft::{scope}" in text, scope
+    # The reader's shapes on the program's own instructions, a second each.
+    by_scope = {"scan": [], "projections": []}
+    for line in text.splitlines():
+        found = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = \(?([a-z0-9]+\[[0-9,]*\])", line)
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        if not found or not op_name or " fusion(" not in line and " convolution(" not in line:
+            continue
+        row = [f"{found.group(1)} {found.group(2)}", 1.0]
+        if re.search(r"tpuft::ssd::|tpuft::mamba::conv", op_name.group(1)):
+            by_scope["scan"].append(row)
+        elif re.search(r"tpuft::mamba::(?:in|out)_proj", op_name.group(1)):
+            by_scope["projections"].append(row)
+    seen = lambda rows: architecture.ssd_seconds({"ops": rows}, config, 1, seq)
+    assert len(by_scope["scan"]) >= 100 and by_scope["projections"]
+    assert seen(by_scope["scan"]) >= 0.5 * len(by_scope["scan"])
+    assert seen(by_scope["projections"]) == 0.0
+    if not widths:
+        memory = program.memory_analysis()
+        total = (
+            memory.argument_size_in_bytes + memory.output_size_in_bytes
+            + memory.temp_size_in_bytes - memory.alias_size_in_bytes
+        )
+        assert total < 14.5 * 2**30, total / 2**30
+
+
+@pytest.mark.slow  # a minute and a half: two float32 programs of ten written-out layers
+def test_the_granite_cells_reference_programs_fit_the_chip(chip) -> None:
+    """The float32 reference's loss and its update at the cell's own size
+    (1 x 8192, ten layers, the scan's masked matrix in blocks of 128 rows),
+    compiled for a described v5e: 3.97 and 9.64 GiB (PERF.md section 6,
+    PR 57), both inside the chip's 15.75 with the bf16 weights they are given."""
+    import json
+    from pathlib import Path
+
+    from chipbench import reference, spec
+    from chipbench.model import System
+
+    root = Path(__file__).parent.parent
+    config = json.loads((root / "chipbench/configs/granite-4.0-h-micro-1chip.json").read_text())
+    architecture = spec.load_module(root / "chipbench/architectures/granitemoehybrid.py")
+    system = System(config, architecture, {"batch": 1, "seq": 8192}, seed=0)
+    params = _sds_tree(jax.eval_shape(system.init_params), chip)
+    tokens = _sds((1, 8193), jnp.int32, chip)
+
+    def total(compiled):
+        memory = compiled.memory_analysis()
+        return (
+            memory.argument_size_in_bytes + memory.output_size_in_bytes
+            + memory.temp_size_in_bytes - memory.alias_size_in_bytes
+        )
+
+    loss = reference.make_loss(architecture, config).lower(params, tokens).compile()
+    assert total(loss) < 4.5 * 2**30
+    update = reference.make_loss_after_first_update(architecture, config)
+    assert total(update.lower(params, tokens, tokens).compile()) < 10.5 * 2**30

@@ -18,8 +18,8 @@ import jax.numpy as jnp
 from torchft_tpu.ops.cross_entropy import chunked_cross_entropy
 
 __all__ = [
-    "apply_rope", "RMSNorm", "LMHead", "into_residual", "remat_policy", "layer_stack",
-    "sown_by_layer",
+    "apply_rope", "RMSNorm", "LMHead", "tied_head", "into_residual", "remat_policy",
+    "layer_stack", "smallest_period", "sown_by_layer",
 ]
 
 
@@ -72,6 +72,22 @@ class LMHead(nn.Module):
         if targets is None:
             return jnp.dot(x, kernel.astype(self.dtype))
         return chunked_cross_entropy(x, kernel, targets, self.loss_vocab_chunk)
+
+
+def tied_head(
+    embed: nn.Embed, x: jnp.ndarray, targets: Optional[jnp.ndarray] = None,
+    loss_vocab_chunk: Optional[int] = None,
+) -> jnp.ndarray:
+    """The output head of a model whose head IS its embedding (``embed``, the
+    bound module, so its one ``(vocab, dim)`` matrix is the only leaf): with
+    ``targets`` the mean token cross-entropy of ``softmax(x @ embedding.T)``
+    through :func:`~torchft_tpu.ops.cross_entropy.chunked_cross_entropy`,
+    which never forms the logits; without, the logits. The matrix then has
+    two uses in one program, the gather and this product, and autodiff gives
+    it the sum of both gradients."""
+    if targets is None:
+        return embed.attend(x)
+    return chunked_cross_entropy(x, embed.embedding.T, targets, loss_vocab_chunk)
 
 
 def into_residual(depth: int, **axes):
@@ -138,6 +154,17 @@ def _scanned(cell: Any, length: int):
     )
 
 
+def smallest_period(kinds) -> int:
+    """The smallest number of layers a sequence of layer kinds repeats with:
+    what a model whose config lists a kind for every layer hands
+    :func:`layer_stack` as ``period``."""
+    n = len(kinds)
+    return next(
+        p for p in range(1, n + 1)
+        if n % p == 0 and all(kinds[i] == kinds[i % p] for i in range(n))
+    )
+
+
 def layer_stack(
     block: Any, cfg: Any, policy: Any, x: jnp.ndarray, positions: jnp.ndarray, period: int = 1
 ):
@@ -163,7 +190,11 @@ def layer_stack(
         if cfg.n_layers % period:
             raise ValueError(f"{cfg.n_layers} layers are not whole periods of {period}")
         if cfg.remat != "none":
-            block = nn.remat(block, policy=policy, prevent_cse=not cfg.scan_layers)
+            # A scan of ONE period is no loop to XLA: it inlines the body, the
+            # forward and its recomputation then meet in one computation, and
+            # without the barrier CSE merges them and keeps every activation.
+            inlined = not cfg.scan_layers or cfg.n_layers == period
+            block = nn.remat(block, policy=policy, prevent_cse=inlined)
         if cfg.scan_layers:
             stack = _scanned(_PeriodCell, cfg.n_layers // period)
             return stack(block, cfg, period, name="layers")(x, positions)[0]
@@ -189,15 +220,16 @@ def sown_by_layer(
 ) -> jnp.ndarray:
     """What the blocks' submodule ``module`` sowed into ``intermediates`` under
     ``name`` for ``tokens`` (b, s), by layer: (n_layers, ...), from any layout
-    of :func:`layer_stack`'s tree."""
-    n_layers = model.config.n_layers
+    of :func:`layer_stack`'s tree. Where only some kinds of a period have the
+    submodule, the layers that have it, in their order."""
     _, seen = model.apply(params, tokens, mutable=["intermediates"])
     seen = seen["intermediates"]
     if "layers" not in seen:
-        return jnp.stack([seen[f"layer_{i}"][module][name][0] for i in range(n_layers)])
+        layers = sorted((k for k in seen if k.startswith("layer_")), key=lambda k: int(k[6:]))
+        return jnp.stack([seen[k][module][name][0] for k in layers if module in seen[k]])
     stack = seen["layers"]
     if "block" in stack:
         return stack["block"][module][name][0]
-    kinds = [stack[f"block_{kind}"][module][name][0] for kind in range(len(stack))]
-    by_period = jnp.stack(kinds, axis=1)  # (periods, period, ...)
-    return by_period.reshape(n_layers, *by_period.shape[2:])
+    kinds = sorted((k for k in stack if module in stack[k]), key=lambda k: int(k[6:]))
+    by_period = jnp.stack([stack[k][module][name][0] for k in kinds], axis=1)
+    return by_period.reshape(-1, *by_period.shape[2:])  # (periods x kinds, ...)

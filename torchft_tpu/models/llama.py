@@ -33,9 +33,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from torchft_tpu.models.decoder import LMHead, RMSNorm, apply_rope, layer_stack, remat_policy
+from torchft_tpu.models.decoder import (
+    LMHead, RMSNorm, apply_rope, layer_stack, remat_policy, tied_head,
+)
 from torchft_tpu.ops.attention import attend
-from torchft_tpu.ops.cross_entropy import chunked_cross_entropy
 from torchft_tpu.ops.flash_attention import FLASH_LSE, FLASH_OUT
 
 __all__ = [
@@ -266,9 +267,8 @@ class Llama(nn.Module):
         x = layer_stack(Block, cfg, policy, x, positions)
         x = RMSNorm(cfg.norm_eps, cfg.dtype, name="final_norm")(x)
         if cfg.tie_embeddings:
-            if targets is None:
-                return embed.attend(x).astype(jnp.float32)
-            return chunked_cross_entropy(x, embed.embedding.T, targets, cfg.loss_vocab_chunk)
+            out = tied_head(embed, x, targets, cfg.loss_vocab_chunk)
+            return out if targets is not None else out.astype(jnp.float32)
         head = LMHead(cfg.dim, cfg.vocab_size, cfg.dtype, cfg.loss_vocab_chunk, name="lm_head")
         return head(x, targets) if targets is not None else head(x).astype(jnp.float32)
 

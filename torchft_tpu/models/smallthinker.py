@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from torchft_tpu.models.decoder import (
-    LMHead, RMSNorm, apply_rope, into_residual, layer_stack, remat_policy,
+    LMHead, RMSNorm, apply_rope, into_residual, layer_stack, remat_policy, smallest_period,
 )
 from torchft_tpu.models.experts import RoutedExperts, dispatch_rows, router_load
 from torchft_tpu.ops.attention import attend
@@ -111,11 +111,7 @@ class SmallThinkerConfig:
     @property
     def period(self) -> int:
         """The smallest number of layers the kinds repeat with."""
-        kinds, n = self.kinds, self.n_layers
-        return next(
-            p for p in range(1, n + 1)
-            if n % p == 0 and all(kinds[i] == kinds[i % p] for i in range(n))
-        )
+        return smallest_period(self.kinds)
 
 
 def _dense(cfg: SmallThinkerConfig):
