@@ -72,32 +72,100 @@ def decays_after(system, params, steps) -> dict:
     return at
 
 
-def scan_against_recurrence(config, seq: int, seed: int) -> dict:
+def _milliseconds(fn, *args, repeats: int = 10) -> float:
+    """Wall ms a call of jitted ``fn`` once warm (the mean of ``repeats``)."""
+    import time
+
+    import jax
+
+    jax.block_until_ready(fn(*args))
+    start = time.perf_counter()
+    for _ in range(repeats):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return 1e3 * (time.perf_counter() - start) / repeats
+
+
+def scan_against_recurrence(config, seq: int, seed: int, rehearse: bool) -> dict:
     """The chunked scan on bfloat16 operands against the float32 recurrence
-    at the cell's shapes: inputs of unit scale, steps and decays drawn as the
-    model's initialisers draw them."""
+    at the cell's shapes, by path: ``kernels`` is what ``ssd_scan`` takes on a
+    TPU (the Mosaic calls; interpreted in a rehearsal, at a toy size they
+    take), ``einsums`` the XLA path. Inputs of unit scale, steps and decays
+    drawn as the model's initialisers draw them. Beside it the kernels'
+    gradients against the einsum path's autodiff (largest difference over the
+    largest entry, by argument), the convolution's kernels against
+    ``silu(causal_conv)`` in float32, and (on a chip) the wall ms of one
+    layer's forward and gradient by path."""
     import jax
     import jax.numpy as jnp
 
     from torchft_tpu.ops import ssd
 
     heads, p, n, groups = (config[k] for k in ("mamba_n_heads", "mamba_d_head", "mamba_d_state", "mamba_n_groups"))
-    keys = jax.random.split(jax.random.PRNGKey(seed & 0x7FFFFFFF), 6)
+    chunk, width = config["mamba_chunk_size"], config["mamba_d_conv"]
+    if rehearse:  # the smallest the kernels take
+        seq, heads, p, n, groups, chunk = 256, 4, 64, 128, 1, 128
+    interpret = True if rehearse else None
+    keys = jax.random.split(jax.random.PRNGKey(seed & 0x7FFFFFFF), 9)
     x = jax.random.normal(keys[0], (1, seq, heads, p), jnp.bfloat16)
     b_in = jax.random.normal(keys[1], (1, seq, groups, n), jnp.bfloat16)
     c_out = jax.random.normal(keys[2], (1, seq, groups, n), jnp.bfloat16)
     dt = jnp.exp(jax.random.uniform(keys[3], (1, seq, heads), minval=jnp.log(1e-3), maxval=jnp.log(1e-1)))
     a = -jax.random.uniform(keys[4], (heads,), minval=1.0, maxval=16.0)
     d_skip = jnp.ones((heads,))
-    chunk = config["mamba_chunk_size"]
-    got = jax.jit(lambda *z: ssd.ssd_scan(*z, chunk=chunk))(x, dt, a, b_in, c_out, d_skip)
-    want, _ = jax.jit(ssd.ssd_recurrence)(x, dt, a, b_in, c_out, d_skip)
-    worst = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want)))
-    return {
-        "shape": list(x.shape), "chunk": chunk, "largest_output": float(jnp.max(jnp.abs(want))),
-        "largest_difference": worst, "relative": worst / float(jnp.max(jnp.abs(want))),
-        "chunk_log_decay": jax.device_get(ssd.chunk_log_decay(dt, a, chunk)).tolist(),
+    operands = (x, dt, a, b_in, c_out, d_skip)
+    assert ssd.scan_kernel_fits(x, b_in, chunk)
+    paths = {
+        "kernels": lambda *z: ssd.ssd_scan(*z, chunk=chunk, interpret=interpret),
+        "einsums": lambda *z: ssd.ssd_scan_einsums(*z, chunk=chunk),
     }
+    relative = lambda got, want: float(
+        jnp.max(jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32)))
+        / jnp.max(jnp.abs(want.astype(jnp.float32)))
+    )
+    want, _ = jax.jit(ssd.ssd_recurrence)(*operands)
+    weigh = jnp.cos(jnp.arange(x.size, dtype=jnp.float32)).reshape(x.shape)
+    out = {
+        "shape": list(x.shape), "chunk": chunk, "largest_output": float(jnp.max(jnp.abs(want))),
+        "chunk_log_decay": jax.device_get(ssd.chunk_log_decay(dt, a, chunk)).tolist(), "relative": {},
+    }
+    gradients = {}
+    for name, path in paths.items():
+        out["relative"][name] = relative(jax.jit(path)(*operands), want)
+        gradient = jax.jit(jax.grad(
+            lambda *z: jnp.sum(weigh * path(*z).astype(jnp.float32)), argnums=tuple(range(6))
+        ))
+        gradients[name] = gradient(*operands)
+        if not rehearse:
+            out.setdefault("gradient_ms", {})[name] = _milliseconds(gradient, *operands)
+    out["gradients_kernels_against_einsums"] = {
+        leaf: relative(got, other)
+        for leaf, got, other in zip(("x", "dt", "A", "B", "C", "D"), gradients["kernels"], gradients["einsums"])
+    }
+    # The convolution in front of it, over x, B and C together.
+    channels = heads * p + 2 * groups * n
+    xbc = jax.random.normal(keys[5], (1, seq, channels), jnp.bfloat16)
+    kernel = jax.random.uniform(keys[6], (channels, width), minval=-0.5, maxval=0.5).astype(jnp.bfloat16)
+    bias = (0.1 * jax.random.normal(keys[7], (channels,))).astype(jnp.bfloat16)
+    assert ssd.conv_kernel_fits(xbc, kernel)
+    spread = jnp.cos(jnp.arange(xbc.size, dtype=jnp.float32)).reshape(xbc.shape)
+    convolutions = {
+        "kernels": lambda *z: ssd.conv_silu(*z, interpret=interpret),
+        "float32": lambda *z: jax.nn.silu(ssd.causal_conv(*z)),
+    }
+    results = {}
+    for name, path in convolutions.items():
+        gradient = jax.jit(jax.value_and_grad(
+            lambda *z: jnp.sum(spread * path(*z).astype(jnp.float32)), argnums=(0, 1, 2)
+        ))
+        results[name] = (jax.jit(path)(xbc, kernel, bias), *gradient(xbc, kernel, bias)[1])
+        if not rehearse:
+            out.setdefault("convolution_gradient_ms", {})[name] = _milliseconds(gradient, xbc, kernel, bias)
+    out["convolution_kernels_against_float32"] = {
+        leaf: relative(got, other)
+        for leaf, got, other in zip(("out", "dx", "dkernel", "dbias"), results["kernels"], results["float32"])
+    }
+    return out
 
 
 def flash_at_head_width_64(config, seq: int, rehearse: bool) -> dict:
@@ -131,19 +199,24 @@ def flash_at_head_width_64(config, seq: int, rehearse: bool) -> dict:
 
 
 def update_by_attention_path(system, params) -> dict:
-    """By path of the attention layer, over the matrices (every kernel but
-    the tied one) and over all leaves: ``sum(g2 * u(gp)) / sum(g2 * u(g))``
-    with g and g2 the float32 reference's gradients of batches 0 and 1, gp the
-    program's of batch 0 and ``u(x) = x / (|x| + eps)``, AdamW's first step
-    from zero moments but for the learning rate; ``linear`` is the same
-    without ``u``. 1.0 is the reference's own descent."""
+    """By path of the attention layer (and, on a TPU, of the Mamba layers'
+    scan and convolution: ``<path>`` has them on the Mosaic kernels the cell
+    runs, ``<path>+einsum_scan`` on the XLA path), over the matrices (every
+    kernel but the tied one) and over all leaves: ``sum(g2 * u(gp)) / sum(g2
+    * u(g))`` with g and g2 the float32 reference's gradients of batches 0 and
+    1, gp the program's of batch 0 and ``u(x) = x / (|x| + eps)``, AdamW's
+    first step from zero moments but for the learning rate; ``linear`` is the
+    same without ``u``. 1.0 is the reference's own descent."""
     import dataclasses
+    import itertools
+    from unittest import mock
 
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from chipbench import reference
+    from torchft_tpu.ops import ssd
 
     config, architecture = system.config, system.architecture
     eps = float(config["optimizer"]["eps"])
@@ -162,10 +235,13 @@ def update_by_attention_path(system, params) -> dict:
     matrices = [leaf.ndim >= 2 and leaf.shape[-2:] != (config["vocab_size"], config["hidden_size"]) for leaf in g]
     step = lambda x: x / (np.abs(x) + eps)
     out = {}
-    for path in ("auto", "blockwise"):
+    on_kernels = ssd.on_tpu()  # elsewhere the Mamba layers have the one path
+    for path, einsum_scan in itertools.product(("auto", "blockwise"), (False, True)[: 1 + on_kernels]):
         model = type(system.model)(dataclasses.replace(system.model.config, attention_impl=path))
         loss = lambda p, tokens: model.apply(p, tokens[:, :-1], targets=tokens[:, 1:])
-        gp = leaves(jax.jit(jax.grad(loss))(params, first))
+        with mock.patch.object(ssd, "on_tpu", lambda: on_kernels and not einsum_scan):
+            gp = leaves(jax.jit(jax.grad(loss))(params, first))
+        path += "+einsum_scan" * einsum_scan
         sums = np.zeros((2, 4))  # (matrices, all) x (step got, step ref, linear got, linear ref)
         for a, b, c, is_matrix in zip(g, g2, gp, matrices):
             # the reference's gradients are sums over the batch's tokens: u() wants the mean's size
@@ -204,7 +280,9 @@ def check(bench, config, traffic, seed: int, args, shared) -> dict:
         abs(want["second"]["0"] - want["second_without_update"]) / abs(want["first"])
     )
     if not args.reference_only:
-        out["scan_against_recurrence"] = scan_against_recurrence(config, system.seq, seed)
+        out["scan_against_recurrence"] = scan_against_recurrence(
+            config, system.seq, seed, args.rehearse
+        )
         out["flash_at_head_width_64"] = flash_at_head_width_64(
             config, min(system.seq, 2048), args.rehearse
         )

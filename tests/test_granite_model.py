@@ -315,7 +315,42 @@ def test_a_sliced_vocabulary_is_the_smaller_vocabulary_model(short):
     assert abs(float(got) - float(want)) / float(got) < TOLERANCE
 
 
+def test_a_mamba_layer_on_the_interpreted_kernels_is_the_layer_on_the_einsum_path(monkeypatch):
+    """One Mamba-2 block at the smallest widths ops/ssd.py's Mosaic kernels
+    take (two heads of 64, a state of 128, chunks of 128, 384 channels of
+    convolution), float32: the loss and every leaf's gradient with the
+    convolution and the scan in the Pallas interpreter against the same layer
+    on the XLA path. The model has no switch for it, so the test binds
+    ``interpret=True`` where the model looks the two functions up."""
+    from functools import partial
+
+    from torchft_tpu.ops import ssd
+
+    cfg = GraniteConfig(
+        vocab_size=64, dim=64, n_layers=1, layer_types=("mamba",), n_heads=4, n_kv_heads=2,
+        mlp_hidden=96, mamba_heads=2, mamba_head_dim=64, mamba_state=128, mamba_chunk=128,
+        dtype=jnp.float32,
+    )
+    model = Granite(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(11), (1, 257), 0, cfg.vocab_size)
+    params = jax.jit(model.init)(jax.random.PRNGKey(3), tokens[:, :-1])
+    loss_and_gradient = lambda: jax.jit(jax.value_and_grad(lambda p: program_loss(model, p, tokens)))
+    with jax.default_matmul_precision("highest"):
+        want_loss, want = loss_and_gradient()(params)
+        monkeypatch.setattr(ssd, "conv_silu", partial(ssd.conv_silu, interpret=True))
+        monkeypatch.setattr(ssd, "ssd_scan", partial(ssd.ssd_scan, interpret=True))
+        traced = str(jax.make_jaxpr(lambda p: program_loss(model, p, tokens))(params))
+        assert ssd.CONV_FWD in traced and ssd.SSD_FWD in traced
+        got_loss, got = loss_and_gradient()(params)
+    assert abs(float(got_loss) - float(want_loss)) / float(want_loss) < TOLERANCE
+    got, want = flat(got), flat(want)
+    assert set(got) == set(want) and len(got) == 12 + 2
+    for name in sorted(want):
+        assert float(jnp.linalg.norm(want[name])) > 0, name
+        assert relative(got[name], want[name]) < 10 * TOLERANCE, name
+
+
 def test_the_model_file_asks_no_platform_and_ops_chooses_the_kernel():
     text = (ROOT / "torchft_tpu/models/granite.py").read_text()
-    assert "on_tpu" not in text and "jax.devices" not in text
+    assert "on_tpu" not in text and "jax.devices" not in text and "interpret" not in text
     assert "from torchft_tpu.ops.attention import attend" in text

@@ -716,3 +716,75 @@ def test_a_call_traces_the_half_steps_its_table_holds_and_lowers(
     forward, backward = _pallas_calls(traced.jaxpr.jaxpr)
     assert _update_bodies(forward) == _update_bodies(backward) == bodies
     traced.lower(lowering_platforms=("tpu",))
+
+
+# The granite cell's Mamba-2 geometry: 1 x 8192, 64 heads of 64, a state of
+# 128 in one group, 4352 channels of convolution 4 wide, chunks of 256.
+MAMBA = {"b": 1, "s": 8192, "heads": 64, "p": 64, "n": 128, "groups": 1, "width": 4, "chunk": 256}
+
+
+def _mamba_operands(b, s, heads, p, n, groups, dtype=jnp.bfloat16, **_):
+    return (
+        _sds((b, s, heads, p), dtype), _sds((b, s, heads), jnp.float32), _sds((heads,), jnp.float32),
+        _sds((b, s, groups, n), dtype), _sds((b, s, groups, n), dtype), _sds((heads,), dtype),
+    )
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_the_scans_kernels_lower_for_tpu_at_the_granite_cells_geometry(direction):
+    """``ssd_fwd`` alone (the primal call keeps no states) and the pair a
+    gradient traces (``ssd_fwd`` with the entering states, ``ssd_bwd``)."""
+    from torchft_tpu.ops import ssd
+
+    operands = _mamba_operands(**MAMBA)
+    assert ssd.scan_kernel_fits(operands[0], operands[3], MAMBA["chunk"])
+    scan = lambda *z: ssd.ssd_scan(*z, chunk=MAMBA["chunk"], interpret=False)
+    if direction == "forward":
+        text = _lower_tpu(scan, *operands).as_text()
+        assert text.count("tpu_custom_call") == 1 and ssd.SSD_FWD in text
+        return
+    loss = lambda *z: jnp.sum(scan(*z).astype(jnp.float32) ** 2)
+    text = _lower_tpu(jax.grad(loss, argnums=tuple(range(6))), *operands).as_text()
+    assert text.count("tpu_custom_call") == 2 and ssd.SSD_FWD in text and ssd.SSD_BWD in text
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_the_convolutions_kernels_lower_for_tpu_at_the_granite_cells_geometry(direction):
+    from torchft_tpu.ops import ssd
+
+    channels = MAMBA["heads"] * MAMBA["p"] + 2 * MAMBA["groups"] * MAMBA["n"]
+    operands = (
+        _sds((MAMBA["b"], MAMBA["s"], channels), jnp.bfloat16),
+        _sds((channels, MAMBA["width"]), jnp.bfloat16), _sds((channels,), jnp.bfloat16),
+    )
+    assert channels == 4352 and ssd.conv_kernel_fits(*operands[:2])
+    conv = lambda *z: ssd.conv_silu(*z, interpret=False)
+    if direction == "forward":
+        text = _lower_tpu(conv, *operands).as_text()
+        assert text.count("tpu_custom_call") == 1 and ssd.CONV_FWD in text
+        return
+    loss = lambda *z: jnp.sum(conv(*z).astype(jnp.float32) ** 2)
+    text = _lower_tpu(jax.grad(loss, argnums=(0, 1, 2)), *operands).as_text()
+    assert text.count("tpu_custom_call") == 2 and ssd.CONV_FWD in text and ssd.CONV_BWD in text
+
+
+@pytest.mark.parametrize(
+    "sizes",
+    [
+        pytest.param({"heads": 4, "p": 32, "s": 256, "chunk": 128}, id="four-heads-of-32-a-slab"),
+        pytest.param({"heads": 2, "p": 128, "s": 256, "chunk": 128}, id="a-head-of-128"),
+        pytest.param({"heads": 8, "p": 64, "groups": 2, "s": 512, "chunk": 256}, id="two-groups"),
+        pytest.param({"heads": 4, "p": 64, "s": 256, "chunk": 128, "dtype": jnp.float32}, id="float32"),
+    ],
+)
+def test_the_scans_kernels_lower_at_the_other_shapes_they_take(sizes):
+    """Every shape ``scan_kernel_fits`` admits has to lower: head widths on
+    either side of a slab of 128 lanes, more than one group, float32."""
+    from torchft_tpu.ops import ssd
+
+    sizes = {**MAMBA, **sizes}
+    operands = _mamba_operands(**sizes)
+    assert ssd.scan_kernel_fits(operands[0], operands[3], sizes["chunk"])
+    loss = lambda *z: jnp.sum(ssd.ssd_scan(*z, chunk=sizes["chunk"], interpret=False).astype(jnp.float32))
+    text = _lower_tpu(jax.grad(loss, argnums=tuple(range(6))), *operands).as_text()
+    assert text.count("tpu_custom_call") == 2

@@ -1044,6 +1044,157 @@ def test_the_smallthinker_cells_reference_programs_fit_the_chip(chip) -> None:
     assert total(update.lower(params, tokens, tokens).compile()) < 14.5 * 2**30
 
 
+@pytest.mark.parametrize("kernels", ["scan", "convolution"])
+def test_the_mamba_kernels_compile_at_the_granite_cells_geometry(chip, kernels) -> None:
+    """ops/ssd.py's four Mosaic calls at the cell's geometry (1 x 8192, 64
+    heads of 64, a state of 128 in one group, 4352 channels 4 wide, chunks of
+    256, bf16), forward and backward, compiled for a described v5e: each under
+    its name, stating no VMEM limit (PR 42: a stated limit moves other
+    fusions) and using under the 16 MiB a call gets without asking."""
+    from torchft_tpu.ops import ssd
+
+    b, s, heads, p, n, groups, chunk = 1, 8192, 64, 64, 128, 1, 256
+    if kernels == "scan":
+        operands = (
+            _sds((b, s, heads, p), jnp.bfloat16, chip), _sds((b, s, heads), jnp.float32, chip),
+            _sds((heads,), jnp.float32, chip), _sds((b, s, groups, n), jnp.bfloat16, chip),
+            _sds((b, s, groups, n), jnp.bfloat16, chip), _sds((heads,), jnp.bfloat16, chip),
+        )
+        call = lambda *z: ssd.ssd_scan(*z, chunk=chunk, interpret=False)
+        names = (ssd.SSD_FWD, ssd.SSD_BWD)
+    else:
+        channels = heads * p + 2 * groups * n
+        operands = (
+            _sds((b, s, channels), jnp.bfloat16, chip), _sds((channels, 4), jnp.bfloat16, chip),
+            _sds((channels,), jnp.bfloat16, chip),
+        )
+        call = lambda *z: ssd.conv_silu(*z, interpret=False)
+        names = (ssd.CONV_FWD, ssd.CONV_BWD)
+    loss = lambda *z: jnp.sum(call(*z).astype(jnp.float32) ** 2)
+    calls = _mosaic_calls(_compile(jax.grad(loss, argnums=tuple(range(len(operands)))), *operands))
+    assert len(calls) == 2 and all(name in call for name, (call, _, _) in zip(names, calls)), calls
+    for name, stated, used in calls:
+        assert not stated and 0 < max(used) <= 16 * 2**20, (name, stated, used)
+
+
+# The cell ``granite-4.0-h-micro-1chip.ftddp-seq8k``'s own size, and a twin at
+# toy widths for the slow marker's other side: the same head widths (64 and
+# 64), state, chunk and period of ten.
+_GRANITE_TOY = {
+    "hidden_size": 256, "intermediate_size": 512, "shared_intermediate_size": 512,
+    "num_attention_heads": 4, "num_key_value_heads": 1, "mamba_n_heads": 8, "vocab_size": 2048,
+}
+
+
+@pytest.mark.parametrize(
+    "widths, seq",
+    [
+        pytest.param({}, 8192, id="granite-1x8192"),  # under a minute
+        pytest.param(_GRANITE_TOY, 2048, id="toy-1x2048"),
+    ],
+)
+def test_dots_step_of_the_granite_cell_recomputes_its_period_and_fits(
+    chip, monkeypatch, widths, seq
+) -> None:
+    """The FT-DDP fused step of the granite cell (one period of ten layers,
+    nine Mamba-2 and one attention, bf16, ``dots``, AdamW) compiled for a
+    described v5e as the model builds it on a TPU. The scan of ONE period is
+    inlined by XLA, and the remat barrier must survive that: at the cell's own
+    size the program's arguments, results and temporaries come to under 14.5 of
+    the chip's 15.75 GiB (13.49 with the einsum scan: PERF.md section 6, PR 57;
+    17.55 where CSE merges the recomputation with the forward). Since PR 58 a
+    Mamba layer's convolution and scan are Mosaic calls, counted BY NAME: the
+    forward pair twice (``dots`` keeps neither: they come again in the layer's
+    backward) and the backward pair once, six a layer and 54 in all, beside the
+    one attention layer's two under its scope's name. The scopes that survive
+    reach the compiled text, the einsum path's four are gone, and the
+    architecture file's reader finds the named calls and none of the
+    projections."""
+    import json
+    from pathlib import Path
+
+    import torchft_tpu.ops.attention as attention
+    import torchft_tpu.ops.flash_attention as flash
+    import torchft_tpu.ops.grouped_matmul as grouped
+    from chipbench import spec
+    from chipbench.model import System
+    from torchft_tpu.optim import make_jit_fused_step
+
+    for module in (attention, flash, grouped):
+        monkeypatch.setattr(module, "on_tpu", lambda: True)
+    root = Path(__file__).parent.parent
+    config = json.loads(
+        (root / "chipbench/configs/smallthinker-21b-a3b-ep8-1chip.json").read_text()
+    )
+    config.update(widths)
+    assert config["run"]["remat"] == "dots" and config["run"]["scan_layers"]
+    architecture = spec.load_module(root / "chipbench/architectures/smallthinker.py")
+    system = System(config, architecture, {"batch": 1, "seq": seq}, seed=0)
+    assert system.model.config.period == 4 and system.model.config.n_layers == 8
+    params = jax.eval_shape(system.init_params)
+    assert sorted(params["params"]["layers"]) == [f"block_{kind}" for kind in range(4)]
+    opt_state = jax.eval_shape(system.tx.init, params)
+    program = (
+        make_jit_fused_step(system.tx, system.loss_fn)
+        .lower(
+            _sds_tree(params, chip), _sds_tree(opt_state, chip),
+            _sds((1, seq + 1), jnp.int32, chip),
+        )
+        .compile()
+    )
+    names = [name for name, _, _ in _mosaic_calls(program)]
+    attention_calls = [n for n in names if architecture.ATTENTION_KERNEL.search(n)]
+    window_calls = [n for n in names if architecture.WINDOW_KERNEL.search(n)]
+    assert len(attention_calls) == 8 and len(window_calls) == 6, names
+    assert sum(n.startswith(flash.WINDOW_FWD) for n in window_calls) == 3
+    assert all(
+        architecture.EXPERT_KERNEL.search(n) for n in names if n not in attention_calls
+    ), names
+    assert not [n for n in attention_calls if architecture.EXPERT_KERNEL.search(n)]
+    if not widths:
+        memory = program.memory_analysis()
+        total = (
+            memory.argument_size_in_bytes + memory.output_size_in_bytes
+            + memory.temp_size_in_bytes - memory.alias_size_in_bytes
+        )
+        assert total < 14 * 2**30, total / 2**30
+
+
+@pytest.mark.slow  # four minutes: two float32 programs of eight written-out layers
+def test_the_smallthinker_cells_reference_programs_fit_the_chip(chip) -> None:
+    """The float32 reference's loss and its update at the cell's own size
+    (1 x 16,384, eight layers, attention in blocks of 512 queries a key-value
+    group), compiled for a described v5e: 3.13 and 14.06 GiB (PERF.md
+    section 6, PR 54), both inside the chip's 15.75 with the bf16 weights
+    they are given."""
+    import json
+    from pathlib import Path
+
+    from chipbench import reference, spec
+    from chipbench.model import System
+
+    root = Path(__file__).parent.parent
+    config = json.loads(
+        (root / "chipbench/configs/smallthinker-21b-a3b-ep8-1chip.json").read_text()
+    )
+    architecture = spec.load_module(root / "chipbench/architectures/smallthinker.py")
+    system = System(config, architecture, {"batch": 1, "seq": 16384}, seed=0)
+    params = _sds_tree(jax.eval_shape(system.init_params), chip)
+    tokens = _sds((1, 16385), jnp.int32, chip)
+
+    def total(compiled):
+        memory = compiled.memory_analysis()
+        return (
+            memory.argument_size_in_bytes + memory.output_size_in_bytes
+            + memory.temp_size_in_bytes - memory.alias_size_in_bytes
+        )
+
+    loss = reference.make_loss(architecture, config).lower(params, tokens).compile()
+    assert total(loss) < 4 * 2**30
+    update = reference.make_loss_after_first_update(architecture, config)
+    assert total(update.lower(params, tokens, tokens).compile()) < 14.5 * 2**30
+
+
 # The cell ``granite-4.0-h-micro-1chip.ftddp-seq8k``'s own size, and a twin at
 # toy widths for the slow marker's other side: the same head widths (64 and
 # 64), state, chunk and period of ten.
@@ -1078,11 +1229,12 @@ def test_dots_step_of_the_granite_cell_recomputes_its_period_and_fits(
 
     import torchft_tpu.ops.attention as attention
     import torchft_tpu.ops.flash_attention as flash
+    import torchft_tpu.ops.ssd as ssd
     from chipbench import spec
     from chipbench.model import System
     from torchft_tpu.optim import make_jit_fused_step
 
-    for module in (attention, flash):
+    for module in (attention, flash, ssd):
         monkeypatch.setattr(module, "on_tpu", lambda: True)
     root = Path(__file__).parent.parent
     config = json.loads((root / "chipbench/configs/granite-4.0-h-micro-1chip.json").read_text())
@@ -1102,28 +1254,32 @@ def test_dots_step_of_the_granite_cell_recomputes_its_period_and_fits(
         )
         .compile()
     )
-    names = [name for name, _, _ in _mosaic_calls(program)]
-    assert len(names) == 2 and all("tpuft__nope_attention" in n for n in names), names
+    calls = _mosaic_calls(program)
+    count = lambda name: sum(name in call for call, _, _ in calls)
+    mamba_layers = system.model.config.layer_types.count("mamba")
+    assert mamba_layers == 9 and len(calls) == 6 * mamba_layers + 2, [name for name, _, _ in calls]
+    assert count("tpuft__nope_attention") == 2
+    assert count(ssd.CONV_FWD) == count(ssd.SSD_FWD) == 2 * mamba_layers
+    assert count(ssd.CONV_BWD) == count(ssd.SSD_BWD) == mamba_layers
+    for name, stated, used in calls:  # inside the VMEM a call gets without asking
+        assert not stated and max(used, default=0) <= 16 * 2**20, (name, stated, used)
     text = program.as_text()
-    for scope in ("mamba::in_proj", "mamba::conv", "ssd::intra_chunk", "ssd::chunk_states",
-                  "ssd::inter_chunk", "ssd::state_out", "mamba::gated_norm", "mamba::out_proj"):
+    for scope in ("mamba::in_proj", "mamba::conv", "mamba::gated_norm", "mamba::out_proj"):
         assert f"tpuft::{scope}" in text, scope
-    # The reader's shapes on the program's own instructions, a second each.
-    by_scope = {"scan": [], "projections": []}
+    assert "tpuft::ssd::" not in text  # the einsum path's four parts
+    # The reader on the program's own instructions, a second each: every
+    # named call, none of the projections' fusions.
+    by_kind = {"calls": [[name, 1.0] for name, _, _ in calls if "tpuft__" not in name], "projections": []}
     for line in text.splitlines():
         found = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = \(?([a-z0-9]+\[[0-9,]*\])", line)
         op_name = re.search(r'op_name="([^"]*)"', line)
         if not found or not op_name or " fusion(" not in line and " convolution(" not in line:
             continue
-        row = [f"{found.group(1)} {found.group(2)}", 1.0]
-        if re.search(r"tpuft::ssd::|tpuft::mamba::conv", op_name.group(1)):
-            by_scope["scan"].append(row)
-        elif re.search(r"tpuft::mamba::(?:in|out)_proj", op_name.group(1)):
-            by_scope["projections"].append(row)
+        if re.search(r"tpuft::mamba::(?:in|out)_proj", op_name.group(1)):
+            by_kind["projections"].append([f"{found.group(1)} {found.group(2)}", 1.0])
     seen = lambda rows: architecture.ssd_seconds({"ops": rows}, config, 1, seq)
-    assert len(by_scope["scan"]) >= 100 and by_scope["projections"]
-    assert seen(by_scope["scan"]) >= 0.5 * len(by_scope["scan"])
-    assert seen(by_scope["projections"]) == 0.0
+    assert seen(by_kind["calls"]) == 6.0 * mamba_layers and by_kind["projections"]
+    assert seen(by_kind["projections"]) == 0.0
     if not widths:
         memory = program.memory_analysis()
         total = (

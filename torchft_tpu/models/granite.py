@@ -16,7 +16,9 @@ multipliers and a head that is the embedding.
   a depthwise causal convolution ``mamba_conv`` wide with bias; ``[x, B, C] =
   xBC``; ``dt = softplus(dt + dt_bias)``, ``A = -exp(A_log)`` by head; the
   selective scan ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T``, ``y_t = S_t C_t
-  + D x_t`` by chunks of ``mamba_chunk`` (ops/ssd.py ``ssd_scan``); then the
+  + D x_t`` by chunks of ``mamba_chunk`` (ops/ssd.py ``conv_silu`` and
+  ``ssd_scan``: Mosaic kernels on a TPU where the shapes fit them, XLA
+  einsums elsewhere; ops/ssd.py chooses, nothing here does); then the
   gate BEFORE the norm, ``RMSNorm over inner (y * silu(z))``, and ``W_outproj``.
   No bias but the convolution's. The layer sows ``ssd_chunk_log_decay``, the
   smallest and largest total log-decay of a chunk over heads
@@ -185,7 +187,7 @@ class Mamba(nn.Module):
         )
         bias = self.param("conv_bias", nn.initializers.zeros, (xbc.shape[-1],), cfg.dtype)
         with jax.named_scope("tpuft::mamba::conv"):
-            xbc = nn.silu(ssd.causal_conv(xbc, kernel, bias)).astype(cfg.dtype)
+            xbc = ssd.conv_silu(xbc, kernel, bias)  # rounded once, to cfg.dtype
         x, b_in, c_out = jnp.split(xbc, [inner, inner + shared], axis=-1)
         a_log = self.param("A_log", _a_log, (heads,), cfg.norm_dtype)
         dt_bias = self.param("dt_bias", _dt_bias, (heads,), cfg.norm_dtype)
