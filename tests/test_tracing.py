@@ -531,6 +531,8 @@ def test_phase_feeds_the_sinks_its_table_row_names(name, fake_annotations) -> No
     # The ids ride on the annotation; the free-form argument does not.
     want = {"quorum_id": 3, "fragment": 1}
     want["step_num" if spec.root else "step"] = 7
+    if spec.root:  # a root names its OS thread, for the runtime's own lines (PR 59)
+        want["tid"] = threading.get_native_id()
     assert enter[2] == want
 
 
@@ -711,6 +713,306 @@ def test_xplane_holds_the_anchors_and_bare_phase_names(tmp_path) -> None:
     mapped = begin_ns + (barrier["t_mono"] * 1e9 - begin_stats["mono_ns"])
     assert mapped == pytest.approx(found["tpuft::manager::should_commit"][1], abs=2e6)
     assert found["tpuft::manager::should_commit"][2] >= 2e6
+
+
+# -- what the runtime did under a span (PR 59) --------------------------------
+
+
+def _many_buffers(n=60):
+    """A jitted call of ``n`` input and ``n`` output arrays, compiled."""
+    import jax
+    import jax.numpy as jnp
+
+    xs = [jnp.full((8,), float(i)) for i in range(n)]
+    f = jax.jit(lambda *a: [x + 1 for x in a])
+    jax.block_until_ready(f(*xs))
+    return jax, f, xs
+
+
+def test_capture_returns_what_the_runtime_did_under_update_dispatch(tmp_path) -> None:
+    jax, f, xs = _many_buffers()
+    journal = tracing.TraceJournal(maxlen=64)
+    tracing.start_capture(str(tmp_path), journal=journal)
+    for step in range(3):
+        with tracing.phase("optim_step", journal, step=step):
+            with tracing.phase("update_dispatch", journal):
+                out = f(*xs)
+            jax.block_until_ready(out)
+    got = tracing.stop_capture()
+    json.dumps(got)  # plain data, the new key too
+    span = got["runtime"]["tpuft::optim::update_dispatch"]
+    assert span["count"] == 3 and span["seconds"] > 0
+    under = span["under"]
+    assert len([n for n in under if n != "other"]) <= 12
+    executes = {n: slot for n, slot in under.items() if "Execute" in n and n != "other"}
+    assert executes, sorted(under)
+    outermost = max(executes.values(), key=lambda slot: slot["seconds"])
+    assert 0 < outermost["seconds"] <= span["seconds"]
+    assert outermost["count"] == 3
+    for name, slot in under.items():
+        assert 0 <= slot["self_seconds"] <= slot["seconds"] + 1e-12, name
+        assert slot["seconds"] <= span["seconds"] + 1e-12, name
+        assert slot["count"] >= 1
+        if name != "other":
+            assert 0 <= slot["first_at_s"] <= span["seconds"], name
+            assert "(" not in name
+    # 120 buffers a call: more names than the table keeps, the rest summed.
+    assert set(under["other"]) == {"count", "seconds", "self_seconds"}
+    # The self times tile the span's runtime work: they sum to the outermost
+    # runtime events' inclusive seconds, which the span holds.
+    assert sum(slot["self_seconds"] for slot in under.values()) <= span["seconds"] + 1e-9
+    # The root held no runtime event of its child's.
+    root = got["runtime"]["tpuft::optim::step"]
+    assert root["count"] == 3 and not any("Execute" in n for n in root["under"])
+    assert not [e for e in got["events"] if e["name"] == "capture_runtime_unread"]
+
+
+def test_a_runtime_event_belongs_to_the_innermost_span(tmp_path) -> None:
+    jax, f, xs = _many_buffers(4)
+    journal = tracing.TraceJournal(maxlen=64)
+    tracing.start_capture(str(tmp_path), journal=journal)
+    for _ in range(2):
+        with tracing.phase("adopt", journal):
+            with tracing.phase("state_swap", journal):
+                jax.block_until_ready(f(*xs))
+    jax.block_until_ready(f(*xs))  # under no span of the program: in no table
+    got = tracing.stop_capture()
+    parent = got["runtime"]["tpuft::optim::adopt"]
+    child = got["runtime"]["tpuft::optim::state_swap"]
+    assert parent["count"] == child["count"] == 2
+    assert any("Execute" in n for n in child["under"])
+    assert not any("Execute" in n or n.startswith("PjitFunction") for n in parent["under"])
+    assert child["under"]["PjitFunction"]["count"] >= 2
+    assert set(got["runtime"]) == {
+        "tpuft::capture_begin", "tpuft::capture_end",
+        "tpuft::optim::adopt", "tpuft::optim::state_swap",
+    }
+
+
+def test_two_captures_in_a_row_do_not_mix_their_runtime_tables(tmp_path) -> None:
+    jax, f, xs = _many_buffers(4)
+    journal = tracing.TraceJournal(maxlen=64)
+    tables = []
+    for phase_name, calls in (("update_dispatch", 2), ("inner_dispatch", 3)):
+        tracing.start_capture(str(tmp_path), journal=journal)  # the same directory
+        for _ in range(calls):
+            with tracing.phase(phase_name, journal):
+                jax.block_until_ready(f(*xs))
+        tables.append(tracing.stop_capture()["runtime"])
+    first, second = tables
+    assert first["tpuft::optim::update_dispatch"]["count"] == 2
+    assert "tpuft::local_sgd::inner_dispatch" not in first
+    assert second["tpuft::local_sgd::inner_dispatch"]["count"] == 3
+    assert "tpuft::optim::update_dispatch" not in second
+
+
+@pytest.mark.parametrize("fault", ["missing", "garbage", "no_reader", "stale_only"])
+def test_an_unreadable_trace_gives_an_empty_runtime_and_raises_nothing(
+    tmp_path, monkeypatch, fault
+) -> None:
+    import jax.profiler
+
+    journal = tracing.TraceJournal(maxlen=64)
+    if fault == "stale_only":
+        # An earlier capture's file is there; this capture writes none.
+        tracing.start_capture(str(tmp_path), journal=journal)
+        with tracing.phase("update_dispatch", journal):
+            pass
+        assert tracing.stop_capture()["runtime"]
+    real_stop = jax.profiler.stop_trace
+
+    def stop_then_spoil():
+        real_stop()
+        for path in tmp_path.glob("plugins/profile/*/*.xplane.pb"):
+            if fault == "missing":
+                path.unlink()
+            elif fault == "garbage":
+                path.write_bytes(b"\xff not an xplane \x00" * 50)
+
+    tracing.start_capture(str(tmp_path), journal=journal)
+    if fault == "stale_only":
+        monkeypatch.setattr(tracing, "_xplane_files", lambda log_dir: dict(
+            tracing._capture["files"]
+        ))
+    elif fault == "no_reader":
+        monkeypatch.delattr(jax.profiler, "ProfileData")
+    else:
+        monkeypatch.setattr(jax.profiler, "stop_trace", stop_then_spoil)
+    with tracing.phase("update_dispatch", journal):
+        pass
+    got = tracing.stop_capture()
+    assert got["runtime"] == {}
+    assert [e["name"] for e in got["events"]] == ["update_dispatch"]
+    (instant,) = [e for e in journal.snapshot() if e["name"] == "capture_runtime_unread"]
+    assert instant["ph"] == "i" and instant["args"]["error"]
+    # The control is whole: the next capture runs and reads.
+    monkeypatch.undo()
+    tracing.start_capture(str(tmp_path / "next"), journal=journal)
+    with tracing.phase("update_dispatch", journal):
+        pass
+    assert tracing.stop_capture()["runtime"]["tpuft::optim::update_dispatch"]["count"] == 1
+
+
+class _FakeEvent:
+    def __init__(self, name, start_ns, duration_ns, **stats):
+        self.name, self.start_ns, self.duration_ns = name, start_ns, duration_ns
+        self.stats = list(stats.items())
+
+
+class _FakeLine:
+    def __init__(self, events, name="thread"):
+        self.name, self.events = name, events
+
+
+class _FakePlane:
+    def __init__(self, name, lines):
+        self.name, self.lines = name, lines
+
+
+def _fake_profile(monkeypatch, planes):
+    import jax.profiler
+
+    class Data:
+        @staticmethod
+        def from_file(path):
+            return type("D", (), {"planes": planes})()
+
+    monkeypatch.setattr(jax.profiler, "ProfileData", Data)
+
+
+def test_runtime_table_arithmetic_on_a_hand_made_line(monkeypatch) -> None:
+    """Two dispatches on one thread line (events out of order, as a file may
+    hold them), a runtime event outside any span, a span on a device plane
+    and a line with no span of the program."""
+    E = _FakeEvent
+    main = [
+        E("tpuft::optim::step", 0, 10_000),
+        E("tpuft::optim::update_dispatch", 1_000, 5_000),
+        E("PjitFunction(fused)", 1_100, 4_800),
+        E("PjitFunction(fused)", 1_200, 4_600),  # its own name inside it
+        E("ParseArguments", 1_300, 200),
+        E("Runtime::Execute", 2_000, 3_000),
+        E("Wait for holds", 2_100, 100),
+        E("Wait for holds", 2_300, 100),
+        E("chipbench/inner", 2_500, 300),  # the benchmark's: in no table
+        E("Wait for holds", 2_600, 100),  # ... but what is under it still is
+        E("GC", 7_000, 500),  # under the root, not the dispatch
+        E("tpuft::optim::step", 20_000, 10_000),
+        E("Runtime::Execute", 23_000, 1_000),
+        E("tpuft::optim::update_dispatch", 22_000, 3_000),
+        E("PjitFunction(fused)", 22_500, 2_000),
+        E("outside", 40_000, 100),
+    ]
+    other_thread = [E("Runtime::Execute", 1_000, 9_000)]
+    device = [E("tpuft::optim::update_dispatch", 0, 1_000_000)]
+    _fake_profile(monkeypatch, [
+        _FakePlane("/device:TPU:0", [_FakeLine(device)]),
+        _FakePlane("/host:CPU", [_FakeLine(main), _FakeLine(other_thread)]),
+    ])
+    table = tracing._runtime_under_spans("ignored")
+    assert set(table) == {"tpuft::optim::step", "tpuft::optim::update_dispatch"}
+    root, span = table["tpuft::optim::step"], table["tpuft::optim::update_dispatch"]
+    assert root == {"count": 2, "seconds": pytest.approx(20e-6), "under": {"GC": {
+        "count": 1, "seconds": pytest.approx(0.5e-6), "self_seconds": pytest.approx(0.5e-6),
+        "first_at_s": pytest.approx(7e-6),
+    }}}
+    assert span["count"] == 2 and span["seconds"] == pytest.approx(8e-6)
+    under = span["under"]
+    assert list(under) == ["PjitFunction", "Runtime::Execute", "Wait for holds", "ParseArguments"]
+    # Inclusive seconds count the outer of two nested events of one name.
+    assert under["PjitFunction"]["count"] == 3
+    assert under["PjitFunction"]["seconds"] == pytest.approx((4_800 + 2_000) * 1e-9)
+    # Self: outer 4800 - 4600, inner 4600 - 200 - 3000, second 2000 - 1000.
+    assert under["PjitFunction"]["self_seconds"] == pytest.approx((200 + 1_400 + 1_000) * 1e-9)
+    assert under["PjitFunction"]["first_at_s"] == pytest.approx((100 + 500) / 2 * 1e-9)
+    execute = under["Runtime::Execute"]
+    assert execute["count"] == 2 and execute["seconds"] == pytest.approx(4_000e-9)
+    # Less the two waits directly in it and the benchmark's span (with its wait).
+    assert execute["self_seconds"] == pytest.approx((3_000 - 200 - 300 + 1_000) * 1e-9)
+    assert execute["first_at_s"] == pytest.approx((1_000 + 1_000) / 2 * 1e-9)
+    assert under["Wait for holds"] == {
+        "count": 3, "seconds": pytest.approx(300e-9), "self_seconds": pytest.approx(300e-9),
+        "first_at_s": pytest.approx(1_100e-9),
+    }
+    # Self times tile the outermost runtime events (less the benchmark's span's own).
+    assert sum(s["self_seconds"] for s in under.values()) == pytest.approx(
+        (4_800 - 200 + 2_000) * 1e-9
+    )
+
+
+def test_runtime_table_lays_the_runtimes_own_line_into_its_thread(monkeypatch) -> None:
+    """The TPU's PJRT plugin records its events on a line of its own, named
+    ``<thread name>/<OS thread id>``; the root's ``tid`` says whose it is. A
+    line of another thread's id, and one with no id, stay out."""
+    E = _FakeEvent
+    python = [
+        E("tpuft::optim::step", 0, 30_000, step_num=3, tid=557),
+        E("tpuft::optim::update_dispatch", 1_000, 24_000, step=3),
+        E("PjitFunction(fused)", 1_010, 23_900),
+        E("PJRT_LoadedExecutable_Execute linkage", 1_500, 1),
+    ]
+    plugin = [
+        E("PJRT_LoadedExecutable_Execute", 1_505, 22_900),
+        E("CommonPjRtLoadedExecutable::Execute", 1_510, 22_800),
+        E("AllocateRawBuffer", 1_600, 60), E("AllocateRawBuffer", 1_700, 60),
+        E("MemoryDeallocation", 26_000, 40),  # under the root, after the dispatch
+    ]
+    worker = [E("EnqueueProgram", 2_000, 500)]
+    quorum = [E("tpuft::manager::should_commit", 2_000, 300, step=3), E("rpc", 2_100, 100)]
+    _fake_profile(monkeypatch, [_FakePlane("/host:CPU", [
+        _FakeLine(python, "python"), _FakeLine(plugin, "main/557"),
+        _FakeLine(worker, "tfrt-non-blocking-queue/616"), _FakeLine(quorum, "python"),
+    ])])
+    table = tracing._runtime_under_spans("ignored")
+    under = table["tpuft::optim::update_dispatch"]["under"]
+    assert list(under) == [
+        "PjitFunction", "PJRT_LoadedExecutable_Execute", "CommonPjRtLoadedExecutable::Execute",
+        "AllocateRawBuffer", "PJRT_LoadedExecutable_Execute linkage",
+    ]
+    assert under["PJRT_LoadedExecutable_Execute"]["first_at_s"] == pytest.approx(505e-9)
+    assert under["PjitFunction"]["self_seconds"] == pytest.approx((23_900 - 1 - 22_900) * 1e-9)
+    assert under["CommonPjRtLoadedExecutable::Execute"]["self_seconds"] == pytest.approx(
+        (22_800 - 120) * 1e-9
+    )
+    assert under["AllocateRawBuffer"]["count"] == 2
+    assert list(table["tpuft::optim::step"]["under"]) == ["MemoryDeallocation"]
+    assert list(table["tpuft::manager::should_commit"]["under"]) == ["rpc"]
+    assert "EnqueueProgram" not in str(table)
+
+
+def test_a_root_and_the_captures_marks_name_their_os_thread(tmp_path) -> None:
+    from jax.profiler import ProfileData
+
+    journal = tracing.TraceJournal(maxlen=16)
+    tracing.start_capture(str(tmp_path), journal=journal)
+    with tracing.phase("optim_step", journal, step=5):
+        with tracing.phase("update_dispatch", journal):
+            pass
+    tracing.stop_capture()
+    (path,) = (tmp_path / "plugins" / "profile").glob("*/*.xplane.pb")
+    stats = {
+        event.name: dict(event.stats)
+        for plane in ProfileData.from_file(str(path)).planes
+        for line in plane.lines for event in line.events if event.name.startswith("tpuft::")
+    }
+    tid = threading.get_native_id()
+    assert stats["tpuft::optim::step"]["tid"] == tid
+    assert stats["tpuft::capture_begin"]["tid"] == stats["tpuft::capture_end"]["tid"] == tid
+    assert "tid" not in stats["tpuft::optim::update_dispatch"]
+
+
+def test_runtime_table_keeps_twelve_names_and_sums_the_rest(monkeypatch) -> None:
+    E = _FakeEvent
+    events = [E("tpuft::optim::update_dispatch", 0, 100_000)]
+    for i in range(15):  # op 0 the longest
+        events.append(E(f"op.{i}", 1_000 + 5_000 * i, 1_500 - 100 * i))
+    _fake_profile(monkeypatch, [_FakePlane("/host:CPU", [_FakeLine(events)])])
+    under = tracing._runtime_under_spans("ignored")["tpuft::optim::update_dispatch"]["under"]
+    assert list(under) == [f"op.{i}" for i in range(12)] + ["other"]
+    rest = sum(1_500 - 100 * i for i in (12, 13, 14)) * 1e-9
+    assert under["other"] == {
+        "count": 3, "seconds": pytest.approx(rest), "self_seconds": pytest.approx(rest),
+    }
 
 
 def test_two_captures_in_one_process_through_the_harness(tmp_path, monkeypatch) -> None:

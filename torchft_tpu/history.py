@@ -55,7 +55,7 @@ import threading
 from collections import OrderedDict
 from typing import Any, Callable, Dict, List, Optional, Set, Tuple
 
-from torchft_tpu import metrics
+from torchft_tpu import metrics, tracing
 
 __all__ = [
     "WeightHistory",
@@ -133,7 +133,11 @@ class WeightHistory:
         max_versions: Optional[int] = None,
         max_bytes: Optional[int] = None,
         ring: str = "state",
+        journal: Optional[tracing.TraceJournal] = None,
     ) -> None:
+        # Where the ring's one span (``history_evict``) lands: its manager's
+        # journal (None: the evicting thread's current one).
+        self._journal = journal
         self._max_versions = history_max_versions(
             max_versions if max_versions is not None else 1
         )
@@ -274,9 +278,14 @@ class WeightHistory:
                 return total > self._max_bytes
             return False
 
-        while len(self._entries) > 1 and over_budget():
-            self._entries.popitem(last=False)  # oldest; newest never goes
-            metrics.inc("tpuft_history_evictions_total")
+        if not (len(self._entries) > 1 and over_budget()):
+            return
+        # The span is where a version's buffers are released, when this
+        # ring held the last reference to them; opened only when one leaves.
+        with tracing.phase("history_evict", self._journal):
+            while len(self._entries) > 1 and over_budget():
+                self._entries.popitem(last=False)  # oldest; newest never goes
+                metrics.inc("tpuft_history_evictions_total")
 
     def _publish_gauges_locked(self) -> None:
         metrics.set_gauge(

@@ -530,7 +530,9 @@ class Manager:
             if self._commit_pipeline_adaptive
             else self._commit_pipeline_depth
         )
-        self._history = WeightHistory(max_versions=window + 1)
+        self._history = WeightHistory(
+            max_versions=window + 1, journal=tracing.current()
+        )
 
         # Per-step error/heal state.
         self._errored: Optional[ExceptionWithTraceback] = None

@@ -37,7 +37,6 @@ import numpy as np
 from torchft_tpu import health, metrics, tracing
 from torchft_tpu.manager import Manager
 from torchft_tpu.utils import schedules
-from torchft_tpu.utils.profiling import trace_span
 
 logger = logging.getLogger(__name__)
 
@@ -74,6 +73,18 @@ def _trace_of(manager: Any) -> "tracing.TraceJournal":
     per-replica timeline its manager records into), falling back to the
     thread's current journal for scripted/mocked managers."""
     return getattr(manager, "_trace", None) or tracing.current()
+
+
+def _count_dispatch(buffers_in: int, buffers_out: int) -> None:
+    """One call of a jitted step program and the buffers (array leaves) it
+    hands to the runtime and takes back. The host cost of a dispatch grows
+    with them, so beside the ``update_dispatch`` span's seconds they give
+    a cost per buffer. The counts are the step's static ones, taken where
+    the state's structure is set (:meth:`Optimizer._note_state_structure`);
+    no tree is walked here."""
+    metrics.inc("tpuft_step_dispatch_total")
+    metrics.inc("tpuft_step_dispatch_buffers_total", buffers_in, direction="in")
+    metrics.inc("tpuft_step_dispatch_buffers_total", buffers_out, direction="out")
 
 
 def _sync_device(x: Any) -> Any:
@@ -431,6 +442,15 @@ class Optimizer:
         self._heal_count = 0
         self._register_key = register_key
         self.opt_state = self._init_state(tx, params)
+        # What the step reads of the state's structure without walking it:
+        # (params leaves, opt_state leaves), the era they were counted in,
+        # (era, bytes) of one (params, opt_state) pair once a snapshot was
+        # measured, and (arguments, leaves) of a step function's batch.
+        self._state_era = 0
+        self._state_leaves = (0, 0)
+        self._state_nbytes: Optional[tuple] = None
+        self._batch_leaves: Optional[tuple] = None
+        self._note_state_structure()
         manager.register_state_dict_fn(
             register_key, self._load_state_dict, self._state_dict
         )
@@ -458,6 +478,26 @@ class Optimizer:
         rollback, heal re-binding) treats ``opt_state`` as opaque."""
         return _align_opt_state(tx.init(params), params)
 
+    def _note_state_structure(self) -> None:
+        """Called wherever the owned state's structure is set (construction,
+        a heal's ``_load_state_dict``, a ZeRO re-balance): counts the leaves
+        a dispatch hands over and opens a new era, so the next
+        :meth:`_snapshot_nbytes` measures the state again. Between two
+        calls every step reuses both."""
+        leaves = jax.tree_util.tree_leaves
+        self._state_leaves = (len(leaves(self.params)), len(leaves(self.opt_state)))
+        self._state_era += 1
+
+    def _batch_buffers(self, batch: Any) -> int:
+        """Leaves of a step function's batch: counted at its first call, and
+        again only when a call passes another number of arguments."""
+        known = self._batch_leaves
+        if known is None or known[0] != len(batch):
+            known = self._batch_leaves = (
+                len(batch), len(jax.tree_util.tree_leaves(batch)),
+            )
+        return known[1]
+
     def _state_dict(self) -> Any:
         return {"params": self.params, "opt_state": self.opt_state}
 
@@ -467,6 +507,7 @@ class Optimizer:
         # reassembled locally (each rank received its own shards).
         self.params = _as_device_tree(state["params"], like=self.params)
         self.opt_state = _as_device_tree(state["opt_state"], like=self.opt_state)
+        self._note_state_structure()
         # Any speculative update dispatched before this heal is stale.
         self._heal_count += 1
 
@@ -537,6 +578,8 @@ class Optimizer:
                     barrier_result,
                 )
             raise
+        n_params, n_opt = self._state_leaves
+        _count_dispatch(2 * n_params + n_opt, n_params + n_opt)
         return self._commit_and_adopt(
             heal_count,
             spec,
@@ -572,14 +615,15 @@ class Optimizer:
         if not committed:
             return False
         with tracing.phase("adopt", trace):
-            self.manager.disallow_state_dict_read()
-            try:
-                if self._heal_count != heal_count:
-                    self.params, self.opt_state = recompute()
-                else:
-                    self.params, self.opt_state = speculation
-            finally:
-                self.manager.allow_state_dict_read()
+            with tracing.phase("state_swap", trace):
+                self.manager.disallow_state_dict_read()
+                try:
+                    if self._heal_count != heal_count:
+                        self.params, self.opt_state = recompute()
+                    else:
+                        self.params, self.opt_state = speculation
+                finally:
+                    self.manager.allow_state_dict_read()
             # Promote the just-committed state into the manager's history
             # ring (refs only — immutable trees make holding a reference a
             # true snapshot). The barrier already advanced the step counter.
@@ -599,31 +643,37 @@ class Optimizer:
         return value if isinstance(value, int) else None
 
     def _promote_committed(
-        self, step: Optional[int], params: Any, opt_state: Any
+        self, step: Optional[int], params: Any, opt_state: Any,
+        span_step: Optional[int] = None,
     ) -> None:
         """Hands one committed step's ``(params, opt_state)`` refs to the
         manager's history ring — the slot promotion that replaces simply
         dropping resolved window snapshots. Best-effort: history is an
         availability plane (exact deep-window heals, pinned serving);
-        its bookkeeping must never wound a commit."""
+        its bookkeeping must never wound a commit. ``span_step`` is the
+        step its ``history_promote`` span belongs to (the enclosing
+        ``adopt``'s; None: the step's root's)."""
         if step is None:
             return
-        hist = getattr(self.manager, "history", None)
-        try:
-            from torchft_tpu.history import WeightHistory
+        with tracing.phase(
+            "history_promote", _trace_of(self.manager), step=span_step
+        ):
+            hist = getattr(self.manager, "history", None)
+            try:
+                from torchft_tpu.history import WeightHistory
 
-            if not isinstance(hist, WeightHistory):
-                return  # scripted/mocked managers without a real ring
-            state = {"params": params, "opt_state": opt_state}
-            hist.note_state(
-                self._register_key,
-                step,
-                state,
-                nbytes=self._snapshot_nbytes((params, opt_state)),
-                quorum_id=getattr(self.manager, "_quorum_id", None),
-            )
-        except Exception:  # noqa: BLE001 — bookkeeping must not wound a step
-            logger.exception("history promotion failed (ignored)")
+                if not isinstance(hist, WeightHistory):
+                    return  # scripted/mocked managers without a real ring
+                state = {"params": params, "opt_state": opt_state}
+                hist.note_state(
+                    self._register_key,
+                    step,
+                    state,
+                    nbytes=self._snapshot_nbytes((params, opt_state)),
+                    quorum_id=getattr(self.manager, "_quorum_id", None),
+                )
+            except Exception:  # noqa: BLE001 — bookkeeping must not wound a step
+                logger.exception("history promotion failed (ignored)")
 
     def _post_commit_state(self, rec: "_PendingStep") -> Any:
         """The committed state AFTER ``rec``'s step: the next younger
@@ -651,12 +701,22 @@ class Optimizer:
         return len(self._pipeline) if self._pipeline is not None else 0
 
     def _snapshot_nbytes(self, snapshot: Any) -> int:
-        """Approximate resident bytes of one rollback snapshot (device
-        array leaves by ``nbytes``; opaque states that expose
-        ``owned_bytes`` — the ZeRO shard state — by that). Feeds the
+        """Approximate resident bytes of one rollback snapshot, a
+        ``(params, opt_state)`` pair of the owned state (device array
+        leaves by ``nbytes``; opaque states that expose ``owned_bytes`` —
+        the ZeRO shard state — by that). Feeds the
         ``tpuft_pipeline_snapshot_bytes`` gauge: the window holds one
         (params, opt_state) copy per slot, which is THE memory cost of
-        deepening it (the doctor's depth probe states the formula)."""
+        deepening it (the doctor's depth probe states the formula).
+
+        Every pair of one structure weighs the same, so the state is walked
+        once an era (:meth:`_note_state_structure`), not once a committed
+        step. The era is read BEFORE the walk: a heal that lands meanwhile
+        leaves an entry no later step reads."""
+        era = self._state_era
+        known = self._state_nbytes
+        if known is not None and known[0] == era:
+            return known[1]
         total = 0
         try:
             for leaf in jax.tree_util.tree_leaves(
@@ -664,11 +724,13 @@ class Optimizer:
             ):
                 owned = getattr(leaf, "owned_bytes", None)
                 if owned is not None:
-                    total += int(owned)
+                    # ZeroState's is a method.
+                    total += int(owned() if callable(owned) else owned)
                 else:
                     total += int(getattr(leaf, "nbytes", 0) or 0)
         except Exception:  # noqa: BLE001 — a gauge must never wound a step
             return 0
+        self._state_nbytes = (era, total)
         return total
 
     def _note_snapshot(self, rec: "_PendingStep", admitted: bool) -> None:
@@ -730,16 +792,13 @@ class Optimizer:
                 )
                 rec.committed = False
                 return False
-            with trace_span(
-                "tpuft::optim::resolve_pipelined_commit",
-                step=self.manager.current_step(),
-            ):
-                trace = _trace_of(self.manager)
-                with tracing.phase("commit_wait", trace, step=rec.claimed_step):
-                    committed = rec.commit_future.result()
-                rolled_back = False
-                discarded = 0
-                with tracing.phase("adopt", trace, step=rec.claimed_step):
+            trace = _trace_of(self.manager)
+            with tracing.phase("commit_wait", trace, step=rec.claimed_step):
+                committed = rec.commit_future.result()
+            rolled_back = False
+            discarded = 0
+            with tracing.phase("adopt", trace, step=rec.claimed_step):
+                with tracing.phase("state_swap", trace, step=rec.claimed_step):
                     self.manager.disallow_state_dict_read()
                     try:
                         if self._heal_count != rec.heal_count:
@@ -794,46 +853,47 @@ class Optimizer:
                             if self._heal_count != rec.heal_count
                             else self._post_commit_state(rec)
                         ),
+                        span_step=rec.claimed_step,
                     )
-                if rolled_back:
-                    # Incident capture runs OUTSIDE the writer: dumping
-                    # journals is file I/O a concurrent checkpoint serve
-                    # must not wait on. Quorum-wide refusal means every
-                    # survivor rolls this step back identically and derives
-                    # the SAME incident id — the fleet's journals + flight
-                    # recorders dump under one correlatable stamp.
-                    journal = _trace_of(self.manager)
-                    rolled_step = self.manager.current_step()
-                    rolled_quorum = getattr(self.manager, "_quorum_id", -1)
-                    journal.record(
-                        "rollback",
-                        step=rolled_step,
-                        quorum_id=rolled_quorum,
-                        unwound_to=rolled_step,
-                        discarded=discarded,
-                    )
-                    tracing.open_incident(
-                        "rollback", rolled_step, rolled_quorum,
-                        journal=journal,
-                        reason="speculative step refused by the commit barrier",
-                    )
-                    # Serving plane: an unwind retracts any due-but-
-                    # unpublished version newer than the surviving
-                    # committed step — a discarded speculation must never
-                    # surface to readers (published versions are post-
-                    # barrier and final, so this is the only window).
-                    publisher = getattr(self.manager, "_publisher", None)
-                    if publisher is not None:
-                        publisher.retract_after(rolled_step)
-                    # History ring: drop anything newer than the surviving
-                    # committed step (belt-and-braces — refused steps were
-                    # never promoted, but the ring must stay provably on
-                    # the committed trajectory).
-                    hist = getattr(self.manager, "_history", None)
-                    if hist is not None and hasattr(hist, "retract_newer"):
-                        hist.retract_newer(rolled_step)
-                rec.committed = committed
-                return committed
+            if rolled_back:
+                # Incident capture runs OUTSIDE the writer: dumping
+                # journals is file I/O a concurrent checkpoint serve
+                # must not wait on. Quorum-wide refusal means every
+                # survivor rolls this step back identically and derives
+                # the SAME incident id — the fleet's journals + flight
+                # recorders dump under one correlatable stamp.
+                journal = _trace_of(self.manager)
+                rolled_step = self.manager.current_step()
+                rolled_quorum = getattr(self.manager, "_quorum_id", -1)
+                journal.record(
+                    "rollback",
+                    step=rolled_step,
+                    quorum_id=rolled_quorum,
+                    unwound_to=rolled_step,
+                    discarded=discarded,
+                )
+                tracing.open_incident(
+                    "rollback", rolled_step, rolled_quorum,
+                    journal=journal,
+                    reason="speculative step refused by the commit barrier",
+                )
+                # Serving plane: an unwind retracts any due-but-
+                # unpublished version newer than the surviving
+                # committed step — a discarded speculation must never
+                # surface to readers (published versions are post-
+                # barrier and final, so this is the only window).
+                publisher = getattr(self.manager, "_publisher", None)
+                if publisher is not None:
+                    publisher.retract_after(rolled_step)
+                # History ring: drop anything newer than the surviving
+                # committed step (belt-and-braces — refused steps were
+                # never promoted, but the ring must stay provably on
+                # the committed trajectory).
+                hist = getattr(self.manager, "_history", None)
+                if hist is not None and hasattr(hist, "retract_newer"):
+                    hist.retract_newer(rolled_step)
+            rec.committed = committed
+            return committed
 
     def flush_pipeline(self, raise_on_error: bool = True) -> Optional[bool]:
         """Resolves every pending pipelined step (vote + rollback + device
@@ -1048,6 +1108,8 @@ class Optimizer:
             loss, spec_params, spec_opt_state = fused(
                 self.params, self.opt_state, *batch
             )
+        n_state = sum(self._state_leaves)
+        _count_dispatch(n_state + self._batch_buffers(batch), 1 + n_state)
 
         def recompute():
             # Same semantics as :meth:`step` (and the reference's

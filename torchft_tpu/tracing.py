@@ -58,12 +58,14 @@ docs/observability.md has the span tree.
 
 from __future__ import annotations
 
+import glob
 import itertools
 import json
 import logging
 import os
 import threading
 import time
+import warnings
 from contextlib import contextmanager
 from typing import (
     Any, Callable, Deque, Dict, Generator, List, NamedTuple, Optional,
@@ -543,6 +545,14 @@ PHASES: Dict[str, _PhaseSpec] = {
     ),
     "commit_wait": _PhaseSpec("commit_wait", "tpuft::optim::commit_wait"),
     "adopt": _PhaseSpec("adopt", "tpuft::optim::adopt"),
+    # ``adopt``'s children, opened where the work happens: the write-locked
+    # assignment of the new state (a wait for a reader of the state dict
+    # shows here), the hand-over to the history ring, and inside it the
+    # ring's release of the versions that leave (history.py; only when one
+    # does).
+    "state_swap": _PhaseSpec("state_swap", "tpuft::optim::state_swap"),
+    "history_promote": _PhaseSpec("history_promote", "tpuft::history::promote"),
+    "history_evict": _PhaseSpec("history_evict", "tpuft::history::evict"),
     "pipeline_drain": _PhaseSpec(
         "pipeline_drain", "tpuft::optim::pipeline_drain", rollup=True
     ),
@@ -685,9 +695,11 @@ class _Span:
                         # is not the manager's (that counts committed syncs).
                         ids = dict(self._ids)
                         step = ids.pop("step", 0)
+                        # ``tid``: see :func:`_runtime_under_spans`.
                         self._annotation = types[1](
                             spec.annotation,
-                            step_num=self._args.get("inner_step", step), **ids,
+                            step_num=self._args.get("inner_step", step),
+                            tid=threading.get_native_id(), **ids,
                         )
                     else:
                         self._annotation = types[0](spec.annotation, **self._ids)
@@ -784,13 +796,153 @@ def _registry_totals() -> Dict[tuple, Dict[str, float]]:
 def _capture_mark(name: str) -> int:
     """One instant annotation carrying the monotonic clock's reading: the
     same instant on the profiler's clock (the annotation's start) and on the
-    journal's (``t_mono`` seconds = ``mono_ns`` / 1e9)."""
+    journal's (``t_mono`` seconds = ``mono_ns`` / 1e9). And the OS thread's
+    id, as a step's root does: see :func:`_runtime_under_spans`."""
     mono_ns = time.monotonic_ns()
     types = _annotations()
     if types:
-        with types[0](name, mono_ns=mono_ns):
+        with types[0](name, mono_ns=mono_ns, tid=threading.get_native_id()):
             pass
     return mono_ns
+
+
+def _xplane_files(log_dir: str) -> Dict[str, int]:
+    """{path: mtime_ns} of the profiler's files under ``log_dir``."""
+    found: Dict[str, int] = {}
+    pattern = os.path.join(log_dir, "plugins", "profile", "*", "*.xplane.pb")
+    for path in glob.glob(pattern):
+        try:
+            found[path] = os.stat(path).st_mtime_ns
+        except OSError:
+            pass
+    return found
+
+
+# Of the names under one span the table keeps these many, by inclusive
+# seconds; the rest is summed as "other".
+_RUNTIME_NAMES_KEPT = 12
+
+
+class _OpenSpan(NamedTuple):
+    """An open ``tpuft::`` annotation while its line is walked: its table in
+    the result, its start and the runtime names seen under it so far."""
+
+    table: Dict[str, Any]
+    start_ns: int
+    seen: set
+
+
+class _OpenEvent(NamedTuple):
+    """An open event of a line: where it ends, its name, the innermost
+    ``tpuft::`` annotation around it (itself, for an annotation) and, for a
+    runtime event, its name's slot in that annotation's table."""
+
+    end_ns: int
+    name: str
+    owner: Optional[_OpenSpan]
+    slot: Optional[Dict[str, Any]]
+
+
+def _thread_id(line: Any) -> Optional[int]:
+    """The OS thread id a root or a capture mark on this line carries."""
+    with warnings.catch_warnings():
+        # jaxlib's stats type lacks ``__module__``, which Python warns of.
+        warnings.simplefilter("ignore", DeprecationWarning)
+        for event in line.events:
+            if event.name.startswith("tpuft::"):
+                tid = dict(event.stats).get("tid")
+                if tid is not None:
+                    return int(tid)
+    return None
+
+
+def _runtime_under_spans(path: str) -> Dict[str, Any]:
+    """What the runtime did under each of the program's spans, from the
+    xplane a capture wrote: on every host thread line, each event that is
+    neither the program's (``tpuft::``) nor the benchmark's (``chipbench/``)
+    goes to the INNERMOST ``tpuft::`` annotation that contains it on that
+    line. ``{annotation: {"count", "seconds", "under": {name: {"count",
+    "seconds", "self_seconds", "first_at_s"}}}}``: a name is the event's cut
+    at ``(``; ``seconds`` is inclusive (an event inside one of its own name
+    adds to ``count`` alone, so a name never holds more than its span),
+    ``self_seconds`` is less the events nested in it, ``first_at_s`` is the
+    mean offset of the name's first start from its span's start. No event
+    lists: a tail of 16 steps holds 11,000 waits for holds.
+
+    One thread may have two lines: a runtime that records its own events
+    (the TPU's PJRT plugin) writes them to a line of its own, named
+    ``<thread name>/<OS thread id>``, beside the line that holds the
+    annotations. A step's root and the capture's marks carry ``tid``, the OS
+    thread id, as a stat, so such a line is laid into its thread's before
+    the walk; on the CPU there is none."""
+    from jax.profiler import ProfileData
+
+    spans: Dict[str, Dict[str, Any]] = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        lines = [
+            (line.name, [(e.start_ns, e.duration_ns, e.name) for e in line.events], line)
+            for line in plane.lines
+        ]
+        for _, events, line in lines:
+            if not any(name.startswith("tpuft::") for _, _, name in events):
+                continue
+            tid = _thread_id(line)
+            if tid is not None:
+                events = events + [
+                    e for other, guest, _ in lines
+                    if other.endswith(f"/{tid}") for e in guest
+                    if not e[2].startswith("tpuft::")
+                ]
+            events.sort(key=lambda e: (e[0], -e[1]))
+            stack: List[_OpenEvent] = []  # outermost first
+            for start, dur, name in events:
+                while stack and stack[-1].end_ns <= start:
+                    stack.pop()
+                seconds = dur * 1e-9
+                if stack and stack[-1].slot is not None:
+                    stack[-1].slot["self_seconds"] -= seconds
+                if name.startswith("tpuft::"):
+                    table = spans.setdefault(
+                        name, {"count": 0, "seconds": 0.0, "under": {}}
+                    )
+                    table["count"] += 1
+                    table["seconds"] += seconds
+                    owner = _OpenSpan(table, start, set())
+                    stack.append(_OpenEvent(start + dur, name, owner, None))
+                    continue
+                owner = stack[-1].owner if stack else None
+                if owner is None or name.startswith("chipbench/"):
+                    stack.append(_OpenEvent(start + dur, name, owner, None))
+                    continue
+                name = name.split("(")[0].strip()
+                slot = owner.table["under"].setdefault(
+                    name, {"count": 0, "seconds": 0.0, "self_seconds": 0.0,
+                           "first_at_s": 0.0, "spans": 0},
+                )
+                slot["count"] += 1
+                slot["self_seconds"] += seconds
+                if not any(o.name == name and o.owner is owner for o in stack):
+                    slot["seconds"] += seconds
+                if name not in owner.seen:
+                    owner.seen.add(name)
+                    slot["spans"] += 1
+                    slot["first_at_s"] += (start - owner.start_ns) * 1e-9
+                stack.append(_OpenEvent(start + dur, name, owner, slot))
+    for table in spans.values():
+        under = table["under"]
+        for slot in under.values():
+            slot["first_at_s"] /= slot.pop("spans")
+        ranked = sorted(under, key=lambda n: -under[n]["seconds"])
+        table["under"] = {n: under[n] for n in ranked[:_RUNTIME_NAMES_KEPT]}
+        rest = ranked[_RUNTIME_NAMES_KEPT:]
+        if rest:
+            table["under"]["other"] = {
+                key: sum(under[n][key] for n in rest)
+                for key in ("count", "seconds", "self_seconds")
+            }
+    return spans
 
 
 def start_capture(
@@ -814,12 +966,14 @@ def start_capture(
                 f"a capture into {_capture['trace_dir']} is already running; "
                 "stop_capture() it first"
             )
+        files = _xplane_files(str(log_dir))
         options = jax.profiler.ProfileOptions()
         options.host_tracer_level = host_level
         options.python_tracer_level = python_level  # the spans, not every Python frame
         jax.profiler.start_trace(str(log_dir), profiler_options=options)
         _capture = {
             "trace_dir": str(log_dir),
+            "files": files,
             "journal": j,
             "seq": j._last_seq,
             "totals": _registry_totals(),
@@ -833,8 +987,13 @@ def stop_capture() -> Dict[str, Any]:
     ``counters``, the growth of every histogram (``sum``, ``count``) and
     counter (``value``) over it, ``{name: [{"labels": {...}, ...}]}`` with
     what did not grow left out; ``clock``, the monotonic clock at the
-    ``tpuft::capture_begin`` / ``tpuft::capture_end`` annotations; and
-    ``dropped``, events of the capture the ring had already overwritten."""
+    ``tpuft::capture_begin`` / ``tpuft::capture_end`` annotations;
+    ``dropped``, events of the capture the ring had already overwritten; and
+    ``runtime``, what the runtime did under each of the program's spans
+    (:func:`_runtime_under_spans` of the xplane this capture wrote, on the
+    profiler's clock, which is the device ops'). It is read here, after the
+    capture has stopped; a trace that cannot be read gives ``{}`` and a
+    journal instant ``capture_runtime_unread``, never an exception."""
     global _capture
     import jax.profiler
 
@@ -853,12 +1012,26 @@ def stop_capture() -> Dict[str, Any]:
         growth = {k: v - was.get(k, 0.0) for k, v in now.items()}
         if any(growth.values()):
             counters.setdefault(name, []).append({"labels": dict(labels), **growth})
+    dropped = max(0, (j._last_seq - cap["seq"]) - len(events))
+    runtime: Dict[str, Any] = {}
+    try:
+        was = cap["files"]
+        wrote = {
+            path: mtime for path, mtime in _xplane_files(cap["trace_dir"]).items()
+            if was.get(path) != mtime
+        }
+        if not wrote:
+            raise FileNotFoundError(f"no new *.xplane.pb under {cap['trace_dir']}")
+        runtime = _runtime_under_spans(max(wrote, key=wrote.__getitem__))
+    except Exception as e:  # noqa: BLE001 — observability must not wound
+        j.record("capture_runtime_unread", cat="capture", error=repr(e))
     return {
         "trace_dir": cap["trace_dir"],
         "events": events,
         "counters": counters,
         "clock": {"begin_mono_ns": cap["begin_mono_ns"], "end_mono_ns": end_mono_ns},
-        "dropped": max(0, (j._last_seq - cap["seq"]) - len(events)),
+        "dropped": dropped,
+        "runtime": runtime,
     }
 
 

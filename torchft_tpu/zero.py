@@ -81,6 +81,7 @@ from torchft_tpu.manager import Manager
 from torchft_tpu.optim import (
     Optimizer,
     _as_device_tree,
+    _count_dispatch,
     _replica_labels,
     _sync_device,
     _trace_of,
@@ -463,6 +464,7 @@ class ZeroOptimizer(Optimizer):
             balance_key=None,  # force a re-balance under the new quorum
             ever_balanced=self.opt_state.ever_balanced,
         )
+        self._note_state_structure()
         self._heal_count += 1
 
     # ------------------------------------------------------------------
@@ -678,6 +680,7 @@ class ZeroOptimizer(Optimizer):
             )
         finally:
             self.manager.allow_state_dict_read()
+        self._note_state_structure()  # the held shards changed
         metrics.inc("tpuft_zero_rebalance_total", **labels)
         metrics.set_gauge("tpuft_zero_owned_shards", len(held), **labels)
 
@@ -912,12 +915,16 @@ class ZeroOptimizer(Optimizer):
         new_held: Dict[int, _ShardState] = dict(pre_state.held)
         updated_masters: Dict[int, Any] = {}
         if ids:
-            with metrics.timer("tpuft_update_dispatch_seconds"):
+            with tracing.phase("update_dispatch", _trace_of(self.manager)):
                 new_masters, new_opts = self._jit_shard_update(
                     [jnp.asarray(avg_blocks[s]) for s in ids],
                     [pre_state.held[s].opt for s in ids],
                     [pre_state.held[s].master for s in ids],
                 )
+            # A shard hands over its gradient range, its master and its
+            # optax state, and takes the last two back.
+            n_opt = len(self._opt_leaf_templates)
+            _count_dispatch(len(ids) * (2 + n_opt), len(ids) * (1 + n_opt))
             for slot, s in enumerate(ids):
                 new_held[s] = _ShardState(
                     step=pre_state.step + 1,
@@ -974,8 +981,10 @@ class ZeroOptimizer(Optimizer):
         self._maybe_rebalance()
         pre_params = self.params
         pre_state: ZeroState = self.opt_state
-        with metrics.timer("tpuft_update_dispatch_seconds"):
+        with tracing.phase("update_dispatch", _trace_of(self.manager)):
             loss, grads = grad_fn(pre_params, *batch)
+        n_params = self._state_leaves[0]
+        _count_dispatch(n_params + self._batch_buffers(batch), 1 + n_params)
         avg_blocks = self._reduce_grad_shards(grads, pre_state)
         spec, recompute = self._zero_speculate(avg_blocks, pre_state)
         return loss, spec, recompute
