@@ -64,10 +64,11 @@ GiB = 2**30
 # layers) — 11.7 GiB predicted, 11.5 measured on the chip. At 6 layers that
 # is 13.7 GiB before the ~1.5 GiB of finished sync payloads the manager's
 # timed futures keep alive for their timeout: no margin, so 4. (FT-DDP alone
-# — committed state + speculative state; at depth 0 the history ring's one
-# version IS the committed state — stays under the plain phase's 5.79 GiB
-# at 4 layers, measured; it was 8.6 while the ring pinned one older
-# version.)
+# holds ONE copy of the state since PR 60: the lone step votes first and is
+# then given its state, and at depth 0 the history ring's one version IS
+# that state. It held committed + speculative state before, under the
+# plain phase's 5.79 GiB at 4 layers, measured, and 8.6 while the ring
+# pinned one older version.)
 SMOKE_LAYERS = 4
 BATCH, SEQ = 4, 2048
 
@@ -455,12 +456,13 @@ class OneChip:
             plane = Plane("smoke_ddp", 30.0)
             try:
                 opt = Optimizer(plane.manager, self.tx, self.init_params())
-                # The program make_step_fn's lone-replica path dispatches,
-                # compiled here to read its text (a compile-cache hit, like
-                # the step's own first call).
+                # The program make_step_fn's lone-replica path dispatches
+                # (the one that is given its state), compiled here to read
+                # its text (a compile-cache hit, like the step's own first
+                # call; lowering gives nothing away).
                 self.require_kernel(
                     "FT-DDP fused step",
-                    make_jit_fused_step(self.tx, self.loss_fn)
+                    make_jit_fused_step(self.tx, self.loss_fn, donate_state=True)
                     .lower(opt.params, opt.opt_state, self.batch_for(0))
                     .compile(),
                 )
@@ -497,20 +499,23 @@ class OneChip:
                     f"{'equal' if digest == ref['digest'] else 'differ'} -> "
                     + ("bitwise the same" if bitwise else "close, not bitwise")
                 )
-            # README: the lone-replica fused step is bitwise the plain program
-            # — the one it is built from, which does not donate. Against the
-            # donated step XLA may fuse the update differently; there the
-            # bound is bf16's, 2^-8 of the loss.
-            if not verdict["not donated"]["bitwise"]:
+            # Since PR 60 the lone-replica step IS the fused program given
+            # its state (the verdict first, then donation): bitwise the plain
+            # step that is given its state too (and, on the chip at these
+            # widths, the one that is not: my chip run, PR 60). Against the
+            # other XLA may fuse the update differently; there the bound is
+            # bf16's, 2^-8 of the loss.
+            if not verdict["donated"]["bitwise"]:
                 raise AssertionError(
-                    "lone-replica FT-DDP is not bitwise the plain fused program"
+                    "lone-replica FT-DDP is not bitwise the plain donated program"
                 )
             tol = max(abs(l) for l in self.plain["donated"]["losses"]) * 2**-8
-            if verdict["donated"]["max_abs_dloss"] > tol:
-                raise AssertionError(
-                    f"FT-DDP losses differ from the donated plain step by "
-                    f"{verdict['donated']['max_abs_dloss']} > {tol}"
-                )
+            for label, against in verdict.items():
+                if against["max_abs_dloss"] > tol:
+                    raise AssertionError(
+                        f"FT-DDP losses differ from the {label} plain step by "
+                        f"{against['max_abs_dloss']} > {tol}"
+                    )
         self.summary["ft-ddp"].update(
             losses=losses, all_committed=True, vs_plain=verdict
         )

@@ -4,8 +4,10 @@
 The TPU's compiler is installed beside JAX and compiles for a chip that is
 described, not attached (``jax.experimental.topologies``). This probe
 compiles the plain SGD-momentum step (donated, as a user's would be) or
-the FT-DDP fused step (not donated: the committed state stays beside the
-speculative one) for one described ``v5e:2x2`` device and prints what
+the FT-DDP fused step as the lone replica's depth-0 step runs it since
+PR 60 (given its state after the vote: one copy; a strict or pipelined
+step keeps the committed state beside the speculative one, so add one
+state by hand there) for one described ``v5e:2x2`` device and prints what
 ``memory_analysis()`` says against the chip's 15.75 GiB — that compile does
 not itself refuse a program that is too large. It counts one program: what
 else the process keeps on the device (a pipelined manager's history ring of
@@ -94,7 +96,7 @@ def probe(spec: dict, device) -> None:
     loss_fn = chip_smoke.make_loss_fn(model)
     step = spec.get("step", "plain")
     jitted = (
-        make_jit_fused_step(tx, loss_fn)
+        make_jit_fused_step(tx, loss_fn, donate_state=True)
         if step == "ftddp"
         else chip_smoke.make_plain_step(tx, loss_fn)
     )

@@ -411,6 +411,77 @@ def ddp_train_loop(
         pg.shutdown()
 
 
+def step_fn_ddp_train_loop(
+    runner: Runner,
+    rank: int,
+    store_client: StoreClient,
+    store_addr: str,
+    rejoin_after_step: Optional[int] = None,
+    lone_step_sleep: float = 0.0,
+) -> Dict[str, Any]:
+    """The DDP loop through ``Optimizer.make_step_fn`` at depth 0: the wire
+    path while two groups train, the lone replica's vote-first step (the
+    state updated in place) while one trains alone. With
+    ``rejoin_after_step`` a group that was killed comes back only once the
+    lighthouse shows a member past that step, so the survivor provably ran
+    alone in between and the joiner heals from a donor whose state its step
+    gives away; ``lone_step_sleep`` paces the lone steps so that the
+    survivor is still training when the joiner is back."""
+    if (
+        rejoin_after_step is not None
+        and runner.injector is not None
+        and runner.injector.count
+        and runner.replica_group == 1
+    ):
+        from torchft_tpu.coordination import LighthouseClient
+
+        client = LighthouseClient(runner.lighthouse_addr)
+        deadline = time.monotonic() + 60
+        while time.monotonic() < deadline:
+            steps = [m.member.step for m in client.status().members if not m.joining]
+            if steps and max(steps) > rejoin_after_step:
+                break
+            time.sleep(0.05)
+        client.close()
+    pg = FakeProcessGroupWrapper(ProcessGroupTCP(timeout=10.0))
+    manager = Manager(
+        pg=pg,
+        min_replica_size=1,
+        store=store_client,
+        store_addr=store_addr,
+        use_async_quorum=runner.use_async_quorum,
+        group_rank=rank,
+        group_world_size=runner.world_size,
+        lighthouse_addr=runner.lighthouse_addr,
+        replica_id=f"ddp_{runner.replica_group}",
+        heartbeat_interval=0.05,
+        timeout=10.0,
+        quorum_timeout=20.0,
+        **runner.manager_args,
+    )
+    opt = Optimizer(manager, optax.sgd(0.05), _init_model_params())
+    step_fn = opt.make_step_fn(_loss_fn)
+    failed_commits = 0
+    try:
+        while manager.current_step() < runner.num_steps:
+            step = manager.current_step()
+            if runner.injector is not None:
+                runner.injector.check(runner.replica_group, step, pg)
+            _, committed = step_fn(*_batch_for(step, runner.replica_group))
+            if not committed:
+                failed_commits += 1
+            if lone_step_sleep and manager.is_lone_replica():
+                time.sleep(lone_step_sleep)
+        return {
+            "state_dict": {"params": opt.params, "opt_state": opt.opt_state},
+            "manager_state": manager.state_dict(),
+            "failed_commits": failed_commits,
+        }
+    finally:
+        manager.shutdown(wait=False)
+        pg.shutdown()
+
+
 def pipelined_ddp_train_loop(
     runner: Runner,
     rank: int,

@@ -1,6 +1,8 @@
 """Pipelined gradient-sync tests (parity: reference ddp_test.py, plus the
 bucket scheduling that replaces the reference's overlapped comm hook)."""
 
+import threading
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -144,6 +146,9 @@ def test_make_step_fn_lone_replica_runs_fused_and_matches_plain(monkeypatch):
     def loss_fn(p, batch):
         return jnp.sum((p["w"] - batch) ** 2)
 
+    # The arrays given to the Optimizer are its own from here on (the lone
+    # step updates them in place): the plain trajectory starts from a copy.
+    start = jax.tree_util.tree_map(np.asarray, params)
     opt = Optimizer(manager, tx, params)
     quorum_waits = []
     step_fn = opt.make_step_fn(loss_fn, on_quorum=quorum_waits.append)
@@ -154,7 +159,9 @@ def test_make_step_fn_lone_replica_runs_fused_and_matches_plain(monkeypatch):
         assert committed
         losses.append(float(loss))
     assert manager.is_lone_replica()
-    want_params, want_losses = _plain_trajectory(loss_fn, tx, params, batches)
+    want_params, want_losses = _plain_trajectory(
+        loss_fn, tx, jax.tree_util.tree_map(jnp.asarray, start), batches
+    )
     np.testing.assert_array_equal(
         np.asarray(opt.params["w"]), np.asarray(want_params["w"])
     )
@@ -162,12 +169,21 @@ def test_make_step_fn_lone_replica_runs_fused_and_matches_plain(monkeypatch):
     assert len(quorum_waits) == 5 and all(t >= 0 for t in quorum_waits)
 
 
-def test_make_step_fn_heal_applies_preheal_grads_to_healed_state():
-    """Heal during the barrier: semantics must match Optimizer.step (and the
-    reference's load_state_dict + optimizer.step() order) — the gradients
-    computed on the PRE-heal params apply to the HEALED state. The loss has
-    a params-dependent gradient so the two possible semantics (pre-heal
-    grads vs grads recomputed on healed params) give different answers."""
+@pytest.mark.parametrize("order", ["vote_first", "speculative"])
+def test_make_step_fn_heal_during_the_barrier_steps_the_healed_state(monkeypatch, order):
+    """Heal during the barrier, by the order the lone step runs in. The loss
+    has a params-dependent gradient so the possible semantics give
+    different answers.
+
+    - vote_first (the default: verdict, then the donated program): the state
+      is read AFTER the verdict, so the healed state is what steps, its own
+      gradient and all: the reference's ``if should_commit():
+      optimizer.step()`` with the forward on what the barrier left.
+    - speculative (``TPUFT_STRICT_COMMIT=1`` here; any step that keeps the
+      old state): as ``Optimizer.step`` and the reference's load_state_dict
+      + optimizer.step() order, the gradients computed on the PRE-heal
+      params apply to the HEALED state."""
+    monkeypatch.setenv("TPUFT_STRICT_COMMIT", "1" if order == "speculative" else "0")
     manager = scripted_manager()
     tx = optax.sgd(0.1)
     params = {"w": jnp.array([1.0, 1.0], jnp.float32)}
@@ -188,12 +204,13 @@ def test_make_step_fn_heal_applies_preheal_grads_to_healed_state():
     step_fn = opt.make_step_fn(loss_fn)
     _, committed = step_fn(jnp.array([1.0, 2.0], jnp.float32))
     assert committed
-    # Pre-heal grads: 2*(1-1)=0, 2*(1-2)=-2; applied to healed [10, 10]:
-    # 10 - 0.1*0 = 10.0, 10 - 0.1*(-2) = 10.2. (Grads recomputed on the
-    # healed params would give [8.2, 8.4] — the wrong semantics.)
-    np.testing.assert_allclose(
-        np.asarray(opt.params["w"]), np.array([10.0, 10.2], np.float32), rtol=1e-6
-    )
+    if order == "vote_first":
+        # Grads on the healed params: 2*(10-1)=18, 2*(10-2)=16.
+        want = np.array([8.2, 8.4], np.float32)
+    else:
+        # Pre-heal grads: 2*(1-1)=0, 2*(1-2)=-2; applied to healed [10, 10].
+        want = np.array([10.0, 10.2], np.float32)
+    np.testing.assert_allclose(np.asarray(opt.params["w"]), want, rtol=1e-6)
 
 
 def test_make_step_fn_uses_wire_path_when_not_lone():
@@ -247,26 +264,45 @@ def _spy_commit_ordering(monkeypatch, manager, opt):
         events.append(("vote",))
         return real_async(timeout)
 
+    real_commit = manager.should_commit
+
+    caller = threading.current_thread()
+
+    def spy_commit(timeout=None):
+        # The vote-first step's own call, on the caller's thread; the
+        # executor's call of a vote that spy_async already counted is not.
+        if threading.current_thread() is caller:
+            events.append(("vote",))
+        return real_commit(timeout=timeout)
+
     monkeypatch.setattr(optim_mod, "_bound_device", spy_sync)
     manager.should_commit_async = spy_async
+    manager.should_commit = spy_commit
     return events
 
 
-@pytest.mark.parametrize("mode", ["strict", "overlapped", "pipelined"])
+@pytest.mark.parametrize("mode", ["strict", "vote_first", "overlapped", "pipelined"])
 def test_make_step_fn_commit_sync_orderings(monkeypatch, mode):
-    """Pins all three commit orderings on the lone-replica step:
+    """Pins all four commit orderings on the lone-replica step:
 
     - strict (TPUFT_STRICT_COMMIT=1): vote only after observed completion
       (reference manager.py:816-827) — sync precedes the vote, same call,
       every step.
-    - overlapped (default): the barrier RPC launches first and rides under
-      the readiness wait — vote precedes sync, same call, every step.
+    - vote_first (default: a ring of one version): the verdict is taken on
+      the caller's thread, then the donated program is dispatched and
+      synced — vote precedes sync, same call, every step, and the state
+      before the call is deleted.
+    - overlapped (a ring asked to keep two versions, so nothing may be
+      given away): the barrier RPC launches first and rides under the
+      readiness wait — vote precedes sync, same call, every step.
     - pipelined (commit_pipeline_depth=1): a step's own call does NO sync
       of its own loss; it syncs the PREVIOUS step's loss (after dispatch,
       so the readiness RTT rides under the new step's device execution)
       and then votes — exactly one step's completion unobserved per vote.
     """
     monkeypatch.setenv("TPUFT_STRICT_COMMIT", "1" if mode == "strict" else "0")
+    if mode == "overlapped":
+        monkeypatch.setenv("TPUFT_HISTORY_MAX_VERSIONS", "2")
     manager = scripted_manager(
         commit_pipeline_depth=1 if mode == "pipelined" else 0
     )
@@ -277,15 +313,19 @@ def test_make_step_fn_commit_sync_orderings(monkeypatch, mode):
 
     step_fn = opt.make_step_fn(lambda p, b: jnp.sum(p["w"] * b))
     losses = []
+    given_away = []
     for _ in range(3):
+        before = opt.params["w"]
         loss, committed = step_fn(jnp.array([1.0, 2.0], jnp.float32))
         losses.append(loss)
+        given_away.append(before.is_deleted())
+    assert given_away == [mode == "vote_first"] * 3
     kinds = [e[0] for e in events]
     if mode == "strict":
         assert kinds == ["sync", "vote"] * 3
         # Each call syncs its OWN loss before its vote leaves.
         assert [e[1] for e in events if e[0] == "sync"] == losses
-    elif mode == "overlapped":
+    elif mode in ("vote_first", "overlapped"):
         assert kinds == ["vote", "sync"] * 3
         assert [e[1] for e in events if e[0] == "sync"] == losses
     else:
@@ -753,7 +793,11 @@ def test_strict_depth0_donor_send_stages_live_max_step_without_the_ring():
     seen = []
 
     def spy_send(dst_ranks, step, state_dict, timeout, quorum_id=None):
-        seen.append((step, manager.current_step(), state_dict))
+        # As a transport does: host copies staged inside the call. The
+        # references are the live state's, which the next step deletes.
+        seen.append(
+            (step, manager.current_step(), jax.tree_util.tree_map(np.asarray, state_dict))
+        )
 
     transport.send_checkpoint.side_effect = spy_send
     step_fn = opt.make_step_fn(lambda p, b: jnp.sum(p["w"] * b))
@@ -798,10 +842,10 @@ def _state_leaves(opt):
 
 def _ring_state(manager, opt):
     """The ring's version at the manager's committed step, or None."""
-    entry = manager.history.state_dict_at(
-        manager.current_step(), {opt._register_key}
-    )
-    return None if entry is None else entry["user"][opt._register_key]
+    # The entry itself: ``state_dict_at`` hands a ring of one version's out
+    # as a device copy, and these tests pin what the ring HOLDS.
+    entry = manager.history._entries.get(manager.current_step())
+    return None if entry is None else entry.states.get(opt._register_key)
 
 
 def _strict_setup(path):

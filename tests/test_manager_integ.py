@@ -14,6 +14,7 @@ from ft_harness import (
     ddp_train_loop,
     pipelined_ddp_train_loop,
     run_replica_groups,
+    step_fn_ddp_train_loop,
 )
 
 
@@ -88,6 +89,43 @@ def test_ddp_recovery_after_replica_kill(lighthouse) -> None:
     # North star (BASELINE.md): a kill costs the survivor < 1 step — at most
     # the in-flight commit may fail when the peer vanishes mid-allreduce.
     assert results[0][0]["failed_commits"] <= 1, results[0][0]["failed_commits"]
+
+
+def test_step_fn_kill_and_heal_donor_reads_its_in_place_state_by_reference(
+    lighthouse,
+) -> None:
+    """Two groups through ``make_step_fn`` at depth 0; group 1 dies at step 2
+    and comes back only after the survivor has trained alone, its state
+    updated IN PLACE by every lone step (``tpuft_step_state_donated_total``
+    grows), and then heals from it. The donor's ``send_checkpoint`` reads
+    that state by reference, while the train thread waits for the quorum:
+    no device copy is paid (``tpuft_state_snapshot_copies_total`` stays), and
+    the groups end bitwise equal."""
+    from torchft_tpu import metrics
+
+    copies = metrics.counter_total("tpuft_state_snapshot_copies_total", key="optimizer")
+    donated = metrics.counter_total("tpuft_step_state_donated_total")
+    heals = metrics.counter_total("tpuft_heals_total", role="donor")
+    injector = EventInjector().fail_at(group=1, step=2)
+    runners = [
+        Runner(
+            replica_group=i,
+            lighthouse_addr=lighthouse.address(),
+            train_loop=step_fn_ddp_train_loop,
+            train_loop_args={"rejoin_after_step": 4, "lone_step_sleep": 0.05},
+            num_steps=100,
+            injector=injector,
+        )
+        for i in range(2)
+    ]
+    results = run_replica_groups(runners, timeout=180)
+    assert injector.count == 1
+    assert_groups_converged(results, 100)
+    assert metrics.counter_total("tpuft_heals_total", role="donor") > heals
+    assert metrics.counter_total("tpuft_step_state_donated_total") >= donated + 2
+    assert metrics.counter_total(
+        "tpuft_state_snapshot_copies_total", key="optimizer"
+    ) == copies
 
 
 def test_ddp_pipelined_two_groups_healthy(lighthouse) -> None:

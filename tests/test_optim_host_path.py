@@ -1,8 +1,12 @@
 """The FT step's host path from inside (PR 59): ``adopt``'s children in the
 journal, the counters of what a dispatch hands to the runtime and takes
 back, and the byte accounting that no longer walks the state every committed
-step. On the CPU; the runtime's own events under a span are
-tests/test_tracing.py's."""
+step; and who owns the step's state (PR 60): the lone replica's step votes
+first and then updates ``params`` / ``opt_state`` in place. On the CPU; the
+runtime's own events under a span are tests/test_tracing.py's."""
+
+import sys
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -123,12 +127,17 @@ def test_refused_commit_has_no_adopt_and_no_children():
         assert _spans(journal, name) == []
 
 
-def test_history_evict_is_where_the_version_leaves():
+@pytest.mark.parametrize("order", ["vote_first", "speculative"])
+def test_history_evict_is_where_the_version_leaves(monkeypatch, order):
     """With a real manager the commit tail's ``note_accounting`` makes the new
-    entry, so the previous version leaves the ring THERE, on the commit
-    thread after the barrier, before ``adopt`` begins; ``adopt``'s promotion
-    finds nothing to evict. A ring whose accounting half arrives
-    late (a scripted manager's) evicts inside ``history_promote``."""
+    entry, so the previous version leaves the ring THERE, after the barrier
+    and before ``adopt`` begins; ``adopt``'s promotion finds nothing to
+    evict. The vote-first step takes its verdict on its own thread, so that
+    is inside its ``commit_wait``, before the program that deletes the
+    version's arrays is dispatched; the speculative step (strict mode here)
+    votes on the commit thread. A ring whose accounting half arrives late (a
+    scripted manager's) evicts inside ``history_promote``."""
+    monkeypatch.setenv("TPUFT_STRICT_COMMIT", "1" if order == "speculative" else "0")
     journal = tracing.TraceJournal(maxlen=1024)
     with tracing.use_journal(journal):
         manager = scripted_manager()
@@ -140,10 +149,16 @@ def test_history_evict_is_where_the_version_leaves():
         # Step 1's commit has nothing to evict; steps 2 and 3 evict one each.
         assert len(evicts) == 2
         adopts = {e["step"]: e for e in _spans(journal, "adopt")}
+        waits = {e["step"]: e for e in _spans(journal, "commit_wait")}
+        dispatches = {e["step"]: e for e in _spans(journal, "update_dispatch")}
         for evict in evicts:
             adopt = adopts[evict["step"]]
-            assert evict["thread"] != adopt["thread"]
             assert evict["t_mono"] + evict["dur"] <= adopt["t_mono"]
+            if order == "vote_first":
+                assert _inside(evict, waits[evict["step"]])
+                assert evict["t_mono"] + evict["dur"] <= dispatches[evict["step"]]["t_mono"]
+            else:
+                assert evict["thread"] != adopt["thread"]
         # The accounting half left out: the promotion is what evicts.
         manager._history.note_accounting = lambda *a, **k: None
         seen = len(journal.snapshot())
@@ -392,3 +407,290 @@ def test_weight_history_evict_span_only_when_a_version_leaves():
     hist.retract_newer(3)
     hist.clear()  # retraction and clearing are no eviction
     assert len(_spans(journal, "history_evict")) == 2
+
+
+# ---------------------------------------------------------------------------
+# who owns the step's state: vote first, then update in place
+# ---------------------------------------------------------------------------
+
+
+def _state(opt):
+    return jax.tree_util.tree_leaves((opt.params, opt.opt_state))
+
+
+def _host(tree):
+    # A copy: on the CPU ``np.asarray`` is a view of the device's buffer, and
+    # a buffer with a view outstanding is quietly not given away.
+    return jax.tree_util.tree_map(np.array, tree)
+
+
+def _copies(key="optimizer"):
+    return metrics.counter_total("tpuft_state_snapshot_copies_total", key=key)
+
+
+def _donated_kept():
+    return (
+        metrics.counter_total("tpuft_step_state_donated_total"),
+        metrics.counter_total("tpuft_step_state_kept_total"),
+    )
+
+
+def test_committed_lone_step_deletes_the_state_it_replaces():
+    """The donated program is given ``params`` and ``opt_state``: after a
+    committed step every array of the state before it is deleted, none of
+    the new state's is, the caller's arrays among the former (they are the
+    Optimizer's from construction), and the ring's one version is the new
+    state by reference. No copy is made anywhere."""
+    given = _params()
+    copies = _copies()
+    manager = scripted_manager()
+    opt = Optimizer(manager, optax.adam(0.1), given)
+    assert all(a is b for a, b in zip(jax.tree_util.tree_leaves(given), jax.tree_util.tree_leaves(opt.params)))
+    step_fn = opt.make_step_fn(_loss)
+    for i in range(3):
+        before = _state(opt)
+        loss, committed = step_fn(_batch(i))
+        assert committed and np.isfinite(float(loss))
+        assert all(x.is_deleted() for x in before)
+        assert not any(x.is_deleted() for x in _state(opt))
+        entry = manager.history._entries[manager.current_step()]
+        assert entry.states["optimizer"]["params"] is opt.params
+        assert entry.states["optimizer"]["opt_state"] is opt.opt_state
+    assert all(x.is_deleted() for x in jax.tree_util.tree_leaves(given))
+    assert _copies() == copies
+
+
+@pytest.mark.parametrize("why", ["too_few_replicas", "reported_error"])
+def test_refused_lone_step_dispatches_nothing_to_the_state(why):
+    """A refused verdict comes BEFORE any dispatch: no step program runs, the
+    state is the very objects it was, alive, the manager's step stays, and
+    the call still returns ``(loss, False)``, the loss from the program that
+    takes nothing."""
+    journal = tracing.TraceJournal(maxlen=256)
+    with tracing.use_journal(journal):
+        manager = scripted_manager(
+            min_replica_size=2 if why == "too_few_replicas" else 1
+        )
+        opt = Optimizer(manager, optax.adam(0.1), _params())
+        step_fn = opt.make_step_fn(_loss)
+        if why == "reported_error":
+            # An error that lands after the step chose its path (another
+            # thread's, in a deployment): the vote carries it.
+            lone = manager.is_lone_replica
+            manager.is_lone_replica = lambda: (
+                manager.report_error(RuntimeError("injected")), lone()
+            )[1]
+        state, params, opt_state = _state(opt), opt.params, opt.opt_state
+        want = float(_loss(_host(opt.params), np.asarray(_batch(1))))
+        before, counted = _dispatch_totals(), _donated_kept()
+        loss, committed = step_fn(_batch(1))
+    assert committed is False and float(loss) == pytest.approx(want, rel=1e-6)
+    assert manager.current_step() == 0
+    assert opt.params is params and opt.opt_state is opt_state
+    assert all(a is b for a, b in zip(_state(opt), state))
+    assert not any(x.is_deleted() for x in state)
+    assert _growth(before) == (0, 0, 0) and _donated_kept() == counted
+    for name in ("update_dispatch", "adopt", "state_swap", "history_promote"):
+        assert _spans(journal, name) == []
+    assert len(_spans(journal, "commit_wait")) == 1
+
+
+def test_donated_steps_equal_kept_steps_bit_for_bit(monkeypatch):
+    """The same program on the same inputs: N steps that update in place and
+    N that keep both copies (``TPUFT_STRICT_COMMIT=1``) end in bit-equal
+    state and return bit-equal losses."""
+
+    def run(strict):
+        monkeypatch.setenv("TPUFT_STRICT_COMMIT", "1" if strict else "0")
+        counted = _donated_kept()
+        opt = Optimizer(scripted_manager(), optax.adamw(0.05), _params())
+        step_fn = opt.make_step_fn(_loss)
+        losses = [np.asarray(step_fn(_batch(i))[0]) for i in range(6)]
+        grew = tuple(n - w for n, w in zip(_donated_kept(), counted))
+        assert grew == ((0, 6) if strict else (6, 0))
+        return losses, _host((opt.params, opt.opt_state))
+
+    donated, kept = run(False), run(True)
+    for a, b in zip(jax.tree_util.tree_leaves(donated), jax.tree_util.tree_leaves(kept)):
+        assert a.dtype == b.dtype and a.tobytes() == b.tobytes()
+
+
+def _kept_setup(case, monkeypatch):
+    """An optimizer whose step may give nothing away, and ``run(batch) ->
+    committed``."""
+    tx = optax.sgd(0.2, momentum=0.9)
+    if case == "strict":
+        monkeypatch.setenv("TPUFT_STRICT_COMMIT", "1")
+    if case == "ring_of_two":
+        monkeypatch.setenv("TPUFT_HISTORY_MAX_VERSIONS", "2")
+    manager = scripted_manager(commit_pipeline_depth=1 if case == "depth_1" else 0)
+    if case == "zero":
+        opt = ZeroOptimizer(manager, tx, _params(), num_shards=2)
+    elif case == "tied_leaves":
+        w = jnp.ones(3, jnp.float32)
+        opt = Optimizer(manager, tx, {"w": w, "tied": w})
+        assert opt._state_aliased
+    else:
+        opt = Optimizer(manager, tx, _params())
+    if case == "wire":
+        manager.is_lone_replica = lambda: False
+    step_fn = opt.make_step_fn(
+        lambda p, b: sum(jnp.sum((x.astype(jnp.float32) - 0.5) ** 2) for x in jax.tree_util.tree_leaves(p)) + jnp.sum(b)
+    )
+    return manager, opt, step_fn
+
+
+@pytest.mark.parametrize(
+    "case", ["strict", "depth_1", "ring_of_two", "wire", "zero", "tied_leaves"]
+)
+def test_a_step_that_may_give_nothing_away_deletes_nothing(case, monkeypatch):
+    """Where the old state may still be asked for (strict mode votes after
+    completion, a window or a ring of two holds older versions by reference,
+    the wire path waits for its peers, ZeRO's programs differ, a tied array
+    cannot be given twice) the step is the speculative one: every array ever
+    read from the optimizer stays readable."""
+    manager, opt, step_fn = _kept_setup(case, monkeypatch)
+    counted = _donated_kept()
+    seen = list(jax.tree_util.tree_leaves(opt.params))
+    for i in range(4):
+        step_fn(_batch(i))
+        seen += [x for x in jax.tree_util.tree_leaves(opt.params) if isinstance(x, jax.Array)]
+    opt.flush_pipeline()
+    assert manager.current_step() == 4
+    assert not any(x.is_deleted() for x in seen)
+    assert _donated_kept()[0] == counted[0]
+    assert _donated_kept()[1] >= counted[1] + 4
+
+
+def test_state_dict_read_racing_the_step_never_sees_a_deleted_array():
+    """A reader that takes the state-dict read lock in a loop (what a
+    checkpoint serve does) while the step updates in place: the dispatch that
+    deletes the old arrays, the rebinding and the ring's promotion are one
+    write-locked section, so under the read lock the registered state and
+    the ring's version are always whole and alive."""
+    manager = scripted_manager()
+    opt = Optimizer(manager, optax.adam(0.1), _params())
+    step_fn = opt.make_step_fn(_loss)
+    step_fn(_batch(0))
+    stop, seen, reads = threading.Event(), [], [0]
+
+    def reader():
+        try:
+            while not stop.is_set():
+                with manager._state_dict_lock.r_lock(timeout=10):
+                    state = manager._user_state_dicts["optimizer"]()
+                    with manager.history._lock:
+                        ring = [e.states.get("optimizer") for e in manager.history._entries.values()]
+                    for x in jax.tree_util.tree_leaves((state, ring)):
+                        assert not x.is_deleted()
+                    total = sum(float(jnp.sum(x)) for x in jax.tree_util.tree_leaves(state["params"]))
+                    assert np.isfinite(total)
+                    reads[0] += 1
+        except BaseException as e:  # noqa: BLE001 - handed to the test's thread
+            seen.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    threads = [threading.Thread(target=reader, daemon=True) for _ in range(3)]
+    try:
+        for t in threads:
+            t.start()
+        for i in range(60):
+            before = _state(opt)
+            _, committed = step_fn(_batch(i))
+            assert committed and all(x.is_deleted() for x in before)
+    finally:
+        stop.set()
+        for t in threads:
+            t.join(timeout=30)
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert seen == [] and reads[0] > 0
+
+
+def test_a_capture_from_the_ring_of_one_version_outlives_the_steps_after_it():
+    """``state_dict_at`` on a ring of one version (what a deep donor or a
+    publisher that pins a step would hold past the step): a device copy,
+    counted under the registered key, that still reads step N's values after
+    step N + 2 deleted step N's arrays. A steady run without such a capture
+    counts none."""
+    manager = scripted_manager()
+    opt = Optimizer(manager, optax.adam(0.1), _params())
+    step_fn = opt.make_step_fn(_loss)
+    copies = _copies()
+    for i in range(3):
+        step_fn(_batch(i))
+    assert _copies() == copies  # nothing copies in a steady run
+    at_n = _host(opt.params)
+    live = _state(opt)
+    with manager._state_dict_lock.r_lock(timeout=1):  # as Manager._history_state_dict
+        capture = manager.history.state_dict_at(3, {"optimizer"})
+    assert _copies() == copies + 1
+    assert capture["tpuft"] == {"step": 3, "batches_committed": 3}
+    held = capture["user"]["optimizer"]
+    assert not any(
+        a is b for a in jax.tree_util.tree_leaves(held) for b in live
+    )
+    for i in range(3, 5):
+        step_fn(_batch(i))
+    assert all(x.is_deleted() for x in live)
+    for a, b in zip(jax.tree_util.tree_leaves(_host(held["params"])), jax.tree_util.tree_leaves(at_n)):
+        assert a.tobytes() == b.tobytes()
+    assert manager.history.state_dict_at(3, {"optimizer"}) is None  # the ring moved on
+
+
+def test_a_ring_of_more_versions_hands_out_references():
+    copies = _copies()
+    hist = WeightHistory(max_versions=2)
+    state = {"params": {"w": jnp.ones(3)}}
+    hist.note_state("optimizer", 1, state, nbytes=12)
+    hist.note_accounting(1, 1)
+    assert hist.state_dict_at(1, {"optimizer"})["user"]["optimizer"] is state
+    assert _copies() == copies
+
+
+def test_donated_counter_and_the_dispatch_events_field():
+    """``tpuft_step_state_donated_total`` grows by one a step that updated in
+    place, ``tpuft_step_state_kept_total`` by one a step that kept both
+    copies, and the ``update_dispatch`` event says which beside ``fused``."""
+    journal = tracing.TraceJournal(maxlen=512)
+    with tracing.use_journal(journal):
+        opt, run = _setup("lone")
+        for i in range(3):
+            counted = _donated_kept()
+            assert run(_batch(i))
+            assert _donated_kept() == (counted[0] + 1, counted[1])
+        kept_opt, kept_run = _setup("wire")
+        counted = _donated_kept()
+        assert kept_run(_batch(0))
+        assert _donated_kept() == (counted[0], counted[1] + 1)
+    events = _spans(journal, "update_dispatch")
+    args = [e.get("args") or {} for e in events]
+    assert [(a.get("fused"), a.get("donated")) for a in args] == [
+        (True, True)
+    ] * 3 + [(None, None)]
+
+
+def test_a_failure_after_a_true_verdict_is_a_phantom_commit():
+    """The verdict is in before the dispatch, so a dispatch that fails on the
+    host (here: a batch the program cannot take) has advanced the step
+    counter without its update: counted and journalled as the phantom commit
+    the speculative order already knows, and raised. The state is what it
+    was: the failed call gave nothing away."""
+    journal = tracing.TraceJournal(maxlen=256)
+    phantoms = metrics.counter_total("tpuft_phantom_commits_total")
+    with tracing.use_journal(journal):
+        manager = scripted_manager()
+        opt = Optimizer(manager, optax.sgd(0.1), _params())
+        step_fn = opt.make_step_fn(lambda p, b: _loss(p, b) + jnp.sum(b @ p["w"]))
+        state = _state(opt)
+        with pytest.raises(TypeError):
+            step_fn(jnp.ones((2, 5), jnp.float32))  # 5 against w's 3
+    assert manager.current_step() == 1
+    assert metrics.counter_total("tpuft_phantom_commits_total") == phantoms + 1
+    assert [e["name"] for e in journal.snapshot() if e["name"] == "phantom_commit"] == ["phantom_commit"]
+    assert all(a is b for a, b in zip(_state(opt), state))
+    assert not any(x.is_deleted() for x in state)
+    # The write lock was released on the way out.
+    with manager._state_dict_lock.r_lock(timeout=1):
+        pass

@@ -499,7 +499,7 @@ def test_dots_step_runs_one_flash_forward_a_layer(
 
     def compiled():
         program = (
-            make_jit_fused_step(system.tx, system.loss_fn)
+            make_jit_fused_step(system.tx, system.loss_fn, donate_state=True)
             .lower(
                 _sds_tree(params, chip), _sds_tree(opt_state, chip),
                 _sds((batch, seq + 1), jnp.int32, chip),
@@ -709,7 +709,7 @@ def test_dots_step_of_the_keye_cell_selects_once_and_runs_one_flash_pair_a_layer
 
     def compiled():
         program = (
-            make_jit_fused_step(system.tx, system.loss_fn)
+            make_jit_fused_step(system.tx, system.loss_fn, donate_state=True)
             .lower(
                 _sds_tree(params, chip), _sds_tree(opt_state, chip),
                 _sds((1, seq + 1), jnp.int32, chip),
@@ -807,7 +807,7 @@ def test_dots_step_of_a_keye_share_keeps_every_worst_case_row_inside_the_last_ru
     system = System(config, architecture, {"batch": 1, "seq": seq}, seed=0)
     params = jax.eval_shape(system.init_params)
     text = (
-        make_jit_fused_step(system.tx, system.loss_fn)
+        make_jit_fused_step(system.tx, system.loss_fn, donate_state=True)
         .lower(
             _sds_tree(params, chip), _sds_tree(jax.eval_shape(system.tx.init, params), chip),
             _sds((1, seq + 1), jnp.int32, chip),
@@ -984,7 +984,7 @@ def test_dots_step_of_the_smallthinker_cell_is_one_traced_period_and_fits(
     assert sorted(params["params"]["layers"]) == [f"block_{kind}" for kind in range(4)]
     opt_state = jax.eval_shape(system.tx.init, params)
     program = (
-        make_jit_fused_step(system.tx, system.loss_fn)
+        make_jit_fused_step(system.tx, system.loss_fn, donate_state=True)
         .lower(
             _sds_tree(params, chip), _sds_tree(opt_state, chip),
             _sds((1, seq + 1), jnp.int32, chip),
@@ -1135,7 +1135,7 @@ def test_dots_step_of_the_granite_cell_recomputes_its_period_and_fits(
     assert sorted(params["params"]["layers"]) == [f"block_{kind}" for kind in range(4)]
     opt_state = jax.eval_shape(system.tx.init, params)
     program = (
-        make_jit_fused_step(system.tx, system.loss_fn)
+        make_jit_fused_step(system.tx, system.loss_fn, donate_state=True)
         .lower(
             _sds_tree(params, chip), _sds_tree(opt_state, chip),
             _sds((1, seq + 1), jnp.int32, chip),
@@ -1247,7 +1247,7 @@ def test_dots_step_of_the_granite_cell_recomputes_its_period_and_fits(
     assert sorted(params["params"]["layers"]) == sorted(f"block_{kind}" for kind in range(10))
     opt_state = jax.eval_shape(system.tx.init, params)
     program = (
-        make_jit_fused_step(system.tx, system.loss_fn)
+        make_jit_fused_step(system.tx, system.loss_fn, donate_state=True)
         .lower(
             _sds_tree(params, chip), _sds_tree(opt_state, chip),
             _sds((1, seq + 1), jnp.int32, chip),

@@ -217,7 +217,12 @@ def test_zero_lone_replica_matches_plain_optimizer(monkeypatch) -> None:
 
     monkeypatch.setattr(ddp_mod, "ft_allreduce_gradients", _boom)
     ref_manager = scripted_manager()
-    ref = Optimizer(ref_manager, optax.sgd(0.2, momentum=0.9), _PARAMS)
+    # A copy: the arrays given to an Optimizer are its own (its lone step
+    # updates them in place), and _PARAMS is every test's.
+    ref = Optimizer(
+        ref_manager, optax.sgd(0.2, momentum=0.9),
+        jax.tree_util.tree_map(jnp.copy, _PARAMS),
+    )
     ref_fn = ref.make_step_fn(_loss)
     ref_losses = [float(ref_fn(b)[0]) for b in _BATCHES]
 

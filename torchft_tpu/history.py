@@ -43,8 +43,19 @@ ring sizes itself by the commit window, window + 1 at every depth (the
 versions the rollback ring already held; ``auto`` counts its adaptive
 maximum as the window), so a strict depth-0 manager runs at ``K=1``: its
 one version IS the live committed state, held by reference at no cost in
-memory, and a strict step keeps two copies of the state (committed N,
-speculative N + 1), never a third. The serving store keeps
+memory. A ring of one version is also what lets that state be updated IN
+PLACE: the lone replica's step votes first and then gives ``params`` and
+``opt_state`` to its program (``Optimizer``'s ownership contract,
+optim.py), so the chip holds ONE copy of the state, and the version this
+ring holds is deleted by the step that replaces it, inside the state-dict
+write lock, where the promotion of its successor happens too. So
+:meth:`WeightHistory.state_dict_at` hands a ring of one version's entry
+out as a device copy (:func:`_snapshot`, counted), never as the
+references themselves. A ring asked to keep older versions
+(``max_versions`` > 1: a pipelined window, or the environment) holds
+them by reference, which nothing may then delete: there the step keeps
+today's speculative order and two copies of the state (committed N,
+speculative N + 1) plus the window's. The serving store keeps
 :data:`DEFAULT_SERVING_VERSIONS`.
 """
 
@@ -100,6 +111,38 @@ def history_max_versions(default: int) -> int:
         return max(1, int(raw))
     except ValueError:
         return max(1, default)
+
+
+def _device_copy(tree: Any) -> Any:
+    """``tree`` with every ``jax.Array`` in it copied into a buffer of its
+    own (same sharding; anything else passes through). The programs that
+    update state in place (DiLoCo's, the lone replica's FT-DDP step) donate
+    the state they replace, so a buffer that something else must still
+    read is copied before the next program is dispatched: the copy is
+    queued on the device ahead of that program and reads the old values.
+    One small program per leaf, so that leaves on different device sets
+    need no common mesh."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.tree_util.tree_map(
+        lambda x: jnp.copy(x) if isinstance(x, jax.Array) else x, tree
+    )
+
+
+def _snapshot(key: str, tree: Any) -> Any:
+    """A capture of registered state ``key`` that survives later steps: a
+    device copy (:func:`_device_copy`), counted. Holding a reference is
+    no snapshot where the next step deletes what it refers to."""
+    import jax
+
+    nbytes = sum(
+        x.nbytes for x in jax.tree_util.tree_leaves(tree) if isinstance(x, jax.Array)
+    )
+    if nbytes:
+        metrics.inc("tpuft_state_snapshot_copies_total", key=key)
+        metrics.inc("tpuft_state_snapshot_copy_bytes_total", nbytes, key=key)
+    return _device_copy(tree)
 
 
 class _StateEntry:
@@ -214,7 +257,10 @@ class WeightHistory:
         or None when the ring cannot serve it exactly (step evicted /
         never promoted, a registered key missing, or accounting absent).
         A miss means the caller falls back to staging its drained step;
-        it can never mean serving mislabeled or partial state."""
+        it can never mean serving mislabeled or partial state. A ring of
+        one version answers with device copies (the module docstring says
+        why): call it under the state-dict read lock, as
+        ``Manager._history_state_dict`` does."""
         with self._lock:
             entry = self._entries.get(step)
             if entry is None:
@@ -223,8 +269,13 @@ class WeightHistory:
                 return None
             if entry.batches_committed is None:
                 return None
+            states = {k: entry.states[k] for k in required_keys}
+            if self._max_versions == 1:
+                # The one version is the live state, which its owner may
+                # update in place: the capture has to outlive it.
+                states = {k: _snapshot(k, v) for k, v in states.items()}
             return {
-                "user": {k: entry.states[k] for k in required_keys},
+                "user": states,
                 "tpuft": {
                     "step": step,
                     "batches_committed": entry.batches_committed,

@@ -28,6 +28,7 @@ import jax
 import numpy as np
 
 from torchft_tpu import metrics, tracing
+from torchft_tpu.history import _device_copy, _snapshot
 from torchft_tpu.manager import Manager
 from torchft_tpu.optim import _trace_of
 from torchft_tpu.utils import netem
@@ -59,35 +60,6 @@ def cross_region_fleet() -> bool:
     DDP inside a region never leaves the cheap links)."""
     topo = netem.describe_topology()
     return bool(topo.get("configured")) and not topo.get("single_region", True)
-
-
-def _device_copy(tree: Any) -> Any:
-    """``tree`` with every ``jax.Array`` in it copied into a buffer of its
-    own (same sharding; anything else passes through). DiLoCo's programs
-    donate the state they replace, so a buffer that something else must
-    still read is copied before the next program is dispatched: the copy
-    is queued on the device ahead of that program and reads the old
-    values. One small program per leaf, so that leaves on different
-    device sets need no common mesh."""
-    import jax.numpy as jnp
-
-    return jax.tree_util.tree_map(
-        lambda x: jnp.copy(x) if isinstance(x, jax.Array) else x, tree
-    )
-
-
-def _snapshot(key: str, tree: Any) -> Any:
-    """A capture of registered state ``key`` that survives later steps: a
-    device copy (:func:`_device_copy`), counted. Holding a reference is
-    no snapshot here, because the next inner step deletes what it
-    refers to."""
-    nbytes = sum(
-        x.nbytes for x in jax.tree_util.tree_leaves(tree) if isinstance(x, jax.Array)
-    )
-    if nbytes:
-        metrics.inc("tpuft_state_snapshot_copies_total", key=key)
-        metrics.inc("tpuft_state_snapshot_copy_bytes_total", nbytes, key=key)
-    return _device_copy(tree)
 
 
 def _to_device_like(host: np.ndarray, like: Any) -> Any:

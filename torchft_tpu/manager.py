@@ -832,10 +832,14 @@ class Manager:
         (evicted, a registered key never promoted — e.g. DiLoCo's
         fragments, which don't promote yet — or accounting missing).
         None means the caller stages its drained step instead: the
-        fallback fetches more, it never mislabels."""
+        fallback fetches more, it never mislabels. Read under the
+        state-dict read lock: a ring of one version holds the live
+        state, which its owner replaces (and deletes) under the writer,
+        and answers with device copies made here."""
         if not self._user_state_dicts:
             return None
-        return self._history.state_dict_at(step, set(self._user_state_dicts))
+        with self._state_dict_lock.r_lock(timeout=self._timeout):
+            return self._history.state_dict_at(step, set(self._user_state_dicts))
 
     def register_quorum_change_hook(self, hook: Callable[[], None]) -> None:
         """Runs ``hook`` on the quorum thread whenever the quorum id

@@ -35,6 +35,7 @@ import jax
 import numpy as np
 
 from torchft_tpu import health, metrics, tracing
+from torchft_tpu.history import WeightHistory
 from torchft_tpu.manager import Manager
 from torchft_tpu.utils import schedules
 
@@ -75,16 +76,26 @@ def _trace_of(manager: Any) -> "tracing.TraceJournal":
     return getattr(manager, "_trace", None) or tracing.current()
 
 
-def _count_dispatch(buffers_in: int, buffers_out: int) -> None:
+def _count_dispatch(
+    buffers_in: int, buffers_out: int, donated: Optional[bool] = None
+) -> None:
     """One call of a jitted step program and the buffers (array leaves) it
     hands to the runtime and takes back. The host cost of a dispatch grows
     with them, so beside the ``update_dispatch`` span's seconds they give
     a cost per buffer. The counts are the step's static ones, taken where
     the state's structure is set (:meth:`Optimizer._note_state_structure`);
-    no tree is walked here."""
+    no tree is walked here. What is handed over is the same whether the
+    state is donated or kept; what the runtime then allocates is not.
+    ``donated`` says which it was for the program that writes the step's
+    new state (None: a program that leaves the state alone, as ZeRO's
+    gradient program), and counts the step under it."""
     metrics.inc("tpuft_step_dispatch_total")
     metrics.inc("tpuft_step_dispatch_buffers_total", buffers_in, direction="in")
     metrics.inc("tpuft_step_dispatch_buffers_total", buffers_out, direction="out")
+    if donated:
+        metrics.inc("tpuft_step_state_donated_total")
+    elif donated is not None:
+        metrics.inc("tpuft_step_state_kept_total")
 
 
 def _sync_device(x: Any) -> Any:
@@ -173,7 +184,9 @@ def make_microbatch_grad(loss_fn: Any, num_microbatches: int):
     return grad_fn
 
 
-def make_jit_fused_step(tx: Any, loss_fn: Any, num_microbatches: int = 1):
+def make_jit_fused_step(
+    tx: Any, loss_fn: Any, num_microbatches: int = 1, donate_state: bool = False
+):
     """ONE jitted program for a whole local train step:
     ``(params, opt_state, *batch) -> (loss, new_params, new_opt_state)``.
     ``loss_fn(params, *batch) -> scalar``. The fused form is the plain-JAX
@@ -181,7 +194,11 @@ def make_jit_fused_step(tx: Any, loss_fn: Any, num_microbatches: int = 1):
     share it — DiLoCo keeps its own leaves-layout variant
     (local_sgd.py make_step_fn). ``num_microbatches > 1`` accumulates
     gradients over equal batch chunks inside the same program
-    (:func:`make_microbatch_grad`)."""
+    (:func:`make_microbatch_grad`). ``donate_state`` gives ``params`` and
+    ``opt_state`` to the program, as :func:`make_jit_update`'s does: the
+    new state is written where the old one was and both inputs are
+    deleted, so only for a caller that owns both and has its verdict
+    (the lone replica's vote-first step)."""
     import optax
 
     if num_microbatches < 1:
@@ -196,7 +213,7 @@ def make_jit_fused_step(tx: Any, loss_fn: Any, num_microbatches: int = 1):
         updates, new_state = tx.update(grads, opt_state, params)
         return loss, optax.apply_updates(params, updates), new_state
 
-    return jax.jit(_fused)
+    return jax.jit(_fused, donate_argnums=(0, 1) if donate_state else ())
 
 
 def make_jit_update(tx: Any, donate_state: bool = False):
@@ -427,7 +444,56 @@ class _PendingStep:
 
 
 class Optimizer:
-    """Owns (params, opt_state); steps only on quorum-wide commit."""
+    """Owns (params, opt_state); steps only on quorum-wide commit.
+
+    **Who owns the state, and when it is updated in place.** The arrays
+    given as ``params`` are the Optimizer's from construction, as a plain
+    donated train step takes them: no copy is made (a copy would put the
+    peak of HBM at state + params), so the caller keeps no use of them and
+    takes a host copy (``np.array``; on the CPU ``np.asarray`` is a view of
+    the buffer, which then is quietly not given away) first of whatever it
+    wants to compare with later. What follows:
+
+    - a lone replica's step at commit-pipeline depth 0
+      (:meth:`make_step_fn`, :meth:`_lone_step`) takes its verdict FIRST
+      and then runs one fused program that is given ``params`` and
+      ``opt_state`` (``jax.jit`` donation): the new state is written where
+      the old one was, the device holds ONE copy of the state, and a
+      dispatch allocates one output (the loss) instead of one per leaf.
+      A refused step dispatches nothing to the state, which stays the very
+      objects it was. This is the reference's order (``if
+      manager.should_commit(): optimizer.step()``, torchft updates in
+      place after the vote), and it engages on what the step can observe:
+      lone replica, no error, ``TPUFT_STRICT_COMMIT`` unset, no array that
+      appears twice in the state, and a history ring that keeps one
+      version (``manager.history.max_versions == 1``: a ring asked to keep
+      older versions by reference cannot have them deleted). Otherwise,
+      and on the wire path (:meth:`step`), in the pipelined window and in
+      ``ZeroOptimizer``, the step keeps the speculative order and both
+      copies;
+    - an array read from :attr:`params` (or ``opt_state``) is valid until
+      the next step, which may DELETE it: read them afresh after every
+      step, ``jnp.copy`` what must outlive one;
+    - the state is mutated only between a True verdict and the next
+      ``start_quorum``, under the state-dict write lock, and the history
+      ring's version is replaced inside the same lock: the old arrays are
+      never reachable from ``self`` or the ring outside it;
+    - who else holds the state: the heal donor's ``send_checkpoint`` reads
+      it BY REFERENCE (no copy: a donor's HBM does not grow during a heal),
+      on the quorum thread while the train thread waits for the quorum,
+      and stages host copies before ``wait_quorum`` returns;
+      ``Manager._maybe_publish`` / ``WeightPublisher.publish`` and the
+      joiner's delta-rejoin local state likewise read by reference and are
+      done (host copies staged) before the step that follows dispatches; a
+      capture that outlives a step (``WeightHistory.state_dict_at`` on a
+      ring of one version, a checkpoint saved asynchronously) is a device
+      copy, counted by ``tpuft_state_snapshot_copies_total{key=
+      "optimizer"}`` / ``tpuft_state_snapshot_copy_bytes_total``. A steady
+      window without heals reads 0 copies.
+    - ``tpuft_step_state_donated_total`` / ``tpuft_step_state_kept_total``
+      count the steps by which program wrote their state, and the
+      ``update_dispatch`` event carries ``donated``.
+    """
 
     def __init__(
         self,
@@ -448,6 +514,7 @@ class Optimizer:
         # measured, and (arguments, leaves) of a step function's batch.
         self._state_era = 0
         self._state_leaves = (0, 0)
+        self._state_aliased = False
         self._state_nbytes: Optional[tuple] = None
         self._batch_leaves: Optional[tuple] = None
         self._note_state_structure()
@@ -485,7 +552,12 @@ class Optimizer:
         :meth:`_snapshot_nbytes` measures the state again. Between two
         calls every step reuses both."""
         leaves = jax.tree_util.tree_leaves
-        self._state_leaves = (len(leaves(self.params)), len(leaves(self.opt_state)))
+        params, opt_state = leaves(self.params), leaves(self.opt_state)
+        self._state_leaves = (len(params), len(opt_state))
+        # An array that appears twice (tied leaves) cannot be given away
+        # twice: such a state is never donated.
+        arrays = [id(x) for x in params + opt_state if isinstance(x, jax.Array)]
+        self._state_aliased = len(set(arrays)) != len(arrays)
         self._state_era += 1
 
     def _batch_buffers(self, batch: Any) -> int:
@@ -579,7 +651,7 @@ class Optimizer:
                 )
             raise
         n_params, n_opt = self._state_leaves
-        _count_dispatch(2 * n_params + n_opt, n_params + n_opt)
+        _count_dispatch(2 * n_params + n_opt, n_params + n_opt, donated=False)
         return self._commit_and_adopt(
             heal_count,
             spec,
@@ -660,8 +732,6 @@ class Optimizer:
         ):
             hist = getattr(self.manager, "history", None)
             try:
-                from torchft_tpu.history import WeightHistory
-
                 if not isinstance(hist, WeightHistory):
                     return  # scripted/mocked managers without a real ring
                 state = {"params": params, "opt_state": opt_state}
@@ -964,13 +1034,17 @@ class Optimizer:
         the identity-skip condition, see ``Manager.is_lone_replica``) the
         averaged gradient IS the local gradient, so nothing needs to leave
         the device: the whole loss+grad+update runs as ONE jitted XLA
-        program, exactly like a plain non-FT train step. The update is
-        adopted only if the commit barrier succeeds (and recomputed if the
-        barrier healed this replica), so semantics match :meth:`step` — the
-        fusion removes the last fixed cost the split program pays (the
-        standalone optimizer dispatch), making single-group FT-DDP
-        bitwise-plain compute with only the quorum + commit RPCs on top
-        (the reference's 'FT for free' design point, lighthouse.rs:202-215).
+        program, exactly like a plain non-FT train step, and like it the
+        program is GIVEN the state: the step takes its verdict first and
+        then updates ``params`` / ``opt_state`` in place, one copy of the
+        state on the device (:meth:`_lone_step`; the class docstring says
+        who owns what, and when the step keeps the speculative order and
+        both copies instead). A refused step dispatches nothing to the
+        state; a heal the barrier applied is what steps. The fusion removes
+        the last fixed cost the split program pays (the standalone
+        optimizer dispatch), making single-group FT-DDP bitwise-plain
+        compute with only the quorum + commit RPCs on top (the reference's
+        'FT for free' design point, lighthouse.rs:202-215).
 
         ``loss_fn(params, *batch) -> scalar``; ``on_quorum(seconds)``, when
         given, receives each step's measured quorum wait (telemetry hook).
@@ -989,6 +1063,9 @@ class Optimizer:
         overrides any pipeline depth back to the strict per-step ordering.
         """
         fused = make_jit_fused_step(self.tx, loss_fn)
+        # The same program given its state: compiled at the first lone step
+        # that updates in place (:meth:`_lone_step`), never otherwise.
+        fused_in_place = make_jit_fused_step(self.tx, loss_fn, donate_state=True)
         grad_fn = jax.jit(jax.value_and_grad(loss_fn))
 
         depth = self.manager.commit_pipeline_depth
@@ -1026,65 +1103,7 @@ class Optimizer:
             else:
                 self.manager.wait_quorum()
             if self.manager.errored() is None and self.manager.is_lone_replica():
-                heal_count = self._heal_count
-                loss, spec, recompute = self._lone_dispatch(
-                    fused, grad_fn, batch
-                )
-                # Launch the barrier BEFORE the device sync so the commit
-                # RPC rides under the readiness wait instead of after it
-                # (the wait lasts as long as the step's remaining compute,
-                # so serializing sync -> RPC was pure addition). This widens
-                # .step()'s accepted envelope slightly: .step() bounds the
-                # GRADS pre-vote and risks only a host-side dispatch
-                # failure post-vote, while here a device-side failure of
-                # the whole fused step can land after the vote was sent.
-                # The blast radius in this LONE topology is bounded
-                # accounting, not divergence: there is no peer to diverge
-                # from, and recovery is the same supervisor-restart path
-                # .step() documents — the committed counter can run one
-                # step ahead of the restored state (a phantom commit).
-                # Deployments that prefer the strict reference ordering
-                # (vote only after observed completion; reference
-                # manager.py:816-827) set TPUFT_STRICT_COMMIT=1 and pay
-                # the serialized sync; a sync failure then raises before
-                # any vote leaves, the pre-change semantics exactly.
-                strict = os.environ.get("TPUFT_STRICT_COMMIT", "0") == "1"
-                if strict:
-                    _sync_device(loss)
-                commit_future = self.manager.should_commit_async(None)
-                if not strict:
-                    try:
-                        _sync_device(loss)
-                    except BaseException:
-                        try:
-                            barrier_result = commit_future.result()
-                        except Exception:
-                            logger.exception(
-                                "commit barrier also failed while handling a "
-                                "fused-step sync failure; barrier outcome lost "
-                                "to the re-raise"
-                            )
-                        else:
-                            if barrier_result:
-                                metrics.inc(
-                                    "tpuft_phantom_commits_total",
-                                    **_replica_labels(self.manager),
-                                )
-                                _trace_of(self.manager).record("phantom_commit")
-                            logger.error(
-                                "fused step sync failed with the commit barrier "
-                                "in flight; barrier resolved committed=%s (a "
-                                "committed step here advanced the step counter "
-                                "without its update)",
-                                barrier_result,
-                            )
-                        raise
-
-                committed = self._commit_and_adopt(
-                    heal_count, spec, recompute, None,
-                    commit_future=commit_future,
-                )
-                return loss, committed
+                return self._lone_step(fused, fused_in_place, grad_fn, batch)
             return self._wire_step(grad_fn, batch, should_quantize)
 
         return step_fn
@@ -1092,6 +1111,154 @@ class Optimizer:
     # ------------------------------------------------------------------
     # make_step_fn seams (overridden by zero.ZeroOptimizer)
     # ------------------------------------------------------------------
+
+    def _lone_step(
+        self, fused: Any, fused_in_place: Any, grad_fn: Any, batch: Any
+    ):
+        """One lone-replica step at depth 0, after the quorum: returns
+        ``(loss, committed)``. Base: vote first and update the state in
+        place wherever nothing else may hold the old state (the class
+        docstring's ownership contract), else the speculative order.
+        ``ZeroOptimizer``, whose programs differ, stays on the latter."""
+        if self._may_update_in_place():
+            return self._vote_then_update_in_place(fused_in_place, grad_fn, batch)
+        return self._speculative_lone_step(fused, grad_fn, batch)
+
+    def _may_update_in_place(self) -> bool:
+        """Whether the old state may be given away once this step's verdict
+        is in. Read every step from what can be observed, no option:
+        strict mode votes only after observed completion, so nothing can be
+        given away before its vote; a tied array cannot be given twice; and
+        a ring asked to keep older versions holds them by reference."""
+        if os.environ.get("TPUFT_STRICT_COMMIT", "0") == "1" or self._state_aliased:
+            return False
+        hist = getattr(self.manager, "history", None)
+        return isinstance(hist, WeightHistory) and hist.max_versions == 1
+
+    def _vote_then_update_in_place(
+        self, fused_in_place: Any, grad_fn: Any, batch: Any
+    ):
+        """The reference's order (``if manager.should_commit():
+        optimizer.step()``): the verdict, then ONE fused program that is
+        given the state. The lone replica's verdict is a function of what
+        the host already knows (enough participants, no reported error: no
+        peer's gradient to wait for), the speculative order sent it before
+        the device had finished anyway, and ``should_commit`` has stopped
+        serving checkpoints before it returns. What it costs is the commit
+        RPC no longer hidden under the step's compute.
+
+        A refused step dispatches nothing to the state. An accepted one
+        reads the state AFTER the verdict (a heal that ``should_commit``
+        applied is what steps, gradient and all), and replaces it, in
+        ``self`` and in the history ring, inside the state-dict write
+        lock. A failure after a True verdict, on the host or on the device,
+        is the phantom commit the speculative order knows: the counter
+        advanced without a verified update, the supervisor-restart path
+        owns the recovery."""
+        manager = self.manager
+        trace = _trace_of(manager)
+        with tracing.phase("commit_wait", trace):
+            committed = manager.should_commit()
+        if not committed:
+            # The return contract's loss, from the program that takes
+            # nothing: compiled at the first refusal only.
+            loss, _ = grad_fn(self.params, *batch)
+            return _sync_device(loss), False
+        try:
+            manager.disallow_state_dict_read()
+            try:
+                with tracing.phase(
+                    "update_dispatch", trace, fused=True, donated=True
+                ):
+                    loss, params, opt_state = fused_in_place(
+                        self.params, self.opt_state, *batch
+                    )
+                n_state = sum(self._state_leaves)
+                _count_dispatch(
+                    n_state + self._batch_buffers(batch), 1 + n_state,
+                    donated=True,
+                )
+                with tracing.phase("adopt", trace):
+                    with tracing.phase("state_swap", trace):
+                        self.params, self.opt_state = params, opt_state
+                    self._promote_committed(
+                        self._int_or_none(manager.current_step()),
+                        params, opt_state,
+                    )
+            finally:
+                manager.allow_state_dict_read()
+            _sync_device(loss)
+        except BaseException as e:
+            metrics.inc("tpuft_phantom_commits_total", **_replica_labels(manager))
+            trace.record("phantom_commit", error=str(e))
+            logger.error(
+                "fused step failed after its commit vote resolved "
+                "committed=True (the step counter advanced without a "
+                "verified update)"
+            )
+            raise
+        return loss, True
+
+    def _speculative_lone_step(self, fused: Any, grad_fn: Any, batch: Any):
+        """The lone step that keeps the old state until its verdict:
+        dispatch speculatively, vote with the device work in flight (or
+        after it, ``TPUFT_STRICT_COMMIT=1``), adopt on commit."""
+        heal_count = self._heal_count
+        loss, spec, recompute = self._lone_dispatch(fused, grad_fn, batch)
+        # Launch the barrier BEFORE the device sync so the commit
+        # RPC rides under the readiness wait instead of after it
+        # (the wait lasts as long as the step's remaining compute,
+        # so serializing sync -> RPC was pure addition). This widens
+        # .step()'s accepted envelope slightly: .step() bounds the
+        # GRADS pre-vote and risks only a host-side dispatch
+        # failure post-vote, while here a device-side failure of
+        # the whole fused step can land after the vote was sent.
+        # The blast radius in this LONE topology is bounded
+        # accounting, not divergence: there is no peer to diverge
+        # from, and recovery is the same supervisor-restart path
+        # .step() documents — the committed counter can run one
+        # step ahead of the restored state (a phantom commit).
+        # Deployments that prefer the strict reference ordering
+        # (vote only after observed completion; reference
+        # manager.py:816-827) set TPUFT_STRICT_COMMIT=1 and pay
+        # the serialized sync; a sync failure then raises before
+        # any vote leaves, the pre-change semantics exactly.
+        strict = os.environ.get("TPUFT_STRICT_COMMIT", "0") == "1"
+        if strict:
+            _sync_device(loss)
+        commit_future = self.manager.should_commit_async(None)
+        if not strict:
+            try:
+                _sync_device(loss)
+            except BaseException:
+                try:
+                    barrier_result = commit_future.result()
+                except Exception:
+                    logger.exception(
+                        "commit barrier also failed while handling a "
+                        "fused-step sync failure; barrier outcome lost "
+                        "to the re-raise"
+                    )
+                else:
+                    if barrier_result:
+                        metrics.inc(
+                            "tpuft_phantom_commits_total",
+                            **_replica_labels(self.manager),
+                        )
+                        _trace_of(self.manager).record("phantom_commit")
+                    logger.error(
+                        "fused step sync failed with the commit barrier "
+                        "in flight; barrier resolved committed=%s (a "
+                        "committed step here advanced the step counter "
+                        "without its update)",
+                        barrier_result,
+                    )
+                raise
+
+        committed = self._commit_and_adopt(
+            heal_count, spec, recompute, None, commit_future=commit_future
+        )
+        return loss, committed
 
     def _lone_dispatch(self, fused: Any, grad_fn: Any, batch: Any):
         """Dispatches the lone-replica step's device work; returns
@@ -1103,13 +1270,15 @@ class Optimizer:
         # heal-during-barrier recompute below.
         pre_params = self.params
         with tracing.phase(
-            "update_dispatch", _trace_of(self.manager), fused=True
+            "update_dispatch", _trace_of(self.manager), fused=True, donated=False
         ):
             loss, spec_params, spec_opt_state = fused(
                 self.params, self.opt_state, *batch
             )
         n_state = sum(self._state_leaves)
-        _count_dispatch(n_state + self._batch_buffers(batch), 1 + n_state)
+        _count_dispatch(
+            n_state + self._batch_buffers(batch), 1 + n_state, donated=False
+        )
 
         def recompute():
             # Same semantics as :meth:`step` (and the reference's

@@ -924,7 +924,9 @@ class ZeroOptimizer(Optimizer):
             # A shard hands over its gradient range, its master and its
             # optax state, and takes the last two back.
             n_opt = len(self._opt_leaf_templates)
-            _count_dispatch(len(ids) * (2 + n_opt), len(ids) * (1 + n_opt))
+            _count_dispatch(
+                len(ids) * (2 + n_opt), len(ids) * (1 + n_opt), donated=False
+            )
             for slot, s in enumerate(ids):
                 new_held[s] = _ShardState(
                     step=pre_state.step + 1,
@@ -976,6 +978,15 @@ class ZeroOptimizer(Optimizer):
         loss, grads = grad_fn(self.params, *batch)
         committed = self.step(grads)
         return loss, committed
+
+    def _lone_step(
+        self, fused: Any, fused_in_place: Any, grad_fn: Any, batch: Any
+    ):
+        # The lone step's programs here are a gradient program and a shard
+        # update over held ranges that the re-balance exchange and the heal
+        # address by reference: nothing is given away, the order stays
+        # speculative.
+        return self._speculative_lone_step(fused, grad_fn, batch)
 
     def _lone_dispatch(self, fused: Any, grad_fn: Any, batch: Any):
         self._maybe_rebalance()
