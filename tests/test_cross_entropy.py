@@ -1,5 +1,8 @@
 """Fused linear+CE (ops/cross_entropy.py): value/grad parity with the
-materialized path, and the Llama targets= loss mode."""
+materialized path, the loss by token under a cotangent by token, and the
+Llama targets= loss mode."""
+
+import hashlib
 
 from dataclasses import replace
 
@@ -9,7 +12,7 @@ import numpy as np
 import pytest
 
 from torchft_tpu.models.llama import CONFIGS, Llama, cross_entropy_loss
-from torchft_tpu.ops.cross_entropy import chunked_cross_entropy
+from torchft_tpu.ops.cross_entropy import chunked_cross_entropy, chunked_cross_entropy_by_token
 
 
 def _dense_ref(x, w, targets):
@@ -78,6 +81,76 @@ def test_out_of_range_targets_clamp_consistently() -> None:
     ref = chunked_cross_entropy(x, w, clamped, None)
     np.testing.assert_allclose(float(dense), float(ref), rtol=1e-6)
     np.testing.assert_allclose(float(chunked), float(ref), rtol=1e-5)
+
+
+@pytest.mark.parametrize(
+    "dtype,vocab,chunk",
+    [
+        (jnp.float32, 512, 64), (jnp.float32, 500, 64), (jnp.float32, 500, None),
+        (jnp.bfloat16, 512, 128), (jnp.float32, 512, 512),
+    ],
+)
+def test_the_loss_by_token_matches_dense_under_a_cotangent_by_token(dtype, vocab, chunk) -> None:
+    """Every token's own loss, in ``targets``' shape, and its two gradients
+    when each token's loss is weighed by a number of its own (a model that
+    weighs its exits by a learned probability: models/ouro.py)."""
+    b, s, d = 3, 8, 32
+    kx, kw, kt, kg = jax.random.split(jax.random.PRNGKey(7), 4)
+    x = jax.random.normal(kx, (b, s, d), dtype)
+    w = jax.random.normal(kw, (d, vocab), dtype) * 0.1
+    targets = jax.random.randint(kt, (b, s), 0, vocab)
+    weights = jax.random.uniform(kg, (b, s), minval=-1.0, maxval=2.0)  # no two tokens alike
+
+    def dense(x, w):
+        logits = jnp.dot(x.astype(jnp.float32), w.astype(jnp.float32))
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        return -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+
+    fused = lambda x, w: chunked_cross_entropy_by_token(x, w, targets, chunk)
+    got, want = jax.jit(fused)(x, w), dense(x, w)
+    assert got.shape == targets.shape and got.dtype == jnp.float32
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    weighed = lambda f: jax.jit(jax.grad(lambda x, w: jnp.sum(weights * f(x, w)), argnums=(0, 1)))
+    tol = dict(rtol=2e-2, atol=2e-3) if dtype == jnp.bfloat16 else dict(rtol=2e-5, atol=1e-6)
+    for mine, plain in zip(weighed(fused)(x, w), weighed(dense)(x, w)):
+        assert mine.shape == plain.shape and mine.dtype == plain.dtype
+        np.testing.assert_allclose(np.asarray(mine, np.float32), np.asarray(plain, np.float32), **tol)
+
+
+@pytest.mark.parametrize("vocab,chunk", [(512, 64), (500, 64), (500, None)])
+def test_the_mean_of_the_loss_by_token_is_the_mean_loss_to_the_bit(vocab, chunk) -> None:
+    n, d = 24, 32
+    kx, kw, kt = jax.random.split(jax.random.PRNGKey(0), 3)
+    x = jax.random.normal(kx, (n, d), jnp.bfloat16)
+    w = jax.random.normal(kw, (d, vocab), jnp.bfloat16) * 0.1
+    targets = jax.random.randint(kt, (n,), 0, vocab)
+    mean = lambda x, w: chunked_cross_entropy(x, w, targets, chunk)
+    of_tokens = lambda x, w: jnp.mean(chunked_cross_entropy_by_token(x, w, targets, chunk))
+    value, grads = jax.jit(jax.value_and_grad(mean, argnums=(0, 1)))(x, w)
+    again, again_grads = jax.jit(jax.value_and_grad(of_tokens, argnums=(0, 1)))(x, w)
+    assert float(value) == float(again)
+    for a, b in zip(grads, again_grads):
+        np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
+
+
+@pytest.mark.parametrize(
+    "chunk,golden",
+    [
+        (64, "a065409c323640de07b722901786fe188d98b4c98540bfb6b35f78378a09c226"),
+        (None, "3934d1ea7ebfd018cc1dbacc3936fa052f3d196c0bd8e8ca94281be5007f9578"),
+    ],
+)
+def test_the_mean_losss_program_is_the_one_it_was_before_the_loss_by_token(chunk, golden) -> None:
+    """The jaxpr of the mean loss and its two gradients, as commit 712ab83 (the
+    parent of PR 62, which had no loss by token) traced it: ``by_token`` is
+    static, so six cells' step programs did not move with the seventh's loss
+    (their lowered modules were compared whole in that PR; this holds the op)."""
+    x, w = jnp.zeros((24, 32), jnp.bfloat16), jnp.zeros((32, 500), jnp.bfloat16)
+    targets = jnp.zeros((24,), jnp.int32)
+    traced = jax.make_jaxpr(jax.value_and_grad(
+        lambda x, w: chunked_cross_entropy(x, w, targets, chunk), argnums=(0, 1)
+    ))(x, w)
+    assert hashlib.sha256(str(traced).encode()).hexdigest() == golden
 
 
 @pytest.mark.parametrize("tied", [False, True])

@@ -803,3 +803,80 @@ def test_a_block_defined_here_runs_through_layer_stack(scan_layers, remat) -> No
     for got, wanted in zip(*map(jax.tree_util.tree_leaves, (looped(grads), want_grads))):
         assert float(jnp.linalg.norm(wanted)) > 0
         np.testing.assert_allclose(np.asarray(got), np.asarray(wanted), rtol=1e-5, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# The four models a loop over the stack does not concern (PR 62)
+# ---------------------------------------------------------------------------
+
+# model file -> (configuration, rehearsal overlay, sha256 of the seeded tree,
+# sha256 of the jaxpr of the loss and its gradient), as commit 712ab83 (the
+# parent of PR 62: no ``looped_stack``, no loss by token) made them.
+_UNLOOPED = {
+    "llama": (
+        "mistral-7b-v0.3-1chip", "rehearsal.json",
+        "e18ffe1f23a55721232fec596784d8feae9b7ce01394ddeaf6d46d34eee1014a",
+        "ff9db91479f3b49aa3bb1a579e5c266f3442f8bf5f7af16e74648df3dfa8e991",
+    ),
+    "keye": (
+        "keye-vl2-30b-a3b-ep8-1chip", "rehearsal-keye.json",
+        "1c29e353da905d45f9faf339e3523ac623284158c2a6c85a692f3fb92b6f47bf",
+        "d8174fefa7d1a3bbcb1cdd9c8328b35ac37b4e6ba527a487420805fcab2650b0",
+    ),
+    "smallthinker": (
+        "smallthinker-21b-a3b-ep8-1chip", "rehearsal-smallthinker.json",
+        "cefe89df40d01b356f0b994ca5b718b436dd434db87198adf16f901df90a840b",
+        "4b167ff1c09a910cdd677c017b2e1ab4c5842298c091df3f326c80493d46a739",
+    ),
+    "granite": (
+        "granite-4.0-h-micro-1chip", "rehearsal-granite.json",
+        "5ff201b99d949a9c09bed1c7a99b82ebb6d13f50e3f6ceb06607d7338b9af6e0",
+        "7b99f6045539718fffb0f96c2bbef019078cdc4d912535f6e16f9db3f83ebc3b",
+    ),
+}
+
+
+@pytest.fixture(scope="module")
+def unlooped_digests():
+    """{model: (tree digest, program digest)} of the four models at their
+    cells' rehearsal sizes, scanned as the cells run them, each traced once."""
+    import hashlib
+    import json
+    import re
+    from pathlib import Path
+
+    from chipbench import spec
+
+    root = Path(__file__).resolve().parents[1]
+    found = {}
+    for name, (config_name, overlay_name, _, _) in _UNLOOPED.items():
+        config = json.loads((root / f"chipbench/configs/{config_name}.json").read_text())
+        overlay = json.loads((root / f"chipbench/fixtures/{overlay_name}").read_text())
+        config = {**config, **overlay["config"]}
+        config["run"] = {**config["run"], **overlay["run"]}
+        architecture = spec.load_module(root / f"chipbench/architectures/{config['model_type']}.py")
+        model = architecture.build(config, 64)
+        tokens = jnp.zeros((1, 65), jnp.int32)
+        params = model.init(jax.random.PRNGKey(0), tokens[:, :-1])
+        tree = hashlib.sha256()
+        for path, leaf in jax.tree_util.tree_leaves_with_path(params):
+            tree.update(("/".join(str(k.key) for k in path) + str(leaf.shape) + str(leaf.dtype)).encode())
+            tree.update(jax.device_get(leaf).tobytes())
+        traced = jax.make_jaxpr(jax.value_and_grad(
+            lambda p: model.apply(p, tokens[:, :-1], targets=tokens[:, 1:])
+        ))(params)
+        text = re.sub(r" at 0x[0-9a-f]+", "", str(traced))
+        found[name] = (tree.hexdigest(), hashlib.sha256(text.encode()).hexdigest())
+    return found
+
+
+@pytest.mark.parametrize("what", ["tree", "program"])
+@pytest.mark.parametrize("name", sorted(_UNLOOPED))
+def test_a_model_whose_stack_runs_once_is_what_it_was_before_the_loop(name, what, unlooped_digests) -> None:
+    """``layer_stack``, the head and the fused mean loss serve the four older
+    models as they did: the same seeded leaves under the same paths, and the
+    same traced loss and gradient, op for op (on the chip their lowered step
+    programs were compared whole, parent against change: PERF.md, PR 62)."""
+    golden = _UNLOOPED[name][2:]
+    index = ["tree", "program"].index(what)
+    assert unlooped_digests[name][index] == golden[index]

@@ -189,10 +189,10 @@ def _kernel_refs(call) -> list:
     return [(v.aval.shape, str(v.aval.dtype)) for v in call.params["jaxpr"].invars]
 
 
-def _cell_gradient(kv, with_selection):
-    """The traced gradient of ``flash_attention`` at 1 x 8192 with 32 q heads
-    of 128 over ``kv`` KV heads, default blocks; its two pallas_calls."""
-    b, s, h, d = 1, 8192, 32, 128
+def _cell_gradient(kv, with_selection, h=32):
+    """The traced gradient of ``flash_attention`` at 1 x 8192 with ``h`` (32) q
+    heads of 128 over ``kv`` KV heads, default blocks; its two pallas_calls."""
+    b, s, d = 1, 8192, 128
     q = _sds((b, s, h, d), jnp.bfloat16)
     k = _sds((b, s, kv, d), jnp.bfloat16)
     selection = _sds((b, s, s), jnp.int8)
@@ -244,6 +244,21 @@ def test_without_a_selection_the_calls_are_the_ones_the_mistral_cells_run():
     assert len(backward.invars) == 9
     # No int8 tensor among the lowered module's values (">" is no letter of
     # the base64 the Mosaic bodies are written in).
+    assert "xi8>" not in traced.lower(lowering_platforms=("tpu",)).as_text()
+
+
+def test_with_no_shared_head_the_calls_are_the_same_at_the_ouro_cells_geometry():
+    """16 query heads over 16 key-value heads of 128 (the cell
+    ``ouro-2.6b-1chip.ftddp-seq8k``, the first whose KV heads are not shared):
+    the listed walk and the pair classes serve it unchanged. The grids count 16
+    heads, the one table lists the same 72 pairs, every block and scratch is
+    the Mistral cells', and k and v come 16 heads wide."""
+    traced, forward, backward = _cell_gradient(kv=16, with_selection=False, h=16)
+    assert forward.params["grid_mapping"].grid == (1, 16, 72)
+    assert backward.params["grid_mapping"].grid == (1, 16, 1, 72)
+    assert _kernel_refs(forward) == _TABLES + _QKV + _POSITIONS + _FWD_REST
+    assert _kernel_refs(backward) == _TABLES + _QKV + _BWD_ROWS + _POSITIONS + _BWD_REST
+    assert [v.aval.shape for v in forward.invars[1:4]] == [(1, 16, 8192, 128)] * 3
     assert "xi8>" not in traced.lower(lowering_platforms=("tpu",)).as_text()
 
 

@@ -1319,3 +1319,77 @@ def test_the_granite_cells_reference_programs_fit_the_chip(chip) -> None:
     assert total(loss) < 4.5 * 2**30
     update = reference.make_loss_after_first_update(architecture, config)
     assert total(update.lower(params, tokens, tokens).compile()) < 10.5 * 2**30
+
+
+def test_dots_step_of_the_ouro_cell_is_one_traced_layer_for_32_passes_and_fits(chip, monkeypatch) -> None:
+    """The FT-DDP fused step of the cell ``ouro-2.6b-1chip.ftddp-seq8k`` (eight
+    layers run four times on one set of weights, bf16, ``dots``, AdamW with a
+    float32 first moment, state donated) compiled for a described v5e as the
+    model builds it on a TPU. The layers are one scan and the passes a scan
+    around it, so the program holds ONE flash forward and ONE backward call for
+    its 32 layer passes, under the model's scope; what ``dots`` keeps (the flash
+    pair and the unit's output) brings arguments, results and temporaries to
+    13.49 of the chip's 15.75 GiB (PERF.md section 6, PR 62; with gate and up
+    kept as well it does not compile: 15.86); the three scopes reach the
+    compiled text; and the architecture file's shapes find the exits' slabs in
+    it under ``tpuft::exit`` and nothing of a layer pass."""
+    import json
+    from pathlib import Path
+
+    import torchft_tpu.ops.attention as attention
+    import torchft_tpu.ops.flash_attention as flash
+    from chipbench import spec
+    from chipbench.model import System
+    from torchft_tpu.optim import make_jit_fused_step
+
+    for module in (attention, flash):
+        monkeypatch.setattr(module, "on_tpu", lambda: True)
+    root = Path(__file__).parent.parent
+    config = json.loads((root / "chipbench/configs/ouro-2.6b-1chip.json").read_text())
+    assert config["run"]["remat"] == "dots" and config["run"]["scan_layers"]
+    architecture = spec.load_module(root / "chipbench/architectures/ouro.py")
+    system = System(config, architecture, {"batch": 1, "seq": 8192}, seed=0)
+    params = jax.eval_shape(system.init_params)
+    leaves = jax.tree_util.tree_leaves(params)
+    assert sum(leaf.size for leaf in leaves) == architecture.parameter_counts(config)["total"] == 461_443_073
+    assert params["params"]["layers"]["block"]["mlp"]["w_up"]["kernel"].shape == (8, 2048, 5632)  # ONE copy
+    opt_state = jax.eval_shape(system.tx.init, params)
+    state_bytes = sum(
+        leaf.size * leaf.dtype.itemsize for leaf in jax.tree_util.tree_leaves((params, opt_state))
+    )
+    assert 3.43 * 2**30 < state_bytes < 3.45 * 2**30  # 8 bytes a parameter: bf16, float32 mu, bf16 nu
+    program = (
+        make_jit_fused_step(system.tx, system.loss_fn, donate_state=True)
+        .lower(
+            _sds_tree(params, chip), _sds_tree(opt_state, chip),
+            _sds((1, 8193), jnp.int32, chip),
+        )
+        .compile()
+    )
+    calls = _mosaic_calls(program)
+    assert len(calls) == 2 and all("tpuft__ouro_attention" in name for name, _, _ in calls), calls
+    for name, stated, used in calls:  # inside the VMEM a call gets without asking
+        assert not stated and max(used, default=0) <= 16 * 2**20, (name, stated, used)
+    text = program.as_text()
+    for scope in ("tpuft::ouro_attention", "tpuft::loop_pass", "tpuft::exit"):
+        assert scope in text, scope
+    # The reader's shapes on the program's own instructions: found under the
+    # exits' scope, never under a layer pass's; the float32 (n, d) result, the
+    # gradient to an exit's state summed over the slabs, among them.
+    found = {"exit": 0, "pass": 0, "state": 0}
+    for line in text.splitlines():
+        match = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = \(?([a-z0-9]+\[[0-9,]*\])", line)
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        if not match or not op_name or " fusion(" not in line and " convolution(" not in line:
+            continue
+        if architecture.is_exit_loss_op(match.group(2), config, 1, 8192):
+            found["pass" if "tpuft::loop_pass" in op_name.group(1) else "exit"] += 1
+            found["state"] += match.group(2) == "f32[8192,2048]"
+            assert "tpuft::loop_pass" in op_name.group(1) or "tpuft::exit" in op_name.group(1), line[:300]
+    assert found["exit"] >= 5 and found["pass"] == 0 and found["state"] >= 1, found
+    memory = program.memory_analysis()
+    total = (
+        memory.argument_size_in_bytes + memory.output_size_in_bytes
+        + memory.temp_size_in_bytes - memory.alias_size_in_bytes
+    )
+    assert 13.0 * 2**30 < total < 14.0 * 2**30, total / 2**30

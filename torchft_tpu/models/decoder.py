@@ -1,10 +1,11 @@
 """What every decoder here shares, written once: rotary, the norm, the output
 head with its fused loss, the ``dots`` remat rule and the layer stack (of one
-kind of block, or of a period of kinds). A model file (models/llama.py,
-models/keye.py, models/smallthinker.py) is a config, a block and a top-level
-module of embedding, :func:`layer_stack`, final norm and head; its attention
-asks ops/ for a kernel (ops/attention.py, ops/sparse_attention.py), and a
-routed expert layer is models/experts.py's.
+kind of block, or of a period of kinds; run once, or by :func:`looped_stack`
+several times on ONE set of parameters). A model file (models/llama.py,
+models/keye.py, models/smallthinker.py, models/granite.py, models/ouro.py) is
+a config, a block and a top-level module of embedding, :func:`layer_stack`,
+final norm and head; its attention asks ops/ for a kernel (ops/attention.py,
+ops/sparse_attention.py), and a routed expert layer is models/experts.py's.
 """
 
 from __future__ import annotations
@@ -15,11 +16,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from torchft_tpu.ops.cross_entropy import chunked_cross_entropy
+from torchft_tpu.ops.cross_entropy import chunked_cross_entropy, chunked_cross_entropy_by_token
 
 __all__ = [
     "apply_rope", "RMSNorm", "LMHead", "tied_head", "into_residual", "remat_policy",
-    "layer_stack", "smallest_period", "sown_by_layer",
+    "layer_stack", "looped_stack", "smallest_period", "sown_by_layer",
 ]
 
 
@@ -57,7 +58,9 @@ class LMHead(nn.Module):
     the kernel directly lets the fused loss path hand it to
     :func:`~torchft_tpu.ops.cross_entropy.chunked_cross_entropy` without
     ever forming the logits. With ``targets`` the mean token cross-entropy,
-    in vocabulary slabs of ``loss_vocab_chunk`` (None = dense)."""
+    in vocabulary slabs of ``loss_vocab_chunk`` (None = dense), or with
+    ``by_token`` every token's own (a model with several exits calls the one
+    head once an exit and weighs the tokens itself)."""
 
     dim: int
     vocab_size: int
@@ -65,13 +68,16 @@ class LMHead(nn.Module):
     loss_vocab_chunk: Optional[int] = None
 
     @nn.compact
-    def __call__(self, x: jnp.ndarray, targets: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    def __call__(
+        self, x: jnp.ndarray, targets: Optional[jnp.ndarray] = None, by_token: bool = False
+    ) -> jnp.ndarray:
         kernel = self.param(
             "kernel", nn.initializers.lecun_normal(), (self.dim, self.vocab_size), self.dtype
         )
         if targets is None:
             return jnp.dot(x, kernel.astype(self.dtype))
-        return chunked_cross_entropy(x, kernel, targets, self.loss_vocab_chunk)
+        loss = chunked_cross_entropy_by_token if by_token else chunked_cross_entropy
+        return loss(x, kernel, targets, self.loss_vocab_chunk)
 
 
 def tied_head(
@@ -208,11 +214,57 @@ def layer_stack(
             # boundary already blocks the CSE remat would otherwise fight.
             cell = nn.remat(cell, policy=policy, prevent_cse=False)
         return _scanned(cell, cfg.n_layers)(block, cfg, name="layers")(x, positions)[0]
+    for layer in _inlined_layers(block, cfg, policy):
+        x = layer(x, positions)
+    return x
+
+
+def _inlined_layers(block: Any, cfg: Any, policy: Any) -> list:
+    """The blocks of a one-kind stack as modules ``layer_<i>`` of the calling
+    model, each rematerialised on its own where ``cfg.remat`` asks."""
     if cfg.remat != "none":
         block = nn.remat(block, policy=policy)
-    for layer in range(cfg.n_layers):
-        x = block(cfg, name=f"layer_{layer}")(x, positions)
-    return x
+    return [block(cfg, name=f"layer_{layer}") for layer in range(cfg.n_layers)]
+
+
+def looped_stack(
+    model: nn.Module, block: Any, cfg: Any, policy: Any, x: jnp.ndarray, positions: jnp.ndarray,
+    loops: int, after_pass: Any,
+) -> jnp.ndarray:
+    """:func:`layer_stack` run ``loops`` times on ONE set of parameters, the
+    state carried from pass to pass: ``x <- after_pass()(layer_stack(x))``,
+    ``after_pass()`` the module every pass ends in (the final norm, shared by
+    the passes like the layers). Returns every pass's state, ``(loops, *x.shape)``.
+    Called inside ``model``'s ``__call__`` with the model itself.
+
+    The tree is :func:`layer_stack`'s (``layers/block/...`` with a leading axis
+    of layers, or ``layer_<i>/...``), ONE copy whatever ``loops`` is: under
+    ``cfg.scan_layers`` the passes are a scan too whose parameters are
+    BROADCAST to every pass (``variable_broadcast``: closed over, not stacked
+    along the loop's axis), one traced pass for all of them; otherwise the
+    same modules are called again pass after pass. Either way a weight's
+    gradient is the sum of its ``loops`` uses, which autodiff carries in the
+    weight's own dtype. A block may not sow inside the passes (nothing of a
+    scan over broadcast parameters is stacked by pass)."""
+    if cfg.scan_layers:
+        def one_pass(_model, carry, _):
+            with jax.named_scope("tpuft::loop_pass"):
+                carry = layer_stack(block, cfg, policy, carry, positions)
+            carry = after_pass()(carry)
+            return carry, carry
+
+        passes = nn.scan(
+            one_pass, variable_broadcast="params", split_rngs={"params": False}, length=loops
+        )
+        return passes(model, x, None)[1]
+    layers, ends_in, states = _inlined_layers(block, cfg, policy), after_pass(), []
+    for _ in range(loops):
+        with jax.named_scope("tpuft::loop_pass"):
+            for layer in layers:
+                x = layer(x, positions)
+        x = ends_in(x)
+        states.append(x)
+    return jnp.stack(states)
 
 
 def sown_by_layer(

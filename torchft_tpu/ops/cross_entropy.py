@@ -19,6 +19,13 @@ residuals and reconstruct exactly the array this op exists to avoid).
 
 FLOPs are identical to the dense path (the matmul is computed once per
 direction either way); what changes is peak HBM and the fusion shape.
+
+Two results of the one op: :func:`chunked_cross_entropy`, the MEAN token loss
+(a scalar cotangent in the backward), and
+:func:`chunked_cross_entropy_by_token`, every token's own loss (a cotangent BY
+TOKEN: a model that weighs each token's loss at each of several exits by a
+learned probability, models/ouro.py). ``by_token`` is static, so the mean's
+program is the one it was before the second result existed.
 """
 
 from __future__ import annotations
@@ -29,7 +36,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-__all__ = ["chunked_cross_entropy"]
+__all__ = ["chunked_cross_entropy", "chunked_cross_entropy_by_token"]
 
 
 def _chunk_logits(x2, w, start, chunk):
@@ -41,9 +48,9 @@ def _chunk_logits(x2, w, start, chunk):
     )
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _chunked_ce(x2, w, targets1, chunk, vocab_valid):
-    loss, _ = _ce_fwd(x2, w, targets1, chunk, vocab_valid)
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _chunked_ce(x2, w, targets1, chunk, vocab_valid, by_token):
+    loss, _ = _ce_fwd(x2, w, targets1, chunk, vocab_valid, by_token)
     return loss
 
 
@@ -54,7 +61,7 @@ def _col_mask(idx, chunk, vocab_valid):
     return idx * chunk + jnp.arange(chunk) < vocab_valid
 
 
-def _ce_fwd(x2, w, targets1, chunk, vocab_valid):
+def _ce_fwd(x2, w, targets1, chunk, vocab_valid, by_token):
     n, d = x2.shape
     vocab = w.shape[1]
     n_chunks = vocab // chunk
@@ -84,16 +91,17 @@ def _ce_fwd(x2, w, targets1, chunk, vocab_valid):
     )
     (m, s, tl), _ = jax.lax.scan(body, init, jnp.arange(n_chunks))
     lse = m + jnp.log(s)
-    loss = jnp.mean(lse - tl)
+    loss = lse - tl if by_token else jnp.mean(lse - tl)
     return loss, (x2, w, targets1, lse)
 
 
-def _ce_bwd(chunk, vocab_valid, residuals, g):
+def _ce_bwd(chunk, vocab_valid, by_token, residuals, g):
     x2, w, targets1, lse = residuals
     n, d = x2.shape
     vocab = w.shape[1]
     n_chunks = vocab // chunk
-    scale = g / n  # d(mean)/d(per-row loss)
+    # By token the cotangent is a row's own; of the mean, d(mean)/d(per-row loss).
+    scale = g[:, None] if by_token else g / n
 
     def body(carry, idx):
         dx, dw = carry
@@ -132,6 +140,27 @@ def _ce_bwd(chunk, vocab_valid, residuals, g):
 _chunked_ce.defvjp(_ce_fwd, _ce_bwd)
 
 
+def _token_losses(x, w, targets, vocab_chunk, by_token):
+    """What both results share: rows, clamped targets, the dense one-shot for
+    a vocabulary of one slab, the padded tail slab."""
+    d = x.shape[-1]
+    vocab = w.shape[1]
+    x2 = x.reshape(-1, d)
+    targets1 = jnp.clip(targets.reshape(-1).astype(jnp.int32), 0, vocab - 1)
+    if vocab_chunk is None or vocab_chunk >= vocab:
+        logits = jnp.dot(
+            x2.astype(jnp.float32), w.astype(jnp.float32),
+            preferred_element_type=jnp.float32,
+        )
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        tl = jnp.take_along_axis(logp, targets1[:, None], axis=1)[:, 0]
+        return -tl if by_token else -jnp.mean(tl)
+    pad = (-vocab) % vocab_chunk
+    if pad:
+        w = jnp.pad(w, ((0, 0), (0, pad)))
+    return _chunked_ce(x2, w, targets1, vocab_chunk, vocab, by_token)
+
+
 def chunked_cross_entropy(
     x: jnp.ndarray,
     w: jnp.ndarray,
@@ -164,19 +193,18 @@ def chunked_cross_entropy(
     previously the chunked path silently used a 0.0 target logit while
     the dense path clamped (round-3 advisor).
     """
-    d = x.shape[-1]
-    vocab = w.shape[1]
-    x2 = x.reshape(-1, d)
-    targets1 = jnp.clip(targets.reshape(-1).astype(jnp.int32), 0, vocab - 1)
-    if vocab_chunk is None or vocab_chunk >= vocab:
-        logits = jnp.dot(
-            x2.astype(jnp.float32), w.astype(jnp.float32),
-            preferred_element_type=jnp.float32,
-        )
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        tl = jnp.take_along_axis(logp, targets1[:, None], axis=1)[:, 0]
-        return -jnp.mean(tl)
-    pad = (-vocab) % vocab_chunk
-    if pad:
-        w = jnp.pad(w, ((0, 0), (0, pad)))
-    return _chunked_ce(x2, w, targets1, vocab_chunk, vocab)
+    return _token_losses(x, w, targets, vocab_chunk, by_token=False)
+
+
+def chunked_cross_entropy_by_token(
+    x: jnp.ndarray,
+    w: jnp.ndarray,
+    targets: jnp.ndarray,
+    vocab_chunk: Optional[int] = 4096,
+) -> jnp.ndarray:
+    """Every token's cross-entropy, float32 in ``targets``' shape: the vector
+    :func:`chunked_cross_entropy` is the mean of, from the same slabs and
+    under the same clamp. Its backward takes a cotangent by token, so a
+    caller may weigh the tokens as it likes (their mean is
+    :func:`chunked_cross_entropy` to the bit, value and gradients)."""
+    return _token_losses(x, w, targets, vocab_chunk, by_token=True).reshape(targets.shape)
