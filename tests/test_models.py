@@ -811,7 +811,11 @@ def test_a_block_defined_here_runs_through_layer_stack(scan_layers, remat) -> No
 
 # model file -> (configuration, rehearsal overlay, sha256 of the seeded tree,
 # sha256 of the jaxpr of the loss and its gradient), as commit 712ab83 (the
-# parent of PR 62: no ``looped_stack``, no loss by token) made them.
+# parent of PR 62: no ``looped_stack``, no loss by token) made them; the
+# PROGRAMS of keye and smallthinker as PR 63 made them (models/experts.py
+# ``route`` names what it hands the layer: six ``name`` identities more in
+# keye's jaxpr and no other primitive, its compiled step the parent's to the
+# instruction; smallthinker's ``dots`` keeps more), their trees as before.
 _UNLOOPED = {
     "llama": (
         "mistral-7b-v0.3-1chip", "rehearsal.json",
@@ -821,12 +825,12 @@ _UNLOOPED = {
     "keye": (
         "keye-vl2-30b-a3b-ep8-1chip", "rehearsal-keye.json",
         "1c29e353da905d45f9faf339e3523ac623284158c2a6c85a692f3fb92b6f47bf",
-        "d8174fefa7d1a3bbcb1cdd9c8328b35ac37b4e6ba527a487420805fcab2650b0",
+        "aad318734db84e0ba1424a775ecfa11f8c0c4549466db5cc5e28b547a5bc1eea",
     ),
     "smallthinker": (
         "smallthinker-21b-a3b-ep8-1chip", "rehearsal-smallthinker.json",
         "cefe89df40d01b356f0b994ca5b718b436dd434db87198adf16f901df90a840b",
-        "4b167ff1c09a910cdd677c017b2e1ab4c5842298c091df3f326c80493d46a739",
+        "a86d934e83c51297f4a2ccc2a154d0c17a86961b32298c4d2bfec8c885e10fc0",
     ),
     "granite": (
         "granite-4.0-h-micro-1chip", "rehearsal-granite.json",

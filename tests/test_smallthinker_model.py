@@ -3,7 +3,9 @@ seeded weights (CPU): the model against the benchmark's float32 reference
 (chipbench/architectures/smallthinker.py, written from the equations), loss and
 every leaf's gradient, on the CPU's attention paths and through the flash
 kernels (interpreted); the eight shares of the routed layer against the uncut
-layer; and that a layer's routing does not move with its attention. The stack,
+layer; that a layer's routing does not move with its attention; and that a
+remat policy which keeps the router's decisions decides once and differentiates
+the same. The stack,
 the window's edge and the positions are tests/test_smallthinker_stack.py's.
 
     JAX_PLATFORMS=cpu python -m pytest tests/test_smallthinker_model.py -q
@@ -24,9 +26,9 @@ ROOT = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT))
 
 from chipbench import reference, spec  # noqa: E402
-from torchft_tpu.models.experts import RoutedExperts  # noqa: E402
+from torchft_tpu.models.experts import RoutedExperts, route, routing_saveable  # noqa: E402
 from torchft_tpu.models.smallthinker import dispatch_rows, router_load  # noqa: E402
-from torchft_tpu.ops.grouped_matmul import dispatch_rungs  # noqa: E402
+from torchft_tpu.ops.grouped_matmul import dispatch_rungs, routed_experts  # noqa: E402
 
 ARCHITECTURE = spec.load_module(ROOT / "chipbench/architectures/smallthinker.py")
 SEQ, BATCH = 96, 2
@@ -164,6 +166,53 @@ def test_the_eight_shares_add_up_to_the_uncut_expert_layer():
     assert relative(uncut, want) < 1e-5
     # Read from the rows themselves it is another routing.
     assert relative(whole.apply(params, x), uncut) > 1e-2
+
+
+@pytest.mark.parametrize("kept", ["routing", "nothing"])
+def test_a_rematerialised_routed_layer_differentiates_as_the_plain_one(kept):
+    """A share of the routed layer (four of sixteen experts: the ladder and its
+    conditional) under ``jax.checkpoint``: with ``routing_saveable`` the
+    backward reads what ``route`` decided (the differentiated program holds
+    the top-k, the sort and the tally as often as the plain one's), with
+    ``nothing_saveable`` it decides again (each once more); either way the
+    router kernel's gradient, the input's and, through ``route`` and the
+    grouped product alone, the probabilities' (which is the top-k's and the
+    gates' backward) are the plain layer's."""
+    policy = {
+        "routing": routing_saveable, "nothing": jax.checkpoint_policies.nothing_saveable,
+    }[kept]
+    layer = RoutedExperts(
+        dim=32, hidden=24, num_experts=16, experts_per_token=3, num_local_experts=4,
+        expert_share=1, activation=jax.nn.relu, dtype=jnp.float32,
+    )
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, 32))
+    params = layer.init(jax.random.PRNGKey(1), x)
+    target = jax.random.normal(jax.random.PRNGKey(2), x.shape)
+    weights = [params["params"][name] for name in ("w_gate", "w_up", "w_down")]
+
+    def through_layer(p, x):
+        return jnp.sum(layer.apply(p, x) * target)
+
+    def through_route(probs, flat):
+        out, _ = routed_experts(
+            flat, *route(probs, 3, 4, 1), *weights, num_experts=16, activation=jax.nn.relu
+        )
+        return jnp.sum(out * target.reshape(out.shape))
+
+    probs = jax.nn.softmax(jax.random.normal(jax.random.PRNGKey(3), (48, 16)), axis=-1)
+    router_ops = ("top_k", "sort", "scatter-add")  # the tally is the last: ``bincount``
+    for fn, operands in ((through_layer, (params, x)), (through_route, (probs, x.reshape(48, 32)))):
+        plain = jax.grad(fn, argnums=(0, 1))
+        again = jax.grad(jax.checkpoint(fn, policy=policy), argnums=(0, 1))
+        want, got = flat(plain(*operands)), flat(again(*operands))
+        assert any("router" in name for name in want) or fn is through_route
+        for name, leaf in want.items():
+            assert float(jnp.linalg.norm(leaf)) > 0 and relative(got[name], leaf) < 1e-6, name
+        once, decided = (
+            [str(jax.make_jaxpr(g)(*operands)).count(f" {prim}[") for prim in router_ops]
+            for g in (plain, again)
+        )
+        assert once[:2] == [1, 1] and decided == [n + (kept == "nothing") for n in once], decided
 
 
 def test_a_layers_routing_does_not_move_with_its_attention(toy):

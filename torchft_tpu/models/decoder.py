@@ -107,9 +107,10 @@ def into_residual(depth: int, **axes):
 
 def remat_policy(remat: str, dots: Any, *names: str):
     """The policy of ``remat`` for :func:`layer_stack`. ``dots`` keeps what
-    the MXU produced, the ``dot_general`` results the model's ``dots`` policy
-    picks (``jax.checkpoint_policies.checkpoint_dots`` or a narrower one), and
-    the arrays tagged ``names``: a Pallas call is no ``dot_general``, so
+    the model's ``dots`` policy picks by primitive (the ``dot_general`` results
+    of ``jax.checkpoint_policies.checkpoint_dots`` or a narrower one; the
+    router's decisions of models/experts.py ``routing_saveable``; nothing),
+    and the arrays tagged ``names``: a Pallas call is no ``dot_general``, so
     without the flash forward kernel's (out, logsumexp) by name the backward
     would run that whole kernel a second time. ``full`` (None) recomputes
     everything, that kernel included."""

@@ -20,6 +20,17 @@ The router's logits are an argument of the layer, so a block decides what the
 router reads: the layer's own normed input (``logits=None``: models/keye.py)
 or the block's input ahead of attention (``RoutedExperts.logits`` called
 there: models/smallthinker.py). The gated unit's activation is a field.
+
+What ``route`` decides need not be decided again in the layer's backward:
+``order``, ``gates`` and ``group_sizes`` (the grouped product's operands and so
+its backward's residuals) carry the ``checkpoint_name`` tag ``ROUTING``, and
+the top-k's values and indices, which ``lax.top_k``'s own differentiation rule
+reads before anything could name them, are kept by primitive. Sixteen bytes a
+choice and the tally, where a remat policy that keeps neither (no
+``dot_general`` made them) runs the top-k, the sort and the tally a second
+time. ``routing_saveable`` is the policy that keeps both; a model's ``dots``
+may hold it (models/decoder.py ``remat_policy``; models/smallthinker.py's
+does), and where no policy does the tag does nothing.
 """
 
 from __future__ import annotations
@@ -30,11 +41,24 @@ from typing import Any, Callable, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from torchft_tpu.models.decoder import sown_by_layer
 from torchft_tpu.ops.grouped_matmul import routed_experts
 
-__all__ = ["RoutedExperts", "route", "router_load", "dispatch_rows"]
+__all__ = ["RoutedExperts", "route", "router_load", "dispatch_rows", "routing_saveable"]
+
+# checkpoint_name tag of what :func:`route` hands the layer (module docstring).
+ROUTING = "routing"
+
+
+_tagged_routing = jax.checkpoint_policies.save_only_these_names(ROUTING)
+
+
+def routing_saveable(prim: Any, *_: Any, **params: Any) -> bool:
+    """The remat policy that keeps what :func:`route` decides: the arrays
+    tagged ``ROUTING`` and the outputs of ``lax.top_k`` (a block has the one)."""
+    return prim is jax.lax.top_k_p or _tagged_routing(prim, **params)
 
 
 def route(probs: jnp.ndarray, experts_per_token: int, num_local_experts: int, expert_share: int):
@@ -45,12 +69,13 @@ def route(probs: jnp.ndarray, experts_per_token: int, num_local_experts: int, ex
     ``group_sizes`` (num_local_experts + 1,), rows by held expert and, last,
     the rows that belong elsewhere."""
     local = num_local_experts
+    decided = partial(checkpoint_name, name=ROUTING)
     top, experts = jax.lax.top_k(probs, experts_per_token)
-    gates = top / jnp.sum(top, axis=-1, keepdims=True)
+    gates = decided(top / jnp.sum(top, axis=-1, keepdims=True))
     mine = experts - expert_share * local
     group = jnp.where((mine >= 0) & (mine < local), mine, local).reshape(-1)
-    order = jnp.argsort(group, stable=True)
-    group_sizes = jnp.bincount(group, length=local + 1).astype(jnp.int32)
+    order = decided(jnp.argsort(group, stable=True))
+    group_sizes = decided(jnp.bincount(group, length=local + 1).astype(jnp.int32))
     return order, gates, group_sizes
 
 

@@ -25,8 +25,11 @@ with is what models/decoder.py ``layer_stack`` scans (``[0, 1, 1, 1]`` x 13:
 a period of four, one traced period for the whole depth; a layout of one kind
 is the one-kind stack). Rotary, the norm (its scale stored in ``norm_dtype``),
 the output head with its fused loss and the remat rule are models/decoder.py's
-(``dots`` keeps the flash kernels' output and logsumexp and recomputes the
-projections: ``SmallThinker.__call__`` says why). No sharding plan yet: the model runs on one device or replicated.
+(``dots`` keeps the flash kernels' output and logsumexp, what the flash call
+reads, the stream after attention and the router's decisions, so that a
+layer's backward computes no projection, no rotary and no routing a second
+time: ``SmallThinker.__call__``). No sharding plan yet: the model runs on one
+device or replicated.
 ``router_load`` and ``dispatch_rows`` are models/experts.py's.
 """
 
@@ -39,15 +42,26 @@ from typing import Any, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from torchft_tpu.models.decoder import (
     LMHead, RMSNorm, apply_rope, into_residual, layer_stack, remat_policy, smallest_period,
 )
-from torchft_tpu.models.experts import RoutedExperts, dispatch_rows, router_load
+from torchft_tpu.models.experts import (
+    RoutedExperts, dispatch_rows, router_load, routing_saveable,
+)
 from torchft_tpu.ops.attention import attend
 from torchft_tpu.ops.flash_attention import FLASH_LSE, FLASH_OUT
 
 __all__ = ["SmallThinkerConfig", "SmallThinker", "router_load", "dispatch_rows"]
+
+# checkpoint_name tags of what a layer's flash call reads (q and k AFTER
+# rotary, v) and of the stream after the attention branch, which ``dots``
+# keeps by name (``SmallThinker.__call__``).
+ATTN_Q = "attn_q"
+ATTN_K = "attn_k"
+ATTN_V = "attn_v"
+POST_ATTN = "post_attn"
 
 
 @dataclass(frozen=True)
@@ -133,6 +147,7 @@ class Attention(nn.Module):
         if self.rotary:
             q = apply_rope(q, positions, cfg.rope_theta)
             k = apply_rope(k, positions, cfg.rope_theta)
+        q, k, v = checkpoint_name(q, ATTN_Q), checkpoint_name(k, ATTN_K), checkpoint_name(v, ATTN_V)
         scope = "tpuft::window_attention" if self.windowed else "tpuft::full_attention"
         with jax.named_scope(scope):
             out = attend(
@@ -169,7 +184,7 @@ class Block(nn.Module):
         with jax.named_scope("tpuft::router"):
             logits = moe.logits(x)
         attention = Attention(cfg, bool(windowed), bool(rotary), name="attn")
-        x = x + attention(norm(name="attn_norm")(x), positions)
+        x = checkpoint_name(x + attention(norm(name="attn_norm")(x), positions), POST_ATTN)
         return x + moe(norm(name="mlp_norm")(x), logits)
 
 
@@ -194,14 +209,19 @@ class SmallThinker(nn.Module):
             cfg.vocab_size, cfg.dim, dtype=cfg.dtype, param_dtype=cfg.dtype,
             embedding_init=nn.initializers.normal(1.0), name="tok_embed",
         )(tokens)
-        # ``dots`` keeps the flash kernels' output and logsumexp by their
-        # names and NO dot_general: a layer's projections come again in its
-        # backward (a twentieth of a step at 16,384 tokens) where keeping
-        # them would be a quarter of a GiB a layer and sequence of that
-        # length, beside the routed layer's worst-case row buffers, which a
-        # share of few experts makes the largest temporaries of the step.
+        # ``dots`` keeps, beside the flash kernels' output and logsumexp, what
+        # the backward would otherwise compute a second time and the chip has
+        # room for since the lone FT step holds ONE copy of the state: what
+        # the flash call reads (q and k after rotary, v: the three products
+        # AND rotary stay out of the backward) and the stream after attention
+        # (what the second norm reads, so ``wo``'s product is not needed
+        # again), a quarter of a GiB a layer and sequence of 16,384 tokens;
+        # and what the router decided (models/experts.py). By name and not
+        # ``checkpoint_dots``: that keeps q and k BEFORE rotary and ``wo``'s
+        # result short of its sum, and measured a sixtieth of a step slower
+        # for the same bytes. The norms and the router's logits come again.
         policy = remat_policy(
-            cfg.remat, jax.checkpoint_policies.nothing_saveable, FLASH_OUT, FLASH_LSE
+            cfg.remat, routing_saveable, FLASH_OUT, FLASH_LSE, ATTN_Q, ATTN_K, ATTN_V, POST_ATTN
         )
         x = layer_stack(Block, cfg, policy, x, positions, period=cfg.period)
         x = RMSNorm(cfg.norm_eps, cfg.dtype, cfg.norm_dtype, name="final_norm")(x)
