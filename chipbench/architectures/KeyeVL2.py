@@ -52,12 +52,14 @@ from __future__ import annotations
 
 import functools
 import re
+from pathlib import Path
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 
 from chipbench import reference
+from chipbench.spec import load_module
 
 # A Pallas call of the step program whose name in the device trace holds this
 # is the grouped expert product (torchft_tpu/ops/grouped_matmul.py): XLA names
@@ -66,6 +68,12 @@ from chipbench import reference
 # program (my chip run, PR 46), and by longer names that still hold ``gmm``
 # where the product is differentiated on its own.
 EXPERT_KERNEL = re.compile(r"gmm")
+# Every Mosaic call of the expert layer: the grouped product's, which
+# ``expert_time_pct`` reads in this cell, and since PR 51 the two sums by token
+# (``sum_by_token.<n>``, ``transpose_jvp_sum_by_token__.<n>``). A Pallas call of
+# the step programs under neither name is selected attention's.
+EXPERT_LAYER_KERNEL = re.compile(r"gmm|sum_by_token")
+_SPARSE_FLASH = load_module(Path(__file__).parents[1] / "layer_metrics" / "sparse_flash_time_pct.py")
 
 
 def build(config: Dict[str, Any], seq: int):
@@ -338,29 +346,40 @@ def selected_attention_flops(config: Dict[str, Any], batch: int, seq: int) -> fl
 
 
 def selected_attention_seconds(trace: Dict[str, Any], config: Dict[str, Any], seq: int) -> float:
-    """Device seconds of selected attention in a reduced trace. The program's
-    path is plain tiled XLA (no Pallas call), so its ops are found by what only
-    that path produces: a result shaped by a tile of queries
-    (``sa_config.q_chunk_size``), alone or against a group's key length (a
-    multiple of the tile):
-    ``[.., tile, keys]`` (index scores, the threshold's masks, attention scores,
-    probabilities and their gradients), ``[tile, indexer heads, keys]``,
-    ``[kv heads, keys, tile, group]``, ``[1, tile, n, 128]`` (a tile of queries
-    or outputs by head, or a row of keys folded to lanes), ``[kv heads, group,
-    tile]`` (row maxima and sums, which XLA fuses with the products that feed
-    them), ``[kv heads, tile, group, 128]`` and its transpose (a tile's
-    output), ``s32[tile]`` / ``u32[tile]`` (the radix select's counters), and
-    ``[1, keys, kv heads, 128]`` for keys SHORTER than the sequence (a group's
-    slice of k or v, and the gradient into it). NOT seen, and so counted as
-    other time: ops on the whole sequence's k and v (the sum of the groups'
-    gradients, ``[1, seq, kv heads, 128]``, which the projections' own ops
-    share), so the time share reads a little low and the share of the peak a
-    little high (my chip run, PR 46: PERF.md section 5 has the table by shape).
-    The shapes are THIS path's: another tiling, a fusion XLA draws otherwise or
-    a kernel in the path's place moves ops in or out of sight, so the two
-    shares read from here are not comparable across a change of the path.
-    The scopes ``tpuft::indexer`` and ``tpuft::sparse_attention`` name the
-    same ops in the profile's ``op_name``, which ``trace_reduce`` does not
+    """Device seconds of attention under the learned key selection in a reduced
+    trace: the index scores, the per-row threshold and attention over the
+    selected keys, forward and backward, WHATEVER implements any of them (since
+    PR 64; from PR 47 to PR 63 this read the selection's XLA ops alone while
+    ``selected_attention_flops`` kept counting attention's matmuls). Two kinds
+    of op, and no op is of both (a kernel's name matches no shape below):
+
+    1. The step programs' Pallas calls that are not the expert layer's
+       (``EXPERT_LAYER_KERNEL``): since PR 47 the flash kernels that attend
+       with the selection as an operand, ``attn.<n>`` in a trace, the calls
+       ``sparse_flash_time_pct`` reads; a kernel that one day makes the
+       selection would be counted here by the same rule.
+    2. The selection's plain tiled XLA ops (``ops/sparse_attention.py``
+       ``select_keys``), found by what only that path produces: a result shaped
+       by a tile of queries (``sa_config.q_chunk_size``), alone or against a
+       group's key length (a multiple of the tile):
+       ``[.., tile, keys]`` (index scores, the threshold's masks; before PR 47
+       also attention scores, probabilities and their gradients), ``[tile,
+       indexer heads, keys]``, ``[kv heads, keys, tile, group]``, ``[1, tile, n,
+       128]`` (a tile of queries or outputs by head, or a row of keys folded to
+       lanes), ``[kv heads, group, tile]`` (row maxima and sums, which XLA fuses
+       with the products that feed them), ``[kv heads, tile, group, 128]`` and
+       its transpose (a tile's output), ``s32[tile]`` / ``u32[tile]`` (the radix
+       select's counters), and ``[1, keys, kv heads, 128]`` for keys SHORTER
+       than the sequence (a group's slice of k or v, and the gradient into it).
+
+    NOT seen, and so counted as other time: XLA ops on the whole sequence's k
+    and v (``[1, seq, kv heads, 128]``, which the projections' own ops share),
+    so the time share reads a little low and the share of the peak a little high
+    (my chip run, PR 46: PERF.md section 5 has the table by shape). The shapes
+    are THIS path's: another tiling or a fusion XLA draws otherwise moves XLA
+    ops in or out of sight, while a kernel in the path's place stays in sight
+    under 1. The scopes ``tpuft::indexer`` and ``tpuft::sparse_attention`` name
+    the same ops in the profile's ``op_name``, which ``trace_reduce`` does not
     keep (PERF.md section 7)."""
     sa = config["sa_config"]
     tile = min(sa["q_chunk_size"], seq)
@@ -375,4 +394,5 @@ def selected_attention_seconds(trace: Dict[str, Any], config: Dict[str, Any], se
         rf"\[{kv},{group},{tile}\]", rf"\[{kv},{tile},{group},128\]", rf"\[{kv},128,{group},{tile}\]",
         rf"\b[su]32\[{tile}\]", rf"\[1,{shorter},{kv},128\]",
     ]))
-    return sum(s for name, s in trace.get("ops", []) if mine.search(name))
+    selection = sum(s for name, s in trace.get("ops", []) if mine.search(name))
+    return selection + _SPARSE_FLASH.kernel_seconds_but(trace, EXPERT_LAYER_KERNEL)

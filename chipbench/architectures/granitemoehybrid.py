@@ -373,9 +373,12 @@ SSD_KERNEL = re.compile(r"ssd|mamba_conv")
 
 def ssd_seconds(trace: Dict[str, Any], config: Dict[str, Any], batch: int, seq: int) -> float:
     """Device seconds of the state-space scan and its convolution in a
-    reduced trace, forward and backward, NOT the projections. The program's
-    path is plain XLA (ops/ssd.py: no Pallas call), so its ops are found by the
-    result shapes only that path produces. XLA orders and folds the leading
+    reduced trace, forward and backward, NOT the projections. Since PR 58 the
+    program's path on a TPU is four Mosaic calls (ops/ssd.py: ``mamba_conv_fwd``
+    / ``mamba_conv_bwd`` / ``ssd_fwd`` / ``ssd_bwd``), found by NAME
+    (``SSD_KERNEL``); what stays plain XLA around them, and the whole einsum
+    path of PR 57 or of another platform, is found by the result shapes only
+    that path produces. XLA orders and folds the leading
     dimensions (batch, chunks, heads) as it likes, so a shape is one of the
     path's where its ELEMENT COUNT is a family's and the family's telling
     sizes are among its dimensions, with b the batch, c = seq / Q chunks, H

@@ -18,8 +18,8 @@ def flash_attention_flops(config: Dict[str, Any], batch: int, seq: int) -> float
     half of them under the causal mask. Forward two (q k^T, p v); backward
     five: the scores once more, which the algorithm keeps nowhere and cannot
     avoid recomputing, then dv = p^T do, dp = do v^T, dq = ds k, dk = ds^T q.
-    What the two backward kernels recompute beyond that one, and a forward
-    repeated under remat, is time and not need.
+    What the backward kernel (one call since PR 42; two before) recomputes
+    beyond that one, and a forward repeated under remat, is time and not need.
     For cells in which every one of ``num_hidden_layers`` layers runs causal
     flash attention at ``num_attention_heads x head_dim`` and no other Pallas
     call is in the step programs; any other cell brings a count of its own."""
@@ -29,9 +29,10 @@ def flash_attention_flops(config: Dict[str, Any], batch: int, seq: int) -> float
 
 def fp8_codec_bytes(elements: int, block: int = 256) -> Dict[str, int]:
     """Bytes the fp8 block codecs must move for ``elements`` values in blocks
-    of ``block`` (ops/quantization.py's BLOCK): quantize reads float32 and
-    writes one byte a value plus a float32 scale a block; dequantize the
-    reverse."""
+    of ``block`` (ops/quantization.py's BLOCK): quantize reads 4 bytes a
+    value (a float32 pseudo-gradient until PR 44; since then the two bf16
+    operands whose difference the kernel forms in VMEM) and writes one byte a
+    value plus a float32 scale a block; dequantize the reverse."""
     blocks = -(-elements // block)
     padded = blocks * block
     one_way = padded * 4 + padded * 1 + blocks * 4

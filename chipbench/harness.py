@@ -142,8 +142,12 @@ class MemoryGauge:
     at the bottom of memory" and reported apart (a program with 4 GiB of
     temporaries moved ``bytes_reserved`` by 4.0 GiB and ``peak_bytes_in_use``
     by nothing: my chip run, PR 24). It is a peak of the process's whole life,
-    so set-up must leave no large array behind: the float32 reference is one
-    program to a number for that reason.
+    so set-up must leave no large array behind and hold fewer live bytes than
+    the job: the float32 reference's update is one program to a number where
+    that fits the chip, and where it is made a part at a time its live arrays
+    are the system's weights, one stepped copy of them and the leaf being
+    stacked (5.3 bytes a parameter at 1.36G under the job's 6.0: my chip run,
+    PR 64), each part's float32 copies being its program's scratch.
 
     ``held_peak_bytes`` is the largest ``bytes_in_use + bytes_reserved`` of ONE
     instant, sampled every 5 ms through the window by the HostPulse thread
@@ -329,24 +333,48 @@ def window_checks(losses, compiled_inside: int) -> List[str]:
     return problems
 
 
-def reference_losses(system, params, group: int = 0, groups: int = 1) -> Dict[str, Any]:
+def reference_losses(
+    system, params, group: int = 0, groups: int = 1, by_parts: Optional[bool] = None
+) -> Dict[str, Any]:
     """Float32 losses of ``group``'s first two batches (chipbench/reference.py
     around the architecture's ``sequence_loss``):
     ``first`` under ``params``; ``second_without_update``; and ``second``
     after one reference AdamW step on the mean gradient over the first batches
     of a set of groups, one value for every set that can have taken part in
     step 0 ("0", "1", "0+1": the program says afterwards which it was, and a
-    group that heals in a step gives no gradient to it). Every value is one
-    program from the system's weights to a number, so the reference's float32
-    copies are temporaries and no array of it outlives the call."""
+    group that heals in a step gives no gradient to it).
+
+    The update is one program from the system's weights to a number where that
+    program fits the device (``reference.whole_update_fits``: the tree's
+    parameters against the ``bytes_limit`` of the device that holds them), so
+    that its float32 copies are temporaries; where it would not fit, the update
+    is made a part at a time and the second loss taken on the stepped tree,
+    which is let go before the next set of groups. Either way no array of the
+    reference outlives the call, and its live arrays stay under the job's own.
+    ``by_parts`` is for the tests and the builder's comparison of the two ways;
+    the benchmark never passes it."""
+    import jax
     import jax.numpy as jnp
 
     from chipbench import reference
 
     if groups not in (1, 2):
         raise ValueError("the reference's update is written for one or two groups")
+    leaves = jax.tree_util.tree_leaves(params)
+    n_parameters = sum(leaf.size for leaf in leaves)
+    if by_parts is None:
+        limit = max(int((d.memory_stats() or {}).get("bytes_limit", 0)) for d in leaves[0].devices())
+        by_parts = not reference.whole_update_fits(n_parameters, limit)
     loss = reference.make_loss(system.architecture, system.config)
-    loss_after = reference.make_loss_after_first_update(system.architecture, system.config)
+    if by_parts:
+        update = reference.make_first_update_by_parts(system.architecture, system.config)
+        say(f"reference: {n_parameters} parameters, the update in "
+            f"{len(reference.parts_of(params))} parts")
+
+        def loss_after(params, first, then):
+            return loss(update(params, first), then)
+    else:
+        loss_after = reference.make_loss_after_first_update(system.architecture, system.config)
     then = system.tokens(1, group)
     out: Dict[str, Any] = {
         "first": float(loss(params, system.tokens(0, group))),
@@ -509,7 +537,14 @@ def run_one_process(run: Run, make_job: Callable[..., Any]) -> Dict[str, Any]:
     say(f"device: {devices[0].platform} {devices[0].device_kind} x{len(devices)}; cache {cache_dir}")
 
     params = system.init_params()
-    system.reference = reference_losses(system, params)
+    # Sampled and said, never judged: the yardstick's own share of the chip.
+    setup_gauge = MemoryGauge(devices)
+    with HostPulse(setup_gauge.sample):
+        system.reference = reference_losses(system, params)
+    setup_memory = setup_gauge.report()
+    say(f"reference: held at most {setup_gauge.held} bytes (arrays and scratch at one instant, "
+        f"{setup_gauge.samples} samples), live arrays at most {setup_memory['arrays_peak_bytes']}, "
+        f"on a chip of {setup_memory['bytes_limit']}")
     job = make_job(run, system, params, spans)
     del params
     problems: List[str] = []

@@ -1,7 +1,9 @@
 """ftddp: a lone replica through ``Optimizer.make_step_fn``: quorum and commit
-vote every step, no donation, so two copies of the state: the committed one
-(the history ring's one version at depth 0) and the speculative one. What a
-user runs when the fleet has shrunk to one group."""
+vote every step. Since PR 60 the step votes first and then updates its state in
+place (``params`` and ``opt_state`` donated), so ONE copy of the state, 6 bytes
+a parameter (two from PR 32 to PR 59: the committed one, the history ring's one
+version at depth 0, and a speculative one). What a user runs when the fleet has
+shrunk to one group."""
 
 from __future__ import annotations
 

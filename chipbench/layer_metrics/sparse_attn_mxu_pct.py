@@ -3,13 +3,20 @@ the operations attention over the SELECTED pairs needs for the window's steps
 (the architecture file's ``selected_attention_flops``: seven matmuls over the
 sum of min(t + 1, topk) pairs, plus the index scores over the causal pairs,
 forward only) over the device seconds sparse_attn_time_pct sums, against the
-published peak (chipbench/peaks.json). It cannot pass 100. What lowers it: the
-pairs computed under the mask and thrown away (a plain tiled path computes
-every causal pair of a tile's key length where the need is the selected ones),
-the float32 index scores at six passes of the MXU, a tile recomputed in the
-backward. The reader finds the path by its result shapes and sees part of its
-time only (see ``selected_attention_seconds``), so the share reads HIGH by
-that part, and a reading is comparable with another of the SAME path only."""
+published peak (chipbench/peaks.json). Count and seconds are of the SAME
+mechanism since PR 64: the index scores, the threshold and attention over the
+selected keys, forward and backward, whatever implements any of them: the
+selection's XLA ops and every Pallas call that is not the expert layer's (from
+PR 47 to PR 63 the seconds were the selection's alone under a count that held
+attention's matmuls: 49.33 on 18.58% of the busy time, ledger PR 63, and a
+faster selection alone would have read past 100). It cannot pass 100. What
+lowers it: the pairs the flash kernels step and mask where the need is the
+selected ones (72 of 128 block pairs a head stepped, every one under the int8
+mask), the float32 index scores at six passes of the MXU, the radix select's 32
+counting passes (no product at all), the backward's recomputation beyond the
+one the count holds. The reader sees the selection's XLA ops by their result
+shapes and misses a few (see ``selected_attention_seconds``), so the share reads
+a little HIGH; the kernels are found by name and all seen."""
 
 from pathlib import Path
 

@@ -25,27 +25,51 @@ nothing is approximated; in the gradient each block is recomputed
 program. The block sizes are constants of the reference, not keys of a
 configuration (a rehearsal, which runs toy sequences, sets its own).
 
-The update (``grad_sum`` + ``first_adamw_step``, as one program in
-``make_loss_after_first_update``) is the float32 gradient of that loss and
-AdamW's first step from zero moments written out by hand (Loshchilov & Hutter,
-arXiv:1711.05101, with Adam's bias correction): after one step the corrected
-moments are g and g*g, so the step is ``p - lr * (g / (|g| + eps) + wd * p)``,
-stored in the configuration's parameter dtype. No optimizer library, no
-moments: what the system's second loss is held to.
+The update (``grad_sum`` + ``first_adamw_step``) is the float32 gradient of that
+loss and AdamW's first step from zero moments written out by hand (Loshchilov &
+Hutter, arXiv:1711.05101, with Adam's bias correction): after one step the
+corrected moments are g and g*g, so the step is ``p - lr * (g / (|g| + eps) +
+wd * p)``, stored in the configuration's parameter dtype. No optimizer library,
+no moments: what the system's second loss is held to.
 For the gradient alone each block of the model is recomputed in the backward
 pass (``jax.checkpoint``), which changes memory and no number.
+
+The update runs in one of two ways, the same mathematics in both, and
+``whole_update_fits`` says which from the tree's size and the device's memory
+alone. WHOLE (``make_loss_after_first_update``): one program from the system's
+weights to the second loss, which holds a float32 copy, the summed gradient, one
+sequence's gradient and the stepped weights of the WHOLE tree at once. BY PARTS
+(``make_first_update_by_parts``, since PR 64): a part is the leaves of one
+layer, or the leaves outside the layers; one program a part differentiates a
+float32 copy of THAT part while every other leaf enters as the system stores it
+and is cast where it is used, and returns the part stepped; the stepped tree is
+put together from the parts and ``make_loss``'s program gives the second loss
+on it. A chip's share whose whole update would not fit the chip gets its
+``correct`` that way.
 """
 
 from __future__ import annotations
 
+import functools
+import re
 from types import ModuleType
-from typing import Any, Dict
+from typing import Any, Callable, Dict, List, Sequence
 
 import jax
 import jax.numpy as jnp
 
 QUERY_BLOCK = 2048
 HEAD_BLOCK = 2048
+# What the WHOLE update program holds at its fullest for every parameter of a
+# bf16 tree: the system's weights (2 bytes), their float32 copy (4), the summed
+# float32 gradient (4), one sequence's gradient inside ``grad_sum``'s scan (4)
+# and the stepped weights (2), 16 in all, and the float32 activations of one
+# sequence under ``recompute``: 16 to 18 by the records of PRs 36 to 62 (the
+# configurations' ``reduced`` reasons; PERF.md section 7 has what the chip held
+# in set-up by cell, PR 64). The upper end, so that the rule errs towards the
+# parts.
+WHOLE_UPDATE_BYTES_PER_PARAMETER = 18
+_LAYER = re.compile(r"layer_\d+")  # a layer's subtree in the program's unstacked layout
 
 
 def rms_norm(x: jnp.ndarray, scale: jnp.ndarray, eps: float) -> jnp.ndarray:
@@ -122,17 +146,24 @@ def make_loss(architecture: ModuleType, config: Dict[str, Any]):
     return loss
 
 
-def grad_sum(architecture: ModuleType, params, tokens, config: Dict[str, Any]):
-    """Float32 gradient of the SUM of token losses of ``tokens`` (n, s + 1)
-    with respect to float32 ``params``, one sequence at a time."""
-    grad = jax.grad(
-        lambda p, seq: architecture.sequence_loss(p, seq, config, recompute=True)
-    )
+def _grad_sum_of(loss_sum: Callable, params, tokens):
+    """Gradient of ``loss_sum(params, sequence)`` summed over the sequences of
+    ``tokens`` (n, s + 1), one sequence at a time."""
+    grad = jax.grad(loss_sum)
 
     def add(total, seq):
         return jax.tree_util.tree_map(jnp.add, total, grad(params, seq)), None
 
     return jax.lax.scan(add, jax.tree_util.tree_map(jnp.zeros_like, params), tokens)[0]
+
+
+def grad_sum(architecture: ModuleType, params, tokens, config: Dict[str, Any]):
+    """Float32 gradient of the SUM of token losses of ``tokens`` (n, s + 1)
+    with respect to float32 ``params``, one sequence at a time."""
+    return _grad_sum_of(
+        lambda p, seq: architecture.sequence_loss(p, seq, config, recompute=True),
+        params, tokens,
+    )
 
 
 def first_adamw_step(params, grad_total, count, config: Dict[str, Any]):
@@ -182,3 +213,158 @@ def make_loss_after_first_update(architecture: ModuleType, config: Dict[str, Any
         return jnp.sum(sums) / (then.shape[0] * (then.shape[1] - 1))
 
     return loss_after
+
+
+# -- the update a part at a time ------------------------------------------------
+
+
+def whole_update_fits(n_parameters: int, bytes_limit: int) -> bool:
+    """Whether ``make_loss_after_first_update``'s one program fits a device of
+    ``bytes_limit`` bytes for a tree of ``n_parameters``: a rule on sizes alone.
+    A device that reports no limit (a CPU rehearsal) takes the whole."""
+    return not bytes_limit or n_parameters * WHOLE_UPDATE_BYTES_PER_PARAMETER <= bytes_limit
+
+
+def _stacked_layers(tree: Any) -> Dict[str, List[int]]:
+    """Which layers each stacked subtree of the system's ``layers`` holds, in
+    order along its leading axis: ``block`` every layer; ``block_<k>``, of a
+    period of n kinds, the layers k, k + n, k + 2n, ... Empty for a tree that
+    does not stack its layers under ``layers``."""
+    stack = tree.get("layers") if isinstance(tree, dict) else None
+    if not isinstance(stack, dict):
+        return {}
+    leading = lambda sub: jax.tree_util.tree_leaves(sub)[0].shape[0]
+    if "block" in stack:
+        return {"block": list(range(leading(stack["block"])))}
+    period = len(stack)
+    return {
+        f"block_{k}": list(range(k, period * leading(stack[f"block_{k}"]), period))
+        for k in range(period)
+    }
+
+
+def _unstacked(tree: Dict[str, Any]) -> Dict[str, Any]:
+    """The system's tree (under ``params``) with its layers laid out one by one,
+    ``layer_<i>``: the program's other layout, which every architecture file
+    reads. A tree that does not stack its layers under ``layers`` is returned
+    as it is. Slices: called while tracing, it copies nothing."""
+    stacked = _stacked_layers(tree)
+    if not stacked:
+        return tree
+    out = {name: sub for name, sub in tree.items() if name != "layers"}
+    for name, layers in stacked.items():
+        for at, layer in enumerate(layers):
+            out[f"layer_{layer}"] = jax.tree_util.tree_map(lambda a: a[at], tree["layers"][name])
+    return out
+
+
+def _restacked(unstacked: Dict[str, Any], like: Dict[str, Any]) -> Dict[str, Any]:
+    """The inverse of ``_unstacked``, in the layout ``like`` has. ``unstacked``
+    is taken apart: leaf by leaf, a leaf's slices are let go before the next is
+    stacked, so that at most one stacked leaf is held twice."""
+    stacked_layers = _stacked_layers(like)
+    if not stacked_layers:
+        return unstacked
+    out: Dict[str, Any] = {"layers": {}}
+    for name, layers in stacked_layers.items():
+        flat = [jax.tree_util.tree_flatten(unstacked.pop(f"layer_{layer}")) for layer in layers]
+        slices, treedef = [leaves for leaves, _ in flat], flat[0][1]
+        del flat
+        stacked = []
+        for at in range(treedef.num_leaves):
+            stacked.append(jnp.stack([leaves[at] for leaves in slices]))
+            for leaves in slices:
+                leaves[at] = None
+        out["layers"][name] = treedef.unflatten(stacked)
+    out.update(unstacked)  # what is outside the layers
+    return out
+
+
+def _part_of(path: Sequence[Any]) -> str:
+    """The part a leaf of an unstacked tree belongs to, by its path: a
+    ``layer_<i>`` subtree, or an element of a list the tree keeps its layers in,
+    is a part of its own; every other leaf is ``outside``."""
+    head = path[0]
+    name = str(getattr(head, "key", head))
+    if _LAYER.fullmatch(name):
+        return name
+    if len(path) > 1 and isinstance(path[1], jax.tree_util.SequenceKey):
+        return f"{name}[{path[1].idx}]"
+    return "outside"
+
+
+def parts_of(params: Dict[str, Any]) -> Dict[str, List[int]]:
+    """The parts of the system's tree by name, each the positions of its leaves
+    among the UNSTACKED tree's (``jax.tree_util.tree_leaves`` order). Shapes
+    alone are read: nothing runs on a device."""
+    shapes = jax.eval_shape(_unstacked, params["params"])
+    parts: Dict[str, List[int]] = {}
+    for at, (path, _) in enumerate(jax.tree_util.tree_flatten_with_path(shapes)[0]):
+        parts.setdefault(_part_of(path), []).append(at)
+    return parts
+
+
+def make_part_step(architecture: ModuleType, config: Dict[str, Any], part: str):
+    """``step(params, first)``: the leaves of ``part`` (a name of ``parts_of``)
+    after one AdamW step on the mean loss of ``first`` (n, s + 1), in
+    ``run.dtype``. One program a part. The float32 copy that ``jax.grad``
+    differentiates is of that part alone; every other leaf enters the loss as
+    the system stores it, and the architecture casts it where it uses it, so the
+    loss is the whole program's to the bit and the gradient is the part's rows
+    of the whole program's gradient."""
+
+    @jax.jit
+    def step(params, first):
+        with jax.default_matmul_precision("highest"):
+            leaves, treedef = jax.tree_util.tree_flatten(_unstacked(params["params"]))
+            mine = parts_of(params)[part]
+
+            def loss_sum(part32, seq):
+                seen = list(leaves)
+                for at, leaf in zip(mine, part32):
+                    seen[at] = leaf
+                view = {**params, "params": treedef.unflatten(seen)}
+                return architecture.sequence_loss(view, seq, config, recompute=True)
+
+            part32 = [leaves[at].astype(jnp.float32) for at in mine]
+            count = first.shape[0] * (first.shape[1] - 1)
+            return first_adamw_step(part32, _grad_sum_of(loss_sum, part32, first), count, config)
+
+    return step
+
+
+def assemble(params: Dict[str, Any], stepped: Dict[str, List[jnp.ndarray]]) -> Dict[str, Any]:
+    """The tree in the layout ``params`` has whose parts are ``stepped`` (by
+    name, each the part's leaves in ``parts_of``'s order). ``stepped`` is
+    emptied: the tree is then the only holder of its arrays."""
+    treedef = jax.tree_util.tree_structure(jax.eval_shape(_unstacked, params["params"]))
+    leaves: List[Any] = [None] * treedef.num_leaves
+    for name, places in parts_of(params).items():
+        for at, leaf in zip(places, stepped.pop(name)):
+            leaves[at] = leaf
+    unstacked = treedef.unflatten(leaves)
+    del leaves
+    return {**params, "params": _restacked(unstacked, params["params"])}
+
+
+def make_first_update_by_parts(architecture: ModuleType, config: Dict[str, Any]):
+    """``update(params, first)``: the system's tree after one AdamW step on the
+    mean loss of ``first`` (n, s + 1), every leaf in ``run.dtype``, in the
+    layout ``params`` has: ``make_loss_after_first_update``'s stepped weights,
+    made a part at a time. Alive at the worst instant: the system's weights, the
+    parts stepped so far, and for ONE part its program's scratch (the part's
+    float32 copy, two float32 gradients and its stepped leaves, beside one
+    sequence's activations and the float32 casts a backward keeps). A part's
+    program is let go before the next is loaded: on this runtime a loaded
+    program's scratch stays reserved (my chip run, PR 64: the six programs of a
+    1.36G share loaded together held 16.6 of the chip's 16.9 GB)."""
+
+    def update(params, first):
+        stepped = {}
+        for name in parts_of(params):
+            step = make_part_step(architecture, config, name)
+            stepped[name] = jax.block_until_ready(step(params, first))
+            del step
+        return assemble(params, stepped)
+
+    return update

@@ -153,8 +153,8 @@ def test_benchmark_json_lists_the_four_at_the_end_and_is_sound(bench_root):
         assert set(FTDDP_CELLS) <= set(by_name[name]["workloads"])
         assert by_name[name]["workloads"] == by_name["ft_step_host_ms"]["workloads"]
         assert bench.reader_path("per_layer", name).is_file()
-    if bench_root == ROOT:
-        assert [m["name"] for m in bench.data["per_layer"]][-4:] == list(NEW)
+    names = [m["name"] for m in bench.data["per_layer"]]  # in this order, wherever a later PR appends
+    assert [n for n in names if n in NEW] == list(NEW)
     for cell in ("mistral7b-1chip.plain", "mistral7b-1chip.diloco-fp8"):
         assert not set(NEW) & {m["name"] for m in bench.metrics_of(cell, "per_layer")}
 

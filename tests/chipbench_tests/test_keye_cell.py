@@ -44,7 +44,9 @@ LISTED = ("tokens_per_s", "ft_host_ms", "quorum_commit_ms", "mfu_pct", "device_i
           "host_stall_ms", "ft_idle_ms", "ft_step_host_ms", "trace_overhead_pct")
 # ISSUE 46 named a fourth, ``expert_mxu_pct``: left out, because no reader can
 # see how many rows arrived at the held experts (PERF.md section 7, PR 46).
-OWN = ("expert_time_pct", "sparse_attn_time_pct", "sparse_attn_mxu_pct")
+# ``sparse_flash_time_pct`` (reader and test since PR 47) was listed by PR 64,
+# which also gave ``expert_time_pct`` its second cell: the windowed one's.
+OWN = ("expert_time_pct", "sparse_attn_time_pct", "sparse_attn_mxu_pct", "sparse_flash_time_pct")
 
 
 @pytest.fixture(scope="module")
@@ -93,16 +95,21 @@ def test_the_entries_are_the_ones_the_issue_names(bench):
     cell = bench.cell(CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (CONFIG, "ftddp-seq8k", 1)
     by_name = {m["name"]: m for m in bench.data["end_to_end"] + bench.data["per_layer"]}
-    for name in LISTED:
-        assert by_name[name]["workloads"][-1] == CELL, name
+    for name in LISTED:  # membership, not places: a later PR appends after these
+        assert CELL in by_name[name]["workloads"], name
+    # A metric outside these lists may list the cell only if it names its
+    # cells by job (PR 59's four list every ``ftddp`` cell): then all of them.
+    by_job = {w["name"] for w in bench.data["workloads"] if bench.traffic(w["traffic"])["job"] == "ftddp"}
     for name, metric in by_name.items():
-        if name not in LISTED + OWN and "workloads" in metric:
-            assert CELL not in metric["workloads"], name
-    assert [m["name"] for m in bench.data["per_layer"][-3:]] == list(OWN)
+        if name not in LISTED + OWN and CELL in metric.get("workloads", ()):
+            assert by_job <= set(metric["workloads"]), name
+    names = [m["name"] for m in bench.data["per_layer"]]
+    assert [n for n in names if n in OWN] == list(OWN)
     assert "expert_mxu_pct" not in by_name
     for name in OWN:
         metric = by_name[name]
-        assert metric["workloads"] == [CELL] and metric["unit"] == "%"
+        assert metric["workloads"][0] == CELL and metric["unit"] == "%"
+        assert metric["workloads"] == [CELL] or name == "expert_time_pct"  # the other routed stack's too
         assert (metric["source"], metric["layer"], metric["moves"]) == ("device_trace", "kernels", "tokens_per_s")
 
 
@@ -150,6 +157,40 @@ def test_the_readers_read_the_kernels_and_the_tiled_path(bench, config, architec
     assert read("sparse_attn_time_pct", obs) == pytest.approx(100 * 1.5 / 6.0)
     assert read("sparse_attn_mxu_pct", obs) == pytest.approx(
         100 * 20 * architecture.selected_attention_flops(config, 1, 8192) / 1.5 / 197e12)
+    assert read("sparse_flash_time_pct", obs) is None  # PR 46's program: no attention kernel
+
+
+def test_selected_attention_is_the_selection_and_the_kernels_that_attend_under_it(bench, config, architecture):
+    """Since PR 64 the two ``sparse_attn_*`` readers sum the selection's XLA ops
+    AND the Pallas calls that are not the expert layer's (PR 47's flash calls
+    with the selection as an operand): the count ``selected_attention_flops``
+    holds attention's seven matmuls, so the seconds must hold attention's
+    kernels, or a faster selection alone pushes the share of the peak past 100
+    (ledger, PR 63: 49.33 on 18.58% of the busy time). No op is counted twice,
+    the expert layer's sums by token are neither attention nor the grouped
+    product, and a codec program's kernels are another reader's."""
+    kernels = {
+        "jit__fused": KERNELS["jit__fused"] + [
+            ["attn.21 bf16[1,32,8192,128]", 1.0], ["attn.22 bf16[1,32,8192,128]", 0.5],
+            ["sum_by_token.9 f32[8192,2048]", 0.25], ["transpose_jvp_sum_by_token__.9 bf16[8192,2048]", 0.25],
+        ],
+        "jit_quantize_pseudograd": [["quantize.3 f8e4m3fn[1048576,256]", 9.0]],
+    }
+    read = lambda name, obs: bench.reader("per_layer", name).read(obs)
+    obs = obs_of(config, trace={"busy_s": 6.0, "kernels": kernels, "ops": OPS})
+    assert architecture.selected_attention_seconds(obs["trace"], config, 8192) == pytest.approx(1.5 + 1.5)
+    assert read("sparse_flash_time_pct", obs) == pytest.approx(100 * 1.5 / 6.0)
+    assert read("sparse_attn_time_pct", obs) == pytest.approx(100 * 3.0 / 6.0)
+    assert read("sparse_attn_mxu_pct", obs) == pytest.approx(
+        100 * 20 * architecture.selected_attention_flops(config, 1, 8192) / 3.0 / 197e12)
+    assert read("expert_time_pct", obs) == pytest.approx(100 * 0.6 / 6.0)  # as before: the product's calls
+    # A selection made by a kernel one day stays in sight, and a selection ten
+    # times faster cannot pass the peak while attention's kernels are counted.
+    faster = [[name, s / 10] for name, s in OPS]
+    obs = obs_of(config, trace={"busy_s": 6.0, "kernels": kernels, "ops": faster})
+    assert read("sparse_attn_time_pct", obs) == pytest.approx(100 * (0.15 + 1.5) / 6.0)
+    names = [name for rows in kernels.values() for name, _ in rows]
+    assert not [n for n in names if architecture.EXPERT_KERNEL.search(n) and not architecture.EXPERT_LAYER_KERNEL.search(n)]
 
 
 @pytest.mark.parametrize("name", OWN)
@@ -157,7 +198,7 @@ def test_the_readers_read_the_kernels_and_the_tiled_path(bench, config, architec
 def test_a_reader_with_nothing_to_read_returns_nothing(name, case, bench, config):
     """As on the parent commit, whose program has neither the kernels nor the
     tiles: the line leaves the metric out and nothing raises."""
-    dense = {"busy_s": 6.0, "kernels": {"jit__fused": [["attn.17 bf16[1,32,8192,128]", 1.0]]},
+    dense = {"busy_s": 6.0, "kernels": {"jit_quantize_pseudograd": [["quantize.3 f8e4m3fn[1048576,256]", 1.0]]},
              "ops": [["fusion.9 bf16[8192,4096]", 3.0]]}
     obs = {
         "no-trace": obs_of(config, trace=None),

@@ -1,17 +1,15 @@
 """The reader ``sparse_flash_time_pct`` (PR 47) on hand-made traces: the share
 of busy time in the flash kernels that attend under the key selection, in a
-cell whose other Pallas calls are the grouped expert product's. It has no
-entry in BENCHMARK.json yet (``test_keye_cell.py`` holds that file's list of
-the cell's metrics to what PR 46 listed; PERF.md section 7): the entry a
-``benchmark`` PR appends is held sound here from a copy of the data. On the
-CPU; tier-1 collects it.
+cell whose other Pallas calls are the expert layer's (the grouped product's,
+and since PR 51 the sums by token). PR 64 listed it in BENCHMARK.json, for the
+Keye cell alone; the entry is held to what PR 47 wrote down here. On the CPU;
+tier-1 collects it.
 
     JAX_PLATFORMS=cpu python -m pytest tests/chipbench_tests/test_sparse_flash_reader.py -q
 """
 
 from __future__ import annotations
 
-import copy
 import sys
 from pathlib import Path
 
@@ -80,14 +78,24 @@ def test_with_nothing_to_read_it_returns_nothing(case, bench, read):
 
 
 def test_the_benchmark_is_sound_and_stays_so_with_the_entry_appended(bench):
+    """Appended by PR 64, as PR 47 wrote it down: the Keye cell reports it and
+    no other cell does."""
     assert spec.problems(bench) == []
-    assert NAME not in [m["name"] for m in bench.data["per_layer"]]
-    with_entry = spec.Benchmark(ROOT)
-    with_entry.data = copy.deepcopy(bench.data)
-    with_entry.data["per_layer"].append(ENTRY)
-    assert spec.problems(with_entry) == []
-    assert NAME in [m["name"] for m in with_entry.metrics_of(CELL, "per_layer")]
+    (entry,) = [m for m in bench.data["per_layer"] if m["name"] == NAME]
+    assert entry == ENTRY
+    assert NAME in [m["name"] for m in bench.metrics_of(CELL, "per_layer")]
     assert all(
-        NAME not in [m["name"] for m in with_entry.metrics_of(w["name"], "per_layer")]
+        NAME not in [m["name"] for m in bench.metrics_of(w["name"], "per_layer")]
         for w in bench.data["workloads"] if w["name"] != CELL
     )
+
+
+def test_the_sums_by_token_are_the_expert_layers_and_not_attention(bench, read):
+    """PR 51 made the expert layer's two sums by token Mosaic calls: the Keye
+    file names them (``EXPERT_LAYER_KERNEL``) beside the grouped product's, so
+    they are not read as attention; ``expert_time_pct`` stays the product's."""
+    kernels = {"jit__fused": KERNELS["jit__fused"] + [
+        ["sum_by_token.9 f32[8192,2048]", 0.27], ["transpose_jvp_sum_by_token__.9 bf16[8192,2048]", 0.21]]}
+    obs = obs_of(bench, "keye-vl2-30b-a3b-ep8-1chip", {"busy_s": 6.0, "kernels": kernels, "ops": []})
+    assert read(obs) == pytest.approx(100 * (0.75 + 0.45) / 6.0)
+    assert bench.reader("per_layer", "expert_time_pct").read(obs) == pytest.approx(100 * (0.30 + 0.10) / 6.0)
