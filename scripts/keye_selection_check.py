@@ -7,6 +7,18 @@
   selected (query, key) pairs that the other side did not select. The two
   differ because the hidden states differ by bf16 rounding and a key near a
   query's threshold then falls on the other side of it;
+- on a TPU, beside it, the pairs on which the selection of the ONE Mosaic call
+  (``ops/key_selection.py``) and the tiled XLA path's differ, layer by layer
+  (the same model run again with the call switched off: layer 0 reads the
+  same inputs on both sides, a later layer also what an earlier difference did
+  to its hidden states): the two differ by the order of a float32 sum, so 0 to
+  a few pairs in 10^8;
+- the share of the flash kernels' needed blocks (those that hold a pair under
+  the diagonal) that the selection leaves WHOLLY EMPTY (a schedule made from
+  the operand would not step them) and WHOLLY SELECTED (every pair the causal
+  mask allows: they would run without the operand), at 128, 256 and 512 rows
+  by 1024 keys, layer by layer: ROADMAP Speed 1(a)'s "measure before
+  building";
 - the rows each held expert receives (``models.keye.router_load``), so that the
   cell's ``why`` can say how near uniform the routing of seeded weights is,
   and the row count each layer's expert dispatch runs at for them
@@ -109,7 +121,6 @@ def check(bench, config, traffic, seed: int, steps) -> dict:
     params, tokens = system.init_params(), system.tokens(0)
     out = {"seed": seed, "device": jax.devices()[0].device_kind}
 
-    @jax.jit
     def program(params, tokens):
         _, seen = system.model.apply(params, tokens[:, :-1], mutable=["intermediates"])
         return seen["intermediates"]["layers"]["block"]["attn"]["selection"][0][:, 0]
@@ -125,9 +136,16 @@ def check(bench, config, traffic, seed: int, steps) -> dict:
         only = jnp.sum((mine != 0) & ~theirs, axis=(1, 2))
         return only, jnp.sum(theirs, axis=(1, 2))
 
-    only, selected = differing(program(params, tokens), plain(params, tokens))
+    mine = jax.jit(program)(params, tokens)
+    only, selected = differing(mine, plain(params, tokens))
     out["pairs_selected_by_layer"] = np.asarray(selected).tolist()
     out["share_of_pairs_that_differ_by_layer"] = (np.asarray(only) / np.asarray(selected)).tolist()
+    out["kernel_pairs_that_differ_from_the_xla_path_by_layer"] = kernel_against_xla(
+        program, system.config["sa_config"], params, tokens, mine
+    )
+    out["flash_blocks_by_rows"] = {
+        rows: block_shares(mine != 0, rows, FLASH_BLOCK_KEYS) for rows in (128, 256, 512)
+    }
 
     routing = routing_of(system)
     out["rows_by_held_expert"] = routing(params, tokens)
@@ -135,6 +153,66 @@ def check(bench, config, traffic, seed: int, steps) -> dict:
     if steps:
         out["routing_after_steps"] = routing_after(system, routing, params, steps)
     return out
+
+
+def kernel_against_xla(program, sa, params, tokens, mine):
+    """Per layer, the pairs on which ``mine`` (``program``'s selection as the
+    platform makes it) and the tiled XLA path's differ; None where the program
+    made its selection by that path itself (off a TPU, or a sequence the call
+    does not take)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from torchft_tpu.ops import key_selection
+    from torchft_tpu.ops.sparse_attention import select_keys
+
+    probe = jax.ShapeDtypeStruct((1, mine.shape[-1], 1, sa["indexer_head_dim"]), jnp.float32)
+    ran = jax.make_jaxpr(lambda x: select_keys(x, x[:, :, 0], x[..., 0], topk=sa["topk"]))(probe)
+    if "pallas_call" not in str(ran):
+        return None
+    fits = key_selection.fits
+    key_selection.fits = lambda *shape: False  # the same model, the call switched off
+    try:
+        # A function of its own: jit's cache is by function, and ``program``'s
+        # entry is the one traced with the call in it.
+        tiled = jax.jit(lambda params, tokens: program(params, tokens))(params, tokens)
+    finally:
+        key_selection.fits = fits
+    return np.asarray(jnp.sum((mine != 0) != (tiled != 0), axis=(1, 2))).tolist()
+
+
+FLASH_BLOCK_KEYS = 1024
+
+
+def block_shares(chosen, rows: int, keys: int) -> dict:
+    """chosen (layers, s, s) bool -> of the (rows x keys) blocks that hold a
+    pair under the diagonal, by layer, the share with no selected pair and the
+    share whose every causal pair is selected."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    s = chosen.shape[-1]
+    rows, keys = min(rows, s), min(keys, s)
+
+    @jax.jit
+    def shares(chosen):
+        def blocks(x):
+            pairs = x.reshape(*x.shape[:-2], s // rows, rows, s // keys, keys)
+            return jnp.sum(pairs, axis=(-3, -1), dtype=jnp.int32)
+
+        selected, allowed = blocks(chosen), blocks(jnp.tril(jnp.ones((s, s), bool)))
+        needed = allowed > 0
+        share = lambda hit: jnp.sum(hit & needed, axis=(1, 2)) / jnp.sum(needed)
+        return jnp.sum(needed), share(selected == 0), share(selected == allowed)
+
+    needed, empty, full = shares(chosen)
+    return {
+        "needed": int(needed),
+        "empty_share_by_layer": np.asarray(empty).tolist(),
+        "full_share_by_layer": np.asarray(full).tolist(),
+    }
 
 
 def control(system, params) -> dict:

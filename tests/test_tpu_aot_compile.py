@@ -647,6 +647,29 @@ def test_selected_flash_kernels_compile_at_the_keye_cells_geometry(chip) -> None
     assert max(used[0] for _, _, used in calls) <= need, calls
 
 
+def test_selection_kernel_compiles_at_the_keye_cells_geometry(chip) -> None:
+    """The key selection as one Mosaic call (ops/key_selection.py) at 1 x 8192
+    with 16 indexer heads of 64 and top-2048: it lowers through Mosaic under
+    its own name, states no VMEM limit (a stated one retiles other fusions of
+    the step: ops/flash_attention.py ``_SCOPED_VMEM_BYTES``) and uses no more
+    than the 16 MiB a call gets unasked; around it XLA only transposes the
+    two operands, with no product of its own."""
+    from torchft_tpu.ops import flash_attention as fa
+    from torchft_tpu.ops import key_selection as ks
+
+    s, j, e = 8192, 16, 64
+    assert ks.fits(s, e)
+    compiled = _compile(
+        lambda qi, ki, w: ks.key_selection(qi, ki, w, topk=2048),
+        _sds((1, s, j, e), jnp.float32, chip), _sds((1, s, e), jnp.float32, chip),
+        _sds((1, s, j), jnp.float32, chip),
+    )
+    ((name, stated, used),) = _mosaic_calls(compiled)
+    assert ks.KERNEL_NAME in name and stated == [], (name, stated)
+    assert used[0] <= fa._SCOPED_VMEM_BYTES, used
+    assert " convolution(" not in compiled.as_text()
+
+
 # The cell ``keye-vl2-30b-a3b-1chip.ftddp-seq8k``'s own size, and a twin at toy
 # widths for tier-1: the same head_dim, GQA group of 8, block sizes and four
 # key groups, an eighth of the sequence.
@@ -677,11 +700,12 @@ def test_dots_step_of_the_keye_cell_selects_once_and_runs_one_flash_pair_a_layer
     for each kernel of a layer body: beside the expert layer's (``gmm`` and the
     sum by token, ``sum_by_token``: one a rung in each of its two
     conditionals), the flash forward and the ONE backward, each with the
-    selection as its operand, and neither stating a VMEM limit; and the index scores are
-    computed once a key group, in the forward's loop body alone. With a
-    policy that keeps the dots and none of the three names, the backward's
-    loop holds the forward call and the selection a second time: what the
-    names are kept for."""
+    selection as its operand, and neither stating a VMEM limit; and the
+    selection is ONE Mosaic call (``key_selection``) a traced layer, in the
+    forward's loop body alone, with no index-score product left in XLA. With
+    a policy that keeps the dots and none of the three names, the backward's
+    loop holds the forward call and the selection's call a second time: what
+    the names are kept for."""
     import json
     from pathlib import Path
 
@@ -691,7 +715,7 @@ def test_dots_step_of_the_keye_cell_selects_once_and_runs_one_flash_pair_a_layer
     import torchft_tpu.ops.sparse_attention as sparse
     from chipbench import spec
     from chipbench.model import System
-    from torchft_tpu.ops.sparse_attention import KEY_GROUPS
+    from torchft_tpu.ops.key_selection import KERNEL_NAME
     from torchft_tpu.optim import make_jit_fused_step
 
     for module in (sparse, flash, grouped):
@@ -720,21 +744,24 @@ def test_dots_step_of_the_keye_cell_selects_once_and_runs_one_flash_pair_a_layer
         assert all(stated == [] for _, stated, _ in calls), calls
         sums = [name for name, _, _ in calls if "sum_by_token" in name]
         assert len(sums) == 6 and not any(architecture.EXPERT_KERNEL.search(name) for name in sums)
+        selections = [name for name, _, _ in calls if KERNEL_NAME in name]
+        assert not any(architecture.EXPERT_LAYER_KERNEL.search(name) for name in selections)
         flash_calls = [
             name for name, _, _ in calls
-            if not architecture.EXPERT_KERNEL.search(name) and name not in sums
+            if not architecture.EXPERT_KERNEL.search(name) and name not in sums + selections
         ]
-        index_scores = [
-            line for line in program.as_text().splitlines()
-            if "tpuft::indexer" in line and " convolution(" in line
+        text = program.as_text().splitlines()
+        assert not any("tpuft::indexer" in line and " convolution(" in line for line in text)
+        made = [
+            line for line in text
+            if 'custom_call_target="tpu_custom_call"' in line and KERNEL_NAME in line.split(" = ")[0]
         ]
-        return len(flash_calls), len(index_scores), sum(
-            "rematted_computation" in line for line in index_scores
-        )
+        assert len(made) == len(selections) and all("tpuft::indexer" in line for line in made)
+        return len(flash_calls), len(made), sum("rematted_computation" in line for line in made)
 
-    assert compiled() == (2, KEY_GROUPS, 0)
+    assert compiled() == (2, 1, 0)
     monkeypatch.setattr(keye, "remat_policy", lambda remat, dots, *names: dots)
-    assert compiled() == (3, 2 * KEY_GROUPS, KEY_GROUPS)
+    assert compiled() == (3, 2, 1)
 
 
 def _computations(text: str) -> dict:

@@ -14,22 +14,31 @@ matmul precision whatever the model's dtype: a key that flips in or out of
 ``S_t`` is a discontinuity of the output and not a rounding of it. The
 selection carries no gradient (top-k is piecewise constant).
 
-Two paths, and :func:`selected_attention` says which runs where. Both walk the queries in
-tiles of ``block``, one at a time (``lax.map``), in ``KEY_GROUPS`` groups that
-share a key length, so that a tile early in the sequence does not pay for the
-keys after it (with 4 groups the pairs computed are 1.18 times the causal
-half, with 1 group twice); a tile scores itself against the keys up to its
-group's last position and finds each row's threshold by a radix select over
-the scores' bits (32 counting passes, exact, no sort).
+Two paths, and :func:`selected_attention` says which runs where.
 
 - ON THE CHIP the selection is an operand: :func:`select_keys` makes it once
   a layer step as one (b, s, s) int8 array, named ``SELECTION`` for a remat
   policy to keep, and ops/flash_attention.py's forward and its one backward
   call read it block by block (``flash_attention(..., selection=...)``):
   the attention scores never leave VMEM and the backward makes no selection
-  again.
-- ELSEWHERE, and as the oracle of the kernels' tests, :func:`sparse_attention`:
-  a tile selects and then attends under the mask in plain XLA. A tile is one
+  again. The operand is made by ONE Mosaic call (ops/key_selection.py,
+  ``key_selection.<n>`` in a trace; since PR 67): a block of 128 queries at a
+  time it scores the keys the block may see and no others (the six float32
+  products packed into three passes of the MXU's full depth), finds each
+  row's threshold where the scores lie in VMEM and writes the int8 rows where
+  the flash kernels read them; rows with no more than ``topk`` earlier keys
+  are the causal mask and score nothing.
+- ELSEWHERE, for a sequence that is not whole blocks of that call, and as the
+  oracle of the kernels' tests, plain tiled XLA: :func:`sparse_attention`
+  selects and then attends under the mask tile by tile, and
+  :func:`select_keys` keeps the same tiles for the operand alone. The queries
+  are walked in tiles of ``block``, one at a time (``lax.map``), in
+  ``KEY_GROUPS`` groups that share a key length, so that a tile early in the
+  sequence does not pay for the keys after it (with 4 groups the pairs
+  computed are 1.25 times the causal half, with 1 group twice); a
+  tile scores itself against the keys up to its group's last position and
+  finds each row's threshold by a radix select over the scores' bits (32
+  counting passes, exact, no sort). In :func:`sparse_attention` a tile is one
   ``jax.checkpoint``: the backward recomputes its scores and selection and
   keeps nothing of a tile but its inputs.
 """
@@ -42,7 +51,9 @@ from typing import List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+from jax.sharding import AxisType
 
+from torchft_tpu.ops import key_selection
 from torchft_tpu.ops.attention import flash_under_mesh
 from torchft_tpu.ops.flash_attention import FLASH_OUT
 from torchft_tpu.utils.platform import on_tpu
@@ -150,7 +161,18 @@ def _indexer_inputs(s: int, block: int, *xs: jnp.ndarray):
     block = min(block, s)
     if s % block:
         raise ValueError(f"a sequence of {s} positions is not whole tiles of {block}")
-    return block, s // block, *(jax.lax.stop_gradient(x.astype(jnp.float32)) for x in xs)
+    return block, s // block, *_no_gradient(*xs)
+
+
+def _no_gradient(*xs: jnp.ndarray):
+    return tuple(jax.lax.stop_gradient(x.astype(jnp.float32)) for x in xs)
+
+
+def _one_program() -> bool:
+    """No axis of an ambient mesh is left to XLA's partitioner, which cannot
+    split a Mosaic call (ops/attention.py ``flash_under_mesh``)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return all(kind == AxisType.Manual for kind in getattr(mesh, "axis_types", ()))
 
 
 def select_keys(
@@ -158,9 +180,24 @@ def select_keys(
 ) -> jnp.ndarray:
     """The indexer's qi (b, s, j, e), ki (b, s, e), w (b, s, j) -> the
     selection as an array, (b, s, s) int8: 1 where query t attends to key s,
-    never a later key. The arithmetic is :func:`sparse_attention`'s own, tile
-    by tile; nothing is checkpointed (no gradient passes: the inputs stop it),
-    and the result carries the name ``SELECTION``."""
+    never a later key, under the name ``SELECTION``. On a TPU ONE Mosaic call
+    (ops/key_selection.py) where the sequence is whole blocks of it and no
+    mesh would have XLA partition it; elsewhere, and for any other shape,
+    :func:`sparse_attention`'s own arithmetic tile by tile. Nothing is
+    checkpointed (no gradient passes: the inputs stop it)."""
+    s, e = qi.shape[1], qi.shape[3]
+    if on_tpu() and key_selection.fits(s, e) and _one_program():
+        with jax.named_scope("tpuft::indexer"):
+            chosen = key_selection.key_selection(*_no_gradient(qi, ki, w), topk=topk)
+    else:
+        chosen = _select_keys_tiled(qi, ki, w, topk=topk, block=block)
+    return checkpoint_name(chosen, SELECTION)
+
+
+def _select_keys_tiled(qi, ki, w, *, topk: int, block: int) -> jnp.ndarray:
+    """:func:`select_keys` in plain XLA: a tile's selection by
+    :func:`_tile_selection`, the int8 tiles padded to the sequence and laid
+    into one array."""
     s = qi.shape[1]
     block, tiles, qi, ki, w = _indexer_inputs(s, block, qi, ki, w)
     rows = (jnp.arange(0, s, block), _tiled(qi, tiles), _tiled(w, tiles))
@@ -172,7 +209,7 @@ def select_keys(
             tuple(x[lo:hi] for x in rows),
         )
         chosen.append(jnp.pad(sel, ((0, 0), (0, 0), (0, 0), (0, s - hi * block))))
-    return checkpoint_name(_untiled(chosen), SELECTION)
+    return _untiled(chosen)
 
 
 def sparse_attention(
